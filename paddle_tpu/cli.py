@@ -1195,6 +1195,9 @@ def main(argv=None) -> int:
         print(__doc__)
         return 0
     cmd, rest = argv[0], argv[1:]
+    from . import compile_cache
+
+    compile_cache.enable()
     if cmd == "train":
         return _cmd_train(rest)
     if cmd == "merge_model":

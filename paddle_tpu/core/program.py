@@ -271,6 +271,9 @@ class Program:
 
         return {
             "version": 1,
+            # the compute dtype is part of what was built: a bf16 model
+            # saved for serving must not come back as an f32 one
+            **({"amp_dtype": self.amp_dtype} if self.amp_dtype else {}),
             "blocks": [
                 {
                     "idx": b.idx,
@@ -315,6 +318,7 @@ class Program:
                 b.ops.append(Operator(od["type"], od["inputs"], od["outputs"], od["attrs"]))
             p.blocks.append(b)
         p._current_block_idx = 0
+        p.amp_dtype = d.get("amp_dtype")
         return p
 
 

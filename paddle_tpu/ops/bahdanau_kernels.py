@@ -663,16 +663,17 @@ def _gru_fwd_step(xp, h_prev, wh, H):
 
 
 @functools.lru_cache(maxsize=None)
-def _decoder_fn(interpret: bool, axis=None):
+def _decoder_fn(interpret: bool):
     """custom-VJP'd teacher-forcing decoder over padded-S operands.
 
     (enc, ep, maskf [B,Sp], trg [T,B,E], tmask [T,B], h0,
      wa_dec [H,A], v [A], wx [(E+C),3H], wh [H,3H], bias [3H]) -> h_seq.
 
-    `axis` names the dp shard_map axis when the call runs under a mesh
-    (mesh_dispatch policy): operands are then per-shard, and the weight
-    cotangents — per-shard partial sums over the local batch — are
-    psum'd in the backward (check_vma is off, so no automatic psum).
+    Under a dp mesh the call runs inside shard_map (mesh_dispatch
+    policy): operands are then per-shard and the weight cotangents are
+    per-shard partial sums over the local batch, returned as they are —
+    shard_map's transpose sums the cotangents of replicated inputs
+    (see ops/pallas_kernels._lstm_core).
     """
 
     def forward(enc, ep, maskf, trg, tmask, h0, wa_dec, v, wx, wh, bias):
@@ -809,11 +810,6 @@ def _decoder_fn(interpret: bool, axis=None):
         denc = jnp.einsum("tbs,tbc->bsc", alpha_seq.astype(dt),
                           dctx_seq).astype(enc.dtype)
         dv = dv.astype(jnp.float32)
-        if axis is not None:
-            # replicated-weight cotangents: per-shard partials -> global
-            dwx, dbias, dwh, dwa_dec, dv = (
-                jax.lax.psum(g, axis)
-                for g in (dwx, dbias, dwh, dwa_dec, dv))
         return (denc, dep, jnp.zeros_like(maskf), dx_seq,
                 jnp.zeros_like(tmask), dh0, dwa_dec.astype(wa_dec.dtype),
                 dv.astype(v.dtype), dwx.astype(wx.dtype),
@@ -841,10 +837,7 @@ def fused_attention_decoder(enc_b, enc_proj, enc_mask, trg_b, trg_mask,
     dispatch_stats["fused_calls"] += 1
     from . import mesh_dispatch
 
-    am = mesh_dispatch.current()
-    # axis only when shard_batch will actually wrap (dp > 1)
-    f = _decoder_fn(_interpret(),
-                    am.batch_axis if am and am.dp > 1 else None)
+    f = _decoder_fn(_interpret())
     # mesh policy (ops/mesh_dispatch.py): the kernels run per-shard
     # under shard_map — batch-sharded operands, replicated weights
     call = mesh_dispatch.shard_batch(

@@ -11,10 +11,15 @@ pattern for pallas + sharding:
 
 - batch-sharded operands in, batch-sharded activations out;
 - weights replicated in; their cotangents are per-shard partial sums,
-  so the custom-VJP backwards `psum` them over the dp axis (shard_map
-  runs with check_vma off — pallas calls don't carry replication
-  rules — which means NO automatic cotangent psum: each kernel
-  family's bwd does it explicitly, keyed by the `axis` parameter);
+  and shard_map's transpose psums the cotangent of every replicated
+  input over the mesh axis — in jax 0.9.0 also with check_vma off
+  (pallas calls don't carry replication rules, so it is off here). The
+  custom-VJP backwards therefore return the per-shard partial and
+  nothing else. They used to psum it themselves, for an older jax that
+  did not; on 0.9.0 that counted every weight gradient dp times over,
+  which Adam's scale invariance hid from the loss-level tests until
+  `chip_smoke.py --four-chips` compared gradients (PR 21;
+  tests/test_mesh_fused_kernels.py now does so on the CPU mesh);
 - eligibility is evaluated at the PER-SHARD batch (`local_batch`):
   what the kernel actually sees inside shard_map. Non-divisible or
   ineligible-at-local-batch configs fall back to the XLA scan
@@ -113,8 +118,9 @@ def shard_batch(fn, batch_dims, out_dims, out_tree=None):
     output's (batch_dim, ndim) — callers know their output ranks
     statically. `out_tree` (a treedef from jax.tree.structure on an
     example output) restores structure; None = single array output. The
-    wrapped fn's custom-VJP backward must psum replicated-input
-    cotangents itself (see module docstring)."""
+    wrapped fn's custom-VJP backward returns per-shard partial
+    cotangents for replicated inputs; shard_map's transpose sums them
+    (see module docstring)."""
     am = _ACTIVE.get()
     if am is None or am.dp == 1:
         return fn

@@ -26,7 +26,6 @@ the same code path).
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -68,6 +67,18 @@ def _backend_ok() -> bool:
 # on different compiles of the same graph — borderline configs flip with
 # the compiler's scratch scheduling, so they stay on the scan.
 _VMEM_BUDGET = 15 * 1024 * 1024
+
+# The model above counts blocks and carries; it does not count the f32
+# gate temporaries the compiler keeps per step (they grow with B*4H), and
+# libtpu 0.0.34 refuses configs the model admits at its 16M DEFAULT
+# scoped-VMEM cap: LSTM backward bf16 H=512 at B=256 (16.6M — a cell of
+# the reference's published grid), B=384 (21.9M), B=512 (18.0M); the
+# forward at H=1280 B=128 (17.5M, its resident [H,4H] weight alone is
+# 13.1M). The chip has 128M of VMEM, so every kernel of this family
+# raises the cap instead of narrowing the windows; the largest need
+# among the eligible configs is 22M (tests/test_tpu_compile.py compiles
+# them for a described v5e).
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
 
 
 def _bwd_vmem_bytes(B: int, H: int, G: int, itemsize: int,
@@ -225,6 +236,7 @@ def _lstm_pallas_raw(x_tbh, mask, w_rec):
             pltpu.VMEM((B, H), dt),
             pltpu.VMEM((B, H), dt),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
     )(x_tbh, mask.astype(jnp.float32).reshape(T, 1, B), w_rec)
 
@@ -366,6 +378,7 @@ def _lstm_bwd_pallas(x_tbh, mask, w_rec, h_seq, c_seq, dh_seq, dhT, dcT):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
     )(
         gates_pre,
@@ -395,7 +408,7 @@ def lstm_fused(x_tbh, mask, w_rec, bias=None, reverse=False):
     time reversal (flip in, flip the emitted sequence back). Under an
     active mesh the call is shard_map'd over the dp axis (mesh_dispatch
     policy): batch-sharded x/mask, replicated weight, per-shard kernel
-    at the local batch, dW psum'd in the backward."""
+    at the local batch; shard_map's transpose sums the per-shard dW."""
     if bias is not None:
         # master-weight bias casts DOWN to the activation dtype (amp):
         # promoting x to f32 here would double the whole sequence's HBM
@@ -404,13 +417,9 @@ def lstm_fused(x_tbh, mask, w_rec, bias=None, reverse=False):
     # f32 master weight likewise meets the activation dtype at the kernel
     # boundary; the cast's transpose restores an f32 dW for the optimizer
     w_rec = w_rec.astype(x_tbh.dtype)
-    am = mesh_dispatch.current()
-    # axis only when shard_batch will actually wrap (dp > 1): a dp=1
-    # mesh runs unwrapped, where a psum over the axis name is unbound
-    core = _lstm_core(am.batch_axis if am and am.dp > 1 else None)
     # outputs (h_seq [T,B,H], (h_T [B,H], c_T [B,H]))
     call = mesh_dispatch.shard_batch(
-        core, (1, 1, None), ((1, 3), (0, 2), (0, 2)),
+        _lstm_core, (1, 1, None), ((1, 3), (0, 2), (0, 2)),
         out_tree=_RNN_LSTM_OUT_TREE)
     if reverse:
         h_seq, last = call(x_tbh[::-1], mask[::-1], w_rec)
@@ -422,35 +431,33 @@ _RNN_LSTM_OUT_TREE = jax.tree.structure((0, (0, 0)))
 _RNN_GRU_OUT_TREE = jax.tree.structure((0, 0))
 
 
-@functools.lru_cache(maxsize=None)
-def _lstm_core(axis):
-    """custom-VJP fused LSTM; `axis` names the dp shard_map axis (None =
-    unsharded). The weight cotangent is a per-shard partial sum, so the
-    backward psums it over `axis` — shard_map runs with check_vma off
-    (pallas calls carry no replication rule), which disables the
-    automatic cotangent psum for replicated inputs."""
+@jax.custom_vjp
+def _lstm_core(x_tbh, mask, w_rec):
+    """custom-VJP fused LSTM. Under a dp mesh it runs inside shard_map
+    and its weight cotangent is a per-shard partial sum; the backward
+    returns it AS IS — shard_map's transpose psums the cotangent of a
+    replicated input over the mesh axis, with check_vma off too (jax
+    0.9.0; see mesh_dispatch). An explicit psum here counts it dp times
+    over."""
+    h_seq, _c_seq, h_T, c_T = _lstm_pallas_raw(x_tbh, mask, w_rec)
+    return h_seq, (h_T, c_T)
 
-    @jax.custom_vjp
-    def core(x_tbh, mask, w_rec):
-        h_seq, _c_seq, h_T, c_T = _lstm_pallas_raw(x_tbh, mask, w_rec)
-        return h_seq, (h_T, c_T)
 
-    def fwd(x_tbh, mask, w_rec):
-        h_seq, c_seq, h_T, c_T = _lstm_pallas_raw(x_tbh, mask, w_rec)
-        return (h_seq, (h_T, c_T)), (x_tbh, mask, w_rec, h_seq, c_seq)
+def _lstm_core_fwd(x_tbh, mask, w_rec):
+    h_seq, c_seq, h_T, c_T = _lstm_pallas_raw(x_tbh, mask, w_rec)
+    return (h_seq, (h_T, c_T)), (x_tbh, mask, w_rec, h_seq, c_seq)
 
-    def bwd(res, ct):
-        x_tbh, mask, w_rec, h_seq, c_seq = res
-        dh_seq, (dhT, dcT) = ct
-        dx, dw = _lstm_bwd_pallas(
-            x_tbh, mask, w_rec, h_seq, c_seq, dh_seq, dhT, dcT
-        )
-        if axis is not None:
-            dw = jax.lax.psum(dw, axis)
-        return dx, None, dw
 
-    core.defvjp(fwd, bwd)
-    return core
+def _lstm_core_bwd(res, ct):
+    x_tbh, mask, w_rec, h_seq, c_seq = res
+    dh_seq, (dhT, dcT) = ct
+    dx, dw = _lstm_bwd_pallas(
+        x_tbh, mask, w_rec, h_seq, c_seq, dh_seq, dhT, dcT
+    )
+    return dx, None, dw
+
+
+_lstm_core.defvjp(_lstm_core_fwd, _lstm_core_bwd)
 
 
 # ------------------------------------------------------------------- GRU ---
@@ -511,6 +518,7 @@ def _gru_pallas_raw(x_tbh, mask, w_rec):
             jax.ShapeDtypeStruct((B, H), dt),
         ],
         scratch_shapes=[pltpu.VMEM((B, H), dt)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
     )(x_tbh, mask.astype(jnp.float32).reshape(T, 1, B), w_rec)
 
@@ -647,6 +655,7 @@ def _gru_bwd_pallas(x_tbh, mask, w_rec, h_seq, dh_seq, dhT):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
     )(
         ur_pre,
@@ -677,40 +686,36 @@ def gru_fused(x_tbh, mask, w_rec, bias=None, reverse=False):
     """Fused GRU over the whole sequence (zero-boot, sigmoid/tanh).
 
     Mesh policy as lstm_fused: shard_map'd over dp when a mesh is
-    active, dW psum'd in the backward."""
+    active."""
     if bias is not None:
         x_tbh = x_tbh + bias.astype(x_tbh.dtype)  # see lstm_fused
     w_rec = w_rec.astype(x_tbh.dtype)
-    am = mesh_dispatch.current()
-    core = _gru_core(am.batch_axis if am and am.dp > 1 else None)  # see lstm_fused
     call = mesh_dispatch.shard_batch(
-        core, (1, 1, None), ((1, 3), (0, 2)), out_tree=_RNN_GRU_OUT_TREE)
+        _gru_core, (1, 1, None), ((1, 3), (0, 2)),
+        out_tree=_RNN_GRU_OUT_TREE)
     if reverse:
         h_seq, h_T = call(x_tbh[::-1], mask[::-1], w_rec)
         return h_seq[::-1], h_T
     return call(x_tbh, mask, w_rec)
 
 
-@functools.lru_cache(maxsize=None)
-def _gru_core(axis):
-    """custom-VJP fused GRU; see _lstm_core for the axis/psum contract."""
+@jax.custom_vjp
+def _gru_core(x_tbh, mask, w_rec):
+    """custom-VJP fused GRU; see _lstm_core for the mesh contract."""
+    h_seq, h_T = _gru_pallas_raw(x_tbh, mask, w_rec)
+    return h_seq, h_T
 
-    @jax.custom_vjp
-    def core(x_tbh, mask, w_rec):
-        h_seq, h_T = _gru_pallas_raw(x_tbh, mask, w_rec)
-        return h_seq, h_T
 
-    def fwd(x_tbh, mask, w_rec):
-        h_seq, h_T = _gru_pallas_raw(x_tbh, mask, w_rec)
-        return (h_seq, h_T), (x_tbh, mask, w_rec, h_seq)
+def _gru_core_fwd(x_tbh, mask, w_rec):
+    h_seq, h_T = _gru_pallas_raw(x_tbh, mask, w_rec)
+    return (h_seq, h_T), (x_tbh, mask, w_rec, h_seq)
 
-    def bwd(res, ct):
-        x_tbh, mask, w_rec, h_seq = res
-        dh_seq, dhT = ct
-        dx, dw = _gru_bwd_pallas(x_tbh, mask, w_rec, h_seq, dh_seq, dhT)
-        if axis is not None:
-            dw = jax.lax.psum(dw, axis)
-        return dx, None, dw
 
-    core.defvjp(fwd, bwd)
-    return core
+def _gru_core_bwd(res, ct):
+    x_tbh, mask, w_rec, h_seq = res
+    dh_seq, dhT = ct
+    dx, dw = _gru_bwd_pallas(x_tbh, mask, w_rec, h_seq, dh_seq, dhT)
+    return dx, None, dw
+
+
+_gru_core.defvjp(_gru_core_fwd, _gru_core_bwd)

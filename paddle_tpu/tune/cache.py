@@ -69,14 +69,12 @@ def device_kind() -> str:
     """Canonical device identity for table keys: jax's device_kind
     string (e.g. 'TPU v5 lite'), lowercased with spaces collapsed so the
     key survives JSON round-trips and shell quoting. 'cpu' off-TPU —
-    which is exactly why CPU test runs can never hit TPU-tuned entries."""
+    which is exactly why CPU test runs can never hit TPU-tuned entries.
+    A process with no backend at all fails here, loudly: a made-up key
+    would file measurements under a device that does not exist."""
     import jax
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # no backend at all — still a valid (empty) key
-        kind = "unknown"
-    return "-".join(str(kind).lower().split())
+    return "-".join(str(jax.devices()[0].device_kind).lower().split())
 
 
 def make_sig(params: Dict[str, Any]) -> str:

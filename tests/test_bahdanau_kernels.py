@@ -104,8 +104,9 @@ def test_fused_decoder_gradient_parity(interpret_flag, seq_bwd):
     """Both backward formulations — the reverse scan of per-step kernels
     (default) and the whole-sequence mega kernel — reproduce every
     gradient of the XLA scan. (The mega kernel ships off by default —
-    measured 0.963x, benchmarks/bahdanau_megabwd.json — but stays
-    parity-tested: vs f64 ground truth it is the MORE accurate path.)"""
+    0.963x, measured in an early round on a link that is gone; not
+    re-measured — but stays parity-tested: vs f64 ground truth it is
+    the MORE accurate path.)"""
     from paddle_tpu.ops import bahdanau_kernels as bk
 
     prev = FLAGS.fused_attention_seq_bwd

@@ -15,7 +15,6 @@ import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -56,7 +55,6 @@ def test_lstm_bench_under_dp_mesh():
     assert np.isfinite(rec["value"]) and rec["value"] > 0
 
 
-@pytest.mark.needs_shard_map
 def test_lstm_bench_mesh_at_fused_in_window_shape():
     """VERDICT r4 weak #2/#5: the mesh smoke must exercise the shapes
     the fused kernels actually engage at (H=512 is in the fused-LSTM
@@ -74,7 +72,6 @@ def test_lstm_bench_mesh_at_fused_in_window_shape():
     assert np.isfinite(rec["value"]) and rec["value"] > 0
 
 
-@pytest.mark.needs_shard_map
 def test_nmt_bench_under_dp_mesh_fused():
     """BENCH_MESH x BENCH_MODEL=nmt — the fused Bahdanau decoder under
     a dp2 mesh through bench.py's own path (tiny eligible geometry:
@@ -106,22 +103,67 @@ def test_mesh_rejects_non_dividing_batch():
     assert "does not divide" in (r.stderr + r.stdout)
 
 
-def test_dp_scaling_efficiency_floor():
-    """Fixed global batch, dp1 vs dp8 on the timeshared CPU mesh.
-    Measured curve (benchmarks/mesh_scaling.json): dp2 0.77, dp4 0.65,
-    dp8 0.44 of dp1 — the cost is 8 per-shard programs timesharing ONE
-    physical core (batch 8 vs 64 amortizes per-step overhead worse),
-    not the sharding machinery. The floor at 0.3 guards the
-    catastrophic regression class (e.g. an accidental full replication
-    would be ~8x slower, far below it), not the curve itself; the
-    preparable analogue of the reference's 4-GPU table
-    (benchmark/README.md:72-96) — real Nx needs real chips."""
-    common = {"BENCH_MODEL": "lstm", "BENCH_BATCH": "64",
-              "BENCH_HIDDEN": "256", "BENCH_SEQLEN": "16",
-              "BENCH_STEPS": "6", "BENCH_AMP": "0", "BENCH_CALIBRATE": "0"}
-    # best-of-2 per arm: single-shot wall-clock on the timeshared 1-core
-    # box flakes under transient load (the d34af46 overlap-test lesson)
-    r1 = max((_run_bench(dict(common))["value"] for _ in range(2)))
-    r8 = max((_run_bench({**common, "BENCH_MESH": "dp8"})["value"]
-              for _ in range(2)))
-    assert r8 >= 0.3 * r1, (r1, r8)
+def test_dp_scaling_efficiency_floor(monkeypatch):
+    """Fixed global batch, dp1 vs dp8. The regression class this guards
+    is an accidental full replication (every device running the whole
+    batch). On real chips that shows as a rate; a CPU mesh timeshares
+    its cores, so a wall-clock ratio there measures the box (it failed
+    on the driver's run with nothing wrong). What a CPU run CAN show is
+    where the batch lives and what the program contains, so that is what
+    is asserted: dp8 agrees with dp1 on the loss, every device holds one
+    eighth of the batch and of the ZeRO-sharded optimizer state, the
+    per-device program carries the per-shard batch and never the global
+    one, and it all-reduces gradients. Real Nx needs real chips
+    (`chip_smoke.py --four-chips`)."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as pt
+
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+
+    B, H, T = 64, 256, 16
+    for k, v in {"BENCH_HIDDEN": str(H), "BENCH_SEQLEN": str(T),
+                 "BENCH_AMP": "0"}.items():
+        monkeypatch.setenv(k, v)
+    losses = {}
+    for spec in ("dp1", "dp8"):
+        pt.reset()
+        cfg = bench._build_lstm_train(B)
+        cfg["startup"].random_seed = 5
+        exe = bench._mesh_executor(spec)
+        exe.run(cfg["startup"])
+        losses[spec] = [
+            float(exe.run(cfg["prog"], feed=cfg["feed"],
+                          fetch_list=[cfg["loss"]])[0]) for _ in range(3)]
+    np.testing.assert_allclose(losses["dp8"], losses["dp1"], rtol=1e-4)
+
+    # where things live after the dp8 steps (exe/cfg are the dp8 ones)
+    prog, scope = cfg["prog"], pt.global_scope()
+    sharded_state = 0
+    for v in prog.persistables():
+        a = scope.get(v.name)
+        assert len({s.device for s in a.addressable_shards}) == 8, v.name
+        if exe._state_sharding(prog, v.name).spec != \
+                jax.sharding.PartitionSpec():
+            sharded_state += 1
+            assert all(s.data.shape[0] == a.shape[0] // 8
+                       for s in a.addressable_shards), v.name
+    assert sharded_state > 0  # the Adam moments ride the dp axis
+    label = cfg["feed"]["label"]
+    placed = jax.device_put(label, exe._feed_sharding(label))
+    assert [s.data.shape for s in placed.addressable_shards] == \
+        [(B // 8, 1)] * 8
+
+    # what the per-device program contains
+    persist = sorted(v.name for v in prog.persistables()
+                     if scope.has(v.name))
+    fn = exe._compile(prog, cfg["feed"], [cfg["loss"].name], persist)
+    state = {n: scope.get(n) for n in persist}
+    with exe._device_context(), exe._trace_context():
+        text = fn.lower(state, cfg["feed"],
+                        jnp.uint32(0)).compile().as_text()
+    assert f"f32[{T},{B // 8},{4 * H}]" in text   # per-shard scan input
+    assert f"f32[{T},{B},{4 * H}]" not in text    # never the global batch
+    assert "all-reduce" in text

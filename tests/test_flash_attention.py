@@ -83,7 +83,6 @@ def test_eligibility_rules():
     assert _prefers_flash(big, big)
 
 
-@pytest.mark.needs_shard_map
 def test_ulysses_uses_flash_dispatch_path():
     """Ulysses routes local attention through flash_attention; on the CPU
     mesh that's the reference formulation — results must still match the

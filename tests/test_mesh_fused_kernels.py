@@ -101,7 +101,6 @@ def _train_lstm(mesh, steps=3, hidden=512, fused=False):
         FLAGS.use_fused_rnn = True
 
 
-@pytest.mark.needs_shard_map
 def test_fused_lstm_dp8_matches_single_device(fused_interpret):
     """dp8 mesh + fused LSTM kernels (in-window H=512) == single-device
     run of the SAME fused kernels, through training steps — isolates
@@ -118,7 +117,6 @@ def test_fused_lstm_dp8_matches_single_device(fused_interpret):
     np.testing.assert_allclose(par_w, ref_w, rtol=5e-3, atol=5e-3)
 
 
-@pytest.mark.needs_shard_map
 def test_fused_lstm_dp8_matches_scan_one_step(fused_interpret):
     """One step (before optimizer-state feedback compounds rounding):
     dp8 mesh + fused kernels matches the single-device XLA SCAN — the
@@ -129,7 +127,6 @@ def test_fused_lstm_dp8_matches_scan_one_step(fused_interpret):
     np.testing.assert_allclose(par_losses, ref_losses, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.needs_shard_map
 def test_fused_lstm_dp_mp_mesh(fused_interpret):
     """Same equivalence under a 2-axis (dp4, mp2) mesh — the fused
     kernels shard over dp and replicate over mp."""
@@ -184,7 +181,6 @@ def _train_nmt(mesh, steps=3, fused=False):
         FLAGS.use_fused_attention = True
 
 
-@pytest.mark.needs_shard_map
 def test_fused_decoder_dp2_matches_single_device(fused_interpret):
     """dp2 mesh + fused Bahdanau decoder == single-device fused decoder
     through training (psum'd dWx/dWh/dv/dWaDec/dbias correct), plus a
@@ -202,7 +198,6 @@ def test_fused_decoder_dp2_matches_single_device(fused_interpret):
                                rtol=5e-4, atol=5e-4)
 
 
-@pytest.mark.needs_shard_map
 def test_bench_geometry_dispatches_fused_under_mesh(fused_interpret):
     """The bench-default NMT geometry (bs256, S=T=50, H=512, C=1024,
     bf16) keeps the FUSED path under a dp4 mesh: per-shard batch 64 is
@@ -265,7 +260,6 @@ def test_fused_lstm_dp1_mesh(fused_interpret):
     assert np.isfinite(losses).all() and losses[1] < losses[0], losses
 
 
-@pytest.mark.needs_shard_map
 def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch):
     """The flash dispatcher wraps its kernel in shard_map under a dp
     mesh (kernel monkeypatched to the jnp reference — the real Mosaic
@@ -297,3 +291,55 @@ def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch):
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                rtol=1e-4, atol=1e-4)
     assert calls and calls[0][0] == 16 // 8, calls  # per-shard batch
+
+
+def _fused_weight_grads(family, rng):
+    """(loss_fn, args, weight_argnums) for one fused family at a small
+    eligible geometry (interpret-mode kernels on the CPU)."""
+    f32 = lambda *shape: jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+    T, B, H = 6, 32, 128
+    mask = jnp.asarray(
+        (np.arange(T)[:, None] < rng.randint(2, T + 1, B)[None, :])
+        .astype(np.float32))
+    if family in ("lstm", "gru"):
+        G = 4 if family == "lstm" else 3
+        fused = (pallas_kernels.lstm_fused if family == "lstm"
+                 else pallas_kernels.gru_fused)
+
+        def loss(x, w, b):
+            h_seq, _ = fused(x, mask, w, bias=b)
+            return jnp.sum(h_seq ** 2)
+
+        return loss, (f32(T, B, G * H), f32(H, G * H), f32(G * H)), (1, 2)
+    S, A, C, E = 10, 128, 128, 16
+    enc_mask = jnp.asarray(np.arange(S)[None, :]
+                           < rng.randint(3, S + 1, B)[:, None])
+
+    def loss(enc, ep, trg, h0, wa_dec, v, wx, wh, bias):
+        return jnp.sum(bk.fused_attention_decoder(
+            enc, ep, enc_mask, trg, mask, h0, wa_dec, v, wx, wh, bias) ** 2)
+
+    args = (f32(B, S, C), f32(B, S, A), f32(T, B, E), f32(B, H),
+            f32(H, A), f32(A), f32(E + C, 3 * H), f32(H, 3 * H),
+            f32(3 * H))
+    return loss, args, (4, 5, 6, 7, 8)
+
+
+@pytest.mark.parametrize("family", ["lstm", "gru", "bahdanau"])
+def test_fused_weight_gradients_under_dp_match_single_device(family):
+    """The gradient itself, not a loss after Adam: under a dp4 mesh each
+    shard's custom-VJP backward yields a partial weight cotangent and
+    shard_map's transpose sums them — exactly once. On jax 0.9.0 the
+    backwards' own psum (written for an older jax) made every fused
+    weight gradient dp times too large, and every loss-level test above
+    passed regardless, because Adam divides the scale back out (found on
+    four real chips by chip_smoke.py --four-chips, PR 21)."""
+    loss, args, wrt = _fused_weight_grads(family, np.random.RandomState(0))
+    want = jax.grad(loss, argnums=wrt)(*args)
+    mesh = pp.make_mesh((4,), ("dp",), devices=jax.devices()[:4])
+    with mesh_dispatch.active_mesh(mesh, "dp"):
+        got = jax.jit(jax.grad(loss, argnums=wrt))(*args)
+    for g, w in zip(got, want):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=0, atol=1e-4 * scale)

@@ -123,7 +123,6 @@ def test_ragged_feed_data_parallel(mesh8):
     np.testing.assert_allclose(ref, par, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.needs_shard_map
 def test_collectives_shard_map(mesh8):
     """psum / ring allreduce equivalence under shard_map."""
     x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
@@ -142,7 +141,6 @@ def test_collectives_shard_map(mesh8):
     np.testing.assert_allclose(np.asarray(out2), np.asarray(out1), rtol=1e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_reduce_scatter_allgather_roundtrip(mesh8):
     x = jnp.ones((64, 16), jnp.float32)  # per-shard [8, 16]
 

@@ -32,7 +32,6 @@ def _qkv(seed=0):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.needs_shard_map
 def test_ring_matches_oracle(mesh_sp, causal):
     q, k, v = _qkv()
     want = scaled_dot_product_attention(q, k, v, causal=causal)
@@ -41,7 +40,6 @@ def test_ring_matches_oracle(mesh_sp, causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.needs_shard_map
 def test_ring_gradients_match_oracle(mesh_sp, causal):
     q, k, v = _qkv(1)
 
@@ -66,7 +64,6 @@ def test_ring_requires_divisible_T(mesh_sp):
         ring_attention(q, q, q, mesh_sp)
 
 
-@pytest.mark.needs_shard_map
 def test_ring_under_jit_with_sharded_inputs(mesh_sp):
     """The intended deployment: inputs arrive already sharded over sp."""
     from jax.sharding import NamedSharding, PartitionSpec
@@ -82,7 +79,6 @@ def test_ring_under_jit_with_sharded_inputs(mesh_sp):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.needs_shard_map
 def test_ulysses_matches_oracle(mesh_sp, causal):
     from paddle_tpu.parallel.ring_attention import ulysses_attention
 
@@ -95,7 +91,6 @@ def test_ulysses_matches_oracle(mesh_sp, causal):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_ulysses_gradients_match_oracle(mesh_sp):
     from paddle_tpu.parallel.ring_attention import ulysses_attention
 

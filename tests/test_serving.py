@@ -148,12 +148,17 @@ def test_uniform_dispatch_sync_counters(dense_model_dir):
 
 def test_seq_len_buckets(seq_model_dir):
     """Varying [B, T] traffic lands on the (batch × seq) bucket grid;
-    padded positions are sliced away and real positions bit-match the
-    exact-shape path."""
+    padded positions are sliced away. A request whose shape IS a bucket
+    runs the same program as the exact-shape path and must bit-match it.
+    Any other request runs a DIFFERENT compiled program (the padded
+    bucket's), and XLA:CPU may order its reductions differently there
+    (observed 1.8e-7 on O(1) outputs with jaxlib 0.9.0), so those are
+    held to 4 ulp of the largest output, not to bit identity."""
     pol = BucketPolicy(max_batch_size=4, seq_len_buckets=(4, 8))
     eng = ServingEngine(seq_model_dir, policy=pol, model_name="seq")
     oracle = ServingEngine(seq_model_dir, model_name="seq_oracle")
     rng = np.random.RandomState(3)
+    exact = 0
     for _ in range(40):
         n = int(rng.randint(1, 5))
         t = int(rng.randint(2, 9))
@@ -161,7 +166,14 @@ def test_seq_len_buckets(seq_model_dir):
         got = eng.predict({"x": xv})[0]
         want = oracle.predict({"x": xv}, bucketed=False)[0]
         assert got.shape == (n, t, 5)
-        np.testing.assert_array_equal(got, want)
+        if n in pol.batch_buckets and t in pol.seq_len_buckets:
+            exact += 1
+            np.testing.assert_array_equal(got, want)
+        else:
+            atol = 4 * np.finfo(np.float32).eps * max(
+                1.0, float(np.abs(want).max()))
+            np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+    assert exact > 0  # the bit-exact arm was exercised
     assert eng.compiled_programs() <= pol.max_programs(), eng.stats()
 
 

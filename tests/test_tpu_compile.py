@@ -1,0 +1,206 @@
+"""Main-path Pallas kernels compile for a described TPU v5e, at real widths.
+
+No chip is attached under test: the TPU compiler that ships with the
+install compiles for a topology that is only described
+(`topologies.get_topology_desc`). This catches what interpret mode cannot
+— a block the Mosaic tiling refuses, a kernel past its VMEM budget — at
+no chip time. It says nothing about results or speed.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture that skips when it cannot be,
+never at import and never in a parametrize argument; every compile
+happens in the test's own process; all such tests live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
+T, B = 100, 128  # bench LSTM sequence length / batch
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    """What the chip would decide: interpret mode off, fused dispatch
+    eligible (`jax.default_backend()` still says cpu during these
+    compiles), and the matmul precision the chip process runs with — the
+    default, not conftest's CPU-oracle "highest", which Mosaic refuses
+    on bf16 operands. The persistent compile cache is off around them:
+    an entry written for a described chip cannot be read back without
+    one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from paddle_tpu.ops import pallas_kernels, quant_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_kernels, "_backend_ok", lambda: True)
+    monkeypatch.setattr(quant_kernels, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with jax.default_matmul_precision("default"):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# ---- case builders: each returns (fn, [(shape, dtype), ...]) ------------
+def _lstm_fwd(H, B=B):
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    return pk._lstm_pallas_raw, [
+        ((T, B, 4 * H), BF16), ((T, B), F32), ((H, 4 * H), BF16)]
+
+
+def _lstm_bwd(hb):
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    H, B = hb
+    seq, last = ((T, B, H), BF16), ((B, H), BF16)
+    return pk._lstm_bwd_pallas, [
+        ((T, B, 4 * H), BF16), ((T, B), F32), ((H, 4 * H), BF16),
+        seq, seq, seq, last, last]
+
+
+def _largest_gru_h():
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    return max(h for h in range(128, 4096 + 1, 128)
+               if pk.gru_supported(B, h, "sigmoid", "tanh", itemsize=2))
+
+
+def _gru_fwd(H):
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    H = H or _largest_gru_h()
+    return pk._gru_pallas_raw, [
+        ((T, B, 3 * H), BF16), ((T, B), F32), ((H, 3 * H), BF16)]
+
+
+def _gru_bwd(H):
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    H = H or _largest_gru_h()
+    seq = ((T, B, H), BF16)
+    return pk._gru_bwd_pallas, [
+        ((T, B, 3 * H), BF16), ((T, B), F32), ((H, 3 * H), BF16),
+        seq, seq, ((B, H), BF16)]
+
+
+# NMT bench decoder attention: batch 128, source length 50 (padded by the
+# kernels' own rule), attention width 512, bidirectional context 1024
+_ATT = dict(B=128, S=50, A=512, C=1024, T=50)
+
+
+def _bahdanau(which):
+    from paddle_tpu.ops import bahdanau_kernels as bk
+
+    b, a, c, t = _ATT["B"], _ATT["A"], _ATT["C"], _ATT["T"]
+    sp = bk._pad_s(_ATT["S"])
+    ep, enc = ((b, sp, a), BF16), ((b, sp, c), BF16)
+    dp, v, maskf = ((b, a), BF16), ((a,), BF16), ((b, sp), F32)
+    if which == "fwd":
+        return (lambda *xs: bk._attn_fwd(*xs, interpret=False),
+                [ep, enc, dp, v, maskf])
+    if which == "bwd_step":
+        return (lambda *xs: bk._attn_bwd_step(*xs, interpret=False),
+                [ep, enc, dp, v, maskf, ((b, c), BF16), ((b, sp), F32)])
+    return (lambda ep_, dps, dscs, v_: bk._attn_phase2(
+                ep_, dps, dscs, v_, c, interpret=False),
+            [ep, ((t, b, a), BF16), ((t, b, sp), F32), v])
+
+
+def _flash(with_bwd):
+    from paddle_tpu.ops import flash_ops
+
+    # transformer_lm bench default: batch 8, T 1024, 12 heads x 64, causal
+    qkv = [((8, 1024, 12, 64), BF16)] * 3
+
+    def fwd(q, k, v):
+        return flash_ops._flash_kernel(q, k, v, causal=True)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(), (0, 1, 2))(
+            q, k, v)
+
+    return (fwd_bwd if with_bwd else fwd), qkv
+
+
+def _quant(mkn):
+    from paddle_tpu.ops import quant_kernels as qk
+    from paddle_tpu.tune import space
+
+    m, k, n = mkn
+    cfg = space.quant_matmul_default(dict(M=m, K=k, N=n, dtype="int8"))
+    assert cfg is not None, f"no legal int8 tile for {mkn}"
+    return (lambda x, w: qk._quant_matmul_pallas(
+                x, w, int(cfg["block_m"]), int(cfg["block_n"])),
+            [((m, k), I8), ((k, n), I8)])
+
+
+CASES = [
+    ("lstm_fwd_h512", _lstm_fwd, 512),
+    ("lstm_bwd_h512", _lstm_bwd, (512, 128)),
+    ("lstm_fwd_h1280", _lstm_fwd, 1280),
+    ("lstm_bwd_h1280", _lstm_bwd, (1280, 128)),
+    # past the compiler's 16M default scoped-VMEM cap (the kernels raise
+    # it): batch 256 is a cell of the reference's published LSTM grid,
+    # 512 is chip_smoke's one-chip reference for dp4, 384 the largest
+    # need (21.9M) among the configs lstm_supported admits
+    ("lstm_bwd_h512_b256", _lstm_bwd, (512, 256)),
+    ("lstm_bwd_h512_b384", _lstm_bwd, (512, 384)),
+    ("lstm_bwd_h512_b512", _lstm_bwd, (512, 512)),
+    ("gru_fwd_h512", _gru_fwd, 512),
+    ("gru_bwd_h512", _gru_bwd, 512),
+    ("gru_fwd_largest_h", _gru_fwd, None),
+    ("gru_bwd_largest_h", _gru_bwd, None),
+    ("bahdanau_fwd", _bahdanau, "fwd"),
+    ("bahdanau_bwd_step", _bahdanau, "bwd_step"),
+    ("bahdanau_phase2", _bahdanau, "phase2"),
+    ("flash_fwd_t1024", _flash, False),
+    ("flash_fwd_bwd_t1024", _flash, True),
+    # ResNet-50 head at a full serving bucket, and the small probe shape
+    ("quant_matmul_64x2048x1000", _quant, (64, 2048, 1000)),
+    ("quant_matmul_8x512x512", _quant, (8, 512, 512)),
+    # the served int8 MLP's three sites (bench serving_quant: batch 8,
+    # 512 -> 1024 -> 1024 -> 128)
+    ("quant_matmul_mlp_fc1", _quant, (8, 512, 1024)),
+    ("quant_matmul_mlp_fc2", _quant, (8, 1024, 1024)),
+    ("quant_matmul_mlp_fc3", _quant, (8, 1024, 128)),
+]
+
+
+@pytest.mark.parametrize("build,arg", [c[1:] for c in CASES],
+                         ids=[c[0] for c in CASES])
+def test_kernel_compiles_for_v5e(one_chip, compiled_mode, build, arg):
+    fn, specs = build(arg)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_largest_gru_h_is_the_published_maximum(compiled_mode):
+    """The `largest_h` cases above must be compiling the reference's
+    largest published hidden size, not a window that quietly shrank."""
+    assert _largest_gru_h() == 1280
