@@ -1,0 +1,320 @@
+"""Driver `train`: `Trainer.train` over the configuration's endless seeded
+reader for the cell's window, the way `paddle_tpu train` runs it
+(`cli._cmd_train`: default programs, `Trainer(cost, executor=...)`,
+`train(reader, feed_order=..., event_handler=..., log_interval=...)`).
+
+Everything the harness learns comes from outside the program: the
+trainer's events (`BeginIteration` / `EndIteration`), its exact counters
+(`host_dispatch_count`, `host_sync_count`), its `profiler.StatSet` timers
+and, in a traced run, the profiler's trace. The window:
+
+    warm-up steps (compile or cache read, first syncs)   -> set-up
+    fenced cost read; [start_trace]; t0                   -> window opens
+    every `sync_every` steps a fenced cost read: one interval
+    first read at or past the deadline: t1; trainer.stop() -> window closes
+
+so the window starts after a fence and ends in a fence on the last
+step's cost, and `items_s` is all its steps over all its time.
+"""
+
+from __future__ import annotations
+
+import math
+import time
+
+
+def _executor(cell):
+    if not cell.get("mesh"):
+        return None
+    from paddle_tpu.parallel import ParallelExecutor
+    from paddle_tpu.parallel.mesh import mesh_from_spec
+
+    return ParallelExecutor(mesh_from_spec(cell["mesh"]))
+
+
+class _Window:
+    """The event handler. One instance, one run."""
+
+    def __init__(self, ctx, trainer, sync_every, warmup, seconds, reference):
+        self.ctx, self.trainer = ctx, trainer
+        self.reference = reference    # the plain reference's first step
+        self.grad_errors = None
+        self.sync_every, self.warmup, self.seconds = sync_every, warmup, seconds
+        self.steps = 0            # EndIterations seen since the start
+        self.first_cost = None    # the first read after initialisation
+        self.t0 = self.t1 = None
+        self.t0_wall = None
+        self.last_read = None
+        self.intervals = []       # seconds per step, one per fenced read
+        self.costs = []           # the fenced reads inside the window
+        self.bad_intervals = 0
+        self.feed_wait_s = 0.0    # EndIteration -> next BeginIteration
+        self._last_end = None
+        self._gap_ann = self._step_ann = None
+        self.at_open = self.at_close = None
+
+    # -- profiler annotations (host spans on the trace's own clock) -------
+    def _ann(self, name):
+        if not self.ctx.trace:
+            return None
+        import jax
+
+        a = jax.profiler.TraceAnnotation(name)
+        a.__enter__()
+        return a
+
+    @staticmethod
+    def _close(a):
+        if a is not None:
+            a.__exit__(None, None, None)
+
+    def _snapshot(self):
+        from paddle_tpu import profiler
+
+        t = self.trainer
+        stats = profiler.global_stat_set().as_dict()
+        return {"dispatches": t.host_dispatch_count, "syncs": t.host_sync_count,
+                "programs_built": self.ctx.clock.built,
+                "cache_misses": self.ctx.clock.misses,
+                "timers": {k: v["total"] for k, v in stats.items()}}
+
+    def _read(self, event, name="chipbench.cost_read"):
+        a = self._ann(name)
+        c = float(event.cost)     # the fence: blocks until this step is done
+        now = time.perf_counter()
+        self._close(a)
+        return c, now
+
+    def __call__(self, event):
+        from paddle_tpu.trainer import BeginIteration, EndIteration
+
+        if isinstance(event, BeginIteration):
+            self._close(self._gap_ann)
+            self._gap_ann = None
+            if self.t0 is not None and self._last_end is not None:
+                self.feed_wait_s += time.perf_counter() - self._last_end
+            self._step_ann = self._ann("chipbench.prepare_and_dispatch")
+            return
+        if not isinstance(event, EndIteration):
+            return
+        self._close(self._step_ann)
+        self._step_ann = None
+        self.steps += 1
+        if self.t0 is None:
+            self._warmup_step(event)
+        else:
+            self._window_step(event)
+        if self.t1 is None:
+            self._last_end = time.perf_counter()
+            if self.t0 is not None:
+                self._gap_ann = self._ann("chipbench.wait_for_batch")
+
+    def _warmup_step(self, event):
+        if self.steps == 1:
+            self.first_cost, _ = self._read(event, "chipbench.warmup_read")
+            self.grad_errors = _gradient_errors(
+                self.trainer, self.reference.pop("grads"))
+        if self.steps < self.warmup:
+            return
+        self._read(event, "chipbench.warmup_read")   # fence before the window
+        if self.ctx.trace:
+            self.ctx.start_trace()
+        self.at_open = self._snapshot()
+        self.t0_wall = time.time()
+        self.t0 = self.last_read = time.perf_counter()
+
+    def _window_step(self, event):
+        n = self.steps - self.warmup
+        if n % self.sync_every:
+            return
+        cost, now = self._read(event)
+        self.intervals.append((now - self.last_read) / self.sync_every)
+        self.last_read = now
+        self.costs.append(cost)
+        if not math.isfinite(cost):
+            self.bad_intervals += 1
+        if now - self.t0 >= self.seconds:
+            self.t1 = now
+            self.at_close = self._snapshot()
+            if self.ctx.trace:
+                self.ctx.stop_trace()
+            self.trainer.stop()
+
+
+# The plain reference (`configs/<config>/reference.py`: float32
+# `jax.numpy`, matmuls at the highest precision) is held to the system's
+# first step on the same weights and the same batch, in set-up:
+#  - the first cost: |difference| over max(1, |reference|). On the chip
+#    (PR 23, 29 runs, 6 seeds) at most 2.9e-6 for gpt2-small; the bound is
+#    seven times that. Weak alone: with fresh weights the cost sits within
+#    2e-3 of ln(vocabulary).
+#  - every parameter's gradient. Adam's first moment after the first step
+#    is (1 - beta1) x the gradient the step computed, so the gradients are
+#    read from the optimizer's state with no second program: per tensor,
+#    over a strided sample of at most GRAD_SAMPLE elements, rms(system -
+#    reference) over rms(reference), where a tensor whose reference
+#    gradient is (near) zero, such as a key bias, is held to a tenth of
+#    the median tensor's rms instead. A wrong backward pass or a wrong
+#    gradient hand-over to the optimizer reads near 1; bf16 AMP against
+#    float32 read at most 0.013, the median tensor 0.006 (gpt2-small on
+#    the chip, PR 23, 29 runs, 6 seeds); the bound is four times that.
+# The tiny models of the CPU rehearsal are looser in both: their cell's
+# `rehearsal` block carries its own tolerances, read only in a rehearsal.
+REFERENCE_TOL = 2e-5
+GRAD_TOL = 0.05
+GRAD_SAMPLE = 65536
+
+
+def _sample(x):
+    flat = x.reshape(-1)
+    return flat[::-(-flat.size // GRAD_SAMPLE)]
+
+
+def _reference(ctx, trainer, model):
+    """The plain reference on the first batch and the freshly made weights,
+    computed before the first step (set-up time): its cost, and a sample of
+    each parameter's gradient, left on the device until the first step has
+    run."""
+    import os
+
+    import jax
+
+    ref = ctx.load_module(
+        os.path.join(os.path.dirname(ctx.model.__file__), "reference.py"))
+    params = [trainer.scope.get(p.name)
+              for p in trainer.main_program.parameters()]
+    first = ref.prepare(next(iter(model["reader"]())))
+
+    def cost_and_sampled_grads(params, first):
+        cost, grads = ref.loss_and_grads(ctx.config, params, first)
+        return cost, [_sample(g) for g in grads]
+
+    cost, grads = jax.jit(cost_and_sampled_grads)(params, first)
+    return {"cost": float(cost), "grads": grads}
+
+
+def _gradient_errors(trainer, ref_grads):
+    """Per parameter, how far the gradient of the step that has just run
+    is from the reference's (see above). Parameters that no `adam` op
+    updates are not compared."""
+    import jax
+    import jax.numpy as jnp
+
+    moment = {}
+    for block in trainer.main_program.blocks:
+        for op in block.ops:
+            if op.type == "adam":
+                moment[op.inputs["Param"][0]] = (
+                    op.inputs["Moment1"][0], 1.0 - op.attrs.get("beta1", 0.9))
+    names, moments, scales, refs = [], [], [], []
+    for p, g in zip(trainer.main_program.parameters(), ref_grads):
+        if p.name in moment:
+            names.append(p.name)
+            moments.append(trainer.scope.get(moment[p.name][0]))
+            scales.append(moment[p.name][1])
+            refs.append(g)
+    if not names:
+        return None
+
+    def rms(x):
+        return jnp.sqrt(jnp.mean(jnp.square(x)))
+
+    def errors(moments, refs):
+        ref_rms = jnp.stack([rms(g) for g in refs])
+        diff = jnp.stack([rms(_sample(m).astype(jnp.float32) / s - g)
+                          for m, s, g in zip(moments, scales, refs)])
+        return diff / jnp.maximum(ref_rms, 0.1 * jnp.median(ref_rms))
+
+    errs = [float(e) for e in jax.jit(errors)(moments, refs)]
+    return dict(zip(names, errs))
+
+
+def run(ctx):
+    """Returns the run record the metric readers take their numbers from."""
+    from paddle_tpu import profiler
+    from paddle_tpu.flags import FLAGS
+    from paddle_tpu.trainer import Trainer
+
+    cell = ctx.cell
+    sync_every, warmup = int(cell["sync_every"]), int(cell["warmup_steps"])
+    if warmup < 2 * sync_every or warmup % sync_every:
+        raise SystemExit(
+            f"chipbench: warmup_steps {warmup} must be a multiple of "
+            f"sync_every {sync_every} and at least twice it (the first "
+            f"sync compiles)")
+    if ctx.trace:
+        # host timers on: prepareBatchData is the in-loop feed time
+        FLAGS.enable_timers = True
+    model = ctx.model.get_model(ctx.config, cell, ctx.seed)
+    trainer = Trainer(cost=model["cost"], executor=_executor(cell))
+    trainer.init()    # startup: the weights, on the device, from the seed
+    reference = _reference(ctx, trainer, model)
+    profiler.global_stat_set().reset()
+    seconds = min(ctx.seconds, float(cell["trace_seconds"])) if ctx.trace \
+        else ctx.seconds
+    win = _Window(ctx, trainer, sync_every, warmup, seconds, reference)
+    trainer.train(model["reader"], num_passes=1,
+                  feed_order=model["feed_order"], event_handler=win,
+                  log_interval=sync_every)
+    if win.t1 is None:
+        raise SystemExit("chipbench: the trainer returned before the window "
+                         "closed")
+    steps = win.steps - warmup
+    delta = {k: win.at_close[k] - win.at_open[k]
+             for k in ("dispatches", "syncs", "programs_built", "cache_misses")}
+    timers = {k: v - win.at_open["timers"].get(k, 0.0)
+              for k, v in win.at_close["timers"].items()}
+    return {
+        "steps": steps, "items": steps * model["items_per_step"],
+        "window_s": win.t1 - win.t0, "t0_wall": win.t0_wall,
+        "intervals_s": win.intervals, "costs": win.costs,
+        "first_cost": win.first_cost, "bad_intervals": win.bad_intervals,
+        "reference_first_cost": reference["cost"],
+        "gradient_errors": win.grad_errors,
+        "tolerances": dict(
+            {"reference_tol": REFERENCE_TOL, "grad_tol": GRAD_TOL},
+            **(cell["rehearsal"].get("tolerances", {})
+               if ctx.rehearsal else {})),
+        "feed_wait_s": win.feed_wait_s,
+        "counters": delta, "timers_s": timers,
+        "attempted": steps, "failed": win.bad_intervals * sync_every,
+    }
+
+
+def info(run):
+    """What the run's `info` line says of the window."""
+    errs = run["gradient_errors"]
+    worst = errs and max(errs, key=errs.get)
+    return {"steps": run["steps"], "window_s": run["window_s"],
+            "intervals": len(run["intervals_s"]),
+            "counters_in_window": run["counters"],
+            "first_cost": run["first_cost"], "last_cost": run["costs"][-1],
+            "reference_first_cost": run["reference_first_cost"],
+            "gradient_error_worst": worst and [worst, errs[worst]],
+            "gradient_error_median": errs and sorted(errs.values())[len(errs) // 2]}
+
+
+def correct(run):
+    """What a train cell owes: finite costs, a loss that fell, a first step
+    that agrees with the plain reference, and nothing built inside the
+    window. Returns a list of what failed (empty = ok)."""
+    bad = []
+    costs = run["costs"]
+    if not costs or run["bad_intervals"] or not math.isfinite(run["first_cost"]):
+        bad.append("a cost read was not finite")
+    elif not costs[-1] < run["first_cost"]:
+        bad.append(f"the loss did not fall: first {run['first_cost']}, "
+                   f"last {costs[-1]}")
+    want = run["reference_first_cost"]
+    tol = run["tolerances"]
+    off = abs(run["first_cost"] - want) / max(1.0, abs(want))
+    if not off <= tol["reference_tol"]:
+        bad.append(f"the first cost {run['first_cost']} is off the plain "
+                   f"reference's {want} by {off} (> {tol['reference_tol']})")
+    for name, err in (run["gradient_errors"] or {}).items():
+        if not err <= tol["grad_tol"]:
+            bad.append(f"the first step's gradient of {name} is off the plain "
+                       f"reference's by {err} of its rms (> {tol['grad_tol']})")
+    if run["counters"]["programs_built"] or run["counters"]["cache_misses"]:
+        bad.append(f"programs were built inside the window: {run['counters']}")
+    return bad

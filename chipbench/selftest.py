@@ -1,0 +1,343 @@
+#!/usr/bin/env python3
+"""chipbench's own checks; no chip, no JAX backend. Run by hand:
+
+    python3 chipbench/selftest.py
+
+1. the trace reduction (`xplane.reduce`) on made-up traces (overlapping and
+   nested events, the idle share, the custom-call share, collectives, the
+   division by steps, the idle gaps' labels) and on a small trace recorded
+   on a TPU v5e in PR 23 (`testdata/gpt2_two_steps.trace.json.gz`: two
+   steps of gpt2-small.train cut from a traced run's `xplane.load`);
+2. the percentile rule (refuses fewer than 100 intervals);
+3. the manifest: BENCHMARK.json's names, units and limits, and that every
+   cell resolves to a workload file, a configuration, a driver, and a
+   reader for each of its metrics.
+"""
+
+from __future__ import annotations
+
+import gzip
+import importlib.util
+import json
+import os
+import re
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, ROOT)
+
+
+def _load(path):
+    spec = importlib.util.spec_from_file_location(
+        "st_" + re.sub(r"\W", "_", os.path.basename(path)), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+xplane = _load(os.path.join(HERE, "xplane.py"))
+from chipbench import stats  # noqa: E402 — the module the readers import
+
+CHECKS = []
+
+
+def check(fn):
+    CHECKS.append(fn)
+    return fn
+
+
+def _trace(ops, host=(), extra_planes=()):
+    planes = [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": [list(e) for e in ops]},
+        {"name": "XLA Modules", "events": [["jit_step", 0, 10**9]]}]}]
+    planes += list(extra_planes)
+    planes.append({"name": "/host:CPU", "lines": [
+        {"name": "python3", "events": [list(e) for e in host]}]})
+    return {"planes": planes}
+
+
+PALLAS = ('%flash.1 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %p), '
+          'custom_call_target="tpu_custom_call"')
+CONCAT = ('%custom-call.67 = f32[128,2048]{1,0} custom-call(f32[32,2048]{1,0} '
+          '%s), custom_call_target="ConcatBitcast"')
+FUSION = ('%fusion.10 = bf16[128000,2048]{1,0:T(8,128)(2,1)} fusion(s32[128000]'
+          '{0:T(1024)S(1)} %gte.1), kind=kCustom, calls=%fused_computation.261')
+WHILE = ('%while.11 = (s32[]{:T(128)}, bf16[512,2048]{1,0:T(8,128)(2,1)S(1)}) '
+         'while((s32[]{:T(128)}, bf16[512,2048]{1,0}) %tuple.3), '
+         'condition=%cond, body=%body')
+ALLREDUCE = ('%all-reduce.3 = f32[512,2048]{1,0} all-reduce(f32[512,2048]{1,0} '
+             '%x), replica_groups={{0,1,2,3}}, to_apply=%add')
+ARSTART = ('%all-reduce-start.1 = f32[8]{0} all-reduce-start(f32[8]{0} %y), '
+           'replica_groups={{0,1,2,3}}, to_apply=%add')
+
+
+@check
+def opcodes_from_instruction_text():
+    assert xplane.opcode(FUSION) == "fusion"
+    assert xplane.opcode(WHILE) == "while"          # tuple-shaped result
+    assert xplane.opcode(PALLAS) == "custom-call"
+    assert xplane.opcode(ALLREDUCE) == "all-reduce"
+    assert xplane.opcode("fusion.12") == "fusion"    # a bare name
+    assert xplane.is_custom_call(PALLAS) and not xplane.is_custom_call(CONCAT)
+    assert xplane.is_collective(ALLREDUCE) and xplane.is_collective(ARSTART)
+    assert xplane.is_container(WHILE) and not xplane.is_container(FUSION)
+    assert xplane.short(FUSION) == "%fusion.10 fusion bf16[128000,2048]"
+
+
+@check
+def busy_is_a_union_not_a_sum():
+    # window 0..1000 by the annotations. Ops: 100..300, an overlapping
+    # 200..400, one nested inside (250..260); then 600..700. A while
+    # covering 0..900 must not count: only its body's ops are busy time
+    host = [("chipbench.prepare_and_dispatch", 0, 400),
+            ("chipbench.cost_read", 400, 600)]
+    ops = [(WHILE, 0, 900), (FUSION, 100, 200), (PALLAS, 200, 200),
+           (CONCAT, 250, 10), (ALLREDUCE, 600, 100)]
+    r = xplane.reduce(_trace(ops, host), chips=1)
+    p = r["planes"][0]
+    assert r["window_s"] == 1000 / 1e9, r["window_s"]
+    assert p["busy_ns"] == 300 + 100, p            # 100..400 and 600..700
+    assert p["custom_call_ns"] == 200, p           # the Pallas call only
+    assert p["collective_ns"] == 100, p
+    assert r["busy_s_mean"] == 400 / 1e9
+    # idle: 0..100 under prepare_and_dispatch, 400..600 and 700..1000
+    # under cost_read
+    gaps = dict(r["breakdown"]["idle_gaps"])
+    assert abs(gaps["prepare_and_dispatch"] - 100e-9) < 1e-15, gaps
+    assert abs(gaps["cost_read"] - 500e-9) < 1e-15, gaps
+    top = dict(r["breakdown"]["device_ops"])
+    assert abs(top["%while.11 while s32[] (body included)"] - 900e-9) < 1e-15
+    assert len(r["breakdown"]["device_ops"]) <= 10
+
+
+@check
+def events_are_clipped_to_the_window():
+    host = [("chipbench.wait_for_batch", 1000, 1000)]   # window 1000..2000
+    ops = [(FUSION, 500, 700), (FUSION, 1900, 500)]     # 500..1200, 1900..2400
+    p = xplane.reduce(_trace(ops, host))["planes"][0]
+    assert p["busy_ns"] == 200 + 100, p
+
+
+@check
+def innermost_host_event_labels_a_gap():
+    host = [("chipbench.prepare_and_dispatch", 0, 1000),
+            ("PjitFunction(raw)", 100, 700),
+            ("chipbench.cost_read", 1000, 100)]
+    ops = [(FUSION, 800, 300)]
+    gaps = dict(xplane.reduce(_trace(ops, host))["breakdown"]["idle_gaps"])
+    assert abs(gaps["PjitFunction(raw)"] - 800e-9) < 1e-15, gaps
+
+
+@check
+def per_layer_readers_divide_by_steps():
+    host = [("chipbench.cost_read", 0, 10**9)]
+    ops = [(FUSION, 0, 4 * 10**8), (PALLAS, 5 * 10**8, 10**8),
+           (ARSTART, 7 * 10**8, 10**8)]
+    second = {"name": "/device:TPU:1", "lines": [
+        {"name": "XLA Ops", "events": [[FUSION, 0, 2 * 10**8]]}]}
+    run = {"steps": 4, "trace": xplane.reduce(
+        _trace(ops, host, [second]), chips=2)}
+
+    def metric(name):
+        return _load(os.path.join(HERE, "layer_metrics", name + ".py")
+                     ).compute(run)
+    assert abs(metric("step.device_ms") - 600.0 / 4) < 1e-9
+    assert abs(metric("kernel.pallas_share_pct") - 100.0 / 6) < 1e-9
+    assert run["trace"]["planes"][0]["collective_ns"] == 10**8
+    assert abs(metric("device.idle_pct") - 80.0) < 1e-9   # the idler chip
+    assert abs(run["trace"]["busy_s_mean"] - 0.4) < 1e-12
+    assert metric("step.device_ms") is not None
+    assert _load(os.path.join(HERE, "layer_metrics", "step.device_ms.py")
+                 ).compute({"steps": 4}) is None          # nothing to read
+
+
+@check
+def recorded_trace_reduces_to_the_recorded_numbers():
+    path = os.path.join(HERE, "testdata", "gpt2_two_steps.trace.json.gz")
+    with gzip.open(path) as f:
+        trace = json.load(f)
+    r = xplane.reduce(trace, chips=1)
+    p = r["planes"][0]
+    # as reduced when the trace was cut (PR 23, TPU v5 lite)
+    assert p["op_events"] == 13434, p      # inside the window
+    assert p["busy_ns"] == 383193873, p
+    assert p["custom_call_ns"] == 80673222, p
+    assert p["collective_ns"] == 0, p
+    assert abs(r["window_s"] - 0.498756179) < 1e-12, r["window_s"]
+    # 48 flash kernels a step (forward twice, dq, dkv for 12 layers)
+    names = [ev[0] for pl in trace["planes"] for ln in pl["lines"]
+             if ln["name"] == "XLA Ops" for ev in ln["events"]]
+    assert sum(map(xplane.is_custom_call, names)) == 2 * 48, \
+        sum(map(xplane.is_custom_call, names))
+    assert dict(r["breakdown"]["idle_gaps"]).keys() >= {"prepare_and_dispatch"}
+
+
+@check
+def a_trace_without_a_device_is_an_error():
+    try:
+        xplane.reduce({"planes": [{"name": "/host:CPU", "lines": []}]})
+    except ValueError:
+        return
+    raise AssertionError("reduced a trace with no device plane")
+
+
+@check
+def percentile_rule():
+    vals = [float(i) for i in range(1, 101)]
+    assert stats.percentile(vals, 90) == 90.0
+    assert stats.percentile(vals[::-1], 90) == 90.0
+    assert stats.percentile(vals + [1000.0] * 20, 90) == 1000.0  # no trimming
+    try:
+        stats.percentile(vals[:99], 90)
+    except stats.TooFewSamples:
+        pass
+    else:
+        raise AssertionError("a percentile over 99 samples")
+    reader = _load(os.path.join(HERE, "end_to_end", "step_ms_p90.py"))
+    assert reader.compute({"intervals_s": [v / 1e3 for v in vals]}) == 90.0
+    try:
+        reader.compute({"intervals_s": [0.1] * 50})
+    except stats.TooFewSamples:
+        pass
+    else:
+        raise AssertionError("step_ms_p90 over 50 intervals")
+
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+
+def _line(text, where):
+    assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text, \
+        (where, len(text))
+
+
+@check
+def manifest_keeps_the_contract():
+    path = os.path.join(ROOT, "BENCHMARK.json")
+    assert os.path.getsize(path) <= 64 * 1024
+    with open(path) as f:
+        m = json.load(f)
+    assert set(m) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}, set(m)
+    assert 1 <= len(m["command"]) <= 32 and 1 <= len(m["paths"]) <= 16
+    for w in m["command"]:
+        _line(w, "command")
+        assert not w.startswith("/") and ".." not in w.split("/"), w
+    for p in m["paths"]:
+        assert PATH.match(p) and os.path.isdir(os.path.join(ROOT, p)), p
+    assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
+    assert 1 <= len(m["configs"]) <= 24 and 1 <= len(m["workloads"]) <= 24
+    assert 1 <= len(m["end_to_end"]) <= 16 and 1 <= len(m["per_layer"]) <= 128
+    names = set()
+    for c in m["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}, c
+        assert NAME.match(c["name"]) and c["name"] not in names, c["name"]
+        names.add(c["name"])
+        _line(c["why"], c["name"])
+        _line(c["source"], c["name"])
+        assert len(c["reduced"]) <= 16 and all(map(NAME.match, c["reduced"]))
+        assert any(c["file"].startswith(p + "/") for p in m["paths"]), c["file"]
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert isinstance(cfg, dict) and cfg["reduced"] == c["reduced"]
+        assert cfg["source"] == c["source"], c["name"]
+        assert os.path.exists(os.path.join(HERE, "drivers", cfg["driver"] + ".py"))
+        assert os.path.exists(os.path.join(
+            os.path.dirname(os.path.join(ROOT, c["file"])), "model.py"))
+    assert len({c["file"] for c in m["configs"]}) == len(m["configs"])
+    cells, pairs, used = set(), set(), set()
+    for w in m["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}, w
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"]), w
+        assert w["name"] not in cells and w["config"] in names, w
+        assert (w["config"], w["traffic"]) not in pairs and w["chips"] in (1, 4)
+        cells.add(w["name"])
+        pairs.add((w["config"], w["traffic"]))
+        used.add(w["config"])
+        _line(w["why"], w["name"])
+        with open(os.path.join(HERE, "workloads", w["name"] + ".json")) as f:
+            cell = json.load(f)
+        for k in ("config", "traffic", "chips"):
+            assert cell[k] == w[k], (w["name"], k)
+        assert cell["warmup_steps"] % cell["sync_every"] == 0, w["name"]
+        assert cell["warmup_steps"] >= 2 * cell["sync_every"], w["name"]
+    assert used == names, "a configuration no cell uses"
+    four = sum(w["chips"] == 4 for w in m["workloads"])
+    assert four <= max(1, len(m["workloads"]) // 4), four
+    metrics, e2e = set(), set()
+    for sec, folder, keys in (
+            ("end_to_end", "end_to_end",
+             {"name", "unit", "better", "bound", "source"}),
+            ("per_layer", "layer_metrics",
+             {"name", "unit", "better", "source", "layer", "moves"})):
+        for x in m[sec]:
+            assert set(x) - {"workloads"} == keys, x
+            assert NAME.match(x["name"]) and x["name"] not in metrics, x
+            metrics.add(x["name"])
+            assert UNIT.match(x["unit"]) and x["better"] in ("lower", "higher")
+            assert x["source"] in SOURCES, x
+            assert set(x.get("workloads", [])) <= cells, x
+            assert os.path.exists(os.path.join(HERE, folder, x["name"] + ".py")), \
+                f"no reader for {x['name']}"
+            if sec == "end_to_end":
+                e2e.add(x["name"])
+                assert x["source"] in ("host_clock", "device_trace"), x
+                assert 0.01 <= x["bound"] <= 0.1, x
+            else:
+                _line(x["layer"], x["name"])
+                assert x["moves"] in e2e and x["moves"] != "setup_s", x
+                mover = next(e for e in m["end_to_end"] if e["name"] == x["moves"])
+                assert set(x.get("workloads", cells)) <= set(
+                    mover.get("workloads", cells)), x
+    assert "setup_s" in e2e
+    checks = 2 + 14 * 24
+    assert checks * (m["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+@check
+def every_reader_has_a_manifest_entry():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        m = json.load(f)
+    for sec, folder in (("end_to_end", "end_to_end"),
+                        ("per_layer", "layer_metrics")):
+        have = {f[:-3] for f in os.listdir(os.path.join(HERE, folder))
+                if f.endswith(".py")}
+        assert have == {x["name"] for x in m[sec]}, (folder, have)
+
+
+@check
+def flops_arithmetic():
+    flops = _load(os.path.join(HERE, "flops.py"))
+    with open(os.path.join(HERE, "configs", "gpt2-small", "config.json")) as f:
+        g = json.load(f)
+    r = {"flops_family": "lstm2", "hidden_size": 512, "embedding_size": 128}
+    assert flops.train_flops_per_item(g, {"seqlen": 1024}) == 797815296.0
+    assert flops.train_flops_per_item(r, {"seqlen": 100}) == 20447232.0
+    assert flops.peak_flops("TPU v5 lite") == 197e12
+    try:
+        flops.peak_flops("TPU v9")
+    except SystemExit:
+        pass
+    else:
+        raise AssertionError("a peak for an unknown device")
+
+
+def main() -> int:
+    failed = 0
+    for fn in CHECKS:
+        try:
+            fn()
+            print(f"ok   {fn.__name__}")
+        except Exception as e:  # noqa: BLE001 — report every check
+            failed += 1
+            print(f"FAIL {fn.__name__}: {type(e).__name__}: {e}")
+    print(f"{len(CHECKS) - failed} of {len(CHECKS)} checks passed")
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
