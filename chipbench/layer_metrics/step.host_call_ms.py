@@ -1,0 +1,15 @@
+"""step.host_call_ms (layer: Executor step). Host time per step inside
+`executor.call`: the jitted call `fn(state, feed, seed)` as Python sees it,
+and anything that blocks the host inside it (a full allocator). Read from
+the program's own spans: their `profiler.StatSet` totals over the traced
+window (`run["timers_s"]`), over the window's steps. Nothing to read where
+the program records none of them."""
+
+SPANS = ("executor.call",)
+
+
+def compute(run):
+    timers = run.get("timers_s") or {}
+    if not any(s in timers for s in SPANS):
+        return None
+    return 1e3 * sum(timers.get(s, 0.0) for s in SPANS) / run["steps"]

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import registry
+from .. import profiler
 from ..flags import FLAGS
 from .lod import LoDArray
 from .place import Place, default_place
@@ -149,6 +150,13 @@ def _feed_signature(feed: Dict[str, Any]):
     return tuple(sig)
 
 
+def _op_scope(op) -> str:
+    """`<op type>.<first output variable>`: what an op's kernels are
+    called in a profile."""
+    first = next((n for names in op.outputs.values() for n in names), "")
+    return f"{op.type}.{first}" if first else op.type
+
+
 class _BlockRunner:
     """Trace-time walk over a block's ops. Also handed to control-flow
 
@@ -166,7 +174,11 @@ class _BlockRunner:
             kernel = registry.get_kernel(op.type)
             ctx = registry.OpContext(op, env, executor=self, block=block)
             try:
-                kernel(ctx)
+                # trace time only: the op's name in every HLO
+                # instruction's op_name metadata, forward and (through
+                # _run_autodiff's re-trace) transposed
+                with jax.named_scope(_op_scope(op)):
+                    kernel(ctx)
             except Exception as e:
                 # CustomStackTrace parity (utils/CustomStackTrace.h:51):
                 # name the failing op and its I/O so trace errors point at
@@ -401,63 +413,77 @@ class Executor:
         return_numpy (the reference fluid API name)."""
         if as_numpy is None:
             as_numpy = return_numpy
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        scope = scope or global_scope()
-        fetch_names = [
-            v.name if isinstance(v, Variable) else v for v in (fetch_list or [])
-        ]
-
-        # normalize feed values to jax-compatible arrays. Committed jax
-        # arrays (the DevicePrefetcher path puts every batch on device
-        # ahead of time) pass through untouched — re-wrapping them in
-        # jnp.asarray would re-hash/re-place each one every batch
-        for k, v in feed.items():
-            if isinstance(v, jax.Array):
-                continue
-            if isinstance(v, np.ndarray):
-                feed[k] = jnp.asarray(v)
-
-        persist_names = sorted(
-            v.name
-            for v in program.persistables()
-            if scope.has(v.name)
-        )
-        key = self._cache_key_prefix() + self._program_trace_key(program) + (
-            _feed_signature(feed),
-            tuple(fetch_names),
-            tuple(persist_names),
-        )
-        cached = self._cache.get(key)
-        if cached is None:
-            self.cache_stats["misses"] += 1
-            fn = self._compile(program, feed, fetch_names, persist_names)
-            # keep a strong ref to the program: the key uses id(program),
-            # which may be recycled if the program were garbage collected
-            self._cache[key] = (program, fn)
-        else:
-            self.cache_stats["hits"] += 1
-            fn = cached[1]
-
-        state = {n: scope.get(n) for n in persist_names}
-        seed = jnp.asarray(self._draw_seed(program), dtype=jnp.uint32)
-        state, feed, seed = self._place_inputs(program, state, feed, seed)
-        with self._device_context(), self._trace_context():
-            fetches, new_state = fn(state, feed, seed)
-        if FLAGS.check_nan_inf:
-            # reference: CheckTensorNANOrInf per op output behind
-            # FLAGS_check_nan_inf (fluid executor.cc:60-72,125-133). Under
-            # whole-program jit the checkable boundary is the run: every
-            # persistable output + fetch (costs a host sync — debug flag).
-            _check_finite(
-                {**new_state, **{n: f for n, f in zip(fetch_names, fetches)}}
-            )
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if as_numpy:
-            fetches = [
-                np.asarray(f) if not isinstance(f, LoDArray) else f for f in fetches
+        with profiler.timer("executor.prepare"):
+            program = program or default_main_program()
+            feed = dict(feed or {})
+            scope = scope or global_scope()
+            fetch_names = [
+                v.name if isinstance(v, Variable) else v
+                for v in (fetch_list or [])
             ]
+
+            # normalize feed values to jax-compatible arrays. Committed
+            # jax arrays (the DevicePrefetcher path puts every batch on
+            # device ahead of time) pass through untouched — re-wrapping
+            # them in jnp.asarray would re-hash/re-place each one every
+            # batch
+            for k, v in feed.items():
+                if isinstance(v, jax.Array):
+                    continue
+                if isinstance(v, np.ndarray):
+                    feed[k] = jnp.asarray(v)
+
+            persist_names = sorted(
+                v.name
+                for v in program.persistables()
+                if scope.has(v.name)
+            )
+            key = self._cache_key_prefix() + \
+                self._program_trace_key(program) + (
+                    _feed_signature(feed),
+                    tuple(fetch_names),
+                    tuple(persist_names),
+                )
+            cached = self._cache.get(key)
+            if cached is None:
+                self.cache_stats["misses"] += 1
+                fn = self._compile(program, feed, fetch_names, persist_names)
+                # keep a strong ref to the program: the key uses
+                # id(program), which may be recycled if the program were
+                # garbage collected
+                self._cache[key] = (program, fn)
+            else:
+                self.cache_stats["hits"] += 1
+                fn = cached[1]
+
+            state = {n: scope.get(n) for n in persist_names}
+            seed = jnp.asarray(self._draw_seed(program), dtype=jnp.uint32)
+            state, feed, seed = self._place_inputs(program, state, feed, seed)
+        with self._device_context(), self._trace_context(), \
+                profiler.timer("executor.call"):
+            fetches, new_state = fn(state, feed, seed)
+        with profiler.timer("executor.commit"):
+            if FLAGS.check_nan_inf:
+                # reference: CheckTensorNANOrInf per op output behind
+                # FLAGS_check_nan_inf (fluid executor.cc:60-72,125-133).
+                # Under whole-program jit the checkable boundary is the
+                # run: every persistable output + fetch (costs a host
+                # sync — debug flag).
+                _check_finite(
+                    {**new_state,
+                     **{n: f for n, f in zip(fetch_names, fetches)}}
+                )
+            for n, v in new_state.items():
+                scope.set(n, v)
+            # the scope held the other reference to every replaced
+            # buffer: drop the last one here, so that freeing ~a step's
+            # worth of arrays is timed in this span and not in the return
+            del state
+            if as_numpy:
+                fetches = [
+                    np.asarray(f) if not isinstance(f, LoDArray) else f
+                    for f in fetches
+                ]
         return fetches
 
     # ------------------------------------------------------------------
@@ -577,68 +603,75 @@ class Executor:
         Returns (ys, acc_out): ys aligned with fetch_list, each a device
         array with leading axis K (per-step values — still async; reading
         them is the caller's sync decision)."""
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        scope = scope or global_scope()
-        fetch_names = [
-            v.name if isinstance(v, Variable) else v for v in (fetch_list or [])
-        ]
-        if acc_state is not None and not fetch_names:
-            raise ValueError(
-                "run_window with acc_state needs fetch_list[0] = cost")
-        for k, v in feed.items():
-            if isinstance(v, jax.Array):
-                continue
-            if isinstance(v, np.ndarray):
-                feed[k] = jnp.asarray(v)
-        leaves = jax.tree_util.tree_leaves(feed)
-        if not leaves:
-            raise ValueError("run_window needs at least one feed slot")
-        k_steps = int(leaves[0].shape[0])
-        persist_names = sorted(
-            v.name for v in program.persistables() if scope.has(v.name)
-        )
-        key = self._cache_key_prefix() + self._program_trace_key(program) + (
-            "scan_window",
-            bool(skip_nonfinite),
-            acc_state is not None,
-            _feed_signature(feed),  # window size K lives in the leading dim
-            tuple(fetch_names),
-            tuple(persist_names),
-        )
-        cached = self._cache.get(key)
-        if cached is None:
-            self.cache_stats["misses"] += 1
-            fn = self._build_window(
-                program, fetch_names, persist_names,
-                skip_nonfinite, acc_state is not None)
-            self._cache[key] = (program, fn)
-        else:
-            self.cache_stats["hits"] += 1
-            fn = cached[1]
-
-        state = {n: scope.get(n) for n in persist_names}
-        # commit carries to THE device before the call: jit specializes
-        # its executable on input shardings, so an uncommitted leaf (the
-        # startup outputs on the first window, a fresh pass's accumulator
-        # zeros) would silently double-compile every window program. A
-        # device_put of an already-resident array is a cheap no-copy.
-        state = jax.device_put(state, self.place.device)
-        if acc_state is not None:
-            acc_state = jax.device_put(acc_state, self.place.device)
-        seeds = jnp.asarray(
-            [self._draw_seed(program) for _ in range(k_steps)],
-            dtype=jnp.uint32)
-        with self._device_context(), self._trace_context():
-            ys, new_state, acc_out, extras = fn(state, feed, seeds, acc_state)
-        if FLAGS.check_nan_inf:
-            _check_finite(
-                {**new_state, **{n: f for n, f in zip(fetch_names, ys)}}
+        with profiler.timer("executor.prepare"):
+            program = program or default_main_program()
+            feed = dict(feed or {})
+            scope = scope or global_scope()
+            fetch_names = [
+                v.name if isinstance(v, Variable) else v
+                for v in (fetch_list or [])
+            ]
+            if acc_state is not None and not fetch_names:
+                raise ValueError(
+                    "run_window with acc_state needs fetch_list[0] = cost")
+            for k, v in feed.items():
+                if isinstance(v, jax.Array):
+                    continue
+                if isinstance(v, np.ndarray):
+                    feed[k] = jnp.asarray(v)
+            leaves = jax.tree_util.tree_leaves(feed)
+            if not leaves:
+                raise ValueError("run_window needs at least one feed slot")
+            k_steps = int(leaves[0].shape[0])
+            persist_names = sorted(
+                v.name for v in program.persistables() if scope.has(v.name)
             )
-        for n, v in new_state.items():
-            scope.set(n, v)
-        for n, v in extras.items():
-            # stacked K copies of a step-created persistable: keep the
-            # last step's value (what the step loop's scope would hold)
-            scope.set(n, jax.tree_util.tree_map(lambda a: a[-1], v))
+            key = self._cache_key_prefix() + \
+                self._program_trace_key(program) + (
+                    "scan_window",
+                    bool(skip_nonfinite),
+                    acc_state is not None,
+                    _feed_signature(feed),  # window size K: the leading dim
+                    tuple(fetch_names),
+                    tuple(persist_names),
+                )
+            cached = self._cache.get(key)
+            if cached is None:
+                self.cache_stats["misses"] += 1
+                fn = self._build_window(
+                    program, fetch_names, persist_names,
+                    skip_nonfinite, acc_state is not None)
+                self._cache[key] = (program, fn)
+            else:
+                self.cache_stats["hits"] += 1
+                fn = cached[1]
+
+            state = {n: scope.get(n) for n in persist_names}
+            # commit carries to THE device before the call: jit
+            # specializes its executable on input shardings, so an
+            # uncommitted leaf (the startup outputs on the first window,
+            # a fresh pass's accumulator zeros) would silently
+            # double-compile every window program. A device_put of an
+            # already-resident array is a cheap no-copy.
+            state = jax.device_put(state, self.place.device)
+            if acc_state is not None:
+                acc_state = jax.device_put(acc_state, self.place.device)
+            seeds = jnp.asarray(
+                [self._draw_seed(program) for _ in range(k_steps)],
+                dtype=jnp.uint32)
+        with self._device_context(), self._trace_context(), \
+                profiler.timer("executor.call"):
+            ys, new_state, acc_out, extras = fn(state, feed, seeds, acc_state)
+        with profiler.timer("executor.commit"):
+            if FLAGS.check_nan_inf:
+                _check_finite(
+                    {**new_state, **{n: f for n, f in zip(fetch_names, ys)}}
+                )
+            for n, v in new_state.items():
+                scope.set(n, v)
+            for n, v in extras.items():
+                # stacked K copies of a step-created persistable: keep the
+                # last step's value (what the step loop's scope would hold)
+                scope.set(n, jax.tree_util.tree_map(lambda a: a[-1], v))
+            del state  # as in run(): the replaced buffers die in the span
         return ys, acc_out

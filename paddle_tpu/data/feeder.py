@@ -14,6 +14,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
+from .. import profiler
 from ..core.lod import LoDArray
 from ..core.program import Variable
 from ..core.sparse import SparseArray
@@ -174,29 +175,36 @@ class DevicePrefetcher:
                 return v
             return jax.device_put(v, target)
 
+        def read():  # the user's reader, each next() under its span
+            batches = iter(self.reader())
+            while True:
+                with profiler.timer("prefetch.read"):
+                    batch = next(batches, END)
+                if batch is END:
+                    return
+                yield batch
+
+        def put_window(buf):
+            with profiler.timer("prefetch.window"):
+                q.put(_stack_feeds(buf))
+            buf.clear()
+
         def produce():
             from ..core.executor import _feed_signature
 
             buf, sig = [], None
             try:
-                for i, batch in enumerate(self.reader()):
+                for i, batch in enumerate(read()):
                     if stop.is_set():
                         return
-                    # producer-thread span: the batch index here is the
-                    # SAME index the trainer's BeginIteration/step spans
-                    # carry, so prefetch→enqueue latency reads straight
-                    # off the exported timeline (disarmed: one bool test,
-                    # zero allocations — the obs lint enforces the guard)
-                    armed = obs_trace._armed
-                    if armed:
+                    if obs_trace._armed:
+                        # the SAME index the trainer's step spans carry
                         obs_trace.set_context(batch=i)
-                        obs_trace._begin("prefetch.batch", "prefetch")
-                    feed = self.feeder.feed(batch) if self.feeder else batch
-                    feed = {
-                        k: jax.tree.map(put, v) for k, v in feed.items()
-                    }
-                    if armed:
-                        obs_trace._end()
+                    with profiler.timer("prefetch.batch"):
+                        if self.feeder:
+                            batch = self.feeder.feed(batch)
+                        feed = {k: jax.tree.map(put, v)
+                                for k, v in batch.items()}
                     if not self.window:
                         q.put(feed)
                         continue
@@ -204,32 +212,23 @@ class DevicePrefetcher:
                     if buf and s != sig:
                         # shape change mid-stream: flush the partial
                         # window so every window stays one compiled shape
-                        if armed:
-                            obs_trace._begin("prefetch.window", "prefetch")
-                        q.put(_stack_feeds(buf))
-                        if armed:
-                            obs_trace._end()
-                        buf = []
+                        put_window(buf)
                     sig = s
                     buf.append(feed)
                     if len(buf) == self.window:
-                        if armed:
-                            obs_trace._begin("prefetch.window", "prefetch")
-                        q.put(_stack_feeds(buf))
-                        if armed:
-                            obs_trace._end()
-                        buf = []
+                        put_window(buf)
                 if buf:  # ragged tail window at pass end
-                    q.put(_stack_feeds(buf))
+                    put_window(buf)
                 q.put(END)
             except BaseException as e:  # surface reader errors to consumer
                 q.put((ERR, e))
 
-        t = threading.Thread(target=produce, daemon=True)
+        t = threading.Thread(target=produce, daemon=True, name="pt-prefetch")
         t.start()
         try:
             while True:
-                item = q.get()
+                with profiler.timer("prefetchWait"):
+                    item = q.get()
                 if item is END:
                     return
                 if isinstance(item, tuple) and len(item) == 2 and item[0] is ERR:

@@ -7,8 +7,12 @@ counters (ISSUE 8). Three pieces:
                 buffers, zero-cost disarmed (the resilience.faults
                 contract), correlation ids propagated across thread
                 hand-offs, Chrome trace-event JSON export for
-                Perfetto / chrome://tracing, optional XProf bracketing
-                so host spans and device kernels share an interval.
+                Perfetto / chrome://tracing. One of the three sinks of
+                the step path's one span primitive,
+                `profiler.StatSet.timer` (the others: the StatSet, and
+                a `jax.profiler.TraceAnnotation`, which every armed
+                span here is too: a `jax.profiler` capture holds the
+                host spans on the device's clock).
 - `metrics`   — ONE process-wide MetricsRegistry unifying the global
                 profiler.StatSet, trainer dispatch/sync/checkpoint/
                 guard counters, fault-registry hit/fire counts, and

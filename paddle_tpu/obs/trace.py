@@ -22,6 +22,11 @@ Design (the `resilience.faults` contract applied to tracing):
   as the `pt_trace_dropped_total` counter — never silent truncation).
 - Timestamps come from one monotonic clock (`time.perf_counter`), so
   spans across threads order correctly in the exported timeline.
+- Every armed span is ALSO entered as a `jax.profiler.TraceAnnotation`
+  (`_annotate`, the helper `profiler.StatSet.timer` shares): while a
+  `jax.profiler` capture is running, the span is an event on its
+  thread's line of `/host:CPU` in the `.xplane.pb`, on the device
+  lines' own clock. No capture running: the annotation is inert.
 - Correlation travels as a per-thread *trace context* (a plain dict):
   `set_context(step=..)` / `context(request_id=..)` attach ids that
   every subsequent span on that thread records as args. Thread
@@ -32,13 +37,16 @@ Design (the `resilience.faults` contract applied to tracing):
 - Export is Chrome trace-event JSON (one "X" complete event per span,
   "i" instants, "C" counter tracks, "M" thread-name metadata): open it
   in Perfetto / chrome://tracing. `tracing(xprof_dir=...)` brackets the
-  capture inside the existing `profiler.profiler()` XProf trace so host
-  spans and device kernels cover the same interval.
+  capture inside the existing `profiler.profiler()` XProf trace: that
+  trace then holds the same spans beside the device's kernels, in one
+  file on one clock.
 
-`profiler.StatSet.timer` integrates: while tracing is armed every timer
-block (forwardBackward, hostSync, checkpointSnapshot, the serving
-predict timers) also records a span, so the span vocabulary is the
-timer vocabulary plus the explicitly instrumented request/pool events.
+`profiler.StatSet.timer` is the step path's one span primitive (its
+module docstring has the span table): while tracing is armed every timer
+block (forwardBackward and the executor.* spans inside it, hostSync,
+prefetch.batch, checkpointSnapshot, the serving predict timers) records
+a span here, so the span vocabulary is the timer vocabulary plus the
+request/pool events that serving instruments with `span()` directly.
 """
 
 from __future__ import annotations
@@ -121,7 +129,8 @@ class _ThreadBuf:
         self.tid = t.ident or 0
         self.name = t.name
         self.events: collections.deque = collections.deque(maxlen=ring)
-        self.stack: List[tuple] = []  # open spans: (name, cat, t0, args)
+        # open spans: (name, cat, t0, args, entered TraceAnnotation)
+        self.stack: List[tuple] = []
         self.ctx: Dict[str, Any] = {}
         self.dropped = 0
 
@@ -198,7 +207,7 @@ class Trace:
                 events.append(self._event_json(ev, pid, b.tid))
             # spans still open (e.g. export inside the traced region):
             # close them at "now" so the JSON stays schema-valid
-            for name, cat, t0, args in b.stack:
+            for name, cat, t0, args, _ in b.stack:
                 events.append(self._event_json(
                     ("X", name, cat, t0, now - t0, dict(b.ctx, **(args or {}))),
                     pid, b.tid))
@@ -292,8 +301,9 @@ def tracing(out: Optional[str] = None, ring_size: Optional[int] = None,
     """Scoped capture: arm, yield the Trace, export+disarm on exit.
 
     xprof_dir brackets the capture in the existing profiler.profiler()
-    XProf trace, so host spans and device kernels are captured over the
-    same interval (correlate the two timelines by wall offset)."""
+    XProf trace. Every span recorded here is a TraceAnnotation too, so
+    that trace holds the host spans on their threads' lines of
+    `/host:CPU`, on the same clock as the device's kernels."""
     tr = arm(out=out, ring_size=ring_size)
     stack = contextlib.ExitStack()
     if xprof_dir:
@@ -307,12 +317,25 @@ def tracing(out: Optional[str] = None, ring_size: Optional[int] = None,
         disarm(export=True)
 
 
+def _annotate(name: str):
+    """An entered `jax.profiler.TraceAnnotation(name)`: the span on the
+    profiler's own timeline (the caller exits it). Shared with
+    `profiler.StatSet.timer`; on the armed / timers-on path only, so jax
+    is imported here and not with the module."""
+    from jax import profiler as jax_profiler
+
+    ann = jax_profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 def _begin(name: str, cat: str = "host",
            args: Optional[Dict[str, Any]] = None) -> None:
     tr = _trace
     if tr is None:
         return
-    tr.buf().stack.append((name, cat, time.perf_counter(), args))
+    tr.buf().stack.append(
+        (name, cat, time.perf_counter(), args, _annotate(name)))
 
 
 def _end() -> None:
@@ -322,8 +345,9 @@ def _end() -> None:
     b = tr.buf()
     if not b.stack:
         return  # span begun before arm / ended twice: drop, don't crash
-    name, cat, t0, args = b.stack.pop()
+    name, cat, t0, args, ann = b.stack.pop()
     t1 = time.perf_counter()
+    ann.__exit__(None, None, None)
     merged = dict(b.ctx)
     if args:
         merged.update(args)

@@ -1,4 +1,4 @@
-"""Profiling: stat timers, trace contexts, parameter stats.
+"""Profiling: the span primitive, stat timers, trace contexts, parameter stats.
 
 Reference surface:
 - Gen-1 `REGISTER_TIMER*` RAII macros accumulating into a global StatSet
@@ -7,18 +7,45 @@ Reference surface:
   (paddle/platform/profiler.h:25-118, fluid/profiler.py).
 - Per-parameter value/grad stats (TrainerInternal.cpp:81-109).
 
-TPU mapping: host-side timers bracket whole jitted steps (per-op host
-timing is meaningless under fusion); deep kernel profiles come from
-`profiler()` which wraps jax.profiler.trace (XProf). Dispatch is async,
-so what a timer measures depends on whether the block reads a result
-back: the pipelined trainer deliberately splits the two —
-`forwardBackward` brackets only the enqueue (tens of microseconds when
-the host is keeping ahead of the device), and `hostSync` brackets the
-periodic d2h readback of the on-device metric accumulator, which is
-where all device wait time surfaces. The host-blocked fraction of a run
-is hostSync.total / wall time (bench.py BENCH_MODEL=train_loop). To time
-device work in an ad-hoc block, read a result inside it (e.g.
-`float(np.asarray(cost))`) — otherwise the timer measures enqueue."""
+ONE primitive, three sinks. `timer(name)` (= `StatSet.timer`) is the
+single way the step path records a span:
+
+- Off (`FLAGS.enable_timers` false and `obs.trace` disarmed): two
+  boolean tests, then the one shared no-op context object. No clock
+  read, no allocation.
+- On (either switch), the block
+  (a) adds its duration to the `Stat` of that name (timers on),
+  (b) records a span on the calling thread's `obs.trace` ring (armed),
+  (c) is entered as a `jax.profiler.TraceAnnotation(name)`: while a
+      `jax.profiler` capture runs (`profiler()`, `tracing(xprof_dir=)`,
+      chipbench's traced run), the span is an event on its thread's line
+      of `/host:CPU` in the `.xplane.pb`, in the device lines' own
+      nanoseconds, so an idle gap of the device can be put down to it.
+
+The step path's spans (thread; where; what the block covers):
+
+| Span | Thread | Where | Covers |
+|---|---|---|---|
+| `prefetchWait` | trainer | `DevicePrefetcher.__iter__` | the step loop waiting in `q.get()` for a batch |
+| `prepareBatchData` | trainer | `Trainer._step_pass` | in-loop `DataFeeder` (executors that place their own input) |
+| `forwardBackward` | trainer | `_step_pass`, `_scan_pass` | the whole of `Executor.run` / `run_window` |
+| `executor.prepare` | trainer | `Executor.run` / `run_window` | entry to just before the jitted call: feed normalisation, persistables scan, cache key and lookup, state gather, seed, `_place_inputs` |
+| `executor.call` | trainer | around `fn(state, feed, seed)` | the jitted call as Python sees it, and anything that blocks inside it (the first call of a shape traces and compiles here) |
+| `executor.commit` | trainer | after the call to return | `check_nan_inf`, `scope.set` of every new state buffer and the release of the buffers they replace, `as_numpy` |
+| `accumUpdate` | trainer | around `acc.update(...)` | the step's second dispatch (`accum_fold`) |
+| `hostSync` | trainer | `_host_read_step`, `_PassStats.sync` | the periodic d2h read of the accumulator |
+| `lazyRead` | the reader's | `_LazyScalar.materialize`, first read | a handler reading an event's lazy cost: the third fence |
+| `prefetch.read` | `pt-prefetch` | around the reader's `next()` | the user's reader |
+| `prefetch.batch` | `pt-prefetch` | `DataFeeder.feed` + `device_put` | converting and placing one batch |
+
+Dispatch is async, so what a span measures depends on whether its block
+reads a result back. `forwardBackward` is the host's side of a step:
+62-68 ms for the 1 000-buffer GPT-2 small step on a v5e (ledger, PR 23),
+not the device's 192 ms; the device's time surfaces in whichever block
+fences next — `hostSync`, `lazyRead`, or `executor.call` itself when the
+runtime makes the host wait for memory. To time device work in an ad-hoc
+block, read a result inside it (e.g. `float(np.asarray(cost))`) —
+otherwise the span measures the enqueue."""
 
 from __future__ import annotations
 
@@ -75,6 +102,35 @@ class Stat:
             return statistics.median(self.samples)
 
 
+class _Timer:
+    """One live `StatSet.timer` block (the on path only)."""
+
+    __slots__ = ("_stat", "_name", "_traced", "_ann", "_t0")
+
+    def __init__(self, stat: Optional[Stat], name: str, traced: bool):
+        self._stat = stat
+        self._name = name
+        self._traced = traced
+
+    def __enter__(self):
+        if self._traced:
+            _trace._begin(self._name, "timer")  # ring + annotation
+        else:
+            self._ann = _trace._annotate(self._name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        if self._traced:
+            _trace._end()
+        else:
+            self._ann.__exit__(None, None, None)
+        if self._stat is not None:
+            self._stat.add(dt)
+        return False
+
+
 class StatSet:
     """Named timer accumulator (reference: StatSet, Stat.h:230).
 
@@ -100,28 +156,17 @@ class StatSet:
                     s = self.stats[name] = Stat(name, self.keep_samples)
         return s
 
-    @contextlib.contextmanager
     def timer(self, name: str, always: bool = False):
-        """RAII timer (REGISTER_TIMER parity). No-op unless
-        FLAGS.enable_timers or always=True (WITH_TIMER compile gate) —
-        or span tracing is armed (obs.trace), in which case the block
-        additionally records a span on this thread's trace ring (the
-        timer vocabulary IS the span vocabulary)."""
+        """The span primitive (REGISTER_TIMER parity; module docstring).
+        Off — timers off (and not `always`, the WITH_TIMER compile gate)
+        and `obs.trace` disarmed — it returns the shared no-op context
+        object; on, a block that feeds the Stat (timers), the calling
+        thread's trace ring (armed) and the profiler's own timeline."""
         traced = _trace._armed
-        if not (always or FLAGS.enable_timers or traced):
-            yield
-            return
-        if traced:
-            _trace._begin(name, "timer")
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            if traced:
-                _trace._end()
-            if always or FLAGS.enable_timers:
-                self.get(name).add(dt)
+        timed = always or FLAGS.enable_timers
+        if not (timed or traced):
+            return _trace._NULL
+        return _Timer(self.get(name) if timed else None, name, traced)
 
     def print_all_status(self) -> str:
         """Formatted table (reference: StatSet::printAllStatus); adds a

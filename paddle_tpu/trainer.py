@@ -129,7 +129,8 @@ class _LazyScalar:
         if self._host is None:
             if self._on_sync is not None:
                 self._on_sync()
-            v = np.asarray(self._value)
+            with profiler.timer("lazyRead"):
+                v = np.asarray(self._value)
             self._host = float(v if self._index is None else v[self._index])
             self._value = None  # drop the device ref once read
         return self._host
@@ -858,8 +859,9 @@ class Trainer:
                 step_fetch += [grad_var_name(p) for p in stat_params]
             if faults.fire("executor.step", step=self.step) == "corrupt":
                 feed = _poison_feed(feed)
-            # enqueue only: fetches stay on device, the timer measures
-            # dispatch cost; device wait shows up under hostSync
+            # enqueue only: fetches stay on device, the span measures the
+            # host's side of the step (split by the executor.* spans
+            # inside it); device wait shows up in whatever fences next
             with profiler.timer("forwardBackward"):
                 outs = self.exe.run(
                     self.main_program,
@@ -881,7 +883,8 @@ class Trainer:
                     print(f"  param {pname}: " + ", ".join(
                         f"{k}={v:.4g}" for k, v in st.items()))
             metric_devs = outs[1:]
-            acc.update(cost_dev, metric_devs)
+            with profiler.timer("accumUpdate"):
+                acc.update(cost_dev, metric_devs)
             # per-step sync: legacy cadence, a hot StepGuard (open
             # streak / cool-down), or a stats step (it prints anyway)
             per_step = (sync_every == 1 or want_stats
