@@ -705,7 +705,7 @@ def run_ragged(model, batch, steps):
         return {"src": pack(seqs_batch), "trg_in": pack(seqs_batch),
                 "label": pack(seqs_batch)}
 
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     results = {}
     for variant in ("padded", "bucketed"):
         if variant == "padded":
@@ -867,7 +867,7 @@ def run_infer(model, batch, steps):
         # with random weights; real decodes stop earlier)
 
     fetch = [fetch_names[0]]
-    iexe = pt.Executor(donate_state=True)
+    iexe = pt.Executor()
     # two timed blocks, report the second: the first block drains the
     # lazily-staged state h2d + compile tail (asynchronous staging can
     # outlive a short synced warmup)
@@ -2967,7 +2967,7 @@ def main():
                 f"the fused kernels would silently fall back to the scan")
         exe = _mesh_executor(mesh_spec)
     else:
-        exe = pt.Executor(donate_state=True)
+        exe = pt.Executor()
     exe.run(cfg["startup"])
 
     if os.environ.get("BENCH_OVERLAP") == "1":

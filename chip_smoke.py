@@ -206,11 +206,12 @@ def _step_text(exe, feed) -> str:
                 if op.type == "autodiff").inputs["Loss"][0]
     persist = sorted(v.name for v in prog.persistables() if scope.has(v.name))
     fn = exe._compile(prog, feed, [cost], persist)
-    state = {n: scope.get(n) for n in persist}
+    donated, kept = exe._split_state(prog, {n: scope.get(n) for n in persist})
     if exe.prefetch_by_default:
         feed = jax.device_put(feed, exe.place.device)
     with exe._device_context(), exe._trace_context():
-        return fn.lower(state, feed, jnp.uint32(0)).compile().as_text()
+        return fn.lower(donated, kept, feed,
+                        jnp.uint32(0)).compile().as_text()
 
 
 def _kernel_fields(text: str, at_least: int, relower_hits: int) -> dict:

@@ -39,7 +39,7 @@ def main():
 
     import paddle_tpu as pt
 
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     for batch in (128, 256, 512):
         rng = np.random.RandomState(0)
         feed = {

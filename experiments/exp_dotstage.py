@@ -58,7 +58,7 @@ def main():
     configs = [(0, "0"), (6272, "0"), (25088, "0"), (100352, "0"),
                (25088, "1"), (6272, "1")]
     variants = {}
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     for thr, pal in configs:
         # the op kernel reads these FLAGS at trace time (first run below)
         FLAGS.fused_conv_dot_max_n = thr

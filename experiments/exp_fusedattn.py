@@ -51,7 +51,7 @@ def main():
 
     import paddle_tpu as pt
 
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     for batch in (128, 256):
         variants = {}
         for fused in (False, True):

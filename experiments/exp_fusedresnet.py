@@ -45,7 +45,7 @@ def main():
         "label": rng.randint(0, 1000, (BATCH, 1)).astype(np.int32),
     }
     progs = {}
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     for fused in (False, True):
         progs[fused] = build(fused)
     feed = {k: jax.device_put(v) for k, v in feed_np.items()}

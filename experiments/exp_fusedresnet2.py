@@ -64,7 +64,7 @@ def main():
         configs[f"proto2d-{t}"] = (True, train, BIG, False)
         configs[f"pallas-{t}"] = (True, train, BIG, True)
 
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     variants = {}
     for name, cfg in configs.items():
         # the op kernels read the dispatch FLAGS at TRACE time (the first

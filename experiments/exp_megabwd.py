@@ -54,7 +54,7 @@ def main():
     from paddle_tpu.flags import FLAGS
     from paddle_tpu.ops import bahdanau_kernels as bk
 
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     for batch in (128, 256):
         variants = {}
         for mega in (False, True):

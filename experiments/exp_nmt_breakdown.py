@@ -99,7 +99,7 @@ def build(variant):
     feed = {"src": pack(seqs()), "trg_in": pack(seqs()),
             "label": pack(seqs())}
     feed = {k: jax.device_put(v) for k, v in feed.items()}
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     exe.run(startup)
     return exe, prog, loss, feed
 

@@ -70,7 +70,7 @@ def main():
 
     from paddle_tpu.flags import FLAGS
 
-    exe = pt.Executor(donate_state=True)
+    exe = pt.Executor()
     arms = ("per_layer", "op", "op_scan")
     for hid, batch in ((128, 128), (512, 128)):
         variants = {}

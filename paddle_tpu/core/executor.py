@@ -20,7 +20,9 @@ XLA CSEs the duplicated forward, so this costs nothing at runtime.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+import logging
+import weakref
+from typing import Any, Dict, FrozenSet, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +34,8 @@ from ..flags import FLAGS
 from .lod import LoDArray
 from .place import Place, default_place
 from .program import Program, Variable, default_main_program, grad_var_name
+
+logger = logging.getLogger("paddle_tpu.executor")
 
 
 # remat policies: "full" recomputes everything in the backward pass;
@@ -80,7 +84,12 @@ def _check_finite(values: Dict[str, Any]) -> None:
 
 
 class Scope:
-    """name → runtime value store (reference: paddle/framework/scope.h:38)."""
+    """name → runtime value store (reference: paddle/framework/scope.h:38).
+
+    A program that rebinds a persistable consumes its old buffer (the
+    Executor donates it, as the reference updates a scope tensor in
+    place): an array read with `get` is dead once such a step has run.
+    To keep a value, copy it: `np.asarray(scope.get(name))`."""
 
     def __init__(self):
         self.vars: Dict[str, Any] = {}
@@ -311,11 +320,84 @@ class _BlockRunner:
             return None
 
 
+def rebound_persistables(program: Program) -> FrozenSet[str]:
+    """The persistables `program` rebinds (parameters, optimizer moments,
+    beta powers, batch-norm statistics, `@AVG@` sums, a scheduled
+    learning rate): named by a declared op output or by an input slot
+    the op's kernel registered as written (`register_op(writes=...)`),
+    in any block. One walk per program version. This is the set the
+    Executor donates; a program that rebinds nothing (inference,
+    `clone(for_test=True)`, generation) donates nothing."""
+    cached = getattr(program, "_rebound_persistables", None)
+    if cached is not None and cached[0] == program.version:
+        return cached[1]
+    persist = {v.name for v in program.persistables()}
+    names = frozenset(
+        n for block in program.blocks for op in block.ops
+        for n in registry.written_names(op) if n in persist
+    )
+    program._rebound_persistables = (program.version, names)
+    return names
+
+
+def _own_buffers(donated, kept=()):
+    """Give every donated leaf a buffer no other argument of the call
+    shares: PJRT refuses a buffer donated twice, or donated and read, in
+    one call, and a name left bound to a donated array would read a
+    dead one. A second name of an array (`scope.set(b, scope.get(a))`)
+    gets a copy once; from then on each name has its own output. Host
+    values pass: jit donates the temporary it makes of them."""
+    leaves, treedef = jax.tree_util.tree_flatten(donated)
+    others = jax.tree_util.tree_leaves(kept)
+    if len({id(a) for a in leaves} | {id(a) for a in others}) == \
+            len(leaves) + len(others):
+        return donated
+    seen = {id(a) for a in others}
+    for i, a in enumerate(leaves):
+        if id(a) in seen and isinstance(a, jax.Array):
+            leaves[i] = jnp.array(a, copy=True)
+        seen.add(id(a))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _tree_bytes(tree) -> tuple:
+    leaves = jax.tree_util.tree_leaves(tree)
+    return len(leaves), sum(
+        int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize for a in leaves)
+
+
+_DONATION_KEYS = ("donated_buffers", "donated_bytes", "kept_buffers",
+                  "kept_bytes", "mismatches")
+# every live Executor, for the registry's pt_executor_* gauges
+_executors = weakref.WeakSet()
+
+
+def donation_totals() -> Dict[str, int]:
+    """`Executor.donation_stats` summed over the live executors, empty
+    while there is none (read by the obs.metrics collector; no device
+    access)."""
+    live = list(_executors)
+    if not live:
+        return {}
+    totals = dict.fromkeys(_DONATION_KEYS, 0)
+    for exe in live:
+        for k, v in exe.donation_stats.items():
+            totals[k] += v
+    return totals
+
+
 class Executor:
     """Reference API: fluid executor.py:71 `Executor(place).run(program,
 
     feed, fetch_list)`. Compilation is cached per (program version, feed
-    shapes, fetch list)."""
+    shapes, fetch list).
+
+    The buffers of the persistables a program rebinds
+    (`rebound_persistables`) are donated to the step, so a training step
+    updates its parameters and optimizer state in place: their previous
+    arrays are deleted by the run, and `np.asarray(scope.get(name))` is
+    the way to keep a value across one. A program that rebinds nothing
+    donates nothing. `donation_stats` says what engaged."""
 
     # consulted by the Trainer's pipelined loop: the base executor wants
     # the default DevicePrefetcher (host->device copies overlap compute)
@@ -330,17 +412,25 @@ class Executor:
     # explicitly threaded through the mesh (ISSUE 6 scope note)
     scan_window_supported = True
 
-    def __init__(self, place: Optional[Place] = None, donate_state: bool = False):
+    def __init__(self, place: Optional[Place] = None):
         self.place = place or default_place()
-        # donate_state=True lets XLA reuse the parameter/optimizer-state
-        # buffers in-place across steps (halves peak HBM for the update).
-        # Off by default: donation invalidates any outstanding references to
-        # the old arrays outside the Scope.
-        self.donate_state = donate_state
         self._cache: Dict[Any, Any] = {}
         # jit-cache accounting (the serving layer surfaces these in
         # /metrics): a miss = one whole-program trace + XLA compile
         self.cache_stats: Dict[str, int] = {"hits": 0, "misses": 0}
+        # one record per compiled step program, written when it traces
+        self._donation: List[Dict[str, int]] = []
+        _executors.add(self)
+
+    @property
+    def donation_stats(self) -> Dict[str, int]:
+        """Over the step programs this executor compiled: persistable
+        buffers and bytes donated (rebound by the program) and kept
+        (only read), and `mismatches`: persistables a trace rebound
+        though `rebound_persistables` did not name them (left undonated;
+        a kernel lacks its `register_op(writes=...)`)."""
+        return {k: sum(d.get(k, 0) for d in self._donation)
+                for k in _DONATION_KEYS}
 
     def cache_size(self) -> int:
         """Number of compiled (program, feed-signature) entries held."""
@@ -382,8 +472,10 @@ class Executor:
         )
 
     def _compile(self, program: Program, feed, fetch_names, persist_names):
-        """Build + wrap the traced block walk. Base: plain jax.jit."""
-        return self._build(program, sorted(feed), fetch_names, persist_names)
+        """Build + wrap the traced block walk. Base: plain jax.jit, the
+        donated state its donated argument."""
+        return jax.jit(self._raw_step(program, fetch_names),
+                       donate_argnums=(0,))
 
     def _device_context(self):
         return jax.default_device(self.place.device)
@@ -457,12 +549,16 @@ class Executor:
                 fn = cached[1]
 
             state = {n: scope.get(n) for n in persist_names}
+            donated, kept = self._split_state(program, state)
+            kept = self._place_kept(program, scope, kept)
             seed = jnp.asarray(self._draw_seed(program), dtype=jnp.uint32)
-            state, feed, seed = self._place_inputs(program, state, feed, seed)
+            donated, feed, seed = self._place_inputs(
+                program, donated, feed, seed)
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
-            fetches, new_state = fn(state, feed, seed)
+            fetches, new_state, extras = fn(donated, kept, feed, seed)
         with profiler.timer("executor.commit"):
+            new_state.update(extras)
             if FLAGS.check_nan_inf:
                 # reference: CheckTensorNANOrInf per op output behind
                 # FLAGS_check_nan_inf (fluid executor.cc:60-72,125-133).
@@ -476,9 +572,9 @@ class Executor:
             for n, v in new_state.items():
                 scope.set(n, v)
             # the scope held the other reference to every replaced
-            # buffer: drop the last one here, so that freeing ~a step's
-            # worth of arrays is timed in this span and not in the return
-            del state
+            # (donated: deleted by the call) array: drop the last one
+            # here, so that it is timed in this span and not in the return
+            del state, donated
             if as_numpy:
                 fetches = [
                     np.asarray(f) if not isinstance(f, LoDArray) else f
@@ -515,41 +611,89 @@ class Executor:
         device_puts (jit cannot reshard onto devices it cannot address)."""
         return state, feed, seed
 
-    # ------------------------------------------------------------------
-    def _raw_step(self, program: Program, fetch_names, persist_names):
-        """The traced block walk as a pure function of (state, feed,
-        seed) — the unit both `_build` (one jitted step) and
-        `_build_window` (K steps under one lax.scan) compile."""
-        runner = _BlockRunner(program)
-        all_persist = {v.name for v in program.persistables()}
+    def _place_kept(self, program, scope, kept: Dict[str, Any]):
+        """Hook: the step does not return the persistables it only reads,
+        so a host value among them (`load_checkpoint`, a served artifact:
+        every parameter of an inference program) would be uploaded again
+        by every run. Commit it to the device once and leave it in the
+        scope. The ParallelExecutor places them on its mesh."""
+        for n, v in kept.items():
+            if not isinstance(v, jax.Array):
+                kept[n] = jax.device_put(v, self.place.device)
+                scope.set(n, kept[n])
+        return kept
 
-        def raw(state: Dict[str, Any], feed: Dict[str, Any], seed):
+    @staticmethod
+    def _split_state(program: Program, state: Dict[str, Any]):
+        """(donated, kept): the persistables the program rebinds, whose
+        buffers the step consumes, and the ones it only reads."""
+        rebound = rebound_persistables(program)
+        donated = {n: v for n, v in state.items() if n in rebound}
+        kept = {n: v for n, v in state.items() if n not in rebound}
+        return _own_buffers(donated, kept), kept
+
+    # ------------------------------------------------------------------
+    def _state_outputs(self, program: Program, env, donated, kept,
+                       record: Dict[str, int]):
+        """Trace time, after the block walk: (new_state, extras).
+        new_state holds exactly the donated names (one the trace did not
+        rebind aliases its input: harmless). extras holds what the split
+        could not know: a persistable the run created, and a kept one
+        the trace rebound all the same; that one is counted in `record`,
+        warned about and stays undonated (a lost saving, not a wrong
+        answer)."""
+        new_state = {n: env[n] for n in donated}
+        mismatched = sorted(n for n, v in kept.items() if env[n] is not v)
+        extras = {n: env[n] for n in mismatched}
+        for v in program.persistables():
+            if v.name in env and v.name not in donated and v.name not in kept:
+                extras[v.name] = env[v.name]
+        if mismatched:
+            logger.warning(
+                "executor: the step rebinds %s, which no op names as "
+                "written (register_op(writes=...)): left undonated",
+                mismatched)
+        buffers, nbytes = _tree_bytes(donated)
+        kept_buffers, kept_bytes = _tree_bytes(kept)
+        record.update(donated_buffers=buffers, donated_bytes=nbytes,
+                      kept_buffers=kept_buffers, kept_bytes=kept_bytes,
+                      mismatches=len(mismatched))
+        return new_state, extras
+
+    def _donation_record(self) -> Dict[str, int]:
+        """A step program's entry in `donation_stats`: `_state_outputs`
+        fills it when the program traces (again when it retraces)."""
+        record: Dict[str, int] = {}
+        self._donation.append(record)
+        return record
+
+    def _raw_step(self, program: Program, fetch_names):
+        """The traced block walk as a pure function of (donated, kept,
+        feed, seed) -> (fetches, new_state, extras) — the unit both
+        `_compile` (one jitted step) and `_build_window` (K steps under
+        one lax.scan) compile. The state arrives split as `_split_state`
+        splits it; see `_state_outputs` for what comes back."""
+        runner = _BlockRunner(program)
+        record = self._donation_record()
+
+        def raw(donated: Dict[str, Any], kept: Dict[str, Any],
+                feed: Dict[str, Any], seed):
             env: Dict[str, Any] = {}
-            env.update(state)
+            env.update(kept)
+            env.update(donated)
             env.update(feed)
             env["@RNG@"] = jax.random.PRNGKey(seed)
             env["@RNG_COUNTER@"] = 0
             env["@AMP@"] = program.amp_dtype
             runner.run_block(0, env)
             fetches = [env[n] for n in fetch_names]
-            new_state = {
-                n: env[n]
-                for n in set(persist_names) | (all_persist & set(env))
-                if n in env
-            }
-            return fetches, new_state
+            return (fetches,) + self._state_outputs(
+                program, env, donated, kept, record)
 
         return raw
 
-    def _build(self, program: Program, feed_names, fetch_names, persist_names):
-        donate = (0,) if self.donate_state else ()
-        return jax.jit(
-            self._raw_step(program, fetch_names, persist_names),
-            donate_argnums=donate,
-        )
-
     # -- windowed (multi-step fused) execution -------------------------
-    def _build_window(self, program: Program, fetch_names, persist_names,
+    def _build_window(self, program: Program, fetch_names,
                       skip_nonfinite: bool, with_acc: bool):
         """Compile K training steps into ONE program: a lax.scan of the
         traced step over a leading window axis of the feed, with the
@@ -557,30 +701,38 @@ class Executor:
         the scan carry. One host dispatch per window instead of K — the
         ISSUE 6 answer to PERF.md's per-step dispatch floor.
 
+        The donated state and the accumulator ride the scan carry and
+        are donated to the window; the kept state rides it too (XLA
+        drops a carry the body passes through), so that a kept name the
+        body rebinds all the same (`_state_outputs`' mismatch) still
+        reads its own last value at the next step.
+
         Persistables that first materialize inside the step (rare: the
         usual flow initializes everything in startup) cannot join the
         carry (its pytree structure is fixed before the first iteration),
         so they ride the stacked scan outputs and the caller keeps the
         last step's value."""
-        raw = self._raw_step(program, fetch_names, persist_names)
+        raw = self._raw_step(program, fetch_names)
         skip = bool(skip_nonfinite)
 
-        def win(state, feeds, seeds, acc):
+        def win(donated, kept, feeds, seeds, acc):
+            rebound_kept = set()  # filled while the body traces
+
             def body(carry, xs):
-                st, ac = carry
+                st, ro, ac = carry
                 feed_t, seed_t = xs
-                fetches, new_state = raw(st, feed_t, seed_t)
+                fetches, st, extras = raw(st, ro, feed_t, seed_t)
                 if with_acc:
                     ac = accum_fold(ac, fetches[0], list(fetches[1:]), skip)
-                extras = {n: v for n, v in new_state.items() if n not in st}
-                st = {n: new_state.get(n, v) for n, v in st.items()}
-                return (st, ac), (fetches, extras)
+                rebound_kept.update(n for n in extras if n in ro)
+                ro = {n: extras.pop(n, v) for n, v in ro.items()}
+                return (st, ro, ac), (fetches, extras)
 
-            (state, acc), (ys, extras) = jax.lax.scan(
-                body, (state, acc), (feeds, seeds))
-            return ys, state, acc, extras
+            (state, ro, acc), (ys, created) = jax.lax.scan(
+                body, (donated, kept, acc), (feeds, seeds))
+            return ys, state, acc, {n: ro[n] for n in rebound_kept}, created
 
-        return jax.jit(win)
+        return jax.jit(win, donate_argnums=(0, 4))
 
     def run_window(
         self,
@@ -639,7 +791,7 @@ class Executor:
             if cached is None:
                 self.cache_stats["misses"] += 1
                 fn = self._build_window(
-                    program, fetch_names, persist_names,
+                    program, fetch_names,
                     skip_nonfinite, acc_state is not None)
                 self._cache[key] = (program, fn)
             else:
@@ -647,31 +799,37 @@ class Executor:
                 fn = cached[1]
 
             state = {n: scope.get(n) for n in persist_names}
+            donated, kept = self._split_state(program, state)
+            kept = self._place_kept(program, scope, kept)
+            # the window donates the accumulator with the state, and a
+            # fresh pass's holds one zero under several leaves
+            acc_state = _own_buffers(acc_state, (donated, kept))
             # commit carries to THE device before the call: jit
             # specializes its executable on input shardings, so an
             # uncommitted leaf (the startup outputs on the first window,
             # a fresh pass's accumulator zeros) would silently
             # double-compile every window program. A device_put of an
             # already-resident array is a cheap no-copy.
-            state = jax.device_put(state, self.place.device)
-            if acc_state is not None:
-                acc_state = jax.device_put(acc_state, self.place.device)
+            donated, kept, acc_state = jax.device_put(
+                (donated, kept, acc_state), self.place.device)
             seeds = jnp.asarray(
                 [self._draw_seed(program) for _ in range(k_steps)],
                 dtype=jnp.uint32)
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
-            ys, new_state, acc_out, extras = fn(state, feed, seeds, acc_state)
+            ys, new_state, acc_out, rebound_kept, created = fn(
+                donated, kept, feed, seeds, acc_state)
         with profiler.timer("executor.commit"):
+            new_state.update(rebound_kept)
             if FLAGS.check_nan_inf:
                 _check_finite(
                     {**new_state, **{n: f for n, f in zip(fetch_names, ys)}}
                 )
             for n, v in new_state.items():
                 scope.set(n, v)
-            for n, v in extras.items():
+            for n, v in created.items():
                 # stacked K copies of a step-created persistable: keep the
                 # last step's value (what the step loop's scope would hold)
                 scope.set(n, jax.tree_util.tree_map(lambda a: a[-1], v))
-            del state  # as in run(): the replaced buffers die in the span
+            del state, donated  # as in run(): they die in the span
         return ys, acc_out

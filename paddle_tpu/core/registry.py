@@ -19,6 +19,8 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 _KERNELS: Dict[str, Callable] = {}
+# op type -> the INPUT slots its kernel rebinds in the env (see register_op)
+_WRITES: Dict[str, Any] = {}
 
 
 class OpContext:
@@ -74,16 +76,38 @@ class OpContext:
         return jax.random.fold_in(key, counter)
 
 
-def register_op(type_name: str) -> Callable:
-    """Decorator: @register_op("mul") def mul_kernel(ctx): ..."""
+def register_op(type_name: str, writes=()) -> Callable:
+    """Decorator: @register_op("mul") def mul_kernel(ctx): ...
+
+    `writes` names the INPUT slots whose variables the kernel rebinds in
+    the env: the reference's in-place in/out pairs (an optimizer op's
+    Param and moments, batch_norm's running statistics), which the op's
+    declared outputs do not show. A callable `writes(op)` returns the
+    slots where they depend on the op's attrs (batch_norm writes nothing
+    under is_test). The Executor donates what a program rebinds, so a
+    kernel that assigns `ctx.env[<input name>]` states it here."""
 
     def deco(fn):
         if type_name in _KERNELS:
             raise ValueError(f"op {type_name!r} already registered")
         _KERNELS[type_name] = fn
+        if writes:
+            _WRITES[type_name] = writes
         return fn
 
     return deco
+
+
+def written_names(op) -> List[str]:
+    """Every variable name `op` may rebind: its declared outputs plus the
+    input slots its kernel registered as written."""
+    names = op.output_names()
+    slots = _WRITES.get(op.type, ())
+    if callable(slots):
+        slots = slots(op)
+    for slot in slots:
+        names.extend(op.inputs.get(slot, ()))
+    return names
 
 
 def get_kernel(type_name: str) -> Callable:

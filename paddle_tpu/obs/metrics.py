@@ -421,6 +421,37 @@ def _quant_families():
     ]
 
 
+def _executor_families():
+    """What the live Executors' step programs donate (core/executor.py
+    `donation_stats`, summed): the buffers and bytes of the persistables
+    a program rebinds, consumed by each step, against those it only
+    reads. The families exist, at 0, from the first Executor on."""
+    import sys
+
+    executor = sys.modules.get("paddle_tpu.core.executor")
+    st = executor.donation_totals() if executor is not None else None
+    if not st:
+        return []
+    return [
+        ("pt_executor_donated_buffers", "gauge",
+         "persistable buffers the compiled step programs donate (rebound "
+         "by the program)", [(None, st["donated_buffers"])]),
+        ("pt_executor_donated_bytes", "gauge",
+         "bytes of the donated persistable buffers",
+         [(None, st["donated_bytes"])]),
+        ("pt_executor_kept_buffers", "gauge",
+         "persistable buffers the compiled step programs only read "
+         "(never donated)", [(None, st["kept_buffers"])]),
+        ("pt_executor_kept_bytes", "gauge",
+         "bytes of the kept persistable buffers",
+         [(None, st["kept_bytes"])]),
+        ("pt_executor_donation_mismatches", "gauge",
+         "persistables a step rebound though no op names them as written "
+         "(left undonated: a kernel lacks register_op(writes=...))",
+         [(None, st["mismatches"])]),
+    ]
+
+
 def _statset_families():
     """The global StatSet rides the unified render even though it is
     not attach_stat_set'ed (reset_metrics would drop the attachment;
@@ -446,4 +477,5 @@ _REGISTRY.add_collector(_faults_families)
 _REGISTRY.add_collector(_trace_families)
 _REGISTRY.add_collector(_tune_families)
 _REGISTRY.add_collector(_quant_families)
+_REGISTRY.add_collector(_executor_families)
 _REGISTRY.add_collector(_statset_families)

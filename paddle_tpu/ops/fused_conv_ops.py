@@ -333,7 +333,7 @@ def _update_running(ctx, bmean, bvar):
         momentum * var_v + (1 - momentum) * bvar)
 
 
-@register_op("fused_conv_bn")
+@register_op("fused_conv_bn", writes=("Mean", "Variance"))
 def fused_conv_bn_kernel(ctx):
     """1x1 conv (NHWC, optional spatial-subsample stride) with fused
     previous-BN prologue and own-BN stats epilogue. Outputs the RAW conv
@@ -392,7 +392,7 @@ def fused_conv_bn_kernel(ctx):
     ctx.set_output("BatchInv", binv)
 
 
-@register_op("bn_stats")
+@register_op("bn_stats", writes=("Mean", "Variance"))
 def bn_stats_kernel(ctx):
     """Stats-only half of batch_norm (NHWC): one reduce pass emitting
     batch mean/inv + the running-stat update; the normalize is applied

@@ -143,7 +143,10 @@ def pool2d_kernel(ctx):
 
 
 # ------------------------------------------------------------ batch norm ---
-@register_op("batch_norm")
+@register_op(
+    "batch_norm",
+    writes=lambda op: () if op.attrs.get("is_test") else ("Mean", "Variance"),
+)
 def batch_norm_kernel(ctx):
     """Reference: paddle/operators/batch_norm_op.cc. Train mode computes
 

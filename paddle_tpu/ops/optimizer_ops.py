@@ -31,7 +31,7 @@ def _lr(ctx):
     return jnp.reshape(lr, ()) if hasattr(lr, "shape") else lr
 
 
-@register_op("sgd")
+@register_op("sgd", writes=("Param",))
 def sgd_kernel(ctx):
     """Reference: sgd_op.cc — p -= lr * g. SelectedRows grads (embedding
     is_sparse) apply as a row-wise scatter-add, touching only gathered rows
@@ -45,7 +45,7 @@ def sgd_kernel(ctx):
     _write(ctx, "Param", p - _lr(ctx) * g)
 
 
-@register_op("momentum")
+@register_op("momentum", writes=("Param", "Velocity"))
 def momentum_kernel(ctx):
     """Reference: momentum_op.cc — supports use_nesterov."""
     p, g, v = ctx.input("Param"), ctx.input("Grad"), ctx.input("Velocity")
@@ -71,7 +71,7 @@ def momentum_kernel(ctx):
     _write(ctx, "Param", p_new)
 
 
-@register_op("adagrad")
+@register_op("adagrad", writes=("Param", "Moment"))
 def adagrad_kernel(ctx):
     """Reference: adagrad_op.cc — moment += g²; p -= lr*g/(√moment+ε).
 
@@ -93,7 +93,7 @@ def adagrad_kernel(ctx):
     _write(ctx, "Param", p_new)
 
 
-@register_op("adadelta")
+@register_op("adadelta", writes=("Param", "AvgSquaredGrad", "AvgSquaredUpdate"))
 def adadelta_kernel(ctx):
     """Reference: adadelta_op.cc."""
     p, g = ctx.input("Param"), ctx.input("Grad")
@@ -109,7 +109,7 @@ def adadelta_kernel(ctx):
     _write(ctx, "Param", p + update)
 
 
-@register_op("rmsprop")
+@register_op("rmsprop", writes=("Param", "MeanSquare", "Moment"))
 def rmsprop_kernel(ctx):
     """Reference: rmsprop_op.cc — with momentum term."""
     p, g = ctx.input("Param"), ctx.input("Grad")
@@ -125,7 +125,7 @@ def rmsprop_kernel(ctx):
     _write(ctx, "Param", p - mom_new)
 
 
-@register_op("decayed_adagrad")
+@register_op("decayed_adagrad", writes=("Param", "Moment"))
 def decayed_adagrad_kernel(ctx):
     """Reference: decayed_adagrad_op.cc."""
     p, g, m = ctx.input("Param"), ctx.input("Grad"), ctx.input("Moment")
@@ -136,7 +136,7 @@ def decayed_adagrad_kernel(ctx):
     _write(ctx, "Param", p - _lr(ctx) * g / (jnp.sqrt(m_new) + eps))
 
 
-@register_op("adam")
+@register_op("adam", writes=("Param", "Moment1", "Moment2", "Beta1Pow", "Beta2Pow"))
 def adam_kernel(ctx):
     """Reference: adam_op.cc — bias-corrected via Beta1Pow/Beta2Pow state."""
     p, g = ctx.input("Param"), ctx.input("Grad")
@@ -171,7 +171,7 @@ def adam_kernel(ctx):
     _write(ctx, "Param", p_new)
 
 
-@register_op("adamax")
+@register_op("adamax", writes=("Param", "Moment", "InfNorm", "Beta1Pow"))
 def adamax_kernel(ctx):
     """Reference: adamax_op.cc."""
     p, g = ctx.input("Param"), ctx.input("Grad")
@@ -190,7 +190,7 @@ def adamax_kernel(ctx):
     _write(ctx, "Param", p_new)
 
 
-@register_op("ftrl")
+@register_op("ftrl", writes=("Param", "SquaredAccumulator", "LinearAccumulator"))
 def ftrl_kernel(ctx):
     """Reference: ftrl_op.cc."""
     p, g = ctx.input("Param"), ctx.input("Grad")
@@ -216,7 +216,7 @@ def ftrl_kernel(ctx):
     _write(ctx, "Param", p_new)
 
 
-@register_op("average_accumulate")
+@register_op("average_accumulate", writes=("Sum", "Count", "Total"))
 def average_accumulate_kernel(ctx):
     """Sliding-window parameter accumulation for ModelAverage.
 
@@ -250,7 +250,7 @@ def lr_schedule_kernel(ctx):
     ctx.set_output("Out", sched(step, ctx.attr("base_lr")))
 
 
-@register_op("proximal_gd")
+@register_op("proximal_gd", writes=("Param",))
 def proximal_gd_kernel(ctx):
     """Reference: proximal_gd_op.cc — l1/l2-regularized SGD step."""
     p, g = ctx.input("Param"), ctx.input("Grad")
@@ -285,7 +285,7 @@ def prune_mask_init_kernel(ctx):
     ctx.set_output("Out", mask.reshape(w.shape))
 
 
-@register_op("apply_mask")
+@register_op("apply_mask", writes=("Param",))
 def apply_mask_kernel(ctx):
     """Reference: ParameterUpdaterHook.cpp:86 StaticPruningHook::update —
     re-apply the static mask after every optimizer step."""

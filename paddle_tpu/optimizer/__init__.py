@@ -583,7 +583,13 @@ class ModelAverage:
     window of parameter values via a restarting accumulator: the window
     length is clamp(average_window_rate * num_updates, min_average_window,
     max_average_window), matching the reference's semantics. `apply()`
-    swaps averaged values in, `restore()` swaps them back — for eval."""
+    swaps averaged values in, `restore()` swaps them back — for eval.
+
+    Between the two this object holds the live parameter arrays. Run
+    only evaluation programs there: they rebind nothing, so the Executor
+    donates nothing and the held arrays are alive at `restore()`; a
+    training step in between would consume (donate) the averaged values
+    and `restore()` would put the older parameters back over its update."""
 
     def __init__(
         self,

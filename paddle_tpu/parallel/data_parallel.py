@@ -32,7 +32,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..core.executor import Executor
+from ..core.executor import Executor, rebound_persistables
 from ..core.lod import LoDArray
 from ..core.program import Program
 from .mesh import DP, make_mesh
@@ -229,6 +229,23 @@ class ParallelExecutor(Executor):
                            scope=scope_, return_numpy=return_numpy,
                            as_numpy=as_numpy)
 
+    def _place_kept(self, program: Program, scope, kept):
+        """The step returns only the persistables it rebinds, so the ones
+        it only reads (a learning rate, every parameter of an inference
+        program) never come back placed: put them on the mesh here and
+        leave them in the scope, or every run would reshard them anew.
+        A value already on this mesh was placed here or by a step."""
+        for name, val in kept.items():
+            if getattr(getattr(val, "sharding", None), "mesh", None) \
+                    == self.mesh:
+                continue
+            if self._multiprocess or not isinstance(val, jax.Array):
+                val = np.asarray(val)
+            kept[name] = jax.device_put(
+                val, self._state_sharding(program, name))
+            scope.set(name, kept[name])
+        return kept
+
     def _cache_key_prefix(self) -> tuple:
         return ("par", id(self.mesh))
 
@@ -236,20 +253,28 @@ class ParallelExecutor(Executor):
         return self.mesh
 
     def _compile(self, program: Program, feed, fetch_names, persist_names):
-        base = Executor._build(
-            self, program, sorted(feed), fetch_names, persist_names
-        )
-        raw = base.__wrapped__  # the untraced block-walk callable
-        state_shardings = {
-            n: self._state_sharding(program, n) for n in persist_names
+        raw = self._raw_step(program, fetch_names)
+        rebound = rebound_persistables(program)
+        donated_shardings = {
+            n: self._state_sharding(program, n)
+            for n in persist_names if n in rebound
+        }
+        kept_shardings = {
+            n: self._state_sharding(program, n)
+            for n in persist_names if n not in rebound
         }
         feed_shardings = {k: self._feed_sharding(v) for k, v in feed.items()}
+        # the donated state comes back under the shardings it went in
+        # with, so every output aliases its input; the extras (what the
+        # split could not know) are left to the compiler
         return jax.jit(
             raw,
             in_shardings=(
-                state_shardings,
+                donated_shardings,
+                kept_shardings,
                 feed_shardings,
                 NamedSharding(self.mesh, PartitionSpec()),
             ),
-            out_shardings=(None, state_shardings),
+            out_shardings=(None, donated_shardings, None),
+            donate_argnums=(0,),
         )

@@ -27,7 +27,7 @@ from .elastic import (  # noqa: F401
     declare_reshard_counter,
     load_checkpoint_resharded,
     reshard_scope_to_mesh,
-    snapshot_scope_refs,
+    snapshot_scope,
     submit_sharded_save,
 )
 from .partition import (  # noqa: F401
@@ -47,6 +47,6 @@ __all__ = [
     "declare_reshard_counter",
     "load_checkpoint_resharded",
     "reshard_scope_to_mesh",
-    "snapshot_scope_refs",
+    "snapshot_scope",
     "submit_sharded_save",
 ]

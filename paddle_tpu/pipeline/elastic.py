@@ -9,12 +9,16 @@ service.go:346):
    run on the thread every process blocks on. Single-process (one
    controller driving the whole mesh — this framework's normal TPU
    topology), there are no barriers, so the commit can ride the
-   trainer's `_CheckpointWriter` double buffer. The snapshot trick:
-   jax.Array is immutable, so capturing *references* pins this step's
-   values with near-zero submit latency — the device→host copy of each
-   unique shard (`np.asarray(shard.data)`) happens on the writer thread,
-   not the step loop. The step loop blocks only when the PREVIOUS commit
-   is still in flight (the submit/drain contract tests assert).
+   trainer's `_CheckpointWriter` double buffer. The snapshot owns its
+   values: one jitted copy of the persistable tree at submit (one
+   dispatch, no host sync; a second state's worth of device memory
+   for as long as the commit is in flight), because a training step
+   donates the buffers it rebinds and the scope's arrays are dead the
+   moment the next step dispatches. The device→host copy of each
+   unique shard (`np.asarray(shard.data)`) happens on the writer
+   thread, not the step loop. The step loop blocks only when the
+   PREVIOUS commit is still in flight (the submit/drain contract tests
+   assert).
 
 2. **Resume-with-resharding.** `sharded_meta.json` records global
    shapes plus the slice each shard covers, so the loader can assemble
@@ -23,10 +27,6 @@ service.go:346):
    chip count). `reshard_scope_to_mesh` is the explicit placement step;
    the save-time world is recorded so a cross-world restore is
    observable (`pt_ckpt_reshard_total`).
-
-Caveat: reference snapshots require the executor NOT to donate state
-buffers (donate_state=False, the default everywhere in the trainer
-path) — a donated buffer is dead the moment the next step dispatches.
 """
 
 from __future__ import annotations
@@ -34,6 +34,8 @@ from __future__ import annotations
 import logging
 from typing import Any, Dict, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import io
@@ -63,22 +65,30 @@ def count_reshard() -> None:
     obs.registry().counter_inc(RESHARD_COUNTER, help=_RESHARD_HELP)
 
 
-def snapshot_scope_refs(
+@jax.jit
+def _copy_tree(tree):
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
+def snapshot_scope(
     main_program: Optional[Program] = None,
     scope: Optional[Scope] = None,
 ) -> Scope:
-    """Reference-only snapshot of the persistable slice of the scope.
-
-    No device round-trip: jax.Array immutability means holding the
-    reference IS the snapshot. The returned Scope is safe to serialize
-    from another thread while training continues overwriting the live
-    scope's *bindings* (never the captured arrays)."""
+    """A snapshot of the persistable slice of the scope that owns its
+    values: the device arrays are copied on the device by one jitted
+    call (enqueued behind the steps already dispatched, so the values
+    are this step's, with no host sync), host values are held as they
+    are. The returned Scope is safe to serialize from another thread
+    while training goes on donating the live scope's buffers."""
     program = main_program or default_main_program()
     scope = scope or global_scope()
+    values = {v.name: scope.get(v.name)
+              for v in program.persistables() if scope.has(v.name)}
+    on_device = {n: v for n, v in values.items() if isinstance(v, jax.Array)}
+    values.update(_copy_tree(on_device))
     snap = Scope()
-    for v in program.persistables():
-        if scope.has(v.name):
-            snap.set(v.name, scope.get(v.name))
+    for n, v in values.items():
+        snap.set(n, v)
     return snap
 
 
@@ -92,13 +102,12 @@ def submit_sharded_save(
 ) -> None:
     """Hand a sharded checkpoint commit to a `_CheckpointWriter`-style
     background writer (submit/drain double buffer). Blocks only on an
-    in-flight previous commit; the capture itself is reference-only.
+    in-flight previous commit; the capture is one device-side copy
+    (`snapshot_scope`).
 
     Multi-process saves must stay on the training thread (their
     barriers deadlock if even one process commits from a side thread) —
     callers gate on jax.process_count()==1; this re-checks loudly."""
-    import jax
-
     if jax.process_count() > 1:
         raise NotImplementedError(
             "background sharded commit is single-process only: the "
@@ -106,7 +115,7 @@ def submit_sharded_save(
             "process is blocking on (CheckpointConfig(background=False) "
             "for multi-process sharded saves)")
     program = main_program or default_main_program()
-    snap = snapshot_scope_refs(program, scope)
+    snap = snapshot_scope(program, scope)
     writer.submit(lambda: io.save_checkpoint(
         checkpoint_dir,
         trainer_args=trainer_args,
@@ -118,8 +127,6 @@ def submit_sharded_save(
 
 
 def current_world() -> Dict[str, int]:
-    import jax
-
     return {
         "device_count": int(jax.device_count()),
         "process_count": int(jax.process_count()),
@@ -138,7 +145,6 @@ def reshard_scope_to_mesh(
     ZeRO re-slice of optimizer state is re-derived by the next
     ParallelExecutor step from ITS mesh — exactly why the checkpoint
     stores global arrays, not placement. Returns vars placed."""
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
     if mesh is None:
@@ -181,8 +187,6 @@ def gather_handoff_rows(arrays, rows: int):
     for the handoff, mirroring the scheduler's one-fence step loop);
     mesh-sharded prefix outputs all-gather here, which IS the reshard:
     the decode replica re-places from host onto its own devices."""
-    import jax
-
     host = jax.device_get(tuple(arrays))
     return tuple(np.asarray(a)[:rows] for a in host)
 
@@ -195,8 +199,6 @@ def restore_handoff_rows(arrays, mesh=None, batch_axis: str = "dp"):
     dynamic-update owns distribution); without one, a plain device_put.
     A cross-world restore is observable via the same counter the
     checkpoint path increments."""
-    import jax
-
     if mesh is None:
         return tuple(jax.device_put(np.asarray(a)) for a in arrays)
     from jax.sharding import NamedSharding, PartitionSpec

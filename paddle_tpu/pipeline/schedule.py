@@ -71,7 +71,7 @@ SCHEDULES = ("gpipe", "1f1b")
 class PipelineExecutor(Executor):
     """Executor that runs training programs as a K-stage, M-microbatch
     pipeline. Same `run()` / `run_window()` surface as the base Executor
-    (the `_raw_step` override keeps the (state, feed, seed) signature,
+    (the `_raw_step` override keeps the (donated, kept, feed, seed) signature,
     so the Trainer's fused scan windows compose: a window is a scan over
     steps of a scan over ticks). Programs without an `autodiff` op
     (inference, startup) fall through to the unstaged base path.
@@ -91,9 +91,8 @@ class PipelineExecutor(Executor):
         num_microbatches: int = 4,
         mesh=None,
         schedule: str = "gpipe",
-        donate_state: bool = False,
     ):
-        super().__init__(place, donate_state)
+        super().__init__(place)
         if int(num_stages) < 1:
             raise ValueError(f"num_stages must be >= 1, got {num_stages}")
         if int(num_microbatches) < 1:
@@ -162,21 +161,20 @@ class PipelineExecutor(Executor):
         return hit[1]
 
     # -- the staged step ------------------------------------------------
-    def _raw_step(self, program: Program, fetch_names, persist_names):
+    def _raw_step(self, program: Program, fetch_names):
         has_autodiff = any(
             op.type == "autodiff" for op in program.global_block().ops)
         if not has_autodiff:
             # inference / startup / eval programs run unstaged
-            return super()._raw_step(program, fetch_names, persist_names)
+            return super()._raw_step(program, fetch_names)
         self._dispatched = True
         staged = self._staged(program, fetch_names)
-        return self._staged_step(
-            program, staged, list(fetch_names), list(persist_names))
+        return self._staged_step(program, staged, list(fetch_names))
 
-    def _staged_step(self, program, staged, fetch_names, persist_names):
+    def _staged_step(self, program, staged, fetch_names):
         runner = _BlockRunner(program)
         block = program.global_block()
-        all_persist = {v.name for v in program.persistables()}
+        record = self._donation_record()
         K = staged.num_stages
         M = self.num_microbatches
         T = M + K - 1
@@ -221,7 +219,9 @@ class PipelineExecutor(Executor):
             return lax.with_sharding_constraint(
                 x, NamedSharding(mesh, PartitionSpec(*spec_list)))
 
-        def raw(state: Dict[str, Any], feed: Dict[str, Any], seed):
+        def raw(donated: Dict[str, Any], kept: Dict[str, Any],
+                feed: Dict[str, Any], seed):
+            state = {**kept, **donated}
             for n, v in feed.items():
                 if isinstance(v, LoDArray):
                     raise NotImplementedError(
@@ -432,12 +432,8 @@ class PipelineExecutor(Executor):
                         "step (forward activations, persistables and tail "
                         "outputs are fetchable)")
                 fetches.append(env[n])
-            new_state = {
-                n: env[n]
-                for n in set(persist_names) | (all_persist & set(env))
-                if n in env
-            }
-            return fetches, new_state
+            return (fetches,) + self._state_outputs(
+                program, env, donated, kept, record)
 
         return raw
 

@@ -30,7 +30,7 @@ The step path's spans (thread; where; what the block covers):
 | `prepareBatchData` | trainer | `Trainer._step_pass` | in-loop `DataFeeder` (executors that place their own input) |
 | `forwardBackward` | trainer | `_step_pass`, `_scan_pass` | the whole of `Executor.run` / `run_window` |
 | `executor.prepare` | trainer | `Executor.run` / `run_window` | entry to just before the jitted call: feed normalisation, persistables scan, cache key and lookup, state gather, seed, `_place_inputs` |
-| `executor.call` | trainer | around `fn(state, feed, seed)` | the jitted call as Python sees it, and anything that blocks inside it (the first call of a shape traces and compiles here) |
+| `executor.call` | trainer | around `fn(donated, kept, feed, seed)` | the jitted call as Python sees it, and anything that blocks inside it (the first call of a shape traces and compiles here) |
 | `executor.commit` | trainer | after the call to return | `check_nan_inf`, `scope.set` of every new state buffer and the release of the buffers they replace, `as_numpy` |
 | `accumUpdate` | trainer | around `acc.update(...)` | the step's second dispatch (`accum_fold`) |
 | `hostSync` | trainer | `_host_read_step`, `_PassStats.sync` | the periodic d2h read of the accumulator |

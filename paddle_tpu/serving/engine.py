@@ -807,6 +807,7 @@ class ServingEngine:
                 "dispatches_total": self.dispatches_total,
                 "syncs_total": self.syncs_total,
                 "executor_cache": dict(self.exe.cache_stats),
+                "executor_donation": self.exe.donation_stats,
                 "buckets": {
                     "batch": list(self.policy.batch_buckets),
                     "seq_len": list(self.policy.seq_len_buckets),

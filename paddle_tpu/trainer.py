@@ -415,8 +415,9 @@ class CheckpointConfig:
         # background=True hands the disk commit to a writer thread over a
         # device_get snapshot, so the step loop stalls only for the d2h
         # copy, not the serialization+fsync. Single-process sharded saves
-        # background too, via a reference-only snapshot (jax.Array is
-        # immutable) whose d2h happens on the writer thread. Multi-process
+        # background too, via a device-side copy of the state (the steps
+        # that follow donate the live buffers) whose d2h happens on the
+        # writer thread. Multi-process
         # sharded saves stay synchronous: their cross-process barriers
         # must run on the thread every process is blocking on.
         self.background = background
@@ -1170,10 +1171,10 @@ class Trainer:
                 and jax.process_count() == 1:
             # single-process sharded saves have no cross-process barriers,
             # so the commit rides the writer-thread double buffer. The
-            # snapshot is reference-only (jax.Array is immutable), so
-            # submit latency is the drain of the PREVIOUS commit plus
-            # dict-building — the d2h copy of each unique shard happens
-            # on the writer thread (pipeline/elastic.py)
+            # snapshot is one device-side copy of the state, so submit
+            # latency is the drain of the PREVIOUS commit plus one
+            # dispatch — the d2h copy of each unique shard happens on
+            # the writer thread (pipeline/elastic.py)
             from .pipeline import elastic
 
             with profiler.timer("checkpointSnapshot"):

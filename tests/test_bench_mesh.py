@@ -160,9 +160,10 @@ def test_dp_scaling_efficiency_floor(monkeypatch):
     persist = sorted(v.name for v in prog.persistables()
                      if scope.has(v.name))
     fn = exe._compile(prog, cfg["feed"], [cfg["loss"].name], persist)
-    state = {n: scope.get(n) for n in persist}
+    donated, kept = exe._split_state(
+        prog, {n: scope.get(n) for n in persist})
     with exe._device_context(), exe._trace_context():
-        text = fn.lower(state, cfg["feed"],
+        text = fn.lower(donated, kept, cfg["feed"],
                         jnp.uint32(0)).compile().as_text()
     assert f"f32[{T},{B // 8},{4 * H}]" in text   # per-shard scan input
     assert f"f32[{T},{B},{4 * H}]" not in text    # never the global batch

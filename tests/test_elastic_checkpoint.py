@@ -2,10 +2,10 @@
 
 Three contracts:
   * background sharded commit blocks only on an IN-FLIGHT previous
-    commit — the capture is reference-only (jax.Array immutability is
-    the snapshot), so submit latency is independent of model size and
-    the values committed are the values at submit time even if training
-    keeps mutating the scope;
+    commit — the capture is one device-side copy of the state (no d2h,
+    no host sync), and the values committed are the values at submit
+    time even though the training steps that follow donate the live
+    scope's buffers;
   * resume-with-resharding — a dp8-saved checkpoint restores
     bit-identically onto a dp4x2 mesh and onto a 4-device mesh (the
     sharded format stores GLOBAL arrays, placement is re-derived);
@@ -63,7 +63,7 @@ def _host_params():
 
 def test_submit_blocks_only_on_inflight_commit(tmp_path, monkeypatch):
     """The acceptance assertion: with a slow commit in flight, a fresh
-    submit returns immediately (reference capture, no d2h, no disk);
+    submit returns immediately (a device-side copy, no d2h, no disk);
     the NEXT submit drains the in-flight one first (double buffer)."""
     loss = _build()
     exe = pt.Executor()
@@ -125,6 +125,55 @@ def test_snapshot_isolated_from_continued_training(tmp_path):
     got = _host_params()
     for n, v in at_submit.items():
         np.testing.assert_array_equal(v, got[n], err_msg=n)
+
+
+def test_snapshot_survives_the_steps_dispatched_after_it(tmp_path,
+                                                         monkeypatch):
+    """A training step donates the buffers it rebinds, so the arrays the
+    scope held at submit are dead one step later: the snapshot owns a
+    copy, and a commit whose d2h starts after five more steps have been
+    dispatched still writes step k's values."""
+    loss = _build()
+    exe = pt.Executor()
+    exe.run_startup(pt.default_startup_program())
+    for step in range(3):
+        exe.run(feed=_feed(step), fetch_list=[loss])
+    # copies: on the CPU `np.asarray` is a view that pins its buffer,
+    # and a pinned buffer is (safely) not donated
+    at_submit = {n: np.array(v) for n, v in _host_params().items()}
+    live = {n: pt.global_scope().get(n) for n in at_submit}
+
+    real_save = pio.save_checkpoint
+    started = []
+
+    def late_save(*a, **kw):
+        time.sleep(0.3)  # the five steps below are dispatched meanwhile
+        started.append(time.monotonic())
+        return real_save(*a, **kw)
+
+    monkeypatch.setattr(pio, "save_checkpoint", late_save)
+    writer = _CheckpointWriter()
+    d = str(tmp_path / "ck")
+    elastic.submit_sharded_save(writer, d, trainer_args={"step": 3},
+                                main_program=pt.default_main_program())
+    for step in range(3, 8):
+        exe.run(feed=_feed(step), fetch_list=[loss], as_numpy=False)
+    dispatched = time.monotonic()
+    # every array the program rebinds is gone from under a reference
+    # snapshot; only the learning rate is still the object it was
+    dead = {n for n, a in live.items() if a.is_deleted()}
+    assert {"w1", "w2"} <= dead and len(dead) == len(live) - 1
+    writer.drain()
+    assert writer.commits == 1 and writer.failures == 0
+    assert started and started[0] > dispatched - 0.25
+    after_training = _host_params()
+
+    pt.reset_global_scope()
+    assert pio.load_checkpoint(d, pt.default_main_program()) == {"step": 3}
+    got = _host_params()
+    for n, v in at_submit.items():
+        np.testing.assert_array_equal(v, got[n], err_msg=n)
+    assert not np.array_equal(after_training["w1"], at_submit["w1"])
 
 
 def test_submit_refuses_multiprocess(monkeypatch):

@@ -109,9 +109,14 @@ def test_span_nesting_and_context_args():
 def test_spans_never_cross_threads():
     """Each thread's spans land in its own ring with its own tid; the
     per-thread context never leaks to another thread."""
+    # all four alive at once: a thread that ended before the next started
+    # would hand it its ident (seen under a loaded 6-worker run)
+    together = threading.Barrier(4)
+
     def work(n):
         obs_trace.set_context(worker=n)
         with obs_trace.span(f"w{n}", cat="t"):
+            together.wait(timeout=30)
             time.sleep(0.002)
 
     with obs_trace.tracing() as tr:

@@ -231,9 +231,10 @@ def test_compiled_step_carries_op_scopes_forward_and_transposed():
     trainer.exe.run(prog, feed=feed, fetch_list=[trainer.cost],
                     scope=trainer.scope)
     (fn,) = [f for p, f in trainer.exe._cache.values() if p is prog]
-    state = {v.name: trainer.scope.get(v.name) for v in prog.persistables()
-             if trainer.scope.has(v.name)}
-    text = fn.lower(state, feed, jnp.uint32(0)).compile().as_text()
+    donated, kept = trainer.exe._split_state(prog, {
+        v.name: trainer.scope.get(v.name) for v in prog.persistables()
+        if trainer.scope.has(v.name)})
+    text = fn.lower(donated, kept, feed, jnp.uint32(0)).compile().as_text()
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
     muls = [op.outputs["Out"][0] for op in prog.blocks[0].ops
             if op.type == "mul"]
