@@ -48,8 +48,6 @@ class _Window:
         self.intervals = []       # seconds per step, one per fenced read
         self.costs = []           # the fenced reads inside the window
         self.bad_intervals = 0
-        self.feed_wait_s = 0.0    # EndIteration -> next BeginIteration
-        self._last_end = None
         self._gap_ann = self._step_ann = None
         self.at_open = self.at_close = None
 
@@ -76,7 +74,8 @@ class _Window:
         return {"dispatches": t.host_dispatch_count, "syncs": t.host_sync_count,
                 "programs_built": self.ctx.clock.built,
                 "cache_misses": self.ctx.clock.misses,
-                "timers": {k: v["total"] for k, v in stats.items()}}
+                "timers": {k: v["total"] for k, v in stats.items()},
+                "registry": registry_snapshot()}
 
     def _read(self, event, name="chipbench.cost_read"):
         a = self._ann(name)
@@ -91,8 +90,6 @@ class _Window:
         if isinstance(event, BeginIteration):
             self._close(self._gap_ann)
             self._gap_ann = None
-            if self.t0 is not None and self._last_end is not None:
-                self.feed_wait_s += time.perf_counter() - self._last_end
             self._step_ann = self._ann("chipbench.prepare_and_dispatch")
             return
         if not isinstance(event, EndIteration):
@@ -104,10 +101,8 @@ class _Window:
             self._warmup_step(event)
         else:
             self._window_step(event)
-        if self.t1 is None:
-            self._last_end = time.perf_counter()
-            if self.t0 is not None:
-                self._gap_ann = self._ann("chipbench.wait_for_batch")
+        if self.t1 is None and self.t0 is not None:
+            self._gap_ann = self._ann("chipbench.wait_for_batch")
 
     def _warmup_step(self, event):
         if self.steps == 1:
@@ -141,6 +136,51 @@ class _Window:
             self.trainer.stop()
 
 
+def registry_snapshot(text=None):
+    """Every series of the program's one metrics registry
+    (`obs.metrics.registry()`), flat: `{series: [kind, value]}` with the
+    series as Prometheus writes it (`name` or `name{label="v",...}`) and kind
+    `counter` or `gauge`. Read from the registry's own rendering, its public
+    face and the only one that holds the render-time collectors
+    (`pt_executor_*`, the faults, the timers). A histogram gives its `_count`
+    and `_sum` (as counters) and its quantile gauges; its buckets are left
+    out."""
+    if text is None:
+        from paddle_tpu.obs import metrics
+
+        text = metrics.registry().render()
+    kinds, out = {}, {}
+    for line in text.splitlines():
+        if line.startswith("# TYPE "):
+            _, _, family, kind = line.split(" ", 3)
+            kinds[family] = kind.strip()
+            continue
+        if not line or line.startswith("#"):
+            continue
+        series, _, value = line.rpartition(" ")
+        family = series.partition("{")[0]
+        kind = kinds.get(family)
+        if kind is None:    # a histogram's own samples
+            base, _, part = family.rpartition("_")
+            if kinds.get(base) != "histogram" or part == "bucket":
+                continue
+            kind = "counter"
+        try:
+            out[series] = [kind, float(value)]
+        except ValueError:
+            continue
+    return out
+
+
+def registry_delta(at_open, at_close):
+    """What the window did to the registry: a counter's close minus open (a
+    series born inside the window opened at 0), a gauge's value at the
+    close."""
+    return {series: value - at_open.get(series, (kind, 0.0))[1]
+            if kind == "counter" else value
+            for series, (kind, value) in at_close.items()}
+
+
 # The plain reference (`configs/<config>/reference.py`: float32
 # `jax.numpy`, matmuls at the highest precision) is held to the system's
 # first step on the same weights and the same batch, in set-up:
@@ -160,9 +200,78 @@ class _Window:
 #    the chip, PR 23, 29 runs, 6 seeds); the bound is four times that.
 # The tiny models of the CPU rehearsal are looser in both: their cell's
 # `rehearsal` block carries its own tolerances, read only in a rehearsal.
+#
+# Gradients behind a discrete choice. A routed layer sends each token to
+# the k experts its router scores highest, and rounding (bf16 AMP against
+# the float32 reference) turns a near tie between the k-th and the (k+1)-th
+# the other way. Such a token's share of the gradient then lands in another
+# expert: not a wrong backward pass, and not within 0.05. The experiment
+# (`tests/test_harness.py`, one OLMoE-shaped block in plain `jax.numpy`, 64
+# experts, top-8, bf16 matmul inputs against float32 at `highest`; CPU, PR
+# 26, 3 seeds x 2 routers) reads, for a share s of tokens whose expert set
+# differs (0.008-0.031), 0.035-0.077 on the expert stacks, the router and
+# the norm weight before them, where the dense tensors read 0.003-0.006, and
+# on the worst expert stack err^2 / s = 0.169-0.193 every time. That is the arithmetic: a flipped token takes one
+# of its k pair-contributions out and puts one in, 2 s N of the N k that
+# make up the gradient, each at about 0.84 of the mean gate weight (the
+# tie sits at the low end of the top k): err^2 = 0.84^2 x (2 / k) x s =
+# 0.176 s at k = 8. Which tokens flip cannot be read from outside the
+# program, but how many can is in the reference's own float32 router: bf16
+# rounding of both matmul inputs moves a logit by about 0.0023 of the rms
+# of a token's logits and a gap by 0.0032 of it, so s = the share of tokens
+# whose k-th gap is under 1.3 x 2^-9 of that rms (0.025, 0.039, 0.041 so
+# counted where 0.025, 0.031, 0.022 flipped with the router in bf16 too). The rule: a configuration names in
+# `config.json` (`routed_parameters`: `names`, fnmatch patterns on the
+# program's parameter names; `top_k`; `reason`) the parameters whose
+# gradient flows only through a top-k choice, and its `reference.py` gives
+# `router_logits(config, params, feed)` (a list of float32 [tokens,
+# experts], one per routed layer). For those parameters only,
+#     tolerance = min(ROUTED_CAP, sqrt(GRAD_TOL^2 + (2 / top_k) x share))
+# where `share` is the share of tokens whose gap between the k-th and the
+# (k+1)-th logit is under TIE_UNITS x 2^-9 x the rms of the token's centred
+# logits: three times the gap that flips, so about twice the error a sound
+# run reads (0.155-0.176 allowed where 0.038-0.077 was read). No near
+# ties, no allowance; no `router_logits`, share 0. The cap is this file's,
+# not the configuration's: a gradient that is missing or doubled reads 1,
+# one halved 0.5, one handed to the wrong parameter 1.4 (the test shows
+# each failing), so 0.2 leaves a factor 2.5 under the mildest fault. Every
+# parameter that is not named stays at GRAD_TOL, so lower precision in
+# attention, head or embedding still fails. The first cost's 2e-5 stays: a
+# flipped token moves the mean cost by 4e-6 to 5e-5 over 512 tokens in the
+# experiment and 6e-6 at T 256 and half widths (ISSUE 26); a cell's own
+# tokens a step are tens of thousands.
 REFERENCE_TOL = 2e-5
 GRAD_TOL = 0.05
 GRAD_SAMPLE = 65536
+ROUTED_CAP = 0.2
+TIE_UNITS = 4.0
+
+
+def near_tie_share(logits, top_k):
+    """Share of the rows of `logits` ([tokens, experts], float32) whose gap
+    between the `top_k`-th and the next largest is under TIE_UNITS x 2^-9
+    of the rms of the row's centred logits (see above)."""
+    import jax.numpy as jnp
+
+    z = -jnp.sort(-jnp.asarray(logits, jnp.float32), axis=-1)
+    gap = z[:, top_k - 1] - z[:, top_k]
+    scale = jnp.sqrt(jnp.mean(
+        jnp.square(z - z.mean(-1, keepdims=True)), axis=-1))
+    return jnp.mean(gap < TIE_UNITS * 2.0**-9 * scale)
+
+
+def gradient_tolerances(names, config, grad_tol, share):
+    """{parameter: tolerance}: `grad_tol` for every parameter, and the
+    routed rule's for those `config["routed_parameters"]` names."""
+    import fnmatch
+
+    routed = config.get("routed_parameters") or {}
+    allowed = min(max(ROUTED_CAP, grad_tol),
+                  math.sqrt(grad_tol**2 + 2.0 / routed["top_k"] * share)
+                  ) if routed else grad_tol
+    return {n: allowed if any(fnmatch.fnmatchcase(n, pat)
+                              for pat in routed.get("names", ()))
+            else grad_tol for n in names}
 
 
 def _sample(x):
@@ -190,7 +299,16 @@ def _reference(ctx, trainer, model):
         return cost, [_sample(g) for g in grads]
 
     cost, grads = jax.jit(cost_and_sampled_grads)(params, first)
-    return {"cost": float(cost), "grads": grads}
+    out = {"cost": float(cost), "grads": grads, "near_tie_share": 0.0}
+    routed = ctx.config.get("routed_parameters")
+    if routed and hasattr(ref, "router_logits"):
+        def share(params, first):
+            layers = ref.router_logits(ctx.config, params, first)
+            return sum(near_tie_share(z, routed["top_k"])
+                       for z in layers) / len(layers)
+
+        out["near_tie_share"] = float(jax.jit(share)(params, first))
+    return out
 
 
 def _gradient_errors(trainer, ref_grads):
@@ -216,17 +334,42 @@ def _gradient_errors(trainer, ref_grads):
     if not names:
         return None
 
-    def rms(x):
-        return jnp.sqrt(jnp.mean(jnp.square(x)))
-
     def errors(moments, refs):
-        ref_rms = jnp.stack([rms(g) for g in refs])
-        diff = jnp.stack([rms(_sample(m).astype(jnp.float32) / s - g)
-                          for m, s, g in zip(moments, scales, refs)])
-        return diff / jnp.maximum(ref_rms, 0.1 * jnp.median(ref_rms))
+        return relative_errors([_sample(m).astype(jnp.float32) / s
+                                for m, s in zip(moments, scales)], refs)
 
     errs = [float(e) for e in jax.jit(errors)(moments, refs)]
     return dict(zip(names, errs))
+
+
+def relative_errors(grads, refs):
+    """Per tensor, rms(gradient - reference) over the reference's rms, the
+    latter no smaller than a tenth of the median tensor's."""
+    import jax.numpy as jnp
+
+    def rms(x):
+        return jnp.sqrt(jnp.mean(jnp.square(x)))
+
+    ref_rms = jnp.stack([rms(g) for g in refs])
+    diff = jnp.stack([rms(g - r) for g, r in zip(grads, refs)])
+    return diff / jnp.maximum(ref_rms, 0.1 * jnp.median(ref_rms))
+
+
+def program_ops(program):
+    """The Program's ops as the readers of the device trace need them:
+    type, inputs and outputs by slot, and `scope`, the `jax.named_scope`
+    `Executor` traces the op under (`core/executor.py:_op_scope`: `<op
+    type>.<first output variable>`, the rule copied here), which is what
+    `xplane.reduce`'s `ops` rows carry as their `scope`."""
+    out = []
+    for block in program.blocks:
+        for op in block.ops:
+            first = next((n for names in op.outputs.values() for n in names), "")
+            out.append({"type": op.type,
+                        "scope": f"{op.type}.{first}" if first else op.type,
+                        "inputs": {k: list(v) for k, v in op.inputs.items()},
+                        "outputs": {k: list(v) for k, v in op.outputs.items()}})
+    return out
 
 
 def run(ctx):
@@ -248,7 +391,12 @@ def run(ctx):
     model = ctx.model.get_model(ctx.config, cell, ctx.seed)
     trainer = Trainer(cost=model["cost"], executor=_executor(cell))
     trainer.init()    # startup: the weights, on the device, from the seed
+    peak_after_startup = ctx.memory_peaks()
     reference = _reference(ctx, trainer, model)
+    # the yardstick's own memory: the plain reference runs in this process,
+    # so what it peaked at is read here and `correct` checks that the steps
+    # went beyond it (else `peak_hbm_gib` would measure the reference)
+    peak_after_reference = ctx.memory_peaks()
     profiler.global_stat_set().reset()
     seconds = min(ctx.seconds, float(cell["trace_seconds"])) if ctx.trace \
         else ctx.seconds
@@ -271,12 +419,17 @@ def run(ctx):
         "first_cost": win.first_cost, "bad_intervals": win.bad_intervals,
         "reference_first_cost": reference["cost"],
         "gradient_errors": win.grad_errors,
+        "near_tie_share": reference["near_tie_share"],
         "tolerances": dict(
             {"reference_tol": REFERENCE_TOL, "grad_tol": GRAD_TOL},
             **(cell["rehearsal"].get("tolerances", {})
                if ctx.rehearsal else {})),
-        "feed_wait_s": win.feed_wait_s,
+        "peak_after_startup": peak_after_startup,
+        "peak_after_reference": peak_after_reference,
         "counters": delta, "timers_s": timers,
+        "registry": registry_delta(win.at_open["registry"],
+                                   win.at_close["registry"]),
+        "program_ops": program_ops(trainer.main_program) if ctx.trace else None,
         "attempted": steps, "failed": win.bad_intervals * sync_every,
     }
 
@@ -291,13 +444,26 @@ def info(run):
             "first_cost": run["first_cost"], "last_cost": run["costs"][-1],
             "reference_first_cost": run["reference_first_cost"],
             "gradient_error_worst": worst and [worst, errs[worst]],
-            "gradient_error_median": errs and sorted(errs.values())[len(errs) // 2]}
+            "gradient_error_median": errs and sorted(errs.values())[len(errs) // 2],
+            "near_tie_share": run["near_tie_share"],
+            "gradient_tolerance_max": max(_tolerances(run).values(),
+                                          default=None),
+            "peak_after_startup": run["peak_after_startup"],
+            "peak_after_reference": run["peak_after_reference"],
+            "peak_final": run.get("memory_peaks")}
+
+
+def _tolerances(run):
+    return gradient_tolerances(run["gradient_errors"] or (), run["config"],
+                               run["tolerances"]["grad_tol"],
+                               run["near_tie_share"])
 
 
 def correct(run):
     """What a train cell owes: finite costs, a loss that fell, a first step
-    that agrees with the plain reference, and nothing built inside the
-    window. Returns a list of what failed (empty = ok)."""
+    that agrees with the plain reference, nothing built inside the window,
+    and a memory peak that the steps set and not the reference. Returns a
+    list of what failed (empty = ok)."""
     bad = []
     costs = run["costs"]
     if not costs or run["bad_intervals"] or not math.isfinite(run["first_cost"]):
@@ -311,10 +477,18 @@ def correct(run):
     if not off <= tol["reference_tol"]:
         bad.append(f"the first cost {run['first_cost']} is off the plain "
                    f"reference's {want} by {off} (> {tol['reference_tol']})")
+    allowed = _tolerances(run)
     for name, err in (run["gradient_errors"] or {}).items():
-        if not err <= tol["grad_tol"]:
+        if not err <= allowed[name]:
             bad.append(f"the first step's gradient of {name} is off the plain "
-                       f"reference's by {err} of its rms (> {tol['grad_tol']})")
+                       f"reference's by {err} of its rms (> {allowed[name]})")
+    before, final = run["peak_after_reference"], run.get("memory_peaks") or {}
+    for book, peak in final.items():
+        if peak and peak <= before.get(book, 0):
+            bad.append(f"the steps never exceeded the plain reference's "
+                       f"memory ({book}: {peak} bytes at the end, "
+                       f"{before[book]} right after the reference): the "
+                       f"peak would measure the yardstick")
     if run["counters"]["programs_built"] or run["counters"]["cache_misses"]:
         bad.append(f"programs were built inside the window: {run['counters']}")
     return bad

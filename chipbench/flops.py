@@ -9,6 +9,7 @@ operations the model needs, not what the compiled step executes.
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 
@@ -41,16 +42,48 @@ _FAMILIES = {
 }
 
 
-def train_flops_per_item(config: dict, cell: dict) -> float:
-    """`config["flops_family"]` names the arithmetic."""
-    return float(_FAMILIES[config["flops_family"]](config, cell))
+def family_arithmetic(config: dict, config_dir: str):
+    """The function that counts `config["flops_family"]`: this file's, for a
+    family it knows (a configuration may not override those), else
+    `train_flops_per_item(config, cell)` of the `flops.py` beside the
+    configuration's `config.json`. Called when the manifest is read, before
+    any backend starts: a family nobody counts is an error that names the
+    file to add."""
+    family = config.get("flops_family")
+    if family in _FAMILIES:
+        return _FAMILIES[family]
+    path = os.path.join(config_dir, "flops.py")
+    if not os.path.isfile(path):
+        raise SystemExit(
+            f"chipbench: flops_family {family!r} is not one of "
+            f"{sorted(_FAMILIES)} and there is no {os.path.relpath(path)} "
+            f"with train_flops_per_item(config, cell)")
+    spec = importlib.util.spec_from_file_location("chipbench_config_flops", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    fn = getattr(mod, "train_flops_per_item", None)
+    if not callable(fn):
+        raise SystemExit(f"chipbench: {os.path.relpath(path)} defines no "
+                         f"train_flops_per_item(config, cell)")
+    return fn
 
 
-def peak_flops(device_kind: str) -> float:
+def train_flops_per_item(config: dict, cell: dict,
+                         config_dir: str = "") -> float:
+    """`config["flops_family"]` names the arithmetic (`family_arithmetic`)."""
+    return float(family_arithmetic(config, config_dir)(config, cell))
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of this kind (`peaks.json`)."""
     with open(os.path.join(_HERE, "peaks.json")) as f:
-        peaks = json.load(f)
-    if device_kind not in peaks or device_kind.startswith("_"):
+        table = json.load(f)
+    if device_kind not in table or device_kind.startswith("_"):
         raise SystemExit(
             f"chipbench: no published peak for device_kind {device_kind!r}; "
             f"add it to chipbench/peaks.json with its source")
-    return float(peaks[device_kind]["bf16_flops"])
+    return table[device_kind]
+
+
+def peak_flops(device_kind: str) -> float:
+    return float(peaks(device_kind)["bf16_flops"])

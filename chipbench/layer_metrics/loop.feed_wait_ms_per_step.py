@@ -2,8 +2,7 @@
 step loop spends getting its batch, measured inside the program:
 `prefetchWait` (the consumer's `q.get()` in `DevicePrefetcher.__iter__`)
 plus `prepareBatchData` (the in-loop `DataFeeder` of executors that place
-their own input). The inside twin of `loop.feed_ms_per_step`, which also
-holds the loop's tail and the handler. Read from the program's own spans:
+their own input). Read from the program's own spans:
 their `profiler.StatSet` totals over the traced window (`run["timers_s"]`),
 over the window's steps. Nothing to read where the program records none of
 them."""

@@ -105,6 +105,27 @@ class Ctx:
         jax.profiler.stop_trace()
 
 
+def memory_peaks(stats) -> dict:
+    """The fullest chip's peaks so far, in bytes, by the runtime's two books
+    and as `peak_hbm_gib` counts them (`bytes`). The v5e runtime keeps
+    `bytes_in_use` (live arrays: parameters, optimizer state, feeds, a
+    step's outputs from its dispatch on) apart from `bytes_reserved` (the
+    running program's scratch), and reports the peak of each, not of their
+    sum. The sum of the two peaks is therefore at or above the true peak; in
+    a training loop it is reached, because every step holds its outputs and
+    its scratch together (gpt2-small: 4.15 + 6.86 = 11.01 GB against 10.85
+    GB by the compiler's own count, PR 23). `in_use` alone (4.15 GB) would
+    leave out what decides whether a batch fits. `stats` is each chip's
+    `memory_stats()`; empty where the backend reports nothing (XLA:CPU)."""
+    mem = [m for m in stats if m.get("peak_bytes_in_use")]
+    if not mem:
+        return {}
+    return {"in_use": max(m["peak_bytes_in_use"] for m in mem),
+            "reserved": max(m.get("peak_bytes_reserved", 0) for m in mem),
+            "bytes": max(m["peak_bytes_in_use"] + m.get("peak_bytes_reserved", 0)
+                         for m in mem)}
+
+
 def _metric_entries(manifest, section, cell_name):
     return [m for m in manifest[section]
             if cell_name in m.get("workloads", [cell_name])]
@@ -135,6 +156,12 @@ def main() -> int:
             print(f"chipbench: workloads/{entry['name']}.json and "
                   f"BENCHMARK.json disagree on {key}", file=sys.stderr)
             return 2
+    # who counts this configuration's FLOPs is settled here, in the first
+    # second, and not after the window: a family that neither flops.py nor
+    # the configuration's own flops.py counts ends the run by name
+    config_dir = os.path.dirname(os.path.join(ROOT, cfg_entry["file"]))
+    flops = load_module(os.path.join(HERE, "flops.py"))
+    flops_per_item = flops.family_arithmetic(config, config_dir)
     seconds = args.seconds if args.seconds is not None \
         else float(manifest["run_seconds"])
     if args.rehearse_cpu:
@@ -178,12 +205,15 @@ def main() -> int:
     log(f"cell {entry['name']} seed {args.seed} seconds {seconds} trace "
         f"{args.trace} on {device}; compile cache {cache_dir}")
 
-    config_dir = os.path.dirname(os.path.join(ROOT, cfg_entry["file"]))
+    def memory_stats():
+        return [d.memory_stats() or {} for d in devs[:cell["chips"]]]
+
     driver = load_module(os.path.join(HERE, "drivers", config["driver"] + ".py"))
     ctx = Ctx(name=entry["name"], cell=cell, config=config, seed=args.seed,
               seconds=seconds, trace=bool(args.trace),
               rehearsal=args.rehearse_cpu,
               clock=CompileClock(),
+              memory_peaks=lambda: memory_peaks(memory_stats()),
               load_module=load_module,
               model=load_module(os.path.join(config_dir, "model.py")))
     run = driver.run(ctx)
@@ -193,20 +223,10 @@ def main() -> int:
                setup_s=run["t0_wall"] - _T_START,
                compile_s=ctx.clock.seconds, cache_hits=ctx.clock.hits,
                cache_misses=ctx.clock.misses)
-    # the fullest chip's peak, as an upper bound. The v5e runtime keeps
-    # two books: `bytes_in_use` (live arrays: parameters, optimizer state,
-    # feeds, a step's outputs from its dispatch on) and `bytes_reserved`
-    # (the running program's scratch), and reports the peak of each, not of
-    # their sum. The sum of the two peaks is therefore at or above the true
-    # peak; in a training loop it is reached, because every step holds its
-    # outputs and its scratch together (gpt2-small: 4.15 + 6.86 = 11.01 GB
-    # against 10.85 GB by the compiler's own count, PR 23). `in_use` alone
-    # (4.15 GB) would leave out what decides whether a batch fits.
-    mem = [d.memory_stats() or {} for d in devs[:cell["chips"]]]
-    run["memory_stats"] = mem
-    run["memory_peak_bytes"] = max(
-        (m["peak_bytes_in_use"] + m.get("peak_bytes_reserved", 0)
-         for m in mem if m.get("peak_bytes_in_use")), default=None)
+    run["memory_stats"] = memory_stats()
+    run["memory_peaks"] = memory_peaks(run["memory_stats"])
+    run["memory_peak_bytes"] = run["memory_peaks"].get("bytes")
+    run["device"] = device
 
     if ctx.trace_dir:
         xplane = load_module(os.path.join(HERE, "xplane.py"))
@@ -220,7 +240,7 @@ def main() -> int:
 
     section = "per_layer" if args.trace else "end_to_end"
     folder = "layer_metrics" if args.trace else "end_to_end"
-    metrics, problems = {}, driver.correct(run)
+    metrics, problems, notes = {}, driver.correct(run), {}
     for m in _metric_entries(manifest, section, entry["name"]):
         reader = load_module(os.path.join(HERE, folder, m["name"] + ".py"))
         try:
@@ -231,6 +251,8 @@ def main() -> int:
             return 4
         if value is None:
             continue   # nothing to read in this cell: left out of the line
+        if hasattr(reader, "info"):   # what the reader adds to the info line
+            notes[m["name"]] = reader.info(run)
         key = (REHEARSAL_PREFIX if args.rehearse_cpu else "") + m["name"]
         metrics[key] = {"value": float(value), "unit": m["unit"]}
 
@@ -238,14 +260,13 @@ def main() -> int:
     info = {"cell": entry["name"], "setup_s": run["setup_s"],
             "compile_or_cache_read_s": run["compile_s"],
             "cache_hits": run["cache_hits"], "cache_misses": run["cache_misses"],
-            **driver.info(run),
+            **driver.info(run), **notes,
             "memory_stats_fullest": max(
                 run["memory_stats"],
                 key=lambda m: m.get("peak_bytes_in_use", 0)
                 + m.get("peak_bytes_reserved", 0))}
     if not args.rehearse_cpu:
-        flops = load_module(os.path.join(HERE, "flops.py"))
-        per_item = flops.train_flops_per_item(config, cell)
+        per_item = float(flops_per_item(config, cell))
         rate = run["items"] / run["window_s"]
         peak = flops.peak_flops(device["kind"])
         info["model_flops_per_item"] = per_item

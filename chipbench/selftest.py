@@ -5,13 +5,21 @@
 
 1. the trace reduction (`xplane.reduce`) on made-up traces (overlapping and
    nested events, the idle share, the custom-call share, collectives, the
-   division by steps, the idle gaps' labels) and on a small trace recorded
-   on a TPU v5e in PR 23 (`testdata/gpt2_two_steps.trace.json.gz`: two
-   steps of gpt2-small.train cut from a traced run's `xplane.load`);
+   division by steps, the idle gaps' labels, the op table and its scopes)
+   and on two small traces recorded on a TPU v5e, each two steps of
+   gpt2-small.train cut from a traced run's `xplane.load`:
+   `testdata/gpt2_two_steps.trace.json.gz` (PR 23) and
+   `testdata/gpt2_two_steps_scoped.trace.json.gz` (PR 26: with the events'
+   `op_names` and the Program's ops, so the readers by scope are checked
+   against recorded totals);
 2. the percentile rule (refuses fewer than 100 intervals);
 3. the manifest: BENCHMARK.json's names, units and limits, and that every
-   cell resolves to a workload file, a configuration, a driver, and a
-   reader for each of its metrics.
+   cell resolves to a workload file, a configuration with someone to count
+   its FLOPs, a driver, and a reader for each of its metrics;
+4. `roofline.share` on a hand-made kernel, the window's registry deltas on a
+   made-up snapshot, the wire-format reader on a hand-made `XSpace`.
+
+`tests/test_harness.py` holds what needs JAX or pytest.
 """
 
 from __future__ import annotations
@@ -174,6 +182,162 @@ def recorded_trace_reduces_to_the_recorded_numbers():
 
 
 @check
+def scopes_from_op_names():
+    assert xplane.scope_of(
+        "jit(raw)/transpose(jvp(mul.fc_394.tmp_395))/dot_general:") == (
+        "mul.fc_394.tmp_395", "transpose(jvp")
+    assert xplane.scope_of(
+        "jit(raw)/jvp(flash_attention.tfm.h8.attn.tmp_275)/"
+        "jit(flash_attention)/pallas_call:") == (
+        "flash_attention.tfm.h8.attn.tmp_275", "jvp")
+    assert xplane.scope_of("jit(raw)/adam.tfm.tok_emb/sub:") == (
+        "adam.tfm.tok_emb", "")
+    assert xplane.scope_of("jit(accum_fold)/add") == ("", "")
+    assert xplane.scope_of("") == ("", "")
+
+
+@check
+def op_table_has_one_row_per_instruction():
+    host = [("chipbench.cost_read", 0, 1000)]
+    ops = [(WHILE, 0, 900), (FUSION, 100, 200), (FUSION, 400, 100),
+           (PALLAS, 500, 50), (CONCAT, 600, 10), (FUSION, 950, 100)]
+    trace = _trace(ops, host)
+    trace["planes"][0]["op_names"] = {
+        FUSION: "jit(raw)/transpose(jvp(mul.fc_1.tmp_0))/dot_general:",
+        PALLAS: "jit(raw)/flash_attention.a.tmp_1/jit(flash_attention)/"
+                "pallas_call:"}
+    r = xplane.reduce(trace)
+    rows = {row["name"]: row for row in r["ops"]}
+    assert len(r["ops"]) == 4 and r["ops"][0]["name"] == "%while.11"
+    assert rows["%while.11"]["container"] and rows["%while.11"]["ns"] == 900
+    f = rows["%fusion.10"]
+    assert (f["count"], f["ns"], f["opcode"], f["shape"]) == (
+        3, 350, "fusion", "bf16[128000,2048]")        # the last one clipped
+    assert (f["scope"], f["transform"], f["target"]) == (
+        "mul.fc_1.tmp_0", "transpose(jvp", None)
+    k = rows["%flash.1"]
+    assert (k["target"], k["scope"], k["transform"]) == (
+        "tpu_custom_call", "flash_attention.a.tmp_1", "")
+    c = rows["%custom-call.67"]
+    assert (c["target"], c["scope"], c["op_name"]) == ("ConcatBitcast", "", "")
+    leaves = [row for row in r["ops"] if not row["container"]]
+    assert sum(row["ns"] for row in leaves) == r["planes"][0]["busy_ns"] == 410
+
+
+def _varint(n):
+    out = b""
+    while n >= 0x80:
+        out += bytes([n & 0x7F | 0x80])
+        n >>= 7
+    return out + bytes([n])
+
+
+def _field(number, value):
+    if isinstance(value, int):
+        return _varint(number << 3) + _varint(value)
+    return _varint(number << 3 | 2) + _varint(len(value)) + value
+
+
+@check
+def op_names_from_the_wire_format():
+    stat_meta = [_field(5, _field(1, i) + _field(2, _field(1, i) + _field(2, n)))
+                 for i, n in ((1, b"flops"), (2, b"tf_op"),
+                              (3, b"jit(raw)/adam.w/sub:"))]
+
+    def event_meta(i, name, stats):
+        return _field(4, _field(1, i) + _field(2, _field(1, i) + _field(
+            2, name) + b"".join(_field(5, st) for st in stats)))
+
+    plane = _field(2, b"/device:TPU:0") + b"".join(stat_meta)
+    plane += _field(3, _field(2, b"XLA Ops") + _field(4, _field(1, 7)))
+    plane += event_meta(7, b"%a = f32[8] fusion()", [
+        _field(1, 1) + _field(3, 99),
+        _field(1, 2) + _field(5, b"jit(raw)/mul.x.tmp_0/dot_general:")])
+    plane += event_meta(8, b"%b = f32[8] fusion()", [
+        _field(1, 2) + _field(7, 3)])                 # a ref_value
+    plane += event_meta(9, b"%c = f32[8] copy()", [
+        _field(1, 1) + b"\x11" + b"\0" * 8])          # a double, no tf_op
+    space = _field(1, plane) + _field(1, _field(2, b"/host:CPU"))
+    assert xplane.event_op_names(space) == {"/device:TPU:0": {
+        "%a = f32[8] fusion()": "jit(raw)/mul.x.tmp_0/dot_general:",
+        "%b = f32[8] fusion()": "jit(raw)/adam.w/sub:"}}
+
+
+@check
+def recorded_scoped_trace_reduces_to_the_recorded_numbers():
+    path = os.path.join(HERE, "testdata", "gpt2_two_steps_scoped.trace.json.gz")
+    with gzip.open(path) as f:
+        trace = json.load(f)
+    r = xplane.reduce(trace, chips=1)
+    p = r["planes"][0]
+    # as reduced when the trace was cut (PR 26, TPU v5 lite, seed 2147486001)
+    assert p["op_events"] == 14496 and p["busy_ns"] == 387688522, p
+    assert p["custom_call_ns"] == 80484130, p
+    leaves = [row for row in r["ops"] if not row["container"]]
+    assert len(r["ops"]) == 7356 and len(leaves) == 7356   # no loop in it
+    # no two ops overlap on this device, so the leaf rows' plain sum is the
+    # busy union
+    assert sum(row["ns"] for row in leaves) == p["busy_ns"]
+    assert sum(row["count"] for row in leaves) == p["op_events"]
+    assert sum(row["ns"] for row in leaves if not row["scope"]) == 13126749
+    assert sum(row["ns"] for row in leaves
+               if row["transform"].startswith("transpose")) == 167345999
+    with open(os.path.join(HERE, "configs", "gpt2-small", "config.json")) as f:
+        config = json.load(f)
+    run = {"steps": 2, "trace": r, "program_ops": trace["program_ops"],
+           "config": config, "cell": {"batch": 12, "seqlen": 1024},
+           "device": {"kind": "TPU v5 lite"}}
+
+    def metric(name):
+        return _load(os.path.join(HERE, "layer_metrics", name + ".py")
+                     ).compute(run)
+    assert abs(metric("step.device_ms") - 193.844261) < 1e-9
+    assert abs(metric("head.device_ms") - 52.5517205) < 1e-9
+    assert abs(metric("opt.device_ms") - 1.709014) < 1e-9
+    assert abs(metric("kernel.flash_roofline") - 8.785213093442836) < 1e-9
+
+
+@check
+def roofline_share_on_a_hand_made_kernel():
+    from chipbench import roofline
+
+    # 197 TFLOP and 1 byte in 2 s: half the compute roof
+    assert roofline.share(197e12, 1.0, 2.0, "TPU v5 lite") == (50.0, "compute")
+    # 819 GB and 1 FLOP in 4 s: a quarter of the memory roof
+    assert roofline.share(1.0, 819e9, 4.0, "TPU v5 lite") == (25.0, "memory")
+    # a count that is too high shows: never clamped
+    assert roofline.share(197e12, 819e9, 0.5, "TPU v5 lite")[0] == 200.0
+    try:
+        roofline.share(1.0, 1.0, 1.0, "TPU v9")
+    except SystemExit:
+        pass
+    else:
+        raise AssertionError("a share on an unknown device")
+
+
+@check
+def registry_deltas_on_a_made_up_snapshot():
+    train = _load(os.path.join(HERE, "drivers", "train.py"))
+    text = ("# TYPE pt_tokens_total counter\n"
+            'pt_tokens_total{expert="0"} 5\npt_tokens_total{expert="1"} 7\n'
+            "# TYPE pt_executor_donated_bytes gauge\n"
+            "pt_executor_donated_bytes 1960000000\n"
+            "# TYPE pt_lat histogram\n"
+            'pt_lat_bucket{le="+Inf"} 3\npt_lat_sum 0.5\npt_lat_count 3\n')
+    before = train.registry_snapshot(text)
+    after = train.registry_snapshot(
+        text.replace("} 5", "} 9").replace("pt_lat_count 3", "pt_lat_count 4")
+        + "# TYPE pt_dropped_total counter\npt_dropped_total 2\n")
+    assert train.registry_delta(before, after) == {
+        'pt_tokens_total{expert="0"}': 4.0, 'pt_tokens_total{expert="1"}': 0.0,
+        "pt_executor_donated_bytes": 1960000000.0, "pt_lat_sum": 0.0,
+        "pt_lat_count": 1.0, "pt_dropped_total": 2.0}
+    reader = _load(os.path.join(HERE, "layer_metrics", "step.donated_gib.py"))
+    got = reader.compute({"registry": train.registry_delta(before, after)})
+    assert abs(got - 1960000000.0 / 2**30) < 1e-12
+
+
+@check
 def a_trace_without_a_device_is_an_error():
     try:
         xplane.reduce({"planes": [{"name": "/host:CPU", "lines": []}]})
@@ -219,6 +383,7 @@ def _line(text, where):
 def manifest_keeps_the_contract():
     path = os.path.join(ROOT, "BENCHMARK.json")
     assert os.path.getsize(path) <= 64 * 1024
+    flops = _load(os.path.join(HERE, "flops.py"))
     with open(path) as f:
         m = json.load(f)
     assert set(m) == {"command", "paths", "run_seconds", "configs",
@@ -246,8 +411,14 @@ def manifest_keeps_the_contract():
         assert isinstance(cfg, dict) and cfg["reduced"] == c["reduced"]
         assert cfg["source"] == c["source"], c["name"]
         assert os.path.exists(os.path.join(HERE, "drivers", cfg["driver"] + ".py"))
-        assert os.path.exists(os.path.join(
-            os.path.dirname(os.path.join(ROOT, c["file"])), "model.py"))
+        config_dir = os.path.dirname(os.path.join(ROOT, c["file"]))
+        assert os.path.exists(os.path.join(config_dir, "model.py"))
+        assert os.path.exists(os.path.join(config_dir, "reference.py"))
+        # someone counts its FLOPs: flops.py or the configuration's own
+        assert callable(flops.family_arithmetic(cfg, config_dir)), c["name"]
+        routed = cfg.get("routed_parameters")
+        assert routed is None or (
+            routed["names"] and routed["top_k"] >= 1 and routed["reason"])
     assert len({c["file"] for c in m["configs"]}) == len(m["configs"])
     cells, pairs, used = set(), set(), set()
     for w in m["workloads"]:
@@ -318,6 +489,23 @@ def flops_arithmetic():
     assert flops.train_flops_per_item(g, {"seqlen": 1024}) == 797815296.0
     assert flops.train_flops_per_item(r, {"seqlen": 100}) == 20447232.0
     assert flops.peak_flops("TPU v5 lite") == 197e12
+    # a family flops.py does not know comes with its configuration; without
+    # the file the refusal names the family and the file to add
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        own = {"flops_family": "moe_lm", "active": 3}
+        try:
+            flops.family_arithmetic(own, d)
+        except SystemExit as e:
+            assert "'moe_lm'" in str(e) and "flops.py" in str(e), e
+        else:
+            raise AssertionError("an unknown family with no flops.py")
+        with open(os.path.join(d, "flops.py"), "w") as f:
+            f.write("def train_flops_per_item(config, cell):\n"
+                    "    return 2.0 * config['active'] * cell['seqlen']\n")
+        assert flops.train_flops_per_item(own, {"seqlen": 4}, d) == 24.0
+        # a configuration may not override a family flops.py knows
+        assert flops.train_flops_per_item(g, {"seqlen": 1024}, d) == 797815296.0
     try:
         flops.peak_flops("TPU v9")
     except SystemExit:

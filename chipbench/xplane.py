@@ -1,14 +1,30 @@
 """From the profiler's trace (`*.xplane.pb`) to numbers, with nothing but
-`jax.profiler.ProfileData`.
+`jax.profiler.ProfileData` and, for the one thing it does not hand out (the
+stats of an event's metadata, where the compiler's `op_name` lives), forty
+lines that read the protobuf's wire format.
 
 Two stages, so that the second can be checked without a chip
 (`selftest.py`, `testdata/`):
 
 1. `load(path)` -> `{"planes": [{"name", "lines": [{"name", "events":
-   [[name, start_ns, duration_ns], ...]}]}]}` — plain lists.
+   [[name, start_ns, duration_ns], ...]}], "op_names": {instruction text:
+   op_name}}]}` — plain lists; `op_names` on device planes only.
 2. `reduce(trace, chips)` -> busy union, idle share, custom-call and
    collective time per device plane, the ten device ops with most time,
-   and the idle gaps by what the host was doing.
+   the idle gaps by what the host was doing, and `ops`: the first device's
+   whole op table, one row per distinct instruction (see `op_table`).
+
+Where an op's scope comes from (looked at on the chip in PR 26, jax 0.9.0,
+TPU v5 lite): `ProfileData`'s events on `XLA Ops` carry three stats
+(`device_offset_ps`, `device_duration_ps`, `Time Scale Multiplier`) and no
+name of the op that made them. The file does hold it: every event points at
+an `XEventMetadata` of its plane whose name is the instruction's text and
+whose stats include `tf_op` = the HLO metadata's `op_name`, such as
+`jit(raw)/transpose(jvp(mul.fc_394.tmp_395))/dot_general:` (beside
+`hlo_category`, `flops`, `bytes_accessed`, `source`). `event_op_names`
+reads exactly that, so no HLO text of the compiled step is needed. Async
+copies, slices and XLA's own `ConcatBitcast` custom-calls have no `tf_op`:
+their rows have the scope "".
 
 What a TPU v5e trace looks like (read by hand in PR 23, jax 0.9.0): one
 plane per chip named `/device:TPU:<n>` with the lines `Steps`, `XLA
@@ -43,7 +59,10 @@ CONTAINERS = ("while", "conditional", "call")
 def load(path: str) -> dict:
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    with open(path, "rb") as f:
+        buf = f.read()
+    data = ProfileData.from_serialized_xspace(buf)
+    op_names = event_op_names(buf)
     planes = []
     for plane in data.planes:
         lines = []
@@ -53,7 +72,85 @@ def load(path: str) -> dict:
                 events.append([ev.name, int(ev.start_ns), int(ev.duration_ns)])
             lines.append({"name": line.name, "events": events})
         planes.append({"name": plane.name, "lines": lines})
+        if plane.name.startswith(DEVICE_PLANE):
+            planes[-1]["op_names"] = op_names.get(plane.name, {})
     return {"planes": planes}
+
+
+def _varint(buf, i):
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf, lo, hi):
+    """One protobuf message's fields: (number, value) with a varint's value,
+    or the (start, end) of a length-delimited field; fixed-width fields are
+    passed over."""
+    i = lo
+    while i < hi:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+            yield key >> 3, value
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            yield key >> 3, (i, i + n)
+            i += n
+        elif wire in (1, 5):
+            i += 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"wire type {wire} in an xplane.pb")
+
+
+def event_op_names(buf: bytes, stat: str = "tf_op") -> dict:
+    """{plane name: {event metadata name: its `tf_op` stat}} of a serialized
+    `XSpace`, for the planes that have any. Field numbers (tsl's
+    xplane.proto): XSpace.planes 1; XPlane.name 2, .event_metadata 4,
+    .stat_metadata 5 (maps: key 1, value 2); XEventMetadata.name 2, .stats
+    5; XStatMetadata.id 1, .name 2; XStat.metadata_id 1, .str_value 5,
+    .ref_value 7 (a string kept as a stat metadata's name). The lines and
+    their events are passed over unread."""
+    def text(span):
+        return buf[span[0]:span[1]].decode("utf-8", "replace")
+
+    out = {}
+    for number, plane in _fields(buf, 0, len(buf)):
+        if number != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for number, span in _fields(buf, *plane):
+            if number == 2:
+                name = text(span)
+            elif number == 4:
+                events += [v for k, v in _fields(buf, *span) if k == 2]
+            elif number == 5:
+                for k, v in _fields(buf, *span):
+                    if k == 2:
+                        meta = dict(_fields(buf, *v))
+                        stat_names[meta.get(1)] = text(meta[2]) if 2 in meta else ""
+        found = {}
+        for span in events:
+            meta_name, op_name = "", None
+            for number, value in _fields(buf, *span):
+                if number == 2:
+                    meta_name = text(value)
+                elif number == 5:
+                    st = dict(_fields(buf, *value))
+                    if stat_names.get(st.get(1)) == stat:
+                        op_name = text(st[5]) if 5 in st \
+                            else stat_names.get(st.get(7), "")
+            if op_name:
+                found[meta_name] = op_name
+        if found:
+            out[name] = found
+    return out
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -131,6 +228,61 @@ def is_custom_call(name: str) -> bool:
             and 'custom_call_target="tpu_custom_call"' in name)
 
 
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_WRAPPED = re.compile(r"^((?:[A-Za-z_]+\()*)([^()]*)\)*$")
+
+
+def scope_of(op_name: str):
+    """(scope, transform) of an HLO `op_name` such as
+    `jit(raw)/transpose(jvp(mul.fc_394.tmp_395))/dot_general:` ->
+    (`mul.fc_394.tmp_395`, `transpose(jvp`): the first element of the path
+    that is not a `jit(...)`, which is the `jax.named_scope` the program
+    put on the op (`Executor`: `<op type>.<first output>`), without the
+    transformations JAX wrapped it in. `jvp(` alone is the forward half of
+    a differentiated op, `transpose(` its backward half. ("", "") where
+    there is no such element."""
+    parts = op_name.rstrip(":").split("/")
+    for part in parts[:-1] if len(parts) > 1 else parts:
+        if part.startswith(("jit(", "pjit(")):
+            continue
+        m = _WRAPPED.match(part)
+        if m:
+            return m.group(2), m.group(1).rstrip("(")
+        return part, ""
+    return "", ""
+
+
+def op_table(ranked: list, kinds: dict, op_names: dict) -> list:
+    """One row per distinct instruction of `ranked` ([(instruction text,
+    [count, ns])], already cut to the window and longest first):
+
+    name       the instruction's name, `%fusion.10`
+    opcode     the HLO opcode, `fusion`
+    shape      the first output's type and shape, `bf16[128000,2048]`
+    target     a custom-call's `custom_call_target`, else None
+    container  True for `while` / `conditional` / `call`: its ns cover its
+               body's ops, which have rows of their own; every sum over
+               device time leaves containers out
+    count, ns  events inside the window and their summed duration there
+    op_name    the compiler's `op_name` for it, "" where it has none
+    scope, transform   `scope_of(op_name)`
+    """
+    rows = []
+    for text, (count, ns) in ranked:
+        inst, _, rest = text.partition(" = ")
+        shape = _SHAPE.match(rest)
+        target = _TARGET.search(text) if opcode(text) == "custom-call" else None
+        op_name = op_names.get(text, "").rstrip(":")
+        scope, transform = scope_of(op_name) if op_name else ("", "")
+        rows.append({"name": inst.split(" ")[0][:96], "opcode": opcode(text),
+                     "shape": shape.group(1) if shape else "",
+                     "target": target.group(1) if target else None,
+                     "container": kinds[text][0], "count": count, "ns": ns,
+                     "op_name": op_name, "scope": scope,
+                     "transform": transform})
+    return rows
+
+
 def _annotations(trace):
     """(annotations, host): the host events this harness wrote, and every
     event of the threads that wrote them (JAX's own among them:
@@ -204,7 +356,7 @@ def reduce(trace: dict, chips: int = 1) -> dict:
                                is_collective(name))
         return k
 
-    planes, op_time = [], {}
+    planes, op_rows = [], {}   # first device: instruction -> [count, ns]
     for p, events in zip(device_planes, ops_by_plane):
         leaf, cust, coll = [], [], []
         for name, start, dur, *_ in events:
@@ -213,9 +365,11 @@ def reduce(trace: dict, chips: int = 1) -> dict:
                 continue
             container, kernel, collective = kind(name)
             if p is device_planes[0]:
-                # the breakdown lists loops too: a loop's time is its
-                # body's ops (also listed) plus the gaps between them
-                op_time[name] = op_time.get(name, 0) + (e - s)
+                # the table lists loops too: a loop's time is its body's
+                # ops (also listed) plus the gaps between them
+                row = op_rows.setdefault(name, [0, 0])
+                row[0] += 1
+                row[1] += e - s
             if container:
                 continue
             leaf.append((s, e))
@@ -235,13 +389,15 @@ def reduce(trace: dict, chips: int = 1) -> dict:
         if s > prev:
             gaps.append((prev, s))
         prev = max(prev, e)
+    ranked = sorted(op_rows.items(), key=lambda kv: -kv[1][1])
     top = [(short(n) + (" (body included)" if kinds[n][0] else ""), t)
-           for n, t in sorted(op_time.items(), key=lambda kv: -kv[1])[:10]]
+           for n, (_, t) in ranked[:10]]
     idle = sorted(_label_gaps(gaps, host).items(), key=lambda kv: -kv[1])[:10]
     return {
         "window_s": window / 1e9,
         "busy_s_mean": sum(p["busy_ns"] for p in planes) / len(planes) / 1e9,
         "planes": planes,
+        "ops": op_table(ranked, kinds, device_planes[0].get("op_names", {})),
         "breakdown": {
             "device_ops": [[n, t / 1e9] for n, t in top],
             "idle_gaps": [[n, t / 1e9] for n, t in idle],
