@@ -127,7 +127,10 @@ def accum_fold(state, cost, metrics, skip_nonfinite):
     pass metrics, not just equal params).
 
     state: (n_good, cost_sum, [metric_sums...], n_bad) — int32/float32
-    scalars. skip_nonfinite (StepGuard armed) gates a non-finite step's
+    scalars; a metric sum may also be a vector, of any dtype (a Program's
+    step statistics, `Program.add_step_statistic`: int32 counts), and the
+    step's value is then added in the sum's own shape and dtype.
+    skip_nonfinite (StepGuard armed) gates a non-finite step's
     cost/metrics out of the stats; the `bad` counter is what the guard
     reads on its sync cadence."""
     n, cost_sum, metric_sums, bad = state
@@ -137,7 +140,8 @@ def accum_fold(state, cost, metrics, skip_nonfinite):
     n = n + good.astype(jnp.int32)
     cost_sum = cost_sum + jnp.where(good, c, 0.0)
     metric_sums = [
-        m + jnp.where(good, jnp.reshape(jnp.asarray(v, jnp.float32), ()), 0.0)
+        m + jnp.where(good, jnp.reshape(jnp.asarray(v, m.dtype), m.shape),
+                      jnp.zeros((), m.dtype))
         for m, v in zip(metric_sums, metrics)
     ]
     bad = bad + (~finite).astype(jnp.int32)

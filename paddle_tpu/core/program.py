@@ -185,6 +185,22 @@ class Program:
         # rematerialization policy for the backward pass (None = XLA default);
         # see core/executor.py _run_autodiff and pt.memory_optimize
         self.remat_policy: Optional[str] = None
+        # per-step statistics its layers registered (add_step_statistic)
+        self.step_statistics: List[dict] = []
+
+    def add_step_statistic(self, var, counter: str, labels=None,
+                           index_label: str = "index", help: str = "") -> None:
+        """A layer's per-step count that should reach the metrics registry
+        (`obs.metrics.registry()`) from inside the compiled step: `var` (an
+        integer vector the step computes) is summed over steps into the
+        labelled counter family `counter`, element i under `labels` plus
+        {index_label: i}. The Trainer fetches it in the step's own
+        `Executor.run`, folds it on the device in the accumulator dispatch
+        it already issues and publishes it at the host syncs it already
+        pays (trainer.py:_PassStats); a program with none runs as before."""
+        self.step_statistics.append({
+            "var": var.name, "counter": counter, "labels": dict(labels or {}),
+            "index_label": index_label, "help": help})
 
     def set_amp(self, dtype: Optional[str] = "bfloat16") -> None:
         """Enable/disable bf16 mixed-precision compute for MXU ops.

@@ -17,6 +17,8 @@ from .misc import *  # noqa: F401,F403
 from .misc import __all__ as _misc_all
 from .generation import *  # noqa: F401,F403
 from .generation import __all__ as _gen_all
+from .moe import *  # noqa: F401,F403
+from .moe import __all__ as _moe_all
 from .nn import *  # noqa: F401,F403
 from .nn import __all__ as _nn_all
 from .recurrent import *  # noqa: F401,F403
@@ -26,5 +28,5 @@ from .sequence import __all__ as _seq_all
 
 __all__ = (
     list(_nn_all) + list(_seq_all) + list(_att_all) + list(_crf_all)
-    + list(_ctc_all) + list(_misc_all) + list(_det_all) + list(_rec_all) + list(_gen_all) + list(_cf_all)
+    + list(_ctc_all) + list(_misc_all) + list(_det_all) + list(_rec_all) + list(_gen_all) + list(_cf_all) + list(_moe_all)
 )

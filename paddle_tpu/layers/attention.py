@@ -32,14 +32,23 @@ def multi_head_attention(
     param_attr=None,
     bias_attr=None,
     name=None,
+    qk_norm: bool = False,
+    rotary_theta: Optional[float] = None,
+    rms_eps: float = 1e-5,
 ):
     """Transformer multi-head attention over dense [B, T, E] inputs
     (self-attention when key/value are None). Beyond the 2017 reference's
     layer set — the modern long-context workhorse; compute routes through
     the flash-attention dispatcher (ops/flash_ops.py: fused O(T)-memory
     Pallas kernel on TPU, jnp reference elsewhere). Q/K/V/O projections
-    are `fc` layers so AMP/sharding apply as everywhere else."""
-    from .nn import fc
+    are `fc` layers so AMP/sharding apply as everywhere else.
+
+    qk_norm: RMSNorm (learned scale, `rms_eps`) on the Q and K projections
+    over the whole E, before the split into heads (OLMoE's form).
+    rotary_theta: rotary position embedding of that base on Q and K, after
+    the norm. Both off by default, and then the ops appended are exactly
+    those of a layer without them."""
+    from .nn import fc, rms_norm, rotary_embedding
 
     is_cross = key is not None or value is not None
     if is_cross and causal:
@@ -66,6 +75,16 @@ def multi_head_attention(
                            param_attr=_derive(param_attr, s),
                            bias_attr=_derive(bias_attr, f"{s}_b"))
     q, k, v = proj(query, "wq"), proj(key, "wk"), proj(value, "wv")
+    if qk_norm:
+        # the norms' scales start at one whatever initialiser the caller
+        # gave the projections: only the derived name is taken over
+        q = rms_norm(q, epsilon=rms_eps, name=f"{helper.name}.q_norm",
+                     param_attr=_derive(param_attr, "q_norm").name)
+        k = rms_norm(k, epsilon=rms_eps, name=f"{helper.name}.k_norm",
+                     param_attr=_derive(param_attr, "k_norm").name)
+    if rotary_theta:
+        q = rotary_embedding(q, num_heads, rotary_theta)
+        k = rotary_embedding(k, num_heads, rotary_theta)
     out = helper.create_tmp_variable(query.dtype, query.shape)
     helper.append_op(
         type="flash_attention",

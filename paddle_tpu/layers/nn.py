@@ -29,6 +29,8 @@ __all__ = [
     "bn_apply",
     "RawConvBN",
     "layer_norm",
+    "rms_norm",
+    "rotary_embedding",
     "dropout",
     "cross_entropy",
     "softmax_with_cross_entropy",
@@ -505,6 +507,40 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-5, n
         inputs=inputs,
         outputs={"Y": [out]},
         attrs={"begin_norm_axis": begin_norm_axis, "epsilon": epsilon},
+    )
+    return out
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """RMSNorm over the last axis with a learned scale (ones at the
+    start): x * rsqrt(mean(x^2) + epsilon) * w. Beyond the 2017
+    reference's layer set; the norm of the Llama / OLMo families. The
+    output is float32 under amp too (ops/nn_ops.py:rms_norm)."""
+    helper = LayerHelper("rms_norm", name=name)
+    w = helper.create_parameter(
+        param_attr, (int(input.shape[-1]),),
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(np.float32, input.shape)
+    helper.append_op(
+        type="rms_norm",
+        inputs={"X": [input], "Scale": [w]},
+        outputs={"Y": [out]},
+        attrs={"epsilon": epsilon},
+    )
+    return out
+
+
+def rotary_embedding(x, num_heads: int, theta: float = 10000.0, name=None):
+    """Rotary position embedding (Su et al. 2021, rotate-half convention)
+    on a packed multi-head projection [B, T, E]: position t rotates each
+    head's (i, i + D/2) lane pair by t * theta^(-2i/D). No parameters."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_tmp_variable(x.dtype, x.shape)
+    helper.append_op(
+        type="rotary_embedding",
+        inputs={"X": [x]},
+        outputs={"Out": [out]},
+        attrs={"num_heads": num_heads, "theta": float(theta)},
     )
     return out
 

@@ -12,6 +12,7 @@ from . import control_flow_ops  # noqa: F401
 from . import cost_ops  # noqa: F401
 from . import crf_ops  # noqa: F401
 from . import misc_ops  # noqa: F401
+from . import moe_ops  # noqa: F401
 from . import ctc_ops  # noqa: F401
 from . import detection_ops  # noqa: F401
 from . import generation_ops  # noqa: F401

@@ -212,7 +212,12 @@ def flash_attention_kernel(ctx):
     """Program-IR face of the dispatcher: Q/K/V are [B, T, E] packed
     multi-head projections; num_heads splits E. Used by
     layers.multi_head_attention (models/transformer.py)."""
-    q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
+    from .. import amp
+
+    # under amp Q and K may arrive float32 (from rms_norm / rotary, which
+    # emit float32); the kernel's io is the amp dtype, like V's
+    q, k, v = amp.cast_inputs(ctx, ctx.input("Q"), ctx.input("K"),
+                              ctx.input("V"))
     heads = ctx.attr("num_heads")
     causal = ctx.attr("causal", True)
     B, T, E = q.shape

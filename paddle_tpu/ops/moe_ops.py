@@ -1,0 +1,232 @@
+"""Routed experts: a dropless top-k mixture of SwiGLU experts, and the
+grouped matmul under it.
+
+Reference lineage: the 2017 reference has no routed layer (its nearest
+kin is the MixedLayer's per-input projections); this is the sparse-expert
+FFN of OLMoE / Mixtral / DeepSeek as MegaBlocks computes it (Gale et al.
+2022): no capacity factor and no dropped token. Every (token, slot) pair
+is sorted by expert, the experts' matmuls run as ONE grouped matmul over
+ragged groups, and the rows go back weighted by their gates.
+
+Compute path of the grouped matmul (`grouped_matmul`): on TPU, JAX's
+Pallas megablox kernel (jax.experimental.pallas.ops.tpu.megablox.gmm —
+public JAX library code, used the way flash_ops.py uses JAX's flash
+kernel) with its custom VJP (two more grouped kernels: gmm against the
+transposed weights, tgmm for the weights' gradient); anywhere else, or
+when the kernel's shape rules fail, `jax.lax.ragged_dot`. The oracle for
+both is `grouped_matmul_reference` (one row at a time against its own
+group's matrix).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .. import amp
+from ..core.registry import register_op
+
+
+def group_ids(group_sizes, m: int):
+    """Row -> group for `m` rows laid out group after group."""
+    ends = jnp.cumsum(group_sizes)
+    return jnp.searchsorted(ends, jnp.arange(m), side="right")
+
+
+def grouped_matmul_reference(lhs, rhs, group_sizes):
+    """out[i] = lhs[i] @ rhs[group of row i], plain jnp: the numerical
+    oracle of both formulations below (small sizes only: it gathers one
+    matrix per row)."""
+    gid = group_ids(group_sizes, lhs.shape[0])
+    return jnp.einsum("mk,mkn->mn", lhs, rhs[gid],
+                      preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+# Tiling (tm, tk, tn) of the megablox kernels on a v5e, by the chip (PERF.md
+# section 6, PR 27; 32768 rows, 64 groups, d 2048, f 1024, bf16): the
+# library's all-128 default runs at 9 TFLOP/s (14.6 ms a forward matmul
+# where this takes 1.5), as it left flash at 0.6x of XLA in round 2. One
+# tuple serves the forward gmm, the backward's gmm against the transposed
+# weights and its tgmm, because the library's custom VJP hands the same one
+# to all three: (256, 1024, 1024) reads 1.53 / 2.75 ms forward / backward
+# on gate and up and 1.40 / 3.29 on down, (512, 1024, 1024) 1.61 / 3.18 and
+# 1.54 / 3.53, (256, 2048, 512) wins gate's forward alone (1.33) and loses
+# everywhere else; tk 2048 with tn 1024, tm 1024 on down, and tn 2048 do
+# not fit VMEM. tm must divide the rows; a tile that straddles a group's
+# boundary is visited once per group, so a small tm wastes less where the
+# routing is uneven.
+_V5E_TILING = (256, 1024, 1024)
+
+
+def _v5e_tiling(m: int, k: int, n: int):
+    def fit(size, want):
+        t = min(size, want)
+        while size % t:
+            t -= 128
+        return t
+
+    return fit(m, _V5E_TILING[0]), fit(k, _V5E_TILING[1]), fit(n, _V5E_TILING[2])
+
+
+def _shapes_gmm_ok(lhs, rhs) -> bool:
+    """Backend-independent shape rules of the kernel: 128-aligned rows,
+    contraction and output widths (lane and sublane tiling; the kernel
+    masks ragged GROUPS, not ragged matrices), bf16 or f32 inputs."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    return (m % 128 == 0 and k % 128 == 0 and n % 128 == 0
+            and lhs.dtype == rhs.dtype
+            and lhs.dtype in (jnp.bfloat16, jnp.float32))
+
+
+def gmm_eligible(lhs, rhs) -> bool:
+    from . import mesh_dispatch
+
+    # a bare pallas_call cannot be GSPMD-partitioned: under a mesh the XLA
+    # formulation keeps the job (expert parallelism is ROADMAP Queue 2)
+    return (jax.default_backend() == "tpu" and _shapes_gmm_ok(lhs, rhs)
+            and mesh_dispatch.current() is None)
+
+
+def _gmm_kernel(lhs, rhs, group_sizes, interpret: bool = False):
+    """Direct kernel call, no dispatch gate (tests compile it for a
+    described chip and run it interpreted)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    return gmm(lhs, rhs, group_sizes, lhs.dtype,
+               _v5e_tiling(m, k, rhs.shape[2]), None, None, False, interpret)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """[m, k] x [groups, k, n] -> [m, n]: row i is multiplied by the matrix
+    of its group, rows laid out group after group, `group_sizes` int32
+    [groups] summing to m (empty groups allowed). f32 accumulation, output
+    in lhs's dtype."""
+    if gmm_eligible(lhs, rhs):
+        return _gmm_kernel(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+
+
+# -- dispatch / combine: both directions are row gathers -------------------
+# Every token appears in exactly k of the T*k sorted rows, so the gather's
+# transpose (a scatter-add, slow on the TPU) is itself a gather through the
+# inverse permutation plus a sum over the k slots. `order` sorts the
+# (token, slot) pairs by expert; `inverse` undoes it.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k):
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inverse, k):
+    return x[order // k], inverse
+
+
+def _dispatch_bwd(k, inverse, g):
+    dx = g[inverse].reshape(-1, k, g.shape[-1])
+    return dx.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _undispatch(ys, order, inverse):
+    return ys[inverse]
+
+
+def _undispatch_fwd(ys, order, inverse):
+    return ys[inverse], order
+
+
+def _undispatch_bwd(order, g):
+    return g[order], None, None
+
+
+_undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
+
+
+def route(x, router_w, top_k: int, norm_topk_prob: bool):
+    """The router, in float32 whatever the activations' dtype: logits
+    [T, E] (matmul at the highest precision: the TPU's default would round
+    both inputs to bf16), softmax, top-k. Returns (logits, gates [T, k],
+    experts [T, k] int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gates = gates / gates.sum(-1, keepdims=True)
+    return logits, gates, experts.astype(jnp.int32)
+
+
+def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
+            norm_topk_prob: bool = False):
+    """x [T, d] -> (out [T, d], router logits [T, E] f32, tokens per expert
+    [E] int32). y = sum_j gate_j * (silu(x Wg[e_j]) * (x Wu[e_j])) Wd[e_j]
+    over the token's top_k experts e_j. The expert matmuls run, and `out`
+    is returned, in the expert weights' dtype (the amp dtype where the
+    caller cast them); the router reads x as it comes (float32 from
+    rms_norm)."""
+    T, d = x.shape
+    E = router_w.shape[1]
+    with jax.named_scope("route"):
+        logits, gates, experts = route(x, router_w, top_k, norm_topk_prob)
+    with jax.named_scope("dispatch"):
+        flat = experts.reshape(-1)                       # [T*k]
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        cd = gate_w.dtype
+        xs = _dispatch(x.astype(cd), order, inverse, top_k)   # [T*k, d]
+    with jax.named_scope("experts"):
+        g = grouped_matmul(xs, gate_w, group_sizes)
+        u = grouped_matmul(xs, up_w, group_sizes)
+        h = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+             ).astype(cd)
+        ys = grouped_matmul(h, down_w, group_sizes)          # [T*k, d]
+    with jax.named_scope("combine"):
+        yu = _undispatch(ys, order, inverse).reshape(T, top_k, d)
+        out = (yu.astype(jnp.float32) * gates[..., None]).sum(1)
+    return out.astype(cd), logits, group_sizes
+
+
+@register_op("moe_ffn")
+def moe_ffn_kernel(ctx):
+    """Program-IR face: X [B, T, d] (or [T, d]); RouterW [d, E]; GateW, UpW
+    [E, d, f]; DownW [E, f, d]. Out shaped like X, in the compute dtype;
+    RouterLogits [tokens, E]
+    float32 (under amp too: the router never drops precision, the expert
+    matmuls do); TokensPerExpert [E] int32, summing to tokens x top_k."""
+    x = ctx.input("X")
+    gate_w, up_w, down_w = amp.cast_inputs(
+        ctx, ctx.input("GateW"), ctx.input("UpW"), ctx.input("DownW"))
+    out, logits, counts = moe_ffn(
+        x.reshape(-1, x.shape[-1]), ctx.input("RouterW"), gate_w, up_w,
+        down_w, int(ctx.attr("top_k")),
+        bool(ctx.attr("norm_topk_prob", False)))
+    ctx.set_output("Out", out.reshape(x.shape))
+    ctx.set_output("RouterLogits", logits)
+    ctx.set_output("TokensPerExpert", counts)
+
+
+@register_op("moe_aux_loss")
+def moe_aux_loss_kernel(ctx):
+    """The routed layer's two auxiliary costs, float32, as one scalar:
+    balance_weight * E * sum_e f_e P_e (f_e: the share of the (token, slot)
+    pairs routed to expert e, a count and so without gradient; P_e: the mean
+    router probability of e) + z_weight * mean(logsumexp(logits)^2)."""
+    logits = ctx.input("RouterLogits").astype(jnp.float32)
+    counts = ctx.input("TokensPerExpert").astype(jnp.float32)
+    E = logits.shape[-1]
+    share = jax.lax.stop_gradient(counts / counts.sum())
+    mean_prob = jax.nn.softmax(logits, axis=-1).mean(0)
+    balance = E * jnp.sum(share * mean_prob)
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    cost = (ctx.attr("balance_weight", 0.01) * balance
+            + ctx.attr("z_weight", 0.001) * z)
+    ctx.set_output("Out", cost)

@@ -211,20 +211,30 @@ class _PassStats:
     seen) feeds the StepGuard's window observation."""
 
     def __init__(self, n_metrics: int, skip_nonfinite: bool,
-                 device: bool = True, on_sync: Optional[Callable] = None):
+                 device: bool = True, on_sync: Optional[Callable] = None,
+                 statistics: Sequence[dict] = ()):
         self.device = device
         self.skip_nonfinite = bool(skip_nonfinite)
         self.on_sync = on_sync
         self.steps = 0         # steps folded in
         self.synced_steps = 0  # steps whose outcome the host has seen
         self.synced_bad = 0
+        self.n_metrics = n_metrics
         self.host = (0, 0.0, [0.0] * n_metrics, 0)  # (n, Σcost, Σm, bad)
+        # the program's step statistics (Program.add_step_statistic) ride
+        # behind the metrics: int32 vectors summed in the same fold, read
+        # in the same sync, and published there as labelled counters
+        self.statistics = [dict(s) for s in statistics]
+        self.stat_sums = [np.zeros(s["shape"], np.int64)
+                          for s in self.statistics]
         if device:
             z = jnp.zeros((), jnp.int32)
             zf = jnp.zeros((), jnp.float32)
-            self.state = (z, zf, [zf] * n_metrics, z)
+            self.state = (z, zf, [zf] * n_metrics + [
+                jnp.zeros(s["shape"], jnp.int32) for s in self.statistics], z)
 
     def update(self, cost, metrics) -> None:
+        """`metrics`: the step's metric fetches, then its statistics."""
         self.steps += 1
         if self.device:
             self.state = _accum_update(
@@ -242,7 +252,34 @@ class _PassStats:
             n += 1
             cs += c
             ms = [m + float(np.asarray(v)) for m, v in zip(ms, metrics)]
+            self._publish([t + np.asarray(v) for t, v in zip(
+                self.stat_sums, metrics[self.n_metrics:])])
         self.host = (n, cs, ms, bad + (0 if finite else 1))
+
+    def publish_step(self, values, cost: float) -> None:
+        """A per-step sync path has this step's statistics on the host
+        already (the cost read fenced the step): publish them now, in step
+        with what the device fold does, so that the next `sync` finds
+        nothing left to add."""
+        if self.statistics and (np.isfinite(cost) or not self.skip_nonfinite):
+            self._publish([t + np.asarray(v) for t, v in zip(
+                self.stat_sums, values)])
+
+    def _publish(self, totals) -> None:
+        """Hand what the statistics grew by since the last call to the
+        metrics registry. `totals`: the pass's running sums; the device's
+        are int32, so a difference is taken modulo 2^32."""
+        from .obs import metrics as obs_metrics
+
+        reg = obs_metrics.registry()
+        for stat, old, new in zip(self.statistics, self.stat_sums, totals):
+            new = np.asarray(new, np.int64)
+            grown = (new - old) % 2**32
+            for i in np.flatnonzero(grown):
+                reg.counter_inc(
+                    stat["counter"], float(grown[i]), help=stat["help"],
+                    labels={**stat["labels"], stat["index_label"]: int(i)})
+        self.stat_sums = [np.asarray(t, np.int64) for t in totals]
 
     def absorb_window(self, new_state, k: int) -> None:
         """Scan-window path: the executor folded k steps into the
@@ -270,7 +307,9 @@ class _PassStats:
             if self.on_sync is not None:
                 self.on_sync()
             n, cs, ms, bad = jax.device_get(self.state)
-            self.host = (int(n), float(cs), [float(m) for m in ms], int(bad))
+            self.host = (int(n), float(cs),
+                         [float(m) for m in ms[:self.n_metrics]], int(bad))
+            self._publish(ms[self.n_metrics:])
         delta_total = self.steps - self.synced_steps
         # per-step observation tracks cost-only finiteness (mirroring the
         # device counter); clamp so a grads-only bad verdict from the
@@ -693,7 +732,14 @@ class Trainer:
         feeder = DataFeeder(feed_order) if feed_order is not None else None
         metric_items = sorted((fetch_metrics or {}).items())
         metric_names = [k for k, _ in metric_items]
-        fetch_list = [self.cost] + [v for _, v in metric_items]
+        # what the program's layers registered to be counted every step
+        # rides behind the metrics in the same fetch, fold and sync
+        gb = self.main_program.global_block()
+        statistics = [
+            dict(s, shape=tuple(gb.var(s["var"]).shape))
+            for s in getattr(self.main_program, "step_statistics", ())]
+        fetch_list = [self.cost] + [v for _, v in metric_items] + [
+            gb.var(s["var"]) for s in statistics]
         last_metrics: Dict[str, float] = {}
         guard = self.step_guard
         device_acc = getattr(self.exe, "device_metric_accumulation", True)
@@ -732,7 +778,8 @@ class Trainer:
             handler(BeginPass(pass_id))
             acc = _PassStats(len(metric_items),
                              skip_nonfinite=guard is not None,
-                             device=device_acc, on_sync=self._count_sync)
+                             device=device_acc, on_sync=self._count_sync,
+                             statistics=statistics)
             skip_until = self._resume_batch
             self._resume_batch = 0  # only the resumed pass skips
             if scan_k:
@@ -883,9 +930,9 @@ class Trainer:
                 ).items():
                     print(f"  param {pname}: " + ", ".join(
                         f"{k}={v:.4g}" for k, v in st.items()))
-            metric_devs = outs[1:]
             with profiler.timer("accumUpdate"):
-                acc.update(cost_dev, metric_devs)
+                acc.update(cost_dev, outs[1:])
+            metric_devs = outs[1:1 + len(metric_names)]
             # per-step sync: legacy cadence, a hot StepGuard (open
             # streak / cool-down), or a stats step (it prints anyway)
             per_step = (sync_every == 1 or want_stats
@@ -894,6 +941,8 @@ class Trainer:
                 with profiler.timer("hostSync"):
                     cost, metric_vals = self._host_read_step(
                         cost_dev, metric_devs)
+                    if acc.device:
+                        acc.publish_step(outs[1 + len(metric_names):], cost)
                 if guard is not None:
                     ok = guard.observe(cost, grads, scope=self.scope)
                     acc.note_observed(not np.isfinite(cost))

@@ -1,0 +1,263 @@
+"""The benchmark's own checks, in the tier-1 run: `chipbench/selftest.py`
+(no backend), the cases of `chipbench/tests/test_harness.py` (JAX on the
+CPU), and what PR 27 added for the olmoe-1b-7b configuration: its FLOPs
+arithmetic, its kernel's operations and bytes, its readers on hand-made run
+records, its copy of the plain reference against the tree's, and one CPU
+rehearsal of its cell through `chipbench/run.py`.
+"""
+
+import importlib.util
+import json
+import os
+import re
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "chipbench")
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(*parts):
+    path = os.path.join(BENCH, *parts)
+    spec = importlib.util.spec_from_file_location(
+        "cb_" + re.sub(r"\W", "_", "_".join(parts)), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# the harness's own pytest cases, collected here under their own names
+globals().update({name: fn for name, fn in
+                  vars(_load("tests", "test_harness.py")).items()
+                  if name.startswith("test_")})
+
+
+def test_selftest_passes():
+    out = subprocess.run([sys.executable, os.path.join(BENCH, "selftest.py")],
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-1000:]
+    assert re.search(r"(\d+) of \1 checks passed", out.stdout), out.stdout[-300:]
+
+
+def _config():
+    with open(os.path.join(BENCH, "configs", "olmoe-1b-7b", "config.json")) as f:
+        return json.load(f)
+
+
+CELL = {"batch": 1, "seqlen": 4096}
+
+
+def test_olmoe_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _config()
+    config_dir = os.path.join(BENCH, "configs", "olmoe-1b-7b")
+    got = flops.train_flops_per_item(cfg, CELL, config_dir)
+    assert got == 1071906816.0            # ISSUE 27: 1.072e9 at T 4096
+    # the published 16 layers: the layer's part 16 times, the head once
+    full = flops.train_flops_per_item(
+        dict(cfg, num_hidden_layers=16), CELL, config_dir)
+    head = 3 * 2 * 2048 * 50304
+    assert full - head == 16 * (got - head)
+
+
+def test_olmoe_config_keeps_the_published_sizes():
+    cfg = _config()
+    published = {
+        "attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
+        "hidden_size": 2048, "intermediate_size": 1024,
+        "max_position_embeddings": 4096, "model_type": "olmoe",
+        "norm_topk_prob": False, "num_attention_heads": 16,
+        "num_experts": 64, "num_experts_per_tok": 8, "num_hidden_layers": 16,
+        "num_key_value_heads": 16, "rms_norm_eps": 1e-05,
+        "rope_scaling": None, "rope_theta": 10000,
+        "tie_word_embeddings": False, "vocab_size": 50304}
+    differs = [k for k, v in published.items() if cfg.get(k, "absent") != v]
+    assert differs == cfg["reduced"] == ["num_hidden_layers"]
+    assert cfg["published"] == {"num_hidden_layers": 16}
+    assert cfg["num_hidden_layers"] == 1
+
+
+def test_moe_grouped_matmul_counts_on_a_hand_made_cell():
+    kernel = _load("kernels", "moe_grouped_matmul.py")
+    cfg = {"num_hidden_layers": 2, "hidden_size": 8, "intermediate_size": 4,
+           "num_experts": 3, "num_experts_per_tok": 2}
+    flops, bytes_ = kernel.flops_and_bytes(cfg, {"batch": 5, "seqlen": 10})
+    rows = 5 * 10 * 2
+    assert flops == 2 * 18 * rows * 8 * 4
+    assert bytes_ == 2 * 2 * (9 * 3 * 8 * 4 + rows * (5 * 8 + 7 * 4))
+    # the cell's own: 1.24 TFLOP against 3.7 GB, compute-bound by a hair
+    flops, bytes_ = kernel.flops_and_bytes(_config(), CELL)
+    assert flops == 18 * 32768 * 2048 * 1024
+    assert 3.5e9 < bytes_ < 3.9e9
+    assert flops / 197e12 > bytes_ / 819e9
+
+
+def _row(scope, ns, target=None, transform="", inner="", container=False):
+    op_name = f"jit(raw)/{transform}{scope}{')' * transform.count('(')}"
+    return {"name": "%x", "opcode": "custom-call" if target else "fusion",
+            "shape": "", "target": target, "container": container, "count": 2,
+            "ns": ns, "op_name": op_name + (f"/{inner}/dot" if inner else "/dot"),
+            "scope": scope, "transform": transform.rstrip("(")}
+
+
+MOE = "moe_ffn.olmoe.h0.moe.tmp_7"
+
+
+def _run_record():
+    ops = [
+        _row(MOE, 4_000_000, "tpu_custom_call", "", "experts"),
+        _row(MOE, 6_000_000, "tpu_custom_call", "transpose(jvp(", "experts"),
+        _row(MOE, 1_000_000, None, "jvp(", "route"),
+        _row(MOE, 3_000_000, None, "", "dispatch"),
+        _row(MOE, 9_000_000, None, "", "", container=True),   # a loop: left out
+        _row("flash_attention.attn.tmp_3", 8_000_000, "tpu_custom_call"),
+        _row("mul.fc_9.tmp_9", 5_000_000),
+    ]
+    return {
+        "steps": 2, "trace": {"ops": ops}, "device": {"kind": "TPU v5 lite"},
+        "config": {"num_hidden_layers": 1, "hidden_size": 2048,
+                   "intermediate_size": 1024, "num_experts": 64,
+                   "num_experts_per_tok": 8, "num_attention_heads": 16},
+        "cell": {"batch": 1, "seqlen": 4096},
+        "program_ops": [
+            {"type": "moe_ffn", "scope": MOE, "inputs": {"X": ["h"]},
+             "outputs": {"Out": ["olmoe.h0.moe.tmp_7"]}},
+            {"type": "mul", "scope": "mul.fc_9.tmp_9",
+             "inputs": {"X": ["h"], "Y": ["w"]}, "outputs": {"Out": ["l"]}}],
+    }
+
+
+def test_moe_readers_on_a_hand_made_run_record():
+    run = _run_record()
+    device = _load("layer_metrics", "moe.device_ms.py")
+    assert device.compute(run) == pytest.approx((4 + 6 + 1 + 3) / 2)
+    info = device.info(run)
+    assert info["by_inner_scope_ms"] == pytest.approx(
+        {"experts": 5.0, "route": 0.5, "dispatch": 1.5})
+    assert info["by_pass_ms"] == pytest.approx(
+        {"plain": 3.5, "jvp": 0.5, "transpose": 3.0})
+    dispatch = _load("layer_metrics", "moe.dispatch_ms.py")
+    assert dispatch.compute(run) == pytest.approx((1 + 3) / 2)
+    gmm = _load("layer_metrics", "kernel.gmm_roofline.py")
+    # 1.2369 TFLOP at 197 TFLOP/s = 6.279 ms over the kernels' 5 ms a step
+    assert gmm.compute(run) == pytest.approx(100 * 6.2787 / 5.0, rel=1e-3)
+    assert gmm.info(run)["bound"] == "compute"
+    assert gmm.info(run)["kernels_per_step"] == 2.0
+    # nothing to read: no routed op in the Program, or no trace
+    for reader in (device, dispatch, gmm):
+        assert reader.compute(dict(run, program_ops=run["program_ops"][1:])) is None
+        assert reader.compute(dict(run, trace=None)) is None
+
+
+def test_load_max_over_mean_reads_the_counters_and_checks_the_sum():
+    reader = _load("layer_metrics", "moe.load_max_over_mean.py")
+    cfg = {"num_experts": 4, "num_experts_per_tok": 2}
+    cell = {"batch": 1, "seqlen": 8}
+    series = 'pt_moe_expert_tokens_total{expert="%d",layer="olmoe.h0.moe"}'
+    reg = {series % 0: 20.0, series % 1: 4.0, series % 2: 8.0,
+           "pt_executor_donated_bytes": 7.0}         # expert 3 never chosen
+    run = {"steps": 2, "config": cfg, "cell": cell, "registry": reg}
+    assert reader.compute(run) == pytest.approx(20 / (32 / 4))
+    assert reader.compute(dict(run, registry={"x": 1.0})) is None
+    with pytest.raises(ValueError, match="dropped or counted twice"):
+        reader.compute(dict(run, registry=dict(reg, **{series % 2: 7.0})))
+
+
+WRAPPERS = {"olmoe.head_device_ms": "head.device_ms",
+            "olmoe.flash_roofline": "kernel.flash_roofline",
+            "olmoe.opt_device_ms": "opt.device_ms",
+            "olmoe.donated_gib": "step.donated_gib",
+            "olmoe.feed_produce_ms_per_step": "feed.produce_ms_per_step"}
+
+
+@pytest.mark.parametrize("name", sorted(WRAPPERS))
+def test_a_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run = _run_record()
+    run["program_ops"].append(
+        {"type": "adam", "scope": "adam.w", "inputs": {"Param": ["w"]},
+         "outputs": {"ParamOut": ["w"]}})
+    run["trace"]["ops"].append(_row("adam.w", 2_000_000))
+    run["program_ops"].append(
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.s",
+         "inputs": {"Logits": ["l"], "Label": ["y"]},
+         "outputs": {"Softmax": ["s"], "Loss": ["c"]}})
+    run["registry"] = {"pt_executor_donated_bytes": 7.5e9}
+    run["timers_s"] = {"prefetch.read": 0.5, "prefetch.batch": 0.25}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    if hasattr(wrapped, "info"):
+        assert wrapper.info(run) == wrapped.info(run)
+    # and nothing where the wrapped reader finds nothing
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+
+
+def test_the_benchmarks_reference_is_the_trees_reference_bit_for_bit():
+    """`chipbench/configs/olmoe-1b-7b/reference.py` is a copy of
+    `tests/olmoe_reference.py`: the same cost, gradients and router logits
+    to the bit on the CPU, so the two cannot drift apart unseen."""
+    import olmoe_reference as tree
+
+    copy = _load("configs", "olmoe-1b-7b", "reference.py")
+    cfg = dict(_config(), hidden_size=32, num_attention_heads=2,
+               intermediate_size=16, num_experts=8, num_experts_per_tok=2,
+               vocab_size=64, num_hidden_layers=2)
+    d, f, E, V = 32, 16, 8, 64
+    r = np.random.RandomState(0)
+    shapes = [(V, d)]
+    for _ in range(2):
+        shapes += [(d,), (d, d), (d, d), (d, d), (d,), (d,), (d, d), (d,),
+                   (d, E), (E, d, f), (E, d, f), (E, f, d)]
+    shapes += [(d,), (d, V)]
+    params = [(r.randn(*s) * 0.2 + (len(s) == 1)).astype(np.float32)
+              for s in shapes]
+    toks = r.randint(0, V, (2, 17))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    (c1, g1), (c2, g2) = (m.loss_and_grads(cfg, params, feed)
+                          for m in (tree, copy))
+    assert float(c1) == float(c2) and np.isfinite(float(c1))
+    assert len(g1) == len(g2) == len(params)
+    for a, b in zip(g1, g2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(tree.router_logits(cfg, params, feed),
+                    copy.router_logits(cfg, params, feed)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and the text: everything from the imports down is the tree's
+    text = lambda m: open(m.__file__).read().split("import math\n", 1)[1]  # noqa: E731
+    assert text(copy).startswith(text(tree))
+
+
+def test_the_olmoe_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference at the rehearsal's tolerances, the counters reach the run
+    record, and every metric's name carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         "olmoe-1b-7b.train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486099"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.moe.load_max_over_mean",
+            "REHEARSAL_ON_CPU.olmoe.donated_gib",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    assert result["metrics"]["REHEARSAL_ON_CPU.loop.dispatch_per_step"][
+        "value"] == 2.0                      # sync_every 2 in the rehearsal

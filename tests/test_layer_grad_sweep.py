@@ -845,6 +845,53 @@ def build_multi_head_attention():
 
 
 @case
+def build_rms_norm():
+    h, feed = _pre(3, 8)
+    return _scalar(L.rms_norm(h)), feed
+
+
+def _pre_btd(t=4, d=8):
+    """[t, d] data -> trainable fc -> [1, t, d], as multi_head_attention's."""
+    x = L.data("x", shape=[t, d], append_batch_size=False)
+    return L.reshape(L.fc(x, size=d), (1, t, d)), _feed("x", (t, d))
+
+
+@case
+def build_rotary_embedding():
+    h, feed = _pre_btd()
+    return _scalar(L.rotary_embedding(h, num_heads=2)), feed
+
+
+@case
+def build_multi_head_attention_qk_norm_rotary():
+    h, feed = _pre_btd()
+    h = L.multi_head_attention(h, num_heads=2, causal=True, bias_attr=False,
+                               qk_norm=True, rotary_theta=10000.0)
+    return _scalar(h), feed
+
+
+def _moe(t=6, d=8):
+    h, feed = _pre_btd(t, d)
+    return L.moe_ffn(h, num_experts=4, experts_per_token=2, expert_dim=8), feed
+
+
+@case
+def build_moe_ffn():
+    # top-2 of 4 on six tokens: a finite difference that turned a choice
+    # would show as a wrong gradient; the seeded logits' gaps are far wider
+    # than eps
+    (out, _, _), feed = _moe()
+    return _scalar(out), feed
+
+
+@case
+def build_moe_aux_loss():
+    (out, logits, counts), feed = _moe()
+    return L.elementwise_add(_scalar(out),
+                             L.moe_aux_loss(logits, counts, 0.5, 0.5)), feed
+
+
+@case
 def build_attention_gru_decoder():
     src, feed = _pre_seq(lens=(4, 3), d=15, vocab=9, name="src")
     enc = L.dynamic_gru(src, size=5, max_len=8)  # input 3*size wide

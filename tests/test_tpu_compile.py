@@ -147,6 +147,24 @@ def _flash(with_bwd):
     return (fwd_bwd if with_bwd else fwd), qkv
 
 
+def _gmm(kn):
+    from paddle_tpu.ops import moe_ops
+
+    # OLMoE-1B-7B's routed layer at batch 1 x T 4096: 4096 tokens x top-8
+    # rows over 64 experts; (2048, 1024) is gate / up, (1024, 2048) down.
+    # Forward and the custom VJP's two backward kernels, at the
+    # dispatcher's own tiling
+    k, n = kn
+    specs = [((32768, k), BF16), ((64, k, n), BF16), ((64,), jnp.int32)]
+
+    def fwd_bwd(lhs, rhs, sizes):
+        out, vjp = jax.vjp(
+            lambda a, b: moe_ops._gmm_kernel(a, b, sizes), lhs, rhs)
+        return (out, *vjp(out))
+
+    return fwd_bwd, specs
+
+
 def _quant(mkn):
     from paddle_tpu.ops import quant_kernels as qk
     from paddle_tpu.tune import space
@@ -180,6 +198,8 @@ CASES = [
     ("bahdanau_phase2", _bahdanau, "phase2"),
     ("flash_fwd_t1024", _flash, False),
     ("flash_fwd_bwd_t1024", _flash, True),
+    ("gmm_fwd_bwd_olmoe_gate_up", _gmm, (2048, 1024)),
+    ("gmm_fwd_bwd_olmoe_down", _gmm, (1024, 2048)),
     # ResNet-50 head at a full serving bucket, and the small probe shape
     ("quant_matmul_64x2048x1000", _quant, (64, 2048, 1000)),
     ("quant_matmul_8x512x512", _quant, (8, 512, 512)),
