@@ -7,13 +7,19 @@ CP/ring-attention"). XLA's unfused attention materializes the [B, H, T, T]
 score matrix in HBM (16 GB at T=32k bf16 — impossible); flash attention
 streams K/V blocks through VMEM with an online softmax, O(T) memory.
 
-Compute path: on TPU, JAX's Pallas TPU flash kernel
-(jax.experimental.pallas.ops.tpu.flash_attention — public JAX library
-code, used the way lax.conv uses XLA) with its custom VJP; anywhere else,
-the jnp reference formulation. Layout here is [B, T, H, D] (the
-framework's sequence-parallel convention, parallel/ring_attention.py);
-the kernel's [B, H, T, D] transpose happens at the boundary and XLA
-folds it into the kernel's operand layout.
+Compute path: on TPU, Pallas kernels of this repo's own (forward, and a
+backward behind a `custom_vjp`) that read the packed `[B, T, E]` projections
+the `flash_attention` op receives, as they are; anywhere else, and for the
+shapes the kernels refuse, the jnp reference formulation. `[B, T, H, D]`
+callers (the framework's sequence-parallel convention,
+parallel/ring_attention.py) reach the same kernels through a free reshape.
+
+Why packed (PERF.md section 6, PR 28): the library kernel this module used
+to call wants `[B, H, T, D]`, and XLA did not fold the transposes into the
+kernel's operands. On gpt2-small 28.3 ms of the op's 68.6 ms a step were
+not kernels at all: transposes and copies of `[12, 12, 1024, 64]` arrays
+(64-wide minor dimension, half a lane tile) and broadcasts of the softmax
+statistics to 128 and 512 lanes.
 
 `paddle_tpu.parallel.ulysses_attention` routes its per-device full-
 sequence attention through here, so the SP path gets the fused kernel
@@ -22,10 +28,14 @@ for free.
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.registry import register_op
 
@@ -51,21 +61,22 @@ _reference = scaled_dot_product_attention
 
 def _shapes_flash_ok(q, k) -> bool:
     """Backend-independent shape rules (separately testable): 128-aligned
-    q AND kv sequence lengths (the kernel's block divisibility — default
-    blocks are 128 and clamp to the sequence), lane-aligned head dim."""
-    Tq, Dq = q.shape[1], q.shape[3]
+    q AND kv sequence lengths (the kernels' blocks divide them), a head dim
+    a lane block holds whole (two heads at 64, one at 128, one over two lane
+    tiles at 256), and heads x D a whole number of lane blocks (an odd head
+    count at D 64 leaves half a block: XLA keeps it)."""
+    Tq, H, D = q.shape[1:]
     Tk = k.shape[1]
-    return Tq % 128 == 0 and Tk % 128 == 0 and Dq in (64, 128, 256)
+    return (Tq % 128 == 0 and Tk % 128 == 0 and D in (64, 128, 256)
+            and (H * D) % 128 == 0)
 
 
-# Dispatch policy (round 3, benchmarks/flash_block_tuning.json): with
-# v5e-tuned block sizes the kernel BEATS XLA's fused attention fwd+bwd
-# from T=1024 up — 1.4-1.5x at T=1-2k, 2.0x at 4k, 2.6x at 8k, 3.5x at
-# 16k (the library's all-128 default blocks were why round 2 measured
-# 0.59-0.71x). Below the measured window, or when the shape rules fail,
-# XLA keeps the job; the score-bytes rule stays as the memory-capability
-# route for shapes outside the measured-win window (XLA stops compiling
-# outright around several GB of scores).
+# Dispatch policy: from T=1024 up the fused kernels take the job (the
+# window both benchmark configurations sit in: PERF.md section 5 has their
+# device time beside the rest of the step). Below it, or when the shape
+# rules fail, XLA keeps the job; the score-bytes rule stays as the
+# memory-capability route for shorter sequences whose [B, H, Tq, Tk] scores
+# XLA could not hold (it stops compiling around several GB of scores).
 _FLASH_MIN_T = 1024
 _SCORE_BYTES_THRESHOLD = 1.5e9
 
@@ -78,7 +89,7 @@ def _prefers_flash(q, k) -> bool:
     B, Tq, H, _ = q.shape
     Tk = k.shape[1]
     if Tq >= _FLASH_MIN_T and Tk >= _FLASH_MIN_T:
-        return True  # measured-win regime with tuned blocks
+        return True  # the kernels' window
     # the shard_map'd kernel runs at the PER-SHARD batch (B/dp under a
     # mesh), so the score-buffer rule must see that batch too — same
     # eligibility discipline as the decoder/RNN kernels. local_batch
@@ -102,19 +113,21 @@ def flash_eligible(q, k=None) -> bool:
     )
 
 
-def _v5e_block_sizes(Tq: int, Tk: int, dtype=None):
-    """Block choice for the TPU kernel. Consult order (tune/overrides):
+class FlashBlocks(NamedTuple):
+    """Rows of Q and rows of K/V a kernel step holds."""
+    block_q: int
+    block_k: int
+
+
+def _v5e_block_sizes(Tq: int, Tk: int, dtype=None) -> FlashBlocks:
+    """Block choice for the TPU kernels. Consult order (tune/overrides):
     forced/tuned {block_q, block_k} for this (Tq, Tk, dtype, device) —
     validated against the shared legality predicate
     (tune/space.flash_block_legal: blocks must DIVIDE the 128-aligned
-    sequence) — else the v5e-tuned analytic default
-    (benchmarks/flash_block_tuning.json): 512-wide q/k blocks win up to
-    T=4096, 1024 from 8192; repeated-trial medians confirm 512/512 at
-    T=1024/2048 (1.4-1.5x over XLA). The default rounds its target down
-    to the largest 128-multiple divisor (e.g. T=1280 → 256)."""
+    sequence) — else the analytic default: 512-row q/k blocks up to
+    T=4096, 1024 from 8192, rounded down to the largest 128-multiple
+    divisor (e.g. T=1280 → 256)."""
     import numpy as np
-
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
     from ..tune import overrides as tune_overrides
     from ..tune.space import flash_block_legal
@@ -131,7 +144,6 @@ def _v5e_block_sizes(Tq: int, Tk: int, dtype=None):
             b -= 128
         return b
 
-    qb, kb = 0, 0
     ov = tune_overrides.lookup(
         "flash_attention", {"Tq": Tq, "Tk": Tk},
         np.dtype(dtype).name if dtype is not None else "bfloat16")
@@ -139,67 +151,485 @@ def _v5e_block_sizes(Tq: int, Tk: int, dtype=None):
         oq = int(ov.config.get("block_q", 0))
         ok = int(ov.config.get("block_k", 0))
         if flash_block_legal(oq, ok, Tq, Tk):
-            qb, kb = oq, ok
-        elif ov.source in ("forced", "env"):
+            return FlashBlocks(oq, ok)
+        if ov.source in ("forced", "env"):
             import warnings
 
             warnings.warn(
                 f"forced flash blocks q={oq} k={ok} do not divide "
                 f"Tq={Tq} Tk={Tk}; using the analytic default",
                 stacklevel=2)
-    if not qb:
-        qb, kb = blk(Tq), blk(Tk)
-    return BlockSizes(
-        block_q=qb, block_k_major=kb, block_k=kb, block_b=1,
-        block_q_major_dkv=qb, block_k_major_dkv=kb,
-        block_k_dkv=kb, block_q_dkv=qb,
-        block_k_major_dq=kb, block_k_dq=kb, block_q_dq=qb,
-    )
+    return FlashBlocks(blk(Tq), blk(Tk))
+
+
+# ------------------------------------------------------------------ kernels
+# The kernels work on the packed layout the op receives: Q, K, V and the
+# output are [B, T, E], E = heads x D, and a BlockSpec of (1, rows, W) picks
+# a lane block of W = max(128, D) lanes: two heads at D 64 (lanes 0-63 and
+# 64-127), one head at 128, one head over two lane tiles at 256. Loads and
+# stores are whole lane tiles; nothing is transposed in HBM. Two heads in a
+# block are separated on the MXU: a contraction over all W lanes with the
+# other head's lanes zeroed is that head's contraction (at the price a
+# 64-wide contraction has on a 128-wide MXU anyway), and P V over all W value
+# lanes followed by a lane select is that head's output.
+#
+# The softmax statistic the backward needs is one float32 a (row, head): the
+# log-sum-exp, kept as [B, Tq, 128 x ceil(heads / 128)] with head h in lane
+# h, never broadcast. sum(o * do) is computed in the backward kernel.
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+_LANES = 128
+# one fused backward pass keeps dQ for a whole sequence in VMEM (float32
+# accumulator and the output block: 10 bytes a row and lane); beyond this
+# many query rows dQ gets a pass of its own
+_FUSED_BWD_MAX_TQ = 8192
+
+
+def _head_lanes(width: int, D: int):
+    """For each head of a lane block, the [1, width] mask of its lanes
+    (None where the block is one head)."""
+    if width == D:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [(lane >= j * D) & (lane < (j + 1) * D) for j in range(width // D)]
+
+
+def _only(x, lanes):
+    """x with every other head's lanes zeroed."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _merge(parts, masks):
+    """One [rows, width] array from each head's [rows, width]: head j's
+    lanes from parts[j]."""
+    out = parts[-1]
+    for part, lanes in zip(parts[-2::-1], masks[-2::-1]):
+        out = jnp.where(lanes, part, out)
+    return out
+
+
+def _causal_keep(bq, bk, q0, k0):
+    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return cols <= rows
+
+
+def _on_blocks(causal, q0, bq, k0, bk, step):
+    """Run `step(diag)` for this (q block, k block): not at all where the
+    causal rule empties it, with the mask only where the diagonal crosses."""
+    if not causal:
+        step(False)
+        return
+    crosses = k0 + bk - 1 > q0          # some column is right of some row
+    pl.when(jnp.logical_and(k0 <= q0 + bq - 1, crosses))(
+        lambda: step(True))
+    pl.when(jnp.logical_not(crosses))(lambda: step(False))
+
+
+def _stat_lane(stats, head):
+    """[rows, 1]: lane `head` (mod 128) of a [rows, 128] statistics block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    return jnp.sum(jnp.where(lane == head % _LANES, stats, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _across(x, width):
+    """A [rows, 128] array whose lanes all hold the row's value, at `width`
+    lanes (a multiple of 128: the same vector registers again)."""
+    return x if width == _LANES else jnp.tile(x, (1, width // _LANES))
+
+
+def _scaled(x, scale):
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _exact_in_bf16(scale: float) -> bool:
+    """A power of two: scaling a Q or K block by it rounds nothing, so the
+    multiply leaves the [block_q, block_k] scores for a [rows, width] block
+    (1/sqrt(D) at D 64 and 256; at 128 the scores are scaled, in float32)."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal):
+    # grid (B, q blocks, lane blocks, k blocks): the statistics block of a
+    # (batch, q block) stays in VMEM while the lane blocks take their turns.
+    # The running max and sum of a head are kept across 128 lanes, every lane
+    # the row's value: what the VPU broadcasts for nothing
+    *lse_ref, q_sc, m_sc, l_sc, acc_sc = rest   # no statistics: no lse_ref
+    bq, W = q_ref.shape[1:]
+    bk = k_ref.shape[1]
+    qi, hb, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    masks = _head_lanes(W, D)
+    heads = range(len(masks))
+    head0 = hb * len(masks)
+    fold = _exact_in_bf16(scale)
+
+    @pl.when(ki == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+        for j in heads:     # each head's Q, alone in its lanes: once a q block
+            qj = _only(q_ref[0], masks[j])
+            q_sc[j] = _scaled(qj, scale) if fold else qj
+
+    def step(diag):
+        k, v = k_ref[0], v_ref[0]
+        keep = _causal_keep(bq, bk, qi * bq, ki * bk) if diag else None
+        alphas, pvs = [], []
+        for j in heads:
+            s = jax.lax.dot_general(q_sc[j], k, _NT,
+                                    preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_sc[j]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _across(m_next, bk))
+            alpha = jnp.exp(m_prev - m_next)
+            l_sc[j] = alpha * l_sc[j] + jnp.sum(p, axis=1, keepdims=True)
+            m_sc[j] = m_next
+            alphas.append(_across(alpha, W))
+            pvs.append(jnp.dot(p.astype(v.dtype), v,
+                               preferred_element_type=jnp.float32))
+        acc_sc[...] = (_merge(alphas, masks) * acc_sc[...]
+                       + _merge(pvs, masks))
+
+    _on_blocks(causal, qi * bq, bq, ki * bk, bk, step)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _():
+        l = _merge([_across(l_sc[j], W) for j in heads], masks)
+        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        for ref in lse_ref:
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+            stats = jnp.where(head0 % _LANES == 0, 0.0, ref[0])
+            for j in heads:
+                stats = jnp.where(lane == (head0 + j) % _LANES,
+                                  m_sc[j] + jnp.log(l_sc[j]), stats)
+            ref[0] = stats
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+                D, scale, causal, want):
+    """`want` "dkv": grid (B, lane blocks, k blocks, q blocks), dK and dV
+    summed over the q blocks; "dq": grid (.., q blocks, k blocks), dQ summed
+    over the k blocks; "all": the dkv grid, and dQ summed for the whole
+    sequence in a VMEM accumulator beside it, so the scores are recomputed
+    once."""
+    bq, W = q_ref.shape[1:]
+    bk = k_ref.shape[1]
+    hb = pl.program_id(1)
+    if want == "dq":
+        dq_ref, dq_sc = rest
+        qi, ki = pl.program_id(2), pl.program_id(3)
+    else:
+        ki, qi = pl.program_id(2), pl.program_id(3)
+        if want == "all":
+            dk_ref, dv_ref, dq_ref, k_sc, v_sc, dk_sc, dv_sc, dq_sc = rest
+        else:
+            dk_ref, dv_ref, k_sc, v_sc, dk_sc, dv_sc = rest
+    nq = pl.num_programs(2 if want == "dq" else 3)
+    nk = pl.num_programs(3 if want == "dq" else 2)
+    masks = _head_lanes(W, D)
+    heads = range(len(masks))
+    head0 = hb * len(masks)
+    fold = _exact_in_bf16(scale)
+    # the rows of dQ's accumulator this step adds to
+    rows = pl.ds(pl.multiple_of(qi * bq, bq), bq) if want == "all" \
+        else slice(None)
+
+    def alone(j):
+        """Head j's K (scaled where that is exact) and V, alone in their
+        lanes: the contractions with Q and dO then see that head only."""
+        kj = _only(k_ref[0], masks[j])
+        return (_scaled(kj, scale) if fold else kj), _only(v_ref[0], masks[j])
+
+    if want != "dq":
+        @pl.when(qi == 0)
+        def _():
+            dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+            dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+            for j in heads:      # once a k block: the q blocks are inside
+                k_sc[j], v_sc[j] = alone(j)
+    if want != "dkv":
+        @pl.when(ki == 0)
+        def _():
+            dq_sc[rows, :] = jnp.zeros((bq, W), jnp.float32)
+
+    def step(diag):
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        stats = lse_ref[0]
+        o_do = o_ref[0].astype(jnp.float32) * do.astype(jnp.float32)
+        keep = _causal_keep(bq, bk, qi * bq, ki * bk) if diag else None
+        dks, dvs, dqs = [], [], []
+        for j in heads:
+            kj, vj = alone(j) if want == "dq" else (k_sc[j], v_sc[j])
+            s = jax.lax.dot_general(q, kj, _NT,
+                                    preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            p = jnp.exp(s - _stat_lane(stats, head0 + j))
+            dp = jax.lax.dot_general(do, vj, _NT,
+                                     preferred_element_type=jnp.float32)
+            delta = jnp.sum(_only(o_do, masks[j]), axis=1, keepdims=True)
+            ds = p * (dp - delta)
+            if not fold:
+                ds = ds * scale
+            ds = ds.astype(q.dtype)
+            if want != "dq":
+                dvs.append(jax.lax.dot_general(
+                    p.astype(do.dtype), do, _TN,
+                    preferred_element_type=jnp.float32))
+                dks.append(jax.lax.dot_general(
+                    ds, q, _TN, preferred_element_type=jnp.float32))
+            if want != "dkv":
+                dqs.append(jnp.dot(ds, k, preferred_element_type=jnp.float32))
+        if want != "dq":
+            dk_sc[...] += _merge(dks, masks)
+            dv_sc[...] += _merge(dvs, masks)
+        if want != "dkv":
+            dq_sc[rows, :] += _merge(dqs, masks)
+
+    _on_blocks(causal, qi * bq, bq, ki * bk, bk, step)
+
+    # s = scale x q k^T, so dQ and dK carry the scale too: where it is a
+    # power of two it is applied once, to the sums, which rounds the same;
+    # else to dS before its rounding to the matmul's dtype (after it, dQ and
+    # dK read 0.0029 of their rms off the float32 oracle on the chip at D 128
+    # where the library's order reads 0.0021: PERF.md section 6, PR 28)
+    after = scale if fold else 1.0
+    if want != "dq":
+        @pl.when(qi == nq - 1)
+        def _():
+            dk_ref[0] = (dk_sc[...] * after).astype(dk_ref.dtype)
+            dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+    if want != "dkv":
+        @pl.when(ki == nk - 1)
+        def _():
+            dq_ref[0, rows, :] = (dq_sc[rows, :] * after).astype(dq_ref.dtype)
+
+
+def _geometry(q, k, heads):
+    B, Tq, E = q.shape
+    D = E // heads
+    return B, Tq, k.shape[1], E, D, max(_LANES, D)
+
+
+def _last_k(causal, bq, bk):
+    """Index map helper: the last k block a q block reads under the causal
+    rule; a later (empty) step names it again, so nothing is fetched."""
+    if not causal:
+        return lambda qi, ki: ki
+    return lambda qi, ki: jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+
+
+def _first_q(causal, bq, bk):
+    if not causal:
+        return lambda ki, qi: qi
+    return lambda ki, qi: jnp.maximum(qi, (ki * bk) // bq)
+
+
+def _params(*semantics):
+    # the default scoped-VMEM limit (16 MiB of the v5e's 128) is under what a
+    # fused backward holds at T 4096 and up: dQ's accumulator and output block
+    # for the whole sequence beside the [block_q, block_k] float32 temporaries
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=64 * 1024 * 1024)
+
+
+# jitted, as every kernel launch below: a model's layers share shapes, so the
+# kernel is traced and lowered once a program and not once a layer (36 traces
+# of gpt2-small's step cost its set-up 17 s: PERF.md section 6, PR 28). What
+# is read when the op is traced (the block sizes, a tuner's forced ones among
+# them) comes in as a static argument, so a cached trace never hides it.
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "causal", "blocks", "statistics"))
+def _packed_forward(q, k, v, *, heads: int, causal: bool,
+                    blocks: FlashBlocks, statistics: bool):
+    """[out [B, Tq, E]] and, with `statistics`, the log-sum-exp the backward
+    reads: [B, Tq, 128 x ceil(heads / 128)] float32, head h in lane h."""
+    B, Tq, Tk, E, D, W = _geometry(q, k, heads)
+    bq, bk = blocks
+    hpb = W // D
+    kmap = _last_k(causal, bq, bk)
+    out_specs = [pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb))]
+    out_shape = [jax.ShapeDtypeStruct((B, Tq, E), q.dtype)]
+    if statistics:
+        out_specs.append(pl.BlockSpec(
+            (1, bq, _LANES),
+            lambda b, qi, hb, ki: (b, qi, hb * hpb // _LANES)))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (B, Tq, _LANES * pl.cdiv(heads, _LANES)), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, D=D, scale=1.0 / math.sqrt(D),
+                          causal=causal),
+        grid=(B, Tq // bq, E // W, Tk // bk),
+        in_specs=[
+            pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb)),
+            pl.BlockSpec((1, bk, W),
+                         lambda b, qi, hb, ki: (b, kmap(qi, ki), hb)),
+            pl.BlockSpec((1, bk, W),
+                         lambda b, qi, hb, ki: (b, kmap(qi, ki), hb)),
+        ],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((hpb, bq, W), q.dtype),
+                        pltpu.VMEM((hpb, bq, _LANES), jnp.float32),
+                        pltpu.VMEM((hpb, bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, W), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
+        name="flash_attention_fwd",
+    )(q, k, v)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "causal", "blocks", "fused"))
+def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
+                     blocks: FlashBlocks, fused: bool):
+    """(dq, dk, dv). `fused`: one pass with dQ's accumulator for the whole
+    sequence in VMEM; else dK / dV and dQ in a pass each."""
+    B, Tq, Tk, E, D, W = _geometry(q, k, heads)
+    bq, bk = blocks
+    hpb = W // D
+    kernel = functools.partial(_bwd_kernel, D=D, scale=1.0 / math.sqrt(D),
+                               causal=causal)
+
+    def specs(qrow, krow):
+        """In specs of (q, k, v, o, do, lse) given the index maps' q and k
+        block for a grid point."""
+        def at(row, lane=lambda hb: hb):
+            return lambda b, hb, i, j: (b, row(i, j), lane(hb))
+        qs = pl.BlockSpec((1, bq, W), at(qrow))
+        ks = pl.BlockSpec((1, bk, W), at(krow))
+        stat = pl.BlockSpec((1, bq, _LANES),
+                            at(qrow, lane=lambda hb: hb * hpb // _LANES))
+        return [qs, ks, ks, qs, qs, stat], qs, ks
+
+    # dK and dV (and, fused, dQ): k blocks outside, q blocks inside
+    qmap = _first_q(causal, bq, bk)
+    ins, _, kspec = specs(qmap, lambda ki, qi: ki)
+    outs = [kspec, kspec]
+    shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+              jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    scratch = [pltpu.VMEM((hpb, bk, W), k.dtype),
+               pltpu.VMEM((hpb, bk, W), v.dtype),
+               pltpu.VMEM((bk, W), jnp.float32),
+               pltpu.VMEM((bk, W), jnp.float32)]
+    if fused:
+        outs.append(pl.BlockSpec((1, Tq, W), lambda b, hb, ki, qi: (b, 0, hb)))
+        shapes.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch.append(pltpu.VMEM((Tq, W), jnp.float32))
+    got = pl.pallas_call(
+        functools.partial(kernel, want="all" if fused else "dkv"),
+        grid=(B, E // W, Tk // bk, Tq // bq),
+        in_specs=ins, out_specs=outs, out_shape=shapes,
+        scratch_shapes=scratch,
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
+        name="flash_attention_bwd" if fused else "flash_attention_bwd_dkv",
+    )(q, k, v, o, do, lse)
+    if fused:
+        dk, dv, dq = got
+        return dq, dk, dv
+    dk, dv = got
+    kmap = _last_k(causal, bq, bk)
+    ins, qspec, _ = specs(lambda qi, ki: qi, kmap)
+    dq = pl.pallas_call(
+        functools.partial(kernel, want="dq"),
+        grid=(B, E // W, Tq // bq, Tk // bk),
+        in_specs=ins, out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, W), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        name="flash_attention_bwd_dq",
+    )(q, k, v, o, do, lse)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _packed_attention(q, k, v, heads: int, causal: bool):
+    """The fused kernels over packed [B, T, E] Q, K, V: no dispatch gate.
+    Not differentiated, the forward writes no statistics. That also keeps it
+    a launch of its own beside the differentiated forward where a program
+    holds both (`Executor` traces the forward ops twice): as one identical
+    launch XLA merges the two and with them the whole doubled forward, which
+    today's benchmark cannot take (PERF.md section 6, PR 28; ROADMAP Queue 1
+    item 3b)."""
+    return _packed_forward(
+        q, k, v, heads=heads, causal=causal, statistics=False,
+        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))[0]
+
+
+def _packed_attention_fwd(q, k, v, heads, causal):
+    o, lse = _packed_forward(
+        q, k, v, heads=heads, causal=causal, statistics=True,
+        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
+    return o, (q, k, v, o, lse)
+
+
+def _packed_attention_bwd(heads, causal, saved, do):
+    q, k, v, o, lse = saved
+    return _packed_backward(
+        q, k, v, o, lse, do, heads=heads, causal=causal,
+        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
+        fused=q.shape[1] <= _FUSED_BWD_MAX_TQ)
+
+
+_packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)
 
 
 def _flash_kernel(q, k, v, causal: bool):
-    """Direct fused-kernel call, no dispatch gate (benchmarks and the
-    eligible path both come through here)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _tpu_flash,
-    )
+    """Direct fused-kernel call over [B, T, H, D], no dispatch gate
+    (benchmarks, the tuner and the eligible path all come through here):
+    merging H and D is a free reshape to the packed layout."""
+    B, Tq, H, D = q.shape
+    pack = lambda x: x.reshape(x.shape[0], x.shape[1], H * D)  # noqa: E731
+    return _packed_attention(pack(q), pack(k), pack(v), H, causal).reshape(
+        B, Tq, H, D)
 
-    bhtd = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731
-    o = _tpu_flash(
-        bhtd(q), bhtd(k), bhtd(v), causal=causal,
-        sm_scale=float(1.0 / math.sqrt(q.shape[-1])),
-        block_sizes=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
-    )
-    return jnp.transpose(o, (0, 2, 1, 3))
+
+_DISPATCH_COUNTER = "pt_flash_attention_dispatch_total"
+_DISPATCH_HELP = ("attention ops traced, by the path the dispatcher chose "
+                  "from the input's shape (packed: the fused kernels)")
+
+
+def _count_dispatch(path: str) -> None:
+    from ..obs import metrics
+
+    metrics.registry().counter_inc(_DISPATCH_COUNTER, help=_DISPATCH_HELP,
+                                   labels={"path": path})
 
 
 def flash_attention(q, k, v, causal: bool = False):
-    """[B, T, H, D] attention. From T=1024 the v5e-block-tuned fused
-    kernel is the fast path (1.4-3.5x over XLA's fused attention fwd+bwd,
-    benchmarks/flash_block_tuning.json) as well as the O(T)-memory path;
-    below that window XLA keeps the job unless the score buffer would
-    exceed the memory threshold. Numerics: bf16 io with f32
-    online-softmax accumulation inside the kernel (matches the reference
-    formulation to bf16 eps)."""
+    """[B, T, H, D] attention. From T=1024 the fused kernels are the path
+    (and the O(T)-memory one); below that window XLA keeps the job unless
+    the score buffer would exceed the memory threshold. Numerics: the
+    inputs' dtype in and out (bf16 under AMP), float32 scores, statistics
+    and accumulators inside the kernels. The choice is made when the op is
+    traced and counted in `pt_flash_attention_dispatch_total{path}`."""
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
-    if not flash_eligible(q, k):
-        return _reference(q, k, v, causal)
     from . import mesh_dispatch
 
     am = mesh_dispatch.current()
-    if am is not None and am.dp > 1:
-        # mesh policy (ops/mesh_dispatch.py): a bare pallas_call cannot
-        # be GSPMD-partitioned, so the kernel shard_maps over dp (batch
-        # dim 0; no weights -> no cotangent psums). Under an mp axis the
-        # wrap replicates heads (a resharding GSPMD inserts); sharding
-        # heads over mp inside the wrap is a future multi-chip lever.
-        # A batch dp does not divide falls back to the XLA formulation,
-        # which GSPMD partitions natively.
-        if q.shape[0] % am.dp:
-            return _reference(q, k, v, causal)
-        import functools
-
+    # mesh policy (ops/mesh_dispatch.py): a bare pallas_call cannot be
+    # GSPMD-partitioned, so the kernel shard_maps over dp (batch dim 0; no
+    # weights -> no cotangent psums). Under an mp axis the wrap replicates
+    # heads (a resharding GSPMD inserts); sharding heads over mp inside the
+    # wrap is a future multi-chip lever. A batch dp does not divide falls
+    # back to the XLA formulation, which GSPMD partitions natively.
+    sharded = am is not None and am.dp > 1
+    if not flash_eligible(q, k) or (sharded and q.shape[0] % am.dp):
+        _count_dispatch("xla")
+        return _reference(q, k, v, causal)
+    _count_dispatch("packed")
+    if sharded:
         call = mesh_dispatch.shard_batch(
             functools.partial(_flash_kernel, causal=causal),
             (0, 0, 0), ((0, 4),))
@@ -210,8 +640,9 @@ def flash_attention(q, k, v, causal: bool = False):
 @register_op("flash_attention")
 def flash_attention_kernel(ctx):
     """Program-IR face of the dispatcher: Q/K/V are [B, T, E] packed
-    multi-head projections; num_heads splits E. Used by
-    layers.multi_head_attention (models/transformer.py)."""
+    multi-head projections; num_heads splits E (a free reshape: the kernels
+    read the packed layout). Used by layers.multi_head_attention
+    (models/transformer.py)."""
     from .. import amp
 
     # under amp Q and K may arrive float32 (from rms_norm / rotary, which

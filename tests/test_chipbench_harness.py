@@ -154,6 +154,70 @@ def test_moe_readers_on_a_hand_made_run_record():
         assert reader.compute(dict(run, trace=None)) is None
 
 
+ATTN = "flash_attention.attn.tmp_3"
+
+
+def test_attn_device_ms_on_a_hand_made_run_record():
+    """PR 28: the attention op's whole scope, kernels and the ops around
+    them, leaves only."""
+    attn = _load("layer_metrics", "attn.device_ms.py")
+    ops = [
+        _row(ATTN, 4_000_000, "tpu_custom_call"),
+        _row(ATTN, 2_000_000, "tpu_custom_call", "jvp("),
+        _row(ATTN, 10_000_000, "tpu_custom_call", "transpose(jvp("),
+        _row(ATTN, 3_000_000, None, "transpose(jvp("),        # a layout copy
+        _row(ATTN, 1_000_000, None, "jvp("),
+        _row(ATTN, 50_000_000, None, "", container=True),      # a loop: left out
+        _row("flash_attention_2.tmp_0", 7_000_000),            # another op type
+        _row(MOE, 6_000_000, "tpu_custom_call"),
+    ]
+    run = {"steps": 2, "trace": {"ops": ops}}
+    assert attn.compute(run) == pytest.approx(10.0)
+    info = attn.info(run)
+    assert (info["kernels_ms"], info["not_kernels_ms"]) == (8.0, 2.0)
+    assert info["by_pass_ms"] == pytest.approx(
+        {"plain": 2.0, "jvp": 1.5, "transpose": 6.5})
+    # nothing to read: no attention op in the trace, or no trace (a program
+    # without the scopes, an untraced run)
+    assert attn.compute({"steps": 2, "trace": {"ops": ops[-2:]}}) is None
+    assert attn.compute({"steps": 2, "trace": None}) is None
+    assert attn.compute({"steps": 2}) is None
+
+
+def test_attn_device_ms_on_the_recorded_scoped_trace():
+    """The parent's gpt2-small step as recorded by PR 26 (TPU v5 lite, seed
+    2147486001, two steps): PERF.md's 68.58 ms, 28.34 of it not kernels;
+    the kernels' part is what kernel.flash_roofline divides by."""
+    import gzip
+
+    xplane = _load("xplane.py")
+    with gzip.open(os.path.join(
+            BENCH, "testdata", "gpt2_two_steps_scoped.trace.json.gz")) as f:
+        trace = json.load(f)
+    with open(os.path.join(BENCH, "configs", "gpt2-small", "config.json")) as f:
+        config = json.load(f)
+    run = {"steps": 2, "trace": xplane.reduce(trace, chips=1),
+           "config": config, "cell": {"batch": 12, "seqlen": 1024},
+           "device": {"kind": "TPU v5 lite"}}
+    attn = _load("layer_metrics", "attn.device_ms.py")
+    assert abs(attn.compute(run) - 68.582494) < 1e-9
+    info = attn.info(run)
+    assert abs(info["kernels_ms"] - 40.242065) < 1e-9
+    assert abs(info["not_kernels_ms"] - 28.340429) < 1e-9
+    assert sum(info["by_pass_ms"].values()) == pytest.approx(68.582494)
+    flash = _load("layer_metrics", "kernel.flash_roofline.py")
+    assert flash.info(run)["kernel_ms_per_step"] == pytest.approx(
+        info["kernels_ms"])
+
+
+def test_attn_device_ms_is_in_the_manifest_for_every_cell():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = manifest["per_layer"][-1]
+    assert entry["name"] == "attn.device_ms" and entry["layer"] == "Kernels"
+    assert entry["workloads"] == [w["name"] for w in manifest["workloads"]]
+
+
 def test_load_max_over_mean_reads_the_counters_and_checks_the_sum():
     reader = _load("layer_metrics", "moe.load_max_over_mean.py")
     cfg = {"num_experts": 4, "num_experts_per_tok": 2}
