@@ -465,7 +465,6 @@ PHASES = {
 def _child_env(args) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("PT_SERVING_SIM_STEP_MS", None)
     if args.rehearse_cpu:
         env["JAX_PLATFORMS"] = "cpu"
         if args.four_chips:

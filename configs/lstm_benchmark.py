@@ -1,8 +1,7 @@
 """`paddle_tpu train --config` module: the reference's headline RNN
 benchmark (benchmark/paddle/rnn/rnn.py — embedding 128, 2x stacked LSTM
 hidden 512, batch 128, sequence length 100, vocab 30 k, Adam with L2
-decay and global-norm clipping, bf16 AMP), the configuration
-`bench.py:_build_lstm_train` measures. Only builders from
+decay and global-norm clipping, bf16 AMP). Only builders from
 `paddle_tpu.models`; weights and data come from `seed`.
 
 The reader is synthetic and learnable: a sequence's tokens are drawn

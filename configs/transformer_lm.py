@@ -1,6 +1,6 @@
 """`paddle_tpu train --config` module: the decoder-only transformer LM
-at `bench.py:_build_transformer_train`'s default widths (dim 768, 12
-heads, 12 layers, T 1024, vocab 32 k, Adam, bf16 AMP) through the
+at GPT-2 small's widths (dim 768, 12 heads, 12 layers, T 1024; vocab
+32 k, Adam, bf16 AMP) through the
 flash-attention dispatcher. Only builders from `paddle_tpu.models`;
 weights and data come from `seed`.
 

@@ -145,8 +145,8 @@ Commands:
               shapes (what the kernels dispatch under a mesh). --dry-run
               lists candidates without timing (works on any backend;
               real timing requires TPU).
-              Kernels: bahdanau (B,S,A,C), flash (Tq,Tk), conv
-              (n,cin,cout), lstm/gru (B,H), quant (M,K,N — int8).
+              Kernels: bahdanau (B,S,A,C), flash (Tq,Tk), lstm/gru
+              (B,H), quant (M,K,N — int8).
   tune export --out FILE [--cache PATH]
   tune import FILE [FILE...] [--cache PATH]
   tune merge  --out FILE IN1 [IN2...]

@@ -1,6 +1,6 @@
 """Persistent XLA compile cache, placeable from outside.
 
-Every process entry point (`cli.main`, `bench.py`, `chip_smoke.py`)
+Every process entry point (`cli.main`, `chip_smoke.py`)
 calls `enable()` once before its first compile; importing `paddle_tpu`
 never does. Directory rule — the path is part of the cache key, so it
 must not move between runs:

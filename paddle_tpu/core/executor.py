@@ -458,13 +458,7 @@ class Executor:
             FLAGS.fused_rnn_interpret,
             FLAGS.use_fused_attention,
             FLAGS.fused_attention_interpret,
-            FLAGS.fused_attention_seq_fwd,
-            FLAGS.fused_attention_seq_bwd,
             FLAGS.use_fused_conv,
-            FLAGS.fused_conv_pallas,
-            FLAGS.fused_conv_interpret,
-            FLAGS.fused_conv_dot_max_n,
-            FLAGS.stacked_lstm_single_scan,
             # every trace-affecting kernel-config source (forced
             # overrides, legacy env knobs like PT_ATTN_BBLK, the loaded
             # tuned table) collapses into one fingerprint: a tuning

@@ -188,34 +188,17 @@ define_flag("use_fused_rnn", True,
             "hl_lstm_parallel_forward fused CUDA kernels, "
             "cuda/include/hl_lstm.h:42). On by default: measured on v5e "
             "the fused train recurrence beats lax.scan 1.1-1.5x across "
-            "T/B/H/dtype (benchmarks/lstm_kernel_microbench.json: round "
-            "<=5, old shared link, not re-measured)")
+            "T/B/H/dtype (lstm_kernel_microbench: old link, rounds <= 5, "
+            "not re-measured on this chip; record in git history)")
 define_flag("fused_rnn_interpret", False,
             "testing only: allow the fused RNN kernels in pallas interpret "
             "mode on non-TPU backends")
 define_flag("use_fused_conv", True,
             "build conv+BN+ReLU towers through the fused raw-stats protocol "
-            "(pallas 1x1-conv kernels with BN prologue/epilogue — the "
+            "(ops/fused_conv_ops.py: each 1x1 conv applies the previous BN "
+            "to its operand and emits its own output's statistics — the "
             "reference's cuDNN fused-conv analogue, "
-            "gserver/layers/CudnnConvBaseLayer.cpp); ineligible shapes and "
-            "non-TPU backends fall back to identical-semantics jnp inside "
-            "the same ops")
-define_flag("fused_conv_dot_max_n", 0,
-            "run the protocol's 1x1 convs as 2-D matmuls (dot or pallas "
-            "per fused_conv_pallas) when rows N <= this. Default 0 (always "
-            "the 4-D conv_general formulation): measured in-model on v5e "
-            "(experiments/exp_dotstage.py) every threshold LOSES — dots in "
-            "a conv tower force relayouts that outweigh the dot's "
-            "isolated-chain win (exp_protomicro.py)")
-define_flag("fused_conv_pallas", False,
-            "use the hand-written Pallas fused kernel for eligible 2-D "
-            "dispatches (requires fused_conv_dot_max_n > 0). Off by "
-            "default: measured slower than XLA's own fusion of the same "
-            "raw-stats formulation at every ResNet stage shape "
-            "(experiments/exp_protomicro.py; see PERF.md round 4)")
-define_flag("fused_conv_interpret", False,
-            "testing only: allow the fused conv kernels in pallas interpret "
-            "mode on non-TPU backends")
+            "gserver/layers/CudnnConvBaseLayer.cpp)")
 define_flag("use_fused_attention", True,
             "use the fused Bahdanau attention decoder kernels when shapes "
             "are eligible and the backend is TPU (ops/bahdanau_kernels.py "
@@ -225,43 +208,6 @@ define_flag("use_fused_attention", True,
 define_flag("fused_attention_interpret", False,
             "testing only: allow the fused attention decoder kernels in "
             "pallas interpret mode on non-TPU backends")
-define_flag("fused_attention_seq_fwd", False,
-            "run the fused decoder's FORWARD as one whole-sequence pallas "
-            "kernel (grid (T, batch-tiles), hidden state in VMEM scratch "
-            "— the fused-LSTM pattern extended with the attention "
-            "prologue) instead of a per-step kernel inside lax.scan. "
-            "Off by default: measured exactly neutral at the NMT config "
-            "(256.1 vs 256.2k tok/s bs256 — the scan's per-step cost is "
-            "device-side loop overhead that the kernel's T x batch-tile "
-            "grid floor matches); kept tested for parts where dispatch "
-            "economics differ")
-define_flag("fused_attention_seq_bwd", False,
-            "run the fused decoder's BACKWARD as one whole-sequence "
-            "pallas kernel (grid (batch-tiles, T) walking timesteps "
-            "newest-first, dh carry + d(enc_proj)/d(v) accumulators in "
-            "f32 VMEM scratch) instead of a reverse lax.scan of per-step "
-            "kernels + a separate phase-2 accumulation kernel. Off by "
-            "default: measured 0.963x at the NMT config bf16 bs128 AND "
-            "bs256 (310->299k, 316->305k tok/s, experiments/"
-            "exp_megabwd.py) — it eliminates T per-step dispatches + the "
-            "phase-2 dispatch + the [T,B,Sp] dsc HBM round-trip, but "
-            "runs the GRU-cell backward matmuls at the 8-row batch tile "
-            "(MXU ~8/128 utilized) where the scan path runs them at the "
-            "full batch; the dispatch savings don't cover that. Kept "
-            "parity-tested both ways (more accurate than the scan path "
-            "vs f64 ground truth; see PERF.md round 5)")
-define_flag("stacked_lstm_single_scan", False,
-            "run the N-layer stacked_lstm op as ONE all-layers masked "
-            "scan (the stacked_lstm2 lever generalized). Off by "
-            "default: the book's [4H,4H] inter-layer concat-fc "
-            "sequentializes in-scan where the default layer-by-layer "
-            "formulation runs it as one [T*B,4H] batched matmul, and "
-            "measured at the book config (hid=128 bs128, experiments/"
-            "exp_stacked_book.py) neither formulation separates from "
-            "the noise floor (0.79x-1.30x across identical runs; "
-            "measured in an early round on a link that is gone; not "
-            "re-measured), so the batched default stands on the "
-            "structural argument")
 define_flag("use_tuned_table", True,
             "consult the persistent tuned-config table (paddle_tpu.tune, "
             "`paddle_tpu tune`) for kernel tile/block choices before the "
@@ -277,10 +223,3 @@ define_flag("tune_interpolate", True,
             "consult is recorded as source=interpolated in "
             "pt_tune_consults_total. Set 0 to restrict lookups to exact "
             "shape signatures (A/B escape hatch)")
-define_flag("bn_bf16_stats", True,
-            "batch_norm stats: square in the io dtype with f32 reduction "
-            "accumulation instead of upcasting the activation first. "
-            "Default on: +3% ResNet-50 img/s at bs128, +1.5% at bs256, "
-            "neutral at bs512, same-process A/B (PERF.md r4, "
-            "experiments/exp_bnbatch.py); set 0 to restore full-f32 "
-            "stats math")

@@ -17,11 +17,11 @@ package is that layer:
   version in standby replicas, verify the meta.json program
   fingerprint, flip the router atomically, drain the old version.
 - `sim`        — in-process simulated replicas speaking the replica
-  wire protocol (process-like API) for deterministic control-plane
-  tests and the trace-driven bench.
+  wire protocol (process-like API): a fake for deterministic
+  control-plane tests, imported from `paddle_tpu.fleetctl.sim`.
 - `traces`     — seeded, bit-identically replayable load traces
   (diurnal ramps, flash crowds, heavy-tailed request lengths,
-  multi-model mixes) for `BENCH_MODEL=fleet_autoscale`.
+  multi-model mixes).
 
 `tenancy` is imported eagerly (serving/batcher.py depends on its
 class constants); the rest load lazily so the serving -> tenancy
@@ -42,7 +42,6 @@ __all__ = [
     "AutoscalerConfig",
     "RolloutError",
     "RolloutManager",
-    "SimReplica",
     "TraceSpec",
     "generate_trace",
 ]
@@ -52,7 +51,6 @@ _LAZY = {
     "AutoscalerConfig": "autoscaler",
     "RolloutError": "rollout",
     "RolloutManager": "rollout",
-    "SimReplica": "sim",
     "TraceSpec": "traces",
     "generate_trace": "traces",
 }

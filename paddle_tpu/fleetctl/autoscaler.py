@@ -30,8 +30,7 @@ something closes the loop. This module is that something:
 
 Reaction time is measured, not assumed: the loop records the interval
 from the first tick that saw pressure to the scale-up that answered
-it (`pt_autoscale_reaction_seconds` histogram + `last_reaction_s`),
-which `BENCH_MODEL=fleet_autoscale` reports and PERF.md documents.
+it (`pt_autoscale_reaction_seconds` histogram + `last_reaction_s`).
 
 Everything lands in the unified obs registry under `pt_autoscale_*`
 so one /metrics scrape on the router shows the control loop's

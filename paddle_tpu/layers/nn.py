@@ -380,8 +380,8 @@ class RawConvBN:
     normalize it — the currency of the fused conv+BN protocol
     (ops/fused_conv_ops.py). Consumers either materialize the normalized
     tensor (bn_apply: one fused elementwise pass) or hand the pair to the
-    next fused_conv_bn, which applies the normalize inside its Pallas
-    prologue (the activation is then never written normalized at all)."""
+    next fused_conv_bn, which applies the normalize to its operand as
+    it reads it (the activation is then never written normalized at all)."""
 
     __slots__ = ("out", "mean", "inv", "scale", "bias")
 

@@ -77,8 +77,8 @@ def _bottleneck(input, ch_out, stride, is_test, data_format="NCHW",
 def _bottleneck_fused(input, ch_out, stride, name=None):
     """Bottleneck through the fused raw-stats conv+BN protocol
     (ops/fused_conv_ops.py — the reference's cuDNN-fused-path analogue,
-    gserver/layers/CudnnConvBaseLayer.cpp). The two 1x1 convs run as
-    Pallas kernels emitting their BN stats from an epilogue; conv3
+    gserver/layers/CudnnConvBaseLayer.cpp). The two 1x1 convs emit
+    their BN stats beside their output; conv3
     additionally applies conv2's BN+ReLU inside its prologue, so conv2's
     output is never materialized normalized. Explicit parameter names
     (shared with the unfused path via _cbn_attrs) keep checkpoints

@@ -5,7 +5,8 @@ hand-written fused kernel (cuda/include/hl_lstm.h:42, hl_gpu_gru.cuh);
 its RecurrentGradientMachine ran the book's `simple_attention` decoder
 (trainer_config_helpers/networks.py) frame by frame. Here the analogous
 hot loop is the attention-GRU decoder scan: 51% of the NMT step
-(benchmarks/nmt_breakdown.json), dominated by materializing
+(nmt_breakdown: old link, rounds <= 5, not re-measured on this chip;
+record in git history), dominated by materializing
 `tanh(enc_proj + dec_proj)` [B, S, A] to HBM every timestep — ~6.6 MB
 written + read per step forward, and the default scan VJP additionally
 saves that tensor per step (~330 MB of residuals) and accumulates a
@@ -43,14 +44,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_kernels import _VMEM_BUDGET
-
 # trace-time dispatch counters: which formulation each (re)trace of the
 # fused decoder actually engaged. Tests reset this and assert the bench
 # geometry takes the fused path — a silent fallback to the scan (e.g. a
 # config off the eligibility grid) must fail loudly, not just run slow.
-dispatch_stats = {"fused_calls": 0, "seq_fwd": 0, "scan_fwd": 0,
-                  "seq_bwd": 0, "scan_bwd": 0}
+dispatch_stats = {"fused_calls": 0, "scan_fwd": 0, "scan_bwd": 0}
 
 
 def reset_dispatch_stats():
@@ -126,29 +124,6 @@ def _pad_s(s: int) -> int:
     from ..tune.space import pad_s  # one padding rule, shared with tuner
 
     return pad_s(s)
-
-
-def _tmask_bt(tmask_tb):
-    """[T, B] f32 target mask → [B, Tp] (T padded to a sublane multiple)
-    for the whole-sequence kernels: the per-step mask column is selected
-    in-kernel with an iota-match reduce over the resident [blk, Tp]
-    tile. A (1, blk) block of the [T, B] layout is an illegal Mosaic
-    tile (last-two block dims must be (8k, 128k) or span the array) —
-    found the day the whole-sequence kernels first met the real TPU
-    lowering; interpret mode does not check tiling."""
-    T, B = tmask_tb.shape
-    tp = ((T + 7) // 8) * 8
-    return jnp.pad(tmask_tb.astype(jnp.float32).T, [(0, 0), (0, tp - T)])
-
-
-def _tmask_col(tmask_ref, t):
-    """Select mask column t from the resident [blk, Tp] tile → [blk, 1]
-    (iota-match reduce: lane-dim dynamic slices are the one indexing
-    mode Mosaic restricts; a masked sum is layout-native)."""
-    blk, tp = tmask_ref.shape
-    sel = jax.lax.broadcasted_iota(jnp.int32, (blk, tp), 1) == t
-    return jnp.sum(jnp.where(sel, tmask_ref[:], 0.0), axis=1,
-                   keepdims=True)
 
 
 def fused_decoder_eligible(B: int, S: int, A: int, C: int, dtype) -> bool:
@@ -343,312 +318,6 @@ def _attn_phase2(ep, dp_seq, dsc_seq, v, C, interpret):
     return dep, dv[0]
 
 
-# ------------------------------------------- whole-sequence forward kernel --
-def _decoder_seq_kernel(ep_ref, enc_ref, mask_ref, xpx_ref, tmask_ref,
-                        h0_ref, wadec_ref, v_ref, wxc_ref, wur_ref, wc_ref,
-                        h_ref, alpha_ref, ctx_ref, h_s):
-    """One grid step = (timestep t, batch tile b): Bahdanau attention +
-    GRU cell entirely in VMEM, hidden state carried in scratch across t
-    (the fused-LSTM whole-sequence pattern, pallas_kernels.py, extended
-    with the attention prologue). xpx is the hoisted input half of the
-    gate projection (trg @ wx[:E] + bias — no sequential dependency)."""
-    t = pl.program_id(0)
-    b = pl.program_id(1)
-    H = h0_ref.shape[-1]
-    blk = h0_ref.shape[0]
-    rows = pl.ds(b * blk, blk)  # this tile's rows of the [B, H] scratch
-
-    @pl.when(t == 0)
-    def _():
-        h_s[rows, :] = h0_ref[:]
-
-    h = h_s[rows, :]                              # [blk, H]
-    dp = jnp.dot(h, wadec_ref[:],
-                 preferred_element_type=jnp.float32)      # [blk, A]
-    th = jnp.tanh(ep_ref[:].astype(jnp.float32) + dp[:, None, :])
-    scores = jnp.sum(th * v_ref[0].astype(jnp.float32)[None, None, :], -1)
-    scores = jnp.where(mask_ref[:] > 0, scores, -1e9)
-    m = jnp.max(scores, -1, keepdims=True)
-    e = jnp.exp(scores - m)
-    alpha = e / jnp.sum(e, -1, keepdims=True)
-    alpha_ref[:] = alpha[None]
-    enc = enc_ref[:]
-    ctx = jax.lax.dot_general(
-        alpha[:, None, :].astype(enc.dtype), enc,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]                                     # [blk, C] f32
-    ctx_c = ctx.astype(enc.dtype)
-    ctx_ref[:] = ctx_c[None]
-    # gate pre-activations: hoisted x-half + ctx-half
-    xp = xpx_ref[:][0] + jnp.dot(ctx_c, wxc_ref[:]).astype(h.dtype)
-    ur = jax.nn.sigmoid(
-        xp[..., : 2 * H]
-        + jnp.dot(h, wur_ref[:]).astype(h.dtype))
-    u, r = ur[..., :H], ur[..., H:]
-    c = jnp.tanh(
-        xp[..., 2 * H:]
-        + jnp.dot(r * h, wc_ref[:]).astype(h.dtype))
-    h_new = (1 - u) * h + u * c
-    tm = _tmask_col(tmask_ref, t).astype(h.dtype)  # [blk, 1]
-    h_out = tm * h_new + (1 - tm) * h
-    h_s[rows, :] = h_out
-    h_ref[:] = h_out[None]
-
-
-def _decoder_seq_fwd(ep, enc, maskf, xpx, tmask, h0, wa_dec, v, wx_c,
-                     w_ur, w_c, interpret):
-    B, Sp, A = ep.shape
-    C = enc.shape[-1]
-    T = xpx.shape[0]
-    H = h0.shape[-1]
-    G3 = xpx.shape[-1]
-    blk = _bblk(B, Sp, A, C, ep.dtype.itemsize)
-    nb = B // blk
-    tmask_bt = _tmask_bt(tmask)
-    tp = tmask_bt.shape[1]
-    h_seq, alpha_seq, ctx_seq = pl.pallas_call(
-        _decoder_seq_kernel,
-        grid=(T, nb),
-        in_specs=[
-            pl.BlockSpec((blk, Sp, A), lambda t, b: (b, 0, 0)),
-            pl.BlockSpec((blk, Sp, C), lambda t, b: (b, 0, 0)),
-            pl.BlockSpec((blk, Sp), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, blk, G3), lambda t, b: (t, b, 0)),
-            pl.BlockSpec((blk, tp), lambda t, b: (b, 0)),
-            pl.BlockSpec((blk, H), lambda t, b: (b, 0)),
-            pl.BlockSpec((H, A), lambda t, b: (0, 0)),
-            pl.BlockSpec((1, A), lambda t, b: (0, 0)),
-            pl.BlockSpec((C, G3), lambda t, b: (0, 0)),
-            pl.BlockSpec((H, 2 * H), lambda t, b: (0, 0)),
-            pl.BlockSpec((H, H), lambda t, b: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk, H), lambda t, b: (t, b, 0)),
-            pl.BlockSpec((1, blk, Sp), lambda t, b: (t, b, 0)),
-            pl.BlockSpec((1, blk, C), lambda t, b: (t, b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, B, H), h0.dtype),
-            jax.ShapeDtypeStruct((T, B, Sp), jnp.float32),
-            jax.ShapeDtypeStruct((T, B, C), enc.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((B, H), h0.dtype)],
-        interpret=interpret,
-    )(ep, enc, maskf, xpx, tmask_bt, h0, wa_dec, v.reshape(1, -1), wx_c,
-      w_ur, w_c)
-    return h_seq, alpha_seq, ctx_seq
-
-
-def _mega_vmem_ok(B, Sp, A, C, E, H, itemsize) -> bool:
-    """Whole-sequence forward kernel working set: resident weights +
-    streamed ep/enc tiles + f32 tanh temporaries + the full-batch [B, H]
-    hidden-state scratch + the double-buffered per-step output blocks
-    (h/alpha/ctx)."""
-    blk = _bblk(B, Sp, A, C, itemsize)
-    if blk == 0:
-        return False
-    weights = (H * A + C * 3 * H + H * 3 * H + A) * itemsize
-    streams = 2 * blk * (Sp * (A + C) + 3 * H + E) * itemsize
-    temps = 3 * blk * Sp * A * 4
-    h_scratch = B * H * itemsize
-    outs = 2 * blk * (H * itemsize + Sp * 4 + C * itemsize)
-    return weights + streams + temps + h_scratch + outs <= _VMEM_BUDGET
-
-
-# ------------------------------------------- whole-sequence backward kernel --
-def _decoder_seq_bwd_kernel(ep_ref, enc_ref, maskf_ref, g_ref, tmask_ref,
-                            hp_ref, u_ref, r_ref, c_ref, dp_ref, alpha_ref,
-                            v_ref, wc_ref, wur_ref, wxc_ref, wadec_ref,
-                            dxp_ref, dctx_ref, ddp_ref, dh0_ref, dep_ref,
-                            dv_ref, dh_s, dep_s, dv_s):
-    """One grid step = (batch tile b, reverse timestep): the ENTIRE
-    decoder backward step — GRU cell backward, attention backward, and
-    the d(enc_proj)/d(v) accumulation (the separate phase-2 kernel folded
-    in) — with the sequential dh carry held in f32 VMEM scratch. The grid
-    walks t forward; every [T, ...] BlockSpec indexes timestep T-1-t, so
-    each batch tile sees its steps newest-first while its ep/enc tiles
-    stay resident across the whole T walk. Replaces T per-step kernel
-    dispatches + T reverse-scan XLA step bodies + the phase-2 dispatch
-    with ONE kernel (the bwd analogue of _decoder_seq_kernel; the
-    fused-kernel philosophy of the reference's hl_lstm.h:42 backward)."""
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _():
-        dh_s[:] = jnp.zeros_like(dh_s)
-        dep_s[:] = jnp.zeros_like(dep_s)
-
-    @pl.when(jnp.logical_and(b == 0, t == 0))
-    def _():
-        dv_s[:] = jnp.zeros_like(dv_s)
-
-    io_dt = hp_ref.dtype
-    # f32 io: force true-f32 MXU passes so the kernel is at least as
-    # accurate as the scan path (verified vs f64 ground truth); bf16
-    # io: default precision — Mosaic rejects fp32-precision contractions
-    # on bf16 operands, and accumulation is f32 regardless
-    prec = (jax.lax.Precision.HIGHEST if io_dt == jnp.float32 else None)
-    hp = hp_ref[:][0].astype(jnp.float32)        # [blk, H]
-    u = u_ref[:][0].astype(jnp.float32)
-    r = r_ref[:][0].astype(jnp.float32)
-    c = c_ref[:][0].astype(jnp.float32)
-    g = g_ref[:][0].astype(jnp.float32)
-    tt = pl.num_programs(1) - 1 - t              # the timestep this
-    m = _tmask_col(tmask_ref, tt)                # grid step walks
-    dh = dh_s[:] + g
-    dh_cell = dh * m
-    dh_prev = dh * (1.0 - m)
-    # GRU cell backward (h = (1-u) hp + u c)
-    du = dh_cell * (c - hp)
-    dc = dh_cell * u
-    dh_prev = dh_prev + dh_cell * (1.0 - u)
-    dpre_c = dc * (1.0 - c * c)                  # [blk, H]
-    drh = jax.lax.dot_general(                   # dpre_c @ w_c.T
-        dpre_c.astype(io_dt), wc_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=prec)
-    dr = drh * hp
-    dh_prev = dh_prev + drh * r
-    dpre_u = du * u * (1.0 - u)
-    dpre_r = dr * r * (1.0 - r)
-    dur = jnp.concatenate([dpre_u, dpre_r], -1)  # [blk, 2H]
-    dh_prev = dh_prev + jax.lax.dot_general(     # dur @ w_ur.T
-        dur.astype(io_dt), wur_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=prec)
-    dxp = jnp.concatenate([dur, dpre_c], -1)     # [blk, 3H]
-    dxp_ref[:] = dxp.astype(dxp_ref.dtype)[None]
-    dctx = jax.lax.dot_general(                  # dxp @ wx_ctx.T
-        dxp.astype(io_dt), wxc_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=prec)      # [blk, C]
-    dctx_ref[:] = dctx.astype(dctx_ref.dtype)[None]
-    # attention backward + fused dep/dv accumulation
-    enc = enc_ref[:]
-    dalpha = jax.lax.dot_general(
-        dctx[:, None, :].astype(enc.dtype), enc,
-        (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-        precision=prec)[:, 0, :]     # [blk, Sp]
-    alpha = alpha_ref[:][0]                              # [blk, Sp] f32
-    tot = jnp.sum(alpha * dalpha, -1, keepdims=True)
-    dsc = alpha * (dalpha - tot)
-    dsc = jnp.where(maskf_ref[:] > 0, dsc, 0.0)
-    th = jnp.tanh(ep_ref[:].astype(jnp.float32)
-                  + dp_ref[:][0].astype(jnp.float32)[:, None, :])
-    omt2 = 1.0 - th * th                                 # [blk, Sp, A]
-    v = v_ref[0].astype(jnp.float32)
-    ddp = jax.lax.dot_general(
-        dsc[:, None, :].astype(omt2.dtype), omt2,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-        precision=prec)[:, 0, :] * v[None, :]
-    ddp_ref[:] = ddp.astype(ddp_ref.dtype)[None]
-    dh_prev = dh_prev + jax.lax.dot_general(     # ddp @ wa_dec.T
-        ddp.astype(io_dt), wadec_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=prec)
-    dep_s[:] = dep_s[:] + dsc[:, :, None] * omt2 * v[None, None, :]
-    dv_s[:] = dv_s[:] + jnp.sum(th * dsc[:, :, None], axis=(0, 1))[None, :]
-    dh_s[:] = dh_prev
-
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _():
-        dh0_ref[:] = dh_prev.astype(dh0_ref.dtype)
-        dep_ref[:] = dep_s[:].astype(dep_ref.dtype)
-
-    @pl.when(jnp.logical_and(b == pl.num_programs(0) - 1,
-                             t == pl.num_programs(1) - 1))
-    def _():
-        dv_ref[:] = dv_s[:]
-
-
-def _decoder_seq_bwd(ep, enc, maskf, g_seq, tmask, hp_seq, u_seq, r_seq,
-                     c_seq, dp_seq, alpha_seq, v, w_c, w_ur, wx_c, wa_dec,
-                     h0_dtype, interpret):
-    B, Sp, A = ep.shape
-    C = enc.shape[-1]
-    T, _, H = hp_seq.shape
-    dt = hp_seq.dtype
-    blk = _bblk(B, Sp, A, C, ep.dtype.itemsize)
-    nb = B // blk
-    tmask_bt = _tmask_bt(tmask)
-    tp = tmask_bt.shape[1]
-    return pl.pallas_call(
-        _decoder_seq_bwd_kernel,
-        grid=(nb, T),
-        in_specs=[
-            pl.BlockSpec((blk, Sp, A), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((blk, Sp, C), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((blk, Sp), lambda b, t: (b, 0)),
-            pl.BlockSpec((1, blk, H), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((blk, tp), lambda b, t: (b, 0)),
-            pl.BlockSpec((1, blk, H), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, H), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, H), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, H), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, A), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, Sp), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, A), lambda b, t: (0, 0)),
-            pl.BlockSpec((H, H), lambda b, t: (0, 0)),
-            pl.BlockSpec((H, 2 * H), lambda b, t: (0, 0)),
-            pl.BlockSpec((C, 3 * H), lambda b, t: (0, 0)),
-            pl.BlockSpec((H, A), lambda b, t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk, 3 * H), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, C), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((1, blk, A), lambda b, t: (T - 1 - t, b, 0)),
-            pl.BlockSpec((blk, H), lambda b, t: (b, 0)),
-            pl.BlockSpec((blk, Sp, A), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, A), lambda b, t: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, B, 3 * H), dt),
-            jax.ShapeDtypeStruct((T, B, C), dt),
-            jax.ShapeDtypeStruct((T, B, A), dt),
-            jax.ShapeDtypeStruct((B, H), h0_dtype),
-            jax.ShapeDtypeStruct((B, Sp, A), ep.dtype),
-            jax.ShapeDtypeStruct((1, A), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk, H), jnp.float32),
-            pltpu.VMEM((blk, Sp, A), jnp.float32),
-            pltpu.VMEM((1, A), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ep, enc, maskf, g_seq, tmask_bt, hp_seq, u_seq, r_seq, c_seq,
-      dp_seq, alpha_seq, v.reshape(1, -1), w_c, w_ur, wx_c, wa_dec)
-
-
-def _mega_bwd_vmem_ok(B, Sp, A, C, H, T, itemsize) -> bool:
-    """Whole-sequence backward kernel working set: resident ep/enc tiles
-    + resident weights + f32 scratch (dh, dep accumulator, dv) + f32
-    tanh/omt2/dep-term temporaries + double-buffered per-step streams
-    and output blocks + the resident [blk, Tp] f32 tmask tile + the
-    once-written dep/dh0 output blocks."""
-    blk = _bblk(B, Sp, A, C, itemsize)
-    if blk == 0:
-        return False
-    tiles = blk * Sp * (A + C) * itemsize
-    weights = (H * H + 2 * H * H + C * 3 * H + H * A + A) * itemsize
-    scratch = (blk * H + blk * Sp * A + A) * 4
-    temps = 3 * blk * Sp * A * 4
-    # alpha streams at f32 regardless of io dtype
-    streams = 2 * blk * ((5 * H + A + 1) * itemsize + Sp * 4)
-    # the [blk, Tp] tmask tile stays resident across the whole T walk
-    # (f32, T padded to a sublane multiple); small at bench T but a
-    # long-T config must not pass the model and then fail Mosaic's
-    # VMEM allocation at compile time
-    tmask = blk * (((T + 7) // 8) * 8) * 4
-    outs = 2 * blk * (3 * H + C + A) * itemsize \
-        + blk * Sp * A * itemsize + blk * H * itemsize + A * 4
-    return tiles + weights + scratch + temps + streams + tmask + outs \
-        <= _VMEM_BUDGET
-
-
 # -------------------------------------------------- the decoder, custom VJP --
 def _gru_fwd_step(xp, h_prev, wh, H):
     w_ur, w_c = wh[:, : 2 * H], wh[:, 2 * H:]
@@ -677,24 +346,7 @@ def _decoder_fn(interpret: bool):
     """
 
     def forward(enc, ep, maskf, trg, tmask, h0, wa_dec, v, wx, wh, bias):
-        from ..flags import FLAGS
-
         H = h0.shape[-1]
-        E = trg.shape[-1]
-        B, Sp, A = ep.shape
-        if FLAGS.fused_attention_seq_fwd and _mega_vmem_ok(
-                B, Sp, A, enc.shape[-1], E, H, ep.dtype.itemsize):
-            # whole-sequence kernel: every per-step dispatch collapses
-            # into one pallas_call; the x-half of the gate projection
-            # has no sequential dependency and hoists to one batched
-            # matmul
-            dispatch_stats["seq_fwd"] += 1
-            xpx = (jnp.dot(trg, wx[:E]).astype(trg.dtype) + bias)
-            return _decoder_seq_fwd(
-                ep, enc, maskf, xpx, tmask.astype(jnp.float32), h0,
-                wa_dec, v, wx[E:], wh[:, : 2 * H], wh[:, 2 * H:],
-                interpret)
-
         dispatch_stats["scan_fwd"] += 1
 
         def step(h_prev, inp):
@@ -726,11 +378,9 @@ def _decoder_fn(interpret: bool):
         return h_seq, res
 
     def bwd(res, g_seq):
-        from ..flags import FLAGS
-
         (enc, ep, maskf, trg, tmask, h0, wa_dec, v, wx, wh, bias,
          h_seq, alpha_seq, ctx_seq) = res
-        T, B, H = h_seq.shape
+        H = h_seq.shape[-1]
         E = trg.shape[-1]
         dt = h_seq.dtype
         g_seq = g_seq.astype(dt)
@@ -747,59 +397,45 @@ def _decoder_fn(interpret: bool):
         c_seq = jnp.tanh(
             xp_seq[..., 2 * H:] + jnp.dot(rh_seq, w_c).astype(dt))
 
-        if FLAGS.fused_attention_seq_bwd and _mega_bwd_vmem_ok(
-                B, ep.shape[1], ep.shape[-1], enc.shape[-1], H, T,
-                ep.dtype.itemsize):
-            # whole-sequence backward kernel: the reverse dh chain, the
-            # per-step attention backward, AND the phase-2 dep/dv
-            # accumulation run in ONE pallas_call (T per-step dispatches
-            # + the phase-2 dispatch collapse into a single kernel)
-            dispatch_stats["seq_bwd"] += 1
-            (dxp_seq, dctx_seq, ddp_seq, dh0, dep, dv2) = _decoder_seq_bwd(
-                ep, enc, maskf, g_seq, tmask.astype(jnp.float32), hp_seq,
-                u_seq, r_seq, c_seq, dp_seq, alpha_seq, v,
-                w_c, w_ur, wx[E:], wa_dec, h0.dtype, interpret)
-            dv = dv2[0]
-        else:
-            dispatch_stats["scan_bwd"] += 1
+        dispatch_stats["scan_bwd"] += 1
 
-            def back_step(dh_carry, inp):
-                g_t, m_t, hp, u, r, c, dp, alpha = inp
-                dh = dh_carry + g_t
-                m = m_t[:, None].astype(dt)
-                dh_cell = dh * m
-                dh_prev = dh * (1 - m)
-                # GRU cell backward (h = (1-u) hp + u c)
-                du = dh_cell * (c - hp)
-                dc = dh_cell * u
-                dh_prev = dh_prev + dh_cell * (1 - u)
-                dpre_c = dc * (1 - c * c)
-                drh = jnp.dot(dpre_c, w_c.T).astype(dt)
-                dr = drh * hp
-                dh_prev = dh_prev + drh * r
-                dpre_u = du * u * (1 - u)
-                dpre_r = dr * r * (1 - r)
-                dur = jnp.concatenate([dpre_u, dpre_r], -1)
-                dh_prev = dh_prev + jnp.dot(dur, w_ur.T).astype(dt)
-                dxp = jnp.concatenate([dur, dpre_c], -1)      # [B,3H]
-                dctx = jnp.dot(dxp, wx[E:].T).astype(dt)
-                # attention backward, step-local outputs only
-                ddp, dsc = _attn_bwd_step(ep, enc, dp, v, maskf, dctx,
-                                          alpha, interpret)
-                dh_prev = dh_prev + jnp.dot(ddp, wa_dec.T).astype(dt)
-                return dh_prev, (dxp, dctx, dsc, ddp)
+        def back_step(dh_carry, inp):
+            g_t, m_t, hp, u, r, c, dp, alpha = inp
+            dh = dh_carry + g_t
+            m = m_t[:, None].astype(dt)
+            dh_cell = dh * m
+            dh_prev = dh * (1 - m)
+            # GRU cell backward (h = (1-u) hp + u c)
+            du = dh_cell * (c - hp)
+            dc = dh_cell * u
+            dh_prev = dh_prev + dh_cell * (1 - u)
+            dpre_c = dc * (1 - c * c)
+            drh = jnp.dot(dpre_c, w_c.T).astype(dt)
+            dr = drh * hp
+            dh_prev = dh_prev + drh * r
+            dpre_u = du * u * (1 - u)
+            dpre_r = dr * r * (1 - r)
+            dur = jnp.concatenate([dpre_u, dpre_r], -1)
+            dh_prev = dh_prev + jnp.dot(dur, w_ur.T).astype(dt)
+            dxp = jnp.concatenate([dur, dpre_c], -1)      # [B,3H]
+            dctx = jnp.dot(dxp, wx[E:].T).astype(dt)
+            # attention backward, step-local outputs only
+            ddp, dsc = _attn_bwd_step(ep, enc, dp, v, maskf, dctx,
+                                      alpha, interpret)
+            dh_prev = dh_prev + jnp.dot(ddp, wa_dec.T).astype(dt)
+            return dh_prev, (dxp, dctx, dsc, ddp)
 
-            dh0, (dxp_seq, dctx_seq, dsc_seq, ddp_seq) = jax.lax.scan(
-                back_step,
-                jnp.zeros_like(h0),
-                (g_seq, tmask, hp_seq, u_seq, r_seq, c_seq, dp_seq,
-                 alpha_seq),
-                reverse=True,
-            )
-            # the [B,Sp,A]-sized gradient, written exactly once
-            dep, dv = _attn_phase2(ep, dp_seq, dsc_seq, v, enc.shape[-1],
-                                   interpret)
-        # ---- shared tail: dx + batched parameter grads -----------------
+        dh0, (dxp_seq, dctx_seq, dsc_seq, ddp_seq) = jax.lax.scan(
+            back_step,
+            jnp.zeros_like(h0),
+            (g_seq, tmask, hp_seq, u_seq, r_seq, c_seq, dp_seq,
+             alpha_seq),
+            reverse=True,
+        )
+        # the [B,Sp,A]-sized gradient, written exactly once
+        dep, dv = _attn_phase2(ep, dp_seq, dsc_seq, v, enc.shape[-1],
+                               interpret)
+        # ---- dx + batched parameter grads ------------------------------
         dx_seq = jnp.einsum("tbg,eg->tbe", dxp_seq, wx[:E]).astype(dt)
         dwx = jnp.einsum("tbi,tbg->ig", xin_seq, dxp_seq)
         dbias = jnp.sum(dxp_seq, (0, 1))

@@ -35,9 +35,7 @@ fused Bahdanau decoder (bahdanau_kernels.py), and flash attention
 (flash_ops.py — wrapped over dp only; it has no weight operands, so no
 cotangent psums, and under an mp axis the wrap replicates heads — a
 GSPMD-inserted reshard; head-sharding inside the wrap is a named
-multi-chip lever). The opt-in fused-conv pallas kernel
-(fused_conv_ops.py, measured-off by default) is NOT wrapped: under a
-mesh it falls back to its identical-semantics jnp formulation.
+multi-chip lever).
 
 Reference counterpart: MultiGradientMachine ran one replica per GPU
 and ring-reduced gradients (gserver/gradientmachines/

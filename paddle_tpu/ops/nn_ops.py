@@ -168,17 +168,11 @@ def batch_norm_kernel(ctx):
     if is_test:
         mean, var = mean_v, var_v
     else:
-        from ..flags import FLAGS
-
-        if FLAGS.bn_bf16_stats:
-            # escape-route experiment (PERF.md r4): square in the io
-            # dtype, reduce with f32 accumulation, E[x^2]-E[x]^2 var
-            mean = jnp.mean(x, axis=axes, dtype=jnp.float32)
-            sq = jnp.mean(x * x, axis=axes, dtype=jnp.float32)
-            var = jnp.maximum(sq - mean * mean, 0.0)
-        else:
-            mean = jnp.mean(x32, axis=axes)
-            var = jnp.var(x32, axis=axes)
+        # square in the io dtype, reduce with f32 accumulation,
+        # E[x^2]-E[x]^2 var
+        mean = jnp.mean(x, axis=axes, dtype=jnp.float32)
+        sq = jnp.mean(x * x, axis=axes, dtype=jnp.float32)
+        var = jnp.maximum(sq - mean * mean, 0.0)
         new_mean = momentum * mean_v + (1 - momentum) * mean
         new_var = momentum * var_v + (1 - momentum) * var
         # running stats flow back into the Scope as persistables

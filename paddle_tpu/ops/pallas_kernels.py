@@ -41,7 +41,7 @@ def _interpret() -> bool:
 
 
 def backend_ok(interpret_flag: str) -> bool:
-    """Shared dispatch gate for every fused-kernel family (RNN, conv,
+    """Shared dispatch gate for every fused-kernel family (RNN,
     attention): interpret mode exists for tests; production dispatch must
     not send CPU/GPU users through the pure-Python interpreter when the
     XLA formulation is sitting right there. `interpret_flag` names that
@@ -61,7 +61,8 @@ def _backend_ok() -> bool:
 # overflow; GRU f32 H=1280 B=128 → 25.6M vs observed 25.0M overflow;
 # LSTM bf16 H=1280 B=256 → 24.2M vs the microbench fused_error row;
 # GRU bf16 H=1280 B=128 → 14.7M, compiles and wins 1.88x
-# (benchmarks/rnn_kernel_microbench.json). The budget keeps a 1M safety
+# (rnn_kernel_microbench: old link, rounds <= 5, not re-measured on this
+# chip; record in git history). The budget keeps a 1M safety
 # margin below the hardware's 16M: LSTM bf16 H=1280 B=64 models at 15.9M
 # and was observed BOTH compiling (152k tok/s) and overflowing by 824K
 # on different compiles of the same graph — borderline configs flip with
@@ -129,8 +130,9 @@ def lstm_supported(B: int, H: int, gate_act, cell_act, cand_act, peep,
     tuned = _tuned_fused("lstm", B, H, itemsize)
     if tuned is not None:
         return tuned
-    # measured window (benchmarks/rnn_kernel_microbench.json, round 3
-    # with the outer-einsum dW past H=640): 1.02x at H=512, 1.45x at
+    # measured window (rnn_kernel_microbench, round 3: old link, rounds
+    # <= 5, not re-measured on this chip; record in git history; with
+    # the outer-einsum dW past H=640): 1.02x at H=512, 1.45x at
     # 768, 1.60x at 1024, 1.13x at 1280 — the reference's largest
     # published config (benchmark/README.md:129-136) now eligible at
     # bf16; H=256 still loses (0.86x, r2 data): the per-step matmul
@@ -153,8 +155,9 @@ def gru_supported(B: int, H: int, gate_act, cand_act,
     tuned = _tuned_fused("gru", B, H, itemsize)
     if tuned is not None:
         return tuned
-    # measured window (benchmarks/rnn_kernel_microbench.json, round 3
-    # with the hand-written reverse-time backward kernel replacing the
+    # measured window (rnn_kernel_microbench, round 3: old link, rounds
+    # <= 5, not re-measured on this chip; record in git history; with
+    # the hand-written reverse-time backward kernel replacing the
     # scan-replay VJP): 1.18x at H=128, 1.06x at 256, 1.72x at 512
     # (the NMT config), 1.70x at 640, 1.24x at 768, 1.61x at 1024,
     # 1.88x at 1280. H=384 alone dips to 0.86x (3H=1152 tiles badly

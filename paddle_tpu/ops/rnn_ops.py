@@ -163,8 +163,8 @@ def stacked_lstm2_scan(x_tbh, mask, w1, b1, wx2, w2, b2):
     """Two stacked LSTM layers in ONE masked scan: layer 2's input
     projection (h1 @ wx2) runs inside the step, so the sequential step
     count is T instead of 2T. Measured ≈1.2× on the recurrence at
-    dispatch-floor-bound cells (experiments/exp_lstm_smallcell.py,
-    PERF.md r4 small-cell section). Standard gates only (sigmoid/tanh,
+    dispatch-floor-bound cells (exp_lstm_smallcell: old link, rounds
+    <= 5, not re-measured on this chip; record in git history). Standard gates only (sigmoid/tanh,
     no peepholes, forward)."""
     T, B, H4 = x_tbh.shape
     H = H4 // 4
@@ -229,58 +229,6 @@ def stacked_lstm2_kernel(ctx):
     ctx.set_output("Hidden", LoDArray.from_batch(h2_seq, mask, x))
 
 
-def stacked_lstm_book_scan(x_tbh, mask, ws, bs, was, wbs, fbs):
-    """N stacked LSTM layers in ONE masked scan, with the book's
-    inter-layer structure (understand_sentiment stacked_lstm_net):
-    layer i's gate projection fc_i = fc_{i-1} @ WA_i + h_{i-1} @ WB_i
-    (+ bias) — the concat-fc over [fc_prev, lstm_prev] — computed
-    inside the step, so the sequential step count is T instead of nT.
-    Returns (fc_n_seq, h_n_seq): the book pools BOTH streams.
-    Standard gates only (sigmoid/tanh, forward) — the book's config."""
-    T, B, H4 = x_tbh.shape
-    H = H4 // 4
-    n = len(ws)
-    dt = x_tbh.dtype
-    ws = [w.astype(dt) for w in ws]
-    bs = [None if b is None else b.astype(dt) for b in bs]
-    was = [w.astype(dt) for w in was]
-    wbs = [w.astype(dt) for w in wbs]
-    fbs = [None if b is None else b.astype(dt) for b in fbs]
-    z = jnp.zeros((B, H), dt)
-
-    def cell(x_t, h_prev, c_prev, w, b, m):
-        gates = x_t + jnp.dot(
-            h_prev, w, preferred_element_type=jnp.float32).astype(dt)
-        if b is not None:
-            gates = gates + b
-        i, f, g, o = jnp.split(gates, 4, axis=-1)
-        i, f, o = (jax.nn.sigmoid(v) for v in (i, f, o))
-        c = f * c_prev + i * jnp.tanh(g)
-        h = o * jnp.tanh(c)
-        return m * h + (1 - m) * h_prev, m * c + (1 - m) * c_prev
-
-    def step(carry, inp):
-        states = list(carry)  # [(h_i, c_i)] * n
-        x_t, m_t = inp
-        m = m_t[:, None].astype(dt)
-        fc = x_t
-        for i in range(n):
-            if i > 0:
-                fc = (jnp.dot(fc, was[i - 1],
-                              preferred_element_type=jnp.float32)
-                      + jnp.dot(states[i - 1][0], wbs[i - 1],
-                                preferred_element_type=jnp.float32)
-                      ).astype(dt)
-                if fbs[i - 1] is not None:
-                    fc = fc + fbs[i - 1]
-            states[i] = cell(fc, *states[i], ws[i], bs[i], m)
-        return tuple(states), (fc, states[-1][0])
-
-    init = tuple((z, z) for _ in range(n))
-    _, (fc_seq, h_seq) = jax.lax.scan(step, init, (x_tbh, mask))
-    return fc_seq, h_seq
-
-
 @register_op("stacked_lstm")
 def stacked_lstm_kernel(ctx):
     """N-layer book-structure stacked LSTM (reference: fluid book
@@ -289,19 +237,16 @@ def stacked_lstm_kernel(ctx):
     kernel where eligible (else a masked scan), with the inter-layer
     concat-fc as a BATCHED matmul over the full [T, B, ·] sequence.
 
-    Measured (experiments/exp_stacked_book.py; in an early round on a
-    link that is gone; not re-measured): at the book's dispatch-bound
-    hid=128 no formulation separates from the noise floor (op-vs-
-    per-layer swung 0.79x-1.30x across identical interleaved runs);
-    at hid=512 the op is stably neutral (1.01x). The layer-by-layer
-    default stands on the structural argument: the book's [4H, 4H]
-    concat-fc runs as ONE [T*B, 4H] batched matmul per layer here,
-    where the stacked_lstm2-style single scan would run it as T
-    sequential [B, 4H] matmuls. (stacked_lstm2's pure stack won its
-    trade 1.25-1.46x — far above this noise floor — because its
-    inter-layer op is the thin [H, 4H] projection.) The single-scan
-    formulation stays available under FLAGS.stacked_lstm_single_scan,
-    parity-tested.
+    Measured (exp_stacked_book: old link, rounds <= 5, not re-measured
+    on this chip; record in git history): at the book's dispatch-bound
+    hid=128 a single-scan formulation did not separate from the noise
+    floor, and at hid=512 it was neutral (1.01x).
+    The layer-by-layer form stands on the structural argument: the
+    book's [4H, 4H] concat-fc runs as ONE [T*B, 4H] batched matmul per
+    layer here, where a stacked_lstm2-style single scan would run it as
+    T sequential [B, 4H] matmuls. (stacked_lstm2's pure stack won its
+    trade 1.25-1.46x because its inter-layer op is the thin [H, 4H]
+    projection.)
 
     Inputs: Input (layer-1 [*, 4H] projection, LoDArray), Weights (n of
     [H, 4H]), WAs (n-1 of [4H, 4H]: fc_prev half of the inter-layer
@@ -320,31 +265,27 @@ def stacked_lstm_kernel(ctx):
     x_tb, mask = x.to_batch(max_len=max_len)
     B, H = x_tb.shape[1], ws[0].shape[0]
     dt = x_tb.dtype
-    if FLAGS.stacked_lstm_single_scan:
-        fc_seq, h_seq = stacked_lstm_book_scan(
-            x_tb, mask, ws, bs, was, wbs, fbs)
-    else:
-        fused = FLAGS.use_fused_rnn and pallas_kernels.lstm_supported(
-            mesh_dispatch.local_batch(B), H, "sigmoid", "tanh", "tanh",
-            None, itemsize=x_tb.dtype.itemsize)
-        fc_seq = x_tb
-        h_seq = None
-        for i in range(n):
-            if i > 0:
-                fc_seq = (jnp.dot(fc_seq, was[i - 1].astype(dt),
-                                  preferred_element_type=jnp.float32)
-                          + jnp.dot(h_seq, wbs[i - 1].astype(dt),
-                                    preferred_element_type=jnp.float32)
-                          ).astype(dt)
-                if fbs[i - 1] is not None:
-                    fc_seq = fc_seq + fbs[i - 1].astype(dt)
-            if fused:
-                h_seq, _ = pallas_kernels.lstm_fused(fc_seq, mask, ws[i],
-                                                     bias=bs[i])
-            else:
-                h_seq, _ = lstm_scan(
-                    fc_seq, mask, ws[i].astype(dt),
-                    None if bs[i] is None else bs[i].astype(dt))
+    fused = FLAGS.use_fused_rnn and pallas_kernels.lstm_supported(
+        mesh_dispatch.local_batch(B), H, "sigmoid", "tanh", "tanh",
+        None, itemsize=x_tb.dtype.itemsize)
+    fc_seq = x_tb
+    h_seq = None
+    for i in range(n):
+        if i > 0:
+            fc_seq = (jnp.dot(fc_seq, was[i - 1].astype(dt),
+                              preferred_element_type=jnp.float32)
+                      + jnp.dot(h_seq, wbs[i - 1].astype(dt),
+                                preferred_element_type=jnp.float32)
+                      ).astype(dt)
+            if fbs[i - 1] is not None:
+                fc_seq = fc_seq + fbs[i - 1].astype(dt)
+        if fused:
+            h_seq, _ = pallas_kernels.lstm_fused(fc_seq, mask, ws[i],
+                                                 bias=bs[i])
+        else:
+            h_seq, _ = lstm_scan(
+                fc_seq, mask, ws[i].astype(dt),
+                None if bs[i] is None else bs[i].astype(dt))
     ctx.set_output("FcOut", LoDArray.from_batch(fc_seq, mask, x))
     ctx.set_output("Hidden", LoDArray.from_batch(h_seq, mask, x))
 

@@ -45,8 +45,7 @@ def make_mesh(
 
 def parse_mesh_spec(spec: str) -> Tuple[Tuple[str, int], ...]:
     """"dp4,pp2" -> (("dp", 4), ("pp", 2)) — the textual mesh vocabulary
-    shared by bench.py's BENCH_MESH, `cli serve --mesh`, and
-    `cli train --mesh`. Axis names are restricted to KNOWN_AXES."""
+    shared by `cli serve --mesh` and `cli train --mesh`. Axis names are restricted to KNOWN_AXES."""
     import re
 
     axes = []

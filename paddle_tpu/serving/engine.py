@@ -39,7 +39,6 @@ is visible in /metrics rather than silent.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -264,16 +263,6 @@ class ServingEngine:
         self._scheduler = None
         self.metrics = metrics or MetricSet(
             stat_set=profiler.global_stat_set())
-        # fleet-bench CPU proxy: with PT_SERVING_SIM_STEP_MS set, every
-        # engine call pays that much wall time inside the lock (sleep —
-        # GIL released), standing in for the per-dispatch device latency
-        # a real accelerator replica would serialize on. This is what
-        # makes QPS-vs-replicas measurable on a 1-core CI host: the
-        # router/fleet plumbing under test is host-side, the simulated
-        # device time scales per-replica exactly like real chips do.
-        # Never set in production; bench.py serving_scale documents it.
-        self._sim_step_s = float(
-            os.environ.get("PT_SERVING_SIM_STEP_MS", "0")) / 1e3
         self._lock = threading.RLock()
         self._seen_buckets: Dict[tuple, int] = {}
         self.cache_hits = 0
@@ -401,8 +390,6 @@ class ServingEngine:
             # failure — it must fan out to the batch, feed the circuit
             # breaker, and surface as HTTP 500, never wedge the worker
             faults.fire("serving.predict", model=self.model_name)
-            if self._sim_step_s:
-                time.sleep(self._sim_step_s)  # fleet-bench device proxy
             if bucketed:
                 padded, n, seq_lens = self._pad_feed(feed)
                 nb = next(iter(padded.values())).shape[0]

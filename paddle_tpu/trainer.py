@@ -502,13 +502,13 @@ class Trainer:
         self._ckpt_writer = _CheckpointWriter()
         # host-sync accounting: every sanctioned d2h fence (per-step
         # reads, cadence syncs, lazy-cost materializations) increments
-        # this — bench.py's train_loop microbench asserts the async loop
+        # this — tests/test_async_trainer.py asserts the async loop
         # fences strictly less often than the sync loop
         self.host_sync_count = 0
         # host-dispatch accounting: every Executor.run / run_window the
         # step loop issues. The scan-window acceptance test is counted in
-        # THIS unit: K fused steps = 1 dispatch (bench train_loop asserts
-        # scan <= async dispatches; PERF.md 'Breaking the dispatch floor')
+        # THIS unit: K fused steps = 1 dispatch (tests/test_scan_trainer.py
+        # asserts scan < async dispatches)
         self.host_dispatch_count = 0
         self._register_obs_gauges()
 

@@ -3,9 +3,8 @@ per-device config cache.
 
 Why this exists: every fused Pallas kernel in the repo picks its tile
 sizes from hand-derived analytic cost models (`_bblk` in
-ops/bahdanau_kernels.py, `_v5e_block_sizes` in ops/flash_ops.py,
-`_block_rows` in ops/fused_conv_ops.py, the measured H-windows in
-ops/pallas_kernels.py). Those models encode one device generation's
+ops/bahdanau_kernels.py, `_v5e_block_sizes` in ops/flash_ops.py, the
+measured H-windows in ops/pallas_kernels.py). Those models encode one device generation's
 measurements — the bahdanau comment itself records a 256k-vs-217k tok/s
 gap found only by hand-sweeping PT_ATTN_BBLK. CLBlast (arXiv:1705.05249)
 and the per-shape serving buckets in paddle_tpu.serving both apply the
@@ -35,7 +34,7 @@ Module layout:
                from space.py's legality model) ranks candidates, and
                successive halving with early stop times only the
                top-ranked fraction — >= 95% of exhaustive quality at
-               <= 40% of the space (tests + bench tune_search).
+               <= 40% of the space (tests/test_tune_search.py).
   cache.py     the persistent JSON table keyed by (kernel,
                shape-signature, dtype, device_kind): atomic writes,
                schema versioning, corrupt-file recovery, an in-process

@@ -94,8 +94,8 @@ def make_oracle(case: space.Case, ref, warmup: int = 2,
     before any timing (a fast-but-wrong tile must never win), then
     median-of-`iters` wall timing. Protocol: oracle(config, iters) ->
     median seconds, +inf for a config that failed numerics. The guided
-    searcher takes any callable with this protocol — tests and the CPU
-    bench leg inject search.SimulatedOracle instead, which is the whole
+    searcher takes any callable with this protocol — tests inject
+    search.SimulatedOracle instead, which is the whole
     reason the oracle is a parameter and not a hard-wired loop."""
     thunks: Dict[tuple, Any] = {}
 

@@ -31,8 +31,7 @@ proves guided-vs-exhaustive quality on a deterministic SimulatedOracle
 instead (same protocol, synthetic-but-plausible timing surface). The
 guided-search acceptance bar — >= 95% of exhaustive-search quality
 while timing <= 40% of the candidate space — is asserted against that
-oracle in tests and measured for real by bench.py
-BENCH_MODEL=tune_search.
+oracle in tests; it has not been measured on a chip.
 """
 
 from __future__ import annotations
@@ -91,18 +90,6 @@ def _features_flash(params: Dict[str, Any], cfg: Config):
     return hbm, grid, ws
 
 
-def _features_conv(params: Dict[str, Any], cfg: Config):
-    n, cin, cout = params["n"], params["cin"], params["cout"]
-    item = 2 if params.get("dtype") == "bfloat16" else 4
-    b = int(cfg["block_rows"])
-    grid = n // max(1, b)
-    # the weight panel re-streams per row block; io moves once
-    hbm = n * (cin + cout) * item + grid * cin * cout * item
-    ws = cin * cout * item + 2 * b * (cin + cout) * item \
-        + 2 * 4 * cout + 4 * cin * 4
-    return hbm, grid, ws
-
-
 def _features_rnn(kind: str):
     def f(params: Dict[str, Any], cfg: Config):
         B, H = params["B"], params["H"]
@@ -123,12 +110,9 @@ def _features_rnn(kind: str):
 
 
 def _features_quant_matmul(params: Dict[str, Any], cfg: Config):
-    """int8 GEMM features — also the bench's CPU proxy for the serving
-    fast path: with dtype 'int8' the x/w panels stream at 1 B/elem
-    (plus the f32 dequant epilogue write); the SAME formula at a float
-    dtype models the unquantized matmul the site replaced, so
-    bench.py's HBM-bytes-per-request ratio (BENCH_MODEL=serving_quant)
-    is one feature function evaluated at two itemsizes."""
+    """int8 GEMM features: with dtype 'int8' the x/w panels stream at
+    1 B/elem (plus the f32 dequant epilogue write); the SAME formula at
+    a float dtype models the unquantized matmul the site replaced."""
     M, K, N = params["M"], params["K"], params["N"]
     item = _FEATURE_ITEMSIZE.get(params.get("dtype", "int8"), 1)
     bm = int(cfg.get("block_m", M) or M)
@@ -148,7 +132,6 @@ _FEATURE_ITEMSIZE = {"int8": 1, "bfloat16": 2, "float32": 4}
 _FEATURES: Dict[str, Callable] = {
     "bahdanau_attention": _features_bahdanau,
     "flash_attention": _features_flash,
-    "fused_conv": _features_conv,
     "fused_lstm": _features_rnn("lstm"),
     "fused_gru": _features_rnn("gru"),
     "quant_matmul": _features_quant_matmul,
@@ -278,8 +261,7 @@ def guided_search(
 
 # --------------------------------------------------- simulated oracle --
 class SimulatedOracle:
-    """Deterministic synthetic timing surface for off-TPU tests and the
-    CPU leg of bench.py tune_search.
+    """Deterministic synthetic timing surface for off-TPU tests.
 
     The surface is the cost model's shape DISTORTED per config: each
     config's true time is predicted_cost times a deterministic

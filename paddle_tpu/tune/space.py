@@ -2,8 +2,8 @@
 
 THE design rule of this module: the legality model is defined ONCE and
 shared by the tuner and the runtime. `ops/bahdanau_kernels._bblk`
-imports `bahdanau_blk_legal` from here; `ops/fused_conv_ops._block_rows`
-imports `conv_rows_legal`; `ops/flash_ops` imports `flash_block_legal`.
+imports `bahdanau_blk_legal` from here; `ops/flash_ops` imports
+`flash_block_legal`.
 So a candidate this module emits is exactly a config the runtime will
 accept, and a config the runtime accepts is exactly one this module can
 enumerate — the tuner can never measure a config that later fails to
@@ -11,7 +11,7 @@ lower, and the property test (tests/test_tune.py) pins the equivalence.
 
 Legality has two ingredients per family:
 - Mosaic tile rules: the last-two-dims (8k, 128k)-or-full block-shape
-  rule (see the hard-won comments in bahdanau_kernels._tmask_bt), lane
+  rule (see `bahdanau_blk_legal`), lane
   alignment, and divide-the-array constraints;
 - the VMEM-budget working-set models lifted from the kernels (sized
   against the 15 MiB scoped budget in ops/pallas_kernels._VMEM_BUDGET,
@@ -220,82 +220,6 @@ def _flash_case(params: Params, dtype: str) -> "Case":
                 tol=5e-2 if dtype == "bfloat16" else 2e-4)
 
 
-# ----------------------------------------------------------- fused conv --
-CONV_ROW_BLOCKS = (1024, 896, 768, 640, 512, 448, 384, 320, 256, 192,
-                   128, 64, 32, 16, 8)
-
-
-def conv_rows_legal(b: int, n: int, cin: int, cout: int,
-                    itemsize: int) -> bool:
-    """Row-block legality for the fused 1x1-conv+BN kernel: tiles the
-    8-row sublane, divides n, and fits the working set (x/y blocks
-    double-buffered by the pipeline machinery, full weight panel, f32
-    accumulators) in VMEM."""
-    if b <= 0 or b % 8 or n % b:
-        return False
-    weight = cin * cout * itemsize
-    io = 2 * b * (cin + cout) * itemsize
-    return weight + io + 2 * 4 * cout + 4 * cin * 4 <= _vmem_budget()
-
-
-def conv_candidates(params: Params) -> List[Config]:
-    n, cin, cout = params["n"], params["cin"], params["cout"]
-    item = _itemsize(params["dtype"])
-    return [{"block_rows": b} for b in sorted(CONV_ROW_BLOCKS)
-            if conv_rows_legal(b, n, cin, cout, item)]
-
-
-def conv_default(params: Params) -> Optional[Config]:
-    """The runtime's analytic choice (fused_conv_ops._block_rows):
-    largest legal block in the fixed descending list."""
-    n, cin, cout = params["n"], params["cin"], params["cout"]
-    item = _itemsize(params["dtype"])
-    for b in CONV_ROW_BLOCKS:
-        if conv_rows_legal(b, n, cin, cout, item):
-            return {"block_rows": b}
-    return None
-
-
-def _conv_case(params: Params, dtype: str) -> "Case":
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops import fused_conv_ops as fc
-
-    n, cin, cout = params["n"], params["cin"], params["cout"]
-    rng = np.random.RandomState(0)
-    dt = _dtype_of(dtype)
-    x = jnp.asarray(rng.randn(n, cin) * 0.3, dt)
-    w = jnp.asarray(rng.randn(cin, cout) / np.sqrt(cin), dt)
-    pm = jnp.asarray(rng.randn(cin) * 0.1, jnp.float32)
-    pi = jnp.asarray(1.0 + 0.1 * rng.rand(cin), jnp.float32)
-    ps = jnp.asarray(1.0 + 0.1 * rng.rand(cin), jnp.float32)
-    pb = jnp.asarray(rng.randn(cin) * 0.1, jnp.float32)
-    interpret = jax.default_backend() != "tpu"
-    args = (x, w, pm, pi, ps, pb)
-
-    def make(config: Config) -> Callable[[], Any]:
-        from . import overrides
-
-        def f(x, w, pm, pi, ps, pb):
-            return fc._pallas_fwd(x, w, pm, pi, ps, pb, True, True,
-                                  interpret)
-
-        jf = jax.jit(f)
-        with overrides.forcing("fused_conv", config):
-            jf(*args)
-        return lambda: jf(*args)
-
-    def ref():
-        y, s, sq = fc._jnp_fused(x, w, pm, pi, ps, pb, True, True)
-        return [np.asarray(y, np.float32), np.asarray(s), np.asarray(sq)]
-
-    return Case("fused_conv", make, ref,
-                tol=5e-2 if dtype == "bfloat16" else 2e-4)
-
-
 # ------------------------------------------------------------- RNN cells --
 def _rnn_hard_ok(kind: str, B: int, H: int, itemsize: int) -> bool:
     """Hard (non-empirical) fused-RNN legality: tile alignment + the
@@ -328,7 +252,8 @@ def _rnn_default(kind: str):
         B, H = params["B"], params["H"]
         if not _rnn_hard_ok(kind, B, H, _itemsize(params["dtype"])):
             return {"fused": False}
-        # the measured windows (benchmarks/rnn_kernel_microbench.json)
+        # the measured windows (rnn_kernel_microbench: old link, rounds
+        # <= 5, not re-measured on this chip; record in git history)
         if kind == "lstm":
             return {"fused": 384 <= H <= 1280}
         return {"fused": 128 <= H <= 1280 and H != 384}
@@ -481,10 +406,6 @@ FAMILIES: Dict[str, KernelSpace] = {
         "flash_attention", ("Tq", "Tk"),
         flash_candidates, flash_default, _flash_case,
         doc="q/k block sizes of the TPU flash-attention kernel"),
-    "fused_conv": KernelSpace(
-        "fused_conv", ("n", "cin", "cout"),
-        conv_candidates, conv_default, _conv_case,
-        doc="row block of the fused 1x1-conv+BN kernel"),
     "fused_lstm": KernelSpace(
         "fused_lstm", ("B", "H"),
         _rnn_candidates("lstm"), _rnn_default("lstm"),
@@ -501,7 +422,7 @@ FAMILIES: Dict[str, KernelSpace] = {
 }
 
 ALIASES = {"bahdanau": "bahdanau_attention", "attention": "bahdanau_attention",
-           "flash": "flash_attention", "conv": "fused_conv",
+           "flash": "flash_attention",
            "lstm": "fused_lstm", "gru": "fused_gru",
            "quant": "quant_matmul", "int8": "quant_matmul"}
 
@@ -551,9 +472,7 @@ def cases_from_program(program=None, dp: int = 1) -> List[Dict[str, Any]]:
     mesh run misses the table and a global-batch entry tunes a shape
     that never dispatches. Batch-carrying params divide by dp;
     non-divisible sites are skipped (the runtime falls back to the
-    scan/XLA formulation there — nothing to tune). The fused-conv
-    kernel is not mesh-wrapped at all (mesh_dispatch docstring), so its
-    sites are skipped entirely under dp > 1."""
+    scan/XLA formulation there — nothing to tune)."""
     from ..core.program import default_main_program
 
     program = program or default_main_program()
@@ -579,19 +498,6 @@ def cases_from_program(program=None, dp: int = 1) -> List[Dict[str, Any]]:
                     continue
                 out.append({"family": "flash_attention",
                             "params": {"Tq": s[1], "Tk": k[1]},
-                            "dtype": amp_dt, "op": op.type})
-            elif op.type == "fused_conv_bn":
-                if dp > 1:
-                    continue  # not mesh-wrapped: falls back under a mesh
-                s = var_shape(block, op.inputs["X"][0])
-                w = var_shape(block, op.inputs["Filter"][0])
-                if not s or not w or len(s) != 4 or min(s) <= 0:
-                    continue
-                stride = int(op.attrs.get("stride", 1))
-                h, wd = s[1] // stride, s[2] // stride
-                out.append({"family": "fused_conv",
-                            "params": {"n": s[0] * h * wd, "cin": w[1],
-                                       "cout": w[0]},
                             "dtype": amp_dt, "op": op.type})
             elif op.type == "attention_gru_decoder":
                 enc = var_shape(block, op.inputs["EncState"][0])

@@ -3,7 +3,7 @@
 Tests run on a simulated 8-device CPU mesh
 (--xla_force_host_platform_device_count=8, the JAX analogue of the
 reference's in-process multi-GPU/pserver tests — SURVEY.md §4.5) so
-multi-chip sharding is exercised without TPU hardware. bench.py and
+multi-chip sharding is exercised without TPU hardware. chipbench/ and
 chip_smoke.py do NOT import this and use the real TPU. The persistent
 compile cache (paddle_tpu/compile_cache.py) is not enabled here.
 """

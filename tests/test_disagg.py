@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.fleetctl import SimReplica
+from paddle_tpu.fleetctl.sim import SimReplica
 from paddle_tpu.fleetctl.autoscaler import Autoscaler
 from paddle_tpu.fleetctl.traces import (TraceSpec, generate_trace,
                                         trace_digest)

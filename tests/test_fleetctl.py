@@ -19,7 +19,8 @@ import urllib.request
 import pytest
 
 from paddle_tpu.fleetctl import (Autoscaler, AutoscalerConfig,
-                                 RolloutError, RolloutManager, SimReplica)
+                                 RolloutError, RolloutManager)
+from paddle_tpu.fleetctl.sim import SimReplica
 from paddle_tpu.fleetctl.tenancy import (BATCH, INTERACTIVE, SLO_HEADER,
                                          SLOPolicy, resolve_class)
 from paddle_tpu.fleetctl.traces import (TraceSpec, generate_trace,

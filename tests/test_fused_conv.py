@@ -2,15 +2,14 @@
 
 Reference: the cuDNN fused conv path (gserver/layers/CudnnConvBaseLayer.cpp)
 — the reference's conv hot path is never naive composed ops. Here the
-fused raw-stats formulation (Pallas 1x1-conv kernels with BN
-prologue/epilogue) must match the unfused conv2d+batch_norm formulation:
+fused raw-stats formulation (1x1 convs with BN prologue/epilogue) must
+match the unfused conv2d+batch_norm formulation:
 forward losses, gradients, running-stat updates, and checkpoint parameter
 names (so train-mode fused checkpoints load into eval-mode unfused
 graphs).
 """
 
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.flags import FLAGS
@@ -101,52 +100,6 @@ def test_fused_train_checkpoint_loads_into_eval_graph(tmp_path):
     pt.io.load_params(str(tmp_path), pt.default_main_program())
     (v,) = exe2.run(feed=feed, fetch_list=[out])
     assert np.isfinite(v)
-
-
-def test_pallas_kernel_interpret_parity():
-    """The actual Pallas kernel (interpret mode on CPU), fwd + custom-VJP
-    grads, vs the jnp fallback on the same eligible shapes."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.fused_conv_ops import _fused_fn, _jnp_fused
-
-    n, cin, cout = 64, 128, 128
-    if jax.default_backend() == "tpu":
-        pytest.skip("interpret-mode parity is the CPU-suite variant")
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.randn(n, cin), jnp.float32)
-    w = jnp.asarray(rng.randn(cin, cout) * 0.1, jnp.float32)
-    pm = jnp.asarray(rng.randn(cin) * 0.1, jnp.float32)
-    pi = jnp.asarray(1.0 + 0.1 * rng.rand(cin), jnp.float32)
-    ps = jnp.asarray(1.0 + 0.1 * rng.randn(cin), jnp.float32)
-    pb = jnp.asarray(0.1 * rng.randn(cin), jnp.float32)
-
-    for prologue in (False, True):
-        f = _fused_fn(prologue, True, True)  # interpret=True
-
-        def loss_k(x, w, pm, pi, ps, pb):
-            y, s, sq = f(x, w, pm, pi, ps, pb)
-            return (jnp.sum(y * y) * 1e-3 + jnp.sum(s * 3.0)
-                    + jnp.sum(sq) * 1e-4)
-
-        def loss_j(x, w, pm, pi, ps, pb):
-            y, s, sq = _jnp_fused(x, w, pm, pi, ps, pb, prologue, True)
-            return (jnp.sum(y * y) * 1e-3 + jnp.sum(s * 3.0)
-                    + jnp.sum(sq) * 1e-4)
-
-        yk = f(x, w, pm, pi, ps, pb)
-        yj = _jnp_fused(x, w, pm, pi, ps, pb, prologue, True)
-        for a, b in zip(yk, yj):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-5, atol=2e-5)
-        gk = jax.grad(loss_k, argnums=(0, 1, 2, 3, 4, 5))(
-            x, w, pm, pi, ps, pb)
-        gj = jax.grad(loss_j, argnums=(0, 1, 2, 3, 4, 5))(
-            x, w, pm, pi, ps, pb)
-        for a, b in zip(gk, gj):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
 
 
 def test_resnet_builds_fused_nhwc():

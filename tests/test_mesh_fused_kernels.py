@@ -203,7 +203,7 @@ def test_bench_geometry_dispatches_fused_under_mesh(fused_interpret):
     bf16) keeps the FUSED path under a dp4 mesh: per-shard batch 64 is
     in-window, and the shard_map wrap traces end-to-end (fwd + bwd,
     jax.eval_shape — no compute). The day multi-chip hardware appears,
-    BENCH_MESH=dp4 BENCH_MODEL=nmt runs exactly this path."""
+    `train --mesh dp4` on the NMT config runs exactly this path."""
     mesh = pp.make_mesh((4,), ("dp",), devices=jax.devices()[:4])
     B, S, T, E, C, A, H = 256, 50, 50, 512, 1024, 512, 512
     dt = jnp.bfloat16

@@ -138,30 +138,21 @@ def _build_n(stacked, N=3, H=8, F=12):
 import pytest
 
 
-@pytest.mark.parametrize("single_scan", [False, True])
-def test_stacked_n_matches_per_layer_build(single_scan):
+def test_stacked_n_matches_per_layer_build():
     """The N-layer single-op stack reproduces the book's per-layer
     fc([fc_prev, lstm_prev]) + dynamic_lstm build exactly (same weight
-    names -> identical init -> identical losses over Adam steps) — in
-    BOTH op formulations (layer-by-layer default and the flag-gated
-    all-layers single scan)."""
-    from paddle_tpu.flags import FLAGS
-
+    names -> identical init -> identical losses over Adam steps)."""
     feed = _feed()
     results = {}
     for stacked in (False, True):
-        FLAGS.stacked_lstm_single_scan = stacked and single_scan
-        try:
-            loss = _build_n(stacked)
-            exe = pt.Executor()
-            exe.run(pt.default_startup_program())
-            ls = []
-            for _ in range(4):
-                (l,) = exe.run(feed=feed, fetch_list=[loss])
-                ls.append(float(l))
-            results[stacked] = ls
-        finally:
-            FLAGS.stacked_lstm_single_scan = False
+        loss = _build_n(stacked)
+        exe = pt.Executor()
+        exe.run(pt.default_startup_program())
+        ls = []
+        for _ in range(4):
+            (l,) = exe.run(feed=feed, fetch_list=[loss])
+            ls.append(float(l))
+        results[stacked] = ls
     np.testing.assert_allclose(results[True], results[False],
                                rtol=2e-5, atol=2e-5)
 

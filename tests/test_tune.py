@@ -78,7 +78,7 @@ def test_bahdanau_candidates_all_legal(B, S, A, C, dtype):
     assert default in cands
 
 
-def test_flash_and_conv_candidates_all_legal():
+def test_flash_candidates_all_legal():
     for Tq, Tk in [(1024, 1024), (2048, 512), (4096, 4096), (1280, 1280)]:
         cands = space.flash_candidates({"Tq": Tq, "Tk": Tk})
         assert cands
@@ -86,14 +86,6 @@ def test_flash_and_conv_candidates_all_legal():
             assert space.flash_block_legal(cfg["block_q"], cfg["block_k"],
                                            Tq, Tk), (cfg, Tq, Tk)
         assert space.flash_default({"Tq": Tq, "Tk": Tk}) in cands
-    for n, cin, cout in [(2048, 128, 512), (1024, 256, 256),
-                         (8 * 3 * 7, 128, 128)]:
-        params = {"n": n, "cin": cin, "cout": cout, "dtype": "bfloat16"}
-        cands = space.conv_candidates(params)
-        assert cands
-        for cfg in cands:
-            assert space.conv_rows_legal(cfg["block_rows"], n, cin, cout, 2)
-        assert space.conv_default(params) in cands
 
 
 def test_rnn_space_matches_runtime_default():
@@ -196,36 +188,36 @@ def test_override_precedence(tmp_table, monkeypatch):
     assert _bblk(16, 16, 128, 128, 4) == 16
 
 
-def test_flash_and_conv_consult_overrides(tmp_table):
-    """flash_ops._v5e_block_sizes and fused_conv_ops._block_rows
-    consult the registry before their analytic defaults."""
+def test_flash_and_bahdanau_consult_overrides(tmp_table):
+    """flash_ops._v5e_block_sizes and bahdanau_kernels._bblk consult the
+    registry before their analytic defaults."""
     import jax.numpy as jnp2
 
+    from paddle_tpu.ops.bahdanau_kernels import _bblk
     from paddle_tpu.ops.flash_ops import _v5e_block_sizes
-    from paddle_tpu.ops.fused_conv_ops import _block_rows
 
     # analytic defaults first
     bs = _v5e_block_sizes(1024, 1024, jnp2.bfloat16)
     assert (bs.block_q, bs.block_k) == (512, 512)
-    assert _block_rows(2048, 128, 512, 2) == 1024
+    assert _bblk(64, 64, 512, 512, 2) == 8
     # tuned table entries take over
     t = overrides.table()
     t.put("flash_attention", {"Tq": 1024, "Tk": 1024}, "bfloat16",
           {"block_q": 256, "block_k": 128})
-    t.put("fused_conv", {"n": 2048, "cin": 128, "cout": 512}, "bfloat16",
-          {"block_rows": 256})
+    t.put("bahdanau_attention", {"B": 64, "Sp": 64, "A": 512, "C": 512},
+          "bfloat16", {"bblk": 16})
     bs = _v5e_block_sizes(1024, 1024, jnp2.bfloat16)
     assert (bs.block_q, bs.block_k) == (256, 128)
-    assert _block_rows(2048, 128, 512, 2) == 256
+    assert _bblk(64, 64, 512, 512, 2) == 16
     # a stale flash entry (doesn't divide T) is ignored, not fatal
     t.put("flash_attention", {"Tq": 512, "Tk": 512}, "bfloat16",
           {"block_q": 768, "block_k": 768})
     bs = _v5e_block_sizes(512, 512, jnp2.bfloat16)
     assert (bs.block_q, bs.block_k) == (512, 512)
-    # forced illegal conv block warns and disables the fused path
-    with overrides.forcing("fused_conv", {"block_rows": 12}):
+    # forced illegal batch tile warns and disables the fused path
+    with overrides.forcing("bahdanau_attention", {"bblk": 12}):
         with pytest.warns(UserWarning, match="fails eligibility"):
-            assert _block_rows(2048, 128, 512, 2) == 0
+            assert _bblk(64, 64, 512, 512, 2) == 0
 
 
 def test_rnn_dispatch_consults_overrides(tmp_table):
@@ -293,9 +285,8 @@ def test_fingerprint_reacts_to_every_source(tmp_table, monkeypatch):
     assert overrides.fingerprint() != fp0
     monkeypatch.delenv("PT_ATTN_BBLK")
     # table content
-    overrides.table().put("fused_conv", {"n": 1024, "cin": 128,
-                                         "cout": 128}, "bfloat16",
-                          {"block_rows": 256})
+    overrides.table().put("fused_lstm", {"B": 128, "H": 512},
+                          "bfloat16", {"fused": True})
     assert overrides.fingerprint() != fp0
     # flag
     FLAGS.use_tuned_table = False
@@ -321,6 +312,19 @@ def test_executor_retraces_on_override_change(tmp_table):
     overrides.force("bahdanau_attention", {"bblk": 4})
     exe.run(feed=feed, fetch_list=[y])
     assert exe.cache_stats["misses"] == misses0 + 1  # knob -> re-trace
+
+
+@pytest.mark.parametrize("flag", [
+    "use_fused_rnn", "fused_rnn_interpret", "use_fused_attention",
+    "fused_attention_interpret", "use_fused_conv"])
+def test_trace_key_follows_every_kernel_flag(monkeypatch, flag):
+    """The other half of the contract: each flag that picks a kernel at
+    trace time is part of the key, so flipping it on a live Executor
+    re-traces."""
+    prog = pt.default_main_program()
+    key = pt.Executor._program_trace_key(prog)
+    monkeypatch.setattr(FLAGS, flag, not getattr(FLAGS, flag))
+    assert pt.Executor._program_trace_key(prog) != key
 
 
 # --------------------------------------------------------- harness ------
@@ -461,9 +465,8 @@ def test_serving_warmup_warns_on_stale_table(tmp_path, tmp_table):
         _w.simplefilter("error")
         assert engine.check_tuned_table()
     # the serving host's table changes (retune without re-export):
-    overrides.table().put("fused_conv", {"n": 512, "cin": 128,
-                                         "cout": 128}, "bfloat16",
-                          {"block_rows": 128})
+    overrides.table().put("fused_lstm", {"B": 128, "H": 512},
+                          "bfloat16", {"fused": True})
     with pytest.warns(UserWarning, match="stale"):
         assert not engine.check_tuned_table()
     # pre-tuner artifact (no provenance recorded): silently fine
@@ -482,6 +485,54 @@ def test_cases_from_program_finds_flash_sites():
     sites = space.cases_from_program()
     flash = [s for s in sites if s["family"] == "flash_attention"]
     assert flash and flash[0]["params"] == {"Tq": 1024, "Tk": 1024}
+
+
+def test_resnet_sweep_has_no_case_for_a_kernel_it_will_not_run():
+    """The model sweep tunes what the program dispatches, nothing else:
+    ResNet-50's fused_conv_bn ops run as XLA convolutions with no tile
+    to choose, so they yield no case, and every case the sweep does
+    yield names a family the tuner lists."""
+    from paddle_tpu import models
+
+    x = pt.layers.data("img", shape=[224, 224, 3])
+    models.resnet_imagenet(x, class_dim=10, data_format="NHWC")
+    ops = [op.type for op in pt.default_main_program().global_block().ops]
+    assert "fused_conv_bn" in ops
+    sites = space.cases_from_program()
+    assert not [s for s in sites if s["op"] == "fused_conv_bn"], sites
+    assert {s["family"] for s in sites} <= set(space.FAMILIES)
+
+
+def test_every_listed_family_is_consulted_at_default_flags(monkeypatch,
+                                                           tmp_table):
+    """A family the tuner lists but no dispatch consults is a kernel
+    that never runs being tuned. Each op's own eligibility predicate or
+    tile picker runs here with every flag at its default (only the
+    backend gate is neutralised: it is the platform, not an option), and
+    the registry must see a consult for exactly the families listed."""
+    from paddle_tpu.ops import (bahdanau_kernels, flash_ops, pallas_kernels,
+                                quant_kernels)
+
+    seen = set()
+    real_lookup = overrides.lookup
+
+    def lookup(kernel, *args, **kwargs):
+        seen.add(kernel)
+        return real_lookup(kernel, *args, **kwargs)
+
+    monkeypatch.setattr(overrides, "lookup", lookup)
+    monkeypatch.setattr(pallas_kernels, "backend_ok", lambda flag: True)
+    assert bahdanau_kernels.fused_decoder_eligible(
+        64, 50, 512, 512, jnp.bfloat16)
+    flash_ops._v5e_block_sizes(1024, 1024, jnp.bfloat16)
+    assert pallas_kernels.lstm_supported(
+        128, 512, "sigmoid", "tanh", "tanh", None, itemsize=2)
+    assert pallas_kernels.gru_supported(
+        128, 512, "sigmoid", "tanh", itemsize=2)
+    jax.eval_shape(quant_kernels.quant_matmul,
+                   jax.ShapeDtypeStruct((256, 512), jnp.int8),
+                   jax.ShapeDtypeStruct((512, 512), jnp.int8))
+    assert seen == set(space.FAMILIES)
 
 
 def _build_decoder_program(B=16, C=32, A=24, S=8):
@@ -911,9 +962,8 @@ def test_serving_warmup_names_untuned_and_interpolated(tmp_path,
     pt.io.save_inference_model(model_dir, ["q"], [out])
     engine = ServingEngine(model_dir)
     # make provenance stale so the warning fires
-    overrides.table().put("fused_conv", {"n": 512, "cin": 128,
-                                         "cout": 128}, "bfloat16",
-                          {"block_rows": 128})
+    overrides.table().put("fused_lstm", {"B": 128, "H": 512},
+                          "bfloat16", {"fused": True})
     with pytest.warns(UserWarning) as rec:
         assert not engine.check_tuned_table()
     msg = "\n".join(str(w.message) for w in rec)

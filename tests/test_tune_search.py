@@ -23,7 +23,7 @@ BIG_CASES = [
     ("flash_attention", {"Tq": 4096, "Tk": 4096}),   # 25
     ("flash_attention", {"Tq": 8192, "Tk": 8192}),   # 25
     ("flash_attention", {"Tq": 4096, "Tk": 1024}),   # 20
-    ("fused_conv", {"n": 50176, "cin": 64, "cout": 256}),   # 10
+    ("quant_matmul", {"M": 1024, "K": 1024, "N": 4096}),   # 28
 ]
 
 
