@@ -1,5 +1,8 @@
 """peak_hbm_gib: an upper bound of the most device memory the run held
-on its fullest chip, over 2^30: `device.memory_stats()` after the window,
+on its fullest chip, over 2^30: `device.memory_stats()` read by the driver
+at the window's close, when only the program under test has run in the
+process (startup, the step programs, the feeds; the plain reference runs
+after the reading, PR 30, so no step is too small to be seen):
 `peak_bytes_in_use` (live arrays: parameters, optimizer state, feeds, a
 step's outputs from its dispatch on) plus `peak_bytes_reserved` (the
 running program's scratch, which this runtime books apart and peaks

@@ -6,8 +6,9 @@ the cell's chips.
     python3 chipbench/run.py --workload <cell> --rehearse-cpu     (no chip; tiny sizes)
 
 The last line of standard output is one JSON object: `correct`,
-`attempted`, `failed`, `metrics`, `device` and, with `--trace 1`,
-`breakdown`. `--trace 0` prints the cell's end-to-end metrics, `--trace 1`
+`attempted`, `failed`, `metrics`, `device`, with `--trace 1` `breakdown`,
+and last `compared`: every number `correct` held to a limit, beside it (the
+last lines of standard error say the same). `--trace 0` prints the cell's end-to-end metrics, `--trace 1`
 its per-layer metrics (taken over a short traced window of its own).
 Without a TPU holding the cell's chips the script exits non-zero and
 prints no result; `--rehearse-cpu` runs the same control flow on XLA:CPU
@@ -126,6 +127,15 @@ def memory_peaks(stats) -> dict:
                          for m in mem)}
 
 
+def book_memory(run) -> None:
+    """`memory_peaks` and `memory_peak_bytes` of a run record, from the
+    `memory_stats` its driver read at the window's close: before the plain
+    reference ran, so the peaks are the program's. No later reading of the
+    process reaches them (a peak never falls again)."""
+    run["memory_peaks"] = memory_peaks(run["memory_stats"])
+    run["memory_peak_bytes"] = run["memory_peaks"].get("bytes")
+
+
 def _metric_entries(manifest, section, cell_name):
     return [m for m in manifest[section]
             if cell_name in m.get("workloads", [cell_name])]
@@ -190,6 +200,7 @@ def main() -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     devs = jax.devices()
+    t_chip = time.time()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs)}
     if args.rehearse_cpu:
@@ -211,22 +222,19 @@ def main() -> int:
     driver = load_module(os.path.join(HERE, "drivers", config["driver"] + ".py"))
     ctx = Ctx(name=entry["name"], cell=cell, config=config, seed=args.seed,
               seconds=seconds, trace=bool(args.trace),
-              rehearsal=args.rehearse_cpu,
-              clock=CompileClock(),
+              rehearsal=args.rehearse_cpu, t_start=_T_START, t_chip=t_chip,
+              clock=CompileClock(), memory_stats=memory_stats,
               memory_peaks=lambda: memory_peaks(memory_stats()),
               load_module=load_module,
               model=load_module(os.path.join(config_dir, "model.py")))
     run = driver.run(ctx)
     if args.rehearse_cpu:
         run["min_intervals"] = 5   # three seconds at tiny sizes: control flow only
-    run.update(cell=cell, config=config,
+    run.update(cell=cell, config=config, device=device,
                setup_s=run["t0_wall"] - _T_START,
                compile_s=ctx.clock.seconds, cache_hits=ctx.clock.hits,
                cache_misses=ctx.clock.misses)
-    run["memory_stats"] = memory_stats()
-    run["memory_peaks"] = memory_peaks(run["memory_stats"])
-    run["memory_peak_bytes"] = run["memory_peaks"].get("bytes")
-    run["device"] = device
+    book_memory(run)
 
     if ctx.trace_dir:
         xplane = load_module(os.path.join(HERE, "xplane.py"))
@@ -258,10 +266,10 @@ def main() -> int:
 
     # an earlier line, for PERF.md; the driver reads only the last
     info = {"cell": entry["name"], "setup_s": run["setup_s"],
-            "compile_or_cache_read_s": run["compile_s"],
+            "compile_or_cache_read_s": run["compile_s"],   # the whole process
             "cache_hits": run["cache_hits"], "cache_misses": run["cache_misses"],
             **driver.info(run), **notes,
-            "memory_stats_fullest": max(
+            "memory_stats_fullest_at_close": max(
                 run["memory_stats"],
                 key=lambda m: m.get("peak_bytes_in_use", 0)
                 + m.get("peak_bytes_reserved", 0))}
@@ -274,6 +282,7 @@ def main() -> int:
             100.0 * rate * per_item / (peak * cell["chips"]))
     else:
         info["rehearsal"] = True
+    info["wall_s"] = time.time() - _T_START
     log("info " + json.dumps(info))
     for p in problems:
         log("NOT CORRECT: " + p)
@@ -287,6 +296,13 @@ def main() -> int:
         out["breakdown"] = run["trace"]["breakdown"]
     if args.rehearse_cpu:
         out["rehearsal"] = True
+    # every number compared, beside its limit: the run's last lines on
+    # standard error, and the last key of the result
+    out["compared"] = driver.compared(run)
+    for name, (value, limit) in out["compared"].items():
+        print(f"chipbench: compared {name} {value} limit {limit}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0
 

@@ -17,7 +17,11 @@
    cell resolves to a workload file, a configuration with someone to count
    its FLOPs, a driver, and a reader for each of its metrics;
 4. `roofline.share` on a hand-made kernel, the window's registry deltas on a
-   made-up snapshot, the wire-format reader on a hand-made `XSpace`.
+   made-up snapshot, the wire-format reader on a hand-made `XSpace`;
+5. what a train cell owes (`drivers/train.py:correct`, `compared`, `info`)
+   on a hand-made run record: no memory book is compared with anything, a
+   startup that did not repeat fails by name, and `run.py` books the peaks
+   from the driver's reading.
 
 `tests/test_harness.py` holds what needs JAX or pytest.
 """
@@ -335,6 +339,45 @@ def registry_deltas_on_a_made_up_snapshot():
     reader = _load(os.path.join(HERE, "layer_metrics", "step.donated_gib.py"))
     got = reader.compute({"registry": train.registry_delta(before, after)})
     assert abs(got - 1960000000.0 / 2**30) < 1e-12
+
+
+@check
+def a_train_cell_owes_no_memory_to_the_yardstick():
+    train = _load(os.path.join(HERE, "drivers", "train.py"))
+    run_py = _load(os.path.join(HERE, "run.py"))
+    run = {"costs": [9.0, 7.5], "first_cost": 10.8, "bad_intervals": 0,
+           "reference_first_cost": 10.8001, "startup_differs": [],
+           "gradient_errors": {"w": 0.012, "b": 0.049}, "near_tie_share": 0.0,
+           "config": {}, "tolerances": {"reference_tol": 2e-5, "grad_tol": 0.05},
+           "counters": {"programs_built": 0, "cache_misses": 0},
+           "steps": 2, "window_s": 0.3, "intervals_s": [0.15, 0.15],
+           "peak_after_startup": {"in_use": 5, "reserved": 0, "bytes": 5},
+           "after_window_s": {"second_startup": 1.0, "reference": 2.0},
+           # a step that needs less than any reference would: still the peak
+           "memory_stats": [{"peak_bytes_in_use": 7, "peak_bytes_reserved": 3},
+                            {"peak_bytes_in_use": 6, "peak_bytes_reserved": 5}],
+           "setup_split_s": {"reach_chip": 12.0, "startup": 3.0,
+                             "step_program_compile_or_cache_read": 6.0,
+                             "warmup": 9.0}}
+    run_py.book_memory(run)
+    assert run["memory_peaks"] == {"in_use": 7, "reserved": 5, "bytes": 11}
+    assert run["memory_peak_bytes"] == 11
+    assert train.correct(run) == []
+    assert "memory" not in " ".join(train.compared(run))
+    info = train.info(run)
+    assert info["setup_split_s"] is run["setup_split_s"]
+    assert info["peak_final"] == run["memory_peaks"]
+    assert "peak_after_reference" not in info
+    compared = train.compared(run)
+    assert compared["gradient_error_nearest_limit"] == [0.049, 0.05]
+    assert abs(compared["first_cost_off_reference"][0] - 1e-4 / 10.8001) < 1e-12
+    bad = train.correct(dict(run, startup_differs=["w"]))
+    assert len(bad) == 1 and bad[0].startswith(
+        "startup did not reproduce the weights"), bad
+    bad = train.correct(dict(run, gradient_errors={"w": 0.012, "b": 0.051}))
+    assert len(bad) == 1 and "gradient of b" in bad[0], bad
+    bad = train.correct(dict(run, reference_first_cost=10.8 * (1 + 1e-4)))
+    assert len(bad) == 1 and "first cost" in bad[0], bad
 
 
 @check

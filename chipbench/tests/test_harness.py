@@ -12,6 +12,12 @@
    makes its own routing decisions.
 2. The per-layer readers PR 26 brought, on hand-made run records.
 3. The look-up of a configuration's own FLOPs arithmetic.
+4. The order of a run (PR 30): `drivers/train.py:run` driven in this process
+   at gpt2-small's rehearsal sizes, without `run.py`'s look for a chip, with
+   scripted memory books (XLA:CPU reports none) and a log of what was read
+   and loaded when. The yardstick comes last; broken underneath (a startup
+   that does not repeat, a reference with a halved gradient or a cost that
+   is off), the run is not `correct`.
 
 `selftest.py` checks the same readers against a recorded trace, and
 `roofline.share` and the registry's deltas.
@@ -23,6 +29,7 @@ import math
 import os
 import re
 import sys
+import time
 
 import pytest
 
@@ -355,3 +362,194 @@ def test_family_lookup(tmp_path):
     (tmp_path / "flops.py").write_text("x = 1\n")
     with pytest.raises(SystemExit):
         flops.family_arithmetic(own, str(tmp_path))
+
+
+# ---------------------------------------------------------------- 4 --------
+run_py = _load("run.py")
+AFTER_REFERENCE = 100      # the fake books jump once the yardstick is loaded
+_RUNS = {}
+
+
+def _halved(ref, cost, grads):
+    return cost, [0.5 * g if i == 5 else g for i, g in enumerate(grads)]
+
+
+def _cost_off(ref, cost, grads):
+    return cost * (1.0 + 1e-4), grads
+
+
+def _unseeded_startup(model):
+    import paddle_tpu as pt
+
+    pt.default_startup_program().random_seed = 0   # a fresh seed every run
+    return model
+
+
+def _driven(fault=None, seed=11):
+    """(run record, events, the fake memory reader) of one run of the train
+    driver on the CPU. `fault` names what is broken underneath: a function
+    over the reference's (cost, gradients), or over the built model."""
+    if fault in _RUNS:
+        return _RUNS[fault]
+    import types
+
+    import paddle_tpu as pt
+
+    pt.reset()
+    with open(os.path.join(HERE, "workloads", "gpt2-small.train.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(HERE, "configs", "gpt2-small", "config.json")) as f:
+        config = json.load(f)
+    cell.update(cell["rehearsal"])
+    config.update(config.get("rehearsal", {}))
+    events = []
+
+    def memory_stats():
+        n = 1 + sum(e[0] == "memory" for e in events)
+        if ("load", "reference.py") in events:
+            n *= AFTER_REFERENCE
+        events.append(("memory", n))
+        return [{"peak_bytes_in_use": 1000 * n, "peak_bytes_reserved": 10 * n}]
+
+    def load_module(path):
+        events.append(("load", os.path.basename(path)))
+        mod = run_py.load_module(path)
+        if fault in (_halved, _cost_off):
+            plain = mod.loss_and_grads
+            mod.loss_and_grads = lambda *a: fault(mod, *plain(*a))
+        return mod
+
+    model = run_py.load_module(
+        os.path.join(HERE, "configs", "gpt2-small", "model.py"))
+    if fault is _unseeded_startup:
+        model = types.SimpleNamespace(
+            __file__=model.__file__,
+            get_model=lambda *a, _get=model.get_model: fault(_get(*a)))
+    ctx = run_py.Ctx(
+        name="gpt2-small.train", cell=cell, config=config, seed=seed,
+        seconds=0.5, trace=False, rehearsal=True, clock=run_py.CompileClock(),
+        t_start=run_py._T_START, t_chip=time.time(),
+        memory_stats=memory_stats,
+        memory_peaks=lambda: run_py.memory_peaks(memory_stats()),
+        load_module=load_module, model=model)
+    run = train.run(ctx)
+    run.update(cell=cell, config=config, setup_s=run["t0_wall"] - ctx.t_start)
+    run_py.book_memory(run)
+    _RUNS[fault] = run, events, memory_stats
+    return _RUNS[fault]
+
+
+def test_the_books_are_read_at_the_close_and_the_reference_is_loaded_after():
+    run, events, _ = _driven()
+    assert train.correct(run) == [], train.correct(run)
+    loaded = events.index(("load", "reference.py"))
+    reads = [e for e in events[:loaded] if e[0] == "memory"]
+    # one reading after startup, one at the window's close, none between
+    # the close and the yardstick, and no yardstick before the close
+    assert [n for _, n in reads] == [1, 2]
+    assert run["peak_after_startup"] == {
+        "in_use": 1000, "reserved": 10, "bytes": 1010}
+    assert run["memory_stats"] == [
+        {"peak_bytes_in_use": 2000, "peak_bytes_reserved": 20}]
+    assert run["steps"] > 0 and len(run["costs"]) == run["steps"]
+
+
+def test_a_later_larger_reading_does_not_reach_peak_hbm_gib():
+    run, _, memory_stats = _driven()
+    peak = _load("end_to_end", "peak_hbm_gib.py").compute
+    assert run["memory_peaks"] == {"in_use": 2000, "reserved": 20, "bytes": 2020}
+    assert peak(run) == 2020 / 2.0**30
+    later = memory_stats()[0]       # the process after the yardstick
+    assert later["peak_bytes_in_use"] >= AFTER_REFERENCE * 2000
+    run_py.book_memory(run)
+    assert run["memory_peak_bytes"] == 2020 and peak(run) == 2020 / 2.0**30
+
+
+def test_steps_that_peak_below_the_yardstick_are_correct():
+    """What the memory rule refused until PR 30: every book of the steps
+    (2000, 20) far under what the process reads once the reference has run
+    (x 100). The run is `correct` and reports the steps' peak."""
+    run, events, _ = _driven()
+    after = [n for kind, n in events[events.index(("load", "reference.py")):]
+             if kind == "memory"]
+    assert all(n >= 3 * AFTER_REFERENCE for n in after)
+    assert train.correct(run) == []
+    assert "peak_after_reference" not in run
+    assert run["memory_peak_bytes"] == 2020
+    assert train.info(run)["peak_final"] == run["memory_peaks"]
+    # the set-up's four parts are all of `setup_s`
+    assert sum(run["setup_split_s"].values()) == pytest.approx(run["setup_s"])
+
+
+def test_the_reference_sees_the_startup_weights_and_the_first_batch():
+    """Not the trained weights: its cost is the one the plain reference gives
+    on a startup of the same seed and the reader's first batch, computed
+    here apart from the driver, and stays where the system's first cost is
+    while the window's steps took the loss far below."""
+    import jax
+
+    import paddle_tpu as pt
+    from paddle_tpu.trainer import Trainer
+
+    run, _, _ = _driven()
+    pt.reset()
+    model = _load("configs", "gpt2-small", "model.py").get_model(
+        run["config"], run["cell"], 11)
+    trainer = Trainer(cost=model["cost"]).init()
+    params = [trainer.scope.get(p.name)
+              for p in trainer.main_program.parameters()]
+    ref = _load("configs", "gpt2-small", "reference.py")
+    cost = jax.jit(lambda ps, feed: ref.loss_and_grads(
+        run["config"], ps, feed)[0])(params, next(iter(model["reader"]())))
+    assert run["reference_first_cost"] == pytest.approx(float(cost), rel=1e-6)
+    assert run["startup_differs"] == []
+    assert abs(run["first_cost"] - run["reference_first_cost"]) < 1e-3
+    assert run["costs"][-1] < 0.9 * run["reference_first_cost"]
+    # another run of the seed, with another number of steps behind it
+    again, _, _ = _driven(_halved)
+    assert again["reference_first_cost"] == run["reference_first_cost"]
+    assert again["first_cost"] == run["first_cost"]
+
+
+def test_a_startup_that_does_not_reproduce_its_weights_fails_the_run_by_name():
+    run, _, _ = _driven(_unseeded_startup)
+    bad = train.correct(run)
+    assert bad and bad[0].startswith("startup did not reproduce the weights")
+    # every tensor drawn from the seed differs; a constant one cannot
+    assert "tfm.tok_emb" in run["startup_differs"]
+    assert "tfm.h0.attn.wq_b" not in run["startup_differs"]
+    assert train.compared(run)["startup_tensors_differing"][0] == len(
+        run["startup_differs"])
+
+
+def test_a_reference_with_a_halved_gradient_fails_under_the_new_order():
+    """Control (C): one tensor's gradient halved in the yardstick reads
+    about 1 of its rms (PERF.md section 2: 0.995) and fails, that tensor
+    alone."""
+    sound, _, _ = _driven()
+    run, _, _ = _driven(_halved)
+    name = list(run["gradient_errors"])[5]
+    bad = train.correct(run)
+    assert len(bad) == 1 and name in bad[0] and "gradient" in bad[0], bad
+    assert 0.9 < run["gradient_errors"][name] < 1.1
+    assert sound["gradient_errors"][name] < 0.1
+    assert train.compared(run)["gradient_error_nearest_limit"][0] == \
+        run["gradient_errors"][name]
+
+
+def test_a_reference_whose_cost_is_off_by_1e_4_fails_under_the_new_order():
+    """Control (C): the yardstick's cost x (1 + 1e-4) reaches the record, and
+    fails at the chip's tolerance a system that agrees with the sound
+    reference to the last bit. (At tiny sizes on the CPU bf16 AMP itself
+    reads 8e-5 off, so the rehearsal's own tolerance is 1e-3.)"""
+    sound, _, _ = _driven()
+    run, _, _ = _driven(_cost_off)
+    assert run["reference_first_cost"] == pytest.approx(
+        sound["reference_first_cost"] * (1.0 + 1e-4), rel=1e-6)
+    chip = {"reference_tol": train.REFERENCE_TOL, "grad_tol": 1.0}
+    exact = dict(first_cost=sound["reference_first_cost"], tolerances=chip)
+    assert train.correct(dict(sound, **exact)) == []
+    bad = train.correct(dict(run, **exact))
+    assert len(bad) == 1 and "first cost" in bad[0], bad
+    off, limit = train.compared(dict(run, **exact))["first_cost_off_reference"]
+    assert limit == 2e-5 < 9e-5 < off < 1.1e-4
