@@ -294,32 +294,81 @@ def cross_entropy_kernel(ctx):
     ctx.set_output("Y", out)
 
 
+@jax.custom_vjp
+def _rows_cross_entropy(x, lbl):
+    """Hard-label cost of logits [rows, V] (any float dtype) and labels
+    [rows] int32: float32 [rows]. Only one float32 scalar a row (the
+    log-sum-exp) is kept for the backward; the gradient is recomputed
+    from the logits as they arrived, so no float32 [rows, V] array is
+    written. Reverse mode only (custom_vjp)."""
+    return _rows_cross_entropy_fwd(x, lbl)[0]
+
+
+def _rows_cross_entropy_fwd(x, lbl):
+    xf = x.astype(jnp.float32)
+    m = jnp.max(xf, axis=-1)
+    lse = m + jnp.log(jnp.sum(jnp.exp(xf - m[:, None]), axis=-1))
+    picked = jnp.take_along_axis(x, lbl[:, None], axis=-1)[:, 0]
+    return lse - picked.astype(jnp.float32), (x, lbl, lse)
+
+
+def _rows_cross_entropy_bwd(res, g):
+    x, lbl, lse = res
+    p = jnp.exp(x.astype(jnp.float32) - lse[:, None])
+    onehot = lbl[:, None] == jnp.arange(x.shape[-1], dtype=jnp.int32)
+    return ((p - onehot.astype(jnp.float32)) * g[:, None]).astype(x.dtype), None
+
+
+_rows_cross_entropy.defvjp(_rows_cross_entropy_fwd, _rows_cross_entropy_bwd)
+
+_COST_COUNTER = "pt_cost_op_dispatch_total"
+_COST_HELP = ("softmax_with_cross_entropy ops traced, by the path the "
+              "inputs chose (rows: the custom_vjp over flattened logits)")
+
+
 @register_op("softmax_with_cross_entropy")
 def softmax_with_cross_entropy_kernel(ctx):
     """Reference: paddle/operators/softmax_with_cross_entropy_op.cc —
 
     numerically-stable fused version. Ragged (LoDArray) logits/labels give
     a per-token LoD loss with padding slots zeroed (the reference computes
-    token losses over the flat no-padding layout for free)."""
+    token losses over the flat no-padding layout for free).
+
+    Integer labels take `_rows_cross_entropy` over logits flattened to
+    [rows, V] here, inside the op: the flatten is what lets XLA fuse the
+    row reductions into the GEMM that made the logits (no layout copy
+    across `fc`'s [tokens, V] -> [B, T, V] reshape). Soft labels read the
+    whole row of log-probabilities and keep the plain formulation. The
+    path is counted in `pt_cost_op_dispatch_total{path}` when traced."""
+    from ..obs import metrics
+
     logits_in = ctx.input("Logits")
     label_in = ctx.input("Label")
     ragged = isinstance(logits_in, LoDArray)
     logits = logits_in.data if ragged else logits_in
     label = label_in.data if isinstance(label_in, LoDArray) else label_in
+    soft = bool(ctx.attr("soft_label", False))
+    metrics.registry().counter_inc(
+        _COST_COUNTER, help=_COST_HELP,
+        labels={"path": "soft_label" if soft else "rows"})
     # softmax/log in f32 even under amp (loss numerics)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    if ctx.attr("soft_label", False):
+    if soft:
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
     else:
+        V = logits.shape[-1]
         lbl = label[..., 0] if label.ndim == logits.ndim else label
-        lbl = jnp.clip(lbl.astype(jnp.int32), 0, logits.shape[-1] - 1)
-        loss = -jnp.take_along_axis(logp, lbl[..., None], axis=-1)
+        lbl = jnp.clip(lbl.astype(jnp.int32), 0, V - 1)
+        loss = _rows_cross_entropy(logits.reshape(-1, V), lbl.reshape(-1))
+        loss = loss.reshape(lbl.shape + (1,))
+    # outside the custom_vjp: dead code to XLA unless the program reads it
+    softmax = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     if ragged:
         loss = jnp.where(logits_in.token_mask[:, None], loss, 0.0)
-        ctx.set_output("Softmax", logits_in.with_data(jnp.exp(logp)))
+        ctx.set_output("Softmax", logits_in.with_data(softmax))
         ctx.set_output("Loss", logits_in.with_data(loss))
     else:
-        ctx.set_output("Softmax", jnp.exp(logp))
+        ctx.set_output("Softmax", softmax)
         ctx.set_output("Loss", loss)
 
 

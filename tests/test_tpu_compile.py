@@ -12,6 +12,9 @@ never at import and never in a parametrize argument; every compile
 happens in the test's own process; all such tests live in this one file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -245,6 +248,85 @@ def test_plain_and_differentiated_attention_stay_two_launches(
     args = [jax.ShapeDtypeStruct((2, 1024, 768), BF16, sharding=one_chip)] * 3
     text = jax.jit(both).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def _written_arrays(hlo_text):
+    """(opcode, dtype, elements, in_fusion_body) of every array that an
+    instruction of the optimized HLO produces. What an instruction of a
+    fusion's body produces stays in registers; the rest is written out."""
+    bodies = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo_text))
+    out, body = [], False
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            body = head.group(1) in bodies
+            continue
+        inst = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (.*)", line)
+        if not inst:
+            continue
+        # the opcode is the first lower-case word before a "(" that follows
+        # a space: tiling annotations (`:T(8,128)`, `S(1)`) never do
+        opcode = re.search(r"(?:^|\s)([a-z][\w\-]*)\(", inst.group(1))
+        if opcode.group(1) in ("parameter", "tuple", "get-tuple-element",
+                               "bitcast"):
+            continue
+        for dtype, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
+                                      inst.group(1)[:opcode.start()]):
+            elements = math.prod(int(d) for d in dims.split(",") if d)
+            out.append((opcode.group(1), dtype, elements, body))
+    return out
+
+
+def test_gpt2_head_writes_no_float32_logits(one_chip, compiled_mode):
+    """gpt2-small's output head at its real widths, as `layers.fc` and the
+    cost op build it under bf16 AMP: [12, 1024, 768] x [768, 50257], the
+    cost, its mean, the gradients to the activations and the weight. The
+    gain of the cost op's custom_vjp over flattened logits rests on what
+    this pins: no float32 array of tokens x V elements is written out, no
+    array of that size is copied in any dtype, and the step's temporaries
+    stay under the plain log_softmax formulation's (the op before PR 31,
+    inline here as the yardstick). The weight's own [768, 50257] is free
+    to be laid out as XLA likes."""
+    from paddle_tpu.core.program import Operator
+    from paddle_tpu.core.registry import OpContext
+    from paddle_tpu.ops.nn_ops import softmax_with_cross_entropy_kernel
+
+    Bt, Tt, E, V = 12, 1024, 768, 50257
+
+    def cost_op(logits, label):
+        op = Operator("softmax_with_cross_entropy",
+                      {"Logits": ["x"], "Label": ["l"]},
+                      {"Softmax": ["s"], "Loss": ["y"]}, {})
+        env = {"x": logits, "l": label}
+        softmax_with_cross_entropy_kernel(OpContext(op, env))
+        return env["y"]
+
+    def plain(logits, label):
+        logp = jax.nn.log_softmax(logits.astype(F32), axis=-1)
+        lbl = jnp.clip(label[..., 0], 0, V - 1)
+        return -jnp.take_along_axis(logp, lbl[..., None], axis=-1)
+
+    def head(cost):
+        def loss(h, w, label):
+            logits = h.reshape(Bt * Tt, E) @ w.astype(BF16)
+            return jnp.mean(cost(logits.reshape(Bt, Tt, V), label))
+        return jax.value_and_grad(loss, (0, 1))
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            (((Bt, Tt, E), BF16), ((E, V), F32), ((Bt, Tt, 1), jnp.int32))]
+    new = jax.jit(head(cost_op)).lower(*args).compile()
+    old = jax.jit(head(plain)).lower(*args).compile()
+
+    def big(compiled):
+        return [a for a in _written_arrays(compiled.as_text())
+                if a[2] == Bt * Tt * V]
+
+    # the reading has teeth: the yardstick does write float32 logits
+    assert any(d == "f32" and not body for _, d, _, body in big(old))
+    assert not [a for a in big(new) if a[1] == "f32" and not a[3]], big(new)
+    assert not [a for a in big(new) if a[0] == "copy"], big(new)
+    assert (new.memory_analysis().temp_size_in_bytes
+            < old.memory_analysis().temp_size_in_bytes)
 
 
 def test_largest_gru_h_is_the_published_maximum(compiled_mode):
