@@ -61,6 +61,9 @@ LOW_PRECISION_OPS = frozenset({
     # calls cast_inputs); a bf16 router sends 3.5 % of tokens to another
     # expert set where a float32 one sends 2.0 % (ISSUE 26's experiment)
     "moe_ffn",
+    # the state-space mixer's two projections and the scan's matmuls; its
+    # decays and carried state are float32 inside the op (ops/ssm_ops.py)
+    "mamba2_mixer",
 })
 
 # The subset of low-precision sites the int8 converter may rewrite: dense

@@ -84,18 +84,23 @@ def _fan_in_out(var: Variable):
 class XavierInitializer(Initializer):
     """Reference: fluid initializer.py XavierInitializer (Glorot)."""
 
-    def __init__(self, uniform=True, fan_in=None, fan_out=None, seed=0):
+    def __init__(self, uniform=True, fan_in=None, fan_out=None, seed=0,
+                 gain=1.0):
+        """`gain` multiplies the Glorot range: 1 / sqrt(layers) on the
+        projections that write to a residual stream is GPT-2's scheme
+        (`rescale_prenorm_residual` in `transformers`)."""
         self.uniform, self.fan_in, self.fan_out = uniform, fan_in, fan_out
+        self.gain = gain
 
     def __call__(self, var, startup=None):
         fi, fo = _fan_in_out(var)
         fi = self.fan_in or fi
         fo = self.fan_out or fo
         if self.uniform:
-            limit = math.sqrt(6.0 / (fi + fo))
+            limit = self.gain * math.sqrt(6.0 / (fi + fo))
             UniformInitializer(-limit, limit)(var, startup)
         else:
-            std = math.sqrt(2.0 / (fi + fo))
+            std = self.gain * math.sqrt(2.0 / (fi + fo))
             NormalInitializer(0.0, std)(var, startup)
 
 

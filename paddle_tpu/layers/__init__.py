@@ -25,8 +25,10 @@ from .recurrent import *  # noqa: F401,F403
 from .recurrent import __all__ as _rec_all
 from .sequence import *  # noqa: F401,F403
 from .sequence import __all__ as _seq_all
+from .ssm import *  # noqa: F401,F403
+from .ssm import __all__ as _ssm_all
 
 __all__ = (
     list(_nn_all) + list(_seq_all) + list(_att_all) + list(_crf_all)
-    + list(_ctc_all) + list(_misc_all) + list(_det_all) + list(_rec_all) + list(_gen_all) + list(_cf_all) + list(_moe_all)
+    + list(_ctc_all) + list(_misc_all) + list(_det_all) + list(_rec_all) + list(_gen_all) + list(_cf_all) + list(_moe_all) + list(_ssm_all)
 )

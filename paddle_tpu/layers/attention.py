@@ -35,6 +35,8 @@ def multi_head_attention(
     qk_norm: bool = False,
     rotary_theta: Optional[float] = None,
     rms_eps: float = 1e-5,
+    num_kv_heads: Optional[int] = None,
+    head_dim: Optional[int] = None,
 ):
     """Transformer multi-head attention over dense [B, T, E] inputs
     (self-attention when key/value are None). Beyond the 2017 reference's
@@ -47,7 +49,17 @@ def multi_head_attention(
     over the whole E, before the split into heads (OLMoE's form).
     rotary_theta: rotary position embedding of that base on Q and K, after
     the norm. Both off by default, and then the ops appended are exactly
-    those of a layer without them."""
+    those of a layer without them.
+    num_kv_heads: fewer K/V heads than query heads (grouped-query
+    attention): the K and V projections are [E, num_kv_heads x D] and query
+    head j reads K/V head j // (num_heads / num_kv_heads). None or
+    `num_heads`: the ops of plain multi-head attention, unchanged.
+    head_dim: a head size D other than E / num_heads: Q is [E, num_heads x
+    D] and the output projection [num_heads x D, E] (Nemotron-H: 32 x 128
+    at E 2688). The kernel reads the K/V heads from the shapes, so the op
+    carries no attribute for them.
+    param_attr may be a mapping {"wq" | "wk" | "wv" | "wo": attr}
+    (`ParamAttr.derive`)."""
     from .nn import fc, rms_norm, rotary_embedding
 
     is_cross = key is not None or value is not None
@@ -63,18 +75,25 @@ def multi_head_attention(
     value = query if value is None else value
     helper = LayerHelper("multi_head_attention", name=name)
     E = int(query.shape[-1])
-    if E % num_heads:
+    if head_dim is None and E % num_heads:
         raise ValueError(f"hidden dim {E} not divisible by {num_heads} heads")
+    D = E // num_heads if head_dim is None else int(head_dim)
+    kv_heads = num_heads if num_kv_heads is None else int(num_kv_heads)
+    if num_heads % kv_heads:
+        raise ValueError(f"{num_heads} query heads do not share {kv_heads} "
+                         f"K/V heads evenly")
+    E_q, E_kv = num_heads * D, kv_heads * D
 
     def _derive(attr, s):
         # distinct per-projection names; ParamAttr.derive prevents
         # wq/wk/wv/wo collapsing into ONE shared parameter
         return ParamAttr.derive(attr, helper.name, s)
 
-    proj = lambda x, s: fc(x, size=E, num_flatten_dims=2,
-                           param_attr=_derive(param_attr, s),
-                           bias_attr=_derive(bias_attr, f"{s}_b"))
-    q, k, v = proj(query, "wq"), proj(key, "wk"), proj(value, "wv")
+    proj = lambda x, s, size: fc(x, size=size, num_flatten_dims=2,
+                                 param_attr=_derive(param_attr, s),
+                                 bias_attr=_derive(bias_attr, f"{s}_b"))
+    q, k, v = (proj(query, "wq", E_q), proj(key, "wk", E_kv),
+               proj(value, "wv", E_kv))
     if qk_norm:
         # the norms' scales start at one whatever initialiser the caller
         # gave the projections: only the derived name is taken over
@@ -84,8 +103,9 @@ def multi_head_attention(
                      param_attr=_derive(param_attr, "k_norm").name)
     if rotary_theta:
         q = rotary_embedding(q, num_heads, rotary_theta)
-        k = rotary_embedding(k, num_heads, rotary_theta)
-    out = helper.create_tmp_variable(query.dtype, query.shape)
+        k = rotary_embedding(k, kv_heads, rotary_theta)
+    out = helper.create_tmp_variable(query.dtype,
+                                     tuple(query.shape[:-1]) + (E_q,))
     helper.append_op(
         type="flash_attention",
         inputs={"Q": [q], "K": [k], "V": [v]},

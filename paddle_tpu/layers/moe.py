@@ -1,38 +1,70 @@
-"""Routed-expert layer DSL: a dropless top-k mixture of SwiGLU experts
-and its auxiliary costs (ops/moe_ops.py). Beyond the 2017 reference's
-layer set; the feed-forward of OLMoE / Mixtral / DeepSeek-style models.
+"""Routed-expert layer DSL: a dropless top-k mixture of experts and its
+auxiliary costs (ops/moe_ops.py). Beyond the 2017 reference's layer set;
+the feed-forward of OLMoE / Mixtral / DeepSeek / Nemotron-H-style models.
+By arguments: a softmax or a sigmoid router (`scoring`, `router_bias`,
+`gate_scale`), SwiGLU or relu^2 experts (`expert_act`), all experts or one
+chip's share of them (`held_experts`), a shared expert (`shared_expert_dim`).
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from ..initializer import XavierInitializer
+from ..initializer import ConstantInitializer, XavierInitializer
 from ..param_attr import ParamAttr
 from .helper import LayerHelper
 
 __all__ = ["moe_ffn", "moe_aux_loss"]
 
 EXPERT_TOKENS_COUNTER = "pt_moe_expert_tokens_total"
+HELD_PAIRS_COUNTER = "pt_moe_held_pairs_total"
 
 
 def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
-            norm_topk_prob: bool = False, param_attr=None, name=None):
+            norm_topk_prob: bool = False, param_attr=None, name=None,
+            scoring: str = "softmax", router_bias: bool = False,
+            gate_scale: float = 1.0, expert_act: str = "swiglu",
+            held_experts=None, shared_expert_dim: int = 0):
     """input [B, T, d] -> (out [B, T, d], router logits [B*T, E] float32,
     tokens per expert [E] int32). Each token goes to its `experts_per_token`
-    highest-scoring experts of `num_experts` (softmax router in float32,
-    gates not renormalised unless `norm_topk_prob`); every (token, slot)
-    pair is computed: no capacity, no dropped token. Parameters, bias-free:
-    `<name>.router` [d, E], `<name>.gate` and `.up` [E, d, f], `<name>.down`
-    [E, f, d]; each expert is silu(x Wg) * (x Wu) -> Wd.
+    highest-scoring experts of `num_experts`; every (token, slot) pair is
+    computed: no capacity, no dropped token. The router is float32.
 
-    The tokens-per-expert count is registered as a step statistic of the
-    program (`Program.add_step_statistic`): a Trainer folds it on the device
-    with the cost and publishes `pt_moe_expert_tokens_total{layer,expert}`
-    at its host syncs."""
+    scoring "softmax": the gates are the chosen probabilities, not
+    renormalised unless `norm_topk_prob`. "sigmoid": s = sigmoid(logits), the
+    choice is the top k of s + b (`router_bias`: b [E] is `<name>.router_bias`,
+    zeros, NOT trained: a buffer a load balancer would steer), the gates s of
+    the chosen, over their sum with `norm_topk_prob`. `gate_scale` multiplies
+    the gates.
+    expert_act "swiglu": silu(x Wg) * (x Wu) -> Wd, parameters `<name>.gate`,
+    `.up` [E, d, f], `.down` [E, f, d]. "relu2": relu(x Wu)^2 -> Wd, no gate
+    stack. All bias-free; `<name>.router` [d, E].
+    held_experts (lo, hi): this chip's share of an expert-parallel layer.
+    The router still scores all `num_experts` and a token still chooses
+    among all of them; the stacks hold experts lo..hi-1 only, the pairs that
+    chose one of them are computed, and a pair that chose an absent expert
+    adds nothing (its chip would add it; no code stands in for that chip or
+    the exchange). The shares of a layer add up to the whole layer.
+    shared_expert_dim f_s > 0: + relu(x Wu_s)^2 Wd_s for every token
+    (`<name>.shared_up` [d, f_s], `.shared_down`).
+    param_attr may be a mapping {"router" | "up" | "down" | ...: attr}
+    (`ParamAttr.derive`).
+
+    The tokens-per-expert count (over all `num_experts`) is registered as a
+    step statistic of the program (`Program.add_step_statistic`): a Trainer
+    folds it on the device with the cost and publishes
+    `pt_moe_expert_tokens_total{layer,expert}` at its host syncs; a share
+    also publishes `pt_moe_held_pairs_total{layer,expert}`, the pairs it
+    computed, by held expert (0 = `lo`)."""
     helper = LayerHelper("moe_ffn", name=name)
     d = int(input.shape[-1])
     E, f = int(num_experts), int(expert_dim)
+    lo, hi = (0, E) if held_experts is None else map(int, held_experts)
+    if not 0 <= lo < hi <= E:
+        raise ValueError(f"held_experts {held_experts} not within 0..{E}")
+    if expert_act not in ("swiglu", "relu2"):
+        raise ValueError(f"unknown expert_act {expert_act!r}")
+    part = (lo, hi) != (0, E)
 
     def param(suffix, shape):
         # default: Glorot over ONE expert's matrix (the stock default reads
@@ -43,26 +75,49 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
             default_initializer=XavierInitializer(
                 fan_in=shape[-2], fan_out=shape[-1]))
 
-    router = param("router", (d, E))
-    gate, up = param("gate", (E, d, f)), param("up", (E, d, f))
-    down = param("down", (E, f, d))
+    inputs = {"X": [input], "RouterW": [param("router", (d, E))]}
+    if expert_act == "swiglu":
+        inputs["GateW"] = [param("gate", (hi - lo, d, f))]
+    inputs["UpW"] = [param("up", (hi - lo, d, f))]
+    inputs["DownW"] = [param("down", (hi - lo, f, d))]
+    if router_bias:
+        inputs["RouterBias"] = [helper.create_parameter(
+            ParamAttr(name=f"{helper.name}.router_bias", trainable=False),
+            (E,), default_initializer=ConstantInitializer(0.0))]
+    if shared_expert_dim:
+        inputs["SharedUpW"] = [param("shared_up", (d, int(shared_expert_dim)))]
+        inputs["SharedDownW"] = [param(
+            "shared_down", (int(shared_expert_dim), d))]
     tokens = int(np.prod(input.shape[:-1]))
     out = helper.create_tmp_variable(input.dtype, input.shape)
     logits = helper.create_tmp_variable(np.float32, (tokens, E))
     counts = helper.create_tmp_variable(np.int32, (E,))
-    helper.append_op(
-        type="moe_ffn",
-        inputs={"X": [input], "RouterW": [router], "GateW": [gate],
-                "UpW": [up], "DownW": [down]},
-        outputs={"Out": [out], "RouterLogits": [logits],
-                 "TokensPerExpert": [counts]},
-        attrs={"top_k": int(experts_per_token),
-               "norm_topk_prob": bool(norm_topk_prob)},
-    )
+    outputs = {"Out": [out], "RouterLogits": [logits],
+               "TokensPerExpert": [counts]}
+    attrs = {"top_k": int(experts_per_token),
+             "norm_topk_prob": bool(norm_topk_prob)}
+    # only what differs from a softmax router over experts that are all
+    # here: such a layer's op is the one it always was
+    if scoring != "softmax":
+        attrs["scoring"] = scoring
+    if gate_scale != 1.0:
+        attrs["gate_scale"] = float(gate_scale)
+    if part:
+        attrs["held_lo"], attrs["held_hi"] = lo, hi
+        held = helper.create_tmp_variable(np.int32, (hi - lo,))
+        outputs["HeldPairs"] = [held]
+    helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     helper.main_program.add_step_statistic(
         counts, EXPERT_TOKENS_COUNTER, labels={"layer": helper.name},
         index_label="expert",
         help="(token, slot) pairs routed to each expert of a routed layer")
+    if part:
+        helper.main_program.add_step_statistic(
+            held, HELD_PAIRS_COUNTER, labels={"layer": helper.name},
+            index_label="expert",
+            help="(token, slot) pairs a chip's share of a routed layer "
+                 "computed, by held expert")
     return out, logits, counts
 
 

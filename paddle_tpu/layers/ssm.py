@@ -1,0 +1,87 @@
+"""State-space layer DSL: the Mamba-2 mixer (ops/ssm_ops.py). Beyond the
+2017 reference's layer set; the sequence mixer of the hybrid Mamba-2 /
+attention decoders (Nemotron-H and its kin).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from ..core.program import default_startup_program
+from ..initializer import (ConstantInitializer, Initializer,
+                           UniformInitializer, XavierInitializer)
+from ..param_attr import ParamAttr
+from .helper import LayerHelper
+
+__all__ = ["mamba2_mixer"]
+
+
+class _Mamba2Init(Initializer):
+    """A_log or dt_bias as the Mamba family draws them (the `mamba2_init`
+    startup op)."""
+
+    def __init__(self, kind: str):
+        self.kind = kind
+
+    def __call__(self, var, startup=None):
+        b = (startup or default_startup_program()).global_block()
+        b.create_var(var.name, var.shape, var.dtype, persistable=True)
+        b.append_op("mamba2_init", outputs={"Out": [var.name]},
+                    attrs={"shape": list(var.shape), "kind": self.kind})
+
+
+def mamba2_mixer(input, num_heads: int, head_dim: int, n_groups: int,
+                 state_size: int, conv_kernel: int = 4, chunk: int = 128,
+                 epsilon: float = 1e-5, param_attr=None, name=None):
+    """input [B, T, d] -> [B, T, d]: Mamba-2's mixer (Dao & Gu 2024), bias-
+    free projections. With H = `num_heads`, P = `head_dim`, d_in = H P, G =
+    `n_groups`, N = `state_size`:
+
+        [z | xBC | dt] = h W_in           W_in [d, 2 d_in + 2 G N + H]
+        xBC = silu(causal depthwise conv_K(xBC) + b_conv)  -> x, B, C
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+        out = group_rms(y * silu(z), w_n) W_out    (RMS inside each group)
+
+    computed in chunks of `chunk` tokens (any T). Parameters: `<name>.in_w`,
+    `.conv_w` [K, d_in + 2 G N], `.conv_b`, `.dt_bias`, `.A_log`, `.D` [H],
+    `.norm_w` [d_in], `.out_w` [d_in, d]. A_log starts at log U(1, 16),
+    dt_bias at the inverse softplus of a log-uniform draw in [0.001, 0.1], D
+    and the norm's scale at one, the conv at U(+-1/sqrt(K)) with a zero bias
+    (the family's initialisers); the projections keep the DSL's Glorot.
+    param_attr may be a mapping {"in_w" | ... | "out_w": attr}
+    (`ParamAttr.derive`): a caller's initialiser wins."""
+    helper = LayerHelper("mamba2_mixer", name=name)
+    d = int(input.shape[-1])
+    H, P, G, N, K = (int(num_heads), int(head_dim), int(n_groups),
+                     int(state_size), int(conv_kernel))
+    if H % G:
+        raise ValueError(f"{H} heads do not split into {G} groups")
+    d_in, conv_dim = H * P, H * P + 2 * G * N
+
+    def param(suffix, shape, init):
+        return helper.create_parameter(
+            ParamAttr.derive(param_attr, helper.name, suffix), shape,
+            default_initializer=init)
+
+    bound = 1.0 / np.sqrt(K)
+    inputs = {
+        "X": [input],
+        "InW": [param("in_w", (d, 2 * d_in + 2 * G * N + H),
+                      XavierInitializer())],
+        "ConvW": [param("conv_w", (K, conv_dim),
+                        UniformInitializer(-bound, bound))],
+        "ConvB": [param("conv_b", (conv_dim,), ConstantInitializer(0.0))],
+        "DtBias": [param("dt_bias", (H,), _Mamba2Init("dt_bias"))],
+        "ALog": [param("A_log", (H,), _Mamba2Init("A_log"))],
+        "D": [param("D", (H,), ConstantInitializer(1.0))],
+        "NormW": [param("norm_w", (d_in,), ConstantInitializer(1.0))],
+        "OutW": [param("out_w", (d_in, d), XavierInitializer())],
+    }
+    out = helper.create_tmp_variable(input.dtype, input.shape)
+    helper.append_op(
+        type="mamba2_mixer", inputs=inputs, outputs={"Out": [out]},
+        attrs={"num_heads": H, "head_dim": P, "n_groups": G,
+               "state_size": N, "epsilon": float(epsilon),
+               "chunk": int(chunk)})
+    return out

@@ -13,3 +13,4 @@ from .seq2seq import seq2seq_attention, seq2seq_beam_decode  # noqa: F401
 from .text import lstm_benchmark_net, stacked_lstm_net, word2vec_net  # noqa: F401
 from .transformer import transformer_lm  # noqa: F401
 from .olmoe import olmoe_lm  # noqa: F401
+from .nemotron_h import nemotron_h_lm  # noqa: F401

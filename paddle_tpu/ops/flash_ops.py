@@ -42,11 +42,23 @@ from ..core.registry import register_op
 NEG_INF = -1e30  # large-finite mask fill (inf would NaN the softmax grads)
 
 
+def _repeat_kv(q, k, v):
+    """K and V [B, T, KV, D] repeated to Q's H heads (query head j reads K/V
+    head j // (H / KV)); as they are where KV == H."""
+    group = q.shape[2] // k.shape[2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
 def scaled_dot_product_attention(q, k, v, causal: bool = False):
     """[B, T, H, D] attention, plain jnp — the numerical oracle for the
     flash kernel AND for ring/Ulysses sequence parallelism (re-exported
-    by paddle_tpu.parallel; single implementation lives here)."""
+    by paddle_tpu.parallel; single implementation lives here). K and V may
+    have fewer heads than Q (grouped-query attention): query head j reads
+    K/V head j // (H / KV), here by repeating them."""
     d = q.shape[-1]
+    k, v = _repeat_kv(q, k, v)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
     if causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
@@ -64,11 +76,15 @@ def _shapes_flash_ok(q, k) -> bool:
     q AND kv sequence lengths (the kernels' blocks divide them), a head dim
     a lane block holds whole (two heads at 64, one at 128, one over two lane
     tiles at 256), and heads x D a whole number of lane blocks (an odd head
-    count at D 64 leaves half a block: XLA keeps it)."""
+    count at D 64 leaves half a block: XLA keeps it). Fewer K/V heads than Q
+    heads: the index maps share one K/V lane block among a group of query
+    heads, so a lane block has to be one head (D 128 or 256; at D 64
+    `flash_attention` repeats K and V first)."""
     Tq, H, D = q.shape[1:]
-    Tk = k.shape[1]
+    Tk, KV = k.shape[1:3]
     return (Tq % 128 == 0 and Tk % 128 == 0 and D in (64, 128, 256)
-            and (H * D) % 128 == 0)
+            and (H * D) % 128 == 0
+            and (KV == H or (D >= _LANES and H % KV == 0)))
 
 
 # Dispatch policy: from T=1024 up the fused kernels take the job (the
@@ -420,6 +436,15 @@ def _geometry(q, k, heads):
     return B, Tq, k.shape[1], E, D, max(_LANES, D)
 
 
+def _kv_lane(q, k):
+    """Index-map helper: the K/V lane block a query lane block reads. K and
+    V packed narrower than Q hold fewer heads (grouped-query attention, one
+    head a lane block: `_shapes_flash_ok`): a group of E_q / E_kv
+    consecutive query heads shares each."""
+    group = q.shape[2] // k.shape[2]
+    return (lambda hb: hb) if group == 1 else (lambda hb: hb // group)
+
+
 def _last_k(causal, bq, bk):
     """Index map helper: the last k block a q block reads under the causal
     rule; a later (empty) step names it again, so nothing is fetched."""
@@ -457,6 +482,7 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
     bq, bk = blocks
     hpb = W // D
     kmap = _last_k(causal, bq, bk)
+    klane = _kv_lane(q, k)
     out_specs = [pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb))]
     out_shape = [jax.ShapeDtypeStruct((B, Tq, E), q.dtype)]
     if statistics:
@@ -472,9 +498,9 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
         in_specs=[
             pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb)),
             pl.BlockSpec((1, bk, W),
-                         lambda b, qi, hb, ki: (b, kmap(qi, ki), hb)),
+                         lambda b, qi, hb, ki: (b, kmap(qi, ki), klane(hb))),
             pl.BlockSpec((1, bk, W),
-                         lambda b, qi, hb, ki: (b, kmap(qi, ki), hb)),
+                         lambda b, qi, hb, ki: (b, kmap(qi, ki), klane(hb))),
         ],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hpb, bq, W), q.dtype),
@@ -492,10 +518,15 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
 def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
                      blocks: FlashBlocks, fused: bool):
     """(dq, dk, dv). `fused`: one pass with dQ's accumulator for the whole
-    sequence in VMEM; else dK / dV and dQ in a pass each."""
+    sequence in VMEM; else dK / dV and dQ in a pass each. Where a group of
+    query heads shares a K/V head, the kernels write each query head's dK
+    and dV (float32, [B, Tk, E_q]) and one reduction after them sums the
+    group."""
     B, Tq, Tk, E, D, W = _geometry(q, k, heads)
     bq, bk = blocks
     hpb = W // D
+    group = E // k.shape[2]
+    klane = _kv_lane(q, k)
     kernel = functools.partial(_bwd_kernel, D=D, scale=1.0 / math.sqrt(D),
                                causal=causal)
 
@@ -505,10 +536,10 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
         def at(row, lane=lambda hb: hb):
             return lambda b, hb, i, j: (b, row(i, j), lane(hb))
         qs = pl.BlockSpec((1, bq, W), at(qrow))
-        ks = pl.BlockSpec((1, bk, W), at(krow))
+        ks = pl.BlockSpec((1, bk, W), at(krow, lane=klane))
         stat = pl.BlockSpec((1, bq, _LANES),
                             at(qrow, lane=lambda hb: hb * hpb // _LANES))
-        return [qs, ks, ks, qs, qs, stat], qs, ks
+        return [qs, ks, ks, qs, qs, stat], qs, pl.BlockSpec((1, bk, W), at(krow))
 
     # dK and dV (and, fused, dQ): k blocks outside, q blocks inside
     qmap = _first_q(causal, bq, bk)
@@ -516,6 +547,8 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
     outs = [kspec, kspec]
     shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
               jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if group > 1:
+        shapes = [jax.ShapeDtypeStruct((B, Tk, E), jnp.float32)] * 2
     scratch = [pltpu.VMEM((hpb, bk, W), k.dtype),
                pltpu.VMEM((hpb, bk, W), v.dtype),
                pltpu.VMEM((bk, W), jnp.float32),
@@ -533,10 +566,12 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
                                 "arbitrary"),
         name="flash_attention_bwd" if fused else "flash_attention_bwd_dkv",
     )(q, k, v, o, do, lse)
+    dk, dv = got[:2]
+    if group > 1:
+        dk, dv = (a.reshape(B, Tk, E // (group * D), group, D).sum(3)
+                  .reshape(k.shape).astype(k.dtype) for a in (dk, dv))
     if fused:
-        dk, dv, dq = got
-        return dq, dk, dv
-    dk, dv = got
+        return got[2], dk, dv
     kmap = _last_k(causal, bq, bk)
     ins, qspec, _ = specs(lambda qi, ki: qi, kmap)
     dq = pl.pallas_call(
@@ -554,13 +589,16 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _packed_attention(q, k, v, heads: int, causal: bool):
-    """The fused kernels over packed [B, T, E] Q, K, V: no dispatch gate.
+    """The fused kernels over packed Q [B, T, E] and K, V [B, T, E_kv] (E_kv
+    < E: fewer K/V heads, shared by groups of query heads): no dispatch gate.
     Not differentiated, the forward writes no statistics. That also keeps it
     a launch of its own beside the differentiated forward where a program
     holds both (`Executor` traces the forward ops twice): as one identical
-    launch XLA merges the two and with them the whole doubled forward, which
-    today's benchmark cannot take (PERF.md section 6, PR 28; ROADMAP Queue 1
-    item 3b)."""
+    launch XLA would merge the two and with them the whole doubled forward.
+    The benchmark could take that since PR 30 (the memory rule that refused
+    it is gone; +25.9 % on gpt2-small, PERF.md section 6); it is left to the
+    PR that makes the merge (ROADMAP Queue 1), which also moves
+    `tests/test_tpu_compile.py`'s pin from 3 launches."""
     return _packed_forward(
         q, k, v, heads=heads, causal=causal, statistics=False,
         blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))[0]
@@ -589,7 +627,7 @@ def _flash_kernel(q, k, v, causal: bool):
     (benchmarks, the tuner and the eligible path all come through here):
     merging H and D is a free reshape to the packed layout."""
     B, Tq, H, D = q.shape
-    pack = lambda x: x.reshape(x.shape[0], x.shape[1], H * D)  # noqa: E731
+    pack = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # noqa: E731
     return _packed_attention(pack(q), pack(k), pack(v), H, causal).reshape(
         B, Tq, H, D)
 
@@ -607,7 +645,10 @@ def _count_dispatch(path: str) -> None:
 
 
 def flash_attention(q, k, v, causal: bool = False):
-    """[B, T, H, D] attention. From T=1024 the fused kernels are the path
+    """[B, T, H, D] attention; K and V may be [B, T, KV, D] with KV dividing
+    H (query head j reads K/V head j // (H / KV): at D 128 and up the
+    kernels share the K/V block, at D 64 K and V are repeated to H heads
+    first). From T=1024 the fused kernels are the path
     (and the O(T)-memory one); below that window XLA keeps the job unless
     the score buffer would exceed the memory threshold. Numerics: the
     inputs' dtype in and out (bf16 under AMP), float32 scores, statistics
@@ -615,7 +656,13 @@ def flash_attention(q, k, v, causal: bool = False):
     traced and counted in `pt_flash_attention_dispatch_total{path}`."""
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads do not share "
+                         f"{k.shape[2]} K/V heads evenly")
     from . import mesh_dispatch
+
+    if q.shape[3] < _LANES:     # two heads a lane block: no K/V block to share
+        k, v = _repeat_kv(q, k, v)
 
     am = mesh_dispatch.current()
     # mesh policy (ops/mesh_dispatch.py): a bare pallas_call cannot be
@@ -641,8 +688,10 @@ def flash_attention(q, k, v, causal: bool = False):
 def flash_attention_kernel(ctx):
     """Program-IR face of the dispatcher: Q/K/V are [B, T, E] packed
     multi-head projections; num_heads splits E (a free reshape: the kernels
-    read the packed layout). Used by layers.multi_head_attention
-    (models/transformer.py)."""
+    read the packed layout). K and V narrower than Q are [B, T, kv_heads x
+    D], the head count read from their width, and each serves a group of
+    query heads. Used by
+    layers.multi_head_attention (models/transformer.py)."""
     from .. import amp
 
     # under amp Q and K may arrive float32 (from rms_norm / rotary, which
@@ -655,6 +704,6 @@ def flash_attention_kernel(ctx):
     if E % heads:
         raise ValueError(f"hidden dim {E} not divisible by heads {heads}")
     D = E // heads
-    split = lambda x: x.reshape(B, x.shape[1], heads, D)  # noqa: E731
+    split = lambda x: x.reshape(B, x.shape[1], -1, D)  # noqa: E731
     o = flash_attention(split(q), split(k), split(v), causal=causal)
     ctx.set_output("Out", o.reshape(B, T, E))

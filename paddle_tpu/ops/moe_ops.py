@@ -1,5 +1,10 @@
-"""Routed experts: a dropless top-k mixture of SwiGLU experts, and the
-grouped matmul under it.
+"""Routed experts: a dropless top-k mixture of experts, and the grouped
+matmul under it. By arguments: a softmax router (OLMoE, Mixtral) or a
+sigmoid one with a choice bias and a gate scale (DeepSeek-V3's, Nemotron-
+H's); SwiGLU experts (three stacks) or relu^2 ones (two stacks, no gate);
+all experts on the chip or `held`, a contiguous share of them (the others
+live on other chips: pairs that chose them add nothing here); and a shared
+expert beside the routed ones.
 
 Reference lineage: the 2017 reference has no routed layer (its nearest
 kin is the MixedLayer's per-input projections); this is the sparse-expert
@@ -90,6 +95,18 @@ def gmm_eligible(lhs, rhs) -> bool:
             and mesh_dispatch.current() is None)
 
 
+def kernel_width_pad(rows: int, d: int, f: int, dtype) -> int:
+    """Zero columns to add to an expert width `f` so that the grouped-matmul
+    kernel takes the layer (its widths are whole 128-lane tiles; Nemotron's
+    1856 is 14.5): 0 where `f` is aligned already or the kernel would not
+    take the padded shapes either (another backend, a mesh)."""
+    pad = -f % 128
+    if pad and gmm_eligible(jax.ShapeDtypeStruct((rows, d), dtype),
+                            jax.ShapeDtypeStruct((1, d, f + pad), dtype)):
+        return pad
+    return 0
+
+
 def _gmm_kernel(lhs, rhs, group_sizes, interpret: bool = False):
     """Direct kernel call, no dispatch gate (tests compile it for a
     described chip and run it interpreted)."""
@@ -150,68 +167,164 @@ def _undispatch_bwd(order, g):
 _undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
 
 
-def route(x, router_w, top_k: int, norm_topk_prob: bool):
+def route(x, router_w, top_k: int, norm_topk_prob: bool,
+          scoring: str = "softmax", bias=None, gate_scale: float = 1.0):
     """The router, in float32 whatever the activations' dtype: logits
     [T, E] (matmul at the highest precision: the TPU's default would round
-    both inputs to bf16), softmax, top-k. Returns (logits, gates [T, k],
-    experts [T, k] int32)."""
+    both inputs to bf16), then the scores and the top-k. `scoring`
+    "softmax": the k largest probabilities are the gates. "sigmoid"
+    (DeepSeek-V3's): s = sigmoid(logits), the CHOICE is the top k of s +
+    `bias` ([E], no gradient), the gates are s of the chosen. Gates are
+    divided by their sum with `norm_topk_prob` and multiplied by
+    `gate_scale`. Returns (logits, gates [T, k], experts [T, k] int32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, top_k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, top_k)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, experts = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     if norm_topk_prob:
         gates = gates / gates.sum(-1, keepdims=True)
+    if gate_scale != 1.0:
+        gates = gates * gate_scale
     return logits, gates, experts.astype(jnp.int32)
 
 
+def relu2(x):
+    """relu(x)^2 in float32 (Primer's squared ReLU; Nemotron's `relu2`)."""
+    return jnp.square(jax.nn.relu(x.astype(jnp.float32)))
+
+
 def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
-            norm_topk_prob: bool = False):
+            norm_topk_prob: bool = False, *, scoring: str = "softmax",
+            router_bias=None, gate_scale: float = 1.0, held=None,
+            shared=None):
     """x [T, d] -> (out [T, d], router logits [T, E] f32, tokens per expert
-    [E] int32). y = sum_j gate_j * (silu(x Wg[e_j]) * (x Wu[e_j])) Wd[e_j]
-    over the token's top_k experts e_j. The expert matmuls run, and `out`
-    is returned, in the expert weights' dtype (the amp dtype where the
-    caller cast them); the router reads x as it comes (float32 from
-    rms_norm)."""
+    [E] int32, pairs per held expert [held] int32 or None). y = sum_j
+    gate_j * expert_{e_j}(x) over the token's top_k experts e_j of all E the
+    router scores; expert e is (silu(x Wg[e]) * (x Wu[e])) Wd[e], or, with
+    `gate_w` None, relu(x Wu[e])^2 Wd[e].
+
+    `held` (lo, hi): the stacks hold experts lo..hi-1 only. The (token,
+    slot) pairs that chose one of them are sorted to the front of the T x k
+    rows, the grouped matmuls get group sizes that sum to fewer than the
+    rows, and what lies behind is zero going in and coming out (a kernel
+    leaves those rows unwritten); pairs that chose an absent expert add
+    nothing. None: all E are here, and the ops are those of a layer that
+    knows no shares.
+
+    `shared` (up [d, f_s], down [f_s, d]): + relu(x up)^2 down, every token.
+
+    The expert matmuls run, and `out` is returned, in the expert weights'
+    dtype (the amp dtype where the caller cast them); the router reads x as
+    it comes (float32 from rms_norm)."""
     T, d = x.shape
     E = router_w.shape[1]
+    lo, hi = held or (0, E)
+    part = (lo, hi) != (0, E)
     with jax.named_scope("route"):
-        logits, gates, experts = route(x, router_w, top_k, norm_topk_prob)
+        logits, gates, experts = route(x, router_w, top_k, norm_topk_prob,
+                                       scoring, router_bias, gate_scale)
     with jax.named_scope("dispatch"):
         flat = experts.reshape(-1)                       # [T*k]
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        # an absent expert's pairs sort behind every held one's
+        key = jnp.where((flat >= lo) & (flat < hi), flat - lo, hi - lo) \
+            if part else flat
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
-        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
-        cd = gate_w.dtype
-        xs = _dispatch(x.astype(cd), order, inverse, top_k)   # [T*k, d]
-    with jax.named_scope("experts"):
-        g = grouped_matmul(xs, gate_w, group_sizes)
-        u = grouped_matmul(xs, up_w, group_sizes)
-        h = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-             ).astype(cd)
-        ys = grouped_matmul(h, down_w, group_sizes)          # [T*k, d]
-    with jax.named_scope("combine"):
-        yu = _undispatch(ys, order, inverse).reshape(T, top_k, d)
-        out = (yu.astype(jnp.float32) * gates[..., None]).sum(1)
-    return out.astype(cd), logits, group_sizes
+        counts = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        group_sizes = counts[lo:hi] if part else counts
+        cd = up_w.dtype
+        live = (jnp.arange(T * top_k) < group_sizes.sum())[:, None] \
+            if part else None
+
+    def alive(rows):
+        return rows if live is None else jnp.where(
+            live, rows, jnp.zeros_like(rows))
+
+    def matmul(lhs, rhs):
+        # zeros in AND out: the backward's rows behind the groups are as
+        # unwritten as the forward's, and `where` stops them both ways
+        return alive(grouped_matmul(alive(lhs), rhs, group_sizes))
+
+    # an expert width the kernel's tiling does not take is padded with zero
+    # columns of the up (and gate) stack and zero rows of the down stack:
+    # the hidden rows stay at the padded width and the padding adds nothing
+    f_pad = kernel_width_pad(T * top_k, d, up_w.shape[2], cd)
+
+    def routed(x, gates, down, *ups):      # ups: (gate, up) or (up,)
+        if f_pad:
+            ups = [jnp.pad(w, ((0, 0), (0, 0), (0, f_pad))) for w in ups]
+            down = jnp.pad(down, ((0, 0), (0, f_pad), (0, 0)))
+        with jax.named_scope("dispatch"):
+            xs = _dispatch(x.astype(cd), order, inverse, top_k)   # [T*k, d]
+        with jax.named_scope("experts"):
+            pre = [matmul(xs, w) for w in ups]
+            if len(pre) == 2:
+                h = (jax.nn.silu(pre[0].astype(jnp.float32))
+                     * pre[1].astype(jnp.float32)).astype(cd)
+            else:
+                h = relu2(pre[0]).astype(cd)
+            ys = matmul(h, down)                                 # [T*k, d]
+        with jax.named_scope("combine"):
+            yu = _undispatch(ys, order, inverse).reshape(T, top_k, d)
+            return (yu.astype(jnp.float32) * gates[..., None]).sum(1)
+
+    # a share keeps nothing of its T x k rows for the backward (most of
+    # them idle: 1 in 16 of Nemotron's are live, and held they would be
+    # 1 GB a layer at 8 192 tokens): the backward gathers them again
+    ups = (up_w,) if gate_w is None else (gate_w, up_w)
+    out = (jax.checkpoint(routed) if part else routed)(x, gates, down_w, *ups)
+    if shared is not None:
+        with jax.named_scope("shared"):
+            up_s, down_s = shared
+            hs = relu2(jnp.dot(x.astype(cd), up_s,
+                               preferred_element_type=jnp.float32)).astype(cd)
+            out = out + jnp.dot(hs, down_s,
+                                preferred_element_type=jnp.float32)
+    return out.astype(cd), logits, counts, (group_sizes if part else None)
 
 
 @register_op("moe_ffn")
 def moe_ffn_kernel(ctx):
-    """Program-IR face: X [B, T, d] (or [T, d]); RouterW [d, E]; GateW, UpW
-    [E, d, f]; DownW [E, f, d]. Out shaped like X, in the compute dtype;
-    RouterLogits [tokens, E]
-    float32 (under amp too: the router never drops precision, the expert
-    matmuls do); TokensPerExpert [E] int32, summing to tokens x top_k."""
+    """Program-IR face: X [B, T, d] (or [T, d]); RouterW [d, E]; GateW
+    (absent: relu^2 experts), UpW [held, d, f]; DownW [held, f, d]; optional
+    RouterBias [E], SharedUpW [d, f_s], SharedDownW [f_s, d]. Attrs: top_k,
+    norm_topk_prob, and where they differ from a softmax router over experts
+    that are all here: scoring, gate_scale, held_lo / held_hi. Out shaped
+    like X, in the compute dtype; RouterLogits [tokens, E] float32 (under
+    amp too: the router never drops precision, the expert matmuls do);
+    TokensPerExpert [E] int32, summing to tokens x top_k; HeldPairs [held]
+    int32 where the op holds a share."""
     x = ctx.input("X")
     gate_w, up_w, down_w = amp.cast_inputs(
         ctx, ctx.input("GateW"), ctx.input("UpW"), ctx.input("DownW"))
-    out, logits, counts = moe_ffn(
+    shared = None
+    if ctx.has_input("SharedUpW"):
+        shared = amp.cast_inputs(ctx, ctx.input("SharedUpW"),
+                                 ctx.input("SharedDownW"))
+    held = None
+    if ctx.attr("held_hi") is not None:
+        held = (int(ctx.attr("held_lo")), int(ctx.attr("held_hi")))
+    out, logits, counts, held_pairs = moe_ffn(
         x.reshape(-1, x.shape[-1]), ctx.input("RouterW"), gate_w, up_w,
         down_w, int(ctx.attr("top_k")),
-        bool(ctx.attr("norm_topk_prob", False)))
+        bool(ctx.attr("norm_topk_prob", False)),
+        scoring=ctx.attr("scoring", "softmax"),
+        router_bias=ctx.input("RouterBias"),
+        gate_scale=float(ctx.attr("gate_scale", 1.0)), held=held,
+        shared=shared)
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("RouterLogits", logits)
     ctx.set_output("TokensPerExpert", counts)
+    if held_pairs is not None:
+        ctx.set_output("HeldPairs", held_pairs)
 
 
 @register_op("moe_aux_loss")

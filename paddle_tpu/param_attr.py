@@ -84,9 +84,14 @@ class ParamAttr:
         attr but derive a distinct `{base}.{suffix}` name — passing the
         attr through unchanged would tie the weights into ONE shared
         parameter. attr=None derives from `base_default`; attr=False
-        passes through (explicit "no parameter")."""
+        passes through (explicit "no parameter"). A mapping {suffix: attr}
+        gives single weights of the layer an attr of their own (an
+        initialiser for the one matrix that writes to a residual stream);
+        a suffix it does not name gets the default."""
         import dataclasses
 
+        if isinstance(attr, dict):
+            attr = attr.get(suffix)
         if attr is None:
             return ParamAttr(name=f"{base_default}.{suffix}")
         if attr is False:

@@ -213,9 +213,17 @@ def test_attn_device_ms_on_the_recorded_scoped_trace():
 def test_attn_device_ms_is_in_the_manifest_for_every_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    entry = manifest["per_layer"][-1]
-    assert entry["name"] == "attn.device_ms" and entry["layer"] == "Kernels"
-    assert entry["workloads"] == [w["name"] for w in manifest["workloads"]]
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "attn.device_ms")
+    assert entry["layer"] == "Kernels"
+    # every cell of the configurations PR 28 found; a configuration added
+    # since reads the same reader through a wrapper of its own name
+    assert entry["workloads"] == [
+        w["name"] for w in manifest["workloads"]
+        if w["config"] in ("gpt2-small", "olmoe-1b-7b")]
+    wrapped = {m["name"] for m in manifest["per_layer"]
+               if m["name"].endswith(".attn_device_ms")}
+    assert wrapped == {"nemotron.attn_device_ms"}
 
 
 def test_load_max_over_mean_reads_the_counters_and_checks_the_sum():
@@ -325,3 +333,340 @@ def test_the_olmoe_cell_rehearses_on_the_cpu(tmp_path):
             "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
     assert result["metrics"]["REHEARSAL_ON_CPU.loop.dispatch_per_step"][
         "value"] == 2.0                      # sync_every 2 in the rehearsal
+
+
+# ------------------------------------------- nemotron-3-nano-30b-a3b (PR 32) ---
+NEMO = "nemotron-3-nano-30b-a3b"
+NEMO_CELL = {"batch": 1, "seqlen": 8192}
+
+
+def _nemo_config():
+    with open(os.path.join(BENCH, "configs", NEMO, "config.json")) as f:
+        return json.load(f)
+
+
+def test_nemotron_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _nemo_config()
+    config_dir = os.path.join(BENCH, "configs", NEMO)
+    got = flops.train_flops_per_item(cfg, NEMO_CELL, config_dir)
+    assert got == 3 * 715001856.0            # ISSUE 32: "near 0.72 GFLOP"
+    own = _load("configs", NEMO, "flops.py")
+    assert own.mamba_scan_flops_per_token(cfg) == 2757632.0
+    # one more M block adds the mixer's part, one more held expert a pair's
+    more = own.forward_flops_per_token(
+        dict(cfg, hybrid_override_pattern="MEMEM*EMEM"), 8192)
+    assert more - 715001856.0 == 55394304 + 22020096 + 2757632
+    wider = own.forward_flops_per_token(dict(cfg, held_experts=[0, 16]), 8192)
+    assert wider - 715001856.0 == pytest.approx(4 * 7483392.0)
+
+
+def test_nemotron_config_keeps_the_published_sizes():
+    cfg = _nemo_config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        published = next(e for e in map(json.loads, f) if e["name"] ==
+                         "NVIDIA-Nemotron-3-Nano-30B-A3B-BF16")
+    assert cfg["source"] == published["source_url"]
+    differs = [k for k, v in published["config"].items()
+               if cfg.get(k, "absent") != v]
+    assert sorted(differs) == sorted(cfg["reduced"]) == sorted(
+        ["num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
+         "vocab_size"])
+    assert cfg["published"] == {k: published["config"][k] for k in differs}
+    # the cut: the published pattern's first nine, 8 of 128 experts behind a
+    # router that stays 128 wide, an eighth of the vocabulary
+    assert cfg["hybrid_override_pattern"] == \
+        cfg["published"]["hybrid_override_pattern"][:9] == "MEMEM*EME"
+    assert cfg["num_hidden_layers"] == 9 and cfg["vocab_size"] * 8 == 131072
+    assert cfg["router_experts"] == 128 and cfg["held_experts"] == [0, 8]
+    for key in ("assumed", "departures", "deployment", "distortion"):
+        assert cfg[key], key
+    assert "routed_parameters" not in cfg or cfg["routed_parameters"]["reason"]
+
+
+def test_nemotron_kernels_count_on_hand_made_cells():
+    scan = _load("kernels", "mamba2_scan.py")
+    cfg = {"hybrid_override_pattern": "MEM*", "mamba_num_heads": 4,
+           "mamba_head_dim": 8, "n_groups": 2, "ssm_state_size": 16,
+           "chunk_size": 3}
+    flops, bytes_ = scan.flops_and_bytes(cfg, {"batch": 2, "seqlen": 5})
+    per_token = (2 * 16 * 2 + 2 * 8 * 4) * 2 + 4 * 4 * 8 * 16
+    assert flops == 2 * 10 * 3 * per_token
+    x, bc, dt = 32, 64, 4
+    assert bytes_ == 2 * 10 * ((2 * (x + bc) + 4 * dt + 4 * x)
+                               + (2 * (x + bc) + 4 * dt + 4 * x)
+                               + (2 * (x + bc) + 4 * dt))
+    flops, bytes_ = scan.flops_and_bytes(_nemo_config(), NEMO_CELL)
+    assert flops == 4 * 8192 * 3 * 2757632.0     # 0.27 TFLOP: 1.4 ms at peak
+    assert bytes_ == 4 * 8192 * 70400.0          # 2.31 GB: 2.8 ms at peak
+    assert flops / 197e12 < bytes_ / 819e9       # memory-bound, by 2 to 1
+    flash = _load("kernels", "nemotron_flash_attention.py")
+    plain = _load("kernels", "flash_attention.py")
+    cfg = dict(_nemo_config())
+    got = flash.flops_and_bytes(cfg, NEMO_CELL)
+    # one attention block of the nine, and K/V at 2 heads: the plain count
+    # at one layer has the same FLOPs and the same bytes
+    assert got == plain.flops_and_bytes(dict(cfg, num_hidden_layers=1),
+                                        NEMO_CELL)
+    assert got[0] == 32 * 12 * (8192 * 8193 // 2) * 128
+    assert plain.flops_and_bytes(cfg, NEMO_CELL)[0] == 9 * got[0]
+
+
+MIXER = "mamba2_mixer.nemotron_h.h0.mamba.tmp_3"
+NEMO_MOE = "moe_ffn.nemotron_h.h1.moe.tmp_7"
+
+
+def _nemo_run_record():
+    ops = [
+        _row(MIXER, 6_000_000, None, "", "in_proj"),
+        _row(MIXER, 8_000_000, None, "jvp(", "scan"),
+        _row(MIXER, 20_000_000, None, "transpose(jvp(", "scan"),
+        _row(MIXER, 2_000_000, None, "", "gate_norm"),
+        _row(MIXER, 90_000_000, None, "", "scan", container=True),  # a loop
+        _row(NEMO_MOE, 4_000_000, "tpu_custom_call", "", "experts"),
+        _row(NEMO_MOE, 2_000_000, None, "", "shared"),
+        _row("flash_attention.nemotron_h.h5.attn.tmp_30", 10_000_000,
+             "tpu_custom_call"),
+        _row("flash_attention.nemotron_h.h5.attn.tmp_30", 2_000_000, None,
+             "transpose(jvp("),
+        _row("mul.fc_9.tmp_9", 5_000_000),
+    ]
+    held = 'pt_moe_held_pairs_total{expert="%d",layer="nemotron_h.h1.moe"}'
+    every = 'pt_moe_expert_tokens_total{expert="%d",layer="nemotron_h.h1.moe"}'
+    registry = {held % 0: 3000.0, held % 1: 3144.0,
+                every % 0: 3000.0, every % 1: 3144.0, every % 100: 92160.0,
+                "pt_executor_donated_bytes": 8.0e9}
+    return {
+        "steps": 2, "trace": {"ops": ops}, "device": {"kind": "TPU v5 lite"},
+        "config": _nemo_config(), "cell": dict(NEMO_CELL), "registry": registry,
+        "program_ops": [
+            {"type": "mamba2_mixer", "scope": MIXER, "inputs": {"X": ["h"]},
+             "outputs": {"Out": ["nemotron_h.h0.mamba.tmp_3"]}},
+            {"type": "moe_ffn", "scope": NEMO_MOE, "inputs": {"X": ["h"]},
+             "outputs": {"Out": ["nemotron_h.h1.moe.tmp_7"]}},
+            {"type": "mul", "scope": "mul.fc_9.tmp_9",
+             "inputs": {"X": ["h"], "Y": ["w"]}, "outputs": {"Out": ["l"]}}],
+    }
+
+
+def test_ssm_readers_on_a_hand_made_run_record():
+    run = _nemo_run_record()
+    device = _load("layer_metrics", "ssm.device_ms.py")
+    assert device.compute(run) == pytest.approx((6 + 8 + 20 + 2) / 2)
+    info = device.info(run)
+    assert info["by_inner_scope_ms"] == pytest.approx(
+        {"in_proj": 3.0, "scan": 14.0, "gate_norm": 1.0})
+    assert info["by_pass_ms"] == pytest.approx(
+        {"plain": 4.0, "jvp": 4.0, "transpose": 10.0})
+    scan = _load("layer_metrics", "ssm.scan_ms.py")
+    assert scan.compute(run) == pytest.approx(14.0)
+    assert scan.info(run)["run_by"] == "xla"
+    roofline = _load("layer_metrics", "kernel.ssm_scan_roofline.py")
+    # 2.307 GB at 819 GB/s = 2.817 ms (0.2711 TFLOP would take 1.376) over
+    # the scans' 14 ms a step
+    assert roofline.compute(run) == pytest.approx(100 * 2.8167 / 14.0, rel=1e-3)
+    assert roofline.info(run)["bound"] == "memory"
+    assert roofline.info(run)["run_by"] == "xla"
+    # nothing to read: a Program without the op (the parent of the PR that
+    # added it), or no trace
+    for reader in (device, scan, roofline):
+        assert reader.compute(dict(run, program_ops=run["program_ops"][1:])) is None
+        assert reader.compute(dict(run, trace=None)) is None
+
+
+def test_nemotron_flash_roofline_and_moe_wrapper_on_a_hand_made_run_record():
+    run = _nemo_run_record()
+    flash = _load("layer_metrics", "nemotron.flash_roofline.py")
+    # 1.649 TFLOP at 197 TFLOP/s = 8.372 ms over the kernels' 5 ms a step:
+    # over 100 and shown as it is (a hand-made time)
+    assert flash.compute(run) == pytest.approx(100 * 8.3724 / 5.0, rel=1e-3)
+    assert flash.info(run)["kernels_per_step"] == 1.0
+    assert flash.compute(dict(run, trace=None)) is None
+    moe = _load("layer_metrics", "nemotron.moe_device_ms.py")
+    assert moe.compute(run) == pytest.approx(3.0)
+    assert moe.info(run)["by_inner_scope_ms"] == pytest.approx(
+        {"experts": 2.0, "shared": 1.0})
+    assert moe.info(run)["kernels_ms"] == pytest.approx(2.0)
+    assert moe.compute(dict(run, trace=None)) is None
+
+
+def test_held_pair_share_reads_the_counters_and_checks_the_sum():
+    reader = _load("layer_metrics", "moe.held_pair_share.py")
+    run = _nemo_run_record()             # 2 steps x 8 192 tokens x 6 pairs
+    assert reader.compute(run) == pytest.approx(6144 / 98304)   # 0.0625
+    assert reader.info(run)["held_pairs_per_step"] == 3072.0
+    # every expert held (no such counter), or no registry: nothing to read
+    only_all = {k: v for k, v in run["registry"].items()
+                if "held_pairs" not in k}
+    assert reader.compute(dict(run, registry=only_all)) is None
+    assert reader.compute(dict(run, registry=None)) is None
+    with pytest.raises(ValueError, match="dropped or counted twice"):
+        reader.compute(dict(run, steps=3))
+
+
+def test_nemotron_routed_readers_count_a_share_of_the_experts():
+    """The readers REVIEW (PR 32) asked for, each with the count a chip's
+    share needs: the kernels' roofline over the HELD pairs and two stacks at
+    the published width, routing's time without the shared expert, the load
+    over the 128 experts the router scores."""
+    gmm = _load("kernels", "nemotron_grouped_matmul.py")
+    cfg = _nemo_config()
+    d, f = 2688, 1856                       # the published width, not 1920
+    flops, bytes_ = gmm.flops_and_bytes(cfg, NEMO_CELL)
+    rows = 4 * 8192 * 6 * 8 / 128           # even routing: 3 072 a block
+    assert flops == 12 * rows * d * f
+    assert bytes_ == 2 * (6 * 4 * 8 * d * f + rows * 5 * (d + f))
+    assert gmm.flops_and_bytes(cfg, NEMO_CELL, rows=100.0) == (
+        12 * 100.0 * d * f, 2 * (6 * 4 * 8 * d * f + 100.0 * 5 * (d + f)))
+    run = _nemo_run_record()                # 2 steps, 6 144 held pairs
+    roofline = _load("layer_metrics", "nemotron.gmm_roofline.py")
+    # 0.184 TFLOP (0.93 ms at peak) and 2.055 GB, the 32 held experts' two
+    # stacks three times over most of it (2.51 ms at 819 GB/s): memory-bound
+    want_bytes = 2 * (6 * 4 * 8 * d * f + 3072.0 * 5 * (d + f))
+    assert roofline.info(run)["flops_per_step"] == 12 * 3072.0 * d * f
+    assert roofline.info(run)["bytes_per_step"] == want_bytes
+    assert roofline.info(run)["held_pairs_per_step"] == 3072.0
+    assert roofline.compute(run) == pytest.approx(
+        100 * want_bytes / 819e9 / 2e-3, rel=1e-3)    # the kernels' 2 ms
+    assert roofline.info(run)["bound"] == "memory"
+    no_held = {k: v for k, v in run["registry"].items()
+               if "held_pairs" not in k}
+    assert roofline.compute(dict(run, registry=no_held)) is None
+    assert roofline.compute(dict(run, trace=None)) is None
+    dispatch = _load("layer_metrics", "nemotron.moe_dispatch_ms.py")
+    run["trace"]["ops"].append(_row(NEMO_MOE, 3_000_000, None, "", "combine"))
+    # the kernel's 4 ms and the shared expert's 2 ms are not routing's
+    assert dispatch.compute(run) == pytest.approx(1.5)
+    assert dispatch.compute(dict(run, trace=None)) is None
+    load = _load("layer_metrics", "nemotron.load_max_over_mean.py")
+    # 98 304 pairs over 128 scored experts: mean 768, the busiest 92 160
+    assert load.compute(run) == pytest.approx(92160 / 768)
+    assert load.compute(dict(run, registry={})) is None
+    with pytest.raises(ValueError, match="dropped or counted twice"):
+        load.compute(dict(run, steps=3))
+    with pytest.raises(KeyError):           # why it is no plain wrapper
+        _load("layer_metrics", "moe.load_max_over_mean.py").compute(run)
+
+
+NEMO_WRAPPERS = {"nemotron.head_device_ms": "head.device_ms",
+                 "nemotron.feed_produce_ms_per_step":
+                 "feed.produce_ms_per_step",
+                 "nemotron.opt_device_ms": "opt.device_ms",
+                 "nemotron.donated_gib": "step.donated_gib",
+                 "nemotron.attn_device_ms": "attn.device_ms",
+                 "nemotron.moe_device_ms": "moe.device_ms"}
+
+
+@pytest.mark.parametrize("name", sorted(NEMO_WRAPPERS))
+def test_a_nemotron_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run = _nemo_run_record()
+    run["program_ops"] += [
+        {"type": "adam", "scope": "adam.w", "inputs": {"Param": ["w"]},
+         "outputs": {"ParamOut": ["w"]}},
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.s",
+         "inputs": {"Logits": ["l"], "Label": ["y"]},
+         "outputs": {"Softmax": ["s"], "Loss": ["c"]}}]
+    run["trace"]["ops"].append(_row("adam.w", 2_000_000))
+    run["timers_s"] = {"prefetch.read": 0.004, "prefetch.batch": 0.002}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", NEMO_WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == NEMO_WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+
+
+def test_the_manifest_lists_the_nemotron_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = NEMO + ".train-log10"
+    entry = next(w for w in manifest["workloads"] if w["name"] == cell)
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        NEMO, "train-log10", 1)
+    config = next(c for c in manifest["configs"] if c["name"] == NEMO)
+    assert config["reduced"] == _nemo_config()["reduced"]
+    mine = [m["name"] for m in manifest["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine == ["ssm.device_ms", "ssm.scan_ms", "kernel.ssm_scan_roofline",
+                    "nemotron.flash_roofline", "nemotron.attn_device_ms",
+                    "nemotron.moe_device_ms", "moe.held_pair_share",
+                    "nemotron.head_device_ms", "nemotron.opt_device_ms",
+                    "nemotron.donated_gib", "nemotron.moe_dispatch_ms",
+                    "nemotron.gmm_roofline", "nemotron.load_max_over_mean",
+                    "nemotron.feed_produce_ms_per_step"]
+    for name in mine:
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        traffic = json.load(f)
+    assert (traffic["batch"], traffic["seqlen"], traffic["sync_every"],
+            traffic["warmup_steps"], traffic["trace_seconds"]) == (
+        1, 8192, 10, 20, 4)
+
+
+def test_the_benchmarks_nemotron_reference_is_the_trees_bit_for_bit():
+    """`chipbench/configs/nemotron-3-nano-30b-a3b/reference.py` is a copy of
+    `tests/nemotron_h_reference.py`: the same cost, gradients and router
+    logits to the bit on the CPU, so the two cannot drift apart unseen."""
+    import nemotron_h_reference as tree
+
+    copy = _load("configs", NEMO, "reference.py")
+    cfg = dict(_nemo_config(), **_nemo_config()["rehearsal"])
+    d, V = cfg["hidden_size"], cfg["vocab_size"]
+    H, P = cfg["mamba_num_heads"], cfg["mamba_head_dim"]
+    G, N = cfg["n_groups"], cfg["ssm_state_size"]
+    conv = H * P + 2 * G * N
+    E, held, f = cfg["router_experts"], 4, cfg["moe_intermediate_size"]
+    fs = cfg["moe_shared_expert_intermediate_size"]
+    kinds = {
+        "M": [(d,), (d, 2 * H * P + 2 * G * N + H), (4, conv), (conv,), (H,),
+              (H,), (H,), (H * P,), (H * P, d)],
+        "*": [(d,), (d, 64), (d, 32), (d, 32), (64, d)],
+        "E": [(d,), (d, E), (held, d, f), (held, f, d), (E,), (d, fs),
+              (fs, d)]}
+    r = np.random.RandomState(0)
+    shapes = [(V, d)] + [s for kind in cfg["hybrid_override_pattern"]
+                         for s in kinds[kind]] + [(d,), (d, V)]
+    params = [(r.randn(*s) * 0.2 + (len(s) == 1)).astype(np.float32)
+              for s in shapes]
+    toks = r.randint(0, V, (2, 41))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    (c1, g1), (c2, g2) = (m.loss_and_grads(cfg, params, feed)
+                          for m in (tree, copy))
+    assert float(c1) == float(c2) and np.isfinite(float(c1))
+    assert len(g1) == len(g2) == len(params)
+    for a, b in zip(g1, g2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(tree.router_logits(cfg, params, feed),
+                    copy.router_logits(cfg, params, feed)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    text = lambda m: open(m.__file__).read().split("import math\n", 1)[1]  # noqa: E731
+    assert text(copy).startswith(text(tree))
+
+
+def test_the_nemotron_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference at the rehearsal's tolerances, both routed counters reach the
+    run record, and every metric's name carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         NEMO + ".train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486099"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.moe.held_pair_share",
+            "REHEARSAL_ON_CPU.nemotron.donated_gib",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    share = result["metrics"]["REHEARSAL_ON_CPU.moe.held_pair_share"]["value"]
+    assert 0.1 < share < 0.45                # 4 of 16 experts held: 0.25

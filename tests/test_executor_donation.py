@@ -480,6 +480,9 @@ def test_registry_gauges_and_trainer_loop():
     trainer.init()
     names = ("pt_executor_donated_buffers", "pt_executor_donated_bytes",
              "pt_executor_kept_buffers", "pt_executor_donation_mismatches")
+    import gc
+
+    gc.collect()  # an earlier test's executor must not die between the reads
     before = {n: _gauge(n) for n in names}  # declared before any step
 
     def reader():

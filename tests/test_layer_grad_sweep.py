@@ -885,6 +885,15 @@ def build_moe_ffn():
 
 
 @case
+def build_mamba2_mixer():
+    # seven tokens over chunks of four: a whole chunk, a ragged tail and the
+    # state carried between them; two heads to a group
+    h, feed = _pre_btd(7, 8)
+    return _scalar(L.mamba2_mixer(h, num_heads=4, head_dim=2, n_groups=2,
+                                  state_size=3, chunk=4)), feed
+
+
+@case
 def build_moe_aux_loss():
     (out, logits, counts), feed = _moe()
     return L.elementwise_add(_scalar(out),

@@ -13,12 +13,15 @@ happens in the test's own process; all such tests live in this one file.
 """
 
 import math
+import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
 T, B = 100, 128  # bench LSTM sequence length / batch
@@ -153,6 +156,43 @@ def _flash(case):
     return (fwd_bwd if with_bwd else fwd), qkv
 
 
+def _flash_gqa(case):
+    from paddle_tpu.ops import flash_ops
+
+    # Nemotron-3-Nano's attention at the cell's T 8192: 32 query heads x 128
+    # over 2 K/V heads, Q packed [1, 8192, 4096] and K, V [1, 8192, 256]: the
+    # index maps hand 16 query lane blocks the same K/V block, the fused
+    # backward holds dQ for the whole sequence, and dK / dV leave the kernel
+    # once a query head in float32
+    (B, T, heads, kv_heads, D) = case
+    specs = [((B, T, heads * D), BF16)] + [((B, T, kv_heads * D), BF16)] * 2
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: flash_ops._packed_attention(
+            *a, heads, True).astype(F32).sum(), (0, 1, 2))(q, k, v)
+
+    return fwd_bwd, specs
+
+
+def _gmm_share(kn):
+    from paddle_tpu.ops import moe_ops
+
+    # Nemotron's routed layer as one chip of 16 holds it: 8 192 tokens x
+    # top-6 rows, 8 held experts whose group sizes sum to FEWER than the
+    # rows; the expert width 1856 padded to 1920 (15 lane tiles)
+    k, n = kn
+    assert moe_ops._shapes_gmm_ok(jax.ShapeDtypeStruct((49152, k), BF16),
+                                  jax.ShapeDtypeStruct((8, k, n), BF16))
+    specs = [((49152, k), BF16), ((8, k, n), BF16), ((8,), jnp.int32)]
+
+    def fwd_bwd(lhs, rhs, sizes):
+        out, vjp = jax.vjp(
+            lambda a, b: moe_ops._gmm_kernel(a, b, sizes), lhs, rhs)
+        return (out, *vjp(out))
+
+    return fwd_bwd, specs
+
+
 def _gmm(kn):
     from paddle_tpu.ops import moe_ops
 
@@ -207,6 +247,9 @@ CASES = [
     ("flash_fwd_bwd_olmoe_t4096", _flash, ((2, 4096, 2048), 16, True)),
     ("gmm_fwd_bwd_olmoe_gate_up", _gmm, (2048, 1024)),
     ("gmm_fwd_bwd_olmoe_down", _gmm, (1024, 2048)),
+    ("flash_fwd_bwd_nemotron_gqa_t8192", _flash_gqa, (1, 8192, 32, 2, 128)),
+    ("gmm_fwd_bwd_nemotron_share_up", _gmm_share, (2688, 1920)),
+    ("gmm_fwd_bwd_nemotron_share_down", _gmm_share, (1920, 2688)),
     # ResNet-50 head at a full serving bucket, and the small probe shape
     ("quant_matmul_64x2048x1000", _quant, (64, 2048, 1000)),
     ("quant_matmul_8x512x512", _quant, (8, 512, 512)),
@@ -234,9 +277,10 @@ def test_plain_and_differentiated_attention_stay_two_launches(
     statistics, so it is another kernel than the differentiated forward and
     XLA keeps both; as ONE identical jitted launch it merges them, and with
     them the whole doubled forward (gpt2-small's step 139.8 -> 110.6 ms on the
-    chip, PR 28), which shrinks olmoe-1b-7b's step program under the plain
-    reference's and fails the benchmark's memory rule. Whoever repairs that
-    rule flips this count to 2 (ROADMAP Queue 1 item 3b)."""
+    chip, PR 28). The benchmark could take the merge since PR 30 (the memory
+    rule that refused it is gone; +25.9 % on gpt2-small, +3.6 % on olmoe,
+    PERF.md section 6): the PR that makes it (ROADMAP Queue 1) flips this
+    count to 2."""
     from paddle_tpu.ops import flash_ops
 
     def both(q, k, v):
@@ -248,6 +292,121 @@ def test_plain_and_differentiated_attention_stay_two_launches(
     args = [jax.ShapeDtypeStruct((2, 1024, 768), BF16, sharding=one_chip)] * 3
     text = jax.jit(both).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+# The step programs of the configurations the benchmark held before PR 32, as
+# `chipbench/configs/<c>/model.py` builds them (batch 1 x T 1024: the kernels'
+# window), lowered for the described chip with the dispatchers steered as the
+# chip would decide: sha256 of the traced program's jaxpr (the Pallas kernels'
+# bodies and index maps among it) and of the StableHLO text with the kernels'
+# serialized bodies left out (they carry file paths and line numbers). PR 32
+# added fewer K/V heads, held experts, a sigmoid router and a shared expert
+# to code these programs run through; with the old arguments they had to stay
+# what they were, and the parent of PR 32 gives these same four digests. A PR
+# that MEANS to change one of these programs replaces its digests here and
+# says so in PERF.md.
+STEP_PROGRAMS = {
+    "gpt2-small": (
+        "031ebd59ce3ca95f898465ea37e59ec8830de08ac1bbc16c920a9bd18b4fc1bf",
+        "506fcf7540cdac955e3025578367ac2cbafe202a3eb6ade196faeb8ac4c833c0"),
+    "olmoe-1b-7b": (
+        "eca432e66cff73b15d7d9e1824bce033abc53ab5b6a6119fd7d719d8d387acda",
+        "efda60cb391f99aaeb05c640be0f84063d548a91d47d752ea9c767d186ec0930"),
+}
+
+
+def _load_module(path):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("step_program_model", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _benchmark_model(name, batch, seqlen):
+    """A benchmark configuration's model as its cell builds it."""
+    import json
+
+    folder = os.path.join(ROOT, "chipbench", "configs", name)
+    with open(os.path.join(folder, "config.json")) as f:
+        config = json.load(f)
+    return _load_module(os.path.join(folder, "model.py")).get_model(
+        config, {"batch": batch, "seqlen": seqlen}, 7)
+
+
+def _step_program(build, batch, seqlen, one_chip, monkeypatch):
+    """(raw step, its arguments as shapes on the described chip) of the
+    training step of the model `build()` makes."""
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.core import executor as ex
+    from paddle_tpu.ops import flash_ops, moe_ops
+
+    monkeypatch.setattr(
+        flash_ops, "flash_eligible", lambda q, k=None: (
+            flash_ops._shapes_flash_ok(q, q if k is None else k)
+            and flash_ops._prefers_flash(q, q if k is None else k)))
+    monkeypatch.setattr(moe_ops, "gmm_eligible", moe_ops._shapes_gmm_ok)
+    pt.reset()
+    model = build()
+    prog, startup = pt.default_main_program(), pt.default_startup_program()
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), np.dtype(dtype),
+                                    sharding=one_chip)
+
+    made = {n for op in startup.global_block().ops
+            for names in op.outputs.values() for n in names}
+    state = {v.name: shape(v.shape, v.dtype) for v in prog.persistables()
+             if v.name in made}
+    rebound = ex.rebound_persistables(prog)
+    donated = {n: v for n, v in state.items() if n in rebound}
+    kept = {n: v for n, v in state.items() if n not in rebound}
+    feed = {"toks": shape((batch, seqlen), np.int32),
+            "labels": shape((batch, seqlen, 1), np.int32)}
+    fetch = [model["cost"].name] + [s["var"] for s in prog.step_statistics]
+    return (pt.Executor()._raw_step(prog, fetch),
+            (donated, kept, feed, shape((), np.uint32)))
+
+
+@pytest.mark.parametrize("name", sorted(STEP_PROGRAMS))
+def test_step_programs_of_the_older_configurations_did_not_change(
+        one_chip, compiled_mode, monkeypatch, name):
+    import hashlib
+
+    raw, args = _step_program(lambda: _benchmark_model(name, 1, 1024), 1, 1024,
+                              one_chip, monkeypatch)
+    digest = lambda text: hashlib.sha256(text.encode()).hexdigest()  # noqa: E731
+    jaxpr = str(jax.make_jaxpr(raw)(*args))
+    assert "pallas_call" in jaxpr       # the kernels are in what is hashed
+    text = jax.jit(raw, donate_argnums=(0,)).lower(*args).as_text()
+    assert text.count("tpu_custom_call") >= 3
+    hlo = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                 'backend_config = "..."', text)
+    assert (digest(jaxpr), digest(hlo)) == STEP_PROGRAMS[name]
+
+
+def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
+                                             monkeypatch):
+    """The hybrid's whole step at the size `configs/nemotron_h.py` trains
+    (batch 1 x T 8192, 667 M parameters) compiles for the described v5e: it
+    fits 15.75 GiB without `Program.remat_policy` (the compiler refuses a
+    program that does not), the attention and grouped-matmul kernels are in
+    it, and no `ragged-dot` is (XLA's own grouped kernel, which takes the
+    job where the width padding fails to hand it to megablox, carries no
+    scope for a trace's readers)."""
+    config = _load_module(os.path.join(ROOT, "configs", "nemotron_h.py"))
+    raw, args = _step_program(config.get_model, 1, 8192, one_chip,
+                              monkeypatch)
+    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_bwd" in text and "ragged-dot" not in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 7.9e9          # 12 B a parameter
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.0 * 2**30)
 
 
 def _written_arrays(hlo_text):
