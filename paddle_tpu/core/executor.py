@@ -11,15 +11,16 @@ Scope (name → value) mirrors paddle/framework/scope.h:38; persistable vars
 temporaries live only inside the traced function.
 
 Autodiff: the `autodiff` meta-op (inserted by core/backward.py, the
-counterpart of fluid backward.py:338 append_backward) is executed by
-re-tracing the forward op slice as a function of the parameters and calling
-jax.grad — replacing the reference's per-op grad-desc rewriting
+counterpart of fluid backward.py:338 append_backward) is executed by tracing
+the forward op slice in front of it ONCE, as a function of the parameters
+under jax.value_and_grad, which hands back the gradients and the forward's
+environment — replacing the reference's per-op grad-desc rewriting
 (framework/backward.cc, grad_op_desc_maker.h) with one functional transform.
-XLA CSEs the duplicated forward, so this costs nothing at runtime.
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import weakref
 from typing import Any, Dict, FrozenSet, List, Optional, Sequence
@@ -34,6 +35,7 @@ from ..flags import FLAGS
 from .lod import LoDArray
 from .place import Place, default_place
 from .program import Program, Variable, default_main_program, grad_var_name
+from .sparse import SelectedRows, SparseGradTape
 
 logger = logging.getLogger("paddle_tpu.executor")
 
@@ -170,26 +172,42 @@ def _op_scope(op) -> str:
     return f"{op.type}.{first}" if first else op.type
 
 
+def _is_array_tree(name, value) -> bool:
+    """An array or a pytree of arrays (a LoD tensor, a list, None): what a
+    traced closure can return. A Python number, string or object is static."""
+    kinds = {isinstance(leaf, (jax.Array, np.ndarray, np.generic))
+             for leaf in jax.tree_util.tree_leaves(value)}
+    if len(kinds) == 2:  # it could leave the closure neither way
+        raise TypeError(f"{name!r} is bound to a pytree that mixes arrays "
+                        f"with static leaves: {value!r}")
+    return kinds != {False}
+
+
 class _BlockRunner:
     """Trace-time walk over a block's ops. Also handed to control-flow
-
     kernels (via ctx.executor) so sub-blocks can be traced into
-    lax.scan/while_loop bodies."""
+    lax.scan/while_loop bodies. `place_grad(program, name, grad)` says where
+    a dense gradient lives (the ParallelExecutor's mesh)."""
 
-    def __init__(self, program: Program):
+    def __init__(self, program: Program, place_grad=None):
         self.program = program
+        self.place_grad = place_grad or (lambda program, name, grad: grad)
 
     def run_ops(self, ops, env: Dict[str, Any], entry_env: Dict[str, Any], block):
-        for i, op in enumerate(ops):
-            if op.type == "autodiff":
-                self._run_autodiff(ops[:i], op, env, entry_env, block)
-                continue
+        # the ops in front of the (last) autodiff op are traced once, inside
+        # its differentiation: `_run_autodiff` binds in `env` what they bound
+        first = max((i + 1 for i, op in enumerate(ops)
+                     if op.type == "autodiff"), default=0)
+        if first:
+            self._run_autodiff(ops[:first - 1], ops[first - 1], env,
+                               entry_env, block)
+        for i, op in enumerate(ops[first:], first):
             kernel = registry.get_kernel(op.type)
             ctx = registry.OpContext(op, env, executor=self, block=block)
             try:
                 # trace time only: the op's name in every HLO
-                # instruction's op_name metadata, forward and (through
-                # _run_autodiff's re-trace) transposed
+                # instruction's op_name metadata, forward and (for the
+                # ops `_run_autodiff` differentiates) transposed
                 with jax.named_scope(_op_scope(op)):
                     kernel(ctx)
             except Exception as e:
@@ -210,65 +228,57 @@ class _BlockRunner:
         return self.run_ops(block.ops, env, dict(env), block)
 
     def _run_autodiff(self, fwd_ops, op, env, entry_env, block):
+        """Trace `fwd_ops` once, under differentiation, from the block's
+        entry. `env` gets the gradients and everything the forward ops bound
+        (the cost, activations, step statistics, rebound persistables): the
+        ops behind the autodiff op and the fetches read the differentiated
+        forward's values. Arrays leave the closure as its auxiliary output;
+        what is static (the `@RNG_COUNTER@` dropout advanced) through `static`.
+        An earlier autodiff op among `fwd_ops` is differentiated inside this
+        one's closure, by the `run_ops` there."""
         loss_name = op.inputs["Loss"][0]
         param_names = list(op.attrs["params"])
-        entry_counter = entry_env.get("@RNG_COUNTER@", 0)
 
         # params marked sparse_update get SelectedRows grads: their lookup
         # sites route through a SparseGradTape so no dense [vocab, dim]
         # gradient is ever materialized (framework/selected_rows.h parity)
-        sparse_names = [
-            p for p in param_names
-            if getattr(self._var_or_none(block, p), "sparse_update", False)
-        ]
+        sparse_names = [p for p in param_names if getattr(
+            self._var_or_none(block, p), "sparse_update", False)]
         dense_names = [p for p in param_names if p not in sparse_names]
+        pvals = {p: env[p] for p in dense_names}
+        static: Dict[str, Any] = {}
 
-        def run_fwd(pvals: Dict[str, Any], tape):
-            env2 = dict(entry_env)
-            env2.update(pvals)
-            env2["@RNG_COUNTER@"] = entry_counter
-            if tape is not None:
-                env2["@SPARSE_TAPE@"] = tape
+        def closure(pv: Dict[str, Any], slots, tape=None):
+            tape = tape or SparseGradTape(sparse_names, slots=list(slots))
+            start = {**entry_env, **pv, "@SPARSE_TAPE@": tape}
+            env2 = dict(start)
             self.run_ops(fwd_ops, env2, dict(entry_env), block)
             loss = env2[loss_name]
             if getattr(loss, "size", 1) != 1:
-                raise ValueError(
-                    f"loss {loss_name!r} must be scalar for append_backward; "
-                    f"got shape {loss.shape}"
-                )
-            return jnp.reshape(loss, ())
+                raise ValueError(f"loss {loss_name!r} must be scalar for "
+                                 f"append_backward; got shape {loss.shape}")
+            bound = {k: v for k, v in env2.items()
+                     if k not in start or v is not start[k]}
+            arrays = {k: v for k, v in bound.items() if _is_array_tree(k, v)}
+            static.update({k: bound[k] for k in bound.keys() - arrays.keys()})
+            return jnp.reshape(loss, ()), (
+                arrays, [r for (_, r) in tape.ids_out])
 
-        policy = getattr(self.program, "remat_policy", None)
-        remat = (
-            (lambda f: jax.checkpoint(f, policy=_REMAT_POLICIES[policy]))
-            if policy else (lambda f: f)
-        )
-        pvals = {p: env[p] for p in dense_names}
-
-        if not sparse_names:
-            closure = remat(lambda pv: run_fwd(pv, None))
-            grads = jax.grad(closure)(pvals)
-            for p in dense_names:
-                env[grad_var_name(p)] = grads[p]
-            return
-
-        from .sparse import SelectedRows, SparseGradTape
-
-        # a sparse_update param may ONLY be consumed by lookup_table ops:
-        # any other use (e.g. a tied-embedding output projection through
-        # mul) would silently contribute zero gradient, because the param
-        # is stop_gradient'ed at lookup sites and excluded from the
-        # differentiated inputs. Static walk over every block catches it.
-        sparse_set = set(sparse_names)
-        for blk in self.program.blocks:
-            for o in blk.ops:
+        sites = []
+        if sparse_names:
+            # a sparse_update param may ONLY be consumed by lookup_table ops:
+            # any other use (e.g. a tied-embedding output projection through
+            # mul) would silently contribute zero gradient, because the param
+            # is stop_gradient'ed at lookup sites and excluded from the
+            # differentiated inputs. Static walk over every block catches it.
+            for o in (o for blk in self.program.blocks for o in blk.ops):
                 # optimizer update ops legitimately consume the param and
                 # its SelectedRows grad (ops/optimizer_ops.py handles both)
                 if o.type in ("lookup_table", "autodiff") or \
                         o.attrs.get("is_optimizer_op"):
                     continue
                 used = [n for ns in o.inputs.values() for n in ns
-                        if n in sparse_set]
+                        if n in sparse_names]
                 if used:
                     raise ValueError(
                         f"sparse_update param(s) {used} consumed by op "
@@ -276,45 +286,37 @@ class _BlockRunner:
                         "lookup_table uses — rebuild the embedding with "
                         "is_sparse=False for tied/shared-weight patterns"
                     )
+            # abstract pass (no FLOPs): discover gather sites and shapes
+            disco = SparseGradTape(sparse_names)
+            jax.eval_shape(lambda pv: closure(pv, None, disco), pvals)
+            sites = disco.sites
+            missing = sorted(set(sparse_names) - {s[0] for s in sites})
+            if missing:
+                raise ValueError(
+                    f"sparse_update params {missing} have no lookup_table "
+                    "site in the program — only embedding gathers support "
+                    "SelectedRows gradients")
 
-        # pass 1 (abstract, no FLOPs): discover gather sites and shapes
-        disco = SparseGradTape(sparse_names)
-        jax.eval_shape(lambda pv: run_fwd(pv, disco), pvals)
-        missing = [p for p in sparse_names
-                   if p not in {s[0] for s in disco.sites}]
-        if missing:
-            raise ValueError(
-                f"sparse_update params {missing} have no lookup_table site "
-                "in the program — only embedding gathers support "
-                "SelectedRows gradients"
-            )
-
-        # pass 2: differentiate w.r.t. dense params AND the per-site row
-        # slots; the slot cotangents are the SelectedRows values
-        def closure(pv, slots):
-            tape = SparseGradTape(sparse_names, slots=list(slots))
-            loss = run_fwd(pv, tape)
-            rows_aux = [r for (_, r) in tape.ids_out]
-            return loss, rows_aux
-
-        slots0 = [jnp.zeros(shape, dt) for (_, shape, dt) in disco.sites]
-        grad_fn = jax.value_and_grad(
-            remat(closure), argnums=(0, 1), has_aux=True
-        )
-        (_, rows_aux), (grads, slot_grads) = grad_fn(pvals, slots0)
+        # the one trace: differentiate w.r.t. the dense params AND the per-site
+        # row slots (none in a dense program): their cotangents are the rows
+        policy = getattr(self.program, "remat_policy", None)
+        if policy:
+            closure = jax.checkpoint(closure, policy=_REMAT_POLICIES[policy])
+        slots0 = [jnp.zeros(shape, dt) for (_, shape, dt) in sites]
+        (_, (bound, rows_aux)), (grads, slot_grads) = jax.value_and_grad(
+            closure, argnums=(0, 1), has_aux=True)(pvals, slots0)
+        env.update({**bound, **static})
         for p in dense_names:
-            env[grad_var_name(p)] = grads[p]
-        site_params = [s[0] for s in disco.sites]
+            env[grad_var_name(p)] = self.place_grad(self.program, p, grads[p])
+        site_params = [s[0] for s in sites]
         for p in sparse_names:
-            num_rows = env[p].shape[0]
-            dim = env[p].shape[1]
+            num_rows, dim = env[p].shape
             rows = [r.reshape(-1) for sp, r in zip(site_params, rows_aux)
                     if sp == p]
             vals = [g.reshape(-1, dim)
                     for sp, g in zip(site_params, slot_grads) if sp == p]
             env[grad_var_name(p)] = SelectedRows(
-                jnp.concatenate(rows), jnp.concatenate(vals), num_rows
-            )
+                jnp.concatenate(rows), jnp.concatenate(vals), num_rows)
 
     @staticmethod
     def _var_or_none(block, name):
@@ -483,8 +485,6 @@ class Executor:
         ParallelExecutor overrides this to declare its mesh to the
         fused-kernel dispatch layer (ops/mesh_dispatch.py), which then
         shard_maps eligible pallas calls over the dp axis."""
-        import contextlib
-
         return contextlib.nullcontext()
 
     # ------------------------------------------------------------------
@@ -671,7 +671,7 @@ class Executor:
         `_compile` (one jitted step) and `_build_window` (K steps under
         one lax.scan) compile. The state arrives split as `_split_state`
         splits it; see `_state_outputs` for what comes back."""
-        runner = _BlockRunner(program)
+        runner = _BlockRunner(program, getattr(self, "_place_grad", None))
         record = self._donation_record()
 
         def raw(donated: Dict[str, Any], kept: Dict[str, Any],

@@ -67,7 +67,8 @@ class OpContext:
         """Deterministic per-op PRNG key. The executor threads a base key
 
         through the env under "@RNG@"; each draw folds in a fresh counter so
-        re-tracing (e.g. under jax.grad) reproduces identical randomness."""
+        re-tracing (the abstract pass before a sparse_update program's
+        differentiated one) reproduces identical randomness."""
         import jax
 
         key = self.env["@RNG@"]

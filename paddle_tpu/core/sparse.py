@@ -201,12 +201,16 @@ class SparseGradTape:
     differentiated inputs. Static shapes hold because feeds are
     shape-bucketed (core/lod.py / SparseArray).
 
-    Two passes share one tape protocol (core/executor.py _run_autodiff):
-    - discovery (slots=None, under jax.eval_shape): records each site's
-      (param, shape, dtype); next_slot returns zeros.
-    - apply (slots=list of tracers): next_slot hands out the tracers in the
-      same deterministic trace order; record_ids collects the traced row
-      ids per site, returned as the closure's aux output.
+    Two passes of ONE closure share the tape protocol (core/executor.py
+    _run_autodiff; there is no third, plain trace of the forward ops):
+    - discovery (slots=None, under jax.eval_shape: abstract, nothing runs
+      on the device): records each site's (param, shape, dtype); next_slot
+      returns zeros.
+    - apply (slots=list of tracers, under jax.value_and_grad: the step's
+      only forward pass): next_slot hands out the tracers in the same
+      deterministic trace order; record_site collects the traced row ids
+      per site, returned in the closure's aux output beside the forward's
+      environment.
     """
 
     def __init__(self, sparse_params, slots=None):

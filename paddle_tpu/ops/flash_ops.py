@@ -591,14 +591,10 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
 def _packed_attention(q, k, v, heads: int, causal: bool):
     """The fused kernels over packed Q [B, T, E] and K, V [B, T, E_kv] (E_kv
     < E: fewer K/V heads, shared by groups of query heads): no dispatch gate.
-    Not differentiated, the forward writes no statistics. That also keeps it
-    a launch of its own beside the differentiated forward where a program
-    holds both (`Executor` traces the forward ops twice): as one identical
-    launch XLA would merge the two and with them the whole doubled forward.
-    The benchmark could take that since PR 30 (the memory rule that refused
-    it is gone; +25.9 % on gpt2-small, PERF.md section 6); it is left to the
-    PR that makes the merge (ROADMAP Queue 1), which also moves
-    `tests/test_tpu_compile.py`'s pin from 3 launches."""
+    Not differentiated, the forward writes no statistics: this is what an
+    inference program launches. A training step launches `_packed_attention_fwd`
+    instead, once (`Executor` traces the forward ops once, under
+    differentiation), so no program holds the two side by side any more."""
     return _packed_forward(
         q, k, v, heads=heads, causal=causal, statistics=False,
         blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))[0]

@@ -128,6 +128,16 @@ class ParallelExecutor(Executor):
             )
         return shard_leaf(value)
 
+    def _place_grad(self, program: Program, name: str, grad):
+        """A parameter's gradient lives where the parameter does (replicated
+        unless the Variable carries `.sharding`): the psum over `dp` ends
+        here. Without it GSPMD carries the ZeRO-sharded moments' layout back
+        through the weight gradients into the differentiated forward, and
+        splits a scan over its hidden axis, collectives in every iteration,
+        with the whole batch on every device."""
+        return jax.lax.with_sharding_constraint(
+            grad, self._state_sharding(program, name))
+
     # -- Executor hooks -----------------------------------------------------
     @property
     def _multiprocess(self) -> bool:

@@ -184,6 +184,39 @@ def test_attn_device_ms_on_a_hand_made_run_record():
     assert attn.compute({"steps": 2}) is None
 
 
+def test_plain_forward_ms_on_a_hand_made_run_record():
+    """PR 33: the forward ops' device time outside differentiation. A step
+    that holds its forward once leaves only what no parameter reaches."""
+    reader = _load("layer_metrics", "step.plain_forward_ms.py")
+    adam = {"type": "adam", "scope": "adam.w", "inputs": {"Param": ["w"]},
+            "outputs": {"ParamOut": ["w"]}}
+    autodiff = {"type": "autodiff", "scope": "autodiff.w@GRAD",
+                "inputs": {"Loss": ["l"]}, "outputs": {"Grads": ["w@GRAD"]}}
+    run = _run_record()
+    run["program_ops"] += [autodiff, adam]
+    run["trace"]["ops"] += [
+        _row("adam.w", 7_000_000),                       # behind the autodiff
+        _row("mul.fc_9.tmp_9", 2_000_000, None, "jvp("),
+        _row("mul.fc_9.tmp_9", 2_500_000, None, "transpose(jvp("),
+        _row("", 1_500_000),                             # no scope
+    ]
+    # the routed op's two plain rows (its container left out), the attention
+    # op's is of no listed op, the mul's plain row
+    assert reader.compute(run) == pytest.approx((4 + 3 + 5) / 2)
+    assert reader.info(run) == {"by_op_type_ms": pytest.approx(
+        {"moe_ffn": 3.5, "mul": 2.5})}
+    assert list(reader.info(run)["by_op_type_ms"]) == ["moe_ffn", "mul"]
+    # one trace of the forward: every forward row is `jvp`
+    once = dict(run, trace={"ops": [r for r in run["trace"]["ops"]
+                                    if r["transform"] or r["scope"] == "adam.w"]})
+    assert reader.compute(once) == 0.0
+    assert reader.info(once) == {"by_op_type_ms": {}}
+    # nothing to read: an inference Program, no Program, no trace
+    assert reader.compute(dict(run, program_ops=run["program_ops"][:2])) is None
+    assert reader.compute(dict(run, program_ops=None)) is None
+    assert reader.compute(dict(run, trace=None)) is None
+
+
 def test_attn_device_ms_on_the_recorded_scoped_trace():
     """The parent's gpt2-small step as recorded by PR 26 (TPU v5 lite, seed
     2147486001, two steps): PERF.md's 68.58 ms, 28.34 of it not kernels;

@@ -144,7 +144,8 @@ def test_dp_step_is_sharded_not_replicated(n):
     contains: dp<n> agrees with dp1 on the loss, every device holds one
     n-th of the batch and of the ZeRO-sharded optimizer state, the
     per-device program carries the per-shard batch and never the global
-    one, and it all-reduces gradients. Real Nx needs real chips
+    one (the one forward the step holds, under differentiation, and its
+    transpose), and it all-reduces gradients. Real Nx needs real chips
     (`chip_smoke.py --four-chips`)."""
     B, H, T = 64, 256, 16
     losses = {}
@@ -187,4 +188,9 @@ def test_dp_step_is_sharded_not_replicated(n):
                         jnp.uint32(0)).compile().as_text()
     assert f"f32[{T},{B // n},{4 * H}]" in text   # per-shard scan input
     assert f"f32[{T},{B},{4 * H}]" not in text    # never the global batch
+    # nor the whole batch with the scan split over its hidden axis: where
+    # GSPMD took the step before the gradients were held to the parameters'
+    # layout (`ParallelExecutor._place_grad`), an all-to-all a scan step
+    assert f"f32[{T},{B},{H // n}]" not in text
+    assert "all-to-all" not in text
     assert "all-reduce" in text

@@ -240,7 +240,10 @@ def test_compiled_step_carries_op_scopes_forward_and_transposed():
             if op.type == "mul"]
     assert len(muls) == 2
     for out in muls:
-        assert any(f"/mul.{out}/" in n for n in op_names), (out, op_names)
+        # the forward is in the program once, as the forward half of the
+        # differentiated op: no plain copy of it beside that
+        assert any(f"/jvp(mul.{out})/" in n for n in op_names), (out, op_names)
+        assert not any(f"/mul.{out}/" in n for n in op_names), (out, op_names)
         assert any(f"transpose(jvp(mul.{out}))" in n for n in op_names), \
             (out, op_names)
     assert any(re.search(r"/adam\.fc_\d+\.w_\d+/", n) for n in op_names)

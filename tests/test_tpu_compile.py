@@ -272,15 +272,14 @@ def test_kernel_compiles_for_v5e(one_chip, compiled_mode, build, arg):
 
 def test_plain_and_differentiated_attention_stay_two_launches(
         one_chip, compiled_mode):
-    """A step program holds the forward ops twice (`Executor` traces them and
-    `_run_autodiff` traces them again). The plain attention forward writes no
-    statistics, so it is another kernel than the differentiated forward and
-    XLA keeps both; as ONE identical jitted launch it merges them, and with
-    them the whole doubled forward (gpt2-small's step 139.8 -> 110.6 ms on the
-    chip, PR 28). The benchmark could take the merge since PR 30 (the memory
-    rule that refused it is gone; +25.9 % on gpt2-small, +3.6 % on olmoe,
-    PERF.md section 6): the PR that makes it (ROADMAP Queue 1) flips this
-    count to 2."""
+    """This function calls the op twice itself: plainly, as an inference
+    program launches it, and under `jax.grad`. The plain attention forward
+    writes no statistics, so it is another kernel than the differentiated
+    forward and XLA keeps both beside the fused backward: 3. (A training
+    step holds the forward once since PR 33, whatever its launches look
+    like: `test_step_program_holds_each_forward_kernel_launch_once`. As ONE
+    identical launch XLA merged the two, and with them a whole doubled
+    forward: gpt2-small's step 139.8 -> 110.6 ms on the chip, PR 28.)"""
     from paddle_tpu.ops import flash_ops
 
     def both(q, k, v):
@@ -302,17 +301,46 @@ def test_plain_and_differentiated_attention_stay_two_launches(
 # serialized bodies left out (they carry file paths and line numbers). PR 32
 # added fewer K/V heads, held experts, a sigmoid router and a shared expert
 # to code these programs run through; with the old arguments they had to stay
-# what they were, and the parent of PR 32 gives these same four digests. A PR
-# that MEANS to change one of these programs replaces its digests here and
-# says so in PERF.md.
+# what they were. PR 33 MEANT to change them (the forward ops are traced once,
+# under differentiation: `core/executor.py:_run_autodiff`) and replaced all
+# four digests (PERF.md section 6); the programs without an autodiff op it
+# had to leave alone: INFERENCE_PROGRAMS, whose digests its parent gives too.
+# A PR that MEANS to change one of these programs replaces its digests here
+# and says so in PERF.md.
 STEP_PROGRAMS = {
     "gpt2-small": (
-        "031ebd59ce3ca95f898465ea37e59ec8830de08ac1bbc16c920a9bd18b4fc1bf",
-        "506fcf7540cdac955e3025578367ac2cbafe202a3eb6ade196faeb8ac4c833c0"),
+        "b0c916dc2ede4bd1dbb46930d62ab54dd956fc3b1ad9f4055bf6748da0c68051",
+        "730695f9a2bfef705cb0288c6c24bdb03987e0b842152fd2df2cdf96bf441588"),
     "olmoe-1b-7b": (
-        "eca432e66cff73b15d7d9e1824bce033abc53ab5b6a6119fd7d719d8d387acda",
-        "efda60cb391f99aaeb05c640be0f84063d548a91d47d752ea9c767d186ec0930"),
+        "2437f91b266525f66bb74643b01a70d34bf0db40cf40fddc96f955cf243cdd8d",
+        "eed2844a80e3c5a1be6360799c6a496d78b55d20e54cc140d638049bb7fb3c2e"),
 }
+# the same models' `clone(for_test=True)` (no autodiff op, no optimizer op):
+# sha256 of the StableHLO text, kernels' bodies left out
+INFERENCE_PROGRAMS = {
+    "gpt2-small":
+        "b6d9e75d434f2cb52b064a04313fe4d1f325ac2825cd95307a4349a7f2a90fc0",
+    "olmoe-1b-7b":
+        "a155e756d34b15cb476bd9ef1b66039d1624c1b9a33dcfbcafdeda8442d2b19b",
+}
+# Pallas launches in the traced training step, by kernel: each forward launch
+# once (the parent held it twice: 36 and 15 launches)
+STEP_LAUNCHES = {
+    "gpt2-small": {"flash_attention_fwd": 12, "flash_attention_bwd": 12},
+    "olmoe-1b-7b": {"flash_attention_fwd": 1, "flash_attention_bwd": 1,
+                    "grouped_matmul": 9},
+}
+
+
+def _digest(text, kernel_bodies=True):
+    """sha256 of a program's text, of StableHLO with the kernels' serialized
+    bodies left out."""
+    import hashlib
+
+    if not kernel_bodies:
+        text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                      'backend_config = "..."', text)
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def _load_module(path):
@@ -335,9 +363,9 @@ def _benchmark_model(name, batch, seqlen):
         config, {"batch": batch, "seqlen": seqlen}, 7)
 
 
-def _step_program(build, batch, seqlen, one_chip, monkeypatch):
+def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
     """(raw step, its arguments as shapes on the described chip) of the
-    training step of the model `build()` makes."""
+    training step of the model `build()` makes, or of its `for_test` clone."""
     import numpy as np
 
     import paddle_tpu as pt
@@ -352,6 +380,8 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch):
     pt.reset()
     model = build()
     prog, startup = pt.default_main_program(), pt.default_startup_program()
+    if for_test:
+        prog = prog.clone(for_test=True)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(tuple(dims), np.dtype(dtype),
@@ -374,18 +404,52 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch):
 @pytest.mark.parametrize("name", sorted(STEP_PROGRAMS))
 def test_step_programs_of_the_older_configurations_did_not_change(
         one_chip, compiled_mode, monkeypatch, name):
-    import hashlib
-
     raw, args = _step_program(lambda: _benchmark_model(name, 1, 1024), 1, 1024,
                               one_chip, monkeypatch)
-    digest = lambda text: hashlib.sha256(text.encode()).hexdigest()  # noqa: E731
     jaxpr = str(jax.make_jaxpr(raw)(*args))
     assert "pallas_call" in jaxpr       # the kernels are in what is hashed
     text = jax.jit(raw, donate_argnums=(0,)).lower(*args).as_text()
-    assert text.count("tpu_custom_call") >= 3
-    hlo = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
-                 'backend_config = "..."', text)
-    assert (digest(jaxpr), digest(hlo)) == STEP_PROGRAMS[name]
+    assert (_digest(jaxpr), _digest(text, kernel_bodies=False)) \
+        == STEP_PROGRAMS[name]
+
+
+@pytest.mark.parametrize("name", sorted(INFERENCE_PROGRAMS))
+def test_program_without_an_autodiff_op_lowers_to_the_parents_text(
+        one_chip, compiled_mode, monkeypatch, name):
+    raw, args = _step_program(lambda: _benchmark_model(name, 1, 1024), 1, 1024,
+                              one_chip, monkeypatch, for_test=True)
+    text = jax.jit(raw, donate_argnums=(0,)).lower(*args).as_text()
+    assert "tpu_custom_call" in text
+    assert _digest(text, kernel_bodies=False) == INFERENCE_PROGRAMS[name]
+
+
+def _launches(jaxpr):
+    """Pallas launches of a traced program by kernel name, those inside
+    called sub-programs (custom_vjp rules, checkpoints, loops) counted as
+    often as they are called."""
+    import collections
+
+    found = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[str(eqn.params["name"] or "grouped_matmul")] += 1
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _launches(sub)
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(STEP_LAUNCHES))
+def test_step_program_holds_each_forward_kernel_launch_once(
+        one_chip, compiled_mode, monkeypatch, name):
+    """Two thirds of the launches the parent of PR 33 traced: per attention
+    layer one forward and one fused backward (it held a second, plain
+    forward), per routed layer 3 grouped matmuls forward and 6 backward (it
+    held 3 more forward)."""
+    raw, args = _step_program(lambda: _benchmark_model(name, 1, 1024), 1, 1024,
+                              one_chip, monkeypatch)
+    assert dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr)) \
+        == STEP_LAUNCHES[name]
 
 
 def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
