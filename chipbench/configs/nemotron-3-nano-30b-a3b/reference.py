@@ -2,15 +2,35 @@
 `tests/nemotron_h_reference.py` (everything from `import math` down to
 `router_logits` is that file's text; a tier-1 test,
 `tests/test_chipbench_harness.py`, holds the two to the same bits on the
-CPU), with the harness's `prepare` added at the end. Copied so that an edit
-in the tree cannot move the yardstick unseen. The equations, the parameter
-order and the departures from a literal transcription (the recurrence as a
-scan over chunks with a token-by-token scan inside, attention mapped over
-heads, the experts as a scan over the held stack, the head in chunks: so
-that it fits beside 2.7 GB of weights and their gradients after the window)
-are in that file's docstring; `config.json` carries the sizes under the
-published keys plus `router_experts` (the 128 the router scores) and
-`held_experts` (the 8 this chip holds).
+CPU), with the harness's `prepare` and the handed choice (PR 36) added at the
+end. Copied so that an edit in the tree cannot move the yardstick unseen. The
+equations, the parameter order and the departures from a literal transcription
+(the recurrence as a scan over chunks with a token-by-token scan inside,
+attention mapped over heads, the experts as a scan over the held stack, the
+head in chunks: so that it fits beside 2.7 GB of weights and their gradients
+after the window) are in that file's docstring; `config.json` carries the
+sizes under the published keys plus `router_experts` (the 128 the router
+scores) and `held_experts` (the 8 this chip holds).
+
+The handed choice. A float32 reference that makes its own top-6 choice
+disagrees with a sound bf16 program wherever the sixth and seventh scores are
+a rounding apart, and a whole expert (gate about 0.4) then moves in or out:
+the comparison of gradients read that as an error of 0.2-0.3 of a router's
+rms on one run in six (PERF.md section 6, PRs 32-36). So `loss_and_grads`,
+`cost`, `hidden` and `router_logits` take `choice`: a list, one per E block,
+of 0/1 masks [tokens, router_experts] saying which experts each token's pairs
+go to. Where it is given the gates are THIS file's float32 scores of those
+experts, renormalised and scaled as published; gradients flow through the
+scores as before, and a later block's hidden state is this file's own under
+the handed choices. `choice=None` is the reference's own choice, bit for bit
+what the tree's file computes. `chosen` applies the published rule to router
+logits that are handed in (the program's own `RouterLogits`), and
+`loss_grads_and_routers` is `loss_and_grads` with this file's routers (input,
+weight, logits) beside the cost, from the same forward. The functions below
+`prepare` redefine the tree's of the same names with that one more argument,
+because this PR may not edit the tree's file and the twin test wants its text
+whole at the top: when a later PR gives `tests/nemotron_h_reference.py` the
+argument, the redefinitions go.
 """
 
 import math
@@ -248,3 +268,119 @@ def router_logits(config, params, feed):
 def prepare(feed):
     """The reader's batch is already a dict of arrays."""
     return feed
+
+
+# ---------------------------------------------------- the handed choice
+def _top_k_mask(scores, top_k):
+    """[N, E] -> 0/1 [N, E]: the `top_k` largest of each row, picked one at
+    a time, the lowest index first among equals (as `jax.lax.top_k`)."""
+    def pick(_, chosen):
+        best = jnp.argmax(jnp.where(chosen > 0, -jnp.inf, scores), axis=-1)
+        return chosen + jax.nn.one_hot(best, scores.shape[-1],
+                                       dtype=scores.dtype)
+
+    return jax.lax.fori_loop(0, top_k, pick, jnp.zeros_like(scores))
+
+
+def chosen(config, params, logits):
+    """The published choice on HANDED router logits (a list of float32
+    [tokens, E], one per E block): the top k of sigmoid(z) + b, b the block's
+    choice bias among `params`. A list of 0/1 masks [tokens, E]."""
+    _, blocks, _, _ = _split(config, params)
+    biases = [p[4] for kind, p in blocks if kind == "E"]
+    assert len(biases) == len(logits), (len(biases), len(logits))
+    return [_top_k_mask(jax.nn.sigmoid(jnp.asarray(z, jnp.float32)) + b,
+                        config["num_experts_per_tok"])
+            for z, b in zip(logits, biases)]
+
+
+def router_scores(config, h, wr, b, chosen=None):
+    """As above; `chosen` [N, E] 0/1 takes the place of the top-k of s + b."""
+    z = h @ wr
+    s = jax.nn.sigmoid(z)
+    if chosen is None:
+        chosen = _top_k_mask(jax.lax.stop_gradient(s + b),
+                             config["num_experts_per_tok"])
+    gates = s * chosen
+    if config["norm_topk_prob"]:
+        gates = gates / gates.sum(-1, keepdims=True)
+    return z, gates * config["routed_scaling_factor"]
+
+
+def _experts(config, h, wr, w_up, w_down, b, up_s, down_s, chosen=None):
+    """h [N, d] -> (y [N, d], router logits [N, E])."""
+    lo, hi = _held(config)
+    z, gates = router_scores(config, h, wr, b, chosen)
+
+    def add(y, expert):
+        return y + jax.checkpoint(_expert)(h, *expert), None
+
+    y, _ = jax.lax.scan(add, jnp.zeros_like(h),
+                        (w_up, w_down, gates[:, lo:hi].T))
+    return y + _relu2(h @ up_s) @ down_s, z
+
+
+def _hidden(config, params, toks, choice):
+    """toks [B, T] -> (x [B, T, d] before the final norm, each E block's
+    router: its input h [B*T, d], its weight and its logits [B*T, E])."""
+    tok_emb, blocks, _, _ = _split(config, params)
+    Bsz, T = toks.shape
+    eps = config["layer_norm_epsilon"]
+    x = jax.lax.map(lambda t: tok_emb[t], toks)                  # [B, T, d]
+    routers = []
+    for kind, (w_ln, *p) in blocks:
+        h = _rms(x, w_ln, eps)
+        if kind == "M":
+            y = _mamba(config, h, *p)
+        elif kind == "*":
+            y = _attention(config, h, *p)
+        else:
+            h = h.reshape(Bsz * T, -1)
+            y, z = _experts(
+                config, h, *p,
+                chosen=None if choice is None else choice[len(routers)])
+            y = y.reshape(Bsz, T, -1)
+            routers.append((h, p[0], z))
+        x = x + y
+    return x, routers
+
+
+def hidden(config, params, toks, choice=None):
+    """As above; `choice[i]` is handed to the i-th E block."""
+    x, routers = _hidden(config, params, toks, choice)
+    return x, [z for _, _, z in routers]
+
+
+def _cost_and_routers(config, params, feed, choice):
+    _, _, w_f, w_head = _split(config, params)
+    toks, labels = jnp.asarray(feed["toks"]), jnp.asarray(feed["labels"])
+    x, routers = _hidden(config, params, toks, choice)
+    x = x.reshape(-1, x.shape[-1])
+    return _cross_entropy_sum(config, x, labels.reshape(-1, 1), w_f,
+                              w_head) / x.shape[0], routers
+
+
+def cost(config, params, feed, choice=None):
+    return _cost_and_routers(config, params, feed, choice)[0]
+
+
+def loss_grads_and_routers(config, params, feed, choice=None):
+    """(cost, gradients, each E block's router as this file computed it:
+    input [tokens, d], weight [d, E], logits [tokens, E]), one forward pass:
+    `loss_and_grads` with what the gates were scored from beside it."""
+    params = [jnp.asarray(p, jnp.float32) for p in params]
+    with jax.default_matmul_precision("highest"):
+        (cost_, routers), grads = jax.value_and_grad(
+            lambda ps: _cost_and_routers(config, ps, feed, choice),
+            has_aux=True)(params)
+    return cost_, grads, routers
+
+
+def loss_and_grads(config, params, feed, choice=None):
+    """As above; with `choice`, under the handed experts."""
+    return loss_grads_and_routers(config, params, feed, choice)[:2]
+
+
+def router_logits(config, params, feed, choice=None):
+    with jax.default_matmul_precision("highest"):
+        return hidden(config, params, jnp.asarray(feed["toks"]), choice)[1]

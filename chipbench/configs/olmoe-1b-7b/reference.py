@@ -1,8 +1,28 @@
 """Plain reference for olmoe-1b-7b: a COPY of `tests/olmoe_reference.py`
 (everything from `import math` down to `router_logits` is that file's text;
 a tier-1 test, `tests/test_chipbench_harness.py`, holds the two to the same
-bits on the CPU), with the harness's `prepare` added at the end. Copied so
-that an edit in the tree cannot move the yardstick unseen.
+bits on the CPU), with the harness's `prepare` and the handed choice (PR 36)
+added at the end. Copied so that an edit in the tree cannot move the
+yardstick unseen.
+
+The handed choice. A float32 reference that makes its own top-8 choice
+disagrees with a sound bf16 program wherever the eighth and ninth
+probabilities are a rounding apart, and the comparison of gradients then
+reads the moved pair as an error (PERF.md section 6, PRs 26-36). So
+`loss_and_grads`, `cost`, `hidden` and `router_logits` take `choice`: a list,
+one per layer, of 0/1 masks [tokens, E] saying which experts each token's
+pairs go to. Where it is given the gates are THIS file's float32
+probabilities of those experts (renormalised where `norm_topk_prob`), and the
+balance cost's shares f_e are counted over them; gradients flow through the
+probabilities as before. `choice=None` is the reference's own choice, bit for
+bit what the tree's file computes. `chosen` applies the published rule to
+router logits that are handed in (the program's own `RouterLogits`), and
+`loss_grads_and_routers` is `loss_and_grads` with this file's routers (input,
+weight, logits) beside the cost, from the same forward. The functions below
+`prepare` redefine the tree's of the same names with that one more argument,
+because this PR may not edit the tree's file and the twin test wants its text
+whole at the top: when a later PR gives `tests/olmoe_reference.py` the
+argument, the redefinitions go.
 
 The layer, as `allenai/OLMoE-1B-7B-0125-Instruct` publishes it
 (`transformers` model_type `olmoe`; Muennighoff et al. 2024). With x
@@ -237,3 +257,114 @@ def router_logits(config, params, feed):
 def prepare(feed):
     """The reader's batch is already a dict of arrays."""
     return feed
+
+
+# ---------------------------------------------------- the handed choice
+def _top_k_mask(scores, top_k):
+    """[N, E] -> 0/1 [N, E]: the `top_k` largest of each row, picked one at
+    a time, the lowest index first among equals (as `jax.lax.top_k`)."""
+    def pick(_, chosen):
+        best = jnp.argmax(jnp.where(chosen > 0, -jnp.inf, scores), axis=-1)
+        return chosen + jax.nn.one_hot(best, scores.shape[-1],
+                                       dtype=scores.dtype)
+
+    return jax.lax.fori_loop(0, top_k, pick, jnp.zeros_like(scores))
+
+
+def chosen(config, params, logits):
+    """The published choice on HANDED router logits (a list of float32
+    [tokens, E], one per layer): the top k of softmax(z). A list of 0/1
+    masks [tokens, E]."""
+    assert len(logits) == config["num_hidden_layers"], len(logits)
+    return [_top_k_mask(jax.nn.softmax(jnp.asarray(z, jnp.float32), axis=-1),
+                        config["num_experts_per_tok"]) for z in logits]
+
+
+def _routed_ffn(h, wr, wg, wu, wd, top_k, norm_topk_prob, chosen=None):
+    """As above; `chosen` [N, E] 0/1 takes the place of the top k of p."""
+    z = h @ wr
+    p = jax.nn.softmax(z, axis=-1)
+    if chosen is None:
+        chosen = _top_k_mask(jax.lax.stop_gradient(p), top_k)
+    gates = p * chosen
+    if norm_topk_prob:
+        gates = gates / gates.sum(-1, keepdims=True)
+
+    def add(y, expert):
+        return y + jax.checkpoint(_expert)(h, *expert), None
+
+    y, _ = jax.lax.scan(add, jnp.zeros_like(h), (wg, wu, wd, gates.T))
+    return y, z, p, chosen
+
+
+def _hidden(config, params, toks, choice):
+    """toks [B, T] -> (x [B*T, d] before the final norm, each layer's
+    router: its input h [B*T, d], its weight and its logits [B*T, E]; the
+    auxiliary cost)."""
+    _, heads, top_k, eps, theta, norm_topk = _sizes(config)
+    tok_emb, blocks, _, _ = _split(config, params)
+    B, T = toks.shape
+    x = jax.lax.map(lambda t: tok_emb[t], toks).reshape(B * T, -1)    # [N, d]
+    d = x.shape[-1]
+    routers, aux = [], 0.0
+    for (w_in, wq, wk, wv, w_qn, w_kn, wo, w_post, wr, wg, wu, wd) in blocks:
+        h = _rms(x, w_in, eps)
+        qkv = jnp.einsum("nd,sde->sne", h, jnp.stack([wq, wk, wv]))
+        qk = _rms(qkv[:2], jnp.stack([w_qn, w_kn])[:, None, :], eps)
+        qk = _rope(qk.reshape(2 * B, T, heads, d // heads), theta)
+        v = qkv[2].reshape(B, T, heads, d // heads)
+        q, k = qk[:B], qk[B:]
+        per_head = [t.transpose(0, 2, 1, 3).reshape(B * heads, T, d // heads)
+                    for t in (q, k, v)]
+        a = jax.lax.map(jax.checkpoint(_attend), tuple(per_head))
+        a = a.reshape(B, heads, T, d // heads).transpose(0, 2, 1, 3)
+        x = x + a.reshape(B * T, d) @ wo
+        h = _rms(x, w_post, eps)
+        y, z, p, picked = _routed_ffn(
+            h, wr, wg, wu, wd, top_k, norm_topk,
+            None if choice is None else choice[len(routers)])
+        x = x + y
+        routers.append((h, wr, z))
+        aux = aux + _aux(z, p, picked, config["aux_balance_weight"],
+                         config["aux_z_weight"])
+    return x, routers, aux / len(blocks)
+
+
+def hidden(config, params, toks, choice=None):
+    """As above; `choice[i]` is handed to the i-th layer."""
+    x, routers, aux = _hidden(config, params, toks, choice)
+    return x, [z for _, _, z in routers], aux
+
+
+def _cost_and_routers(config, params, feed, choice):
+    _, _, w_f, w_head = _split(config, params)
+    toks, labels = jnp.asarray(feed["toks"]), jnp.asarray(feed["labels"])
+    x, routers, aux = _hidden(config, params, toks, choice)
+    ce = _cross_entropy_sum(config, x, labels.reshape(-1, 1), w_f, w_head)
+    return ce / x.shape[0] + aux, routers
+
+
+def cost(config, params, feed, choice=None):
+    return _cost_and_routers(config, params, feed, choice)[0]
+
+
+def loss_grads_and_routers(config, params, feed, choice=None):
+    """(cost, gradients, each layer's router as this file computed it: input
+    [tokens, d], weight [d, E], logits [tokens, E]), one forward pass:
+    `loss_and_grads` with what the gates were scored from beside it."""
+    params = [jnp.asarray(p, jnp.float32) for p in params]
+    with jax.default_matmul_precision("highest"):
+        (cost_, routers), grads = jax.value_and_grad(
+            lambda ps: _cost_and_routers(config, ps, feed, choice),
+            has_aux=True)(params)
+    return cost_, grads, routers
+
+
+def loss_and_grads(config, params, feed, choice=None):
+    """As above; with `choice`, under the handed experts."""
+    return loss_grads_and_routers(config, params, feed, choice)[:2]
+
+
+def router_logits(config, params, feed, choice=None):
+    with jax.default_matmul_precision("highest"):
+        return hidden(config, params, jnp.asarray(feed["toks"]), choice)[1]

@@ -25,8 +25,11 @@ and `setup_s` are its own. A run goes:
     first step: its cost, a sample of every Adam first moment  -> kept (host)
     warm-up, the window; at its close memory_stats() is read   -> the record's
     the trainer's state dropped; startup again from --seed;
-    fingerprints compared; reference.py on those weights and
-    the first batch; costs and gradients compared              -> `correct`
+    fingerprints compared; a routed program's first step read
+    once more for its routers' logits (and startup a third
+    time); reference.py on those weights and the first batch,
+    under the choice of experts those logits give; costs,
+    gradients and the choice compared                          -> `correct`
 """
 
 from __future__ import annotations
@@ -199,100 +202,159 @@ def registry_delta(at_open, at_close):
 # `jax.numpy`, matmuls at the highest precision) is held to the system's
 # first step on the same weights and the same batch. What the first step
 # left is kept; the reference runs after the window, on weights made again
-# from the seed (`fingerprints` holds them to the first startup's):
-#  - the first cost: |difference| over max(1, |reference|). On the chip
-#    (PR 23, 29 runs, 6 seeds) at most 2.9e-6 for gpt2-small; the bound is
-#    seven times that. Weak alone: with fresh weights the cost sits within
-#    2e-3 of ln(vocabulary).
-#  - every parameter's gradient. Adam's first moment after the first step
-#    is (1 - beta1) x the gradient the step computed, so the gradients are
-#    read from the optimizer's state with no second program: per tensor,
-#    over a strided sample of at most GRAD_SAMPLE elements, rms(system -
-#    reference) over rms(reference), where a tensor whose reference
-#    gradient is (near) zero, such as a key bias, is held to a tenth of
-#    the median tensor's rms instead. A wrong backward pass or a wrong
-#    gradient hand-over to the optimizer reads near 1; bf16 AMP against
-#    float32 read at most 0.013, the median tensor 0.006 (gpt2-small on
-#    the chip, PR 23, 29 runs, 6 seeds); the bound is four times that.
-# The tiny models of the CPU rehearsal are looser in both: their cell's
-# `rehearsal` block carries its own tolerances, read only in a rehearsal.
+# from the seed (`fingerprints` holds them to the first startup's). Every
+# limit below has its two readings in PERF.md section 2.
+#  - The first cost: |difference| over max(1, |reference|), REFERENCE_TOL.
+#    Sound runs read at most 1.01e-5 (three configurations, 370 runs); the
+#    hybrid's published precision (a bf16 residual stream) reads 8.4e-5.
+#  - Every parameter's gradient, GRAD_TOL whatever the tensor. Adam's first
+#    moment after the first step is (1 - beta1) x the gradient the step
+#    computed, so the gradients are read from the optimizer's state: per
+#    tensor, over `_sample`'s elements, rms(system - reference) over
+#    rms(reference), the latter no smaller than a tenth of the median
+#    tensor's (a key bias's gradient is all but zero). bf16 AMP against
+#    float32 reads at most 0.026; a gradient missing or doubled reads 1, one
+#    halved 0.5, one handed to the wrong parameter 1.4.
+# The CPU rehearsal's tiny models are looser: a cell's `rehearsal` block
+# carries its own tolerances, read only in a rehearsal.
 #
-# Gradients behind a discrete choice. A routed layer sends each token to
-# the k experts its router scores highest, and rounding (bf16 AMP against
-# the float32 reference) turns a near tie between the k-th and the (k+1)-th
-# the other way. Such a token's share of the gradient then lands in another
-# expert: not a wrong backward pass, and not within 0.05. The experiment
-# (`tests/test_harness.py`, one OLMoE-shaped block in plain `jax.numpy`, 64
-# experts, top-8, bf16 matmul inputs against float32 at `highest`; CPU, PR
-# 26, 3 seeds x 2 routers) reads, for a share s of tokens whose expert set
-# differs (0.008-0.031), 0.035-0.077 on the expert stacks, the router and
-# the norm weight before them, where the dense tensors read 0.003-0.006, and
-# on the worst expert stack err^2 / s = 0.169-0.193 every time. That is the arithmetic: a flipped token takes one
-# of its k pair-contributions out and puts one in, 2 s N of the N k that
-# make up the gradient, each at about 0.84 of the mean gate weight (the
-# tie sits at the low end of the top k): err^2 = 0.84^2 x (2 / k) x s =
-# 0.176 s at k = 8. Which tokens flip cannot be read from outside the
-# program, but how many can is in the reference's own float32 router: bf16
-# rounding of both matmul inputs moves a logit by about 0.0023 of the rms
-# of a token's logits and a gap by 0.0032 of it, so s = the share of tokens
-# whose k-th gap is under 1.3 x 2^-9 of that rms (0.025, 0.039, 0.041 so
-# counted where 0.025, 0.031, 0.022 flipped with the router in bf16 too). The rule: a configuration names in
-# `config.json` (`routed_parameters`: `names`, fnmatch patterns on the
-# program's parameter names; `top_k`; `reason`) the parameters whose
-# gradient flows only through a top-k choice, and its `reference.py` gives
-# `router_logits(config, params, feed)` (a list of float32 [tokens,
-# experts], one per routed layer). For those parameters only,
-#     tolerance = min(ROUTED_CAP, sqrt(GRAD_TOL^2 + (2 / top_k) x share))
-# where `share` is the share of tokens whose gap between the k-th and the
-# (k+1)-th logit is under TIE_UNITS x 2^-9 x the rms of the token's centred
-# logits: three times the gap that flips, so about twice the error a sound
-# run reads (0.155-0.176 allowed where 0.038-0.077 was read). No near
-# ties, no allowance; no `router_logits`, share 0. The cap is this file's,
-# not the configuration's: a gradient that is missing or doubled reads 1,
-# one halved 0.5, one handed to the wrong parameter 1.4 (the test shows
-# each failing), so 0.2 leaves a factor 2.5 under the mildest fault. Every
-# parameter that is not named stays at GRAD_TOL, so lower precision in
-# attention, head or embedding still fails. The first cost's 2e-5 stays: a
-# flipped token moves the mean cost by 4e-6 to 5e-5 over 512 tokens in the
-# experiment and 6e-6 at T 256 and half widths (ISSUE 26); a cell's own
-# tokens a step are tens of thousands.
+# Tensors behind a discrete choice (PR 36). A routed layer sends each token
+# to the k experts its router scores highest, and bf16 rounding turns a near
+# tie between the k-th and the (k+1)-th the other way. A reference that
+# chose for itself then computed another function: a turned token's share
+# of the gradient lands in another expert, and where hidden states repeat
+# (the hybrid's traffic: each 16 times) the turned copies add with ONE
+# error vector. An allowance in quadrature over turned tokens (PRs 26-35)
+# failed one sound run in six and no control. So the reference is TOLD the
+# choice, and the choice is held on its own:
+#  1. After the window the first step is run once more (`_first_step_again`)
+#     on a second startup's weights and the reader's first batch, through
+#     the trainer's executor and Program, with every routed op's
+#     `RouterLogits` added to the trainer's own fetch list (a routed op is
+#     one that writes that output). Its forward is the timed step's (the two
+#     compiled programs differ in each router's own fusions only); its
+#     backward rounds differently, so cost and first moments are held to the
+#     timed step's at SECOND_COST_TOL and SECOND_GRAD_TOL (read: 3.4e-6 and
+#     0.025 at most; another batch reads 1.4), not to the bit. Fetching the
+#     routed ops' input too moved XLA's rounding of the whole forward
+#     (routed tensors then read 0.05-0.07): only the logits are asked for.
+#  2. `reference.py:chosen` applies the published top-k rule to those
+#     logits, and `loss_grads_and_routers(..., choice)` scores its own gates
+#     for the experts so chosen.
+#  3. `choice_numbers` holds the choice; each number is in `compared`:
+#     (a) `choice_counts_off_program`, limit 0: the pair counts an expert
+#         under the derived choice against the program's `TokensPerExpert`.
+#         The program gives out logits and counts, not indices: one that
+#         keeps k-1 experts, or chooses by a bias its logits do not report,
+#         differs here.
+#     (b) `router_weight_rounding_share`, ROUTER_TOL: the projection of
+#         (program's logits - reference's) on what rounding the router's
+#         WEIGHT to bf16 does to the logits: 0 for a float32 router (read:
+#         0.008 at most), 1 for one whose matmul takes bf16 inputs (read:
+#         0.995-1.007). The rms of the difference cannot tell the two: a
+#         sound deep layer is 0.19 % off by what its mixers rounded, with a
+#         bf16 router 0.30 %; the first routed block 0.078 % and 0.25 %.
+#     (c) `turned_rows_not_near_tie`, limit 0: rows whose derived set is not
+#         the reference's own top k, where the reference's gap between the
+#         best expert that left and the worst that came is TIE_UNITS x 2^-9
+#         x the rms of the row's centred logits or more. The handed choice
+#         may differ from the reference's own only where the reference all
+#         but ties (read: 4.9 units at most; eight rows of wrong router
+#         input 2 000), so a wrong router cannot lead the reference astray.
+#         It implies `turned_row_share` <= `near_tie_share`, printed too.
 REFERENCE_TOL = 2e-5
 GRAD_TOL = 0.05
 GRAD_SAMPLE = 65536
-ROUTED_CAP = 0.2
-TIE_UNITS = 4.0
+SECOND_COST_TOL = 2e-5
+SECOND_GRAD_TOL = 0.1
+ROUTER_TOL = 0.2
+TIE_UNITS = 8.0
 
 
-def near_tie_share(logits, top_k):
-    """Share of the rows of `logits` ([tokens, experts], float32) whose gap
-    between the `top_k`-th and the next largest is under TIE_UNITS x 2^-9
-    of the rms of the row's centred logits (see above)."""
+def _rms(x):
     import jax.numpy as jnp
 
-    z = -jnp.sort(-jnp.asarray(logits, jnp.float32), axis=-1)
-    gap = z[:, top_k - 1] - z[:, top_k]
-    scale = jnp.sqrt(jnp.mean(
-        jnp.square(z - z.mean(-1, keepdims=True)), axis=-1))
-    return jnp.mean(gap < TIE_UNITS * 2.0**-9 * scale)
+    return jnp.sqrt(jnp.mean(jnp.square(x)))
 
 
-def gradient_tolerances(names, config, grad_tol, share):
-    """{parameter: tolerance}: `grad_tol` for every parameter, and the
-    routed rule's for those `config["routed_parameters"]` names."""
-    import fnmatch
+def _row_scale(z):
+    """[N, E] -> [N]: the rms of each row's centred logits."""
+    import jax.numpy as jnp
 
-    routed = config.get("routed_parameters") or {}
-    allowed = min(max(ROUTED_CAP, grad_tol),
-                  math.sqrt(grad_tol**2 + 2.0 / routed["top_k"] * share)
-                  ) if routed else grad_tol
-    return {n: allowed if any(fnmatch.fnmatchcase(n, pat)
-                              for pat in routed.get("names", ()))
-            else grad_tol for n in names}
+    return jnp.sqrt(jnp.mean(jnp.square(z - z.mean(-1, keepdims=True)), -1))
+
+
+def near_tie_share(logits, own):
+    """Share of the rows of `logits` ([tokens, experts], float32) whose gap
+    between the least of the chosen (`own`, 0/1: the row's top k) and the
+    largest of the others is under TIE_UNITS x 2^-9 of the rms of the row's
+    centred logits (see above)."""
+    import jax.numpy as jnp
+
+    z = jnp.asarray(logits, jnp.float32)
+    gap = (jnp.min(jnp.where(own > 0, z, jnp.inf), -1)
+           - jnp.max(jnp.where(own > 0, -jnp.inf, z), -1))
+    return jnp.mean(gap < TIE_UNITS * 2.0**-9 * _row_scale(z))
+
+
+def turned_rows(z_ref, handed, own):
+    """(turned, largest gap): the number of rows in which the 0/1 masks
+    `handed` and `own` ([N, E]; `own` the top k of `z_ref`, the reference's
+    float32 logits) differ, and over those rows the largest gap in `z_ref`
+    between the best expert that left and the worst that came, in units of
+    2^-9 x the row's scale (0.0 where nothing turned)."""
+    import jax.numpy as jnp
+
+    left, came = (own > 0) & (handed == 0), (handed > 0) & (own == 0)
+    turned = jnp.any(left | came, axis=-1)
+    gap = (jnp.max(jnp.where(left, z_ref, -jnp.inf), -1)
+           - jnp.min(jnp.where(came, z_ref, jnp.inf), -1))
+    units = jnp.where(turned, gap / (2.0**-9 * _row_scale(z_ref)), 0.0)
+    return turned, units
+
+
+def choice_numbers(router, own, handed, z_prog, counts_prog):
+    """One routed layer's numbers of point 3 above, as a dict of scalars.
+    `router` is the reference's (input [N, d], weight [d, E], logits
+    [N, E]); `handed` and `own` are 0/1 [N, E]; `z_prog` [N, E] and
+    `counts_prog` [E] are the program's fetched logits and pair counts.
+    Beside those that are held: the share of rows turned, the largest turned
+    gap, the reference's near-tie share and the program's logits against
+    the reference's."""
+    import jax
+    import jax.numpy as jnp
+
+    h, w, z_ref = router
+    # what rounding the router's weight to bf16 does to the logits, and how
+    # much of that is in the program's: 0 for a float32 router, 1 for one
+    # whose matmul takes bf16 inputs
+    # (`reduce_precision`: a cast to bf16 and back is a round trip that XLA
+    # is free to drop, and on the TPU does)
+    rounding = jnp.dot(h, jax.lax.reduce_precision(w, 8, 7) - w,
+                       precision=jax.lax.Precision.HIGHEST)
+    turned, units = turned_rows(z_ref, handed, own)
+    return {
+        "counts_off_program": jnp.sum(jnp.abs(
+            handed.sum(0).astype(jnp.int32) - counts_prog.astype(jnp.int32))),
+        "weight_rounding_share": jnp.sum((z_prog - z_ref) * rounding)
+        / jnp.sum(jnp.square(rounding)),
+        "turned_not_near_tie": jnp.sum(units >= TIE_UNITS),
+        "turned_share": jnp.mean(turned),
+        "turned_gap_units_max": jnp.max(units),
+        "near_tie_share": near_tie_share(z_ref, own),
+        "logits_off_reference": _rms(z_prog - z_ref) / _rms(z_ref)}
 
 
 def _sample(x):
+    """At most GRAD_SAMPLE elements of `x`, flattened, at one stride: the
+    smallest that keeps the count and shares no factor with the last
+    dimension, so that every column is read (ceil(size / 65 536) alone is 6
+    on a [2688, 128] router: its even columns only, 4 of 8 held experts)."""
     flat = x.reshape(-1)
-    return flat[::-(-flat.size // GRAD_SAMPLE)]
+    stride = -(-flat.size // GRAD_SAMPLE)
+    while math.gcd(stride, x.shape[-1] if x.ndim else 1) != 1:
+        stride += 1
+    return flat[::stride]
 
 
 def _parameters(trainer, scope):
@@ -315,7 +377,7 @@ def fingerprints(params):
         jax.jit(lambda ps: [one(x) for x in ps])(params))]
 
 
-def _first_moments(trainer):
+def _first_moments(trainer, scope=None):
     """Right after the first step: a sample of every Adam first moment,
     (1 - beta1) x the gradient that step computed, taken to the host (a few
     MB), so that nothing of the check lies on the chip through the window.
@@ -323,6 +385,7 @@ def _first_moments(trainer):
     are not compared."""
     import jax
 
+    scope = trainer.scope if scope is None else scope
     moment = {}
     for block in trainer.main_program.blocks:
         for op in block.ops:
@@ -332,54 +395,145 @@ def _first_moments(trainer):
     names = [p.name for p in trainer.main_program.parameters()
              if p.name in moment]
     samples = jax.device_get(jax.jit(lambda ms: [_sample(m) for m in ms])(
-        [trainer.scope.get(moment[n][0]) for n in names]))
+        [scope.get(moment[n][0]) for n in names]))
     return {n: (m, moment[n][1]) for n, m in zip(names, samples)}
 
 
-def _after_the_window(ctx, trainer, model, at_startup, moments):
+def routed_layers(program):
+    """The Program's routed ops, in order: every op that writes a
+    `RouterLogits` output, by the names of its logits and its per-expert
+    pair counts."""
+    return [{"logits": op.outputs["RouterLogits"][0],
+             "counts": op.outputs["TokensPerExpert"][0]}
+            for block in program.blocks for op in block.ops
+            if "RouterLogits" in op.outputs]
+
+
+def _first_step_again(trainer, model, scope, layers):
+    """The training step, once more, as the trainer ran the timed first one:
+    the same program through the same executor on a fresh startup's `scope`
+    and the reader's first batch, with each routed layer's logits fetched
+    beside what the trainer fetches. Returns (cost, sampled first moments,
+    [(logits, counts) per layer], still on the device). The step donates
+    and rebinds the scope's parameters: the caller drops the scope."""
+    batch = next(iter(model["reader"]()))
+    if model["feed_order"] is not None:
+        from paddle_tpu.data.feeder import DataFeeder
+
+        batch = DataFeeder(model["feed_order"]).feed(batch)
+    program = trainer.main_program
+    var = program.global_block().var
+    # the trainer's own fetch list (`Trainer._train`: the cost, then what the
+    # layers registered to be counted every step), and behind it only what
+    # the window's program does not give out already
+    names = [s["var"] for s in getattr(program, "step_statistics", ())]
+    names += [layer[k] for layer in layers for k in ("logits", "counts")
+              if layer[k] not in names]
+    outs = trainer.exe.run(
+        program, feed=batch, scope=scope, as_numpy=False,
+        fetch_list=[trainer.cost] + [var(n) for n in names])
+    got = dict(zip(names, outs[1:]))
+    fetched = [(got[layer["logits"]], got[layer["counts"]])
+               for layer in layers]
+    return float(outs[0]), _first_moments(trainer, scope), fetched
+
+
+def _startup_again(trainer, at_startup):
+    """(scope, names of the parameters whose fingerprint is not the first
+    startup's): the startup program run from the seed into a fresh scope."""
+    from paddle_tpu import Scope
+
+    scope = Scope()
+    trainer.exe.run_startup(trainer.startup_program, scope=scope)
+    names = [p.name for p in trainer.main_program.parameters()]
+    return scope, [n for n, a, b in zip(
+        names, at_startup, fingerprints(_parameters(trainer, scope))) if a != b]
+
+
+def _own_compile_cache(path):
+    """From here on the process builds the yardstick's programs only (the
+    second reading's step program, the reference, the comparisons): they are
+    kept in a persistent compile cache of their own, `path`, fixed inside the
+    checkout. In the program's cache the hybrid's two (48 + 15 MiB) pushed
+    the cell's step program out wherever parent and change share one cache
+    under a cap (the chip tool's machines: 192 MiB), and `setup_s` paid a
+    cold compile in every run (PERF.md section 6, PR 36)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", path)
+
+
+def _after_the_window(ctx, trainer, model, at_startup, first_cost, moments):
     """The yardstick's turn, once the books are read and the profiler has
     stopped: the trained state is dropped, startup runs again from the seed
-    and is held to the first startup's fingerprints, and the plain reference
-    gives its cost and a sample of each parameter's gradient on those weights
-    and the first batch (the reader is a function of the seed). Returns what
-    `correct` compares."""
+    and is held to the first startup's fingerprints, a routed program's
+    first step is read once more for its choice of experts (and startup run
+    a third time, since the step consumed the second's weights), and the
+    plain reference gives its cost and a sample of each parameter's gradient
+    on those weights and the first batch (the reader is a function of the
+    seed), under that choice. Returns what `correct` compares."""
     import os
 
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu import Scope
-
     t_begin = time.time()
+    _own_compile_cache(ctx.yardstick_cache_dir)
     trainer.scope.vars.clear()    # parameters and optimizer state, trained
-    scope = Scope()
-    trainer.exe.run_startup(trainer.startup_program, scope=scope)
-    params = _parameters(trainer, scope)
-    del scope                     # Adam's fresh moments go, the weights stay
-    names = [p.name for p in trainer.main_program.parameters()]
-    differ = [n for n, a, b in zip(names, at_startup, fingerprints(params))
-              if a != b]
-    t_startup = time.time()
-
+    scope, differ = _startup_again(trainer, at_startup)
+    out = {"choice": None, "second_reading": None}
+    layers = routed_layers(trainer.main_program)
     ref = ctx.load_module(
         os.path.join(os.path.dirname(ctx.model.__file__), "reference.py"))
+    t_startup = t_again = time.time()
+    if layers:
+        if not hasattr(ref, "chosen"):
+            raise SystemExit(
+                "chipbench: the program has routed layers (ops that write "
+                "`RouterLogits`), so its reference.py has to give `chosen` "
+                "and `loss_grads_and_routers(..., choice)` (README.md)")
+        cost2, moments2, fetched = _first_step_again(
+            trainer, model, scope, layers)
+        both = [n for n in moments if n in moments2]
+        off = jax.jit(relative_errors)([moments2[n][0] for n in both],
+                                       [moments[n][0] for n in both])
+        out["second_reading"] = {
+            "cost_off_timed": abs(cost2 - first_cost) / max(1.0, abs(first_cost)),
+            "moments_off_timed": dict(zip(both, (float(e) for e in off))),
+            "moments_differing_in_a_bit": sum(
+                not (moments[n][0] == moments2[n][0]).all() for n in both)}
+        del scope                 # the step's new state goes
+        scope, again = _startup_again(trainer, at_startup)
+        differ = sorted(set(differ) | set(again))
+        t_again = time.time()
+    names = [p.name for p in trainer.main_program.parameters()]
+    params = _parameters(trainer, scope)
+    del scope                     # Adam's fresh moments go, the weights stay
     first = ref.prepare(next(iter(model["reader"]())))
 
-    def cost_and_sampled_grads(params, first):
-        cost, grads = ref.loss_and_grads(ctx.config, params, first)
-        return cost, [_sample(g) for g in grads]
+    def reference(params, first, fetched):
+        if not layers:
+            cost, grads = ref.loss_and_grads(ctx.config, params, first)
+            return cost, [_sample(g) for g in grads], []
+        handed = ref.chosen(ctx.config, params, [z for z, _ in fetched])
+        cost, grads, routers = ref.loss_grads_and_routers(
+            ctx.config, params, first, handed)
+        own = ref.chosen(ctx.config, params, [z for _, _, z in routers])
+        numbers = [choice_numbers(router, mine, theirs,
+                                  z.astype(jnp.float32), counts)
+                   for router, mine, theirs, (z, counts) in zip(
+                       routers, own, handed, fetched)]
+        return cost, [_sample(g) for g in grads], numbers
 
-    cost, grads = jax.jit(cost_and_sampled_grads)(params, first)
-    out = {"reference_first_cost": float(cost), "near_tie_share": 0.0,
-           "startup_differs": differ, "gradient_errors": None}
-    routed = ctx.config.get("routed_parameters")
-    if routed and hasattr(ref, "router_logits"):
-        def share(params, first):
-            layers = ref.router_logits(ctx.config, params, first)
-            return sum(near_tie_share(z, routed["top_k"])
-                       for z in layers) / len(layers)
-
-        out["near_tie_share"] = float(jax.jit(share)(params, first))
+    cost, grads, numbers = jax.jit(reference)(
+        params, first, fetched if layers else [])
+    out.update(reference_first_cost=float(cost), startup_differs=differ,
+               gradient_errors=None)
+    if layers:
+        out["choice"] = [{k: float(v) for k, v in layer.items()}
+                         for layer in jax.device_get(numbers)]
     if moments:
         refs = [g for n, g in zip(names, grads) if n in moments]
         scales = [s for _, s in moments.values()]
@@ -391,7 +545,8 @@ def _after_the_window(ctx, trainer, model, at_startup, moments):
         errs = jax.jit(errors)([m for m, _ in moments.values()], refs)
         out["gradient_errors"] = dict(zip(moments, (float(e) for e in errs)))
     out["after_window_s"] = {"second_startup": t_startup - t_begin,
-                             "reference": time.time() - t_startup}
+                             "first_step_again": t_again - t_startup,
+                             "reference": time.time() - t_again}
     return out
 
 
@@ -400,11 +555,8 @@ def relative_errors(grads, refs):
     latter no smaller than a tenth of the median tensor's."""
     import jax.numpy as jnp
 
-    def rms(x):
-        return jnp.sqrt(jnp.mean(jnp.square(x)))
-
-    ref_rms = jnp.stack([rms(g) for g in refs])
-    diff = jnp.stack([rms(g - r) for g, r in zip(grads, refs)])
+    ref_rms = jnp.stack([_rms(g) for g in refs])
+    diff = jnp.stack([_rms(g - r) for g, r in zip(grads, refs)])
     return diff / jnp.maximum(ref_rms, 0.1 * jnp.median(ref_rms))
 
 
@@ -472,7 +624,8 @@ def run(ctx):
                    "startup": startup_done - ctx.t_chip,
                    "step_program_compile_or_cache_read": step_programs,
                    "warmup": win.t0_wall - startup_done - step_programs}
-    check = _after_the_window(ctx, trainer, model, at_startup, win.moments)
+    check = _after_the_window(ctx, trainer, model, at_startup,
+                              win.first_cost, win.moments)
     return {
         **check,
         "memory_stats": win.memory_stats, "setup_split_s": setup_split,
@@ -504,9 +657,13 @@ def info(run):
             "reference_first_cost": run["reference_first_cost"],
             "gradient_error_worst": worst and [worst, errs[worst]],
             "gradient_error_median": errs and sorted(errs.values())[len(errs) // 2],
-            "near_tie_share": run["near_tie_share"],
-            "gradient_tolerance_max": max(_tolerances(run).values(),
-                                          default=None),
+            "gradient_errors_largest": errs and sorted(
+                errs.items(), key=lambda kv: -kv[1])[:4],
+            "choice_by_layer": run.get("choice"),
+            "second_reading": run.get("second_reading") and dict(
+                run["second_reading"], moments_off_timed=sorted(
+                    run["second_reading"]["moments_off_timed"].items(),
+                    key=lambda kv: -kv[1])[:4]),
             "startup_differs": run["startup_differs"],
             "setup_split_s": run["setup_split_s"],
             "after_window_s": run["after_window_s"],
@@ -514,19 +671,14 @@ def info(run):
             "peak_final": run.get("memory_peaks")}
 
 
-def _tolerances(run):
-    return gradient_tolerances(run["gradient_errors"] or (), run["config"],
-                               run["tolerances"]["grad_tol"],
-                               run["near_tie_share"])
-
-
 def compared(run):
     """{name: [number, limit]}: every number `correct` holds to a limit, for
-    the run's last lines. The gradient is the tensor nearest its own limit."""
+    the run's last lines. The gradient is the worst tensor's (every tensor
+    has the one limit); a routed program adds the second reading's tie to
+    the timed step and the numbers that hold the choice, each the worst
+    layer's."""
     want, tol = run["reference_first_cost"], run["tolerances"]
-    allowed = _tolerances(run)
     errs = run["gradient_errors"] or {}
-    worst = max(errs, key=lambda n: errs[n] / allowed[n], default=None)
     out = {"startup_tensors_differing": [len(run["startup_differs"]), 0],
            "cost_reads_not_finite": [run["bad_intervals"], 0],
            "last_cost_over_first": [run["costs"][-1] / run["first_cost"], 1.0],
@@ -535,14 +687,59 @@ def compared(run):
                tol["reference_tol"]],
            "programs_built_in_window": [run["counters"]["programs_built"], 0],
            "cache_misses_in_window": [run["counters"]["cache_misses"], 0]}
-    if worst is not None:
-        out["gradient_error_nearest_limit"] = [errs[worst], allowed[worst]]
+    if errs:
+        out["gradient_error_nearest_limit"] = [max(errs.values()),
+                                               tol["grad_tol"]]
+    second, choice = run.get("second_reading"), run.get("choice")
+    if second:
+        out["second_reading_cost_off_timed"] = [
+            second["cost_off_timed"], SECOND_COST_TOL]
+        out["second_reading_moments_off_timed"] = [
+            max(second["moments_off_timed"].values()), SECOND_GRAD_TOL]
+    if choice:
+        def worst(key):
+            return max(abs(layer[key]) for layer in choice)
+
+        out["choice_counts_off_program"] = [worst("counts_off_program"), 0]
+        out["router_weight_rounding_share"] = [
+            worst("weight_rounding_share"), ROUTER_TOL]
+        out["turned_rows_not_near_tie"] = [worst("turned_not_near_tie"), 0]
+        share = max(choice, key=lambda layer: layer["turned_share"]
+                    - layer["near_tie_share"])
+        out["turned_row_share"] = [share["turned_share"],
+                                   share["near_tie_share"]]
     return out
+
+
+# what `correct` says of a compared number that is over its limit
+_SAYS = {
+    "first_cost_off_reference":
+        "the first cost is off the plain reference's",
+    "second_reading_cost_off_timed":
+        "the first step read again for its choice of experts gave another "
+        "cost than the timed first step",
+    "second_reading_moments_off_timed":
+        "the first step read again gave other first moments than the timed "
+        "first step: the worst tensor's",
+    "choice_counts_off_program":
+        "the published top-k rule on the program's own router logits gives "
+        "other pair counts an expert than the program's TokensPerExpert",
+    "router_weight_rounding_share":
+        "the program's router logits hold this share of what rounding the "
+        "router's weight to bf16 does to them",
+    "turned_rows_not_near_tie":
+        "rows chose other experts than the reference's own router where the "
+        "reference is not near a tie",
+    "turned_row_share":
+        "more rows chose other experts than the reference's router has near "
+        "ties",
+}
 
 
 def correct(run):
     """What a train cell owes: finite costs, a loss that fell, a first step
-    that agrees with the plain reference on weights the seed gives again,
+    that agrees with the plain reference on weights the seed gives again
+    (under the program's own choice of experts, itself held: see above),
     and nothing built inside the window. Returns a list of what failed
     (empty = ok)."""
     bad = []
@@ -557,16 +754,16 @@ def correct(run):
     elif not costs[-1] < run["first_cost"]:
         bad.append(f"the loss did not fall: first {run['first_cost']}, "
                    f"last {costs[-1]}")
-    off, limit = compared(run)["first_cost_off_reference"]
-    if not off <= limit:
-        bad.append(f"the first cost {run['first_cost']} is off the plain "
-                   f"reference's {run['reference_first_cost']} by {off} "
-                   f"(> {limit})")
-    allowed = _tolerances(run)
+    numbers = compared(run)
+    for name, says in _SAYS.items():
+        value, limit = numbers.get(name, (0, 0))
+        if not value <= limit:
+            bad.append(f"{says}: {name} {value} (> {limit})")
+    limit = run["tolerances"]["grad_tol"]
     for name, err in (run["gradient_errors"] or {}).items():
-        if not err <= allowed[name]:
+        if not err <= limit:
             bad.append(f"the first step's gradient of {name} is off the plain "
-                       f"reference's by {err} of its rms (> {allowed[name]})")
+                       f"reference's by {err} of its rms (> {limit})")
     if run["counters"]["programs_built"] or run["counters"]["cache_misses"]:
         bad.append(f"programs were built inside the window: {run['counters']}")
     return bad

@@ -213,8 +213,13 @@ def main() -> int:
         print(f"chipbench: cell {entry['name']} needs {cell['chips']} TPU "
               f"chip(s); JAX reports {device}", file=sys.stderr)
         return 3
+    # the yardstick's own programs (a driver builds them once its window has
+    # closed) are kept apart, at a fixed path in the checkout, so that they
+    # take no room from the program's
+    yardstick_cache_dir = os.path.join(ROOT, ".chipbench_cache")
     log(f"cell {entry['name']} seed {args.seed} seconds {seconds} trace "
-        f"{args.trace} on {device}; compile cache {cache_dir}")
+        f"{args.trace} on {device}; compile cache {cache_dir}, the "
+        f"yardstick's {yardstick_cache_dir}")
 
     def memory_stats():
         return [d.memory_stats() or {} for d in devs[:cell["chips"]]]
@@ -224,6 +229,7 @@ def main() -> int:
               seconds=seconds, trace=bool(args.trace),
               rehearsal=args.rehearse_cpu, t_start=_T_START, t_chip=t_chip,
               clock=CompileClock(), memory_stats=memory_stats,
+              yardstick_cache_dir=yardstick_cache_dir,
               memory_peaks=lambda: memory_peaks(memory_stats()),
               load_module=load_module,
               model=load_module(os.path.join(config_dir, "model.py")))
