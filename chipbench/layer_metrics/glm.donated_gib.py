@@ -1,0 +1,15 @@
+"""glm.donated_gib: `step.donated_gib` on the glm-4.7-flash cells, under a name of its own. That
+reader's manifest entry lists the cells of the configurations that were
+there, and a `model_config` PR may not edit an entry that is there (PERF.md
+section 7): this file only loads `step.donated_gib.py` by path and returns what its
+`compute(run)` returns, so the shared code (see that file's docstring for
+what is measured) is seen on this configuration too. A later `benchmark` PR
+that drops the `workloads` list of `step.donated_gib` retires this file."""
+
+from chipbench.readers import load_reader
+
+WRAPS = "step.donated_gib"
+
+
+def compute(run):
+    return load_reader(WRAPS).compute(run)

@@ -20,7 +20,7 @@ from ..param_attr import ParamAttr
 from .helper import LayerHelper
 
 __all__ = ["attention_gru_decoder", "attention_gru_beam_search",
-           "multi_head_attention"]
+           "multi_head_attention", "latent_attention"]
 
 
 def multi_head_attention(
@@ -115,6 +115,93 @@ def multi_head_attention(
     return fc(out, size=E, num_flatten_dims=2,
               param_attr=_derive(param_attr, "wo"),
               bias_attr=_derive(bias_attr, "wo_b"))
+
+
+def latent_attention(
+    x,
+    num_heads: int,
+    q_rank: int,
+    kv_rank: int,
+    nope_dim: int,
+    rope_dim: int,
+    v_dim: int,
+    rotary_theta: float = 10000.0,
+    rms_eps: float = 1e-5,
+    param_attr=None,
+    name=None,
+):
+    """Causal multi-head LATENT self-attention (DeepSeek-V2's MLA, as
+    `transformers`' `deepseek_v3` / `glm4_moe_lite` attention computes it in
+    training) over a dense [B, T, E] input, in the EXPANDED form: keys and
+    values are materialised per head and the packed flash kernels run at a
+    head of `nope_dim + rope_dim` lanes. No biases.
+
+        c_q  = rms(x Wq_a)                 Wq_a  [E, q_rank]
+        q    = c_q Wq_b                    Wq_b  [q_rank, H x (nope + rope)]
+        [c_kv | k_r] = x Wkv_a             Wkv_a [E, kv_rank + rope]
+        [k_n | v]    = rms(c_kv) Wkv_b     Wkv_b [kv_rank, H x (nope + Dv)]
+        q_r, k_r <- rotary(theta) over their `rope` lanes; k_r is ONE head,
+        not normed, laid beside every head's k_n: k_h = [k_n_h | k_r]
+        out  = attention(q, k, v) Wo       Wo    [H x Dv, E]
+
+    scores q k^T / sqrt(nope + rope), causal. The kernels take one head
+    size for Q, K and V, so `v_dim` has to equal `nope_dim + rope_dim` (256
+    = 192 + 64 at GLM-4.7-Flash; a narrower V would be padded by a caller
+    that needs it). Built from `fc`, `rms_norm`, `rotary_embedding(...,
+    rotary_dim=)`, the op `latent_kv_expand` and the `flash_attention` op,
+    so AMP and sharding apply as everywhere else; each part is an op of its
+    own scope (`mul` x 5, `rms_norm` x 2, `rotary_embedding` x 2, `split`,
+    `latent_kv_expand`, `flash_attention`). The absorbed decode form and a
+    cache that stores the latent are not built (ROADMAP Queue 2 A4).
+    param_attr may be a mapping {"wq_a" | "wq_b" | "wkv_a" | "wkv_b" | "wo":
+    attr} (`ParamAttr.derive`). Parameters, in order: wq_a, q_norm, wq_b,
+    wkv_a, kv_norm, wkv_b, wo."""
+    from .nn import fc, rms_norm, rotary_embedding, split
+
+    helper = LayerHelper("latent_attention", name=name)
+    E = int(x.shape[-1])
+    D = int(nope_dim) + int(rope_dim)
+    if int(v_dim) != D:
+        raise ValueError(f"v_dim {v_dim} must equal nope_dim + rope_dim = {D}:"
+                         f" the attention kernels take one head size")
+
+    def _derive(s):
+        return ParamAttr.derive(param_attr, helper.name, s)
+
+    def proj(inp, s, size):
+        return fc(inp, size=size, num_flatten_dims=2, param_attr=_derive(s),
+                  bias_attr=False)
+
+    def norm(inp, s):
+        # scales start at one whatever initialiser the caller gave the
+        # projections: only the derived name is taken over
+        return rms_norm(inp, epsilon=rms_eps, name=f"{helper.name}.{s}",
+                        param_attr=_derive(s).name)
+
+    q = proj(norm(proj(x, "wq_a", int(q_rank)), "q_norm"), "wq_b",
+             num_heads * D)
+    q = rotary_embedding(q, num_heads, rotary_theta, rotary_dim=rope_dim)
+    c_kv, k_rope = split(proj(x, "wkv_a", int(kv_rank) + int(rope_dim)),
+                         [int(kv_rank), int(rope_dim)], dim=2)
+    kv = proj(norm(c_kv, "kv_norm"), "wkv_b", num_heads * (int(nope_dim) + D))
+    k_rope = rotary_embedding(k_rope, 1, rotary_theta)
+    packed = tuple(x.shape[:-1]) + (num_heads * D,)    # Q, K, V and the output
+    k = helper.create_tmp_variable(kv.dtype, packed)
+    v = helper.create_tmp_variable(kv.dtype, packed)
+    helper.append_op(
+        type="latent_kv_expand",
+        inputs={"KV": [kv], "KRope": [k_rope]},
+        outputs={"K": [k], "V": [v]},
+        attrs={"num_heads": num_heads, "nope_dim": int(nope_dim)},
+    )
+    out = helper.create_tmp_variable(x.dtype, packed)
+    helper.append_op(
+        type="flash_attention",
+        inputs={"Q": [q], "K": [k], "V": [v]},
+        outputs={"Out": [out]},
+        attrs={"num_heads": num_heads, "causal": True},
+    )
+    return proj(out, "wo", E)
 
 
 def _decoder_params(helper, ctx_dim, emb_dim, hidden, att_size):

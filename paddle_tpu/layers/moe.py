@@ -3,7 +3,8 @@ auxiliary costs (ops/moe_ops.py). Beyond the 2017 reference's layer set;
 the feed-forward of OLMoE / Mixtral / DeepSeek / Nemotron-H-style models.
 By arguments: a softmax or a sigmoid router (`scoring`, `router_bias`,
 `gate_scale`), SwiGLU or relu^2 experts (`expert_act`), all experts or one
-chip's share of them (`held_experts`), a shared expert (`shared_expert_dim`).
+chip's share of them (`held_experts`), a shared expert of the same kind
+(`shared_expert_dim`).
 """
 
 from __future__ import annotations
@@ -45,8 +46,10 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
     chose one of them are computed, and a pair that chose an absent expert
     adds nothing (its chip would add it; no code stands in for that chip or
     the exchange). The shares of a layer add up to the whole layer.
-    shared_expert_dim f_s > 0: + relu(x Wu_s)^2 Wd_s for every token
-    (`<name>.shared_up` [d, f_s], `.shared_down`).
+    shared_expert_dim f_s > 0: a shared expert for every token, of the
+    routed experts' kind (`expert_act`): "relu2" + relu(x Wu_s)^2 Wd_s
+    (`<name>.shared_up` [d, f_s], `.shared_down`); "swiglu" + (silu(x Wg_s)
+    * (x Wu_s)) Wd_s, with `<name>.shared_gate` [d, f_s] in front of them.
     param_attr may be a mapping {"router" | "up" | "down" | ...: attr}
     (`ParamAttr.derive`).
 
@@ -85,6 +88,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
             ParamAttr(name=f"{helper.name}.router_bias", trainable=False),
             (E,), default_initializer=ConstantInitializer(0.0))]
     if shared_expert_dim:
+        if expert_act == "swiglu":
+            inputs["SharedGateW"] = [param(
+                "shared_gate", (d, int(shared_expert_dim)))]
         inputs["SharedUpW"] = [param("shared_up", (d, int(shared_expert_dim)))]
         inputs["SharedDownW"] = [param(
             "shared_down", (int(shared_expert_dim), d))]

@@ -530,17 +530,25 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, num_heads: int, theta: float = 10000.0, name=None):
+def rotary_embedding(x, num_heads: int, theta: float = 10000.0, name=None,
+                     rotary_dim: Optional[int] = None):
     """Rotary position embedding (Su et al. 2021, rotate-half convention)
     on a packed multi-head projection [B, T, E]: position t rotates each
-    head's (i, i + D/2) lane pair by t * theta^(-2i/D). No parameters."""
+    head's (i, i + D/2) lane pair by t * theta^(-2i/D). No parameters.
+    rotary_dim R: only the last R lanes of each head turn (pairs (i, i +
+    R/2) of those R, frequencies theta^(-2i/R)) and the D - R in front pass
+    through: latent attention's `[nope | rope]` head. None: the whole head,
+    and the op appended is the one it always was."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_tmp_variable(x.dtype, x.shape)
+    attrs = {"num_heads": num_heads, "theta": float(theta)}
+    if rotary_dim is not None:
+        attrs["rotary_dim"] = int(rotary_dim)
     helper.append_op(
         type="rotary_embedding",
         inputs={"X": [x]},
         outputs={"Out": [out]},
-        attrs={"num_heads": num_heads, "theta": float(theta)},
+        attrs=attrs,
     )
     return out
 
@@ -779,10 +787,15 @@ def split(x, num_or_sections, dim=0):
     if isinstance(num_or_sections, int):
         n = num_or_sections
         attrs = {"num": n, "axis": dim}
+        sizes = [x.shape[dim] // n if x.shape[dim] != -1 else -1] * n
     else:
         n = len(num_or_sections)
         attrs = {"sections": list(num_or_sections), "axis": dim}
-    outs = [helper.create_tmp_variable(x.dtype, x.shape) for _ in range(n)]
+        sizes = list(num_or_sections)
+    ax = dim if dim >= 0 else len(x.shape) + dim
+    outs = [helper.create_tmp_variable(
+        x.dtype, tuple(x.shape[:ax]) + (size,) + tuple(x.shape[ax + 1:]))
+        for size in sizes]
     helper.append_op(type="split", inputs={"X": [x]}, outputs={"Out": outs}, attrs=attrs)
     return outs
 

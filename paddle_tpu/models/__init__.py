@@ -14,3 +14,4 @@ from .text import lstm_benchmark_net, stacked_lstm_net, word2vec_net  # noqa: F4
 from .transformer import transformer_lm  # noqa: F401
 from .olmoe import olmoe_lm  # noqa: F401
 from .nemotron_h import nemotron_h_lm  # noqa: F401
+from .glm_moe import glm_moe_lm  # noqa: F401

@@ -75,7 +75,8 @@ def _shapes_flash_ok(q, k) -> bool:
     """Backend-independent shape rules (separately testable): 128-aligned
     q AND kv sequence lengths (the kernels' blocks divide them), a head dim
     a lane block holds whole (two heads at 64, one at 128, one over two lane
-    tiles at 256), and heads x D a whole number of lane blocks (an odd head
+    tiles at 256: latent attention's 192 + 64, 20 heads at T 8192 on the chip
+    since PR 37), and heads x D a whole number of lane blocks (an odd head
     count at D 64 leaves half a block: XLA keeps it). Fewer K/V heads than Q
     heads: the index maps share one K/V lane block among a group of query
     heads, so a lane block has to be one head (D 128 or 256; at D 64
@@ -703,3 +704,45 @@ def flash_attention_kernel(ctx):
     split = lambda x: x.reshape(B, x.shape[1], -1, D)  # noqa: E731
     o = flash_attention(split(q), split(k), split(v), causal=causal)
     ctx.set_output("Out", o.reshape(B, T, E))
+
+
+_LATENT_COUNTER = "pt_latent_attention_dispatch_total"
+_LATENT_HELP = ("latent-attention layers traced, by the form their keys and "
+                "values take (expanded: K and V materialised per head, the "
+                "training form)")
+
+
+def expand_latent_kv(kv, k_rope, heads: int, nope_dim: int):
+    """Latent attention's keys and values in the EXPANDED form, packed for
+    the kernels above. kv [B, T, heads x (nope_dim + Dv)]: per head the
+    up-projected `[k_nope | v]`; k_rope [B, T, R]: ONE rotary key, already
+    turned, that every head shares. Returns K [B, T, heads x (nope_dim + R)]
+    with head h = `[k_nope_h | k_rope]`, and V [B, T, heads x Dv]. The
+    gradient of `k_rope` is the sum over the heads (autodiff's)."""
+    B, T, _ = kv.shape
+    per_head = kv.reshape(B, T, heads, -1)
+    with jax.named_scope("assemble_k"):
+        rope = jnp.broadcast_to(k_rope[:, :, None, :],
+                                (B, T, heads, k_rope.shape[-1]))
+        k = jnp.concatenate(
+            [per_head[..., :nope_dim], rope.astype(kv.dtype)], axis=-1)
+    with jax.named_scope("split_v"):
+        v = per_head[..., nope_dim:]
+    return k.reshape(B, T, -1), v.reshape(B, T, -1)
+
+
+@register_op("latent_kv_expand")
+def latent_kv_expand_kernel(ctx):
+    """Program-IR face of `expand_latent_kv`: KV, KRope -> K, V (attrs
+    num_heads, nope_dim). Sits between the latent's up-projection and the
+    `flash_attention` op of `layers.latent_attention`; one increment of
+    `pt_latent_attention_dispatch_total{path="expanded"}` an op traced."""
+    from ..obs import metrics
+
+    kv, k_rope = ctx.input("KV"), ctx.input("KRope")
+    metrics.registry().counter_inc(_LATENT_COUNTER, help=_LATENT_HELP,
+                                   labels={"path": "expanded"})
+    k, v = expand_latent_kv(kv, k_rope, int(ctx.attr("num_heads")),
+                            int(ctx.attr("nope_dim")))
+    ctx.set_output("K", k)
+    ctx.set_output("V", v)

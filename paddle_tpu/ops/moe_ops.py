@@ -1,10 +1,10 @@
 """Routed experts: a dropless top-k mixture of experts, and the grouped
 matmul under it. By arguments: a softmax router (OLMoE, Mixtral) or a
 sigmoid one with a choice bias and a gate scale (DeepSeek-V3's, Nemotron-
-H's); SwiGLU experts (three stacks) or relu^2 ones (two stacks, no gate);
-all experts on the chip or `held`, a contiguous share of them (the others
-live on other chips: pairs that chose them add nothing here); and a shared
-expert beside the routed ones.
+H's, GLM-4.7-Flash's); SwiGLU experts (three stacks) or relu^2 ones (two
+stacks, no gate); all experts on the chip or `held`, a contiguous share of
+them (the others live on other chips: pairs that chose them add nothing
+here); and a shared expert of the same kind beside the routed ones.
 
 Reference lineage: the 2017 reference has no routed layer (its nearest
 kin is the MixedLayer's per-input projections); this is the sparse-expert
@@ -219,7 +219,9 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     nothing. None: all E are here, and the ops are those of a layer that
     knows no shares.
 
-    `shared` (up [d, f_s], down [f_s, d]): + relu(x up)^2 down, every token.
+    `shared`: a shared expert, every token, of the routed experts' kind:
+    (up [d, f_s], down [f_s, d]) beside relu^2 experts: + relu(x up)^2 down;
+    (gate, up, down) beside SwiGLU experts: + (silu(x gate) * (x up)) down.
 
     The expert matmuls run, and `out` is returned, in the expert weights'
     dtype (the amp dtype where the caller cast them); the router reads x as
@@ -282,10 +284,18 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     ups = (up_w,) if gate_w is None else (gate_w, up_w)
     out = (jax.checkpoint(routed) if part else routed)(x, gates, down_w, *ups)
     if shared is not None:
+        if len(shared) != len(ups) + 1:
+            raise ValueError(
+                f"the shared expert follows the routed experts' kind: "
+                f"{len(ups) + 1} matrices, got {len(shared)}")
         with jax.named_scope("shared"):
-            up_s, down_s = shared
-            hs = relu2(jnp.dot(x.astype(cd), up_s,
-                               preferred_element_type=jnp.float32)).astype(cd)
+            *ups_s, down_s = shared
+            pre = [jnp.dot(x.astype(cd), w,
+                           preferred_element_type=jnp.float32) for w in ups_s]
+            if len(pre) == 2:
+                hs = (jax.nn.silu(pre[0]) * pre[1]).astype(cd)
+            else:
+                hs = relu2(pre[0]).astype(cd)
             out = out + jnp.dot(hs, down_s,
                                 preferred_element_type=jnp.float32)
     return out.astype(cd), logits, counts, (group_sizes if part else None)
@@ -295,7 +305,9 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
 def moe_ffn_kernel(ctx):
     """Program-IR face: X [B, T, d] (or [T, d]); RouterW [d, E]; GateW
     (absent: relu^2 experts), UpW [held, d, f]; DownW [held, f, d]; optional
-    RouterBias [E], SharedUpW [d, f_s], SharedDownW [f_s, d]. Attrs: top_k,
+    RouterBias [E], SharedUpW [d, f_s], SharedDownW [f_s, d] and, beside
+    SwiGLU experts, SharedGateW [d, f_s] (the shared expert is of the routed
+    experts' kind: `expert_act`). Attrs: top_k,
     norm_topk_prob, and where they differ from a softmax router over experts
     that are all here: scoring, gate_scale, held_lo / held_hi. Out shaped
     like X, in the compute dtype; RouterLogits [tokens, E] float32 (under
@@ -307,8 +319,10 @@ def moe_ffn_kernel(ctx):
         ctx, ctx.input("GateW"), ctx.input("UpW"), ctx.input("DownW"))
     shared = None
     if ctx.has_input("SharedUpW"):
-        shared = amp.cast_inputs(ctx, ctx.input("SharedUpW"),
-                                 ctx.input("SharedDownW"))
+        slots = ("SharedUpW", "SharedDownW")
+        if ctx.has_input("SharedGateW"):
+            slots = ("SharedGateW",) + slots
+        shared = amp.cast_inputs(ctx, *(ctx.input(slot) for slot in slots))
     held = None
     if ctx.attr("held_hi") is not None:
         held = (int(ctx.attr("held_lo")), int(ctx.attr("held_hi")))

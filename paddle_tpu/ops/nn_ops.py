@@ -224,35 +224,48 @@ def rms_norm_kernel(ctx):
         ctx.input("X"), ctx.input("Scale"), ctx.attr("epsilon", 1e-5)))
 
 
-def rotary(x, theta: float):
+def rotary(x, theta: float, rotary_dim=None):
     """Rotary position embedding on [B, T, H, D], rotate-half convention
     (the `transformers` one: the head dim's two HALVES pair up, not its
     even/odd lanes): inv_freq_i = theta^(-2i/D), position t; out = x * cos
-    + rotate_half(x) * sin. Computed in f32, returned in x's dtype."""
+    + rotate_half(x) * sin. Computed in f32, returned in x's dtype.
+    `rotary_dim` R < D: only the LAST R lanes of each head turn (their two
+    halves pair up, inv_freq_i = theta^(-2i/R)); the D - R lanes in front
+    pass through untouched (latent attention's `[q_nope | q_rope]` head)."""
     T, D = x.shape[1], x.shape[3]
-    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    R = D if rotary_dim is None else int(rotary_dim)
+    inv_freq = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
     x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., : D // 2], x32[..., D // 2:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    x1, x2 = x32[..., D - R: D - R // 2], x32[..., D - R // 2:]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if R < D:
+        parts.insert(0, x32[..., : D - R])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 @register_op("rotary_embedding")
 def rotary_embedding_kernel(ctx):
     """Program-IR face of `rotary`: X is a [B, T, E] packed multi-head
     projection (as flash_attention's Q/K), num_heads splits E. Sits
-    between the Q/K projections and the flash_attention op."""
+    between the Q/K projections and the flash_attention op. Attr
+    `rotary_dim` (absent: the whole head): the last that many lanes of
+    each head turn, the rest pass through."""
     x = ctx.input("X")
     heads = ctx.attr("num_heads")
+    rotary_dim = ctx.attr("rotary_dim")
     B, T, E = x.shape
     if E % heads or (E // heads) % 2:
         raise ValueError(
             f"hidden dim {E} must split into {heads} heads of even size")
+    if rotary_dim is not None and not (
+            0 < rotary_dim <= E // heads and rotary_dim % 2 == 0):
+        raise ValueError(f"rotary_dim {rotary_dim} is not an even part of "
+                         f"a head of {E // heads}")
     out = rotary(x.reshape(B, T, heads, E // heads),
-                 float(ctx.attr("theta", 10000.0)))
+                 float(ctx.attr("theta", 10000.0)), rotary_dim)
     ctx.set_output("Out", out.reshape(B, T, E))
 
 

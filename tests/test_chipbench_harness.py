@@ -256,7 +256,7 @@ def test_attn_device_ms_is_in_the_manifest_for_every_cell():
         if w["config"] in ("gpt2-small", "olmoe-1b-7b")]
     wrapped = {m["name"] for m in manifest["per_layer"]
                if m["name"].endswith(".attn_device_ms")}
-    assert wrapped == {"nemotron.attn_device_ms"}
+    assert wrapped == {"nemotron.attn_device_ms", "glm.attn_device_ms"}
 
 
 def test_load_max_over_mean_reads_the_counters_and_checks_the_sum():
@@ -703,3 +703,385 @@ def test_the_nemotron_cell_rehearses_on_the_cpu(tmp_path):
             "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
     share = result["metrics"]["REHEARSAL_ON_CPU.moe.held_pair_share"]["value"]
     assert 0.1 < share < 0.45                # 4 of 16 experts held: 0.25
+
+
+# ------------------------------------------------------ glm-4.7-flash (PR 37) ---
+GLM = "glm-4.7-flash"
+GLM_CELL = {"batch": 1, "seqlen": 8192}
+
+
+def _glm_config():
+    with open(os.path.join(BENCH, "configs", GLM, "config.json")) as f:
+        return json.load(f)
+
+
+def test_glm_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _glm_config()
+    config_dir = os.path.join(BENCH, "configs", GLM)
+    got = flops.train_flops_per_item(cfg, GLM_CELL, config_dir)
+    assert got == 3 * 956432384.0 == 2869297152.0       # ISSUE 37's arithmetic
+    own = _load("configs", GLM, "flops.py")
+    # latent attention: five projections and the causal kernels, a layer
+    assert own.attention_flops_per_token(cfg, 8192) == 43515904 + 83886080
+    # one more routed layer adds its attention, router, shared expert and
+    # half a pair; one more held expert an eighth of a pair a routed layer
+    more = own.forward_flops_per_token(dict(cfg, num_hidden_layers=6), 8192)
+    assert more - 956432384.0 == 127401984 + 262144 + 18874368 + 9437184
+    wider = own.forward_flops_per_token(dict(cfg, held_experts=[0, 9]), 8192)
+    assert wider - 956432384.0 == pytest.approx(4 * 9437184.0 / 8)
+    dense = own.forward_flops_per_token(dict(cfg, first_k_dense_replace=2), 8192)
+    assert dense - 956432384.0 == 125829120 - (262144 + 18874368 + 9437184)
+
+
+def test_glm_config_keeps_the_published_sizes():
+    cfg = _glm_config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        published = next(e for e in map(json.loads, f)
+                         if e["name"] == "GLM-4.7-Flash")
+    assert cfg["source"] == published["source_url"]
+    differs = [k for k, v in published["config"].items()
+               if cfg.get(k, "absent") != v]
+    assert sorted(differs) == sorted(cfg["reduced"]) == sorted(
+        ["num_hidden_layers", "n_routed_experts", "vocab_size"])
+    assert cfg["published"] == {k: published["config"][k] for k in differs} \
+        == {"num_hidden_layers": 47, "n_routed_experts": 64,
+            "vocab_size": 154880}
+    # the cut: the dense layer and four routed ones, 8 of 64 experts behind a
+    # router that stays 64 wide, an eighth of the vocabulary; no width cut
+    assert cfg["num_hidden_layers"] == 5 and cfg["first_k_dense_replace"] == 1
+    assert cfg["vocab_size"] * 8 == 154880
+    assert cfg["router_experts"] == 64 and cfg["held_experts"] == [0, 8]
+    assert (cfg["q_lora_rank"], cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+            cfg["qk_rope_head_dim"], cfg["v_head_dim"]) == (768, 512, 192, 64,
+                                                            256)
+    assert "8-chip" in cfg["deployment"] and "47" in cfg["distortion"]
+    for key in ("assumed", "departures", "deployment", "distortion",
+                "gradient_limits"):
+        assert cfg[key], key
+    assert "routed_parameters" not in cfg
+    # the parameters this chip holds: ISSUE 37's count
+    d, f, H = 2048, 1536, 20
+    mla = d * 768 + 768 * H * 256 + d * 576 + 512 * H * 448 + H * 256 * d + 1280
+    dense = mla + 3 * d * 10240 + 2 * d
+    routed = mla + d * 64 + 9 * 3 * d * f + 2 * d
+    assert dense + 4 * routed + 2 * 19360 * d + d == 591294720
+
+
+def test_glm_kernels_count_on_hand_made_cells():
+    flash = _load("kernels", "glm_flash_attention.py")
+    plain = _load("kernels", "flash_attention.py")
+    cfg = {"num_hidden_layers": 3, "num_attention_heads": 2,
+           "qk_nope_head_dim": 6, "qk_rope_head_dim": 2, "v_head_dim": 8}
+    flops, bytes_ = flash.flops_and_bytes(cfg, {"batch": 2, "seqlen": 5})
+    assert flops == 3 * 2 * 2 * 6 * 2 * 15 * 8     # six matmuls over 15 pairs
+    assert bytes_ == 3 * 12 * (2 * 5 * 2 * 8) * 2  # twelve tensors, 2 bytes
+    # at one head size it is the plain count with that head size spelled out
+    assert (flops, bytes_) == plain.flops_and_bytes(
+        dict(cfg, head_dim=8), {"batch": 2, "seqlen": 5})
+    got = flash.flops_and_bytes(_glm_config(), GLM_CELL)
+    assert got[0] == 5 * 20 * 12 * (8192 * 8193 // 2) * 256   # 10.3 TFLOP
+    assert got[1] == 5 * 12 * 8192 * 5120 * 2
+    assert got[0] / 197e12 > got[1] / 819e9                   # compute-bound
+    # what `kernels/flash_attention.py` would read: a head of 2048 / 20
+    assert plain.flops_and_bytes(_glm_config(), GLM_CELL)[0] < 0.5 * got[0]
+    gmm = _load("kernels", "glm_grouped_matmul.py")
+    cfg = _glm_config()
+    d, f = 2048, 1536
+    flops, bytes_ = gmm.flops_and_bytes(cfg, GLM_CELL)
+    rows = 4 * 8192 * 4 * 8 / 64            # even routing: 4 096 a layer
+    assert gmm.routed_layers(cfg) == 4 and rows == 16384
+    assert flops == 18 * rows * d * f
+    assert bytes_ == 2 * (9 * 4 * 8 * d * f + rows * (5 * d + 7 * f))
+    assert gmm.flops_and_bytes(cfg, GLM_CELL, rows=100.0) == (
+        18 * 100.0 * d * f, 2 * (9 * 4 * 8 * d * f + 100.0 * (5 * d + 7 * f)))
+
+
+GLM_MOE = "moe_ffn.glm_moe.h1.moe.tmp_40"
+GLM_FLASH = "flash_attention.latent_attention_0.tmp_12"
+GLM_PARTS = {"q_down": "mul.fc_0.tmp_1", "q_norm": "rms_norm.q.tmp_2",
+             "q_up": "mul.fc_1.tmp_3", "rot_q": "rotary_embedding.r.tmp_4",
+             "kv_down": "mul.fc_2.tmp_5", "split": "split.s.tmp_6",
+             "kv_norm": "rms_norm.kv.tmp_8", "kv_up": "mul.fc_3.tmp_9",
+             "rot_k": "rotary_embedding.r.tmp_10",
+             "assemble": "latent_kv_expand.latent_attention_0.tmp_11",
+             "out": "mul.fc_4.tmp_13"}
+
+
+def _glm_run_record():
+    P = GLM_PARTS
+    ops = [
+        _row(P["q_down"], 2_000_000), _row(P["q_up"], 4_000_000, None, "jvp("),
+        _row(P["kv_down"], 2_000_000), _row(P["kv_up"], 6_000_000),
+        _row(P["out"], 8_000_000, None, "transpose(jvp("),
+        _row(P["q_norm"], 600_000), _row(P["kv_norm"], 400_000),
+        _row(P["rot_q"], 1_000_000), _row(P["rot_k"], 200_000),
+        _row(P["split"], 300_000), _row(P["assemble"], 1_500_000),
+        _row(GLM_FLASH, 20_000_000, "tpu_custom_call", "jvp("),
+        _row(GLM_FLASH, 40_000_000, "tpu_custom_call", "transpose(jvp("),
+        _row(GLM_FLASH, 1_000_000, None, "transpose(jvp("),
+        _row(GLM_FLASH, 90_000_000, None, "", container=True),    # a loop
+        _row(GLM_MOE, 4_000_000, "tpu_custom_call", "", "experts"),
+        _row(GLM_MOE, 2_000_000, None, "", "shared"),
+        _row(GLM_MOE, 3_000_000, None, "", "combine"),
+        _row("mul.fc_9.tmp_9", 5_000_000),        # the head: no part of MLA
+        _row("rms_norm.ln_in.tmp_0", 700_000),    # the layer's own norm: none
+    ]
+    held = 'pt_moe_held_pairs_total{expert="%d",layer="glm_moe.h1.moe"}'
+    every = 'pt_moe_expert_tokens_total{expert="%d",layer="glm_moe.h1.moe"}'
+    registry = {held % 0: 4000.0, held % 1: 4192.0,
+                every % 0: 4000.0, every % 1: 4192.0, every % 50: 57344.0,
+                "pt_executor_donated_bytes": 7.0e9}
+
+    def op(kind, scope, inputs, outputs):
+        return {"type": kind, "scope": scope, "inputs": inputs,
+                "outputs": outputs}
+
+    program_ops = [
+        op("rms_norm", "rms_norm.ln_in.tmp_0", {"X": ["x"]}, {"Y": ["h"]}),
+        op("mul", P["q_down"], {"X": ["h"], "Y": ["wq_a"]}, {"Out": ["t1"]}),
+        op("rms_norm", P["q_norm"], {"X": ["t1"]}, {"Y": ["t2"]}),
+        op("mul", P["q_up"], {"X": ["t2"], "Y": ["wq_b"]}, {"Out": ["t3"]}),
+        op("rotary_embedding", P["rot_q"], {"X": ["t3"]}, {"Out": ["t4"]}),
+        op("mul", P["kv_down"], {"X": ["h"], "Y": ["wkv_a"]}, {"Out": ["t5"]}),
+        op("split", P["split"], {"X": ["t5"]}, {"Out": ["t6", "t7"]}),
+        op("rms_norm", P["kv_norm"], {"X": ["t6"]}, {"Y": ["t8"]}),
+        op("mul", P["kv_up"], {"X": ["t8"], "Y": ["wkv_b"]}, {"Out": ["t9"]}),
+        op("rotary_embedding", P["rot_k"], {"X": ["t7"]}, {"Out": ["t10"]}),
+        op("latent_kv_expand", P["assemble"], {"KV": ["t9"], "KRope": ["t10"]},
+           {"K": ["t11"], "V": ["t11v"]}),
+        op("flash_attention", GLM_FLASH,
+           {"Q": ["t4"], "K": ["t11"], "V": ["t11v"]}, {"Out": ["t12"]}),
+        op("mul", P["out"], {"X": ["t12"], "Y": ["wo"]}, {"Out": ["t13"]}),
+        op("moe_ffn", GLM_MOE, {"X": ["h2"]},
+           {"Out": ["glm_moe.h1.moe.tmp_40"]}),
+        op("mul", "mul.fc_9.tmp_9", {"X": ["hf"], "Y": ["w"]},
+           {"Out": ["l"]})]
+    return {"steps": 2, "trace": {"ops": ops}, "registry": registry,
+            "device": {"kind": "TPU v5 lite"}, "config": _glm_config(),
+            "cell": GLM_CELL, "program_ops": program_ops}
+
+
+def test_mla_readers_find_the_layers_parts_from_the_programs_structure():
+    run = _glm_run_record()
+    reader = _load("layer_metrics", "mla.device_ms.py")
+    parts = reader.parts(run["program_ops"])
+    P = GLM_PARTS
+    assert parts == {
+        P["q_down"]: "q_down", P["q_norm"]: "q_norm", P["q_up"]: "q_up",
+        P["rot_q"]: "rotary", P["rot_k"]: "rotary", P["kv_down"]: "kv_down",
+        P["split"]: "split", P["kv_norm"]: "kv_norm", P["kv_up"]: "kv_up",
+        P["assemble"]: "assemble", GLM_FLASH: "kernels", P["out"]: "out"}
+    # every leaf row of the layer, forward and backward; not the loop, not
+    # the head's GEMM, not the layer's own input norm, not the routed op
+    assert reader.compute(run) == pytest.approx(87.0 / 2)
+    info = reader.info(run)
+    assert info["by_part_ms"] == pytest.approx(
+        {"q_down": 1.0, "q_up": 2.0, "kv_down": 1.0, "kv_up": 3.0, "out": 4.0,
+         "q_norm": 0.3, "kv_norm": 0.2, "rotary": 0.6, "split": 0.15,
+         "assemble": 0.75, "kernels": 30.5})
+    assert info["by_pass_ms"] == pytest.approx(
+        {"plain": 7.0, "jvp": 12.0, "transpose": 24.5})
+    assemble = _load("layer_metrics", "mla.assemble_ms.py")
+    # neither a GEMM nor a kernel: norms, rotary, split, assemble, and the
+    # 1 ms XLA runs under the kernels' scope
+    assert assemble.compute(run) == pytest.approx(
+        (0.6 + 0.4 + 1.0 + 0.2 + 0.3 + 1.5 + 1.0) / 2)
+    assert assemble.info(run)["by_part_ms"]["kernels"] == pytest.approx(0.5)
+    # a Program without the op (every other configuration, the parent of the
+    # PR that added it), or no trace: nothing to read, and nothing raised
+    for empty in (dict(run, trace=None), dict(run, program_ops=None),
+                  dict(run, program_ops=_nemo_run_record()["program_ops"])):
+        assert reader.compute(empty) is None
+        assert assemble.compute(empty) is None
+
+
+def test_glm_rooflines_on_a_hand_made_run_record():
+    run = _glm_run_record()
+    flash = _load("layer_metrics", "glm.flash_roofline.py")
+    # 10.31 TFLOP at 197 TFLOP/s = 52.3 ms over the kernels' 30 ms a step:
+    # over 100 and shown as it is (a hand-made time)
+    need = 5 * 20 * 12 * (8192 * 8193 // 2) * 256 / 197e12
+    assert flash.compute(run) == pytest.approx(100 * need / 30e-3, rel=1e-3)
+    assert flash.info(run)["kernels_per_step"] == 2.0
+    assert flash.info(run)["bound"] == "compute"
+    assert flash.compute(dict(run, trace=None)) is None
+    gmm = _load("layer_metrics", "glm.gmm_roofline.py")
+    d, f = 2048, 1536                       # 2 steps, 8 192 held pairs
+    want_bytes = 2 * (9 * 4 * 8 * d * f + 4096.0 * (5 * d + 7 * f))
+    assert gmm.info(run)["flops_per_step"] == 18 * 4096.0 * d * f
+    assert gmm.info(run)["bytes_per_step"] == want_bytes
+    assert gmm.info(run)["held_pairs_per_step"] == 4096.0
+    assert gmm.compute(run) == pytest.approx(
+        100 * want_bytes / 819e9 / 2e-3, rel=1e-3)    # the kernels' 2 ms
+    assert gmm.info(run)["bound"] == "memory"
+    no_held = {k: v for k, v in run["registry"].items()
+               if "held_pairs" not in k}
+    assert gmm.compute(dict(run, registry=no_held)) is None
+    assert gmm.compute(dict(run, trace=None)) is None
+
+
+def test_glm_routed_readers_count_a_share_of_the_experts():
+    run = _glm_run_record()                 # 2 steps x 8 192 tokens x 4 pairs
+    share = _load("layer_metrics", "glm.held_pair_share.py")
+    assert share.compute(run) == pytest.approx(8192 / 65536)    # 0.125
+    assert share.info(run)["held_pairs_per_step"] == 4096.0
+    with pytest.raises(ValueError, match="dropped or counted twice"):
+        share.compute(dict(run, steps=3))
+    moe = _load("layer_metrics", "glm.moe_device_ms.py")
+    assert moe.compute(run) == pytest.approx(4.5)
+    assert moe.info(run)["by_inner_scope_ms"] == pytest.approx(
+        {"experts": 2.0, "shared": 1.0, "combine": 1.5})
+    assert moe.info(run)["kernels_ms"] == pytest.approx(2.0)
+    dispatch = _load("layer_metrics", "glm.moe_dispatch_ms.py")
+    # the kernel's 4 ms and the shared expert's 2 ms are not routing's
+    assert dispatch.compute(run) == pytest.approx(1.5)
+    load = _load("layer_metrics", "glm.load_max_over_mean.py")
+    # 65 536 pairs over 64 scored experts: mean 1 024, the busiest 57 344
+    assert load.compute(run) == pytest.approx(57344 / 1024)
+    for reader in (share, moe, dispatch, load):
+        assert reader.compute(dict(run, trace=None, registry={})) is None
+
+
+GLM_WRAPPERS = {"glm.head_device_ms": "head.device_ms",
+                "glm.feed_produce_ms_per_step": "feed.produce_ms_per_step",
+                "glm.opt_device_ms": "opt.device_ms",
+                "glm.donated_gib": "step.donated_gib",
+                "glm.attn_device_ms": "attn.device_ms",
+                "glm.held_pair_share": "moe.held_pair_share",
+                "glm.moe_device_ms": "nemotron.moe_device_ms",
+                "glm.moe_dispatch_ms": "nemotron.moe_dispatch_ms",
+                "glm.load_max_over_mean": "nemotron.load_max_over_mean"}
+
+
+@pytest.mark.parametrize("name", sorted(GLM_WRAPPERS))
+def test_a_glm_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run = _glm_run_record()
+    run["program_ops"] += [
+        {"type": "adam", "scope": "adam.w", "inputs": {"Param": ["w"]},
+         "outputs": {"ParamOut": ["w"]}},
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.s",
+         "inputs": {"Logits": ["l"], "Label": ["y"]},
+         "outputs": {"Softmax": ["s"], "Loss": ["c"]}}]
+    run["trace"]["ops"].append(_row("adam.w", 2_000_000))
+    run["timers_s"] = {"prefetch.read": 0.004, "prefetch.batch": 0.002}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", GLM_WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == GLM_WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    if hasattr(wrapper, "info"):
+        assert wrapper.info(run) == wrapped.info(run)
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+
+
+def test_the_manifest_lists_the_glm_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = GLM + ".train-log10"
+    assert manifest["workloads"][-1]["name"] == cell     # appended, last
+    entry = manifest["workloads"][-1]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        GLM, "train-log10", 1)
+    config = manifest["configs"][-1]
+    assert config["name"] == GLM and config["source"] == _glm_config()["source"]
+    assert config["reduced"] == _glm_config()["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"]
+    mine = [m["name"] for m in manifest["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine == ["mla.device_ms", "mla.assemble_ms", "glm.flash_roofline",
+                    "glm.gmm_roofline", "glm.moe_device_ms",
+                    "glm.moe_dispatch_ms", "glm.held_pair_share",
+                    "glm.load_max_over_mean", "glm.attn_device_ms",
+                    "glm.head_device_ms", "glm.opt_device_ms",
+                    "glm.donated_gib", "glm.feed_produce_ms_per_step"]
+    assert mine == [m["name"] for m in manifest["per_layer"]][-len(mine):]
+    layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
+    assert layers["mla.device_ms"] == layers["mla.assemble_ms"] \
+        == "Latent attention"
+    for name in mine:
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    # no reader that was there lists the new cell: their entries are untouched
+    assert not [m["name"] for m in manifest["per_layer"]
+                if cell in m.get("workloads", ()) and m["name"] not in mine]
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        traffic = json.load(f)
+    assert (traffic["batch"], traffic["seqlen"], traffic["sync_every"],
+            traffic["warmup_steps"], traffic["trace_seconds"]) == (
+        1, 8192, 10, 20, 4)
+
+
+def test_the_benchmarks_glm_reference_is_the_trees_bit_for_bit():
+    """`chipbench/configs/glm-4.7-flash/reference.py` is a copy of
+    `tests/glm_moe_reference.py`, text for text, and gives the same cost,
+    gradients and routers to the bit on the CPU, its own choice or a handed
+    one: the two cannot drift apart unseen."""
+    import glm_moe_reference as tree
+
+    copy = _load("configs", GLM, "reference.py")
+    assert open(copy.__file__).read() == open(tree.__file__).read()
+    cfg = dict(_glm_config(), **_glm_config()["rehearsal"])
+    d, V, H = cfg["hidden_size"], cfg["vocab_size"], cfg["num_attention_heads"]
+    rq, r = cfg["q_lora_rank"], cfg["kv_lora_rank"]
+    n, R, Dv = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+                cfg["v_head_dim"])
+    E, held, f = cfg["router_experts"], 4, cfg["moe_intermediate_size"]
+    attn = [(d,), (d, rq), (rq,), (rq, H * (n + R)), (d, r + R), (r,),
+            (r, H * (n + Dv)), (H * Dv, d), (d,)]
+    kinds = {"dense": [(d, cfg["intermediate_size"])] * 2
+             + [(cfg["intermediate_size"], d)],
+             "routed": [(d, E), (held, d, f), (held, d, f), (held, f, d),
+                        (E,), (d, f), (d, f), (f, d)]}
+    rng = np.random.RandomState(0)
+    shapes = [(V, d)] + [s for kind in tree._kinds(cfg)
+                         for s in attn + kinds[kind]] + [(d,), (d, V)]
+    params = [(rng.randn(*s) * 0.2 + (len(s) == 1)).astype(np.float32)
+              for s in shapes]
+    toks = rng.randint(0, V, (2, 41))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    own = [m.loss_grads_and_routers(cfg, params, feed) for m in (tree, copy)]
+    choice = copy.chosen(cfg, params, [z for _, _, z in own[1][2]])
+    handed = [m.loss_grads_and_routers(cfg, params, feed, choice)
+              for m in (tree, copy)]
+    for (c1, g1, r1), (c2, g2, r2) in (own, handed):
+        assert float(c1) == float(c2) and np.isfinite(float(c1))
+        assert len(g1) == len(g2) == len(params) and len(r1) == len(r2) == 2
+        for a, b in zip(g1, g2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(r1, r2):
+            np.testing.assert_array_equal(np.asarray(a[2]), np.asarray(b[2]))
+    # the reference's own choice, handed back to it, is its own result
+    assert float(own[0][0]) == float(handed[0][0])
+    assert [int(m.sum()) for m in choice] == [2 * 40 * 3] * 2
+
+
+def test_the_glm_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference (handed the program's choice) at the rehearsal's tolerances,
+    both routed counters reach the run record, and every metric's name
+    carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         GLM + ".train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486099"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.glm.held_pair_share",
+            "REHEARSAL_ON_CPU.glm.load_max_over_mean",
+            "REHEARSAL_ON_CPU.glm.donated_gib",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    share = result["metrics"]["REHEARSAL_ON_CPU.glm.held_pair_share"]["value"]
+    assert 0.1 < share < 0.45                # 4 of 16 experts held: 0.25
+    assert "choice_counts_off_program" in result["compared"]

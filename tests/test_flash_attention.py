@@ -148,6 +148,11 @@ PACKED_CASES = [
     ("d128_h2_causal_f32", 1, 256, 256, 2, 128, True, jnp.float32),
     ("d128_h3_full_bf16", 1, 256, 256, 3, 128, False, jnp.bfloat16),
     ("d256_h1_causal_f32", 1, 256, 256, 1, 256, True, jnp.float32),
+    # latent attention's head (GLM-4.7-Flash: 192 + 64): one head over two
+    # lane tiles, several heads, bf16 as under AMP
+    ("d256_h2_causal_bf16", 1, 256, 256, 2, 256, True, jnp.bfloat16),
+    ("d256_h3_full_f32", 1, 256, 256, 3, 256, False, jnp.float32),
+    ("d256_h2_causal_blocks_bf16", 1, 1024, 1024, 2, 256, True, jnp.bfloat16),
     # several q and k blocks: the causal rule skips a block, masks the
     # diagonal ones and leaves one whole
     ("d64_h2_causal_blocks_bf16", 1, 1024, 1024, 2, 64, True, jnp.bfloat16),
@@ -228,9 +233,10 @@ def test_bthd_entry_and_packed_entry_agree():
     ((1, 4096, 16, 128), True),    # olmoe: one head a block
     ((1, 1024, 3, 128), True),
     ((1, 1024, 2, 256), True),
+    ((1, 8192, 20, 256), True),    # glm-4.7-flash: one head over two tiles
     ((1, 1024, 4, 32), False),
 ], ids=["d64_h12", "d64_h3_odd", "t1000", "d128_h16", "d128_h3", "d256",
-        "d32"])
+        "d256_h20", "d32"])
 def test_shapes_the_packed_kernel_takes(shape, ok):
     from paddle_tpu.ops.flash_ops import _shapes_flash_ok
 

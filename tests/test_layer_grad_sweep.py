@@ -870,6 +870,35 @@ def build_multi_head_attention_qk_norm_rotary():
     return _scalar(h), feed
 
 
+@case
+def build_rotary_embedding_sub_range():
+    # the last two lanes of each head of four turn, the first two pass
+    h, feed = _pre_btd()
+    return _scalar(L.rotary_embedding(h, num_heads=2, rotary_dim=2)), feed
+
+
+@case
+def build_latent_attention():
+    # two heads of 3 + 2 lanes through a 6-wide query latent and a 4-wide
+    # key/value latent, one shared rotary key
+    h, feed = _pre_btd()
+    return _scalar(L.latent_attention(
+        h, num_heads=2, q_rank=6, kv_rank=4, nope_dim=2, rope_dim=2,
+        v_dim=4, rotary_theta=100.0)), feed
+
+
+@case
+def build_moe_ffn_swiglu_share_with_shared_expert():
+    # a chip's share (experts 1-2 of 4) of SwiGLU experts beside a SwiGLU
+    # shared expert, behind a sigmoid router
+    h, feed = _pre_btd(6, 8)
+    out, _, _ = L.moe_ffn(h, num_experts=4, experts_per_token=2, expert_dim=8,
+                          norm_topk_prob=True, scoring="sigmoid",
+                          router_bias=True, gate_scale=1.8,
+                          held_experts=(1, 3), shared_expert_dim=8)
+    return _scalar(out), feed
+
+
 def _moe(t=6, d=8):
     h, feed = _pre_btd(t, d)
     return L.moe_ffn(h, num_experts=4, experts_per_token=2, expert_dim=8), feed

@@ -473,6 +473,35 @@ def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
             < 15.0 * 2**30)
 
 
+def test_glm_moe_step_program_fits_one_chip(one_chip, compiled_mode,
+                                            monkeypatch):
+    """The GLM-4.7-Flash share's whole step at the size `configs/glm_moe.py`
+    trains (batch 1 x T 8192, 591 M parameters, five latent-attention layers
+    at 20 heads x 256) compiles for the described v5e: it fits 15.75 GiB
+    without `Program.remat_policy` (under 15.0 GiB by the compiler's own
+    books, which settles T 8192 against 4096 before any chip time), the
+    attention kernels at head size 256 (the fused backward with its
+    [8192, 256] float32 dQ accumulator in VMEM) and the grouped-matmul
+    kernels are in it, and no `ragged-dot` is."""
+    config = _load_module(os.path.join(ROOT, "configs", "glm_moe.py"))
+    raw, args = _step_program(config.get_model, 1, 8192, one_chip,
+                              monkeypatch)
+    launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
+    assert launches["flash_attention_fwd"] == 5
+    assert launches["flash_attention_bwd"] == 5
+    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_bwd" in text and "ragged-dot" not in text
+    assert "gmm" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 7.0e9          # 12 B a parameter
+    print("glm step: arguments %.3f GiB, temporaries %.3f GiB" % (
+        memory.argument_size_in_bytes / 2**30,
+        memory.temp_size_in_bytes / 2**30))
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.0 * 2**30)
+
+
 def _written_arrays(hlo_text):
     """(opcode, dtype, elements, in_fusion_body) of every array that an
     instruction of the optimized HLO produces. What an instruction of a
