@@ -12,6 +12,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..initializer import ConstantInitializer, XavierInitializer
+from ..ops.moe_ops import bounds_rows
 from ..param_attr import ParamAttr
 from .helper import LayerHelper
 
@@ -19,6 +20,7 @@ __all__ = ["moe_ffn", "moe_aux_loss"]
 
 EXPERT_TOKENS_COUNTER = "pt_moe_expert_tokens_total"
 HELD_PAIRS_COUNTER = "pt_moe_held_pairs_total"
+ROW_PATH_COUNTER = "pt_moe_row_path_total"
 
 
 def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
@@ -45,7 +47,12 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
     among all of them; the stacks hold experts lo..hi-1 only, the pairs that
     chose one of them are computed, and a pair that chose an absent expert
     adds nothing (its chip would add it; no code stands in for that chip or
-    the exchange). The shares of a layer add up to the whole layer.
+    the exchange). The shares of a layer add up to the whole layer. A share
+    under half of the experts gathers, masks and combines its live rows in
+    chunks of a bound (two even shares of the tokens x k pairs) in place
+    of all the rows: one chunk in a step whose live pairs fit the bound,
+    more, by the same arithmetic, in a step whose pairs exceed it
+    (`ops/moe_ops.py:row_bound`).
     shared_expert_dim f_s > 0: a shared expert for every token, of the
     routed experts' kind (`expert_act`): "relu2" + relu(x Wu_s)^2 Wd_s
     (`<name>.shared_up` [d, f_s], `.shared_down`); "swiglu" + (silu(x Wg_s)
@@ -58,7 +65,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
     folds it on the device with the cost and publishes
     `pt_moe_expert_tokens_total{layer,expert}` at its host syncs; a share
     also publishes `pt_moe_held_pairs_total{layer,expert}`, the pairs it
-    computed, by held expert (0 = `lo`)."""
+    computed, by held expert (0 = `lo`), and, where its rows have a bound,
+    `pt_moe_row_path_total{layer,path}`: the steps whose live pairs fitted
+    one chunk of the bound (path 0) and those that needed more (path 1)."""
     helper = LayerHelper("moe_ffn", name=name)
     d = int(input.shape[-1])
     E, f = int(num_experts), int(expert_dim)
@@ -112,6 +121,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
         attrs["held_lo"], attrs["held_hi"] = lo, hi
         held = helper.create_tmp_variable(np.int32, (hi - lo,))
         outputs["HeldPairs"] = [held]
+        if bounds_rows((lo, hi), E):
+            row_path = helper.create_tmp_variable(np.int32, (2,))
+            outputs["RowPath"] = [row_path]
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
                      attrs=attrs)
     helper.main_program.add_step_statistic(
@@ -124,6 +136,12 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
             index_label="expert",
             help="(token, slot) pairs a chip's share of a routed layer "
                  "computed, by held expert")
+        if "RowPath" in outputs:
+            helper.main_program.add_step_statistic(
+                row_path, ROW_PATH_COUNTER, labels={"layer": helper.name},
+                index_label="path",
+                help="steps a chip's share of a routed layer ran in one "
+                     "chunk of its bounded rows (path 0) or in more (path 1)")
     return out, logits, counts
 
 

@@ -107,6 +107,36 @@ def kernel_width_pad(rows: int, d: int, f: int, dtype) -> int:
     return 0
 
 
+# A share computes its live rows in chunks (`moe_ffn`) of this many even
+# shares of the rows. The routers learn to prefer the held experts, because
+# only their output reaches the cost: by layer the hybrid (8 of 128 held,
+# even 0.0625) reads 0.055-0.106 of its rows live after 20 steps, 0.19-0.28
+# in its two deepest layers on some seeds, and glm (8 of 64, even 0.125)
+# 0.20-0.24 (PERF.md section 6, PRs 37 and 38). A step takes as many chunks
+# as its live rows fill, so a small chunk wastes less and a large one loops
+# less. By the chip (PERF.md section 6, PR 38; one seed, 12 s windows;
+# tokens/s, GiB at the window's close): chunks of 1 / 2 / 4 even shares read
+# 25 080 / 25 463 / 24 465 and 12.57 GiB each on the hybrid (the whole rows
+# 19 400 and 13.83), 1 / 2 / 3 / 4 read 30 545 / 30 700 / 31 147 / 30 471
+# and 11.97 / 12.06 / 12.18 / 12.29 GiB on glm (28 260 and 12.05): from
+# three even shares on glm holds more than the whole rows did.
+_ROW_BOUND = 2
+
+
+def bounds_rows(held, experts: int) -> bool:
+    """Whether a share `held` = (lo, hi) of `experts` is small enough to
+    compute its rows in chunks: under half of the experts."""
+    return _ROW_BOUND * (held[1] - held[0]) < experts
+
+
+def row_bound(rows: int, held, experts: int, tile: int) -> int:
+    """R, the rows of a share's chunk: `_ROW_BOUND` times the even share of
+    the `rows` = T x k pairs, up to a whole row `tile`, and `rows` at most
+    (then there is nothing to bound)."""
+    need = -(-_ROW_BOUND * rows * (held[1] - held[0]) // experts)
+    return min(rows, -(-need // tile) * tile)
+
+
 def _gmm_kernel(lhs, rhs, group_sizes, interpret: bool = False):
     """Direct kernel call, no dispatch gate (tests compile it for a
     described chip and run it interpreted)."""
@@ -167,6 +197,72 @@ def _undispatch_bwd(order, g):
 _undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
 
 
+# -- a share's rows in chunks: gather in, sum out ---------------------------
+# R rows (`row_bound`) of the sort by expert, the live ones first; `tok` [R]
+# is each row's token. The gather's transpose is a scatter-add of R rows into
+# [T, d]. Read on a v5e at the hybrid's shapes (R 12 288, d 2688, T 8192,
+# float32 out; PERF.md section 6, PR 38): 2.56 ms, 2.70 with the rows sorted
+# by token first and `indices_are_sorted`, against 5.02 ms for a form made of
+# gathers alone (sort the rows by token, a segmented sum over runs of at most
+# k in shifted adds, each token's first row) and 5.40 ms for the whole-rows
+# gather of [T x k, d] through the inverse permutation.
+def _add_rows(acc, rows, tok):
+    """acc [T, d] float32 + rows [R, d], each row added to its token's."""
+    return acc.at[tok].add(rows.astype(jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of(x, tok, T):
+    """x [T, d] -> [R, d]; backward summed in float32 whatever x's dtype."""
+    return x[tok]
+
+
+_rows_of.defvjp(
+    lambda x, tok, T: (x[tok], tok),
+    lambda T, tok, g: (_add_rows(jnp.zeros((T, g.shape[1]), jnp.float32), g,
+                                 tok).astype(g.dtype), None))
+
+
+def _summed_chunks(chunk, count, shape):
+    """sum over j < count(ints) of `chunk(j, acc, ints, *operands)`, which
+    adds chunk j to `acc` (`shape`, float32): a loop whose length the device
+    decides, under ONE differentiation rule that keeps only its inputs (what
+    `jax.checkpoint` keeps). The backward is the same loop: each chunk runs
+    its own forward again and adds its gradients to sums in the operands'
+    own dtypes (one chunk's are what they were; a stack's gradient in a
+    step of more chunks is rounded to its dtype once a chunk: float32 sums
+    would hold 0.3 GB a layer more). So the program holds the chunk's
+    kernels once forward and once backward, however many chunks a step
+    needs. `ints`: integer arrays, no cotangent.
+    A chunk runs under the scope `chunk`, which also takes the `jvp(...)`
+    that the backward's differentiation would else wrap around the chunk's
+    own first scope."""
+    def scoped(*args):
+        with jax.named_scope("chunk"):
+            return chunk(*args)
+
+    @jax.custom_vjp
+    def summed(ints, *operands):
+        return jax.lax.fori_loop(
+            0, count(ints), lambda j, acc: scoped(j, acc, ints, *operands),
+            jnp.zeros(shape, jnp.float32))
+
+    def bwd(res, g):
+        ints, operands = res
+
+        def body(j, sums):
+            grads = jax.vjp(lambda *operands: scoped(
+                j, jnp.zeros_like(g), ints, *operands), *operands)[1](g)
+            return tuple(a + b for a, b in zip(sums, grads))
+
+        return (None, *jax.lax.fori_loop(
+            0, count(ints), body, tuple(jnp.zeros_like(o) for o in operands)))
+
+    summed.defvjp(lambda ints, *operands: (summed(ints, *operands),
+                                           (ints, operands)), bwd)
+    return summed
+
+
 def route(x, router_w, top_k: int, norm_topk_prob: bool,
           scoring: str = "softmax", bias=None, gate_scale: float = 1.0):
     """The router, in float32 whatever the activations' dtype: logits
@@ -206,10 +302,10 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
             router_bias=None, gate_scale: float = 1.0, held=None,
             shared=None):
     """x [T, d] -> (out [T, d], router logits [T, E] f32, tokens per expert
-    [E] int32, pairs per held expert [held] int32 or None). y = sum_j
-    gate_j * expert_{e_j}(x) over the token's top_k experts e_j of all E the
-    router scores; expert e is (silu(x Wg[e]) * (x Wu[e])) Wd[e], or, with
-    `gate_w` None, relu(x Wu[e])^2 Wd[e].
+    [E] int32, pairs per held expert [held] int32 or None, the row path [2]
+    int32 or None). y = sum_j gate_j * expert_{e_j}(x) over the token's
+    top_k experts e_j of all E the router scores; expert e is (silu(x Wg[e])
+    * (x Wu[e])) Wd[e], or, with `gate_w` None, relu(x Wu[e])^2 Wd[e].
 
     `held` (lo, hi): the stacks hold experts lo..hi-1 only. The (token,
     slot) pairs that chose one of them are sorted to the front of the T x k
@@ -218,6 +314,17 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     leaves those rows unwritten); pairs that chose an absent expert add
     nothing. None: all E are here, and the ops are those of a layer that
     knows no shares.
+
+    A share under half of the experts (`bounds_rows`) works through that
+    sort in chunks of R rows (`row_bound`: two even shares of the T x k
+    rows), as many as its held pairs fill and at least one: a chunk
+    gathers R rows of x, runs the matmuls, masks and activation on [R, .]
+    and adds the R gate-weighted rows into [T, d]. One chunk in a step whose
+    routing keeps to the bound, more in a step whose routing does not, the
+    count taken on the device (`_summed_chunks`): every held pair is
+    computed either way, and only the order of a token's sum differs from
+    the whole rows'. The row path says which it was: [1, 0] one chunk,
+    [0, 1] more; None where the rows have no bound.
 
     `shared`: a shared expert, every token, of the routed experts' kind:
     (up [d, f_s], down [f_s, d]) beside relu^2 experts: + relu(x up)^2 down;
@@ -230,6 +337,7 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     E = router_w.shape[1]
     lo, hi = held or (0, E)
     part = (lo, hi) != (0, E)
+    rows = T * top_k
     with jax.named_scope("route"):
         logits, gates, experts = route(x, router_w, top_k, norm_topk_prob,
                                        scoring, router_bias, gate_scale)
@@ -243,46 +351,99 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
         counts = jnp.zeros((E,), jnp.int32).at[flat].add(1)
         group_sizes = counts[lo:hi] if part else counts
         cd = up_w.dtype
-        live = (jnp.arange(T * top_k) < group_sizes.sum())[:, None] \
-            if part else None
-
-    def alive(rows):
-        return rows if live is None else jnp.where(
-            live, rows, jnp.zeros_like(rows))
-
-    def matmul(lhs, rhs):
-        # zeros in AND out: the backward's rows behind the groups are as
-        # unwritten as the forward's, and `where` stops them both ways
-        return alive(grouped_matmul(alive(lhs), rhs, group_sizes))
 
     # an expert width the kernel's tiling does not take is padded with zero
     # columns of the up (and gate) stack and zero rows of the down stack:
     # the hidden rows stay at the padded width and the padding adds nothing
-    f_pad = kernel_width_pad(T * top_k, d, up_w.shape[2], cd)
+    f_pad = kernel_width_pad(rows, d, up_w.shape[2], cd)
+    # a share under half of the experts computes R of its rows a chunk
+    R, bound = rows, part and bounds_rows((lo, hi), E)
+    if bound:
+        kernel = gmm_eligible(
+            jax.ShapeDtypeStruct((rows, d), cd),
+            jax.ShapeDtypeStruct((1, d, up_w.shape[2] + f_pad), cd))
+        R = row_bound(rows, (lo, hi), E, _V5E_TILING[0] if kernel else 8)
 
-    def routed(x, gates, down, *ups):      # ups: (gate, up) or (up,)
+    def hidden_rows(xs, down, ups, group_sizes, live):
+        """[m, d] rows sorted by expert -> their experts' outputs [m, d];
+        `live` [m, 1]: the rows inside the groups, None where all are."""
         if f_pad:
             ups = [jnp.pad(w, ((0, 0), (0, 0), (0, f_pad))) for w in ups]
             down = jnp.pad(down, ((0, 0), (0, f_pad), (0, 0)))
+
+        def alive(rows):
+            return rows if live is None else jnp.where(
+                live, rows, jnp.zeros_like(rows))
+
+        def matmul(lhs, rhs):
+            # zeros in AND out: the backward's rows behind the groups are as
+            # unwritten as the forward's, and `where` stops them both ways
+            return alive(grouped_matmul(alive(lhs), rhs, group_sizes))
+
+        pre = [matmul(xs, w) for w in ups]
+        if len(pre) == 2:
+            h = (jax.nn.silu(pre[0].astype(jnp.float32))
+                 * pre[1].astype(jnp.float32)).astype(cd)
+        else:
+            h = relu2(pre[0]).astype(cd)
+        return matmul(h, down)
+
+    def whole(ints, x, gates, down, *ups):     # ups: (gate, up) or (up,)
+        """Every one of the T x k rows."""
+        order, inverse, group_sizes = ints
+        live = (jnp.arange(rows) < group_sizes.sum())[:, None] \
+            if part else None
         with jax.named_scope("dispatch"):
             xs = _dispatch(x.astype(cd), order, inverse, top_k)   # [T*k, d]
         with jax.named_scope("experts"):
-            pre = [matmul(xs, w) for w in ups]
-            if len(pre) == 2:
-                h = (jax.nn.silu(pre[0].astype(jnp.float32))
-                     * pre[1].astype(jnp.float32)).astype(cd)
-            else:
-                h = relu2(pre[0]).astype(cd)
-            ys = matmul(h, down)                                 # [T*k, d]
+            ys = hidden_rows(xs, down, ups, group_sizes, live)   # [T*k, d]
         with jax.named_scope("combine"):
             yu = _undispatch(ys, order, inverse).reshape(T, top_k, d)
             return (yu.astype(jnp.float32) * gates[..., None]).sum(1)
 
-    # a share keeps nothing of its T x k rows for the backward (most of
-    # them idle: 1 in 16 of Nemotron's are live, and held they would be
-    # 1 GB a layer at 8 192 tokens): the backward gathers them again
+    def chunk(j, acc, ints, x, gates, down, *ups):
+        """Rows j R .. (j + 1) R of the sort, added to `acc` [T, d]: each
+        group's part of them; a row behind the live ones is a pair of an
+        absent expert (or none: the sort padded to whole chunks), zero going
+        in and coming out as in `whole`."""
+        order, group_sizes = ints
+        with jax.named_scope("dispatch"):
+            first = j * R
+            ends = jnp.cumsum(group_sizes)
+            cut = jnp.clip(jnp.stack([ends - group_sizes, ends]),
+                           first, first + R)
+            live = (first + jnp.arange(R) < ends[-1])[:, None]
+            pairs = jax.lax.dynamic_slice(order, (first,), (R,))
+            tok = pairs // top_k
+            xs = _rows_of(x.astype(cd), tok, T)                  # [R, d]
+        with jax.named_scope("experts"):
+            ys = hidden_rows(xs, down, ups, cut[1] - cut[0], live)
+        with jax.named_scope("combine"):
+            row_gates = gates.reshape(-1)[pairs]
+            return _add_rows(acc, ys.astype(jnp.float32)
+                             * row_gates[:, None], tok)
+
     ups = (up_w,) if gate_w is None else (gate_w, up_w)
-    out = (jax.checkpoint(routed) if part else routed)(x, gates, down_w, *ups)
+    ints, path = (order, inverse, group_sizes), None
+    if R < rows:
+        # as many chunks of R rows as the held pairs fill: one, in a step
+        # whose routing keeps to the bound
+        def chunks(ints):
+            return jnp.maximum(1, -(-ints[1].sum() // R))
+
+        ints = (jnp.pad(order, (0, -rows % R)), group_sizes)
+        out = _summed_chunks(chunk, chunks, (T, d))(
+            ints, x, gates, down_w, *ups)
+        n = chunks(ints)
+        path = jnp.stack([n == 1, n > 1]).astype(jnp.int32)
+    elif part:
+        # a share keeps nothing of its T x k rows for the backward (held,
+        # they would be 1 GB a layer at 8 192 tokens): it gathers them again
+        out = jax.checkpoint(whole)(ints, x, gates, down_w, *ups)
+        if bound:                          # its bound reaches all the rows
+            path = jnp.array([0, 1], jnp.int32)
+    else:
+        out = whole(ints, x, gates, down_w, *ups)
     if shared is not None:
         if len(shared) != len(ups) + 1:
             raise ValueError(
@@ -298,7 +459,8 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
                 hs = relu2(pre[0]).astype(cd)
             out = out + jnp.dot(hs, down_s,
                                 preferred_element_type=jnp.float32)
-    return out.astype(cd), logits, counts, (group_sizes if part else None)
+    return (out.astype(cd), logits, counts,
+            group_sizes if part else None, path)
 
 
 @register_op("moe_ffn")
@@ -326,7 +488,7 @@ def moe_ffn_kernel(ctx):
     held = None
     if ctx.attr("held_hi") is not None:
         held = (int(ctx.attr("held_lo")), int(ctx.attr("held_hi")))
-    out, logits, counts, held_pairs = moe_ffn(
+    out, logits, counts, held_pairs, row_path = moe_ffn(
         x.reshape(-1, x.shape[-1]), ctx.input("RouterW"), gate_w, up_w,
         down_w, int(ctx.attr("top_k")),
         bool(ctx.attr("norm_topk_prob", False)),
@@ -339,6 +501,8 @@ def moe_ffn_kernel(ctx):
     ctx.set_output("TokensPerExpert", counts)
     if held_pairs is not None:
         ctx.set_output("HeldPairs", held_pairs)
+    if row_path is not None:
+        ctx.set_output("RowPath", row_path)
 
 
 @register_op("moe_aux_loss")

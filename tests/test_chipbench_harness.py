@@ -1,9 +1,9 @@
 """The benchmark's own checks, in the tier-1 run: `chipbench/selftest.py`
-(no backend), the cases of `chipbench/tests/test_harness.py` (JAX on the
-CPU), and what PR 27 added for the olmoe-1b-7b configuration: its FLOPs
-arithmetic, its kernel's operations and bytes, its readers on hand-made run
-records, its copy of the plain reference against the tree's, and one CPU
-rehearsal of its cell through `chipbench/run.py`.
+(no backend), the cases of `chipbench/tests/` (JAX on the CPU), and what
+PR 27 added for the olmoe-1b-7b configuration: its FLOPs arithmetic, its
+kernel's operations and bytes, its readers on hand-made run records, its
+copy of the plain reference against the tree's, and one CPU rehearsal of its
+cell through `chipbench/run.py`.
 """
 
 import importlib.util
@@ -32,9 +32,10 @@ def _load(*parts):
 
 
 # the harness's own pytest cases, collected here under their own names
-globals().update({name: fn for name, fn in
-                  vars(_load("tests", "test_harness.py")).items()
-                  if name.startswith("test_")})
+for _cases in ("test_harness.py", "test_bounded_step_share.py"):
+    globals().update({name: fn for name, fn in
+                      vars(_load("tests", _cases)).items()
+                      if name.startswith("test_")})
 
 
 def test_selftest_passes():
@@ -997,14 +998,18 @@ def test_the_manifest_lists_the_glm_cell_and_its_metrics():
                     "glm.load_max_over_mean", "glm.attn_device_ms",
                     "glm.head_device_ms", "glm.opt_device_ms",
                     "glm.donated_gib", "glm.feed_produce_ms_per_step"]
-    assert mine == [m["name"] for m in manifest["per_layer"]][-len(mine):]
+    # appended in PR 37; PR 38 appended one metric of both shares' cells
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(mine) - 1:] == mine + ["moe.bounded_step_share"]
+    assert manifest["per_layer"][-1]["workloads"] == [NEMO + ".train-log10",
+                                                      cell]
     layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
     assert layers["mla.device_ms"] == layers["mla.assemble_ms"] \
         == "Latent attention"
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     # no reader that was there lists the new cell: their entries are untouched
-    assert not [m["name"] for m in manifest["per_layer"]
+    assert not [m["name"] for m in manifest["per_layer"][:-1]
                 if cell in m.get("workloads", ()) and m["name"] not in mine]
     with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
         traffic = json.load(f)

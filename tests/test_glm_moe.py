@@ -276,7 +276,9 @@ def test_swiglu_share_with_its_shared_expert_against_the_reference(lo, hi):
     cfg = _layer_config(16, lo, hi)
     w = jnp.asarray(_rng(4).randn(64, 16), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        out, _, counts, held = _share(p, lo, hi)
+        out, _, counts, held, path = _share(p, lo, hi)
+        # an eighth of the experts: 48 of the 192 rows, and the pairs fit
+        assert path is None if (lo, hi) == (0, 16) else list(path) == [1, 0]
         np.testing.assert_allclose(out, _whole(p, cfg, lo, hi), rtol=1e-4,
                                    atol=1e-5)
         assert int(counts.sum()) == 64 * 3 and counts.shape == (16,)
@@ -289,17 +291,66 @@ def test_swiglu_share_with_its_shared_expert_against_the_reference(lo, hi):
     assert not np.any(np.asarray(g["b"]))
 
 
+def _steered(p, lo, hi, tokens):
+    """The layer's inputs with the choice of the held experts in hand: the
+    first `tokens` tokens score every held expert at sigmoid(6) and choose
+    them all (hi - lo <= k), every other token scores them at sigmoid(-6)
+    and chooses none: the live pairs are tokens x (hi - lo)."""
+    sign = jnp.where(jnp.arange(p["x"].shape[0]) < tokens, 1.0, -1.0)
+    wr = p["wr"].at[:, lo:hi].set(0.0).at[0, lo:hi].set(6.0)
+    return dict(p, x=p["x"].at[:, 0].set(sign), wr=wr)
+
+
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared_expert", "no_shared_expert"])
+@pytest.mark.parametrize("routing", ["fits", "exactly_R", "spills"])
+def test_a_swiglu_share_on_a_bound_of_its_rows_against_the_reference(
+        routing, shared):
+    """SwiGLU experts, three stacks, 2 of 16 held: R = 48 of the 192 rows.
+    Values, every input's gradient (the gates' path is the router's) and the
+    path output, in one chunk of R rows (routing as it falls; 24 tokens
+    steered to both held experts: 48 live pairs, the bound itself) and in
+    two (25 tokens: 50)."""
+    lo, hi = 8, 10
+    p = _layer_inputs()
+    assert moe_ops.row_bound(64 * 3, (lo, hi), 16, 8) == 48
+    if routing != "fits":
+        p = _steered(p, lo, hi, 24 if routing == "exactly_R" else 25)
+    if not shared:      # the reference always has one: a zero one adds 0
+        p = dict(p, up_s=jnp.zeros_like(p["up_s"]))
+    cfg = _layer_config(16, lo, hi)
+    w = jnp.asarray(_rng(4).randn(64, 16), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out, _, counts, held, path = _share(p, lo, hi, shared=shared)
+        np.testing.assert_allclose(out, _whole(p, cfg, lo, hi), rtol=1e-4,
+                                   atol=1e-5)
+        g = jax.grad(
+            lambda p: (_share(p, lo, hi, shared=shared)[0] * w).sum())(p)
+        r = jax.grad(lambda p: (_whole(p, cfg, lo, hi) * w).sum())(p)
+    np.testing.assert_array_equal(held, counts[lo:hi])
+    live = {"fits": int(held.sum()), "exactly_R": 48, "spills": 50}[routing]
+    assert int(held.sum()) == live and (live <= 48) == (routing != "spills")
+    np.testing.assert_array_equal(path, [1, 0] if live <= 48 else [0, 1])
+    names = ("x", "wr", "gate", "up", "down") + (
+        ("gate_s", "up_s", "down_s") * shared)
+    for name in names:
+        assert _rel(g[name], r[name]) < 1e-4, (name, _rel(g[name], r[name]))
+    assert float(jnp.abs(g["wr"][:, lo:hi]).max()) > 0     # the gates' path
+
+
 def test_the_eight_shares_add_up():
-    """E 16 as 8 shares of 2: every share's routed part, plus the SwiGLU
-    shared expert counted once, is the uncut layer of the reference, and the
-    held pairs are all the pairs."""
+    """E 16 as 8 shares of 2, each in one chunk of 48 of the 192 rows: every
+    share's routed part, plus the SwiGLU shared expert counted once, is the
+    uncut layer of the reference, and the held pairs are all the pairs."""
     p = _layer_inputs()
     with jax.default_matmul_precision("highest"):
         whole = _whole(p, _layer_config(16, 0, 16))
         total, pairs = 0.0, 0
         for lo in range(0, 16, 2):
-            out, _, counts, held = _share(p, lo, lo + 2, shared=(lo == 0))
+            out, _, counts, held, path = _share(p, lo, lo + 2,
+                                                shared=(lo == 0))
             total, pairs = total + out, pairs + int(held.sum())
+            np.testing.assert_array_equal(path, [1, 0])
     np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-5)
     assert pairs == 64 * 3 == int(counts.sum())
 
@@ -518,7 +569,7 @@ def test_configs_glm_moe_trains_at_tiny_sizes(amp):
     m = _load_config().get_model(
         layers=3, first_k_dense=1, dim=48, heads=3, q_rank=24, kv_rank=16,
         nope_dim=12, rope_dim=4, v_dim=16, dense_dim=80, experts=16,
-        held_experts=(0, 4), experts_per_token=3, expert_dim=24,
+        held_experts=(0, 2), experts_per_token=3, expert_dim=24,
         shared_expert_dim=24, seqlen=160, vocab=64, model_layers=3, batch=2,
         steps=30, seed=3, amp=amp)
     costs = []
@@ -536,8 +587,13 @@ def test_configs_glm_moe_trains_at_tiny_sizes(amp):
         every = [reg.counter_value("pt_moe_expert_tokens_total", labels={
             "layer": layer, "expert": e}) for e in range(16)]
         held = [reg.counter_value("pt_moe_held_pairs_total", labels={
-            "layer": layer, "expert": e}) for e in range(4)]
+            "layer": layer, "expert": e}) for e in range(2)]
         assert sum(every) == 30 * 2 * 160 * 3, every
-        assert held == every[:4] and 0 < sum(held) < sum(every)
+        assert held == every[:2] and 0 < sum(held) < sum(every)
+        # an eighth of the experts, as the configuration's 8 of 64: each
+        # step ran one chunk of its rows (240 of 960) or more
+        bounded, whole = (reg.counter_value("pt_moe_row_path_total", labels={
+            "layer": layer, "path": path}) for path in (0, 1))
+        assert bounded + whole == 30 and bounded > 0
     assert reg.counter_value("pt_latent_attention_dispatch_total",
                              labels={"path": "expanded"}) >= 3

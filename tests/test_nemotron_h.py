@@ -294,7 +294,9 @@ def test_held_experts_against_the_reference(lo, hi):
                             p["down_s"])[0]
 
     with jax.default_matmul_precision("highest"):
-        out, logits, counts, held = _share(p, lo, hi)
+        out, logits, counts, held, path = _share(p, lo, hi)
+        # a quarter of the experts: chunks of 96 of the 192 rows
+        assert (path is None) == ((lo, hi) == (0, 16))
         np.testing.assert_allclose(out, want(p), rtol=1e-4, atol=1e-5)
         assert int(counts.sum()) == 64 * 3 and counts.shape == (16,)
         if (lo, hi) == (0, 16):
@@ -308,29 +310,126 @@ def test_held_experts_against_the_reference(lo, hi):
     assert not np.any(np.asarray(g["b"]))
 
 
-def test_the_shares_add_up():
-    """E 16 as 4 shares of 4: every share's routed part, plus the shared
-    expert counted once, is the uncut layer, and the held pairs are all the
-    pairs."""
-    p = _layer_inputs()
+def _steered(p, lo, hi, tokens):
+    """The layer's inputs with the choice of the held experts in hand: the
+    first `tokens` tokens score every held expert at sigmoid(6) and choose
+    them all (hi - lo <= k), every other token scores them at sigmoid(-6)
+    and chooses none: the live pairs are tokens x (hi - lo)."""
+    sign = jnp.where(jnp.arange(p["x"].shape[0]) < tokens, 1.0, -1.0)
+    wr = p["wr"].at[:, lo:hi].set(0.0).at[0, lo:hi].set(6.0)
+    return dict(p, x=p["x"].at[:, 0].set(sign), wr=wr)
+
+
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared_expert", "no_shared_expert"])
+@pytest.mark.parametrize("held,routing,live", [
+    ((4, 6), "fits", None), ((4, 6), "exactly_R", 24),
+    ((4, 6), "spills", 26), ((4, 6), "three_chunks", 50),
+    ((4, 7), "ragged_last_chunk", 162)],
+    ids=["fits", "exactly_R", "spills", "three_chunks", "ragged_last_chunk"])
+def test_a_share_on_a_bound_of_its_rows_against_the_reference(
+        held, routing, live, shared):
+    """relu^2 experts, two stacks, 2 of 32 held: R = 24 of the 192 rows.
+    Values, every input's gradient (the gates' path is the router's) and the
+    path output, in one chunk of R rows (routing as it falls: 10 live pairs;
+    12 tokens steered to both held experts: 24, the bound itself) and in
+    more (13 tokens: 26 live pairs, two chunks; 25: 50, three). 3 of 32
+    held: R = 40, the sort is padded to five chunks (200), and 54 tokens'
+    162 pairs reach into the last."""
+    lo, hi = held
+    p = _layer_inputs(E=32)
+    R = moe_ops.row_bound(64 * 3, held, 32, 8)
+    assert R == {2: 24, 3: 40}[hi - lo]
+    if live:
+        p = _steered(p, lo, hi, live // (hi - lo))
+    if not shared:      # the reference always has one: a zero one adds 0
+        p = dict(p, up_s=jnp.zeros_like(p["up_s"]))
+    cfg = _layer_config(32, lo, hi)
+    w = jnp.asarray(_rng(4).randn(64, 16), jnp.float32)
+
+    def got(p):
+        return _share(p, lo, hi, shared=shared)
+
+    def want(p):
+        return ref._experts(cfg, p["x"], p["wr"], p["up"][lo:hi],
+                            p["down"][lo:hi], p["b"], p["up_s"],
+                            p["down_s"])[0]
+
     with jax.default_matmul_precision("highest"):
-        whole = ref._experts(_layer_config(16, 0, 16), p["x"], p["wr"],
+        out, _, counts, pairs, path = got(p)
+        np.testing.assert_allclose(out, want(p), rtol=1e-4, atol=1e-5)
+        g = jax.grad(lambda p: (got(p)[0] * w).sum())(p)
+        r = jax.grad(lambda p: (want(p) * w).sum())(p)
+    np.testing.assert_array_equal(pairs, counts[lo:hi])
+    live = live or int(pairs.sum())
+    assert int(pairs.sum()) == live and (live <= R) == (
+        routing in ("fits", "exactly_R"))
+    np.testing.assert_array_equal(path, [1, 0] if live <= R else [0, 1])
+    for name in ("x", "wr", "up", "down") + (("up_s", "down_s") * shared):
+        assert _rel(g[name], r[name]) < 1e-4, (name, _rel(g[name], r[name]))
+    assert float(jnp.abs(g["wr"][:, lo:hi]).max()) > 0     # the gates' path
+
+
+def test_the_shares_add_up():
+    """E 32 as 8 shares of 4, each in one chunk of 48 of the 192 rows: every
+    share's routed part, plus the shared expert counted once, is the uncut
+    layer, and the held pairs are all the pairs."""
+    p = _layer_inputs(E=32)
+    with jax.default_matmul_precision("highest"):
+        whole = ref._experts(_layer_config(32, 0, 32), p["x"], p["wr"],
                              p["up"], p["down"], p["b"], p["up_s"],
                              p["down_s"])[0]
         total, pairs = 0.0, 0
-        for lo in range(0, 16, 4):
-            out, _, counts, held = _share(p, lo, lo + 4, shared=(lo == 0))
+        for lo in range(0, 32, 4):
+            out, _, counts, held, path = _share(p, lo, lo + 4,
+                                                shared=(lo == 0))
             total, pairs = total + out, pairs + int(held.sum())
+            np.testing.assert_array_equal(path, [1, 0])
     np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-5)
     assert pairs == 64 * 3 == int(counts.sum())
 
 
-def test_rows_behind_the_groups_are_zero_both_ways(monkeypatch):
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("E,held,chunked", [
+    (16, None, False), (16, (4, 12), False), (16, (0, 4), True),
+    (32, (0, 4), True)], ids=["all_held", "a_half", "a_quarter", "an_eighth"])
+def test_only_a_share_under_a_half_traces_a_loop_over_chunks(E, held,
+                                                             chunked):
+    """All experts held, or half of them or more: the bound is all the
+    rows, a static branch, and the traced op has no `while` and no `cond`
+    (forward or backward) and gives no path output. A smaller share runs
+    its chunks in a `while`, forward and backward, and no `cond`."""
+    p = _layer_inputs(E=E)
+    lo, hi = held or (0, E)
+
+    def share(p):
+        return moe_ops.moe_ffn(
+            p["x"], p["wr"], None, p["up"][lo:hi], p["down"][lo:hi], 3, True,
+            scoring="sigmoid", router_bias=p["b"], gate_scale=2.5, held=held)
+
+    assert (share(p)[4] is not None) == chunked
+    traced = jax.make_jaxpr(jax.grad(lambda p: share(p)[0].sum()))(p)
+    found = list(_primitives(traced.jaxpr))
+    assert found.count("while") == (2 if chunked else 0)
+    assert "cond" not in found
+
+
+@pytest.mark.parametrize("E,hi", [(16, 12), (32, 6)],
+                         ids=["all_rows", "bounded_rows"])
+def test_rows_behind_the_groups_are_zero_both_ways(monkeypatch, E, hi):
     """A kernel leaves the rows behind the held groups unwritten: with the
     grouped matmul made to write NaN there, forward AND backward, the layer's
-    values and gradients are what they were."""
-    p = _layer_inputs()
-    clean = jax.value_and_grad(lambda p: (_share(p, 4, 8)[0] ** 2).sum())(p)
+    values and gradients are what they were; on all T x k rows (half of
+    the experts) and on the R rows of a smaller share's chunk."""
+    p = _layer_inputs(E=E)
+    assert (_share(p, 4, hi)[4] is not None) == (E == 32)
+    clean = jax.value_and_grad(lambda p: (_share(p, 4, hi)[0] ** 2).sum())(p)
 
     @jax.custom_vjp
     def dirty(lhs, rhs, sizes):
@@ -355,7 +454,7 @@ def test_rows_behind_the_groups_are_zero_both_ways(monkeypatch):
     dirty.defvjp(fwd, bwd)
     monkeypatch.setattr(moe_ops, "grouped_matmul", dirty)
     value, grads = jax.value_and_grad(
-        lambda p: (_share(p, 4, 8)[0] ** 2).sum())(p)
+        lambda p: (_share(p, 4, hi)[0] ** 2).sum())(p)
     np.testing.assert_allclose(value, clean[0], rtol=1e-6)
     for name in ("x", "wr", "up", "down"):
         assert np.all(np.isfinite(grads[name])), name
@@ -388,9 +487,12 @@ def test_olmoe_arguments_append_the_op_they_did():
     shapes = {p.name: tuple(p.shape) for p in prog.parameters()}
     assert shapes["nemo.up"] == (2, 16, 8) and shapes["nemo.router"] == (16, 8)
     assert not prog.global_block().var("nemo.router_bias").trainable
+    # 2 of 8 held: under half of the experts, so its rows run in chunks
+    assert sorted(new.outputs) == ["HeldPairs", "Out", "RouterLogits",
+                                   "RowPath", "TokensPerExpert"]
     assert [s["counter"] for s in prog.step_statistics] == [
         "pt_moe_expert_tokens_total", "pt_moe_expert_tokens_total",
-        "pt_moe_held_pairs_total"]
+        "pt_moe_held_pairs_total", "pt_moe_row_path_total"]
 
 
 # ------------------------------ the whole model against the plain reference ---
@@ -604,5 +706,10 @@ def test_configs_nemotron_h_trains_at_tiny_sizes(amp):
             "layer": layer, "expert": e}) for e in range(4)]
         assert sum(every) == 30 * 2 * 160 * 3, every
         assert held == every[:4] and 0 < sum(held) < sum(every)
+        # a quarter of the experts: each step ran one chunk of its rows
+        # (480 of 960) or both
+        one, more = (reg.counter_value("pt_moe_row_path_total", labels={
+            "layer": layer, "path": path}) for path in (0, 1))
+        assert one + more == 30 and one > 0
     assert reg.counter_value("pt_ssm_scan_dispatch_total",
                              labels={"path": "xla_chunked"}) >= 1

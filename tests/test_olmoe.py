@@ -98,7 +98,7 @@ def _moe_inputs(tokens=48, d=16, f=24, E=8, seed=0):
 
 def test_moe_ffn_against_every_expert_on_every_token():
     args = _moe_inputs()
-    out, logits, counts, _ = moe_ops.moe_ffn(*args, top_k=2)
+    out, logits, counts, *_ = moe_ops.moe_ffn(*args, top_k=2)
     # ties absent by construction: continuous random logits
     assert np.unique(np.asarray(logits), axis=-1).shape == logits.shape
     np.testing.assert_allclose(out, _every_expert_masked(*args, 2),
@@ -133,7 +133,7 @@ def test_no_token_is_dropped_when_one_expert_takes_everything():
     wr = jnp.zeros_like(wr).at[:, 5].set(0.0)
     x = jnp.abs(x)
     wr = wr.at[:, 5].set(10.0).at[:, 2].set(5.0)   # everyone: 5 then 2
-    out, _, counts, _ = moe_ops.moe_ffn(x, wr, wg, wu, wd, top_k=2)
+    out, _, counts, *_ = moe_ops.moe_ffn(x, wr, wg, wu, wd, top_k=2)
     want = np.zeros(8, np.int32)
     want[5] = want[2] = 128
     np.testing.assert_array_equal(counts, want)

@@ -502,6 +502,60 @@ def test_glm_moe_step_program_fits_one_chip(one_chip, compiled_mode,
             < 15.0 * 2**30)
 
 
+def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
+        one_chip, compiled_mode, monkeypatch):
+    """The hybrid's routed layer as one chip of 16 holds it (T 8192, top 6,
+    d 2688, f 1856, 8 of 128 experts), forward and backward: every grouped
+    matmul runs at m = 6 144 rows, a chunk, inside one of the two loops
+    over chunks, and as often as the whole-rows share launched them (2
+    forward; 2 again, 2 `gmm` and 2 `tgmm` backward), so the program holds
+    no second copy of the kernels; no float array of 49 152 rows exists
+    anywhere; and the step compiles for the chip with the loops and the
+    kernels in it and no conditional."""
+    from paddle_tpu.ops import moe_ops
+
+    monkeypatch.setattr(moe_ops, "gmm_eligible", moe_ops._shapes_gmm_ok)
+    T, k, d, f, E, held = 8192, 6, 2688, 1856, 128, 8
+    assert moe_ops.row_bound(T * k, (0, held), E, 256) == 6144
+
+    def step(x, wr, up, down):
+        def cost(x, wr, up, down):
+            out, _, _, pairs, path = moe_ops.moe_ffn(
+                x, wr, None, up, down, k, True, scoring="sigmoid",
+                gate_scale=2.5, held=(0, held))
+            return out.astype(F32).sum(), (pairs, path)
+
+        return jax.value_and_grad(cost, (0, 1, 2, 3), has_aux=True)(
+            x, wr, up, down)
+
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((T, d), F32), ((d, E), F32), ((held, d, f), BF16),
+        ((held, f, d), BF16))]
+    kernels, whole_rows = [], []
+
+    def walk(jaxpr, loops):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                kernels.append((loops, {
+                    v.aval.shape[0] for v in eqn.invars
+                    if v.aval.ndim == 2 and v.aval.shape[0] in (6144, T * k)}))
+            whole_rows.extend(
+                (eqn.primitive.name, v.aval) for v in eqn.outvars
+                if getattr(v.aval, "ndim", 0) == 2
+                and v.aval.shape[0] == T * k
+                and jnp.issubdtype(v.aval.dtype, jnp.floating))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, loops + (eqn.primitive.name == "while"))
+
+    walk(jax.make_jaxpr(step)(*args).jaxpr, 0)
+    assert kernels == [(1, {6144})] * 8
+    assert not whole_rows, whole_rows
+    text = jax.jit(step).lower(*args).compile().as_text()
+    assert len(re.findall(r" while\(", text)) >= 2     # forward, backward
+    assert " conditional(" not in text
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+
+
 def _written_arrays(hlo_text):
     """(opcode, dtype, elements, in_fusion_body) of every array that an
     instruction of the optimized HLO produces. What an instruction of a
