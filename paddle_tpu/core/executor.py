@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import registry
+from . import provenance, registry
 from .. import profiler
 from ..flags import FLAGS
 from .lod import LoDArray
@@ -392,6 +392,13 @@ def donation_totals() -> Dict[str, int]:
     return totals
 
 
+def provenance_tables() -> List[dict]:
+    """The live executors' `provenance.table`s, cut to their `published` rows,
+    one a step program compiled while `FLAGS.enable_timers` was on (read by
+    the obs.metrics collector; no device access)."""
+    return [t for exe in list(_executors) for t in exe._provenance]
+
+
 class Executor:
     """Reference API: fluid executor.py:71 `Executor(place).run(program,
 
@@ -426,6 +433,9 @@ class Executor:
         self.cache_stats: Dict[str, int] = {"hits": 0, "misses": 0}
         # one record per compiled step program, written when it traces
         self._donation: List[Dict[str, int]] = []
+        # one `provenance.table`, its published rows, per step program compiled
+        # with timers on
+        self._provenance: List[dict] = []
         _executors.add(self)
 
     @property
@@ -476,6 +486,19 @@ class Executor:
         donated state its donated argument."""
         return jax.jit(self._raw_step(program, fetch_names),
                        donate_argnums=(0,))
+
+    def _read_provenance(self, fn, *args) -> None:
+        """A traced run's record of a freshly compiled step program: which
+        program op each instruction of its optimized HLO belongs to
+        (`core/provenance.py`), for the readers of a device trace. One more
+        lowering and one more read of the compile cache, before the first
+        call, while the arguments are alive; `fn` itself is called as ever."""
+        with self._device_context(), self._trace_context():
+            text = fn.lower(*args).compile().as_text()
+        table = provenance.table(text)
+        # kept for the process's life: only what a trace cannot name itself
+        self._provenance.append(
+            dict(table, rows=provenance.published(table["rows"])))
 
     def _device_context(self):
         return jax.default_device(self.place.device)
@@ -534,6 +557,13 @@ class Executor:
                     tuple(fetch_names),
                     tuple(persist_names),
                 )
+            state = {n: scope.get(n) for n in persist_names}
+            donated, kept = self._split_state(program, state)
+            kept = self._place_kept(program, scope, kept)
+            seed = jnp.asarray(self._draw_seed(program), dtype=jnp.uint32)
+            donated, feed, seed = self._place_inputs(
+                program, donated, feed, seed)
+
             cached = self._cache.get(key)
             if cached is None:
                 self.cache_stats["misses"] += 1
@@ -542,16 +572,11 @@ class Executor:
                 # id(program), which may be recycled if the program were
                 # garbage collected
                 self._cache[key] = (program, fn)
+                if FLAGS.enable_timers:
+                    self._read_provenance(fn, donated, kept, feed, seed)
             else:
                 self.cache_stats["hits"] += 1
                 fn = cached[1]
-
-            state = {n: scope.get(n) for n in persist_names}
-            donated, kept = self._split_state(program, state)
-            kept = self._place_kept(program, scope, kept)
-            seed = jnp.asarray(self._draw_seed(program), dtype=jnp.uint32)
-            donated, feed, seed = self._place_inputs(
-                program, donated, feed, seed)
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
             fetches, new_state, extras = fn(donated, kept, feed, seed)
@@ -785,17 +810,6 @@ class Executor:
                     tuple(fetch_names),
                     tuple(persist_names),
                 )
-            cached = self._cache.get(key)
-            if cached is None:
-                self.cache_stats["misses"] += 1
-                fn = self._build_window(
-                    program, fetch_names,
-                    skip_nonfinite, acc_state is not None)
-                self._cache[key] = (program, fn)
-            else:
-                self.cache_stats["hits"] += 1
-                fn = cached[1]
-
             state = {n: scope.get(n) for n in persist_names}
             donated, kept = self._split_state(program, state)
             kept = self._place_kept(program, scope, kept)
@@ -813,6 +827,20 @@ class Executor:
             seeds = jnp.asarray(
                 [self._draw_seed(program) for _ in range(k_steps)],
                 dtype=jnp.uint32)
+
+            cached = self._cache.get(key)
+            if cached is None:
+                self.cache_stats["misses"] += 1
+                fn = self._build_window(
+                    program, fetch_names,
+                    skip_nonfinite, acc_state is not None)
+                self._cache[key] = (program, fn)
+                if FLAGS.enable_timers:
+                    self._read_provenance(fn, donated, kept, feed, seeds,
+                                          acc_state)
+            else:
+                self.cache_stats["hits"] += 1
+                fn = cached[1]
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
             ys, new_state, acc_out, rebound_kept, created = fn(

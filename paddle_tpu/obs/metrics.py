@@ -452,6 +452,36 @@ def _executor_families():
     ]
 
 
+def _provenance_families():
+    """Which program op the instructions of the compiled step programs belong
+    to where a device trace cannot say (core/provenance.py: XLA's own copies,
+    slices and unrooted fusions, and the fusions that carry another op's
+    members, an Adam update in its gradient's GEMM): one series an
+    (instruction, scope), its value the scope's listing weight. Only the
+    programs compiled while `FLAGS.enable_timers` was on have a table, so an
+    untraced process renders no such family."""
+    import sys
+
+    executor = sys.modules.get("paddle_tpu.core.executor")
+    tables = executor.provenance_tables() if executor is not None else []
+    if not tables:
+        return []
+    samples = {}
+    for table in tables:
+        for row in table["rows"]:
+            for scope, via, weight in row["scopes"]:
+                samples[(table["program"], row["instruction"], scope, via)] = \
+                    weight
+    return [
+        ("pt_executor_instruction_scope", "gauge",
+         "listing weight of a program op's scope in a compiled step "
+         "program's instruction (via: root, member, fused, consumer, "
+         "producer, argument, caller)",
+         [({"program": p, "instruction": i, "scope": s, "via": v}, w)
+          for (p, i, s, v), w in samples.items()]),
+    ]
+
+
 def _statset_families():
     """The global StatSet rides the unified render even though it is
     not attach_stat_set'ed (reset_metrics would drop the attachment;
@@ -478,4 +508,5 @@ _REGISTRY.add_collector(_trace_families)
 _REGISTRY.add_collector(_tune_families)
 _REGISTRY.add_collector(_quant_families)
 _REGISTRY.add_collector(_executor_families)
+_REGISTRY.add_collector(_provenance_families)
 _REGISTRY.add_collector(_statset_families)

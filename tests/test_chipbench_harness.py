@@ -998,18 +998,23 @@ def test_the_manifest_lists_the_glm_cell_and_its_metrics():
                     "glm.load_max_over_mean", "glm.attn_device_ms",
                     "glm.head_device_ms", "glm.opt_device_ms",
                     "glm.donated_gib", "glm.feed_produce_ms_per_step"]
-    # appended in PR 37; PR 38 appended one metric of both shares' cells
+    # appended in PR 37; PR 38 appended one metric of both shares' cells,
+    # PR 40 three of every cell (no `workloads` list)
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(mine) - 1:] == mine + ["moe.bounded_step_share"]
-    assert manifest["per_layer"][-1]["workloads"] == [NEMO + ".train-log10",
+    every_cell = ["step.xla_inserted_ms", "step.unnamed_ms",
+                  "opt.carried_device_ms"]
+    assert names[-len(mine) - 4:] == mine + ["moe.bounded_step_share"] \
+        + every_cell
+    assert manifest["per_layer"][-4]["workloads"] == [NEMO + ".train-log10",
                                                       cell]
+    assert not any("workloads" in m for m in manifest["per_layer"][-3:])
     layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
     assert layers["mla.device_ms"] == layers["mla.assemble_ms"] \
         == "Latent attention"
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     # no reader that was there lists the new cell: their entries are untouched
-    assert not [m["name"] for m in manifest["per_layer"][:-1]
+    assert not [m["name"] for m in manifest["per_layer"][:-4]
                 if cell in m.get("workloads", ()) and m["name"] not in mine]
     with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
         traffic = json.load(f)
