@@ -18,36 +18,68 @@ masked [Q, Q] matmul (the "attention" form, decays as the mask's weights),
 each chunk's contribution to the state is one matmul, and only the T / Q
 chunk states go through a recurrence. The decays (cumsum(dt A), exp) and
 the carried state are float32; the matmuls take the compute dtype (bf16
-under amp) and accumulate in float32. The backward pass is JAX's
-differentiation of this form under `jax.checkpoint` (in the mixer: conv,
-scan and gated norm as one): the backward keeps the inputs and recomputes
-the [chunks, H, Q, Q] decay and score blocks instead of holding them (268
-MB a layer in float32 at T 8192, H 64). The oracle in the tests is the
+under amp) and accumulate in float32. The oracle in the tests is the
 recurrence above, token by token (`tests/nemotron_h_reference.py`).
 
-No Pallas kernel here: XLA runs the einsums (PERF.md names what a kernel
-would save). `pt_ssm_scan_dispatch_total{path}` counts one per op traced.
+Two formulations of that one form, chosen when the op is traced and counted
+in `pt_ssm_scan_dispatch_total{path}` (`ssd_scan_packed`; no flag, no
+attribute):
+
+- `pallas_chunked`: Pallas kernels (below) whose chunk state lives in VMEM
+  across a sequential chunk axis: one forward, one backward behind a
+  `jax.custom_vjp`. Nothing of [chunks, .., Q, Q] or [chunks, .., P, N] shape
+  goes to HBM in the forward; a mixer's forward reads x, B, C and what
+  depends on dt and writes y. Taken on the TPU backend, outside a mesh (a
+  bare `pallas_call` cannot be partitioned: ops/mesh_dispatch.py), for whole
+  chunks of a multiple of 128 tokens, a state size of whole lane tiles and a
+  group of R x P channels of whole lane tiles (`_shapes_scan_ok`).
+- `xla_chunked`: four einsums and a `lax.scan` over the chunk states, XLA's
+  (`_ssd_einsums`): every other case, exactly: the CPU, a mesh, the tests'
+  tiny configuration at chunk 16, a ragged tail (padded with dt = 0). Every
+  intermediate between the einsums is an HBM array (1.9 GB a mixer forward
+  at T 8192, H 64 where the mathematics needs 0.27: PERF.md section 6, PR
+  41), and its backward is JAX's differentiation of that.
+
+Both round where the other does (dt, cumsum(dt A), every exp and the state
+float32; `m`, `x * to_end` and the state as C reads it in the compute
+dtype), so on the chip the kernels' y is the einsums' to the bit.
+
+What the backward keeps and recomputes: `mamba2_mixer` puts conv, scan and
+gated norm under one `jax.checkpoint`, so across the step it keeps z, xBC,
+dt and the small per-head vectors and nothing of the scan. Inside the
+checkpoint's backward the differentiated forward kernel runs once more and
+also writes the state each chunk STARTS from ([T / Q, H P, N] float32, 134
+MB a mixer at T 8192, one transient array that lives until the backward
+kernel has read it); the backward kernel recomputes a chunk's [Q, Q] blocks
+in registers from x, B, C and the dt forms and carries the state's
+cotangent in VMEM from the last chunk down.
 """
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import amp
 from ..core.registry import register_op
 
 CHUNK = 128
+_LANES = 128
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 
-def ssd_chunked_scan(x, dt, A, Bm, Cm, D, chunk: int = CHUNK):
-    """x [B, T, H, P] (compute dtype), dt [B, T, H] float32 and positive, A
-    [H] negative, Bm / Cm [B, T, G, N] (compute dtype), D [H] -> y [B, T, H,
-    P] float32. Any T: a tail shorter than a chunk is padded with dt = 0.
-    Differentiated as it stands it keeps its [chunks, H, Q, Q] blocks for
-    the backward; `mamba2_mixer` puts it under `jax.checkpoint`."""
+def _ssd_einsums(x, dt, A, Bm, Cm, D, chunk: int):
+    """The chunked form as four einsums and a `lax.scan` over the chunk
+    states, all XLA's: the exact fallback of `ssd_chunked_scan`, any shape,
+    any backend. Differentiated as it stands it keeps its [chunks, H, Q, Q]
+    blocks for the backward; `mamba2_mixer` puts it under `jax.checkpoint`."""
     Bsz, T, H, P = x.shape
     G, N = Bm.shape[2:]
     R = H // G                                  # heads a group
@@ -99,6 +131,420 @@ def ssd_chunked_scan(x, dt, A, Bm, Cm, D, chunk: int = CHUNK):
     return y.reshape(Bsz, T + pad, H, P)[:, :T]
 
 
+# ------------------------------------------------------------------ kernels
+# The same form with the chunk states in VMEM. A grid step is one (batch,
+# group, chunk): the group's R heads share B and C, so C B^T is formed once
+# a step and the state of the R heads is one [R P, N] float32 scratch that
+# the sequential chunk axis carries (the forward from chunk 0 up, the
+# backward's cotangent from the last chunk down). x, B and C are column
+# ranges of the ONE packed [B, T, H P + 2 G N] array the conv writes (group
+# g's x R P lanes at g R P, its B N lanes at H P + g N, its C at H P + G N +
+# g N): three BlockSpecs over the same operand, no slice or transpose in HBM.
+#
+# What depends on dt alone is [T, H]-sized and XLA computes it, with the
+# einsum form's own expressions (so its rounding), in the two layouts the
+# kernels read (`_small_forms`): per group a COLUMN form [T, 128] (token in
+# sublanes; lanes 0..R-1 cumsum(dt A), R..2R-1 its exp, 2R..3R-1 exp(last -
+# cum) dt; HBM's tiles pad a narrower minor dimension to 128 lanes anyway)
+# and a ROW form [2 R, T] (token in lanes: cum, dt). The [Q, Q] decay block
+# of a head is exp(column - row) in registers. Both forms are operands of
+# the `custom_vjp`, so their cotangents leave the backward kernel in the
+# same layouts and XLA's differentiation of `_small_forms` gives d dt
+# (through the input weight and through the decays' cumsum) and dA.
+#
+# Two heads of P = 64 share a lane tile: a head's [Q, Q] block times the
+# tile's x gives its own lanes right and the neighbour's wrong, and a lane
+# select keeps the right ones (as ops/flash_ops.py's packed kernels do).
+
+
+class ScanGeometry(NamedTuple):
+    """Static shape of a scan: H heads of P channels in G groups, state
+    size N, chunk Q."""
+    H: int
+    P: int
+    G: int
+    N: int
+    Q: int
+
+    @property
+    def R(self) -> int:
+        return self.H // self.G
+
+
+def _shapes_scan_ok(geom: ScanGeometry, T: int, dtype) -> bool:
+    """Backend-independent shape rules of the kernels (separately testable):
+    whole chunks of a multiple of 128 tokens, a state size of whole lane
+    tiles that the packed array's B and C ranges start on, a group's R x P
+    channels whole lane tiles with a head either whole tiles or a divisor of
+    one, and the three per-head vectors of the column form in one tile."""
+    H, P, G, N, Q = geom
+    R = H // G
+    return (jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and H % G == 0 and T % Q == 0 and Q % _LANES == 0
+            and N % _LANES == 0 and (H * P) % N == 0
+            and (R * P) % _LANES == 0
+            and (P % _LANES == 0 or _LANES % P == 0) and 3 * R <= _LANES)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def scan_kernels_eligible(geom: ScanGeometry, T: int, dtype) -> bool:
+    """The kernels take the scan on the TPU backend, outside a mesh (a bare
+    `pallas_call` cannot be partitioned: ops/mesh_dispatch.py), at shapes
+    `_shapes_scan_ok` admits."""
+    from . import mesh_dispatch
+
+    return (_on_tpu() and mesh_dispatch.current() is None
+            and _shapes_scan_ok(geom, T, dtype))
+
+
+def _small_forms(dt, A, geom: ScanGeometry):
+    """dt [B, T, H] float32, A [H] -> (col [B, G, T, 128], row [B, G, 2 R,
+    T]) float32, as the comment above lays them out. The arithmetic is the
+    einsum form's, on [B, chunks, Q, H] arrays (with the groups split off,
+    minor dimensions of R x R, XLA's fusion around the cumsum took 1.9 ms a
+    mixer on the chip where this takes 0.4: PERF.md section 6, PR 41)."""
+    _, _, G, _, Q = geom
+    R = geom.R
+    Bsz, T, H = dt.shape
+    dt = dt.astype(jnp.float32).reshape(Bsz, T // Q, Q, H)
+    cum = jnp.cumsum(dt * A.astype(jnp.float32), axis=2)
+    to_end = jnp.exp(cum[:, :, -1:] - cum) * dt
+
+    def by_group(*forms):       # [B, T, forms, G, R], a form's heads together
+        return jnp.stack(forms, axis=3).reshape(Bsz, T, len(forms), G, R)
+
+    col = by_group(cum, jnp.exp(cum), to_end).transpose(0, 3, 1, 2, 4)
+    col = jnp.pad(col.reshape(Bsz, G, T, 3 * R),
+                  ((0, 0),) * 3 + ((0, _LANES - 3 * R),))
+    row = by_group(cum, dt).transpose(0, 3, 2, 4, 1)
+    return col, row.reshape(Bsz, G, 2 * R, T)
+
+
+def _lane_col(v, k: int, width: int):
+    """[rows, width]: column k of v in every lane."""
+    return jnp.broadcast_to(v[:, k:k + 1], (v.shape[0], width))
+
+
+def _at_last(col, k: int, width: int):
+    """[1, width]: column k of the column form at the chunk's last token, in
+    every lane: a lane broadcast of the last sublane tile and a masked sum
+    down it (Mosaic has no broadcast of one element both ways, and folds a
+    plain slice of the broadcast into one)."""
+    tail = _lane_col(col[-8:], k, width)
+    last = jax.lax.broadcasted_iota(jnp.int32, tail.shape, 0) == 7
+    return jnp.sum(jnp.where(last, tail, 0.0), axis=0, keepdims=True)
+
+
+def _heads_a_tile(P: int) -> int:
+    return max(1, _LANES // P)
+
+
+def _tile_of(r: int, P: int):
+    """(lane slice, heads a slice, r's place in it): the lane tile head r's
+    channels lie in (a head of whole tiles is its own slice)."""
+    per, width = _heads_a_tile(P), max(P, _LANES)
+    return slice(r // per * width, (r // per + 1) * width), per, r % per
+
+
+def _head_mask(j: int, per: int, P: int):
+    """[1, 128] mask of the j-th head's lanes in a tile of `per` heads (None
+    where the tile is one head's)."""
+    if per == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    return (lane >= j * P) & (lane < (j + 1) * P)
+
+
+def _merge_heads(parts, R: int, P: int):
+    """[Q, R P] from each head's [Q, tile]: head r's lanes from parts[r]."""
+    tiles, per = [], _heads_a_tile(P)
+    for r0 in range(0, R, per):
+        tile = parts[r0 + per - 1]
+        for j in range(per - 2, -1, -1):
+            tile = jnp.where(_head_mask(j, per, P), parts[r0 + j], tile)
+        tiles.append(tile)
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
+
+
+def _expand(col, at: int, R: int, P: int):
+    """[Q, R P]: column at + r of the column form over head r's P lanes."""
+    width = max(P, _LANES)
+    return _merge_heads([_lane_col(col, at + r, width) for r in range(R)], R, P)
+
+
+def _head_sums(t, R: int, P: int):
+    """R columns [Q, 1]: the sum of t [Q, R P] over each head's lanes."""
+    out = []
+    for r in range(R):
+        lanes, per, j = _tile_of(r, P)
+        mask = _head_mask(j, per, P)
+        part = t[:, lanes]
+        out.append(jnp.sum(part if mask is None else jnp.where(mask, part, 0.0),
+                           axis=1, keepdims=True))
+    return out
+
+
+def _causal(Q: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+
+
+def _decay(col, row, r: int, keep):
+    """Head r's [Q, Q] block exp(cum_i - cum_j), zero above the diagonal."""
+    gap = _lane_col(col, r, keep.shape[1]) - row[r:r + 1, :]
+    return jnp.exp(jnp.where(keep, gap, -jnp.inf))
+
+
+def _dot(a, b, dims=None):
+    if dims is None:
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, y_ref, *rest,
+                R: int, P: int):
+    """One (batch, group, chunk): y of the chunk's Q tokens for the group's
+    R heads, and the carried state moved to the chunk's end. With a second
+    output (the differentiated forward) the state the chunk STARTS from is
+    written too: what the backward kernel reads."""
+    *before_ref, s_sc = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_sc[...] = jnp.zeros(s_sc.shape, jnp.float32)
+
+    x, Bm, Cm = x_ref[0], b_ref[0], c_ref[0]
+    col, row = col_ref[0, 0], row_ref[0, 0]
+    Q, cd = x.shape[0], x.dtype
+    before = s_sc[...]
+    for ref in before_ref:
+        ref[0, 0] = before
+    xf = x.astype(jnp.float32)
+    # inside the chunk, a head at a time: m = C B^T x decay x dt_j
+    scores = _dot(Cm, Bm, _NT)
+    keep = _causal(Q)
+    inside = []
+    for r in range(R):
+        m = (scores * _decay(col, row, r, keep)
+             * row[R + r:R + r + 1, :]).astype(cd)
+        inside.append(_dot(m, x[:, _tile_of(r, P)[0]]))
+    # from the state the chunk starts with, the whole group at once
+    y = (_merge_heads(inside, R, P)
+         + _dot(Cm, before.astype(cd), _NT) * _expand(col, R, R, P))
+    y_ref[0] = y + xf * d_ref[...]
+    # the state at the chunk's end
+    own = _dot((xf * _expand(col, 2 * R, R, P)).astype(cd), Bm, _TN)
+    for r in range(R):
+        rows = slice(r * P, (r + 1) * P)
+        s_sc[rows, :] = (_at_last(col, R + r, own.shape[1]) * before[rows]
+                         + own[rows])
+
+
+def _bwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, dy_ref,
+                before_ref, dx_ref, db_ref, dc_ref, dcol_ref, drow_ref,
+                dd_ref, ds_sc, *, R: int, P: int):
+    """One (batch, group, chunk), the chunks from the last down: the
+    cotangents of the chunk's x, B, C (B and C summed over the group's heads)
+    and of the two small forms, D's summed over the chunks in its output
+    block, and the state's cotangent carried to the chunk before."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_sc[...] = jnp.zeros(ds_sc.shape, jnp.float32)
+        dd_ref[...] = jnp.zeros(dd_ref.shape, jnp.float32)
+
+    x, Bm, Cm = x_ref[0], b_ref[0], c_ref[0]
+    col, row = col_ref[0, 0], row_ref[0, 0]
+    Q, cd = x.shape[0], x.dtype
+    N = Bm.shape[1]
+    g = dy_ref[0]
+    gb = g.astype(cd)
+    before, after_bar = before_ref[0, 0], ds_sc[...]
+    before_cd, after_bar_cd = before.astype(cd), after_bar.astype(cd)
+    xf = x.astype(jnp.float32)
+    decayed, to_end = _expand(col, R, R, P), _expand(col, 2 * R, R, P)
+
+    # y's part from the state the chunk starts with: (C before^T) x exp(cum)
+    read = _dot(Cm, before_cd, _NT)
+    read_bar = (g * decayed).astype(cd)
+    dC = _dot(read_bar, before_cd)
+    before_bar = _dot(read_bar, Cm, _TN)
+    d_decayed = _head_sums(g * read, R, P)
+    # the state at the chunk's end: exp(last) before + (x to_end)^T B
+    xw = (xf * to_end).astype(cd)
+    xw_bar = _dot(Bm, after_bar_cd, _NT)
+    dB = _dot(xw, after_bar_cd)
+    d_to_end = _head_sums(xw_bar * xf, R, P)
+    dx = g * d_ref[...] + xw_bar * to_end
+    dd_ref[0] += jnp.sum(g * xf, axis=0, keepdims=True)
+
+    # inside the chunk, a head at a time
+    scores = _dot(Cm, Bm, _NT)
+    keep = _causal(Q)
+    scores_bar = jnp.zeros((Q, Q), jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    bottom = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
+    dcol = jnp.zeros((Q, _LANES), jnp.float32)
+    inside = []
+    for r in range(R):
+        lanes, per, j = _tile_of(r, P)
+        mask = _head_mask(j, per, P)
+        decay = _decay(col, row, r, keep)
+        dtj = row[R + r:R + r + 1, :]
+        weighted = scores * decay
+        m = (weighted * dtj).astype(cd)
+        g_r = gb[:, lanes]
+        m_bar = _dot(g_r if mask is None else jnp.where(mask, g_r, 0),
+                     x[:, lanes], _NT)
+        inside.append(_dot(m, g_r, _TN))
+        through = m_bar * weighted          # m's cotangent through dt_j
+        d_dtj = jnp.sum(through, axis=0, keepdims=True)
+        drow_ref[0, 0, r:r + 1, :] = -(d_dtj * dtj)          # cum_j, in rows
+        drow_ref[0, 0, R + r:R + r + 1, :] = d_dtj
+        d_cum = jnp.sum(through * dtj, axis=1, keepdims=True)    # cum_i
+        scores_bar = scores_bar + m_bar * decay * dtj
+        # exp(last) = the column form's exp(cum) at the chunk's last token
+        rows = slice(r * P, (r + 1) * P)
+        d_last = jnp.sum(jnp.sum(after_bar[rows] * before[rows], axis=0,
+                                 keepdims=True), axis=1, keepdims=True)
+        ds_sc[rows, :] = (before_bar[rows]
+                          + _at_last(col, R + r, N) * after_bar[rows])
+        for at, v in ((r, d_cum),
+                      (R + r, d_decayed[r] + jnp.where(bottom, d_last, 0.0)),
+                      (2 * R + r, d_to_end[r])):
+            dcol = jnp.where(lane == at, jnp.broadcast_to(v, dcol.shape), dcol)
+    scores_bar = scores_bar.astype(cd)
+    dc_ref[0] = (dC + _dot(scores_bar, Bm)).astype(dc_ref.dtype)
+    db_ref[0] = (dB + _dot(scores_bar, Cm, _TN)).astype(db_ref.dtype)
+    dx_ref[0] = (dx + _merge_heads(inside, R, P)).astype(dx_ref.dtype)
+    dcol_ref[0, 0] = dcol
+
+
+def _params():
+    # c sequential (the carried state); a [Q, R P] float32 temporary is 256
+    # KB at the hybrid's sizes and a step holds a dozen beside its blocks
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _operand_specs(geom: ScanGeometry, chunk_of):
+    """BlockSpecs of (x, B, C, col, row, D's lanes) for a grid (batch, group,
+    chunk step); `chunk_of` maps the step to the chunk it works on."""
+    H, P, G, N, Q = geom
+    RP = geom.R * P
+    b_at, c_at = H * P // N, H * P // N + G
+
+    def at(lane):
+        return lambda b, g, s: (b, chunk_of(s), lane(g))
+    return [pl.BlockSpec((1, Q, RP), at(lambda g: g)),
+            pl.BlockSpec((1, Q, N), at(lambda g: b_at + g)),
+            pl.BlockSpec((1, Q, N), at(lambda g: c_at + g)),
+            pl.BlockSpec((1, 1, Q, _LANES),
+                         lambda b, g, s: (b, g, chunk_of(s), 0)),
+            pl.BlockSpec((1, 1, 2 * geom.R, Q),
+                         lambda b, g, s: (b, g, 0, chunk_of(s))),
+            pl.BlockSpec((1, RP), lambda b, g, s: (0, g))]
+
+
+# jitted, as ops/flash_ops.py's launches: a model's mixers share shapes, so
+# the kernels are traced and lowered once a program and not once a layer
+@functools.partial(jax.jit, static_argnames=("geom", "states"))
+def _scan_forward(xBC, col, row, d_lanes, *, geom: ScanGeometry, states: bool):
+    """[y [B, T, H P] float32] and, with `states`, the state every chunk
+    starts from: [B, T / Q, H P, N] float32."""
+    H, P, G, N, Q = geom
+    Bsz, T, _ = xBC.shape
+    RP = geom.R * P
+    out_specs = [pl.BlockSpec((1, Q, RP), lambda b, g, c: (b, c, g))]
+    out_shape = [jax.ShapeDtypeStruct((Bsz, T, H * P), jnp.float32)]
+    if states:
+        out_specs.append(pl.BlockSpec((1, 1, RP, N),
+                                      lambda b, g, c: (b, c, g, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((Bsz, T // Q, H * P, N),
+                                              jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, R=geom.R, P=P),
+        grid=(Bsz, G, T // Q),
+        in_specs=_operand_specs(geom, lambda c: c),
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((RP, N), jnp.float32)],
+        compiler_params=_params(),
+        name="ssd_scan_fwd",
+    )(xBC, xBC, xBC, col, row, d_lanes)
+
+
+@functools.partial(jax.jit, static_argnames=("geom",))
+def _scan_backward(xBC, col, row, d_lanes, before, dy, *, geom: ScanGeometry):
+    """Cotangents of (xBC, col, row, d_lanes) given y's."""
+    H, P, G, N, Q = geom
+    Bsz, T, _ = xBC.shape
+    R, RP, c = geom.R, geom.R * P, T // Q
+    back = lambda s: c - 1 - s  # noqa: E731
+    lanes = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, Q, width), lambda b, g, s: (b, back(s), g))
+    cd = xBC.dtype
+    dx, dB, dC, dcol, drow, dd = pl.pallas_call(
+        functools.partial(_bwd_kernel, R=R, P=P),
+        grid=(Bsz, G, c),
+        in_specs=_operand_specs(geom, back) + [
+            lanes(RP),
+            pl.BlockSpec((1, 1, RP, N), lambda b, g, s: (b, back(s), g, 0))],
+        out_specs=[
+            lanes(RP), lanes(N), lanes(N),
+            pl.BlockSpec((1, 1, Q, _LANES),
+                         lambda b, g, s: (b, g, back(s), 0)),
+            pl.BlockSpec((1, 1, 2 * R, Q), lambda b, g, s: (b, g, 0, back(s))),
+            pl.BlockSpec((1, 1, RP), lambda b, g, s: (b, 0, g))],
+        out_shape=[
+            jax.ShapeDtypeStruct((Bsz, T, H * P), cd),
+            jax.ShapeDtypeStruct((Bsz, T, G * N), cd),
+            jax.ShapeDtypeStruct((Bsz, T, G * N), cd),
+            jax.ShapeDtypeStruct(col.shape, jnp.float32),
+            jax.ShapeDtypeStruct(row.shape, jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, 1, H * P), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((RP, N), jnp.float32)],
+        compiler_params=_params(),
+        name="ssd_scan_bwd",
+    )(xBC, xBC, xBC, col, row, d_lanes, dy, before)
+    # XLA folds this concatenation into the fusion that reads it (the conv's
+    # backward): it is no pass of its own in the hybrid's step. A kernel
+    # that copies the three into one packed output itself was 0.3 ms a
+    # mixer slower and saved nothing (PERF.md section 6, PR 41)
+    return (jnp.concatenate([dx, dB, dC], axis=-1), dcol, drow,
+            jnp.sum(dd, axis=0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _scan_kernels(xBC, col, row, d_lanes, geom: ScanGeometry):
+    """The kernels over the packed [x | B | C] and the small forms: no
+    dispatch gate. Not differentiated, the forward writes y alone."""
+    return _scan_forward(xBC, col, row, d_lanes, geom=geom, states=False)[0]
+
+
+def _scan_kernels_fwd(xBC, col, row, d_lanes, geom):
+    y, before = _scan_forward(xBC, col, row, d_lanes, geom=geom, states=True)
+    return y, (xBC, col, row, d_lanes, before)
+
+
+def _scan_kernels_bwd(geom, saved, dy):
+    return _scan_backward(*saved, dy, geom=geom)
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
+def _ssd_kernels(xBC, dt, A, D, geom: ScanGeometry):
+    """y [B, T, H, P] float32 through the kernels, from the packed [x | B |
+    C] [B, T, H P + 2 G N]."""
+    col, row = _small_forms(dt, A, geom)
+    d_lanes = jnp.repeat(D.astype(jnp.float32), geom.P)[None, :]
+    y = _scan_kernels(xBC, col, row, d_lanes, geom)
+    return y.reshape(*y.shape[:2], geom.H, geom.P)
+
+
 def _count_dispatch(path: str) -> None:
     from ..obs import metrics
 
@@ -106,6 +552,39 @@ def _count_dispatch(path: str) -> None:
         "pt_ssm_scan_dispatch_total",
         help="state-space scans traced, by the formulation that runs them",
         labels={"path": path})
+
+
+def ssd_scan_packed(xBC, dt, A, D, geom: ScanGeometry):
+    """The scan over the packed [x | B | C] [B, T, H P + 2 G N] (compute
+    dtype) the conv writes; dt [B, T, H] float32 and positive, A [H]
+    negative, D [H] -> y [B, T, H, P] float32. The path is chosen here, when
+    the op is traced, from the backend, the mesh and the shapes, and counted
+    in `pt_ssm_scan_dispatch_total{path}`."""
+    H, P, G, N, Q = geom
+    Bsz, T, _ = xBC.shape
+    if scan_kernels_eligible(geom, T, xBC.dtype):
+        _count_dispatch("pallas_chunked")
+        return _ssd_kernels(xBC, dt, A, D, geom)
+    _count_dispatch("xla_chunked")
+    d_in = H * P
+    return _ssd_einsums(
+        xBC[..., :d_in].reshape(Bsz, T, H, P), dt, A,
+        xBC[..., d_in:d_in + G * N].reshape(Bsz, T, G, N),
+        xBC[..., d_in + G * N:].reshape(Bsz, T, G, N), D, Q)
+
+
+def ssd_chunked_scan(x, dt, A, Bm, Cm, D, chunk: int = CHUNK):
+    """x [B, T, H, P] (compute dtype), dt [B, T, H] float32 and positive, A
+    [H] negative, Bm / Cm [B, T, G, N] (compute dtype), D [H] -> y [B, T, H,
+    P] float32. Any T: a tail shorter than a chunk is padded with dt = 0
+    (the einsum form; the kernels take whole chunks). `ssd_scan_packed` with
+    the three sequences side by side: what `mamba2_mixer` holds already."""
+    Bsz, T, H, P = x.shape
+    G, N = Bm.shape[2:]
+    return ssd_scan_packed(
+        jnp.concatenate([x.reshape(Bsz, T, H * P), Bm.reshape(Bsz, T, G * N),
+                         Cm.reshape(Bsz, T, G * N)], axis=-1),
+        dt, A, D, ScanGeometry(H, P, G, N, chunk))
 
 
 def causal_depthwise_conv(x, w, b):
@@ -146,12 +625,10 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
             xBC = jax.nn.silu(
                 causal_depthwise_conv(xBC, conv_w, conv_b)).astype(cd)
         with jax.named_scope("scan"):
-            y = ssd_chunked_scan(
-                xBC[..., :d_in].reshape(Bsz, T, H, P),
-                jax.nn.softplus(dt + dt_bias),
-                -jnp.exp(A_log.astype(jnp.float32)),
-                xBC[..., d_in:d_in + G * N].reshape(Bsz, T, G, N),
-                xBC[..., d_in + G * N:].reshape(Bsz, T, G, N), D, chunk)
+            y = ssd_scan_packed(
+                xBC, jax.nn.softplus(dt + dt_bias),
+                -jnp.exp(A_log.astype(jnp.float32)), D,
+                ScanGeometry(H, P, G, N, chunk))
         with jax.named_scope("gate_norm"):
             return gated_group_rms_norm(y.reshape(Bsz, T, d_in), z, norm_w,
                                         G, eps).astype(cd)
@@ -162,9 +639,9 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
         xBC = zxd[..., d_in:2 * d_in + 2 * G * N].astype(cd)
         dt = zxd[..., -H:]                                   # float32
     # one checkpoint from the projection's output to the other's input: the
-    # backward keeps z, xBC and dt and computes the conv, the scan's
-    # [chunks, H, Q, Q] blocks and the float32 y again instead of holding them
-    _count_dispatch("xla_chunked")
+    # backward keeps z, xBC and dt and computes the conv, the scan (its
+    # [Q, Q] blocks and chunk states, in the kernels or as XLA's arrays) and
+    # the float32 y again instead of holding them
     y = jax.checkpoint(between)(z, xBC, dt, conv_w, conv_b, dt_bias, A_log,
                                 D, norm_w)
     with jax.named_scope("out_proj"):

@@ -64,26 +64,96 @@ def _recurrence(x, dt, A, Bm, Cm, D):
     return y + D[:, None] * x
 
 
-@pytest.mark.parametrize("T,H,G", [(64, 4, 4), (64, 4, 2), (40, 4, 1),
-                                   (7, 2, 2)],
-                         ids=["4chunks", "groups<heads", "ragged_tail",
-                              "under_a_chunk"])
-def test_chunked_scan_matches_the_recurrence(T, H, G):
+def _dispatched(path):
+    from paddle_tpu.obs import metrics
+
+    return metrics.registry().counter_value(
+        "pt_ssm_scan_dispatch_total", labels={"path": path})
+
+
+# T, H, G, P, N, chunk; the kernels' cases (interpreted) are the smallest the
+# shape rules admit: a group's R x P = 128 lanes, Q = N = 128, two groups,
+# three chunks (the carried state and its cotangent cross two chunk edges)
+SCAN_CASES = {
+    "4chunks": (64, 4, 4, 8, 16, 16),
+    "groups<heads": (64, 4, 2, 8, 16, 16),
+    "ragged_tail": (40, 4, 1, 8, 16, 16),
+    "under_a_chunk": (7, 2, 2, 8, 16, 16),
+    "kernels_groups<heads": (384, 4, 2, 64, 128, 128),
+    "kernels_groups=heads": (384, 2, 2, 128, 128, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_chunked_scan_matches_the_recurrence(interpreted, case):
     """Values and every gradient, float32 at the highest precision: the two
-    differ only in the order of float32 sums."""
-    args = _scan_inputs(T, H, G)
-    w = jnp.asarray(_rng(9).randn(2, T, H, 8), jnp.float32)
+    differ only in the order of float32 sums. The einsum form at the tiny
+    shapes, the kernels at the shapes they take."""
+    T, H, G, P, N, chunk = SCAN_CASES[case]
+    kernels = case.startswith("kernels")
+    args = _scan_inputs(T, H, G, P=P, N=N, Bsz=1 if kernels else 2)
+    w = jnp.asarray(_rng(9).randn(*args[0].shape), jnp.float32)
+    scan = lambda *a: ssm_ops.ssd_chunked_scan(*a, chunk=chunk)  # noqa: E731
+    before = _dispatched("pallas_chunked")
     with jax.default_matmul_precision("highest"):
-        got = ssm_ops.ssd_chunked_scan(*args, chunk=16)
+        got = scan(*args)
         want = _recurrence(*args)
         assert got.dtype == jnp.float32 and got.shape == want.shape
         assert _rel(got, want) < 1e-5
         grad = lambda fn: jax.grad(  # noqa: E731
             lambda *a: (fn(*a) * w).sum(), argnums=tuple(range(6)))(*args)
-        for name, g, r in zip(("x", "dt", "A", "B", "C", "D"), grad(
-                lambda *a: ssm_ops.ssd_chunked_scan(*a, chunk=16)),
-                grad(_recurrence)):
+        for name, g, r in zip(("x", "dt", "A", "B", "C", "D"), grad(scan),
+                              grad(_recurrence)):
             assert _rel(g, r) < 1e-4, (name, _rel(g, r))
+    assert (_dispatched("pallas_chunked") > before) == kernels
+
+
+@pytest.mark.parametrize("case", ["kernels_groups<heads",
+                                  "kernels_groups=heads"])
+def test_scan_kernels_in_bf16_round_where_the_einsums_do(interpreted, case):
+    """Under amp x, B and C arrive bf16. The kernels' y is the einsum
+    form's but for the order of float32 sums (1e-3 and up is a dropped rounding point: a
+    bf16 decay, a bf16 state, an unrounded `m`), every gradient is within
+    bf16's rounding of the float32 recurrence's, and so is the einsum
+    form's: the two backward passes round alike."""
+    T, H, G, P, N, chunk = SCAN_CASES[case]
+    x, dt, A, Bm, Cm, D = _scan_inputs(T, H, G, P=P, N=N, Bsz=1)
+    lo = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
+    args = (lo(x), dt, A, lo(Bm), lo(Cm), D)
+    w = jnp.asarray(_rng(9).randn(*x.shape), jnp.float32)
+    got = ssm_ops.ssd_chunked_scan(*args, chunk=chunk)
+    assert got.dtype == jnp.float32
+    assert _rel(got, ssm_ops._ssd_einsums(*args, chunk)) < 2e-5
+    assert _rel(got, _recurrence(x, dt, A, Bm, Cm, D)) < 0.02
+    grad = lambda fn, a: jax.grad(  # noqa: E731
+        lambda *a: (fn(*a) * w).sum(), argnums=tuple(range(6)))(*a)
+    want = grad(_recurrence, (x, dt, A, Bm, Cm, D))
+    kernels = grad(lambda *a: ssm_ops.ssd_chunked_scan(*a, chunk=chunk), args)
+    einsums = grad(lambda *a: ssm_ops._ssd_einsums(*a, chunk), args)
+    for name, k, e, r in zip(("x", "dt", "A", "B", "C", "D"), kernels,
+                             einsums, want):
+        assert k.dtype == e.dtype
+        assert _rel(k, r) < 0.02 and _rel(e, r) < 0.02, (
+            name, _rel(k, r), _rel(e, r))
+
+
+def test_scan_shape_rules():
+    """What the kernels take and what stays the einsums': whole chunks of
+    128, a state of whole lane tiles, a group of whole lane tiles."""
+    ok = lambda T, dtype=jnp.bfloat16, **kw: ssm_ops._shapes_scan_ok(  # noqa: E731
+        ssm_ops.ScanGeometry(**{**dict(H=64, P=64, G=8, N=128, Q=128), **kw}),
+        T, dtype)
+    assert ok(8192) and ok(8192, jnp.float32) and ok(128, G=64, P=128)
+    assert ok(256, H=8, G=8, P=128, Q=256)
+    assert not ok(8192 + 64)              # a ragged tail
+    assert not ok(8192, Q=16) and not ok(8192, N=16) and not ok(8192, N=64)
+    assert ok(8192, P=32)                 # a group of 8 x 32: two tiles
+    assert not ok(8192, H=24, P=32)       # ... of 3 x 32: three quarters
+    assert not ok(8192, H=8, G=8)         # one head of 64: half a tile
+    assert not ok(8192, jnp.float16) and not ok(8192, H=60, G=8)
+    # and nothing is taken off the TPU, whatever the shape
+    assert not ssm_ops.scan_kernels_eligible(
+        ssm_ops.ScanGeometry(64, 64, 8, 128, 128), 8192, jnp.bfloat16)
 
 
 def test_scan_in_bf16_keeps_decays_and_state_in_float32():
@@ -146,7 +216,8 @@ def test_mamba2_init_draws_the_family_ranges():
 # ------------------------------------- attention with shared K/V blocks ---
 @pytest.fixture
 def interpreted(monkeypatch):
-    """The packed kernels, interpreted on the CPU."""
+    """The Pallas kernels (attention's packed ones, the scan's), interpreted
+    on the CPU."""
     from jax.experimental import pallas as pl
 
     real = pl.pallas_call
@@ -156,10 +227,14 @@ def interpreted(monkeypatch):
         return real(*a, interpret=True, **kw)
 
     monkeypatch.setattr(flash_ops.pl, "pallas_call", call)
-    for fn in (flash_ops._packed_forward, flash_ops._packed_backward):
+    # the scan's kernels too (the same `pl`), wherever their shape rules hold
+    monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
+    jitted = (flash_ops._packed_forward, flash_ops._packed_backward,
+              ssm_ops._scan_forward, ssm_ops._scan_backward)
+    for fn in jitted:
         fn.clear_cache()
     yield
-    for fn in (flash_ops._packed_forward, flash_ops._packed_backward):
+    for fn in jitted:
         fn.clear_cache()
 
 

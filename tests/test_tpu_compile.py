@@ -174,6 +174,31 @@ def _flash_gqa(case):
     return fwd_bwd, specs
 
 
+def _ssd_scan(with_bwd):
+    from paddle_tpu.ops import ssm_ops
+
+    # Nemotron-3-Nano's Mamba-2 scan at the cell's shape: T 8192 in 64
+    # chunks of 128, 64 heads x 64 in 8 groups, state 128: the forward
+    # kernel (a group's [512, 128] float32 state in VMEM across the chunk
+    # axis; x, B, C as lane ranges of the packed [1, 8192, 6144]) and, with
+    # `with_bwd`, the differentiated forward (it also writes the state each
+    # chunk starts from) and the backward kernel with its own copies of dx,
+    # dB, dC into the packed cotangent
+    geom = ssm_ops.ScanGeometry(H=64, P=64, G=8, N=128, Q=128)
+    assert ssm_ops._shapes_scan_ok(geom, 8192, BF16)
+    specs = [((1, 8192, 64 * 64 + 2 * 8 * 128), BF16), ((1, 8192, 64), F32),
+             ((64,), F32), ((64,), F32)]
+
+    def fwd(xBC, dt, A, D):
+        return ssm_ops._ssd_kernels(xBC, dt, A, D, geom)
+
+    def fwd_bwd(xBC, dt, A, D):
+        return jax.grad(lambda *a: fwd(*a).sum(), (0, 1, 2, 3))(
+            xBC, dt, A, D)
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
 def _gmm_share(kn):
     from paddle_tpu.ops import moe_ops
 
@@ -250,6 +275,8 @@ CASES = [
     ("flash_fwd_bwd_nemotron_gqa_t8192", _flash_gqa, (1, 8192, 32, 2, 128)),
     ("gmm_fwd_bwd_nemotron_share_up", _gmm_share, (2688, 1920)),
     ("gmm_fwd_bwd_nemotron_share_down", _gmm_share, (1920, 2688)),
+    ("ssd_scan_fwd_nemotron_t8192", _ssd_scan, False),
+    ("ssd_scan_fwd_bwd_nemotron_t8192", _ssd_scan, True),
     # ResNet-50 head at a full serving bucket, and the small probe shape
     ("quant_matmul_64x2048x1000", _quant, (64, 2048, 1000)),
     ("quant_matmul_8x512x512", _quant, (8, 512, 512)),
@@ -370,8 +397,9 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
 
     import paddle_tpu as pt
     from paddle_tpu.core import executor as ex
-    from paddle_tpu.ops import flash_ops, moe_ops
+    from paddle_tpu.ops import flash_ops, moe_ops, ssm_ops
 
+    monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
     monkeypatch.setattr(
         flash_ops, "flash_eligible", lambda q, k=None: (
             flash_ops._shapes_flash_ok(q, q if k is None else k)
@@ -467,10 +495,69 @@ def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
     compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
     text = compiled.as_text()
     assert "flash_attention_bwd" in text and "ragged-dot" not in text
+    # the four mixers' scans are the kernels: forward, the checkpoint's
+    # second forward (it writes the chunk states) and backward, every one
+    # under the op's inner `scan` scope, where `ssm.scan_ms` finds it; and
+    # around them no layout copy of a [chunks x groups, heads, P, N] float32
+    # array is left under a mixer's scope (XLA's einsums had twelve)
+    kernels = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call".*'
+        r'op_name="([^"]*ssd_scan_(?:fwd|bwd)[^"]*)"', text)
+    assert len(kernels) == 12, kernels
+    assert all("mamba2_mixer." in name and "/scan/" in name
+               for name in kernels), kernels
+    assert sum("transpose(jvp(" in name for name in kernels) == 8
+    assert not re.findall(
+        r"= f32\[512,8,64,128\]\S* copy\(.*op_name=\"[^\"]*mamba2_mixer",
+        text)
+    assert dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr)) == {
+        "flash_attention_fwd": 1, "flash_attention_bwd": 1,
+        "grouped_matmul": 32, "ssd_scan_fwd": 8, "ssd_scan_bwd": 4}
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 7.9e9          # 12 B a parameter
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.0 * 2**30)
+
+
+@pytest.mark.parametrize("where,chunk,path", [
+    ("tpu", 128, "pallas_chunked"), ("cpu", 128, "xla_chunked"),
+    ("tpu_mesh", 128, "xla_chunked"), ("tpu", 16, "xla_chunked")])
+def test_scan_dispatch_counts_the_path_it_chose(monkeypatch, where, chunk,
+                                                path):
+    """`pt_ssm_scan_dispatch_total{path}`: the kernels for an eligible shape
+    where the backend is the TPU (steered: nothing is lowered here, the op
+    is only traced), the einsums on the CPU, under an active mesh (a bare
+    `pallas_call` cannot be partitioned) and at a chunk the kernels do not
+    take. One increment an op traced; no flag and no attribute chooses."""
+    import contextlib
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from paddle_tpu.obs import metrics
+    from paddle_tpu.ops import mesh_dispatch, ssm_ops
+
+    if where != "cpu":
+        monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
+    H, P, G, N, d = 4, 64, 2, 128, 64
+    width = 2 * H * P + 2 * G * N + H
+    shapes = [(1, 256, d), (d, width), (4, H * P + 2 * G * N),
+              (H * P + 2 * G * N,), (H,), (H,), (H,), (H * P,), (H * P, d)]
+    mixer = lambda *a: ssm_ops.mamba2_mixer(  # noqa: E731
+        *a, num_heads=H, head_dim=P, n_groups=G, state_size=N, eps=1e-5,
+        chunk=chunk)
+    count = lambda p: metrics.registry().counter_value(  # noqa: E731
+        "pt_ssm_scan_dispatch_total", labels={"path": p})
+    before = {p: count(p) for p in ("pallas_chunked", "xla_chunked")}
+    mesh = mesh_dispatch.active_mesh(
+        Mesh(np.array(jax.devices()[:1]), ("dp",)), "dp") \
+        if where == "tpu_mesh" else contextlib.nullcontext()
+    with mesh:
+        out = jax.eval_shape(mixer, *[jax.ShapeDtypeStruct(s, F32)
+                                      for s in shapes])
+    assert out.shape == (1, 256, d)
+    other = ({"pallas_chunked", "xla_chunked"} - {path}).pop()
+    assert count(path) == before[path] + 1 and count(other) == before[other]
 
 
 def test_glm_moe_step_program_fits_one_chip(one_chip, compiled_mode,
