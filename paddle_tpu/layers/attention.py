@@ -32,11 +32,13 @@ def multi_head_attention(
     param_attr=None,
     bias_attr=None,
     name=None,
-    qk_norm: bool = False,
+    qk_norm=False,
     rotary_theta: Optional[float] = None,
     rms_eps: float = 1e-5,
     num_kv_heads: Optional[int] = None,
     head_dim: Optional[int] = None,
+    window: Optional[int] = None,
+    out_gate: bool = False,
 ):
     """Transformer multi-head attention over dense [B, T, E] inputs
     (self-attention when key/value are None). Beyond the 2017 reference's
@@ -46,10 +48,15 @@ def multi_head_attention(
     are `fc` layers so AMP/sharding apply as everywhere else.
 
     qk_norm: RMSNorm (learned scale, `rms_eps`) on the Q and K projections
-    over the whole E, before the split into heads (OLMoE's form).
+    before the rotary. True: over the WHOLE projection width, heads x D
+    lanes at once, with a scale [heads x D] (OLMoE's form; NOT the per-head
+    form most QK-normed models use). "head": over each head's D lanes on
+    its own, one scale [D] shared by all query heads and one by all K/V
+    heads (the per-head form: `afmoe`, and the Qwen3 / OLMo-2 lineage's).
+    False: none. Anything else raises.
     rotary_theta: rotary position embedding of that base on Q and K, after
-    the norm. Both off by default, and then the ops appended are exactly
-    those of a layer without them.
+    the norm. None: no position signal (the causal prefix alone tells two
+    occurrences of an id apart).
     num_kv_heads: fewer K/V heads than query heads (grouped-query
     attention): the K and V projections are [E, num_kv_heads x D] and query
     head j reads K/V head j // (num_heads / num_kv_heads). None or
@@ -58,9 +65,19 @@ def multi_head_attention(
     D] and the output projection [num_heads x D, E] (Nemotron-H: 32 x 128
     at E 2688). The kernel reads the K/V heads from the shapes, so the op
     carries no attribute for them.
-    param_attr may be a mapping {"wq" | "wk" | "wv" | "wo": attr}
-    (`ParamAttr.derive`)."""
-    from .nn import fc, rms_norm, rotary_embedding
+    window: W > 0, causal self-attention only: position i attends to the W
+    keys i - W < j <= i (itself among them), handed to the `flash_attention`
+    op as its `window` attribute; a layer without one is a global layer.
+    out_gate: a fifth projection W_g [E, num_heads x D] of the layer's INPUT
+    (`query`); the kernels' output is multiplied by sigmoid(query W_g)
+    before the output projection (`afmoe`'s gated attention): an `fc` with
+    a sigmoid and an `elementwise_mul`.
+    All of these off by default, and then the ops appended are exactly
+    those of a layer without them.
+    param_attr may be a mapping {"wq" | "wk" | "wv" | "wg" | "wo": attr}
+    (`ParamAttr.derive`). Parameters, in order: wq, wk, wv, the two norms'
+    scales, wg, wo."""
+    from .nn import elementwise_mul, fc, rms_norm, rotary_embedding
 
     is_cross = key is not None or value is not None
     if is_cross and causal:
@@ -71,6 +88,12 @@ def multi_head_attention(
             "causal=True is only valid for self-attention; pass "
             "causal=False for cross-attention"
         )
+    if qk_norm not in (False, True, "head"):
+        raise ValueError(f"qk_norm {qk_norm!r}: False, True (over the whole "
+                         f"projection) or 'head' (over each head)")
+    if window is not None and (window <= 0 or not causal):
+        raise ValueError(f"window {window}: a positive number of keys, and "
+                         f"only with causal=True")
     key = query if key is None else key
     value = query if value is None else value
     helper = LayerHelper("multi_head_attention", name=name)
@@ -97,21 +120,31 @@ def multi_head_attention(
     if qk_norm:
         # the norms' scales start at one whatever initialiser the caller
         # gave the projections: only the derived name is taken over
+        group = D if qk_norm == "head" else None
         q = rms_norm(q, epsilon=rms_eps, name=f"{helper.name}.q_norm",
-                     param_attr=_derive(param_attr, "q_norm").name)
+                     param_attr=_derive(param_attr, "q_norm").name,
+                     group=group)
         k = rms_norm(k, epsilon=rms_eps, name=f"{helper.name}.k_norm",
-                     param_attr=_derive(param_attr, "k_norm").name)
+                     param_attr=_derive(param_attr, "k_norm").name,
+                     group=group)
     if rotary_theta:
         q = rotary_embedding(q, num_heads, rotary_theta)
         k = rotary_embedding(k, kv_heads, rotary_theta)
     out = helper.create_tmp_variable(query.dtype,
                                      tuple(query.shape[:-1]) + (E_q,))
+    attrs = {"num_heads": num_heads, "causal": causal}
+    if window:
+        attrs["window"] = int(window)
     helper.append_op(
         type="flash_attention",
         inputs={"Q": [q], "K": [k], "V": [v]},
         outputs={"Out": [out]},
-        attrs={"num_heads": num_heads, "causal": causal},
+        attrs=attrs,
     )
+    if out_gate:
+        out = elementwise_mul(out, fc(
+            query, size=E_q, num_flatten_dims=2, act="sigmoid",
+            param_attr=_derive(param_attr, "wg"), bias_attr=False))
     return fc(out, size=E, num_flatten_dims=2,
               param_attr=_derive(param_attr, "wo"),
               bias_attr=_derive(bias_attr, "wo_b"))

@@ -27,7 +27,8 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
             norm_topk_prob: bool = False, param_attr=None, name=None,
             scoring: str = "softmax", router_bias: bool = False,
             gate_scale: float = 1.0, expert_act: str = "swiglu",
-            held_experts=None, shared_expert_dim: int = 0):
+            held_experts=None, shared_expert_dim: int = 0,
+            chunk_shares=None):
     """input [B, T, d] -> (out [B, T, d], router logits [B*T, E] float32,
     tokens per expert [E] int32). Each token goes to its `experts_per_token`
     highest-scoring experts of `num_experts`; every (token, slot) pair is
@@ -52,7 +53,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
     chunks of a bound (two even shares of the tokens x k pairs) in place
     of all the rows: one chunk in a step whose live pairs fit the bound,
     more, by the same arithmetic, in a step whose pairs exceed it
-    (`ops/moe_ops.py:row_bound`).
+    (`ops/moe_ops.py:row_bound`). `chunk_shares` asks for a bound of that
+    many even shares in place of two (a layer whose routers settle on both
+    sides of two; None: the op as it always was).
     shared_expert_dim f_s > 0: a shared expert for every token, of the
     routed experts' kind (`expert_act`): "relu2" + relu(x Wu_s)^2 Wd_s
     (`<name>.shared_up` [d, f_s], `.shared_down`); "swiglu" + (silu(x Wg_s)
@@ -121,7 +124,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
         attrs["held_lo"], attrs["held_hi"] = lo, hi
         held = helper.create_tmp_variable(np.int32, (hi - lo,))
         outputs["HeldPairs"] = [held]
-        if bounds_rows((lo, hi), E):
+        if chunk_shares is not None:
+            attrs["chunk_shares"] = int(chunk_shares)
+        if bounds_rows((lo, hi), E, chunk_shares):
             row_path = helper.create_tmp_variable(np.int32, (2,))
             outputs["RowPath"] = [row_path]
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,

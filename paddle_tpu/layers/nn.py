@@ -511,21 +511,31 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-5, n
     return out
 
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None, group=None):
     """RMSNorm over the last axis with a learned scale (ones at the
     start): x * rsqrt(mean(x^2) + epsilon) * w. Beyond the 2017
     reference's layer set; the norm of the Llama / OLMo families. The
-    output is float32 under amp too (ops/nn_ops.py:rms_norm)."""
+    output is float32 under amp too (ops/nn_ops.py:rms_norm).
+    group G: the last axis is G-lane groups side by side (the heads of a
+    packed projection), each normed on its own over its G lanes, and ONE
+    scale [G] serves them all. None: the whole axis, and the op appended is
+    the one it always was."""
     helper = LayerHelper("rms_norm", name=name)
+    width = int(input.shape[-1])
+    if group is not None and (group <= 0 or width % group):
+        raise ValueError(f"groups of {group} lanes do not divide {width}")
     w = helper.create_parameter(
-        param_attr, (int(input.shape[-1]),),
+        param_attr, (width if group is None else int(group),),
         default_initializer=ConstantInitializer(1.0))
     out = helper.create_tmp_variable(np.float32, input.shape)
+    attrs = {"epsilon": epsilon}
+    if group is not None:
+        attrs["group"] = int(group)
     helper.append_op(
         type="rms_norm",
         inputs={"X": [input], "Scale": [w]},
         outputs={"Y": [out]},
-        attrs={"epsilon": epsilon},
+        attrs=attrs,
     )
     return out
 

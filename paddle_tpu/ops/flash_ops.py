@@ -10,7 +10,9 @@ streams K/V blocks through VMEM with an online softmax, O(T) memory.
 Compute path: on TPU, Pallas kernels of this repo's own (forward, and a
 backward behind a `custom_vjp`) that read the packed `[B, T, E]` projections
 the `flash_attention` op receives, as they are; anywhere else, and for the
-shapes the kernels refuse, the jnp reference formulation. `[B, T, H, D]`
+shapes the kernels refuse, the jnp reference formulation. One mask beside
+`causal`: a `window` W (position i reads the W keys i - W < j <= i), which
+the same kernels take as a second, lower diagonal. `[B, T, H, D]`
 callers (the framework's sequence-parallel convention,
 parallel/ring_attention.py) reach the same kernels through a free reshape.
 
@@ -51,18 +53,23 @@ def _repeat_kv(q, k, v):
     return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
 
-def scaled_dot_product_attention(q, k, v, causal: bool = False):
+def scaled_dot_product_attention(q, k, v, causal: bool = False,
+                                 window: int = 0):
     """[B, T, H, D] attention, plain jnp — the numerical oracle for the
     flash kernel AND for ring/Ulysses sequence parallelism (re-exported
     by paddle_tpu.parallel; single implementation lives here). K and V may
     have fewer heads than Q (grouped-query attention): query head j reads
-    K/V head j // (H / KV), here by repeating them."""
+    K/V head j // (H / KV), here by repeating them. `window` W > 0 (with
+    `causal`): position i reads the keys i - W < j <= i and no earlier one."""
     d = q.shape[-1]
     k, v = _repeat_kv(q, k, v)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
     if causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
+        ahead = jnp.arange(Tq)[:, None] - jnp.arange(Tk)[None, :]   # i - j
+        mask = ahead >= 0
+        if window:
+            mask &= ahead < window
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
@@ -71,7 +78,7 @@ def scaled_dot_product_attention(q, k, v, causal: bool = False):
 _reference = scaled_dot_product_attention
 
 
-def _shapes_flash_ok(q, k) -> bool:
+def _shapes_flash_ok(q, k, window: int = 0) -> bool:
     """Backend-independent shape rules (separately testable): 128-aligned
     q AND kv sequence lengths (the kernels' blocks divide them), a head dim
     a lane block holds whole (two heads at 64, one at 128, one over two lane
@@ -80,16 +87,18 @@ def _shapes_flash_ok(q, k) -> bool:
     count at D 64 leaves half a block: XLA keeps it). Fewer K/V heads than Q
     heads: the index maps share one K/V lane block among a group of query
     heads, so a lane block has to be one head (D 128 or 256; at D 64
-    `flash_attention` repeats K and V first)."""
+    `flash_attention` repeats K and V first). A `window` is a whole number
+    of 128-row tiles, so the lower diagonal enters a block at a tile's edge
+    as the upper one does (any block size divides into them)."""
     Tq, H, D = q.shape[1:]
     Tk, KV = k.shape[1:3]
     return (Tq % 128 == 0 and Tk % 128 == 0 and D in (64, 128, 256)
-            and (H * D) % 128 == 0
+            and window % 128 == 0 and (H * D) % 128 == 0
             and (KV == H or (D >= _LANES and H % KV == 0)))
 
 
 # Dispatch policy: from T=1024 up the fused kernels take the job (the
-# window both benchmark configurations sit in: PERF.md section 5 has their
+# range every benchmark configuration sits in: PERF.md section 5 has their
 # device time beside the rest of the step). Below it, or when the shape
 # rules fail, XLA keeps the job; the score-bytes rule stays as the
 # memory-capability route for shorter sequences whose [B, H, Tq, Tk] scores
@@ -106,7 +115,7 @@ def _prefers_flash(q, k) -> bool:
     B, Tq, H, _ = q.shape
     Tk = k.shape[1]
     if Tq >= _FLASH_MIN_T and Tk >= _FLASH_MIN_T:
-        return True  # the kernels' window
+        return True  # the kernels' range
     # the shard_map'd kernel runs at the PER-SHARD batch (B/dp under a
     # mesh), so the score-buffer rule must see that batch too — same
     # eligibility discipline as the decoder/RNN kernels. local_batch
@@ -121,11 +130,11 @@ def _prefers_flash(q, k) -> bool:
     return Bl * H * Tq * Tk * itemsize > _SCORE_BYTES_THRESHOLD
 
 
-def flash_eligible(q, k=None) -> bool:
+def flash_eligible(q, k=None, window: int = 0) -> bool:
     k = q if k is None else k
     return (
         jax.default_backend() == "tpu"
-        and _shapes_flash_ok(q, k)
+        and _shapes_flash_ok(q, k, window)
         and _prefers_flash(q, k)
     )
 
@@ -190,6 +199,15 @@ def _v5e_block_sizes(Tq: int, Tk: int, dtype=None) -> FlashBlocks:
 # 64-wide contraction has on a 128-wide MXU anyway), and P V over all W value
 # lanes followed by a lane select is that head's output.
 #
+# Masks: `causal` (column <= row) and, with it, `window` W (column > row - W):
+# two diagonals W apart. A (q block, k block) pair wholly outside the band
+# between them is neither fetched (the index maps clamp to the band's first
+# and last block: `_k_range`, `_q_range`) nor computed (`_on_blocks`); one a
+# diagonal crosses is masked; one inside runs bare. A row whose window starts
+# right of the first block it visits sees only masked scores there: what it
+# sums meanwhile is wiped by the first real block (alpha = exp(-1e30 - m) is 0
+# exactly), and every row has one: its own position.
+#
 # The softmax statistic the backward needs is one float32 a (row, head): the
 # log-sum-exp, kept as [B, Tq, 128 x ceil(heads / 128)] with head h in lane
 # h, never broadcast. sum(o * do) is computed in the backward kernel.
@@ -226,21 +244,31 @@ def _merge(parts, masks):
     return out
 
 
-def _causal_keep(bq, bk, q0, k0):
+def _causal_keep(bq, bk, q0, k0, window=0):
     rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return cols <= rows
+    if not window:
+        return cols <= rows
+    return jnp.logical_and(cols <= rows, cols > rows - window)
 
 
-def _on_blocks(causal, q0, bq, k0, bk, step):
+def _on_blocks(causal, window, q0, bq, k0, bk, step):
     """Run `step(diag)` for this (q block, k block): not at all where the
-    causal rule empties it, with the mask only where the diagonal crosses."""
+    causal rule or the window empties it, with the mask only where one of
+    the two diagonals crosses (the upper one: column == row; with a
+    `window`, the lower one: column == row - window + 1)."""
     if not causal:
         step(False)
         return
     crosses = k0 + bk - 1 > q0          # some column is right of some row
-    pl.when(jnp.logical_and(k0 <= q0 + bq - 1, crosses))(
-        lambda: step(True))
+    inside = k0 <= q0 + bq - 1          # some column is at or left of a row
+    if window:
+        # the last column is within the window of the first row; the first
+        # column is left of the window of the last row
+        inside = jnp.logical_and(inside, k0 + bk - 1 > q0 - window)
+        crosses = jnp.logical_or(crosses, k0 < q0 + bq - window)
+    pl.when(jnp.logical_and(inside, crosses))(lambda: step(True))
+    # crossed by neither diagonal and not above the upper one: inside
     pl.when(jnp.logical_not(crosses))(lambda: step(False))
 
 
@@ -268,7 +296,8 @@ def _exact_in_bf16(scale: float) -> bool:
     return math.frexp(scale)[0] == 0.5
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal,
+                window):
     # grid (B, q blocks, lane blocks, k blocks): the statistics block of a
     # (batch, q block) stays in VMEM while the lane blocks take their turns.
     # The running max and sum of a head are kept across 128 lanes, every lane
@@ -293,7 +322,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal):
 
     def step(diag):
         k, v = k_ref[0], v_ref[0]
-        keep = _causal_keep(bq, bk, qi * bq, ki * bk) if diag else None
+        keep = (_causal_keep(bq, bk, qi * bq, ki * bk, window) if diag
+                else None)
         alphas, pvs = [], []
         for j in heads:
             s = jax.lax.dot_general(q_sc[j], k, _NT,
@@ -314,7 +344,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal):
         acc_sc[...] = (_merge(alphas, masks) * acc_sc[...]
                        + _merge(pvs, masks))
 
-    _on_blocks(causal, qi * bq, bq, ki * bk, bk, step)
+    _on_blocks(causal, window, qi * bq, bq, ki * bk, bk, step)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
@@ -330,7 +360,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
-                D, scale, causal, want):
+                D, scale, causal, window, want):
     """`want` "dkv": grid (B, lane blocks, k blocks, q blocks), dK and dV
     summed over the q blocks; "dq": grid (.., q blocks, k blocks), dQ summed
     over the k blocks; "all": the dkv grid, and dQ summed for the whole
@@ -380,7 +410,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         stats = lse_ref[0]
         o_do = o_ref[0].astype(jnp.float32) * do.astype(jnp.float32)
-        keep = _causal_keep(bq, bk, qi * bq, ki * bk) if diag else None
+        keep = (_causal_keep(bq, bk, qi * bq, ki * bk, window) if diag
+                else None)
         dks, dvs, dqs = [], [], []
         for j in heads:
             kj, vj = alone(j) if want == "dq" else (k_sc[j], v_sc[j])
@@ -412,7 +443,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         if want != "dkv":
             dq_sc[rows, :] += _merge(dqs, masks)
 
-    _on_blocks(causal, qi * bq, bq, ki * bk, bk, step)
+    _on_blocks(causal, window, qi * bq, bq, ki * bk, bk, step)
 
     # s = scale x q k^T, so dQ and dK carry the scale too: where it is a
     # power of two it is applied once, to the sums, which rounds the same;
@@ -446,18 +477,38 @@ def _kv_lane(q, k):
     return (lambda hb: hb) if group == 1 else (lambda hb: hb // group)
 
 
-def _last_k(causal, bq, bk):
-    """Index map helper: the last k block a q block reads under the causal
-    rule; a later (empty) step names it again, so nothing is fetched."""
+def _k_range(causal, window, bq, bk):
+    """Index map helper: step `ki` of q block `qi` names a k block the q
+    block reads: no later than the last one under the causal rule and, with
+    a `window`, no earlier than the first one inside it. An empty step names
+    its neighbour's block again, so nothing is fetched for it."""
     if not causal:
         return lambda qi, ki: ki
-    return lambda qi, ki: jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+
+    def kmap(qi, ki):
+        ki = jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+        if window:      # the block of column q0 - window + 1
+            ki = jnp.maximum(ki, jnp.maximum(qi * bq - window + 1, 0) // bk)
+        return ki
+
+    return kmap
 
 
-def _first_q(causal, bq, bk):
+def _q_range(causal, window, bq, bk, Tq):
+    """`_k_range`'s twin for the passes whose inner loop is over q blocks:
+    from the first q block at or under the diagonal to, with a `window`, the
+    last one with a row whose window still reaches this k block."""
     if not causal:
         return lambda ki, qi: qi
-    return lambda ki, qi: jnp.maximum(qi, (ki * bk) // bq)
+
+    def qmap(ki, qi):
+        qi = jnp.maximum(qi, (ki * bk) // bq)
+        if window:      # the block of row k0 + bk - 1 + window - 1
+            qi = jnp.minimum(qi, jnp.minimum((ki + 1) * bk + window - 2,
+                                             Tq - 1) // bq)
+        return qi
+
+    return qmap
 
 
 def _params(*semantics):
@@ -474,15 +525,16 @@ def _params(*semantics):
 # is read when the op is traced (the block sizes, a tuner's forced ones among
 # them) comes in as a static argument, so a cached trace never hides it.
 @functools.partial(jax.jit,
-                   static_argnames=("heads", "causal", "blocks", "statistics"))
+                   static_argnames=("heads", "causal", "blocks", "statistics",
+                                    "window"))
 def _packed_forward(q, k, v, *, heads: int, causal: bool,
-                    blocks: FlashBlocks, statistics: bool):
+                    blocks: FlashBlocks, statistics: bool, window: int = 0):
     """[out [B, Tq, E]] and, with `statistics`, the log-sum-exp the backward
     reads: [B, Tq, 128 x ceil(heads / 128)] float32, head h in lane h."""
     B, Tq, Tk, E, D, W = _geometry(q, k, heads)
     bq, bk = blocks
     hpb = W // D
-    kmap = _last_k(causal, bq, bk)
+    kmap = _k_range(causal, window, bq, bk)
     klane = _kv_lane(q, k)
     out_specs = [pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb))]
     out_shape = [jax.ShapeDtypeStruct((B, Tq, E), q.dtype)]
@@ -494,7 +546,7 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
             (B, Tq, _LANES * pl.cdiv(heads, _LANES)), jnp.float32))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, D=D, scale=1.0 / math.sqrt(D),
-                          causal=causal),
+                          causal=causal, window=window),
         grid=(B, Tq // bq, E // W, Tk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb)),
@@ -515,9 +567,10 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("heads", "causal", "blocks", "fused"))
+                   static_argnames=("heads", "causal", "blocks", "fused",
+                                    "window"))
 def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
-                     blocks: FlashBlocks, fused: bool):
+                     blocks: FlashBlocks, fused: bool, window: int = 0):
     """(dq, dk, dv). `fused`: one pass with dQ's accumulator for the whole
     sequence in VMEM; else dK / dV and dQ in a pass each. Where a group of
     query heads shares a K/V head, the kernels write each query head's dK
@@ -529,7 +582,7 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
     group = E // k.shape[2]
     klane = _kv_lane(q, k)
     kernel = functools.partial(_bwd_kernel, D=D, scale=1.0 / math.sqrt(D),
-                               causal=causal)
+                               causal=causal, window=window)
 
     def specs(qrow, krow):
         """In specs of (q, k, v, o, do, lse) given the index maps' q and k
@@ -543,7 +596,7 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
         return [qs, ks, ks, qs, qs, stat], qs, pl.BlockSpec((1, bk, W), at(krow))
 
     # dK and dV (and, fused, dQ): k blocks outside, q blocks inside
-    qmap = _first_q(causal, bq, bk)
+    qmap = _q_range(causal, window, bq, bk, Tq)
     ins, _, kspec = specs(qmap, lambda ki, qi: ki)
     outs = [kspec, kspec]
     shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -573,7 +626,7 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
                   .reshape(k.shape).astype(k.dtype) for a in (dk, dv))
     if fused:
         return got[2], dk, dv
-    kmap = _last_k(causal, bq, bk)
+    kmap = _k_range(causal, window, bq, bk)
     ins, qspec, _ = specs(lambda qi, ki: qi, kmap)
     dq = pl.pallas_call(
         functools.partial(kernel, want="dq"),
@@ -588,8 +641,8 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _packed_attention(q, k, v, heads: int, causal: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _packed_attention(q, k, v, heads: int, causal: bool, window: int = 0):
     """The fused kernels over packed Q [B, T, E] and K, V [B, T, E_kv] (E_kv
     < E: fewer K/V heads, shared by groups of query heads): no dispatch gate.
     Not differentiated, the forward writes no statistics: this is what an
@@ -597,21 +650,21 @@ def _packed_attention(q, k, v, heads: int, causal: bool):
     instead, once (`Executor` traces the forward ops once, under
     differentiation), so no program holds the two side by side any more."""
     return _packed_forward(
-        q, k, v, heads=heads, causal=causal, statistics=False,
+        q, k, v, heads=heads, causal=causal, statistics=False, window=window,
         blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))[0]
 
 
-def _packed_attention_fwd(q, k, v, heads, causal):
+def _packed_attention_fwd(q, k, v, heads, causal, window=0):
     o, lse = _packed_forward(
-        q, k, v, heads=heads, causal=causal, statistics=True,
+        q, k, v, heads=heads, causal=causal, statistics=True, window=window,
         blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
     return o, (q, k, v, o, lse)
 
 
-def _packed_attention_bwd(heads, causal, saved, do):
+def _packed_attention_bwd(heads, causal, window, saved, do):
     q, k, v, o, lse = saved
     return _packed_backward(
-        q, k, v, o, lse, do, heads=heads, causal=causal,
+        q, k, v, o, lse, do, heads=heads, causal=causal, window=window,
         blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
         fused=q.shape[1] <= _FUSED_BWD_MAX_TQ)
 
@@ -619,19 +672,20 @@ def _packed_attention_bwd(heads, causal, saved, do):
 _packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)
 
 
-def _flash_kernel(q, k, v, causal: bool):
+def _flash_kernel(q, k, v, causal: bool, window: int = 0):
     """Direct fused-kernel call over [B, T, H, D], no dispatch gate
     (benchmarks, the tuner and the eligible path all come through here):
     merging H and D is a free reshape to the packed layout."""
     B, Tq, H, D = q.shape
     pack = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # noqa: E731
-    return _packed_attention(pack(q), pack(k), pack(v), H, causal).reshape(
-        B, Tq, H, D)
+    return _packed_attention(pack(q), pack(k), pack(v), H, causal,
+                             window).reshape(B, Tq, H, D)
 
 
 _DISPATCH_COUNTER = "pt_flash_attention_dispatch_total"
 _DISPATCH_HELP = ("attention ops traced, by the path the dispatcher chose "
-                  "from the input's shape (packed: the fused kernels)")
+                  "from the input's shape (packed: the fused kernels; "
+                  "packed_window: the same kernels with a window bound)")
 
 
 def _count_dispatch(path: str) -> None:
@@ -641,18 +695,29 @@ def _count_dispatch(path: str) -> None:
                                    labels={"path": path})
 
 
-def flash_attention(q, k, v, causal: bool = False):
+def flash_attention(q, k, v, causal: bool = False, window: int = 0):
     """[B, T, H, D] attention; K and V may be [B, T, KV, D] with KV dividing
     H (query head j reads K/V head j // (H / KV): at D 128 and up the
     kernels share the K/V block, at D 64 K and V are repeated to H heads
-    first). From T=1024 the fused kernels are the path
-    (and the O(T)-memory one); below that window XLA keeps the job unless
+    first). `window` W > 0 (causal only): position i reads the W keys
+    i - W < j <= i; a window that covers the sequence is `causal` and runs
+    as that; the kernels skip the key blocks wholly left of it and the XLA
+    formulation masks them (one meaning on both paths). From T=1024 the
+    fused kernels are the path
+    (and the O(T)-memory one); below that range XLA keeps the job unless
     the score buffer would exceed the memory threshold. Numerics: the
     inputs' dtype in and out (bf16 under AMP), float32 scores, statistics
     and accumulators inside the kernels. The choice is made when the op is
-    traced and counted in `pt_flash_attention_dispatch_total{path}`."""
+    traced and counted in `pt_flash_attention_dispatch_total{path}` (`xla`,
+    `packed`, or `packed_window` for the kernels with a window bound)."""
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
+    window = int(window or 0)
+    if window < 0 or (window and not causal):
+        raise ValueError(f"window {window}: a positive number of keys, and "
+                         f"only with causal=True")
+    if window >= k.shape[1]:
+        window = 0      # every earlier key is inside it: plain causal
     if q.shape[2] % k.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads do not share "
                          f"{k.shape[2]} K/V heads evenly")
@@ -669,16 +734,16 @@ def flash_attention(q, k, v, causal: bool = False):
     # wrap is a future multi-chip lever. A batch dp does not divide falls
     # back to the XLA formulation, which GSPMD partitions natively.
     sharded = am is not None and am.dp > 1
-    if not flash_eligible(q, k) or (sharded and q.shape[0] % am.dp):
+    if not flash_eligible(q, k, window) or (sharded and q.shape[0] % am.dp):
         _count_dispatch("xla")
-        return _reference(q, k, v, causal)
-    _count_dispatch("packed")
+        return _reference(q, k, v, causal, window)
+    _count_dispatch("packed_window" if window else "packed")
     if sharded:
         call = mesh_dispatch.shard_batch(
-            functools.partial(_flash_kernel, causal=causal),
+            functools.partial(_flash_kernel, causal=causal, window=window),
             (0, 0, 0), ((0, 4),))
         return call(q, k, v)
-    return _flash_kernel(q, k, v, causal)
+    return _flash_kernel(q, k, v, causal, window)
 
 
 @register_op("flash_attention")
@@ -687,8 +752,10 @@ def flash_attention_kernel(ctx):
     multi-head projections; num_heads splits E (a free reshape: the kernels
     read the packed layout). K and V narrower than Q are [B, T, kv_heads x
     D], the head count read from their width, and each serves a group of
-    query heads. Used by
-    layers.multi_head_attention (models/transformer.py)."""
+    query heads. Attr `window` (absent or 0: none, the op as it always was):
+    with `causal`, position i reads the keys i - window < j <= i. Used by
+    layers.multi_head_attention (models/transformer.py; models/afmoe.py:
+    window and global layers in one model) and layers.latent_attention."""
     from .. import amp
 
     # under amp Q and K may arrive float32 (from rms_norm / rotary, which
@@ -702,7 +769,8 @@ def flash_attention_kernel(ctx):
         raise ValueError(f"hidden dim {E} not divisible by heads {heads}")
     D = E // heads
     split = lambda x: x.reshape(B, x.shape[1], -1, D)  # noqa: E731
-    o = flash_attention(split(q), split(k), split(v), causal=causal)
+    o = flash_attention(split(q), split(k), split(v), causal=causal,
+                        window=ctx.attr("window", 0))
     ctx.set_output("Out", o.reshape(B, T, E))
 
 

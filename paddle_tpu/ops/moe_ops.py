@@ -119,21 +119,27 @@ def kernel_width_pad(rows: int, d: int, f: int, dtype) -> int:
 # 25 080 / 25 463 / 24 465 and 12.57 GiB each on the hybrid (the whole rows
 # 19 400 and 13.83), 1 / 2 / 3 / 4 read 30 545 / 30 700 / 31 147 / 30 471
 # and 11.97 / 12.06 / 12.18 / 12.29 GiB on glm (28 260 and 12.05): from
-# three even shares on glm holds more than the whole rows did.
+# three even shares on glm holds more than the whole rows did. A layer may
+# ask for another chunk (`chunk_shares`): Trinity's routers, top 8 of 128 with
+# 16 held (even 0.125), settle at 0.19-0.28 of their rows by layer and seed,
+# on both sides of two even shares, and a layer that takes a second chunk
+# costs its step 3.6 % (PERF.md section 6, PR 42): its layers take three.
 _ROW_BOUND = 2
 
 
-def bounds_rows(held, experts: int) -> bool:
+def bounds_rows(held, experts: int, shares=None) -> bool:
     """Whether a share `held` = (lo, hi) of `experts` is small enough to
-    compute its rows in chunks: under half of the experts."""
-    return _ROW_BOUND * (held[1] - held[0]) < experts
+    compute its rows in chunks of `shares` even shares (None: `_ROW_BOUND`):
+    the chunk is under all the rows (by default: under half of the experts
+    held)."""
+    return (shares or _ROW_BOUND) * (held[1] - held[0]) < experts
 
 
-def row_bound(rows: int, held, experts: int, tile: int) -> int:
-    """R, the rows of a share's chunk: `_ROW_BOUND` times the even share of
-    the `rows` = T x k pairs, up to a whole row `tile`, and `rows` at most
-    (then there is nothing to bound)."""
-    need = -(-_ROW_BOUND * rows * (held[1] - held[0]) // experts)
+def row_bound(rows: int, held, experts: int, tile: int, shares=None) -> int:
+    """R, the rows of a share's chunk: `shares` (None: `_ROW_BOUND`) times
+    the even share of the `rows` = T x k pairs, up to a whole row `tile`,
+    and `rows` at most (then there is nothing to bound)."""
+    need = -(-(shares or _ROW_BOUND) * rows * (held[1] - held[0]) // experts)
     return min(rows, -(-need // tile) * tile)
 
 
@@ -300,7 +306,7 @@ def relu2(x):
 def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
             norm_topk_prob: bool = False, *, scoring: str = "softmax",
             router_bias=None, gate_scale: float = 1.0, held=None,
-            shared=None):
+            shared=None, chunk_shares=None):
     """x [T, d] -> (out [T, d], router logits [T, E] f32, tokens per expert
     [E] int32, pairs per held expert [held] int32 or None, the row path [2]
     int32 or None). y = sum_j gate_j * expert_{e_j}(x) over the token's
@@ -316,8 +322,9 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     knows no shares.
 
     A share under half of the experts (`bounds_rows`) works through that
-    sort in chunks of R rows (`row_bound`: two even shares of the T x k
-    rows), as many as its held pairs fill and at least one: a chunk
+    sort in chunks of R rows (`row_bound`: `chunk_shares` even shares of the
+    T x k rows, two unless the layer says otherwise), as many as its held
+    pairs fill and at least one: a chunk
     gathers R rows of x, runs the matmuls, masks and activation on [R, .]
     and adds the R gate-weighted rows into [T, d]. One chunk in a step whose
     routing keeps to the bound, more in a step whose routing does not, the
@@ -357,12 +364,13 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     # the hidden rows stay at the padded width and the padding adds nothing
     f_pad = kernel_width_pad(rows, d, up_w.shape[2], cd)
     # a share under half of the experts computes R of its rows a chunk
-    R, bound = rows, part and bounds_rows((lo, hi), E)
+    R, bound = rows, part and bounds_rows((lo, hi), E, chunk_shares)
     if bound:
         kernel = gmm_eligible(
             jax.ShapeDtypeStruct((rows, d), cd),
             jax.ShapeDtypeStruct((1, d, up_w.shape[2] + f_pad), cd))
-        R = row_bound(rows, (lo, hi), E, _V5E_TILING[0] if kernel else 8)
+        R = row_bound(rows, (lo, hi), E, _V5E_TILING[0] if kernel else 8,
+                      chunk_shares)
 
     def hidden_rows(xs, down, ups, group_sizes, live):
         """[m, d] rows sorted by expert -> their experts' outputs [m, d];
@@ -471,7 +479,8 @@ def moe_ffn_kernel(ctx):
     SwiGLU experts, SharedGateW [d, f_s] (the shared expert is of the routed
     experts' kind: `expert_act`). Attrs: top_k,
     norm_topk_prob, and where they differ from a softmax router over experts
-    that are all here: scoring, gate_scale, held_lo / held_hi. Out shaped
+    that are all here: scoring, gate_scale, held_lo / held_hi, and
+    chunk_shares (absent: two even shares of the rows a chunk). Out shaped
     like X, in the compute dtype; RouterLogits [tokens, E] float32 (under
     amp too: the router never drops precision, the expert matmuls do);
     TokensPerExpert [E] int32, summing to tokens x top_k; HeldPairs [held]
@@ -495,7 +504,7 @@ def moe_ffn_kernel(ctx):
         scoring=ctx.attr("scoring", "softmax"),
         router_bias=ctx.input("RouterBias"),
         gate_scale=float(ctx.attr("gate_scale", 1.0)), held=held,
-        shared=shared)
+        shared=shared, chunk_shares=ctx.attr("chunk_shares"))
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("RouterLogits", logits)
     ctx.set_output("TokensPerExpert", counts)

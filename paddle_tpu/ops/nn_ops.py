@@ -219,9 +219,19 @@ def rms_norm(x, scale, eps: float):
 def rms_norm_kernel(ctx):
     """Root-mean-square norm over the last axis (Zhang & Sennrich 2019; the
     Llama / OLMo family's norm): x * rsqrt(mean(x^2) + eps) * Scale. No
-    mean subtraction, no bias. Float32 inside and out (see `rms_norm`)."""
-    ctx.set_output("Y", rms_norm(
-        ctx.input("X"), ctx.input("Scale"), ctx.attr("epsilon", 1e-5)))
+    mean subtraction, no bias. Float32 inside and out (see `rms_norm`).
+    Attr `group` G (absent: the whole axis): every run of G lanes is normed
+    on its own with the one Scale [G] (a per-head norm on a packed
+    projection), under the inner scope `per_head`."""
+    x, group = ctx.input("X"), ctx.attr("group")
+    eps = ctx.attr("epsilon", 1e-5)
+    if group is None:
+        ctx.set_output("Y", rms_norm(x, ctx.input("Scale"), eps))
+        return
+    with jax.named_scope("per_head"):
+        y = rms_norm(x.reshape(x.shape[:-1] + (-1, int(group))),
+                     ctx.input("Scale"), eps)
+    ctx.set_output("Y", y.reshape(x.shape))
 
 
 def rotary(x, theta: float, rotary_dim=None):

@@ -257,7 +257,8 @@ def test_attn_device_ms_is_in_the_manifest_for_every_cell():
         if w["config"] in ("gpt2-small", "olmoe-1b-7b")]
     wrapped = {m["name"] for m in manifest["per_layer"]
                if m["name"].endswith(".attn_device_ms")}
-    assert wrapped == {"nemotron.attn_device_ms", "glm.attn_device_ms"}
+    assert wrapped == {"nemotron.attn_device_ms", "glm.attn_device_ms",
+                       "trinity.attn_device_ms"}
 
 
 def test_load_max_over_mean_reads_the_counters_and_checks_the_sum():
@@ -982,11 +983,11 @@ def test_the_manifest_lists_the_glm_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell = GLM + ".train-log10"
-    assert manifest["workloads"][-1]["name"] == cell     # appended, last
-    entry = manifest["workloads"][-1]
+    assert manifest["workloads"][4]["name"] == cell      # appended in PR 37
+    entry = manifest["workloads"][4]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         GLM, "train-log10", 1)
-    config = manifest["configs"][-1]
+    config = manifest["configs"][3]
     assert config["name"] == GLM and config["source"] == _glm_config()["source"]
     assert config["reduced"] == _glm_config()["reduced"] == [
         "num_hidden_layers", "n_routed_experts", "vocab_size"]
@@ -999,22 +1000,24 @@ def test_the_manifest_lists_the_glm_cell_and_its_metrics():
                     "glm.head_device_ms", "glm.opt_device_ms",
                     "glm.donated_gib", "glm.feed_produce_ms_per_step"]
     # appended in PR 37; PR 38 appended one metric of both shares' cells,
-    # PR 40 three of every cell (no `workloads` list)
+    # PR 40 three of every cell (no `workloads` list), PR 42 its own cell's
     names = [m["name"] for m in manifest["per_layer"]]
     every_cell = ["step.xla_inserted_ms", "step.unnamed_ms",
                   "opt.carried_device_ms"]
-    assert names[-len(mine) - 4:] == mine + ["moe.bounded_step_share"] \
-        + every_cell
-    assert manifest["per_layer"][-4]["workloads"] == [NEMO + ".train-log10",
-                                                      cell]
-    assert not any("workloads" in m for m in manifest["per_layer"][-3:])
+    end = names.index("opt.carried_device_ms") + 1
+    assert names[end - len(mine) - 4:end] == mine \
+        + ["moe.bounded_step_share"] + every_cell
+    assert manifest["per_layer"][end - 4]["workloads"] == [
+        NEMO + ".train-log10", cell]
+    assert not any("workloads" in m
+                   for m in manifest["per_layer"][end - 3:end])
     layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
     assert layers["mla.device_ms"] == layers["mla.assemble_ms"] \
         == "Latent attention"
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     # no reader that was there lists the new cell: their entries are untouched
-    assert not [m["name"] for m in manifest["per_layer"][:-4]
+    assert not [m["name"] for m in manifest["per_layer"][:end - 4]
                 if cell in m.get("workloads", ()) and m["name"] not in mine]
     with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
         traffic = json.load(f)
@@ -1093,5 +1096,440 @@ def test_the_glm_cell_rehearses_on_the_cpu(tmp_path):
             "REHEARSAL_ON_CPU.glm.donated_gib",
             "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
     share = result["metrics"]["REHEARSAL_ON_CPU.glm.held_pair_share"]["value"]
+    assert 0.1 < share < 0.45                # 4 of 16 experts held: 0.25
+    assert "choice_counts_off_program" in result["compared"]
+
+
+# ---------------------------------------------------------------------------
+# PR 42: trinity-mini (window and global attention layers in one model)
+TRI = "trinity-mini"
+TRI_CELL = {"batch": 1, "seqlen": 8192}
+W_, G_ = "sliding_attention", "full_attention"
+
+
+def _tri_config():
+    with open(os.path.join(BENCH, "configs", TRI, "config.json")) as f:
+        return json.load(f)
+
+
+def test_trinity_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _tri_config()
+    config_dir = os.path.join(BENCH, "configs", TRI)
+    got = flops.train_flops_per_item(cfg, TRI_CELL, config_dir)
+    # ISSUE 42's arithmetic with the global layer's keys counted as the
+    # window layers' are ((T + 1) / 2, where the issue wrote T / 2: 8 192 more)
+    assert got == 3 * 737951744.0 == 2213855232.0
+    assert 737951744 - 737943552 == 4 * 32 * 128 // 2
+    own = _load("configs", TRI, "flops.py")
+    assert own.keys_seen(8192) == 4096.5
+    assert own.keys_seen(8192, 2048) == 1792.125
+    assert own.keys_seen(1024, 2048) == own.keys_seen(1024) == 512.5
+    # five projections and the kernels over the keys a query sees, by kind
+    assert own.attention_flops_per_token(cfg, 8192, G_) == 54525952 + 67117056
+    assert own.attention_flops_per_token(cfg, 8192, W_) == 54525952 + 29362176
+    # one more routed window layer adds its attention, router, shared expert
+    # and a pair; one more held expert a sixteenth of a pair a routed layer
+    more = own.forward_flops_per_token(
+        dict(cfg, layer_types=cfg["layer_types"] + [W_]), 8192)
+    assert more - 737951744.0 == 83888128 + 524288 + 12582912 + 12582912
+    wider = own.forward_flops_per_token(dict(cfg, held_experts=[0, 17]), 8192)
+    assert wider - 737951744.0 == pytest.approx(4 * 12582912.0 / 16)
+    dense = own.forward_flops_per_token(dict(cfg, num_dense_layers=2), 8192)
+    assert dense - 737951744.0 == 75497472 - (524288 + 2 * 12582912)
+    # every layer global (no window in the model): 4 x 37.75 M more a token
+    flat = own.forward_flops_per_token(dict(cfg, layer_types=[G_] * 5), 8192)
+    assert flat - 737951744.0 == 4 * (67117056 - 29362176)
+
+
+def test_trinity_config_keeps_the_published_sizes():
+    cfg = _tri_config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        published = next(e for e in map(json.loads, f)
+                         if e["name"] == "Trinity-Mini")
+    assert cfg["source"] == published["source_url"]
+    differs = [k for k, v in published["config"].items()
+               if cfg.get(k, "absent") != v]
+    assert sorted(differs) == sorted(cfg["reduced"]) == sorted(
+        ["num_hidden_layers", "layer_types", "num_dense_layers",
+         "num_experts", "vocab_size"])
+    assert cfg["published"] == {k: published["config"][k] for k in differs}
+    assert (cfg["published"]["num_hidden_layers"],
+            cfg["published"]["num_experts"],
+            cfg["published"]["vocab_size"],
+            cfg["published"]["num_dense_layers"]) == (32, 128, 200192, 2)
+    # the cut: published layers 1-5 (one dense layer, four routed ones, one
+    # whole period 3 window : 1 global among the routed), 16 of 128 experts
+    # behind a router that stays 128 wide, an eighth of the vocabulary
+    assert cfg["layer_types"] == published["config"]["layer_types"][1:6] \
+        == [W_, W_, G_, W_, W_]
+    assert cfg["num_hidden_layers"] == 5 and cfg["num_dense_layers"] == 1
+    assert cfg["layer_types"][1:].count(W_) == 3 * cfg["layer_types"][1:].count(G_)
+    assert cfg["vocab_size"] * 8 == 200192
+    assert cfg["router_experts"] == 128 and cfg["held_experts"] == [0, 16]
+    # no width is cut
+    assert (cfg["hidden_size"], cfg["num_attention_heads"], cfg["head_dim"],
+            cfg["num_key_value_heads"], cfg["sliding_window"],
+            cfg["intermediate_size"], cfg["moe_intermediate_size"],
+            cfg["num_experts_per_tok"], cfg["route_scale"]) == (
+        2048, 32, 128, 4, 2048, 6144, 1024, 8, 2.826)
+    assert "8-chip" in cfg["deployment"] and "32" in cfg["distortion"]
+    for key in ("assumed", "departures", "deployment", "distortion",
+                "gradient_limits"):
+        assert cfg[key], key
+    for key in ("qk_norm", "output_gate", "positions", "window_edge", "norms",
+                "mup", "router", "aux_cost", "optimizer", "compute_dtype"):
+        assert cfg["assumed"][key], key
+    # the parameters this chip holds: ISSUE 42's count
+    d, f = 2048, 1024
+    attn = 2 * d * 4096 + 2 * d * 512 + 4096 * d + 256
+    dense = attn + 3 * d * 6144 + 4 * d
+    routed = attn + d * 128 + 17 * 3 * d * f + 4 * d
+    assert (attn, dense, routed) == (27263232, 65020160, 134488320)
+    assert dense + 4 * routed + 2 * 25024 * d + d == 705473792
+
+
+def test_trinity_kernels_count_on_hand_made_cells():
+    flash = _load("kernels", "trinity_flash_attention.py")
+    plain = _load("kernels", "flash_attention.py")
+    cfg = {"layer_types": [W_, G_, W_], "num_attention_heads": 4,
+           "num_key_value_heads": 2, "head_dim": 8, "sliding_window": 3,
+           "num_hidden_layers": 3}
+    cell = {"batch": 2, "seqlen": 5}
+    # a window of 3 over 5 positions: 1 + 2 + 3 + 3 + 3 = 12 pairs of the
+    # causal 15
+    assert flash.pairs(5, 3) == 12 and flash.pairs(5) == 15
+    assert flash.pairs(5, 5) == flash.pairs(5, 9) == 15
+    flops, bytes_ = flash.flops_and_bytes(cfg, cell)
+    assert flops == 2 * 4 * 6 * 2 * 8 * (12 + 15 + 12)
+    # Q, O, dO, dQ at 4 heads, K, V, dK, dV at 2: six of each, three layers
+    assert bytes_ == 3 * 6 * (2 * 5 * 4 * 8 + 2 * 5 * 2 * 8) * 2
+    # without a window in the model it is the plain causal count, K/V heads
+    # and all
+    wide = dict(cfg, layer_types=[G_] * 3)
+    assert flash.flops_and_bytes(wide, cell) == plain.flops_and_bytes(
+        wide, cell)
+    assert flash.flops_and_bytes(dict(cfg, sliding_window=5), cell) \
+        == plain.flops_and_bytes(cfg, cell)
+    one = flash.flops_and_bytes(cfg, cell, [W_])
+    assert one[0] == 2 * 4 * 12 * 8 * 12 and one[1] == bytes_ / 3
+    got = flash.flops_and_bytes(_tri_config(), TRI_CELL)
+    window, whole = 14681088, 33558528           # ISSUE 42's pairs: 43.75 %
+    assert flash.pairs(8192, 2048) == window and flash.pairs(8192) == whole
+    assert got[0] == 32 * 12 * 128 * (4 * window + whole)      # 4.54 TFLOP
+    assert got[1] == 5 * 6 * 8192 * (4096 + 512) * 2
+    assert got[0] / 197e12 > got[1] / 819e9                    # compute-bound
+    # every layer counted causal: 1.8 times the work the step needs
+    assert plain.flops_and_bytes(_tri_config(), TRI_CELL)[0] \
+        == 32 * 12 * 128 * 5 * whole
+    gmm = _load("kernels", "trinity_grouped_matmul.py")
+    cfg = _tri_config()
+    d, f = 2048, 1024
+    flops, bytes_ = gmm.flops_and_bytes(cfg, TRI_CELL)
+    rows = 4 * 8192 * 8 * 16 / 128          # even routing: 8 192 a layer
+    assert gmm.routed_layers(cfg) == 4 and rows == 32768
+    assert flops == 18 * rows * d * f
+    assert bytes_ == 2 * (9 * 4 * 16 * d * f + rows * (5 * d + 7 * f))
+    assert gmm.flops_and_bytes(cfg, TRI_CELL, rows=100.0) == (
+        18 * 100.0 * d * f, 2 * (9 * 4 * 16 * d * f + 100.0 * (5 * d + 7 * f)))
+
+
+TRI_MOE = "moe_ffn.afmoe.h1.moe.tmp_66"
+
+
+def _tri_layer(i, window):
+    """(program ops, {part: scope}) of one gated attention layer, as
+    `models.afmoe_lm` appends them."""
+    def op(kind, name, inputs, outputs):
+        return {"type": kind, "scope": f"{kind}.{name}", "inputs": inputs,
+                "outputs": outputs}
+
+    n = f"l{i}"
+    ops = [op("rms_norm", n + "n1", {"X": [n + "x"]}, {"Y": [n + "h"]}),
+           op("mul", n + "q", {"X": [n + "h"], "Y": ["wq"]}, {"Out": [n + "q"]}),
+           op("mul", n + "k", {"X": [n + "h"], "Y": ["wk"]}, {"Out": [n + "k"]}),
+           op("mul", n + "v", {"X": [n + "h"], "Y": ["wv"]}, {"Out": [n + "v"]}),
+           op("rms_norm", n + "qn", {"X": [n + "q"]}, {"Y": [n + "qn"]}),
+           op("rms_norm", n + "kn", {"X": [n + "k"]}, {"Y": [n + "kn"]})]
+    parts = {"q_proj": ops[1], "k_proj": ops[2], "v_proj": ops[3],
+             "q_norm": ops[4], "k_norm": ops[5]}
+    q, k = n + "qn", n + "kn"
+    if window:
+        ops += [op("rotary_embedding", n + "qr", {"X": [q]}, {"Out": [n + "qr"]}),
+                op("rotary_embedding", n + "kr", {"X": [k]}, {"Out": [n + "kr"]})]
+        parts.update(q_rotary=ops[-2], k_rotary=ops[-1])
+        q, k = n + "qr", n + "kr"
+    ops += [op("flash_attention", n + "o", {"Q": [q], "K": [k], "V": [n + "v"]},
+               {"Out": [n + "o"]}),
+            op("mul", n + "g", {"X": [n + "h"], "Y": ["wg"]}, {"Out": [n + "g"]}),
+            op("sigmoid", n + "s", {"X": [n + "g"]}, {"Out": [n + "s"]}),
+            op("elementwise_mul", n + "m", {"X": [n + "o"], "Y": [n + "s"]},
+               {"Out": [n + "m"]}),
+            op("mul", n + "out", {"X": [n + "m"], "Y": ["wo"]},
+               {"Out": [n + "out"]})]
+    parts.update(kernels=ops[-5], gate_proj=ops[-4], gate_sigmoid=ops[-3],
+                 gate_mul=ops[-2], out_proj=ops[-1])
+    return ops, {part: o["scope"] for part, o in parts.items()}
+
+
+def _tri_run_record():
+    """Two steps of a window layer, a global layer and one routed layer."""
+    win_ops, win = _tri_layer(0, True)
+    glo_ops, glo = _tri_layer(1, False)
+    ops = [
+        _row(win["kernels"], 6_000_000, "tpu_custom_call", "jvp("),
+        _row(win["kernels"], 12_000_000, "tpu_custom_call", "transpose(jvp("),
+        _row(win["kernels"], 1_000_000, None, "transpose(jvp("),  # dK, dV sums
+        _row(win["kernels"], 50_000_000, None, "", container=True),   # a loop
+        _row(glo["kernels"], 9_000_000, "tpu_custom_call", "jvp("),
+        _row(glo["kernels"], 18_000_000, "tpu_custom_call", "transpose(jvp("),
+        _row(win["q_proj"], 4_000_000), _row(win["gate_proj"], 4_000_000),
+        _row(win["out_proj"], 4_000_000, None, "transpose(jvp("),
+        _row(glo["k_proj"], 1_000_000), _row(glo["v_proj"], 1_000_000),
+        _row(win["q_norm"], 600_000), _row(win["k_norm"], 200_000),
+        _row(glo["q_norm"], 600_000), _row(win["q_rotary"], 800_000),
+        _row(win["k_rotary"], 200_000), _row(win["gate_sigmoid"], 400_000),
+        _row(glo["gate_mul"], 1_200_000),
+        _row(TRI_MOE, 4_000_000, "tpu_custom_call", "", "experts"),
+        _row(TRI_MOE, 2_000_000, None, "", "shared"),
+        _row(TRI_MOE, 3_000_000, None, "", "combine"),
+        _row("mul.fc_9.tmp_9", 5_000_000),        # the head: no part of it
+        _row("rms_norm.l0n1", 700_000),           # the layer's own n1: none
+    ]
+    held = 'pt_moe_held_pairs_total{expert="%d",layer="afmoe.h1.moe"}'
+    every = 'pt_moe_expert_tokens_total{expert="%d",layer="afmoe.h1.moe"}'
+    path = 'pt_moe_row_path_total{layer="afmoe.h1.moe",path="%d"}'
+    registry = {held % 0: 8000.0, held % 1: 8384.0,
+                every % 0: 8000.0, every % 1: 8384.0, every % 100: 114688.0,
+                path % 0: 2.0, "pt_executor_donated_bytes": 8.4e9}
+    program_ops = win_ops + glo_ops + [
+        {"type": "moe_ffn", "scope": TRI_MOE, "inputs": {"X": ["h2"]},
+         "outputs": {"Out": ["afmoe.h1.moe.tmp_66"]}},
+        {"type": "mul", "scope": "mul.fc_9.tmp_9",
+         "inputs": {"X": ["hf"], "Y": ["w"]}, "outputs": {"Out": ["l"]}}]
+    return {"steps": 2, "trace": {"ops": ops}, "registry": registry,
+            "device": {"kind": "TPU v5 lite"}, "config": _tri_config(),
+            "cell": TRI_CELL, "program_ops": program_ops}, win, glo
+
+
+def test_window_readers_tell_the_layers_apart_by_structure():
+    run, win, glo = _tri_run_record()
+    reader = _load("layer_metrics", "attn.window_ms.py")
+    layers = reader.layers(run["program_ops"])
+    assert [layer["kind"] for layer in layers] == ["window", "global"]
+    assert layers[0]["parts"] == {scope: part for part, scope in win.items()}
+    assert layers[1]["parts"] == {scope: part for part, scope in glo.items()}
+    # the window layer's kernels, forward and backward; not the rows XLA
+    # runs around them, not the loop, not the global layer's
+    assert reader.compute(run) == pytest.approx(18.0 / 2)
+    info = reader.info(run)
+    assert (info["window_layers"], info["global_layers"]) == (1, 1)
+    assert info["global_kernels_ms"] == pytest.approx(27.0 / 2)
+    assert info["one_window_layer_over_one_global"] == pytest.approx(18 / 27)
+    assert info["by_pass_ms"] == pytest.approx(
+        {"window_forward_ms": 3.0, "window_backward_ms": 6.0,
+         "global_forward_ms": 4.5, "global_backward_ms": 9.0})
+    assemble = _load("layer_metrics", "attn.assemble_ms.py")
+    # neither a GEMM nor a kernel, both layers: the norms, the rotary passes,
+    # the gate's two passes and the 1 ms under the kernels' scope
+    assert assemble.compute(run) == pytest.approx(
+        (0.6 + 0.2 + 0.6 + 0.8 + 0.2 + 0.4 + 1.2 + 1.0) / 2)
+    assert assemble.info(run)["by_part_ms"] == pytest.approx(
+        {"q_norm": 0.6, "k_norm": 0.1, "q_rotary": 0.4, "k_rotary": 0.1,
+         "gate_sigmoid": 0.2, "gate_mul": 0.6, "around_kernels": 0.5})
+    # a Program without a gated attention layer (every other configuration,
+    # the parent of the PR that added it), or no trace: nothing, not raised
+    for empty in (dict(run, trace=None), dict(run, program_ops=None),
+                  dict(run, program_ops=_glm_run_record()["program_ops"]),
+                  dict(run, program_ops=_nemo_run_record()["program_ops"])):
+        assert reader.compute(empty) is None
+        assert assemble.compute(empty) is None
+
+
+def test_trinity_rooflines_on_a_hand_made_run_record():
+    run, _, _ = _tri_run_record()
+    flash = _load("layer_metrics", "trinity.flash_roofline.py")
+    # the five layers' 4.54 TFLOP at 197 TFLOP/s over the kernels' 22.5 ms a
+    # step (hand-made times): shown as it is
+    window, whole = 14681088, 33558528
+    need = 32 * 12 * 128 * (4 * window + whole) / 197e12
+    assert flash.compute(run) == pytest.approx(100 * need / 22.5e-3, rel=1e-3)
+    info = flash.info(run)
+    assert info["kernels_per_step"] == 4.0 and info["bound"] == "compute"
+    # each kind on its own kernels: four window layers' need over 9 ms, the
+    # global layer's over 13.5 ms
+    assert info["window_layers_pct"] == pytest.approx(
+        100 * 32 * 12 * 128 * 4 * window / 197e12 / 9e-3, rel=1e-3)
+    assert info["global_layers_pct"] == pytest.approx(
+        100 * 32 * 12 * 128 * whole / 197e12 / 13.5e-3, rel=1e-3)
+    assert flash.compute(dict(run, trace=None)) is None
+    gmm = _load("layer_metrics", "trinity.gmm_roofline.py")
+    d, f = 2048, 1024                       # 2 steps, 16 384 held pairs
+    want_bytes = 2 * (9 * 4 * 16 * d * f + 8192.0 * (5 * d + 7 * f))
+    assert gmm.info(run)["flops_per_step"] == 18 * 8192.0 * d * f
+    assert gmm.info(run)["bytes_per_step"] == want_bytes
+    assert gmm.info(run)["held_pairs_per_step"] == 8192.0
+    assert gmm.compute(run) == pytest.approx(
+        100 * want_bytes / 819e9 / 2e-3, rel=1e-3)    # the kernels' 2 ms
+    assert gmm.info(run)["bound"] == "memory"
+    no_held = {k: v for k, v in run["registry"].items()
+               if "held_pairs" not in k}
+    assert gmm.compute(dict(run, registry=no_held)) is None
+    assert gmm.compute(dict(run, trace=None)) is None
+
+
+TRI_WRAPPERS = {"trinity.head_device_ms": "head.device_ms",
+                "trinity.feed_produce_ms_per_step": "feed.produce_ms_per_step",
+                "trinity.opt_device_ms": "opt.device_ms",
+                "trinity.donated_gib": "step.donated_gib",
+                "trinity.attn_device_ms": "attn.device_ms",
+                "trinity.held_pair_share": "moe.held_pair_share",
+                "trinity.bounded_step_share": "moe.bounded_step_share",
+                "trinity.moe_device_ms": "nemotron.moe_device_ms",
+                "trinity.moe_dispatch_ms": "nemotron.moe_dispatch_ms",
+                "trinity.load_max_over_mean": "nemotron.load_max_over_mean"}
+
+
+@pytest.mark.parametrize("name", sorted(TRI_WRAPPERS))
+def test_a_trinity_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run, _, _ = _tri_run_record()           # 2 steps x 8 192 tokens x 8 pairs
+    run["program_ops"] += [
+        {"type": "adam", "scope": "adam.w", "inputs": {"Param": ["w"]},
+         "outputs": {"ParamOut": ["w"]}},
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.s",
+         "inputs": {"Logits": ["l"], "Label": ["y"]},
+         "outputs": {"Softmax": ["s"], "Loss": ["c"]}}]
+    run["trace"]["ops"].append(_row("adam.w", 2_000_000))
+    run["timers_s"] = {"prefetch.read": 0.004, "prefetch.batch": 0.002}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", TRI_WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == TRI_WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    if hasattr(wrapper, "info"):
+        assert wrapper.info(run) == wrapped.info(run)
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+    expected = {"trinity.held_pair_share": 16384 / 131072,       # 0.125
+                "trinity.bounded_step_share": 1.0,
+                "trinity.load_max_over_mean": 114688 / 1024,
+                "trinity.moe_device_ms": 4.5,
+                "trinity.moe_dispatch_ms": 1.5}
+    if name in expected:
+        assert got == pytest.approx(expected[name])
+
+
+def test_the_manifest_lists_the_trinity_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = TRI + ".train-log10"
+    assert manifest["workloads"][-1]["name"] == cell     # appended, last
+    entry = manifest["workloads"][-1]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        TRI, "train-log10", 1)
+    assert sum(w["chips"] for w in manifest["workloads"]) == 6   # no 4-chip
+    config = manifest["configs"][-1]
+    assert config["name"] == TRI and config["source"] == _tri_config()["source"]
+    assert config["reduced"] == _tri_config()["reduced"] == [
+        "num_hidden_layers", "layer_types", "num_dense_layers", "num_experts",
+        "vocab_size"]
+    mine = [m["name"] for m in manifest["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine == ["attn.window_ms", "attn.assemble_ms",
+                    "trinity.flash_roofline", "trinity.gmm_roofline",
+                    "trinity.moe_device_ms", "trinity.moe_dispatch_ms",
+                    "trinity.held_pair_share", "trinity.load_max_over_mean",
+                    "trinity.bounded_step_share", "trinity.attn_device_ms",
+                    "trinity.head_device_ms", "trinity.opt_device_ms",
+                    "trinity.donated_gib", "trinity.feed_produce_ms_per_step"]
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(mine):] == mine                    # appended, last
+    layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
+    assert layers["attn.window_ms"] == layers["attn.assemble_ms"] \
+        == "Window attention"
+    for name in mine:
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    # no reader that was there lists the new cell: their entries are untouched
+    assert not [m["name"] for m in manifest["per_layer"][:-len(mine)]
+                if cell in m.get("workloads", ())]
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        traffic = json.load(f)
+    assert (traffic["batch"], traffic["seqlen"], traffic["sync_every"],
+            traffic["warmup_steps"], traffic["trace_seconds"]) == (
+        1, 8192, 10, 20, 4)
+
+
+def test_the_benchmarks_trinity_reference_is_the_trees_bit_for_bit():
+    """`chipbench/configs/trinity-mini/reference.py` is a copy of
+    `tests/afmoe_reference.py`, text for text, and gives the same cost,
+    gradients and routers to the bit on the CPU, its own choice or a handed
+    one: the two cannot drift apart unseen."""
+    import afmoe_reference as tree
+
+    copy = _load("configs", TRI, "reference.py")
+    assert open(copy.__file__).read() == open(tree.__file__).read()
+    cfg = dict(_tri_config(), **_tri_config()["rehearsal"])
+    d, V = cfg["hidden_size"], cfg["vocab_size"]
+    H, KV, D = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                cfg["head_dim"])
+    E, held, f = cfg["router_experts"], 4, cfg["moe_intermediate_size"]
+    attn = [(d,), (d, H * D), (d, KV * D), (d, KV * D), (D,), (D,),
+            (d, H * D), (H * D, d), (d,), (d,)]
+    kinds = {"dense": [(d, cfg["intermediate_size"])] * 2
+             + [(cfg["intermediate_size"], d)],
+             "routed": [(d, E), (held, d, f), (held, d, f), (held, f, d),
+                        (E,), (d, f), (d, f), (f, d)]}
+    rng = np.random.RandomState(0)
+    shapes = [(V, d)] + [s for kind in tree._kinds(cfg)
+                         for s in attn + kinds[kind] + [(d,)]] + [(d,), (d, V)]
+    params = [(rng.randn(*s) * 0.2 + (len(s) == 1)).astype(np.float32)
+              for s in shapes]
+    toks = rng.randint(0, V, (2, 41))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    own = [m.loss_grads_and_routers(cfg, params, feed) for m in (tree, copy)]
+    choice = copy.chosen(cfg, params, [z for _, _, z in own[1][2]])
+    handed = [m.loss_grads_and_routers(cfg, params, feed, choice)
+              for m in (tree, copy)]
+    for (c1, g1, r1), (c2, g2, r2) in (own, handed):
+        assert float(c1) == float(c2) and np.isfinite(float(c1))
+        assert len(g1) == len(g2) == len(params) and len(r1) == len(r2) == 2
+        for a, b in zip(g1, g2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(r1, r2):
+            np.testing.assert_array_equal(np.asarray(a[2]), np.asarray(b[2]))
+    # the reference's own choice, handed back to it, is its own result
+    assert float(own[0][0]) == float(handed[0][0])
+    assert [int(m.sum()) for m in choice] == [2 * 40 * 3] * 2
+
+
+def test_the_trinity_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference (handed the program's choice) at the rehearsal's tolerances,
+    the routed counters reach the run record, and every metric's name
+    carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         TRI + ".train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486142"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.trinity.held_pair_share",
+            "REHEARSAL_ON_CPU.trinity.load_max_over_mean",
+            "REHEARSAL_ON_CPU.trinity.bounded_step_share",
+            "REHEARSAL_ON_CPU.trinity.donated_gib",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    share = result["metrics"][
+        "REHEARSAL_ON_CPU.trinity.held_pair_share"]["value"]
     assert 0.1 < share < 0.45                # 4 of 16 experts held: 0.25
     assert "choice_counts_off_program" in result["compared"]

@@ -226,6 +226,217 @@ def test_bthd_entry_and_packed_entry_agree():
                                   np.asarray(split(packed)))
 
 
+# ---------------------------------------------------------------------------
+# The window bound (a second, lower diagonal: position i reads the keys
+# i - W < j <= i) in the same kernels, interpreted, against a mask written
+# out in numpy. Blocks are handed in, so that a 512-row case has 4 x 4 of them.
+
+def _window_oracle(q, k, v, H, window):
+    """Packed float32 attention under `0 <= i - j < window`, GQA by
+    repeating K and V: plain numpy-style jnp, no shared code with the op."""
+    B, T, E = q.shape
+    D = E // H
+    KV = k.shape[2] // D
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    qh = q.reshape(B, T, H, D)
+    kh = jnp.repeat(k.reshape(B, T, KV, D), H // KV, axis=2)
+    vh = jnp.repeat(v.reshape(B, T, KV, D), H // KV, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / np.sqrt(D)
+    ahead = np.arange(T)[:, None] - np.arange(T)[None, :]
+    keep = (ahead >= 0) & (ahead < window)
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vh).reshape(B, T, E)
+
+
+def _window_case(T, H, KV, D, dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    mk = lambda heads: jnp.asarray(rng.randn(1, T, heads * D) * 0.5, dtype)  # noqa: E731
+    return mk(H), mk(KV), mk(KV), jnp.asarray(rng.randn(1, T, H * D),
+                                              jnp.float32)
+
+
+WINDOW_CASES = [
+    # id, T, heads, kv heads, D, window, (block_q, block_k), dtype
+    ("one_block", 512, 2, 2, 128, 128, (128, 128), jnp.float32),
+    ("several_blocks", 512, 2, 2, 128, 256, (128, 128), jnp.float32),
+    ("not_a_multiple_of_the_block", 768, 1, 1, 128, 384, (256, 256),
+     jnp.float32),
+    ("narrower_than_the_block", 512, 1, 1, 128, 128, (256, 256), jnp.float32),
+    ("uneven_blocks", 512, 1, 1, 128, 128, (256, 128), jnp.float32),
+    ("uneven_blocks_k_larger", 512, 1, 1, 128, 256, (128, 256), jnp.float32),
+    ("d64_two_heads_a_block", 512, 2, 2, 64, 128, (128, 128), jnp.float32),
+    ("d256_bf16", 512, 1, 1, 256, 256, (128, 128), jnp.bfloat16),
+    ("gqa_group_2", 384, 4, 2, 128, 128, (128, 128), jnp.float32),
+    ("gqa_group_8_bf16", 512, 8, 1, 128, 256, (128, 128), jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("T,H,KV,D,window,blocks,dtype",
+                         [c[1:] for c in WINDOW_CASES],
+                         ids=[c[0] for c in WINDOW_CASES])
+def test_window_kernels_match_the_plain_mask(T, H, KV, D, window, blocks,
+                                             dtype):
+    """Forward, the fused backward and the split backward (dK / dV and dQ in
+    a pass each) under `causal + window`, against the mask written out."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops import flash_ops
+
+    q, k, v, w = _window_case(T, H, KV, D, dtype)
+    blocks = flash_ops.FlashBlocks(*blocks)
+    with pltpu.force_tpu_interpret_mode():
+        out, lse = flash_ops._packed_forward(
+            q, k, v, heads=H, causal=True, blocks=blocks, statistics=True,
+            window=window)
+        do = w.astype(dtype)
+        back = [flash_ops._packed_backward(
+            q, k, v, out, lse, do, heads=H, causal=True, blocks=blocks,
+            fused=fused, window=window) for fused in (True, False)]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, vjp = jax.vjp(lambda *a: _window_oracle(*a, H, window), *f32)
+    ref_grads = vjp(do.astype(jnp.float32))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+    for grads in back:
+        for g, r in zip(grads, ref_grads):
+            assert g.dtype == dtype and g.shape == r.shape
+            scale = float(jnp.max(jnp.abs(r)))
+            np.testing.assert_allclose(np.asarray(g, np.float32) / scale,
+                                       np.asarray(r) / scale, rtol=0,
+                                       atol=tol)
+
+
+@pytest.mark.parametrize("T,bq,bk,window", [
+    (1024, 128, 128, 128), (1024, 128, 128, 384), (1024, 256, 128, 128),
+    (1024, 128, 256, 384), (1024, 256, 256, 384), (8192, 1024, 1024, 2048),
+    (8192, 512, 1024, 2048),
+], ids=["w1blk", "w3blk", "q256_k128", "q128_k256_w384", "w1.5blk",
+        "trinity_8k", "trinity_8k_uneven"])
+def test_window_index_maps_name_only_blocks_inside_the_band(T, bq, bk, window):
+    """The k blocks a q block's steps name (and the q blocks a k block's
+    steps name in the backward) are exactly those with a pair inside the band
+    0 <= i - j < window: nothing outside it is fetched. At the Trinity cell's
+    sizes (T 8192, blocks of 1024, W 2048) a q block names at most 3."""
+    from paddle_tpu.ops import flash_ops
+
+    nq, nk = T // bq, T // bk
+    kmap = flash_ops._k_range(True, window, bq, bk)
+    qmap = flash_ops._q_range(True, window, bq, bk, T)
+
+    def touches(qi, ki):
+        # the band's nearest pair: the block's closest (row, column)
+        lo = qi * bq - (ki * bk + bk - 1)           # least i - j
+        hi = qi * bq + bq - 1 - ki * bk             # greatest i - j
+        return hi >= 0 and lo < window
+
+    for qi in range(nq):
+        named = {int(kmap(qi, ki)) for ki in range(nk)}
+        assert named == {ki for ki in range(nk) if touches(qi, ki)}, qi
+    for ki in range(nk):
+        named = {int(qmap(ki, qi)) for qi in range(nq)}
+        assert named == {qi for qi in range(nq) if touches(qi, ki)}, ki
+    if (T, bq, bk, window) == (8192, 1024, 1024, 2048):
+        assert max(len({int(kmap(qi, ki)) for ki in range(nk)})
+                   for qi in range(nq)) == 3
+
+
+def test_window_kernel_neither_reads_nor_computes_blocks_left_of_the_band():
+    """Keys and values wholly left of the last q block's window are NaN: a
+    step that fetched and multiplied them, masked or not, would leave NaN
+    in that q block's output (0 x NaN). It stays finite and right; an
+    earlier q block, whose window holds those keys, does not."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops import flash_ops
+
+    T, H, D, window = 512, 1, 128, 128
+    q, k, v, _ = _window_case(T, H, H, D, jnp.float32, seed=3)
+    ref = _window_oracle(q, k, v, H, window)
+    # the last q block (rows 384..511) reads columns 257..511: blocks 2, 3
+    poison = jnp.where(jnp.arange(T)[None, :, None] < 256, jnp.nan, 1.0)
+    with pltpu.force_tpu_interpret_mode():
+        out, _ = flash_ops._packed_forward(
+            q, k * poison, v * poison, heads=H, causal=True, statistics=True,
+            blocks=flash_ops.FlashBlocks(128, 128), window=window)
+    out = np.asarray(out)
+    np.testing.assert_allclose(out[:, 384:], np.asarray(ref)[:, 384:],
+                               rtol=2e-5, atol=2e-5)
+    assert np.isnan(out[:, :256]).any()
+
+
+def test_window_wider_than_the_sequence_is_causal(monkeypatch):
+    """A window that holds every earlier key runs as plain `causal` (counted
+    `packed`, not `packed_window`) and gives its bits; a narrower one is
+    counted `packed_window`; both agree with XLA's formulation, which learnt
+    the same mask."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.obs import metrics
+    from paddle_tpu.ops import flash_ops
+
+    def count(path):
+        return metrics.registry().counter_value(
+            "pt_flash_attention_dispatch_total", labels={"path": path})
+
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(1, 1024, 2, 64) * 0.5, jnp.float32)
+    xla_wide = flash_attention(x, x, x, causal=True, window=1024)
+    xla_narrow = flash_attention(x, x, x, causal=True, window=256)
+    monkeypatch.setattr(flash_ops.jax, "default_backend", lambda: "tpu")
+    was = {p: count(p) for p in ("packed", "packed_window", "xla")}
+    with pltpu.force_tpu_interpret_mode():
+        causal = flash_attention(x, x, x, causal=True)
+        wide = flash_attention(x, x, x, causal=True, window=4096)
+        narrow = flash_attention(x, x, x, causal=True, window=256)
+        odd = flash_attention(x, x, x, causal=True, window=200)
+    now = {p: count(p) for p in ("packed", "packed_window", "xla")}
+    assert now["packed"] - was["packed"] == 2
+    assert now["packed_window"] - was["packed_window"] == 1
+    assert now["xla"] - was["xla"] == 1     # 200 is not a whole tile
+    np.testing.assert_array_equal(np.asarray(wide), np.asarray(causal))
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(xla_wide),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(narrow), np.asarray(xla_narrow),
+                               rtol=2e-5, atol=2e-5)
+    assert float(jnp.max(jnp.abs(narrow - causal))) > 1e-3
+    assert float(jnp.max(jnp.abs(odd - narrow))) > 1e-4
+    with pytest.raises(ValueError, match="causal=True"):
+        flash_attention(x, x, x, causal=False, window=256)
+
+
+def test_xla_formulation_masks_the_window_to_the_token():
+    """Position i reads i - W + 1 and not i - W."""
+    T, W = 12, 4
+    rng = np.random.RandomState(1)
+    q, k = (jnp.asarray(rng.randn(1, T, 1, 8), jnp.float32) for _ in "qk")
+    v = jnp.eye(T, dtype=jnp.float32)[None, :, None, :]   # P itself comes out
+    p = np.asarray(pp.scaled_dot_product_attention(
+        q, k, v, causal=True, window=W))[0, :, 0, :]
+    ahead = np.arange(T)[:, None] - np.arange(T)[None, :]
+    assert ((p > 0) == ((ahead >= 0) & (ahead < W))).all()
+    np.testing.assert_allclose(p.sum(-1), 1.0, rtol=1e-6)
+
+
+def test_window_absent_is_the_op_as_it_was():
+    """No `window`: the kernels trace to what they traced to before the
+    window came (the older configurations' step programs stand: the digests
+    of tests/test_tpu_compile.py), here as the same jaxpr whether `window`
+    is left out or 0, and another with one."""
+    from paddle_tpu.ops import flash_ops
+
+    q = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
+    blocks = flash_ops.FlashBlocks(512, 512)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda q, k, v: flash_ops._packed_forward(
+            q, k, v, heads=2, causal=True, blocks=blocks, statistics=True,
+            **kw))(q, q, q))
+
+    assert text() == text(window=0)
+    assert text() != text(window=256)
+
+
 @pytest.mark.parametrize("shape,ok", [
     ((1, 1024, 12, 64), True),     # gpt2-small: two heads a lane block
     ((1, 1024, 3, 64), False),     # odd head count at D 64: half a block
@@ -242,6 +453,18 @@ def test_shapes_the_packed_kernel_takes(shape, ok):
 
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     assert _shapes_flash_ok(x, x) is ok
+
+
+@pytest.mark.parametrize("window,ok", [(0, True), (2048, True), (128, True),
+                                       (2000, False)])
+def test_shapes_rule_wants_a_window_of_whole_tiles(window, ok):
+    """Trinity-Mini's layers: 32 query heads over 4 K/V heads at D 128, T
+    8192, a window of 2048 (global layers: none)."""
+    from paddle_tpu.ops.flash_ops import _shapes_flash_ok
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16)
+    assert _shapes_flash_ok(q, k, window) is ok
 
 
 def _dispatch_counts():

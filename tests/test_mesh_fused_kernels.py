@@ -269,12 +269,13 @@ def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch):
 
     calls = []
 
-    def fake_kernel(q, k, v, causal):
+    def fake_kernel(q, k, v, causal, window=0):
         calls.append(tuple(q.shape))
-        return flash_ops._reference(q, k, v, causal)
+        return flash_ops._reference(q, k, v, causal, window)
 
     monkeypatch.setattr(flash_ops, "_flash_kernel", fake_kernel)
-    monkeypatch.setattr(flash_ops, "flash_eligible", lambda q, k=None: True)
+    monkeypatch.setattr(flash_ops, "flash_eligible",
+                        lambda q, k=None, window=0: True)
     rng = np.random.RandomState(0)
     mk = lambda: jnp.asarray(rng.randn(16, 32, 4, 64) * 0.3, jnp.float32)
     q, k, v = mk(), mk(), mk()

@@ -401,8 +401,8 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
 
     monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
     monkeypatch.setattr(
-        flash_ops, "flash_eligible", lambda q, k=None: (
-            flash_ops._shapes_flash_ok(q, q if k is None else k)
+        flash_ops, "flash_eligible", lambda q, k=None, window=0: (
+            flash_ops._shapes_flash_ok(q, q if k is None else k, window)
             and flash_ops._prefers_flash(q, q if k is None else k)))
     monkeypatch.setattr(moe_ops, "gmm_eligible", moe_ops._shapes_gmm_ok)
     pt.reset()
@@ -583,6 +583,47 @@ def test_glm_moe_step_program_fits_one_chip(one_chip, compiled_mode,
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 7.0e9          # 12 B a parameter
     print("glm step: arguments %.3f GiB, temporaries %.3f GiB" % (
+        memory.argument_size_in_bytes / 2**30,
+        memory.temp_size_in_bytes / 2**30))
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.0 * 2**30)
+
+
+def test_afmoe_step_program_fits_one_chip(one_chip, compiled_mode,
+                                          monkeypatch):
+    """The Trinity-Mini share's whole step at the size `configs/afmoe.py`
+    trains (batch 1 x T 8192, 705 M parameters: a dense layer and four routed
+    ones of 16 held experts, 32-over-4 heads of 128, layers window, window,
+    global, window, window) compiles for the described v5e: under 15.0 GiB by
+    the compiler's own books (which settles the 8-chip share against the
+    16-chip one before any chip time), five forward and five fused backward
+    attention launches, the four window layers' kernels ANOTHER kernel body
+    than the global layer's (the lower diagonal is in it), the grouped-matmul
+    kernels in it and no `ragged-dot`."""
+    config = _load_module(os.path.join(ROOT, "configs", "afmoe.py"))
+    raw, args = _step_program(config.get_model, 1, 8192, one_chip,
+                              monkeypatch)
+    launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
+    assert launches["flash_attention_fwd"] == 5
+    assert launches["flash_attention_bwd"] == 5
+    lowered = jax.jit(raw, donate_argnums=(0,)).lower(*args)
+    # by kernel name, the distinct serialized bodies in the lowered text (a
+    # jitted launch is lowered once however many layers call it): the global
+    # layer's and the window layers'
+    bodies = {}
+    for body, name in re.findall(
+            r'backend_config = "((?:[^"\\]|\\.)*)"[^\n]*?'
+            r'kernel_name = "(flash_attention_\w+)"', lowered.as_text()):
+        bodies.setdefault(name, set()).add(body)
+    assert {n: len(b) for n, b in bodies.items()} == {
+        "flash_attention_fwd": 2, "flash_attention_bwd": 2}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_attention_bwd" in text and "ragged-dot" not in text
+    assert "gmm" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 8.4e9          # 12 B a parameter
+    print("afmoe step: arguments %.3f GiB, temporaries %.3f GiB" % (
         memory.argument_size_in_bytes / 2**30,
         memory.temp_size_in_bytes / 2**30))
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
