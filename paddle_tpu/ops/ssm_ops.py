@@ -22,27 +22,40 @@ under amp) and accumulate in float32. The oracle in the tests is the
 recurrence above, token by token (`tests/nemotron_h_reference.py`).
 
 Two formulations of that one form, chosen when the op is traced and counted
-in `pt_ssm_scan_dispatch_total{path}` (`ssd_scan_packed`; no flag, no
-attribute):
+in `pt_ssm_scan_dispatch_total{path}` (`ssd_scan_packed`,
+`ssd_scan_gated_norm`; no flag, no attribute):
 
 - `pallas_chunked`: Pallas kernels (below) whose chunk state lives in VMEM
   across a sequential chunk axis: one forward, one backward behind a
   `jax.custom_vjp`. Nothing of [chunks, .., Q, Q] or [chunks, .., P, N] shape
-  goes to HBM in the forward; a mixer's forward reads x, B, C and what
+  goes to HBM in the forward; a scan's forward reads x, B, C and what
   depends on dt and writes y. Taken on the TPU backend, outside a mesh (a
   bare `pallas_call` cannot be partitioned: ops/mesh_dispatch.py), for whole
   chunks of a multiple of 128 tokens, a state size of whole lane tiles and a
   group of R x P channels of whole lane tiles (`_shapes_scan_ok`).
+- `pallas_chunked_gated`: the same kernels with the gated group RMSNorm that
+  follows the scan in a mixer as the forward's epilogue and the backward's
+  prologue: where the kernels take the scan AND a norm group is a grid
+  step's [Q, R P] block (`groups` == G, as `mamba2_mixer` has it), the
+  forward also reads z's block and the norm weight's lanes and writes
+  `rms(y silu(z)) w` ONCE, rounded once, in the compute dtype. The float32
+  y never reaches HBM, nothing is relaid for XLA's norm, and the norm has no
+  pass of its own (25.6 ms of the hybrid's 283.6 ms step went there:
+  PERF.md section 6, PR 43). Inside, float32 with `gated_group_rms_norm`'s
+  own expressions; only the order of a block's 512-lane sum may differ.
 - `xla_chunked`: four einsums and a `lax.scan` over the chunk states, XLA's
-  (`_ssd_einsums`): every other case, exactly: the CPU, a mesh, the tests'
-  tiny configuration at chunk 16, a ragged tail (padded with dt = 0). Every
-  intermediate between the einsums is an HBM array (1.9 GB a mixer forward
-  at T 8192, H 64 where the mathematics needs 0.27: PERF.md section 6, PR
-  41), and its backward is JAX's differentiation of that.
+  (`_ssd_einsums`), and XLA's `gated_group_rms_norm` behind them: every
+  other case, exactly: the CPU, a mesh, the tests' tiny configuration at
+  chunk 16, a ragged tail (padded with dt = 0). Every intermediate between
+  the einsums is an HBM array (1.9 GB a mixer forward at T 8192, H 64 where
+  the mathematics needs 0.27: PERF.md section 6, PR 41), and its backward is
+  JAX's differentiation of that.
 
-Both round where the other does (dt, cumsum(dt A), every exp and the state
+All round where the others do (dt, cumsum(dt A), every exp and the state
 float32; `m`, `x * to_end` and the state as C reads it in the compute
-dtype), so on the chip the kernels' y is the einsums' to the bit.
+dtype; the norm float32 with one rounding at its output), so on the chip the
+kernels' y is the einsums' to the bit and the gated kernels' output XLA's
+norm of it to the bit (one mixer at the hybrid's sizes, PR 43).
 
 What the backward keeps and recomputes: `mamba2_mixer` puts conv, scan and
 gated norm under one `jax.checkpoint`, so across the step it keeps z, xBC,
@@ -52,7 +65,13 @@ also writes the state each chunk STARTS from ([T / Q, H P, N] float32, 134
 MB a mixer at T 8192, one transient array that lives until the backward
 kernel has read it); the backward kernel recomputes a chunk's [Q, Q] blocks
 in registers from x, B, C and the dt forms and carries the state's
-cotangent in VMEM from the last chunk down.
+cotangent in VMEM from the last chunk down. Behind the gate it is handed
+the cotangent of the NORMED output (compute dtype), z and the norm weight,
+forms the chunk's y again from the blocks it holds already (one more [Q, Q]
+x [Q, P] matmul a head on an idle MXU: 0.7 ms a mixer faster on the chip
+than reading back a float32 y the forward would have to write, and 134 MB
+less), and from it y's cotangent in registers, z's as one more output and
+the norm weight's summed over the chunks as D's is.
 """
 
 from __future__ import annotations
@@ -304,13 +323,46 @@ def _dot(a, b, dims=None):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, y_ref, *rest,
-                R: int, P: int):
+def _head_blocks(scores, col, row, r: int, R: int, keep, cd):
+    """Head r's [Q, Q] blocks: (decay, dt_j [1, Q], C B^T x decay, m = that x
+    dt_j in the compute dtype)."""
+    decay = _decay(col, row, r, keep)
+    dtj = row[R + r:R + r + 1, :]
+    weighted = scores * decay
+    return decay, dtj, weighted, (weighted * dtj).astype(cd)
+
+
+def _chunk_y(ms, x, xf, read, decayed, d, R: int, P: int):
+    """y [Q, R P] float32 of a chunk: inside the chunk a head at a time (its
+    m [Q, Q] times its lane tile of x), from the state the chunk starts with
+    the whole group at once (`read` = C before^T, `decayed` = exp(cum) over
+    the heads' lanes), and the D skip."""
+    inside = [_dot(m, x[:, _tile_of(r, P)[0]]) for r, m in enumerate(ms)]
+    return _merge_heads(inside, R, P) + read * decayed + xf * d
+
+
+def _gate_parts(y, z_ref, eps: float):
+    """The gated norm's float32 parts over one block, `gated_group_rms_norm`'s
+    own expressions: v = y silu(z) [Q, R P], r = rsqrt(mean of v^2 over the
+    block's lanes + eps) [Q, 1], and z and sigmoid(z) for the backward. A
+    block is one norm group, so the mean never leaves it."""
+    z = z_ref[0].astype(jnp.float32)
+    s = jax.nn.sigmoid(z)
+    v = y * (z * s)
+    r = jax.lax.rsqrt(jnp.mean(v * v, axis=1, keepdims=True) + eps)
+    return v, r, z, s
+
+
+def _fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, *rest,
+                R: int, P: int, eps: float | None):
     """One (batch, group, chunk): y of the chunk's Q tokens for the group's
-    R heads, and the carried state moved to the chunk's end. With a second
-    output (the differentiated forward) the state the chunk STARTS from is
-    written too: what the backward kernel reads."""
-    *before_ref, s_sc = rest
+    R heads, and the carried state moved to the chunk's end. With `eps` two
+    more operands (z's block, the norm weight's lanes) and the output is the
+    gated norm of y, rounded once to its dtype: y itself stays in registers.
+    With a second output (the differentiated forward) the state the chunk
+    STARTS from is written too: what the backward kernel reads."""
+    gate, (out_ref, *before_ref, s_sc) = ((), rest) if eps is None else (
+        rest[:2], rest[2:])
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -323,18 +375,16 @@ def _fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, y_ref, *rest,
     for ref in before_ref:
         ref[0, 0] = before
     xf = x.astype(jnp.float32)
-    # inside the chunk, a head at a time: m = C B^T x decay x dt_j
     scores = _dot(Cm, Bm, _NT)
     keep = _causal(Q)
-    inside = []
-    for r in range(R):
-        m = (scores * _decay(col, row, r, keep)
-             * row[R + r:R + r + 1, :]).astype(cd)
-        inside.append(_dot(m, x[:, _tile_of(r, P)[0]]))
-    # from the state the chunk starts with, the whole group at once
-    y = (_merge_heads(inside, R, P)
-         + _dot(Cm, before.astype(cd), _NT) * _expand(col, R, R, P))
-    y_ref[0] = y + xf * d_ref[...]
+    y = _chunk_y([_head_blocks(scores, col, row, r, R, keep, cd)[3]
+                  for r in range(R)], x, xf, _dot(Cm, before.astype(cd), _NT),
+                 _expand(col, R, R, P), d_ref[...], R, P)
+    if gate:
+        z_ref, w_ref = gate
+        v, r, _, _ = _gate_parts(y, z_ref, eps)
+        y = v * r * w_ref[...]
+    out_ref[0] = y.astype(out_ref.dtype)
     # the state at the chunk's end
     own = _dot((xf * _expand(col, 2 * R, R, P)).astype(cd), Bm, _TN)
     for r in range(R):
@@ -344,30 +394,57 @@ def _fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, y_ref, *rest,
 
 
 def _bwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, dy_ref,
-                before_ref, dx_ref, db_ref, dc_ref, dcol_ref, drow_ref,
-                dd_ref, ds_sc, *, R: int, P: int):
+                before_ref, *rest, R: int, P: int, eps: float | None):
     """One (batch, group, chunk), the chunks from the last down: the
     cotangents of the chunk's x, B, C (B and C summed over the group's heads)
     and of the two small forms, D's summed over the chunks in its output
-    block, and the state's cotangent carried to the chunk before."""
+    block, and the state's cotangent carried to the chunk before. With `eps`
+    `dy_ref` holds the cotangent of the gated norm's output and two more
+    operands (z's block, the norm weight's lanes): the chunk's y is computed
+    again from the [Q, Q] blocks the backward forms anyway (one more matmul
+    a head), which gives y's cotangent in registers, z's as one more output
+    and the norm weight's summed over the chunks as D's is."""
+    gate, (dx_ref, db_ref, dc_ref, dcol_ref, drow_ref, dd_ref, *gate_bar,
+           ds_sc) = ((), rest) if eps is None else (rest[:2], rest[2:])
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_sc[...] = jnp.zeros(ds_sc.shape, jnp.float32)
-        dd_ref[...] = jnp.zeros(dd_ref.shape, jnp.float32)
+        for ref in (dd_ref, *gate_bar[1:]):      # D's, the norm weight's
+            ref[...] = jnp.zeros(ref.shape, jnp.float32)
 
     x, Bm, Cm = x_ref[0], b_ref[0], c_ref[0]
     col, row = col_ref[0, 0], row_ref[0, 0]
     Q, cd = x.shape[0], x.dtype
     N = Bm.shape[1]
-    g = dy_ref[0]
-    gb = g.astype(cd)
     before, after_bar = before_ref[0, 0], ds_sc[...]
     before_cd, after_bar_cd = before.astype(cd), after_bar.astype(cd)
     xf = x.astype(jnp.float32)
     decayed, to_end = _expand(col, R, R, P), _expand(col, 2 * R, R, P)
+    read = _dot(Cm, before_cd, _NT)
+    scores = _dot(Cm, Bm, _NT)
+    keep = _causal(Q)
+    blocks = functools.partial(_head_blocks, scores, col, row, R=R, keep=keep,
+                               cd=cd)
+    g = dy_ref[0]
+    if gate:
+        # out = v r w: dv = r (g w - v r^2 mean(g w v)), and y's and z's
+        # cotangents through v = y z sigmoid(z)
+        z_ref, w_ref = gate
+        dz_ref, dw_ref = gate_bar
+        heads = [blocks(r) for r in range(R)]
+        y = _chunk_y([h[3] for h in heads], x, xf, read, decayed, d_ref[...],
+                     R, P)
+        g = g.astype(jnp.float32)
+        v, r, z, s = _gate_parts(y, z_ref, eps)
+        dw_ref[0] += jnp.sum(g * (v * r), axis=0, keepdims=True)
+        g = g * w_ref[...]
+        dv = r * (g - v * (r * r * jnp.mean(g * v, axis=1, keepdims=True)))
+        g = dv * (z * s)
+        dz_ref[0] = (dv * y * (s * (1.0 + z * (1.0 - s)))).astype(dz_ref.dtype)
+    gb = g.astype(cd)
 
     # y's part from the state the chunk starts with: (C before^T) x exp(cum)
-    read = _dot(Cm, before_cd, _NT)
     read_bar = (g * decayed).astype(cd)
     dC = _dot(read_bar, before_cd)
     before_bar = _dot(read_bar, Cm, _TN)
@@ -381,8 +458,6 @@ def _bwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, dy_ref,
     dd_ref[0] += jnp.sum(g * xf, axis=0, keepdims=True)
 
     # inside the chunk, a head at a time
-    scores = _dot(Cm, Bm, _NT)
-    keep = _causal(Q)
     scores_bar = jnp.zeros((Q, Q), jnp.float32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
     bottom = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
@@ -391,10 +466,7 @@ def _bwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, dy_ref,
     for r in range(R):
         lanes, per, j = _tile_of(r, P)
         mask = _head_mask(j, per, P)
-        decay = _decay(col, row, r, keep)
-        dtj = row[R + r:R + r + 1, :]
-        weighted = scores * decay
-        m = (weighted * dtj).astype(cd)
+        decay, dtj, weighted, m = heads[r] if gate else blocks(r)
         g_r = gb[:, lanes]
         m_bar = _dot(g_r if mask is None else jnp.where(mask, g_r, 0),
                      x[:, lanes], _NT)
@@ -449,99 +521,135 @@ def _operand_specs(geom: ScanGeometry, chunk_of):
             pl.BlockSpec((1, RP), lambda b, g, s: (0, g))]
 
 
+def _gate_specs(geom: ScanGeometry, chunk_of):
+    """BlockSpecs of a gate's (z [B, T, H P], the norm weight's lanes [1, H
+    P]): z's block lies where x's does, the weight's where D's does."""
+    RP = geom.R * geom.P
+    return [pl.BlockSpec((1, geom.Q, RP), lambda b, g, s: (b, chunk_of(s), g)),
+            pl.BlockSpec((1, RP), lambda b, g, s: (0, g))]
+
+
 # jitted, as ops/flash_ops.py's launches: a model's mixers share shapes, so
 # the kernels are traced and lowered once a program and not once a layer
-@functools.partial(jax.jit, static_argnames=("geom", "states"))
-def _scan_forward(xBC, col, row, d_lanes, *, geom: ScanGeometry, states: bool):
-    """[y [B, T, H P] float32] and, with `states`, the state every chunk
-    starts from: [B, T / Q, H P, N] float32."""
+@functools.partial(jax.jit, static_argnames=("geom", "eps", "states"))
+def _scan_forward(xBC, col, row, d_lanes, *gate, geom: ScanGeometry,
+                  eps: float | None, states: bool):
+    """[y [B, T, H P] float32], or behind a `gate` (z, the norm weight's
+    lanes) [its gated norm in z's dtype]; with `states` also the state every
+    chunk starts from, [B, T / Q, H P, N] float32."""
     H, P, G, N, Q = geom
     Bsz, T, _ = xBC.shape
     RP = geom.R * P
     out_specs = [pl.BlockSpec((1, Q, RP), lambda b, g, c: (b, c, g))]
-    out_shape = [jax.ShapeDtypeStruct((Bsz, T, H * P), jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct(
+        (Bsz, T, H * P), gate[0].dtype if gate else jnp.float32)]
     if states:
         out_specs.append(pl.BlockSpec((1, 1, RP, N),
                                       lambda b, g, c: (b, c, g, 0)))
         out_shape.append(jax.ShapeDtypeStruct((Bsz, T // Q, H * P, N),
                                               jnp.float32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, R=geom.R, P=P),
+        functools.partial(_fwd_kernel, R=geom.R, P=P, eps=eps),
         grid=(Bsz, G, T // Q),
-        in_specs=_operand_specs(geom, lambda c: c),
+        in_specs=(_operand_specs(geom, lambda c: c)
+                  + _gate_specs(geom, lambda c: c)[:len(gate)]),
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((RP, N), jnp.float32)],
         compiler_params=_params(),
         name="ssd_scan_fwd",
-    )(xBC, xBC, xBC, col, row, d_lanes)
+    )(xBC, xBC, xBC, col, row, d_lanes, *gate)
 
 
-@functools.partial(jax.jit, static_argnames=("geom",))
-def _scan_backward(xBC, col, row, d_lanes, before, dy, *, geom: ScanGeometry):
-    """Cotangents of (xBC, col, row, d_lanes) given y's."""
+@functools.partial(jax.jit, static_argnames=("geom", "eps"))
+def _scan_backward(xBC, col, row, d_lanes, before, dy, *gate,
+                   geom: ScanGeometry, eps: float | None):
+    """Cotangents of (xBC, col, row, d_lanes) given y's; behind a `gate` (z,
+    the norm weight's lanes) those and (z's, the lanes') given the gated
+    norm's."""
     H, P, G, N, Q = geom
     Bsz, T, _ = xBC.shape
     R, RP, c = geom.R, geom.R * P, T // Q
     back = lambda s: c - 1 - s  # noqa: E731
     lanes = lambda width: pl.BlockSpec(  # noqa: E731
         (1, Q, width), lambda b, g, s: (b, back(s), g))
+    summed = pl.BlockSpec((1, 1, RP), lambda b, g, s: (b, 0, g))
     cd = xBC.dtype
-    dx, dB, dC, dcol, drow, dd = pl.pallas_call(
-        functools.partial(_bwd_kernel, R=R, P=P),
+    out_specs = [
+        lanes(RP), lanes(N), lanes(N),
+        pl.BlockSpec((1, 1, Q, _LANES), lambda b, g, s: (b, g, back(s), 0)),
+        pl.BlockSpec((1, 1, 2 * R, Q), lambda b, g, s: (b, g, 0, back(s))),
+        summed]
+    out_shape = [
+        jax.ShapeDtypeStruct((Bsz, T, H * P), cd),
+        jax.ShapeDtypeStruct((Bsz, T, G * N), cd),
+        jax.ShapeDtypeStruct((Bsz, T, G * N), cd),
+        jax.ShapeDtypeStruct(col.shape, jnp.float32),
+        jax.ShapeDtypeStruct(row.shape, jnp.float32),
+        jax.ShapeDtypeStruct((Bsz, 1, H * P), jnp.float32)]
+    if gate:
+        out_specs += [lanes(RP), summed]
+        out_shape += [jax.ShapeDtypeStruct(gate[0].shape, gate[0].dtype),
+                      out_shape[-1]]
+    dx, dB, dC, dcol, drow, dd, *gate_bar = pl.pallas_call(
+        functools.partial(_bwd_kernel, R=R, P=P, eps=eps),
         grid=(Bsz, G, c),
         in_specs=_operand_specs(geom, back) + [
             lanes(RP),
-            pl.BlockSpec((1, 1, RP, N), lambda b, g, s: (b, back(s), g, 0))],
-        out_specs=[
-            lanes(RP), lanes(N), lanes(N),
-            pl.BlockSpec((1, 1, Q, _LANES),
-                         lambda b, g, s: (b, g, back(s), 0)),
-            pl.BlockSpec((1, 1, 2 * R, Q), lambda b, g, s: (b, g, 0, back(s))),
-            pl.BlockSpec((1, 1, RP), lambda b, g, s: (b, 0, g))],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bsz, T, H * P), cd),
-            jax.ShapeDtypeStruct((Bsz, T, G * N), cd),
-            jax.ShapeDtypeStruct((Bsz, T, G * N), cd),
-            jax.ShapeDtypeStruct(col.shape, jnp.float32),
-            jax.ShapeDtypeStruct(row.shape, jnp.float32),
-            jax.ShapeDtypeStruct((Bsz, 1, H * P), jnp.float32)],
+            pl.BlockSpec((1, 1, RP, N), lambda b, g, s: (b, back(s), g, 0)),
+            *_gate_specs(geom, back)[:len(gate)]],
+        out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((RP, N), jnp.float32)],
         compiler_params=_params(),
         name="ssd_scan_bwd",
-    )(xBC, xBC, xBC, col, row, d_lanes, dy, before)
+    )(xBC, xBC, xBC, col, row, d_lanes, dy, before, *gate)
     # XLA folds this concatenation into the fusion that reads it (the conv's
     # backward): it is no pass of its own in the hybrid's step. A kernel
     # that copies the three into one packed output itself was 0.3 ms a
     # mixer slower and saved nothing (PERF.md section 6, PR 41)
     return (jnp.concatenate([dx, dB, dC], axis=-1), dcol, drow,
-            jnp.sum(dd, axis=0))
+            jnp.sum(dd, axis=0),
+            *([gate_bar[0], jnp.sum(gate_bar[1], axis=0)] if gate else []))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _scan_kernels(xBC, col, row, d_lanes, geom: ScanGeometry):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _scan_kernels(xBC, col, row, d_lanes, gate, geom: ScanGeometry,
+                  eps: float | None):
     """The kernels over the packed [x | B | C] and the small forms: no
-    dispatch gate. Not differentiated, the forward writes y alone."""
-    return _scan_forward(xBC, col, row, d_lanes, geom=geom, states=False)[0]
+    dispatch gate. `gate` is () and `eps` None for y alone, float32, or (z,
+    the norm weight's lanes) and the norm's eps for the gated norm of y in
+    z's dtype. Not differentiated, the forward writes that one array."""
+    return _scan_forward(xBC, col, row, d_lanes, *gate, geom=geom, eps=eps,
+                         states=False)[0]
 
 
-def _scan_kernels_fwd(xBC, col, row, d_lanes, geom):
-    y, before = _scan_forward(xBC, col, row, d_lanes, geom=geom, states=True)
-    return y, (xBC, col, row, d_lanes, before)
+def _scan_kernels_fwd(xBC, col, row, d_lanes, gate, geom, eps):
+    out, before = _scan_forward(xBC, col, row, d_lanes, *gate, geom=geom,
+                                eps=eps, states=True)
+    return out, (xBC, col, row, d_lanes, before, gate)
 
 
-def _scan_kernels_bwd(geom, saved, dy):
-    return _scan_backward(*saved, dy, geom=geom)
+def _scan_kernels_bwd(geom, eps, saved, dout):
+    *saved, gate = saved
+    dxBC, dcol, drow, dd, *gate_bar = _scan_backward(
+        *saved, dout, *gate, geom=geom, eps=eps)
+    return dxBC, dcol, drow, dd, tuple(gate_bar)
 
 
 _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
-def _ssd_kernels(xBC, dt, A, D, geom: ScanGeometry):
-    """y [B, T, H, P] float32 through the kernels, from the packed [x | B |
-    C] [B, T, H P + 2 G N]."""
+def _ssd_kernels(xBC, dt, A, D, geom: ScanGeometry, gate=(), eps=None):
+    """Through the kernels, from the packed [x | B | C] [B, T, H P + 2 G N]:
+    y [B, T, H, P] float32, or with `gate` (z [B, T, H P], the norm's weight
+    [H P]) and `eps` the gated norm of y, [B, T, H P] in z's dtype."""
     col, row = _small_forms(dt, A, geom)
     d_lanes = jnp.repeat(D.astype(jnp.float32), geom.P)[None, :]
-    y = _scan_kernels(xBC, col, row, d_lanes, geom)
+    if gate:
+        z, norm_w = gate
+        return _scan_kernels(xBC, col, row, d_lanes,
+                             (z, norm_w.astype(jnp.float32)[None, :]), geom,
+                             float(eps))
+    y = _scan_kernels(xBC, col, row, d_lanes, (), geom, None)
     return y.reshape(*y.shape[:2], geom.H, geom.P)
 
 
@@ -573,6 +681,28 @@ def ssd_scan_packed(xBC, dt, A, D, geom: ScanGeometry):
         xBC[..., d_in + G * N:].reshape(Bsz, T, G, N), D, Q)
 
 
+def ssd_scan_gated_norm(xBC, dt, A, D, z, norm_w, geom: ScanGeometry,
+                        groups: int, eps: float):
+    """`gated_group_rms_norm` of `ssd_scan_packed`'s y with z [B, T, H P]
+    (compute dtype) and norm_w [H P], the mean square inside each of
+    `groups` runs of lanes -> [B, T, H P] in z's dtype. Where the kernels
+    take the scan and a norm group is a kernel's block (`groups` == G) the
+    norm is the kernels' epilogue (`pallas_chunked_gated`): y never reaches
+    HBM in the forward. Everywhere else the scan as `ssd_scan_packed`
+    chooses it, under the caller's `scan` scope, and XLA's norm under
+    `gate_norm`."""
+    Bsz, T, _ = xBC.shape
+    if groups == geom.G and scan_kernels_eligible(geom, T, xBC.dtype):
+        _count_dispatch("pallas_chunked_gated")
+        with jax.named_scope("scan"):
+            return _ssd_kernels(xBC, dt, A, D, geom, (z, norm_w), eps)
+    with jax.named_scope("scan"):
+        y = ssd_scan_packed(xBC, dt, A, D, geom)
+    with jax.named_scope("gate_norm"):
+        return gated_group_rms_norm(y.reshape(Bsz, T, geom.H * geom.P), z,
+                                    norm_w, groups, eps).astype(z.dtype)
+
+
 def ssd_chunked_scan(x, dt, A, Bm, Cm, D, chunk: int = CHUNK):
     """x [B, T, H, P] (compute dtype), dt [B, T, H] float32 and positive, A
     [H] negative, Bm / Cm [B, T, G, N] (compute dtype), D [H] -> y [B, T, H,
@@ -587,6 +717,7 @@ def ssd_chunked_scan(x, dt, A, Bm, Cm, D, chunk: int = CHUNK):
         dt, A, D, ScanGeometry(H, P, G, N, chunk))
 
 
+@jax.custom_vjp
 def causal_depthwise_conv(x, w, b):
     """x [B, T, C], w [K, C], b [C] -> float32 [B, T, C]: out_t = b + sum_k
     w[k] x_{t - (K - 1) + k}, zeros before the sequence's start."""
@@ -596,6 +727,30 @@ def causal_depthwise_conv(x, w, b):
     for k in range(K):
         out = out + xp[:, k:k + T] * w[k].astype(jnp.float32)
     return out
+
+
+def _conv_fwd(x, w, b):
+    return causal_depthwise_conv(x, w, b), (x, w, b)
+
+
+def _conv_bwd(saved, g):
+    """The transposed taps on g padded BEHIND the sequence, one fused pass:
+    JAX's own transposition pads each tap's product in front and XLA writes
+    the K float32 [B, T, C] products to HBM first (0.8 GB a mixer at the
+    hybrid's sizes: PERF.md section 6, PR 43)."""
+    x, w, b = saved
+    K, T = w.shape[0], x.shape[1]
+    wf = w.astype(jnp.float32)
+    gp = jnp.pad(g, ((0, 0), (0, K - 1), (0, 0)))
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    dx = sum(gp[:, k:k + T] * wf[K - 1 - k] for k in range(K))
+    dw = jnp.stack([jnp.sum(g * xp[:, k:k + T], axis=(0, 1))
+                    for k in range(K)])
+    return (dx.astype(x.dtype), dw.astype(w.dtype),
+            jnp.sum(g, axis=(0, 1)).astype(b.dtype))
+
+
+causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 def gated_group_rms_norm(y, z, w, groups: int, eps: float):
@@ -615,7 +770,6 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
     out_w [d_in, d]. The two projections run in their weights' dtype (the
     amp dtype where the caller cast them), everything between as the module
     docstring says."""
-    Bsz, T, _ = h.shape
     H, P, G, N = num_heads, head_dim, n_groups, state_size
     d_in = H * P
     cd = in_w.dtype
@@ -625,13 +779,10 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
             xBC = jax.nn.silu(
                 causal_depthwise_conv(xBC, conv_w, conv_b)).astype(cd)
         with jax.named_scope("scan"):
-            y = ssd_scan_packed(
-                xBC, jax.nn.softplus(dt + dt_bias),
-                -jnp.exp(A_log.astype(jnp.float32)), D,
-                ScanGeometry(H, P, G, N, chunk))
-        with jax.named_scope("gate_norm"):
-            return gated_group_rms_norm(y.reshape(Bsz, T, d_in), z, norm_w,
-                                        G, eps).astype(cd)
+            dt = jax.nn.softplus(dt + dt_bias)
+            A = -jnp.exp(A_log.astype(jnp.float32))
+        return ssd_scan_gated_norm(xBC, dt, A, D, z, norm_w,
+                                   ScanGeometry(H, P, G, N, chunk), G, eps)
 
     with jax.named_scope("in_proj"):
         zxd = jnp.dot(h.astype(cd), in_w, preferred_element_type=jnp.float32)
@@ -641,7 +792,8 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
     # one checkpoint from the projection's output to the other's input: the
     # backward keeps z, xBC and dt and computes the conv, the scan (its
     # [Q, Q] blocks and chunk states, in the kernels or as XLA's arrays) and
-    # the float32 y again instead of holding them
+    # the float32 y (an array only where XLA's norm reads it) again instead
+    # of holding them
     y = jax.checkpoint(between)(z, xBC, dt, conv_w, conv_b, dt_bias, A_log,
                                 D, norm_w)
     with jax.named_scope("out_proj"):

@@ -71,69 +71,142 @@ def _dispatched(path):
         "pt_ssm_scan_dispatch_total", labels={"path": path})
 
 
-# T, H, G, P, N, chunk; the kernels' cases (interpreted) are the smallest the
-# shape rules admit: a group's R x P = 128 lanes, Q = N = 128, two groups,
-# three chunks (the carried state and its cotangent cross two chunk edges)
+# T, H, G, P, N, chunk, batch; the kernels' cases (interpreted) are the
+# smallest the shape rules admit: a group's R x P = 128 lanes, Q = N = 128,
+# two groups, three chunks (the carried state and its cotangent cross two
+# chunk edges). A `gated_` case runs the scan WITH the gated group norm
+# behind it (`ssd_scan_gated_norm`, what the mixer calls): at the kernels'
+# shapes the norm is the kernels' epilogue, at the tiny ones XLA's
 SCAN_CASES = {
-    "4chunks": (64, 4, 4, 8, 16, 16),
-    "groups<heads": (64, 4, 2, 8, 16, 16),
-    "ragged_tail": (40, 4, 1, 8, 16, 16),
-    "under_a_chunk": (7, 2, 2, 8, 16, 16),
-    "kernels_groups<heads": (384, 4, 2, 64, 128, 128),
-    "kernels_groups=heads": (384, 2, 2, 128, 128, 128),
+    "4chunks": (64, 4, 4, 8, 16, 16, 2),
+    "groups<heads": (64, 4, 2, 8, 16, 16, 2),
+    "ragged_tail": (40, 4, 1, 8, 16, 16, 2),
+    "under_a_chunk": (7, 2, 2, 8, 16, 16, 2),
+    "kernels_groups<heads": (384, 4, 2, 64, 128, 128, 1),
+    "kernels_groups=heads": (384, 2, 2, 128, 128, 128, 1),
+    "gated_groups<heads": (64, 4, 2, 8, 16, 16, 2),
+    "gated_kernels_groups<heads": (384, 4, 2, 64, 128, 128, 1),
+    "gated_kernels_groups=heads": (384, 2, 2, 128, 128, 128, 1),
+    # the norm weight's cotangent summed over two chunks AND two sequences
+    "gated_kernels_batch2": (256, 4, 2, 64, 128, 128, 2),
 }
+NAMES = ("x", "dt", "A", "B", "C", "D", "z", "norm_w")
+EPS = 1e-5
+
+
+def _gate_inputs(x, seed=4):
+    """z [B, T, H P] and a norm weight [H P] around one."""
+    r = _rng(seed)
+    Bsz, T, H, P = x.shape
+    return (jnp.asarray(r.randn(Bsz, T, H * P), jnp.float32),
+            jnp.asarray(1.0 + 0.3 * r.randn(H * P), jnp.float32))
+
+
+def _gated(scan, G):
+    """`scan`'s y [B, T, H, P] through XLA's gated group norm: the oracle's
+    second half."""
+    def fn(x, dt, A, Bm, Cm, D, z, norm_w):
+        y = scan(x, dt, A, Bm, Cm, D)
+        return ssm_ops.gated_group_rms_norm(
+            y.reshape(*y.shape[:2], -1), z, norm_w, G, EPS)
+    return fn
+
+
+def _scan_gated_norm(chunk):
+    """`ssd_scan_gated_norm` on the eight tensors, packed as the mixer's
+    conv hands them over."""
+    def fn(x, dt, A, Bm, Cm, D, z, norm_w):
+        Bsz, T, H, P = x.shape
+        G, N = Bm.shape[2:]
+        xBC = jnp.concatenate([x.reshape(Bsz, T, -1), Bm.reshape(Bsz, T, -1),
+                               Cm.reshape(Bsz, T, -1)], axis=-1)
+        return ssm_ops.ssd_scan_gated_norm(
+            xBC, dt, A, D, z.astype(x.dtype), norm_w,
+            ssm_ops.ScanGeometry(H, P, G, N, chunk), G, EPS)
+    return fn
+
+
+def _grads(fn, args, w):
+    return jax.grad(lambda *a: (fn(*a) * w).sum(),
+                    argnums=tuple(range(len(args))))(*args)
 
 
 @pytest.mark.parametrize("case", list(SCAN_CASES))
 def test_chunked_scan_matches_the_recurrence(interpreted, case):
     """Values and every gradient, float32 at the highest precision: the two
     differ only in the order of float32 sums. The einsum form at the tiny
-    shapes, the kernels at the shapes they take."""
-    T, H, G, P, N, chunk = SCAN_CASES[case]
-    kernels = case.startswith("kernels")
-    args = _scan_inputs(T, H, G, P=P, N=N, Bsz=1 if kernels else 2)
-    w = jnp.asarray(_rng(9).randn(*args[0].shape), jnp.float32)
+    shapes, the kernels at the shapes they take; behind a gate z's and the
+    norm weight's gradients too, against XLA's norm of the recurrence."""
+    T, H, G, P, N, chunk, Bsz = SCAN_CASES[case]
+    kernels, gated = "kernels" in case, case.startswith("gated")
+    args = _scan_inputs(T, H, G, P=P, N=N, Bsz=Bsz)
     scan = lambda *a: ssm_ops.ssd_chunked_scan(*a, chunk=chunk)  # noqa: E731
-    before = _dispatched("pallas_chunked")
+    want_fn = _recurrence
+    if gated:
+        args += _gate_inputs(args[0])
+        scan, want_fn = _scan_gated_norm(chunk), _gated(_recurrence, G)
+    path = ("pallas_chunked_gated" if gated else "pallas_chunked") \
+        if kernels else "xla_chunked"
+    before = {p: _dispatched(p) for p in
+              ("pallas_chunked", "pallas_chunked_gated", "xla_chunked")}
     with jax.default_matmul_precision("highest"):
         got = scan(*args)
-        want = _recurrence(*args)
+        want = want_fn(*args)
         assert got.dtype == jnp.float32 and got.shape == want.shape
         assert _rel(got, want) < 1e-5
-        grad = lambda fn: jax.grad(  # noqa: E731
-            lambda *a: (fn(*a) * w).sum(), argnums=tuple(range(6)))(*args)
-        for name, g, r in zip(("x", "dt", "A", "B", "C", "D"), grad(scan),
-                              grad(_recurrence)):
+        w = jnp.asarray(_rng(9).randn(*want.shape), jnp.float32)
+        for name, g, r in zip(NAMES, _grads(scan, args, w),
+                              _grads(want_fn, args, w)):
+            assert g.shape == r.shape and g.dtype == r.dtype
             assert _rel(g, r) < 1e-4, (name, _rel(g, r))
-    assert (_dispatched("pallas_chunked") > before) == kernels
+    assert {p for p, n in before.items() if _dispatched(p) > n} == {path}
 
 
 @pytest.mark.parametrize("case", ["kernels_groups<heads",
-                                  "kernels_groups=heads"])
+                                  "kernels_groups=heads",
+                                  "gated_kernels_groups<heads",
+                                  "gated_kernels_batch2"])
 def test_scan_kernels_in_bf16_round_where_the_einsums_do(interpreted, case):
     """Under amp x, B and C arrive bf16. The kernels' y is the einsum
     form's but for the order of float32 sums (1e-3 and up is a dropped rounding point: a
     bf16 decay, a bf16 state, an unrounded `m`), every gradient is within
     bf16's rounding of the float32 recurrence's, and so is the einsum
-    form's: the two backward passes round alike."""
-    T, H, G, P, N, chunk = SCAN_CASES[case]
-    x, dt, A, Bm, Cm, D = _scan_inputs(T, H, G, P=P, N=N, Bsz=1)
+    form's: the two backward passes round alike. Behind a gate z arrives
+    bf16 too and the output is bf16, rounded once in the kernel as after
+    XLA's norm of the einsums' y: the two outputs are the same bf16 numbers
+    but where a float32 sum's order moved one across a rounding edge."""
+    T, H, G, P, N, chunk, Bsz = SCAN_CASES[case]
+    gated = case.startswith("gated")
+    x, dt, A, Bm, Cm, D = full = _scan_inputs(T, H, G, P=P, N=N, Bsz=Bsz)
     lo = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
     args = (lo(x), dt, A, lo(Bm), lo(Cm), D)
-    w = jnp.asarray(_rng(9).randn(*x.shape), jnp.float32)
-    got = ssm_ops.ssd_chunked_scan(*args, chunk=chunk)
-    assert got.dtype == jnp.float32
-    assert _rel(got, ssm_ops._ssd_einsums(*args, chunk)) < 2e-5
-    assert _rel(got, _recurrence(x, dt, A, Bm, Cm, D)) < 0.02
-    grad = lambda fn, a: jax.grad(  # noqa: E731
-        lambda *a: (fn(*a) * w).sum(), argnums=tuple(range(6)))(*a)
-    want = grad(_recurrence, (x, dt, A, Bm, Cm, D))
-    kernels = grad(lambda *a: ssm_ops.ssd_chunked_scan(*a, chunk=chunk), args)
-    einsums = grad(lambda *a: ssm_ops._ssd_einsums(*a, chunk), args)
-    for name, k, e, r in zip(("x", "dt", "A", "B", "C", "D"), kernels,
-                             einsums, want):
-        assert k.dtype == e.dtype
-        assert _rel(k, r) < 0.02 and _rel(e, r) < 0.02, (
+    kernels = lambda *a: ssm_ops.ssd_chunked_scan(*a, chunk=chunk)  # noqa: E731
+    einsums = lambda *a: ssm_ops._ssd_einsums(*a, chunk)  # noqa: E731
+    want_fn = _recurrence
+    if gated:
+        z, norm_w = _gate_inputs(x)
+        full, args = full + (z, norm_w), args + (lo(z), norm_w)
+        kernels = _scan_gated_norm(chunk)
+        einsums = lambda *a: _gated(  # noqa: E731
+            lambda *s: ssm_ops._ssd_einsums(*s, chunk), G)(*a).astype(
+                jnp.bfloat16)
+        want_fn = _gated(_recurrence, G)
+    got, same = kernels(*args), einsums(*args)
+    assert got.dtype == same.dtype == (jnp.bfloat16 if gated else jnp.float32)
+    if gated:       # an ulp of bf16 is 4e-3: one number in 25 000 moved
+        assert np.mean(np.asarray(got != same)) < 1e-3
+    assert _rel(got, same) < 2e-5
+    assert _rel(got, want_fn(*full)) < 0.02
+    w = jnp.asarray(_rng(9).randn(*got.shape), jnp.float32)
+    loss = lambda fn: lambda *a: fn(*a).astype(jnp.float32)  # noqa: E731
+    want = _grads(want_fn, full, w)
+    # a bf16 z and a bf16 output add their rounding to B's and C's gradients
+    # (0.0225 on both forms at three chunks)
+    limit = 0.03 if gated else 0.02
+    for name, k, e, r in zip(NAMES, _grads(loss(kernels), args, w),
+                             _grads(loss(einsums), args, w), want):
+        assert k.dtype == e.dtype and k.shape == e.shape
+        assert _rel(k, r) < limit and _rel(e, r) < limit, (
             name, _rel(k, r), _rel(e, r))
 
 
@@ -191,6 +264,31 @@ def test_conv_and_gated_norm_against_plain_numpy():
         ssm_ops.gated_group_rms_norm(jnp.asarray(y, jnp.float32), z, nw, 4,
                                      1e-5),
         v.reshape(3, 12) * nw, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16_in"])
+def test_conv_backward_is_the_transposed_taps(dtype):
+    """The conv's own backward (one pass over the cotangent padded behind
+    the sequence) against JAX's differentiation of the same taps written
+    plainly: x's, the taps' and the bias's gradients, K - 1 tokens past both
+    ends included; x's comes back in x's dtype."""
+    r = _rng(11)
+    x = jnp.asarray(r.randn(2, 9, 6), dtype)
+    w, b = (jnp.asarray(r.randn(*s), jnp.float32) for s in ((4, 6), (6,)))
+    cot = jnp.asarray(r.randn(2, 9, 6), jnp.float32)
+
+    def plain(x, w, b):
+        xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (3, 0), (0, 0)))
+        return b + sum(xp[:, k:k + 9] * w[k] for k in range(4))
+
+    grad = lambda fn: jax.grad(  # noqa: E731
+        lambda *a: (fn(*a) * cot).sum(), argnums=(0, 1, 2))(x, w, b)
+    for got, want in zip(grad(ssm_ops.causal_depthwise_conv), grad(plain)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_mamba2_init_draws_the_family_ranges():
