@@ -80,6 +80,7 @@ HIGH_PRECISION_OPS = frozenset({
     "cross_entropy", "softmax_with_cross_entropy", "mean",
     "reduce_mean", "huber_loss", "smooth_l1", "squared_l2_norm",
     "l2_normalize", "exp", "log", "rms_norm", "moe_aux_loss",
+    "exit_expected_cost",
 })
 
 

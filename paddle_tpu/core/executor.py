@@ -53,7 +53,16 @@ def memory_optimize(program=None, policy: str = "dots") -> None:
     """Reference API: fluid memory_optimization_transpiler.memory_optimize
 
     (liveness-based forward-activation reuse). TPU equivalent: enable
-    rematerialization of the forward slice inside the backward pass."""
+    rematerialization of the forward slice inside the backward pass.
+
+    What it does not do: the whole forward closure becomes ONE
+    `jax.checkpoint` region (`_run_autodiff`), so the backward pass first
+    runs the whole forward again and then holds all of its activations at
+    once: with policy "full" the peak is no lower than without it, and the
+    policies only choose what of the first run is kept as well. Nothing is
+    rematerialised a region at a time. A model whose memory is K passes over
+    one stack of layers gets that from `layers.Repeat(remat=True)`: the loop
+    saves each turn's carry and recomputes one turn at a time."""
     program = program or default_main_program()
     if policy not in _REMAT_POLICIES:
         raise ValueError(

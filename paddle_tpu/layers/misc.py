@@ -66,6 +66,8 @@ __all__ = [
     "lambda_cost",
     "nce",
     "hsigmoid",
+    "exit_gate",
+    "exit_expected_cost",
 ]
 
 
@@ -433,3 +435,40 @@ def hsigmoid(input, label, num_classes, param_attr=None, bias_attr=None):
     helper.append_op(type="hsigmoid", inputs=inputs, outputs={"Cost": [out]},
                      attrs={"num_classes": num_classes})
     return out
+
+
+def exit_gate(input, param_attr=None, bias_attr=None, name=None):
+    """A looped model's exit gate, Linear(d, 1): input [..., d] -> one
+    float32 logit a position [...], `input . w + b` with w [d] (Glorot over
+    d -> 1) and b [1] (zero). Float32 under amp too, as the routers are: a
+    cast, an elementwise product and a sum over d, none of which drops
+    precision."""
+    from ..initializer import XavierInitializer
+    from .nn import cast, elementwise_add, elementwise_mul, reduce_sum
+
+    helper = LayerHelper("exit_gate", name=name)
+    d = int(input.shape[-1])
+    w = helper.create_parameter(
+        param_attr, (d,),
+        default_initializer=XavierInitializer(fan_in=d, fan_out=1))
+    b = helper.create_parameter(bias_attr, (1,), is_bias=True)
+    s = reduce_sum(elementwise_mul(cast(input, np.float32), w), dim=-1)
+    return elementwise_add(s, b)
+
+
+def exit_expected_cost(turn_costs, gate_logits, beta: float = 0.0, name=None):
+    """turn costs [K, ..., 1] (or [K, ...]) and gate logits [K, ...] ->
+    (cost [...] a position, exit probabilities [K, ...]), float32: the
+    expected cost over a looped model's K exits less `beta` times the
+    entropy of the exit distribution (ops/cost_ops.py:exit_expected_cost;
+    Zhu et al. 2025, "Scaling Latent Reasoning via Looped Language
+    Models", stage I). The last exit takes what the gates before it left."""
+    helper = LayerHelper("exit_expected_cost", name=name)
+    cost = helper.create_tmp_variable(np.float32, tuple(gate_logits.shape[1:]))
+    probs = helper.create_tmp_variable(np.float32, tuple(gate_logits.shape))
+    helper.append_op(
+        type="exit_expected_cost",
+        inputs={"TurnCosts": [turn_costs], "GateLogits": [gate_logits]},
+        outputs={"Cost": [cost], "Probs": [probs]},
+        attrs={"beta": float(beta)})
+    return cost, probs

@@ -215,3 +215,35 @@ def hsigmoid_kernel(ctx):
         s = s + _data(ctx.input("Bias")).reshape(-1)[nodes]
     loss = (jax.nn.softplus(s) - bits * s) * valid
     ctx.set_output("Cost", jnp.sum(loss, axis=-1, keepdims=True))
+
+
+@register_op("exit_expected_cost")
+def exit_expected_cost_kernel(ctx):
+    """`layers.exit_expected_cost`: a looped model's K exits as one
+    distribution a token, and the expected cost under it, float32 whatever
+    the inputs. With gate logits s [K, ...] and turn costs c [K, ...]:
+
+        lambda_r = sigmoid(s_r)
+        p_r = lambda_r prod_{j<r} (1 - lambda_j)  (r < K),
+        p_K = prod_{j<K} (1 - lambda_j)           (what is left: sum p = 1)
+        Cost = sum_r p_r c_r - beta H(p),   H(p) = - sum_r p_r log p_r
+
+    log p_r is a sum of `log_sigmoid`s, never the log of a product; the
+    last turn's gate logit is not read (its gradient is zero)."""
+    c = _data(ctx.input("TurnCosts")).astype(jnp.float32)
+    s = _data(ctx.input("GateLogits")).astype(jnp.float32)
+    if c.ndim == s.ndim + 1 and c.shape[-1] == 1:
+        c = c[..., 0]
+    if c.shape != s.shape:
+        raise ValueError(f"exit_expected_cost: turn costs {c.shape} against "
+                         f"gate logits {s.shape}")
+    with jax.named_scope("repeat.exit"):
+        stay = jax.nn.log_sigmoid(-s[:-1])              # log(1 - lambda_j)
+        before = jnp.concatenate(
+            [jnp.zeros_like(s[:1]), jnp.cumsum(stay, axis=0)], axis=0)
+        log_p = before + jnp.concatenate(
+            [jax.nn.log_sigmoid(s[:-1]), jnp.zeros_like(s[:1])], axis=0)
+        p = jnp.exp(log_p)
+        cost = jnp.sum(p * (c + float(ctx.attr("beta", 0.0)) * log_p), axis=0)
+    ctx.set_output("Cost", cost)
+    ctx.set_output("Probs", p)

@@ -258,7 +258,10 @@ def test_attn_device_ms_is_in_the_manifest_for_every_cell():
     wrapped = {m["name"] for m in manifest["per_layer"]
                if m["name"].endswith(".attn_device_ms")}
     assert wrapped == {"nemotron.attn_device_ms", "glm.attn_device_ms",
-                       "trinity.attn_device_ms"}
+                       "trinity.attn_device_ms",
+                       # PR 44: a reader of its own (the rows are inside a
+                       # loop's body, where the scope is the loop's)
+                       "ouro.attn_device_ms"}
 
 
 def test_load_max_over_mean_reads_the_counters_and_checks_the_sum():
@@ -1424,12 +1427,12 @@ def test_the_manifest_lists_the_trinity_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell = TRI + ".train-log10"
-    assert manifest["workloads"][-1]["name"] == cell     # appended, last
-    entry = manifest["workloads"][-1]
+    assert manifest["workloads"][5]["name"] == cell      # appended in PR 42
+    entry = manifest["workloads"][5]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         TRI, "train-log10", 1)
-    assert sum(w["chips"] for w in manifest["workloads"]) == 6   # no 4-chip
-    config = manifest["configs"][-1]
+    assert sum(w["chips"] for w in manifest["workloads"][:6]) == 6   # no 4-chip
+    config = manifest["configs"][4]
     assert config["name"] == TRI and config["source"] == _tri_config()["source"]
     assert config["reduced"] == _tri_config()["reduced"] == [
         "num_hidden_layers", "layer_types", "num_dense_layers", "num_experts",
@@ -1443,15 +1446,17 @@ def test_the_manifest_lists_the_trinity_cell_and_its_metrics():
                     "trinity.bounded_step_share", "trinity.attn_device_ms",
                     "trinity.head_device_ms", "trinity.opt_device_ms",
                     "trinity.donated_gib", "trinity.feed_produce_ms_per_step"]
+    # appended in PR 42; PR 44 appended its own cell's behind them
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(mine):] == mine                    # appended, last
+    end = names.index(mine[-1]) + 1
+    assert names[end - len(mine):end] == mine
     layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
     assert layers["attn.window_ms"] == layers["attn.assemble_ms"] \
         == "Window attention"
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     # no reader that was there lists the new cell: their entries are untouched
-    assert not [m["name"] for m in manifest["per_layer"][:-len(mine)]
+    assert not [m["name"] for m in manifest["per_layer"][:end - len(mine)]
                 if cell in m.get("workloads", ())]
     with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
         traffic = json.load(f)
@@ -1533,3 +1538,374 @@ def test_the_trinity_cell_rehearses_on_the_cpu(tmp_path):
         "REHEARSAL_ON_CPU.trinity.held_pair_share"]["value"]
     assert 0.1 < share < 0.45                # 4 of 16 experts held: 0.25
     assert "choice_counts_off_program" in result["compared"]
+
+
+# PR 44: ouro-2.6b (one stack of layers run four times through `layers.Repeat`)
+OURO = "ouro-2.6b"
+OURO_CELL = {"batch": 1, "seqlen": 4096}
+LOOP_SCOPE = "repeat.looped.turns.out_256"
+_FWD = f"jit(raw)/jvp({LOOP_SCOPE})/while/body/closed_call/repeat.turn/"
+_BWD = f"jit(raw)/transpose(jvp({LOOP_SCOPE}))/while/body/closed_call/checkpoint/"
+
+
+def _ouro_config():
+    with open(os.path.join(BENCH, "configs", OURO, "config.json")) as f:
+        return json.load(f)
+
+
+def test_ouro_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _ouro_config()
+    got = flops.train_flops_per_item(cfg, OURO_CELL,
+                                     os.path.join(BENCH, "configs", OURO))
+    # ISSUE 44's arithmetic: 3 x [K x (L x (2 x 51 380 224 + 2 T d) + 2 d V
+    # + 2 d)], a causal query's keys counted as the other families count them
+    # ((T + 1) / 2: 2 d more a layer application than the issue's 2 T d)
+    assert got == 3 * 4630659072.0 == 13891977216.0
+    assert round(got * 4096 / 1e12, 1) == 56.9
+    own = _load("configs", OURO, "flops.py")
+    layer = 2 * 51380224 + 2 * 2048 * 4097
+    assert own.forward_flops_per_token(cfg, 4096) == 4 * (
+        8 * layer + 2 * 2048 * 49152 + 2 * 2048)
+    # a turn more adds a whole stack, a head and a gate; a layer more adds K
+    # applications; recomputed work counts nothing (no term for it)
+    assert own.forward_flops_per_token(dict(cfg, total_ut_steps=5), 4096) \
+        == 5 * (8 * layer + 2 * 2048 * 49152 + 2 * 2048)
+    assert own.forward_flops_per_token(dict(cfg, num_hidden_layers=9), 4096) \
+        - own.forward_flops_per_token(cfg, 4096) == 4 * layer
+    # the shares ISSUE 44 and the cell's `why` state
+    turn = 8 * layer + 2 * 2048 * 49152 + 2 * 2048
+    assert round(100 * 2 * 2048 * 49152 / turn, 1) == 17.4
+    assert round(100 * 8 * layer / turn, 1) == 82.6
+
+
+def test_ouro_config_keeps_the_published_sizes():
+    cfg = _ouro_config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        published = next(e for e in map(json.loads, f)
+                         if e["name"] == "Ouro-2.6B")
+    assert cfg["source"] == published["source_url"]
+    differs = [k for k, v in published["config"].items()
+               if cfg.get(k, "absent") != v]
+    assert sorted(differs) == sorted(cfg["reduced"]) == [
+        "layer_types", "num_hidden_layers"]
+    assert cfg["published"] == {k: published["config"][k] for k in differs}
+    assert cfg["published"]["num_hidden_layers"] == 48
+    assert cfg["layer_types"] == published["config"]["layer_types"][:8]
+    # no width, no head, no row of the vocabulary and no turn is cut
+    assert (cfg["hidden_size"], cfg["num_attention_heads"], cfg["head_dim"],
+            cfg["num_key_value_heads"], cfg["intermediate_size"],
+            cfg["vocab_size"], cfg["total_ut_steps"], cfg["rope_theta"],
+            cfg["rms_norm_eps"], cfg["early_exit_threshold"]) == (
+        2048, 16, 128, 16, 5632, 49152, 4, 1000000, 1e-6, 1)
+    assert cfg["exit_beta"] == 0.05 and "exit_beta" in cfg["assumed"]
+    for key in ("assumed", "departures", "deployment", "distortion"):
+        assert cfg[key], key
+    for key in ("basis", "layer", "attention", "ffn", "turns", "embedding",
+                "exits", "cost", "early_exit_threshold", "optimizer",
+                "compute_dtype", "initialisers", "remat_policy"):
+        assert cfg["assumed"][key], key
+    assert "six pipeline stages" in cfg["deployment"] \
+        and "17.4 %" in cfg["distortion"]
+    # the parameters this chip holds: ISSUE 44's count
+    d, f, V = 2048, 5632, 49152
+    layer = 4 * d * d + 3 * d * f + 4 * d
+    assert layer == 51388416
+    assert 8 * layer + 2 * V * d + d + d + 1 == 612438017
+    assert "612 438 017" in cfg["deployment"]
+
+
+def test_ouro_flash_kernel_counts_k_times_l_layer_applications():
+    ours = _load("kernels", "ouro_flash_attention.py")
+    plain = _load("kernels", "flash_attention.py")
+    cfg = _ouro_config()
+    flops, bytes_ = ours.flops_and_bytes(cfg, OURO_CELL)
+    once = plain.flops_and_bytes(dict(cfg, num_hidden_layers=1), OURO_CELL)
+    assert (flops, bytes_) == (32 * once[0], 32 * once[1])
+    pairs = 4096 * 4097 // 2
+    assert once[0] == 16 * 6 * 2 * pairs * 128      # nothing for a recomputed
+    assert once[1] == 12 * 4096 * 2048 * 2          # forward, flops or bytes
+    # K is part of the count: three turns need three quarters
+    assert ours.flops_and_bytes(dict(cfg, total_ut_steps=3), OURO_CELL)[0] \
+        == 24 * once[0]
+
+
+def _ouro_row(op_name, ns, target=None, container=False, opcode="fusion",
+              count=2):
+    xplane = _load("xplane.py")
+    scope, transform = xplane.scope_of(op_name)
+    return {"name": "%f", "opcode": opcode, "shape": "", "target": target,
+            "container": container, "count": count, "ns": ns,
+            "op_name": op_name, "scope": scope, "transform": transform}
+
+
+def _ouro_run_record():
+    """Two steps of a looped model's trace, by hand: the body's rows carry
+    the `repeat` op's scope first and their own op's further down the path
+    (the three forms read on the chip, PR 44)."""
+    def op(kind, out, inputs, slot="Out"):
+        return {"type": kind, "scope": f"{kind}.{out}", "inputs": inputs,
+                "outputs": {slot: [out]}}
+
+    program_ops = [
+        op("lookup_table", "emb", {"Ids": ["toks"], "W": ["tok_emb"]}),
+        {"type": "repeat", "scope": LOOP_SCOPE, "inputs": {"Carried": ["emb"]},
+         "outputs": {"Out": ["looped.turns.out_256"], "Turns": ["ce_t", "s_t"]}},
+        {"type": "exit_expected_cost", "scope": "exit_expected_cost.cost",
+         "inputs": {"TurnCosts": ["ce_t"], "GateLogits": ["s_t"]},
+         "outputs": {"Cost": ["cost"], "Probs": ["p"]}},
+        op("mean", "loss", {"X": ["cost"]}),
+        {"type": "adam", "scope": "adam.wq", "inputs": {"Param": ["wq"]},
+         "outputs": {"ParamOut": ["wq"]}},
+        # the sub-block
+        op("rms_norm", "n1", {"X": ["emb"], "Scale": ["g1"]}, "Y"),
+        op("mul", "q0", {"X": ["n1"], "Y": ["wq"]}),
+        op("mul", "k0", {"X": ["n1"], "Y": ["wk"]}),
+        op("mul", "v", {"X": ["n1"], "Y": ["wv"]}),
+        op("rotary_embedding", "q", {"X": ["q0"]}),
+        op("rotary_embedding", "k", {"X": ["k0"]}),
+        op("flash_attention", "att", {"Q": ["q"], "K": ["k"], "V": ["v"]}),
+        op("mul", "o", {"X": ["att"], "Y": ["wo"]}),
+        op("rms_norm", "hf", {"X": ["o"], "Scale": ["gf"]}, "Y"),
+        op("mul", "logits", {"X": ["hf"], "Y": ["out_w"]}),
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.sm",
+         "inputs": {"Logits": ["logits"], "Label": ["labels"]},
+         "outputs": {"Softmax": ["sm"], "Loss": ["ce"]}},
+    ]
+    K = "tpu_custom_call"
+    rows = [
+        _ouro_row(_FWD + "flash_attention.att/pallas_call", 8_000_000, K),
+        _ouro_row(_BWD + "rematted_computation/repeat.turn/flash_attention.att/pallas_call", 8_000_000, K),
+        _ouro_row(_BWD + "repeat.turn/flash_attention.att/pallas_call", 16_000_000, K),
+        _ouro_row(_BWD + "repeat.turn/flash_attention.att/reduce_sum", 2_000_000),
+        _ouro_row(_FWD + "mul.q0/dot_general", 4_000_000),
+        _ouro_row(_BWD + "rematted_computation/repeat.turn/mul.o/dot_general", 2_000_000),
+        _ouro_row(_BWD + "repeat.turn/rotary_embedding.k/mul", 1_000_000),
+        _ouro_row(_FWD + "mul.logits/dot_general", 6_000_000),
+        _ouro_row(_BWD + "repeat.turn/mul.logits/dot_general", 12_000_000),
+        _ouro_row(_BWD + "rematted_computation/repeat.turn/softmax_with_cross_entropy.sm/reduce_sum", 1_000_000),
+        _ouro_row(_FWD + "rms_norm.n1/mul", 500_000),
+        # the loop's own: no inner op's scope on the path
+        _ouro_row(f"jit(raw)/transpose(jvp({LOOP_SCOPE}))/while/body/closed_call/add_any", 1_500_000),
+        # the two loops themselves: containers, in no sum
+        # (they reach the trace without an `op_name`, as read on the chip)
+        _ouro_row("", 19_000_000, container=True, opcode="while"),
+        _ouro_row("", 44_000_000, container=True, opcode="while"),
+        # outside the loop
+        _ouro_row("jit(raw)/jvp(exit_expected_cost.cost)/repeat.exit/exp", 300_000),
+        _ouro_row("jit(raw)/transpose(jvp(mean.loss))/div", 100_000),
+        _ouro_row("jit(raw)/adam.wq/mul", 3_000_000),
+        _ouro_row("jit(raw)/jvp(lookup_table.emb)/gather", 700_000),
+    ]
+    return {"steps": 2, "program_ops": program_ops, "trace": {"ops": rows},
+            "config": _ouro_config(), "cell": dict(OURO_CELL),
+            "device": {"kind": "TPU v5 lite"},
+            "registry": {"pt_repeat_saved_bytes": 4 * 2**25 + 2 * 4 * 4096 * 4.0}}
+
+
+def test_loop_readers_find_a_bodys_rows_by_the_inner_scope():
+    run = _ouro_run_record()
+    body = _load("layer_metrics", "repeat.body_device_ms.py")
+    scopes, loops = body._program(run)
+    assert loops == {LOOP_SCOPE} and LOOP_SCOPE not in scopes
+    assert body.inner_scope(_BWD + "repeat.turn/mul.q0/dot_general", scopes) == "mul.q0"
+    assert body.inner_scope(f"jit(raw)/jvp({LOOP_SCOPE})/while", scopes) == ""
+    found = {(r["op_name"].rsplit("/", 2)[-2], which)
+             for r, inner, kind, which in body.body_rows(run)}
+    assert ("flash_attention.att", "forward") in found
+    assert ("flash_attention.att", "recomputed") in found
+    assert ("flash_attention.att", "backward") in found
+    # every leaf row under the loop, containers and the rows outside left out
+    assert body.compute(run) == pytest.approx((8 + 8 + 16 + 2 + 4 + 2 + 1 + 6
+                                               + 12 + 1 + .5 + 1.5) / 2)
+    info = body.info(run)
+    assert info["by_pass_ms"] == pytest.approx(
+        {"forward": 9.25, "recomputed": 5.5, "backward": 16.25})
+    assert info["no_inner_op_ms"] == pytest.approx(0.75)
+    assert info["by_inner_op_type_ms"]["flash_attention"] == pytest.approx(
+        {"forward": 4.0, "recomputed": 4.0, "backward": 9.0})
+    assert info["while_containers_ms"] == pytest.approx(31.5)
+    assert info["containers_over_their_bodies_ms"] == pytest.approx(0.5)
+    recompute = _load("layer_metrics", "repeat.recompute_ms.py")
+    assert recompute.compute(run) == pytest.approx(5.5)
+    assert recompute.info(run)["over_forward"] == pytest.approx(5.5 / 9.25)
+    saved = _load("layer_metrics", "repeat.saved_gib.py")
+    assert saved.compute(run) == pytest.approx(0.125 + 2**17 / 2**30)
+    attn = _load("layer_metrics", "ouro.attn_device_ms.py")
+    assert attn.compute(run) == pytest.approx(17.0)
+    info = attn.info(run)
+    assert info.pop("by_pass_ms") == pytest.approx(
+        {"forward": 4.0, "recomputed": 4.0, "backward": 9.0})
+    assert info == pytest.approx({"kernels_ms": 16.0, "not_kernels_ms": 1.0,
+                                  "rotary_ms": 0.5, "projections_ms": 3.0})
+    assert attn.around(run["program_ops"]) == {
+        "rotary": {"rotary_embedding.q", "rotary_embedding.k"},
+        "projections": {"mul.q0", "mul.k0", "mul.v", "mul.o"}}
+    head = _load("layer_metrics", "ouro.head_device_ms.py")
+    assert head.head_scopes(run["program_ops"]) == (
+        {"softmax_with_cross_entropy.sm": "cross_entropy", "mul.logits": "gemm"},
+        {"exit_expected_cost.cost", "mean.loss"})
+    assert head.compute(run) == pytest.approx((6 + 12 + 1 + .3 + .1) / 2)
+    assert head.info(run)["gemm"] == pytest.approx(
+        {"forward": 3.0, "recomputed": 0.0, "backward": 6.0})
+    assert head.info(run)["exit_cost_ms"] == pytest.approx(0.2)
+    roof = _load("layer_metrics", "ouro.flash_roofline.py")
+    need = _load("kernels", "ouro_flash_attention.py").flops_and_bytes(
+        run["config"], run["cell"])
+    want = _load("roofline.py").share(*need, 0.016, "TPU v5 lite")
+    assert roof.compute(run) == pytest.approx(want[0])
+    assert roof.info(run)["kernel_ms_by_pass"] == pytest.approx(
+        {"forward": 4.0, "recomputed": 4.0, "backward": 8.0})
+    assert roof.info(run)["bound"] == want[1]
+    # the readers that go by the OUTER scope see nothing inside the loop
+    assert _load("layer_metrics", "attn.device_ms.py").compute(run) is None
+    # and what the loop's readers, the rows outside it and nothing else hold
+    # is the whole of the leaf rows
+    leaf = sum(r["ns"] for r in run["trace"]["ops"] if not r["container"])
+    outside = 300_000 + 100_000 + 3_000_000 + 700_000
+    assert leaf / 1e6 / 2 == pytest.approx(body.compute(run) + outside / 2e6)
+    # nothing to read without a loop, a trace or the gauge: None, no raise
+    for empty in (dict(run, trace=None), dict(run, program_ops=[]),
+                  dict(run, program_ops=[o for o in run["program_ops"]
+                                         if o["type"] != "repeat"])):
+        for reader in (body, recompute, attn, head, roof):
+            assert reader.compute(empty) is None
+    assert saved.compute(dict(run, registry={})) is None
+
+
+OURO_WRAPPERS = {"ouro.opt_device_ms": "opt.device_ms",
+                 "ouro.donated_gib": "step.donated_gib",
+                 "ouro.feed_produce_ms_per_step": "feed.produce_ms_per_step"}
+
+
+@pytest.mark.parametrize("name", sorted(OURO_WRAPPERS))
+def test_an_ouro_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run = _ouro_run_record()
+    run["registry"]["pt_executor_donated_bytes"] = 7.35e9
+    run["timers_s"] = {"prefetch.read": 0.004, "prefetch.batch": 0.002}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", OURO_WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == OURO_WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+    if name == "ouro.opt_device_ms":
+        assert got == pytest.approx(1.5)     # Adam stands alone behind the loop
+
+
+def test_the_manifest_lists_the_ouro_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = OURO + ".train-log10"
+    assert manifest["workloads"][-1]["name"] == cell     # appended, last
+    entry = manifest["workloads"][-1]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        OURO, "train-log10", 1)
+    assert len(manifest["workloads"]) == 7 and len(manifest["configs"]) == 6
+    assert sum(w["chips"] for w in manifest["workloads"]) == 7   # no 4-chip
+    config = manifest["configs"][-1]
+    assert config["name"] == OURO and config["source"] == _ouro_config()["source"]
+    assert config["reduced"] == _ouro_config()["reduced"] == [
+        "num_hidden_layers", "layer_types"]
+    mine = [m["name"] for m in manifest["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine == ["repeat.body_device_ms", "repeat.recompute_ms",
+                    "repeat.saved_gib", "ouro.attn_device_ms",
+                    "ouro.head_device_ms", "ouro.flash_roofline",
+                    "ouro.opt_device_ms", "ouro.donated_gib",
+                    "ouro.feed_produce_ms_per_step"]
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(mine):] == mine                    # appended, last
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert {by_name[n]["layer"] for n in mine[:3]} == {"Looped stack"}
+    assert by_name["repeat.saved_gib"]["moves"] == "peak_hbm_gib" \
+        == by_name["ouro.donated_gib"]["moves"]
+    assert by_name["ouro.flash_roofline"]["unit"] == "%"
+    for name in mine:
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    # no reader that was there lists the new cell: their entries are untouched
+    assert not [m["name"] for m in manifest["per_layer"][:-len(mine)]
+                if cell in m.get("workloads", ())]
+    # no step tail: the cell reports items_s, peak_hbm_gib and setup_s
+    tail = next(m for m in manifest["end_to_end"] if m["name"] == "step_ms_p90")
+    assert cell not in tail["workloads"]
+    assert manifest["run_seconds"] == 36
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        traffic = json.load(f)
+    assert (traffic["batch"], traffic["seqlen"], traffic["sync_every"],
+            traffic["warmup_steps"], traffic["trace_seconds"]) == (
+        1, 4096, 10, 20, 6)
+    for why in (entry["why"], config["why"]):
+        assert len(why) <= 200 and "\n" not in why
+
+
+def test_the_benchmarks_ouro_reference_is_the_trees_bit_for_bit():
+    """`chipbench/configs/ouro-2.6b/reference.py` is a copy of
+    `tests/looped_reference.py`, text for text, and gives the same cost and
+    gradients to the bit on the CPU: the two cannot drift apart unseen."""
+    import looped_reference as tree
+
+    copy = _load("configs", OURO, "reference.py")
+    assert open(copy.__file__).read() == open(tree.__file__).read()
+    cfg = dict(_ouro_config(), **_ouro_config()["rehearsal"])
+    d, V, f = cfg["hidden_size"], cfg["vocab_size"], cfg["intermediate_size"]
+    width = cfg["num_attention_heads"] * cfg["head_dim"]
+    layer = [(d,), (d, width), (d, width), (d, width), (width, d), (d,), (d,),
+             (d, f), (d, f), (f, d), (d,)]
+    shapes = [(V, d)] + layer * cfg["num_hidden_layers"] \
+        + [(d,), (d, V), (d,), (1,)]
+    rng = np.random.RandomState(0)
+    params = [(rng.randn(*s) * 0.2 + (len(s) == 1)).astype(np.float32)
+              for s in shapes]
+    params[-2] -= 1.0                  # the gate's weight: about zero, not one
+    toks = rng.randint(0, V, (2, 41))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    (c1, g1), (c2, g2) = (m.loss_and_grads(cfg, params, feed)
+                          for m in (tree, copy))
+    assert float(c1) == float(c2) and np.isfinite(float(c1))
+    assert len(g1) == len(g2) == len(params)
+    for a, b in zip(g1, g2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert np.abs(np.asarray(a)).max() > 0
+
+
+def test_the_ouro_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference at the rehearsal's tolerances, the loop's gauge reaches the run
+    record, and every metric's name carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         OURO + ".train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486144"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.repeat.saved_gib",
+            "REHEARSAL_ON_CPU.ouro.donated_gib",
+            "REHEARSAL_ON_CPU.ouro.feed_produce_ms_per_step",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    # four turns' carries [2, 64, 64] and the stacked costs and gate logits
+    assert result["metrics"]["REHEARSAL_ON_CPU.repeat.saved_gib"]["value"] \
+        == (4 * 2 * 64 * 64 * 4 + 2 * 4 * 2 * 64 * 4) / 2**30
+    assert "choice_counts_off_program" not in result["compared"]
+
+
+def test_the_parents_tree_ends_at_once_on_a_cell_it_lacks(tmp_path):
+    """A manifest without the cell (the parent's own) ends `run.py` with exit
+    code 2 before any backend starts."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         "no-such-config.train-log10", "--rehearse-cpu"],
+        capture_output=True, text=True, timeout=120, cwd=ROOT)
+    assert out.returncode == 2 and "no cell" in out.stderr

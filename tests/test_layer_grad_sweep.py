@@ -950,6 +950,37 @@ def build_cond():
     return _scalar(out), feed
 
 
+@case
+def build_Repeat():
+    """Three turns of a tanh layer with its own parameters on the carried
+    stream, and a turn output: the gradient of a weight the loop reads three
+    times, through the scan and its rematerialised body."""
+    h, feed = _pre(3, 6)
+    loop = L.Repeat(times=3)
+    with loop.block():
+        h2 = L.elementwise_add(h, L.fc(h, size=6, act="tanh"))
+        loop.update(h, h2)
+        loop.turn_output(L.reduce_sum(h2, dim=-1))
+    h_fin, rows = loop()
+    return L.elementwise_add(_scalar(h_fin), _scalar(rows)), feed
+
+
+@case
+def build_exit_gate():
+    h, feed = _pre(3, 6)
+    return _scalar(L.exit_gate(h, param_attr=pt.ParamAttr(
+        initializer=pt.initializer.NormalInitializer(0.0, 0.5)))), feed
+
+
+@case
+def build_exit_expected_cost():
+    """Four exits' costs and gate logits from one trainable stream."""
+    h, feed = _pre(4, 6)                       # [K = 4, 6]
+    cost, _ = L.exit_expected_cost(L.elementwise_mul(h, h), L.tanh(h),
+                                   beta=0.05)
+    return _scalar(cost), feed
+
+
 # ------------------------------------------------------------------ exempt --
 EXEMPT = {
     # graph construction / constants — nothing differentiable

@@ -638,6 +638,41 @@ def test_glm_moe_step_program_fits_one_chip(one_chip, compiled_mode,
             < 15.0 * 2**30)
 
 
+def test_looped_lm_step_program_fits_one_chip(one_chip, compiled_mode,
+                                              monkeypatch):
+    """The ouro-2.6b cell's whole step (batch 1 x T 4096, 8 layers of Ouro-2.6B
+    run four times through `layers.Repeat`, 612 M parameters) compiles for the
+    described v5e under 15.0 GiB by `memory_analysis()` (arguments 6.845 +
+    temporaries 7.906, which overstate: the compiler's `program HBM usage` line
+    says 6.03 G beside the arguments, 12.90 GiB, and the chip reads that to
+    0.01), holds the loop as `while`s (a forward and a backward one), and calls the flash kernels as
+    often as 2 L forward and L backward applications need: the stack once in
+    the forward loop's body, once recomputed and once backward in the backward
+    loop's; not K L times."""
+    layers_, turns = 8, 4
+    raw, args = _step_program(lambda: _benchmark_model("ouro-2.6b", 1, 4096),
+                              1, 4096, one_chip, monkeypatch)
+    jaxpr = jax.make_jaxpr(raw)(*args)
+    launches = dict(_launches(jaxpr.jaxpr))
+    assert launches == {"flash_attention_fwd": 2 * layers_,
+                        "flash_attention_bwd": layers_}
+    assert str(jaxpr).count(f"length={turns}") >= 2      # the two scans
+    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 2
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len([c for c in calls if "flash_attention_fwd" in c]) == 2 * layers_
+    assert len([c for c in calls if "flash_attention_bwd" in c]) == layers_
+    assert len(calls) < turns * layers_
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 7.3e9          # 12 B a parameter
+    print("looped step: arguments %.3f GiB, temporaries %.3f GiB" % (
+        memory.argument_size_in_bytes / 2**30,
+        memory.temp_size_in_bytes / 2**30))
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.0 * 2**30)
+
+
 def test_afmoe_step_program_fits_one_chip(one_chip, compiled_mode,
                                           monkeypatch):
     """The Trinity-Mini share's whole step at the size `configs/afmoe.py`
