@@ -202,11 +202,19 @@ def _v5e_block_sizes(Tq: int, Tk: int, dtype=None) -> FlashBlocks:
 # Masks: `causal` (column <= row) and, with it, `window` W (column > row - W):
 # two diagonals W apart. A (q block, k block) pair wholly outside the band
 # between them is neither fetched (the index maps clamp to the band's first
-# and last block: `_k_range`, `_q_range`) nor computed (`_on_blocks`); one a
-# diagonal crosses is masked; one inside runs bare. A row whose window starts
-# right of the first block it visits sees only masked scores there: what it
-# sums meanwhile is wiped by the first real block (alpha = exp(-1e30 - m) is 0
-# exactly), and every row has one: its own position.
+# and last block: `_k_range`, `_q_range`) nor computed (`_on_blocks`); one
+# inside runs bare, whole; one a diagonal crosses is computed as two strips
+# (`_strips`): the half of its rows that reads the whole width of the block
+# (or, two heads a lane block, the half of its columns that every row reads),
+# and the triangle alone in the other half; the quarter of the block that
+# holds no pair inside the band is not computed. Both strips run under the
+# mask, each traced once, at a place the kernel works out from the block's:
+# three copies of the step a kernel, whatever the sequence and with or
+# without a window (a loop over smaller sub-tiles was measured and lost on
+# the chip: PERF.md section 6, PR 46). A row whose window starts right of the
+# first strip it visits sees only masked scores there: what it sums meanwhile
+# is wiped by the first real one (alpha = exp(-1e30 - m) is 0 exactly), and
+# every row has one: its own position.
 #
 # The softmax statistic the backward needs is one float32 a (row, head): the
 # log-sum-exp, kept as [B, Tq, 128 x ceil(heads / 128)] with head h in lane
@@ -245,31 +253,98 @@ def _merge(parts, masks):
 
 
 def _causal_keep(bq, bk, q0, k0, window=0):
-    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    """[bq, bk] mask of the pairs inside the band, rows from q0 and columns
+    from k0: 0 <= row - column (< window). The offsets meet the iotas'
+    difference as one scalar, so a mask costs a compare a diagonal."""
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+             - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    off = k0 - q0
     if not window:
-        return cols <= rows
-    return jnp.logical_and(cols <= rows, cols > rows - window)
+        return ahead >= off
+    return jnp.logical_and(ahead >= off, ahead < off + window)
 
 
-def _on_blocks(causal, window, q0, bq, k0, bk, step):
-    """Run `step(diag)` for this (q block, k block): not at all where the
-    causal rule or the window empties it, with the mask only where one of
-    the two diagonals crosses (the upper one: column == row; with a
-    `window`, the lower one: column == row - window + 1)."""
-    if not causal:
-        step(False)
-        return
+def _block_kind(q0, bq, k0, bk, window):
+    """(inside, crosses) of the (q block, k block) at rows q0, columns k0:
+    some pair is inside the band; one of the two diagonals crosses it (the
+    upper one: column == row; with a `window`, the lower one: column == row
+    - window + 1). Operators only: Python ints, numpy and traced values."""
     crosses = k0 + bk - 1 > q0          # some column is right of some row
     inside = k0 <= q0 + bq - 1          # some column is at or left of a row
     if window:
         # the last column is within the window of the first row; the first
         # column is left of the window of the last row
-        inside = jnp.logical_and(inside, k0 + bk - 1 > q0 - window)
-        crosses = jnp.logical_or(crosses, k0 < q0 + bq - window)
-    pl.when(jnp.logical_and(inside, crosses))(lambda: step(True))
+        inside = inside & (k0 + bk - 1 > q0 - window)
+        crosses = crosses | (k0 < q0 + bq - window)
+    return inside, crosses
+
+
+def _in_strips(bq: int, bk: int, window: int) -> bool:
+    """Whether a crossed block is computed as two strips: square blocks (so
+    the causal diagonal is a block's own) and a window of whole blocks (so
+    the lower diagonal is another block's own, and no block holds both).
+    Anything else computes the whole crossed block under the mask."""
+    return bq == bk and bq % (2 * _LANES) == 0 and window % bk == 0
+
+
+def _strips(q0, k0, bq, window, columns=False, where=jnp.where):
+    """The two strips of the crossed square block at rows q0, columns k0, h
+    = bq / 2: ((rows, columns) of the h x h triangle, where the long strip
+    starts). By rows (the long strip h x bq: half the rows, every column):
+    under the causal diagonal (k0 == q0) the upper rows read the left
+    triangle alone and the lower rows all columns; under a window's lower
+    diagonal (k0 == q0 - window) the lower rows read the right triangle alone
+    and the upper rows all columns. By `columns` (the long strip bq x h:
+    every row, half the columns) the same two regions cut the other way: the
+    left columns are read by every row and the right ones by the lower rows'
+    triangle alone, and the reverse under a lower diagonal. Either way the h
+    x h quarter left out holds no pair inside the band. Without a window the
+    places are Python ints."""
+    h = bq // 2
+    lower = where(k0 < q0, h, 0) if window else 0   # h: the lower diagonal's
+    if columns:
+        return (h - lower, h - lower), lower
+    return (lower, lower), h - lower
+
+
+def _on_blocks(causal, window, q0, bq, k0, bk, step, columns=False):
+    """Run `step(r0, rn, c0, cn, diag)` (rows [r0, r0 + rn) by columns [c0,
+    c0 + cn) of the block pair, `diag`: under the mask) for this (q block, k
+    block): not at all where the causal rule or the window empties it; bare
+    over the whole block where neither diagonal crosses it; where one does,
+    as `_strips`' two masked strips (the long one by `columns` or by rows),
+    or whole under the mask."""
+    whole = lambda diag: step(0, bq, 0, bk, diag)  # noqa: E731
+    if not causal:
+        whole(False)
+        return
+    inside, crosses = _block_kind(q0, bq, k0, bk, window)
+
+    def crossed():
+        if not _in_strips(bq, bk, window):
+            whole(True)
+            return
+        h = bq // 2
+        (r0, c0), long = _strips(q0, k0, bq, window, columns)
+        step(r0, h, c0, h, True)
+        if columns:
+            step(0, bq, long, h, True)
+        else:
+            step(long, h, 0, bk, True)
+
+    pl.when(jnp.logical_and(inside, crosses))(crossed)
     # crossed by neither diagonal and not above the upper one: inside
-    pl.when(jnp.logical_not(crosses))(lambda: step(False))
+    pl.when(jnp.logical_not(crosses))(lambda: whole(False))
+
+
+def _span(start, size, whole):
+    """The rows (or columns) [start, start + size) of a block of `whole`:
+    all of it, or a slice at a multiple of its size."""
+    if size == whole:
+        return slice(None)
+    if isinstance(start, int):
+        return pl.ds(start, size)
+    return pl.ds(pl.multiple_of(start, size), size)
 
 
 def _stat_lane(stats, head):
@@ -320,31 +395,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal,
             qj = _only(q_ref[0], masks[j])
             q_sc[j] = _scaled(qj, scale) if fold else qj
 
-    def step(diag):
-        k, v = k_ref[0], v_ref[0]
-        keep = (_causal_keep(bq, bk, qi * bq, ki * bk, window) if diag
-                else None)
+    def step(r0, rn, c0, cn, diag):
+        rows = _span(r0, rn, bq)
+        cols = _span(c0, cn, bk)
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        keep = (_causal_keep(rn, cn, qi * bq + r0, ki * bk + c0, window)
+                if diag else None)
         alphas, pvs = [], []
         for j in heads:
-            s = jax.lax.dot_general(q_sc[j], k, _NT,
+            s = jax.lax.dot_general(q_sc[j, rows, :], k, _NT,
                                     preferred_element_type=jnp.float32)
             if not fold:
                 s = s * scale
             if keep is not None:
                 s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_sc[j]
+            m_prev = m_sc[j, rows, :]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - _across(m_next, bk))
+            p = jnp.exp(s - _across(m_next, cn))
             alpha = jnp.exp(m_prev - m_next)
-            l_sc[j] = alpha * l_sc[j] + jnp.sum(p, axis=1, keepdims=True)
-            m_sc[j] = m_next
+            l_sc[j, rows, :] = (alpha * l_sc[j, rows, :]
+                                + jnp.sum(p, axis=1, keepdims=True))
+            m_sc[j, rows, :] = m_next
             alphas.append(_across(alpha, W))
             pvs.append(jnp.dot(p.astype(v.dtype), v,
                                preferred_element_type=jnp.float32))
-        acc_sc[...] = (_merge(alphas, masks) * acc_sc[...]
-                       + _merge(pvs, masks))
+        acc_sc[rows, :] = (_merge(alphas, masks) * acc_sc[rows, :]
+                           + _merge(pvs, masks))
 
-    _on_blocks(causal, window, qi * bq, bq, ki * bk, bk, step)
+    # two heads a lane block: a crossed block's long strip runs down the rows
+    # (0.574 -> 0.562 ms forward, 0.893 -> 0.884 backward at gpt2-small's
+    # shape on the chip; one head a block reads the same either way or worse)
+    _on_blocks(causal, window, qi * bq, bq, ki * bk, bk, step,
+               columns=len(masks) > 1)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
@@ -388,11 +470,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
     rows = pl.ds(pl.multiple_of(qi * bq, bq), bq) if want == "all" \
         else slice(None)
 
-    def alone(j):
+    def alone(j, cols=slice(None)):
         """Head j's K (scaled where that is exact) and V, alone in their
         lanes: the contractions with Q and dO then see that head only."""
-        kj = _only(k_ref[0], masks[j])
-        return (_scaled(kj, scale) if fold else kj), _only(v_ref[0], masks[j])
+        kj = _only(k_ref[0, cols, :], masks[j])
+        return ((_scaled(kj, scale) if fold else kj),
+                _only(v_ref[0, cols, :], masks[j]))
 
     if want != "dq":
         @pl.when(qi == 0)
@@ -406,15 +489,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         def _():
             dq_sc[rows, :] = jnp.zeros((bq, W), jnp.float32)
 
-    def step(diag):
-        q, k, do = q_ref[0], k_ref[0], do_ref[0]
-        stats = lse_ref[0]
-        o_do = o_ref[0].astype(jnp.float32) * do.astype(jnp.float32)
-        keep = (_causal_keep(bq, bk, qi * bq, ki * bk, window) if diag
-                else None)
+    def step(r0, rn, c0, cn, diag):
+        local = _span(r0, rn, bq)
+        q, do = q_ref[0, local, :], do_ref[0, local, :]
+        stats = lse_ref[0, local, :]
+        o_do = o_ref[0, local, :].astype(jnp.float32) * do.astype(jnp.float32)
+        # the rows of dQ's accumulator these add to
+        into = (pl.ds(pl.multiple_of(qi * bq + r0, rn), rn) if want == "all"
+                else local)
+        cols = _span(c0, cn, bk)
+        keep = (_causal_keep(rn, cn, qi * bq + r0, ki * bk + c0, window)
+                if diag else None)
         dks, dvs, dqs = [], [], []
         for j in heads:
-            kj, vj = alone(j) if want == "dq" else (k_sc[j], v_sc[j])
+            kj, vj = (alone(j, cols) if want == "dq"
+                      else (k_sc[j, cols, :], v_sc[j, cols, :]))
             s = jax.lax.dot_general(q, kj, _NT,
                                     preferred_element_type=jnp.float32)
             if not fold:
@@ -436,14 +525,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
                 dks.append(jax.lax.dot_general(
                     ds, q, _TN, preferred_element_type=jnp.float32))
             if want != "dkv":
-                dqs.append(jnp.dot(ds, k, preferred_element_type=jnp.float32))
+                dqs.append(jnp.dot(ds, k_ref[0, cols, :],
+                                   preferred_element_type=jnp.float32))
         if want != "dq":
-            dk_sc[...] += _merge(dks, masks)
-            dv_sc[...] += _merge(dvs, masks)
+            dk_sc[cols, :] += _merge(dks, masks)
+            dv_sc[cols, :] += _merge(dvs, masks)
         if want != "dkv":
-            dq_sc[rows, :] += _merge(dqs, masks)
+            dq_sc[into, :] += _merge(dqs, masks)
 
-    _on_blocks(causal, window, qi * bq, bq, ki * bk, bk, step)
+    _on_blocks(causal, window, qi * bq, bq, ki * bk, bk, step,
+               columns=len(masks) > 1)
 
     # s = scale x q k^T, so dQ and dK carry the scale too: where it is a
     # power of two it is applied once, to the sums, which rounds the same;
@@ -686,13 +777,62 @@ _DISPATCH_COUNTER = "pt_flash_attention_dispatch_total"
 _DISPATCH_HELP = ("attention ops traced, by the path the dispatcher chose "
                   "from the input's shape (packed: the fused kernels; "
                   "packed_window: the same kernels with a window bound)")
+_PAIRS_GAUGE = "pt_flash_attention_pairs"
+_PAIRS_HELP = ("query-key score pairs of the attention ops traced so far, by "
+               "the dispatcher's path: `computed` by the path's forward (the "
+               "kernels' whole blocks and strips of crossed ones; all of "
+               "them on `xla`), `kept` by the mask")
+_pairs: dict = {}      # (path, "computed" | "kept") -> pairs, summed over ops
 
 
-def _count_dispatch(path: str) -> None:
+def pair_counts(Tq, Tk, causal, window=0, blocks=None):
+    """(computed, kept) score pairs of one (batch, head) from the static
+    shapes: what the mask keeps, and what the forward computes for them:
+    every pair without `blocks` (the XLA formulation), else what the kernels'
+    `_on_blocks` runs: the whole of a block inside the band, `_strips`' three
+    quarters of a block a diagonal crosses (or all of it), nothing of the
+    rest."""
+    import numpy as np
+
+    if not causal:
+        return Tq * Tk, Tq * Tk
+    i = np.arange(Tq)
+    first = np.maximum(i - window + 1, 0) if window else 0
+    kept = int(np.maximum(np.minimum(i, Tk - 1) - first + 1, 0).sum())
+    if blocks is None:
+        return Tq * Tk, kept
+    bq, bk = blocks
+    q0 = (np.arange(Tq // bq) * bq)[:, None]
+    k0 = (np.arange(Tk // bk) * bk)[None, :]
+    inside, crosses = _block_kind(q0, bq, k0, bk, window)
+    whole = int((~crosses).sum()) * bq * bk
+    crossed = int((inside & crosses).sum()) * bq * bk
+    if _in_strips(bq, bk, window):
+        crossed = crossed * 3 // 4
+    return whole + crossed, kept
+
+
+def _pairs_family():
+    return [(_PAIRS_GAUGE, "gauge", _PAIRS_HELP,
+             [({"path": path, "pairs": kind}, float(n))
+              for (path, kind), n in sorted(_pairs.items())])]
+
+
+def _count_dispatch(path: str, q, k, causal, window) -> None:
+    """One attention op traced: its path, and its score pairs (a gauge that
+    sums over the ops traced: a counter's increase would be over before a
+    window of steps opens)."""
     from ..obs import metrics
 
-    metrics.registry().counter_inc(_DISPATCH_COUNTER, help=_DISPATCH_HELP,
-                                   labels={"path": path})
+    reg = metrics.registry()
+    reg.counter_inc(_DISPATCH_COUNTER, help=_DISPATCH_HELP,
+                    labels={"path": path})
+    (B, Tq, H, _), Tk = q.shape, k.shape[1]
+    blocks = None if path == "xla" else _v5e_block_sizes(Tq, Tk, q.dtype)
+    computed, kept = pair_counts(Tq, Tk, causal, window, blocks)
+    for kind, n in (("computed", computed), ("kept", kept)):
+        _pairs[path, kind] = _pairs.get((path, kind), 0) + B * H * n
+    reg.add_collector(_pairs_family)
 
 
 def flash_attention(q, k, v, causal: bool = False, window: int = 0):
@@ -709,7 +849,9 @@ def flash_attention(q, k, v, causal: bool = False, window: int = 0):
     inputs' dtype in and out (bf16 under AMP), float32 scores, statistics
     and accumulators inside the kernels. The choice is made when the op is
     traced and counted in `pt_flash_attention_dispatch_total{path}` (`xla`,
-    `packed`, or `packed_window` for the kernels with a window bound)."""
+    `packed`, or `packed_window` for the kernels with a window bound), with
+    the score pairs that path computes and the mask keeps beside it
+    (`pt_flash_attention_pairs{path,pairs}`)."""
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
     window = int(window or 0)
@@ -735,9 +877,10 @@ def flash_attention(q, k, v, causal: bool = False, window: int = 0):
     # back to the XLA formulation, which GSPMD partitions natively.
     sharded = am is not None and am.dp > 1
     if not flash_eligible(q, k, window) or (sharded and q.shape[0] % am.dp):
-        _count_dispatch("xla")
+        _count_dispatch("xla", q, k, causal, window)
         return _reference(q, k, v, causal, window)
-    _count_dispatch("packed_window" if window else "packed")
+    _count_dispatch("packed_window" if window else "packed", q, k, causal,
+                    window)
     if sharded:
         call = mesh_dispatch.shard_batch(
             functools.partial(_flash_kernel, causal=causal, window=window),

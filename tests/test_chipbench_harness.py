@@ -1817,7 +1817,9 @@ def test_the_manifest_lists_the_ouro_cell_and_its_metrics():
                     "ouro.opt_device_ms", "ouro.donated_gib",
                     "ouro.feed_produce_ms_per_step"]
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(mine):] == mine                    # appended, last
+    end = names.index(mine[-1]) + 1                      # appended in PR 44
+    assert names[end - len(mine):end] == mine
+    assert names[end:] == ["attn.masked_pair_share"]     # PR 46's, every cell
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     assert {by_name[n]["layer"] for n in mine[:3]} == {"Looped stack"}
     assert by_name["repeat.saved_gib"]["moves"] == "peak_hbm_gib" \
@@ -1826,7 +1828,7 @@ def test_the_manifest_lists_the_ouro_cell_and_its_metrics():
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     # no reader that was there lists the new cell: their entries are untouched
-    assert not [m["name"] for m in manifest["per_layer"][:-len(mine)]
+    assert not [m["name"] for m in manifest["per_layer"][:end - len(mine)]
                 if cell in m.get("workloads", ())]
     # no step tail: the cell reports items_s, peak_hbm_gib and setup_s
     tail = next(m for m in manifest["end_to_end"] if m["name"] == "step_ms_p90")
@@ -1909,3 +1911,84 @@ def test_the_parents_tree_ends_at_once_on_a_cell_it_lacks(tmp_path):
          "no-such-config.train-log10", "--rehearse-cpu"],
         capture_output=True, text=True, timeout=120, cwd=ROOT)
     assert out.returncode == 2 and "no cell" in out.stderr
+
+
+# ---------------------------------------------------------------------------
+# PR 46: attn.masked_pair_share, the share of the computed score pairs that the
+# mask throws away, from the gauge the attention op sets when it is traced.
+
+PAIRS = 'pt_flash_attention_pairs{pairs="%s",path="%s"}'
+MASKED_SHARES = [
+    # id, the window's registry, the share
+    # gpt2-small on the parent's arithmetic: 3 blocks of 512 x 512 computed a
+    # layer for T (T + 1) / 2 pairs: 1 - 524 800 / 786 432 = 0.3327
+    ("whole_crossed_blocks", {PAIRS % ("computed", "packed"): 12 * 144 * 786432.0,
+                              PAIRS % ("kept", "packed"): 12 * 144 * 524800.0},
+     1 - 524800 / 786432),
+    # a crossed block as two strips, three quarters of it: 2.5 blocks computed
+    ("strips", {PAIRS % ("computed", "packed"): 655360.0,
+                PAIRS % ("kept", "packed"): 524800.0},
+     1 - 524800 / 655360),
+    # trinity's two paths summed: four window layers at 17.5 blocks of 1024
+    # (7 whole, 14 crossed) and the global one at 34 (28 and 8), for 14 and 32
+    # blocks' worth kept: 16 / 104
+    ("two_paths", {PAIRS % ("computed", "packed_window"): 4 * 17.5,
+                   PAIRS % ("kept", "packed_window"): 4 * 14.0,
+                   PAIRS % ("computed", "packed"): 34.0,
+                   PAIRS % ("kept", "packed"): 32.0,
+                   "pt_flash_attention_dispatch_total{path=\"packed\"}": 0.0},
+     16 / 104),
+    ("no_series_the_parent", {"pt_executor_donated_bytes": 8.4e9}, None),
+    ("no_registry", None, None),
+]
+
+
+@pytest.mark.parametrize("registry,share", [c[1:] for c in MASKED_SHARES],
+                         ids=[c[0] for c in MASKED_SHARES])
+def test_masked_pair_share_reads_the_ops_pairs_gauge(registry, share):
+    reader = _load("layer_metrics", "attn.masked_pair_share.py")
+    got = reader.compute({"registry": registry})
+    assert got == (share if share is None else pytest.approx(share))
+    if share is not None:
+        assert set(reader.info({"registry": registry})["pairs_by_path"]) \
+            <= {"packed", "packed_window", "xla"}
+
+
+def test_the_manifest_lists_the_masked_pair_share_for_every_cell():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = manifest["per_layer"][-1]
+    assert entry == {"name": "attn.masked_pair_share", "unit": "ratio",
+                     "better": "lower", "source": "program_counter",
+                     "layer": "Kernels", "moves": "items_s"}
+    assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
+                                       entry["name"] + ".py"))
+
+
+def test_the_traced_op_publishes_the_pairs_the_reader_reads():
+    """The program's side: an attention op traced on each path sets
+    `pt_flash_attention_pairs`, which `registry_snapshot` reads as a gauge (so
+    `registry_delta` keeps its value at the close, though the op was traced
+    before the window opened), and the reader gives the share."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import flash_ops
+
+    train = _load("drivers", "train.py")
+    x = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    before = train.registry_snapshot()
+    flash_ops.flash_attention(x, x, x, causal=True)               # XLA here
+    flash_ops.flash_attention(x, x, x, causal=True, window=128)
+    after = train.registry_snapshot()
+    series = PAIRS % ("computed", "xla")
+    assert after[series][0] == "gauge"
+    grew = {s: after[s][1] - before.get(s, ("gauge", 0.0))[1]
+            for s in (PAIRS % ("computed", "xla"), PAIRS % ("kept", "xla"))}
+    band = 256 * 257 // 2
+    narrow = 128 * 129 // 2 + 128 * 128
+    assert grew == {PAIRS % ("computed", "xla"): 2 * 2 * 256 * 256,
+                    PAIRS % ("kept", "xla"): 2 * (band + narrow)}
+    reader = _load("layer_metrics", "attn.masked_pair_share.py")
+    share = reader.compute({"registry": train.registry_delta(after, after)})
+    kept, computed = (after[PAIRS % (k, "xla")][1] for k in ("kept", "computed"))
+    assert share == pytest.approx(1 - kept / computed)

@@ -156,6 +156,14 @@ PACKED_CASES = [
     # several q and k blocks: the causal rule skips a block, masks the
     # diagonal ones and leaves one whole
     ("d64_h2_causal_blocks_bf16", 1, 1024, 1024, 2, 64, True, jnp.bfloat16),
+    # the same in float32, a head dim each: the diagonal blocks (512 rows) are
+    # computed as two strips of 256 rows, three quarters of the block, and
+    # the tolerance is the tight one
+    ("d64_h2_causal_strips_f32", 1, 1024, 1024, 2, 64, True, jnp.float32),
+    ("d128_h2_causal_strips_f32", 1, 1024, 1024, 2, 128, True, jnp.float32),
+    ("d256_h1_causal_strips_f32", 1, 1024, 1024, 1, 256, True, jnp.float32),
+    ("d64_h2_cross_causal_strips_f32", 1, 1536, 1024, 2, 64, True,
+     jnp.float32),
 ]
 
 
@@ -268,6 +276,30 @@ WINDOW_CASES = [
     ("d256_bf16", 512, 1, 1, 256, 256, (128, 128), jnp.bfloat16),
     ("gqa_group_2", 384, 4, 2, 128, 128, (128, 128), jnp.float32),
     ("gqa_group_8_bf16", 512, 8, 1, 128, 256, (128, 128), jnp.bfloat16),
+    # blocks of 512 and of 1024, the sizes the chip runs, a crossed block in
+    # two strips of half its rows: causal alone (window 0), windows of one,
+    # two and three blocks' width; and what computes the whole crossed block
+    # under the mask: a window that is no multiple of the block, uneven
+    # blocks; grouped K/V heads; every head dim
+    ("b512_causal_alone_d64", 1024, 2, 2, 64, 0, (512, 512), jnp.float32),
+    ("b512_w1blk_d128", 1536, 1, 1, 128, 512, (512, 512), jnp.float32),
+    ("b512_w1blk_d64_two_heads_a_block", 1536, 2, 2, 64, 512, (512, 512),
+     jnp.float32),
+    ("b512_w2blk_gqa_group_2", 1536, 4, 2, 128, 1024, (512, 512),
+     jnp.float32),
+    ("b512_w3blk_d256_bf16", 2048, 1, 1, 256, 1536, (512, 512), jnp.bfloat16),
+    ("b512_w_not_a_multiple_d64", 1536, 2, 2, 64, 640, (512, 512),
+     jnp.float32),
+    ("b1024_causal_alone_d128", 2048, 1, 1, 128, 0, (1024, 1024),
+     jnp.float32),
+    ("b1024_w1blk_gqa_group_2", 3072, 2, 1, 128, 1024, (1024, 1024),
+     jnp.float32),
+    ("b1024_w2blk_d128_bf16", 3072, 1, 1, 128, 2048, (1024, 1024),
+     jnp.bfloat16),
+    ("b1024_w_narrower_than_half_a_block_d256", 2048, 1, 1, 256, 384,
+     (1024, 1024), jnp.float32),
+    ("b1024_uneven_blocks_d64", 2048, 2, 2, 64, 1280, (512, 1024),
+     jnp.float32),
 ]
 
 
@@ -277,7 +309,8 @@ WINDOW_CASES = [
 def test_window_kernels_match_the_plain_mask(T, H, KV, D, window, blocks,
                                              dtype):
     """Forward, the fused backward and the split backward (dK / dV and dQ in
-    a pass each) under `causal + window`, against the mask written out."""
+    a pass each) under `causal + window` (window 0: causal alone), against
+    the mask written out."""
     from jax.experimental.pallas import tpu as pltpu
 
     from paddle_tpu.ops import flash_ops
@@ -293,7 +326,7 @@ def test_window_kernels_match_the_plain_mask(T, H, KV, D, window, blocks,
             q, k, v, out, lse, do, heads=H, causal=True, blocks=blocks,
             fused=fused, window=window) for fused in (True, False)]
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
-    ref, vjp = jax.vjp(lambda *a: _window_oracle(*a, H, window), *f32)
+    ref, vjp = jax.vjp(lambda *a: _window_oracle(*a, H, window or T), *f32)
     ref_grads = vjp(do.astype(jnp.float32))
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
@@ -339,6 +372,180 @@ def test_window_index_maps_name_only_blocks_inside_the_band(T, bq, bk, window):
     if (T, bq, bk, window) == (8192, 1024, 1024, 2048):
         assert max(len({int(kmap(qi, ki)) for ki in range(nk)})
                    for qi in range(nq)) == 3
+
+
+STRIPS = [
+    # id, T, bq, bk, window
+    ("gpt2", 1024, 512, 512, 0),
+    ("trinity_window", 8192, 1024, 1024, 2048),
+    ("glm_global", 8192, 1024, 1024, 0),
+    ("window_of_one_block", 2048, 512, 512, 512),
+    ("window_of_three_blocks", 4096, 512, 512, 1536),
+    ("blocks_of_256", 1024, 256, 256, 256),
+    # whole crossed blocks under the mask
+    ("window_no_multiple_of_the_block", 2048, 512, 512, 640),
+    ("window_inside_a_block", 2048, 512, 512, 128),
+    ("uneven_blocks", 2048, 512, 1024, 1024),
+    ("uneven_blocks_k_smaller", 2048, 1024, 512, 1024),
+    ("blocks_of_one_lane_tile", 1024, 128, 128, 256),
+    ("window_of_one_key", 1024, 256, 256, 1),
+]
+
+
+def _band(T, window):
+    ahead = np.arange(T)[:, None] - np.arange(T)[None, :]
+    return (ahead >= 0) & (ahead < (window or T))
+
+
+def _computed_of(block, q0, k0, bq, bk, window, columns):
+    """The pairs of a crossed block the kernels compute, as a mask over it:
+    `_strips`' two pieces, or all of it."""
+    from paddle_tpu.ops import flash_ops
+
+    done = np.zeros_like(block)
+    if not flash_ops._in_strips(bq, bk, window):
+        done[:] = True
+        return done
+    h = bq // 2
+    (r0, c0), long = flash_ops._strips(q0, k0, bq, window, columns,
+                                       where=np.where)
+    r0, c0, long = int(r0), int(c0), int(long)
+    assert r0 == c0 and {r0, long} == {0, h}
+    done[r0:r0 + h, c0:c0 + h] = True
+    if columns:
+        done[:, long:long + h] = True
+    else:
+        done[long:long + h, :] = True
+    return done
+
+
+@pytest.mark.parametrize("columns", [False, True], ids=["rows", "columns"])
+@pytest.mark.parametrize("T,bq,bk,window", [c[1:] for c in STRIPS],
+                         ids=[c[0] for c in STRIPS])
+def test_strips_hold_every_kept_pair_and_leave_out_the_empty_quarter(
+        T, bq, bk, window, columns):
+    """`_block_kind` and `_strips`, the pure functions the kernels place their
+    steps by, over every block of a sequence against the mask written out: a
+    block is computed iff it holds a kept pair, whole and bare iff it holds
+    nothing else; of a crossed block every kept pair lies in a computed
+    piece, every computed quarter holds a kept pair (a quarter's K/V columns
+    are live for other rows, so poison cannot show one computed for nothing;
+    the count can), and in strips exactly one quarter is left out. The
+    published counts (`pair_counts`) are these."""
+    from paddle_tpu.ops import flash_ops
+
+    keep = _band(T, window)
+    computed = 0
+    for q0 in range(0, T, bq):
+        for k0 in range(0, T, bk):
+            block = keep[q0:q0 + bq, k0:k0 + bk]
+            inside, crosses = flash_ops._block_kind(q0, bq, k0, bk, window)
+            assert bool(inside) == block.any(), (q0, k0)
+            if not block.any():
+                continue
+            assert bool(crosses) == (not block.all()), (q0, k0)
+            if block.all():
+                computed += bq * bk
+                continue
+            done = _computed_of(block, q0, k0, bq, bk, window, columns)
+            assert not (block & ~done).any(), (q0, k0)
+            computed += int(done.sum())
+            if flash_ops._in_strips(bq, bk, window):
+                h = bq // 2
+                quarters = block.reshape(2, h, 2, h).any(axis=(1, 3))
+                assert (quarters == done.reshape(2, h, 2, h).all(axis=(1, 3))
+                        ).all(), (q0, k0)
+                assert quarters.sum() == 3
+    assert flash_ops.pair_counts(T, T, True, window, (bq, bk)) == (
+        computed, int(keep.sum()))
+    assert flash_ops.pair_counts(T, T, True, window) == (T * T,
+                                                         int(keep.sum()))
+    assert flash_ops.pair_counts(T, T, False) == (T * T, T * T)
+
+
+def test_a_crossed_block_goes_in_strips_where_each_diagonal_has_its_own_block():
+    from paddle_tpu.ops.flash_ops import _in_strips
+
+    assert _in_strips(512, 512, 0) and _in_strips(1024, 1024, 2048)
+    assert _in_strips(256, 256, 256) and _in_strips(512, 512, 1536)
+    assert not _in_strips(128, 128, 0)          # half a block: 64 rows
+    assert not _in_strips(384, 384, 0)          # 192 rows: no whole lane tiles
+    assert not _in_strips(512, 1024, 0) and not _in_strips(1024, 512, 2048)
+    assert not _in_strips(512, 512, 640) and not _in_strips(512, 512, 128)
+
+
+@pytest.mark.parametrize("T,window,share", [
+    (1024, 0, 1 - 524800 / (2.5 * 512 * 512)),          # gpt2-small: 0.1992
+    (4096, 0, 1 - 8390656 / (34 * 512 * 512)),          # olmoe, ouro: 0.0586
+], ids=["gpt2_small", "olmoe_ouro"])
+def test_masked_share_at_the_cells_shapes(T, window, share):
+    """The arithmetic PERF.md section 3 quotes: blocks of 512 below T 8192;
+    the parent computed 3 and 36 blocks for these pairs (0.3327, 0.1110)."""
+    from paddle_tpu.ops.flash_ops import _v5e_block_sizes, pair_counts
+
+    computed, kept = pair_counts(T, T, True, window, _v5e_block_sizes(T, T))
+    assert 1 - kept / computed == pytest.approx(share)
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (list, tuple)) else [value]:
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _kernel_bodies(jaxpr, found):
+    """{kernel name: its body's jaxpr} of every pallas_call under `jaxpr`."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn.params["jaxpr"]
+            continue
+        for sub in _sub_jaxprs(eqn):
+            _kernel_bodies(sub, found)
+    return found
+
+
+def _count(jaxpr, primitive):
+    return sum((eqn.primitive.name == primitive)
+               + sum(_count(sub, primitive) for sub in _sub_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
+KERNEL_PAIRS = [
+    # id, Q's shape, K/V's width, heads, window, the parent's dot_generals in
+    # the forward and the fused backward body (c958758: a whole-block masked
+    # and a whole-block bare copy of the step, 2 and 5 a head of a lane block)
+    ("gpt2_d64", (12, 1024, 768), 768, 12, 0, 8, 20),
+    ("trinity_global_d128_gqa", (1, 8192, 4096), 512, 32, 0, 4, 10),
+    ("trinity_window_d128_gqa", (1, 8192, 4096), 512, 32, 2048, 4, 10),
+    ("glm_d256", (1, 8192, 5120), 5120, 20, 0, 4, 10),
+]
+
+
+@pytest.mark.parametrize("shape,kv_width,heads,window,fwd_dots,bwd_dots",
+                         [c[1:] for c in KERNEL_PAIRS],
+                         ids=[c[0] for c in KERNEL_PAIRS])
+def test_kernel_bodies_hold_no_more_copies_of_the_step_than_the_parents(
+        shape, kv_width, heads, window, fwd_dots, bwd_dots):
+    """The size guard (PR 45 was refused for set-up time: each of its live
+    sub-tiles was a traced copy of the step): the matmuls in the forward and
+    the fused backward body of each benchmark kernel pair are at most 1.5
+    times the parent's, with or without a window: the bare whole block and
+    the two strips of a crossed one, three copies where the parent held
+    two."""
+    from paddle_tpu.ops import flash_ops
+
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct(shape[:2] + (kv_width,), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_ops._packed_attention(
+            q, k, v, heads, True, window).astype(jnp.float32).sum(),
+        (0, 1, 2)))(q, kv, kv)
+    bodies = _kernel_bodies(jaxpr.jaxpr, {})
+    assert set(bodies) == {"flash_attention_fwd", "flash_attention_bwd"}
+    assert _count(bodies["flash_attention_fwd"], "dot_general") <= 1.5 * fwd_dots
+    assert _count(bodies["flash_attention_bwd"], "dot_general") <= 1.5 * bwd_dots
 
 
 def test_window_kernel_neither_reads_nor_computes_blocks_left_of_the_band():

@@ -277,7 +277,9 @@ def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch):
     monkeypatch.setattr(flash_ops, "flash_eligible",
                         lambda q, k=None, window=0: True)
     rng = np.random.RandomState(0)
-    mk = lambda: jnp.asarray(rng.randn(16, 32, 4, 64) * 0.3, jnp.float32)
+    # T 128: the dispatcher counts the kernels' pairs by their blocks, which
+    # divide a 128-aligned sequence (what `flash_eligible` admits)
+    mk = lambda: jnp.asarray(rng.randn(16, 128, 4, 64) * 0.3, jnp.float32)
     q, k, v = mk(), mk(), mk()
     ref = flash_ops._reference(q, k, v, True)
     g_ref = jax.grad(lambda q: jnp.sum(

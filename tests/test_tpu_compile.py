@@ -342,13 +342,15 @@ def test_plain_and_differentiated_attention_stay_two_launches(
 # four digests (PERF.md section 6); the programs without an autodiff op it
 # had to leave alone: INFERENCE_PROGRAMS, whose digests its parent gives too.
 # A PR that MEANS to change one of these programs replaces its digests here
-# and says so in PERF.md.
+# and says so in PERF.md. PR 46 meant to change the flash kernels' bodies (a
+# crossed block in two strips): the jaxprs' digests are its, the StableHLO
+# digests without the kernels' bodies are the parent's still.
 STEP_PROGRAMS = {
     "gpt2-small": (
-        "b0c916dc2ede4bd1dbb46930d62ab54dd956fc3b1ad9f4055bf6748da0c68051",
+        "67175aee1ee0d338d3262d309294bef3754189380ee3398ca6e60179ed6e9e39",
         "730695f9a2bfef705cb0288c6c24bdb03987e0b842152fd2df2cdf96bf441588"),
     "olmoe-1b-7b": (
-        "2437f91b266525f66bb74643b01a70d34bf0db40cf40fddc96f955cf243cdd8d",
+        "603f2438b2d6897ed9b14096109fa3f684caa64524fdcb6f1581e077e6dda91a",
         "eed2844a80e3c5a1be6360799c6a496d78b55d20e54cc140d638049bb7fb3c2e"),
 }
 # the same models' `clone(for_test=True)` (no autodiff op, no optimizer op):
