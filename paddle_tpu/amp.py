@@ -75,6 +75,13 @@ QUANTIZABLE_OPS = frozenset({"mul", "matmul"})
 # Numerically sensitive: upcast internally, emit f32, never quantized.
 # batch_norm/softmax live HERE and only here — amp and quant both read
 # this set, so the exclusions cannot drift between the two passes.
+# rms_norm: float32 arithmetic always, and float32 out for every norm a
+# router or a projection may read (a model's stream norms, the latent norms,
+# a closing norm, a user's). The norm an attention layer marked as the LAST
+# op in front of its kernel (ops/nn_ops.py: QK_EMIT_ATTR "kernel") emits the
+# amp dtype instead: the float32 value rounded once, where the kernel's own
+# cast_inputs rounded it before; one marked "float32" feeds a rotary, which
+# does that rounding.
 HIGH_PRECISION_OPS = frozenset({
     "batch_norm", "layer_norm", "softmax", "log_softmax",
     "cross_entropy", "softmax_with_cross_entropy", "mean",

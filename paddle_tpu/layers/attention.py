@@ -23,6 +23,23 @@ __all__ = ["attention_gru_decoder", "attention_gru_beam_search",
            "multi_head_attention", "latent_attention"]
 
 
+def _in_front_of_kernel(helper, emit, var):
+    """Marks the op that just made `var` (a norm or a rotary between a Q or K
+    projection and the attention kernel) with what it emits: "kernel" for the
+    LAST op in front of the kernel, whose only reader rounds it to the
+    kernel's input dtype, so the op emits that; "float32" for a norm whose
+    reader is a rotary. The ops' kernels (ops/nn_ops.py: `QK_EMIT_ATTR`) then
+    keep no float32 array of the projection's shape. Set here, by the layer
+    that knows the reader; a norm or a rotary a user builds has no mark and
+    lowers as it always did."""
+    from ..ops.nn_ops import QK_EMIT_ATTR
+
+    op = helper.block.ops[-1]
+    assert var.name in op.output_names(), (op, var)
+    op.attrs[QK_EMIT_ATTR] = emit
+    return var
+
+
 def multi_head_attention(
     query,
     key=None,
@@ -121,15 +138,18 @@ def multi_head_attention(
         # the norms' scales start at one whatever initialiser the caller
         # gave the projections: only the derived name is taken over
         group = D if qk_norm == "head" else None
-        q = rms_norm(q, epsilon=rms_eps, name=f"{helper.name}.q_norm",
-                     param_attr=_derive(param_attr, "q_norm").name,
-                     group=group)
-        k = rms_norm(k, epsilon=rms_eps, name=f"{helper.name}.k_norm",
-                     param_attr=_derive(param_attr, "k_norm").name,
-                     group=group)
+        emit = "float32" if rotary_theta else "kernel"
+        q = _in_front_of_kernel(helper, emit, rms_norm(
+            q, epsilon=rms_eps, name=f"{helper.name}.q_norm",
+            param_attr=_derive(param_attr, "q_norm").name, group=group))
+        k = _in_front_of_kernel(helper, emit, rms_norm(
+            k, epsilon=rms_eps, name=f"{helper.name}.k_norm",
+            param_attr=_derive(param_attr, "k_norm").name, group=group))
     if rotary_theta:
-        q = rotary_embedding(q, num_heads, rotary_theta)
-        k = rotary_embedding(k, kv_heads, rotary_theta)
+        q = _in_front_of_kernel(helper, "kernel", rotary_embedding(
+            q, num_heads, rotary_theta))
+        k = _in_front_of_kernel(helper, "kernel", rotary_embedding(
+            k, kv_heads, rotary_theta))
     out = helper.create_tmp_variable(query.dtype,
                                      tuple(query.shape[:-1]) + (E_q,))
     attrs = {"num_heads": num_heads, "causal": causal}
@@ -213,11 +233,15 @@ def latent_attention(
 
     q = proj(norm(proj(x, "wq_a", int(q_rank)), "q_norm"), "wq_b",
              num_heads * D)
-    q = rotary_embedding(q, num_heads, rotary_theta, rotary_dim=rope_dim)
+    q = _in_front_of_kernel(helper, "kernel", rotary_embedding(
+        q, num_heads, rotary_theta, rotary_dim=rope_dim))
     c_kv, k_rope = split(proj(x, "wkv_a", int(kv_rank) + int(rope_dim)),
                          [int(kv_rank), int(rope_dim)], dim=2)
     kv = proj(norm(c_kv, "kv_norm"), "wkv_b", num_heads * (int(nope_dim) + D))
-    k_rope = rotary_embedding(k_rope, 1, rotary_theta)
+    # `latent_kv_expand` lays k_rope beside every head in kv's dtype: the
+    # kernel's
+    k_rope = _in_front_of_kernel(helper, "kernel", rotary_embedding(
+        k_rope, 1, rotary_theta))
     packed = tuple(x.shape[:-1]) + (num_heads * D,)    # Q, K, V and the output
     k = helper.create_tmp_variable(kv.dtype, packed)
     v = helper.create_tmp_variable(kv.dtype, packed)

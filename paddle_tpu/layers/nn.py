@@ -515,7 +515,10 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None, group=None):
     """RMSNorm over the last axis with a learned scale (ones at the
     start): x * rsqrt(mean(x^2) + epsilon) * w. Beyond the 2017
     reference's layer set; the norm of the Llama / OLMo families. The
-    output is float32 under amp too (ops/nn_ops.py:rms_norm).
+    output is float32 under amp too (ops/nn_ops.py:rms_norm): a router may
+    read it. The one exception is not a caller's to ask for: the two norms
+    `multi_head_attention` puts on Q and K, which it marks itself as read by
+    nothing but its rotary or its kernel (layers/attention.py).
     group G: the last axis is G-lane groups side by side (the heads of a
     packed projection), each normed on its own over its G lanes, and ONE
     scale [G] serves them all. None: the whole axis, and the op appended is

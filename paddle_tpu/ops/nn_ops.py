@@ -18,6 +18,7 @@ import numpy as np
 from ..core.lod import LoDArray
 from .. import amp
 from ..core.registry import register_op
+from .qk_ops import qk_assemble
 
 
 def _pair(v):
@@ -209,51 +210,106 @@ def rms_norm(x, scale, eps: float):
     layer_norm): what reads a norm is either an MXU op, which casts its own
     inputs down, or the routed FFN's router, which must see the float32
     value: a router fed a bf16-rounded input turns more near-ties the
-    other way than one fed float32 (3.5 % of tokens against 2.0 %)."""
+    other way than one fed float32 (3.5 % of tokens against 2.0 %). That is
+    every norm of a model's stream, the latent norms and a closing norm. The
+    two norms an attention layer puts on Q and K in front of its kernel have
+    no such reader and go through `qk_ops.qk_assemble`."""
     x32 = x.astype(jnp.float32)
     out = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return out if scale is None else out * scale
+
+
+# What an attention layer puts between a Q or K projection and its kernel
+# (`layers/attention.py:_in_front_of_kernel` sets the attribute, never a
+# user): "kernel" on the LAST op in front of the kernel, which emits the dtype
+# the kernel would cast it to, "float32" on a norm whose reader is a rotary.
+# Neither keeps a float32 array of the projection's shape for its backward.
+QK_EMIT_ATTR = "qk_emit"
+_QK_COUNTER = "pt_qk_assemble_dispatch_total"
+_QK_HELP = ("norm and rotary ops traced between a Q or K projection and an "
+            "attention kernel, by what they emit (kernel: the last op in "
+            "front of the kernel, in the kernel's input dtype; float32: a "
+            "norm in front of a rotary)")
+
+
+def _qk_emit(ctx, natural):
+    """(the attribute's value or None, the dtype the op emits): an op marked
+    "kernel" emits what `amp.cast_inputs` at the kernel's door would make of
+    its `natural` output dtype, so that cast becomes a no-op; every other op
+    its natural one. Counts a marked op."""
+    emit = ctx.attr(QK_EMIT_ATTR)
+    if emit is None:
+        return None, natural
+    if emit not in ("kernel", "float32"):
+        raise ValueError(f"{QK_EMIT_ATTR} {emit!r}: 'kernel' or 'float32'")
+    from ..obs import metrics
+
+    metrics.registry().counter_inc(_QK_COUNTER, help=_QK_HELP,
+                                   labels={"op": ctx.op.type, "emit": emit})
+    low = ctx.env.get(amp.AMP_KEY)
+    if emit == "kernel" and low is not None and natural == jnp.float32:
+        return emit, jnp.dtype(low)
+    return emit, natural
 
 
 @register_op("rms_norm")
 def rms_norm_kernel(ctx):
     """Root-mean-square norm over the last axis (Zhang & Sennrich 2019; the
     Llama / OLMo family's norm): x * rsqrt(mean(x^2) + eps) * Scale. No
-    mean subtraction, no bias. Float32 inside and out (see `rms_norm`).
+    mean subtraction, no bias. Float32 inside always, and float32 out (see
+    `rms_norm`) except for a norm an attention layer marked as the last op
+    in front of its kernel (`QK_EMIT_ATTR`), which emits the kernel's input
+    dtype; a marked norm runs `qk_assemble`, the same values.
     Attr `group` G (absent: the whole axis): every run of G lanes is normed
     on its own with the one Scale [G] (a per-head norm on a packed
     projection), under the inner scope `per_head`."""
     x, group = ctx.input("X"), ctx.attr("group")
     eps = ctx.attr("epsilon", 1e-5)
-    if group is None:
-        ctx.set_output("Y", rms_norm(x, ctx.input("Scale"), eps))
-        return
-    with jax.named_scope("per_head"):
-        y = rms_norm(x.reshape(x.shape[:-1] + (-1, int(group))),
-                     ctx.input("Scale"), eps)
+    emit, out_dtype = _qk_emit(ctx, jnp.dtype(jnp.float32))
+    if emit is not None:
+        # [B, T, H, D] rows: a group is a head; the whole axis is all the
+        # 128-lane heads it divides into (or one head) normed as one
+        D = int(group or (128 if x.shape[-1] % 128 == 0 else x.shape[-1]))
+        y = qk_assemble(x.reshape(x.shape[:2] + (-1, D)),
+                        ctx.input("Scale").reshape(-1, D), eps, group is None,
+                        None, None, out_dtype, emit == "kernel")
+    elif group is None:
+        y = rms_norm(x, ctx.input("Scale"), eps)
+    else:
+        with jax.named_scope("per_head"):
+            y = rms_norm(x.reshape(x.shape[:-1] + (-1, int(group))),
+                         ctx.input("Scale"), eps)
     ctx.set_output("Y", y.reshape(x.shape))
 
 
-def rotary(x, theta: float, rotary_dim=None):
+def rotary(x, theta: float, rotary_dim=None, out_dtype=None):
     """Rotary position embedding on [B, T, H, D], rotate-half convention
     (the `transformers` one: the head dim's two HALVES pair up, not its
     even/odd lanes): inv_freq_i = theta^(-2i/D), position t; out = x * cos
-    + rotate_half(x) * sin. Computed in f32, returned in x's dtype.
-    `rotary_dim` R < D: only the LAST R lanes of each head turn (their two
-    halves pair up, inv_freq_i = theta^(-2i/R)); the D - R lanes in front
-    pass through untouched (latent attention's `[q_nope | q_rope]` head)."""
-    T, D = x.shape[1], x.shape[3]
-    R = D if rotary_dim is None else int(rotary_dim)
-    inv_freq = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., D - R: D - R // 2], x32[..., D - R // 2:]
-    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
-    if R < D:
-        parts.insert(0, x32[..., : D - R])
-    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
+    + rotate_half(x) * sin. Computed in f32, returned in x's dtype (or
+    `out_dtype`). `rotary_dim` R < D: only the LAST R lanes of each head turn
+    (their two halves pair up, inv_freq_i = theta^(-2i/R)); the D - R lanes
+    in front pass through untouched (latent attention's `[q_nope | q_rope]`
+    head). The backward is a rule of its own (`qk_assemble`; reverse mode
+    only): the turn by the negated angle of the cotangent, one pass, no
+    residual, returned in x's dtype, the passed-through lanes' cotangent
+    passed through."""
+    R = x.shape[3] if rotary_dim is None else int(rotary_dim)
+    return qk_assemble(x, None, 0.0, False, float(theta), R,
+                       jnp.dtype(x.dtype if out_dtype is None else out_dtype))
+
+
+def _norm_behind(ctx):
+    """The `rms_norm` op whose output this rotary reads, if an attention layer
+    marked it as feeding only a rotary (`QK_EMIT_ATTR` "float32"); else
+    None."""
+    name = ctx.op.inputs["X"][0]
+    for op in ctx.block.ops if ctx.block is not None else ():
+        if name in op.output_names():
+            marked = op.type == "rms_norm" \
+                and op.attrs.get(QK_EMIT_ATTR) == "float32"
+            return op if marked else None
+    return None
 
 
 @register_op("rotary_embedding")
@@ -262,7 +318,12 @@ def rotary_embedding_kernel(ctx):
     projection (as flash_attention's Q/K), num_heads splits E. Sits
     between the Q/K projections and the flash_attention op. Attr
     `rotary_dim` (absent: the whole head): the last that many lanes of
-    each head turn, the rest pass through."""
+    each head turn, the rest pass through. Emits X's dtype, or, marked by an
+    attention layer as the last op in front of its kernel (`QK_EMIT_ATTR`),
+    the kernel's input dtype. Such a rotary behind a norm the layer marked
+    too takes the norm's INPUT and does both (`qk_assemble`: the same
+    values): the norm's float32 output stays bound for whoever else reads
+    it, and is never computed where nobody does."""
     x = ctx.input("X")
     heads = ctx.attr("num_heads")
     rotary_dim = ctx.attr("rotary_dim")
@@ -274,8 +335,18 @@ def rotary_embedding_kernel(ctx):
             0 < rotary_dim <= E // heads and rotary_dim % 2 == 0):
         raise ValueError(f"rotary_dim {rotary_dim} is not an even part of "
                          f"a head of {E // heads}")
-    out = rotary(x.reshape(B, T, heads, E // heads),
-                 float(ctx.attr("theta", 10000.0)), rotary_dim)
+    D, theta = E // heads, float(ctx.attr("theta", 10000.0))
+    emit, out_dtype = _qk_emit(ctx, jnp.dtype(x.dtype))
+    norm = _norm_behind(ctx) if emit == "kernel" and rotary_dim is None \
+        else None
+    if norm is not None and norm.attrs.get("group") in (None, D):
+        out = qk_assemble(
+            ctx.env[norm.inputs["X"][0]].reshape(B, T, heads, D),
+            ctx.env[norm.inputs["Scale"][0]].reshape(-1, D),
+            norm.attrs.get("epsilon", 1e-5), norm.attrs.get("group") is None,
+            theta, D, out_dtype)
+    else:
+        out = rotary(x.reshape(B, T, heads, D), theta, rotary_dim, out_dtype)
     ctx.set_output("Out", out.reshape(B, T, E))
 
 

@@ -184,7 +184,12 @@ def test_every_existing_caller_appends_the_ops_it_did():
         "mul", "mul", "mul", "rms_norm", "rms_norm", "rotary_embedding",
         "rotary_embedding", "flash_attention", "mul"]
     assert ops[7].attrs == {"num_heads": 4, "causal": True}
-    assert ops[3].attrs == {"epsilon": 1e-5}
+    # the layer's own mark on what it builds in front of its kernel (PR 47):
+    # the norms feed a rotary, the rotaries are the last ops in front of it
+    assert ops[3].attrs == {"epsilon": 1e-5, "qk_emit": "float32"}
+    assert [o.attrs.get("qk_emit") for o in ops] == [
+        None, None, None, "float32", "float32", "kernel", "kernel", None,
+        None]
     assert [tuple(p.shape) for p in prog.parameters()][3:5] == [(32,), (32,)]
     prog, _, _ = _attention_program(num_heads=4, num_kv_heads=2, head_dim=16)
     ops = prog.global_block().ops
@@ -199,6 +204,12 @@ def test_every_existing_caller_appends_the_ops_it_did():
         "rotary_embedding", "flash_attention", "mul", "sigmoid",
         "elementwise_mul", "mul"]
     assert ops[7].attrs == {"num_heads": 4, "causal": True, "window": 8}
+    # Trinity's global layer: no rotary, so the norms are the last ops
+    prog, _, _ = _attention_program(num_heads=4, qk_norm="head", out_gate=True)
+    assert [(o.type, o.attrs.get("qk_emit"))
+            for o in prog.global_block().ops][3:6] == [
+        ("rms_norm", "kernel"), ("rms_norm", "kernel"),
+        ("flash_attention", None)]
     for bad in ("per_head", 2, "heads"):
         with pytest.raises(ValueError, match="qk_norm"):
             _attention_program(num_heads=4, qk_norm=bad)

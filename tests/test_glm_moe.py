@@ -132,8 +132,11 @@ def test_latent_attention_appends_its_parts_as_ops_of_their_own():
         "rms_norm", "mul", "rotary_embedding", "latent_kv_expand",
         "flash_attention", "mul"]
     rot_q, rot_k = [o for o in ops if o.type == "rotary_embedding"]
-    assert rot_q.attrs == {"num_heads": 3, "theta": 1e6, "rotary_dim": 4}
-    assert rot_k.attrs == {"num_heads": 1, "theta": 1e6}
+    assert rot_q.attrs == {"num_heads": 3, "theta": 1e6, "rotary_dim": 4,
+                           "qk_emit": "kernel"}
+    assert rot_k.attrs == {"num_heads": 1, "theta": 1e6, "qk_emit": "kernel"}
+    # the latent norms feed projections, not a kernel: no mark
+    assert all("qk_emit" not in o.attrs for o in ops if o.type == "rms_norm")
     expand, = [o for o in ops if o.type == "latent_kv_expand"]
     flash, = [o for o in ops if o.type == "flash_attention"]
     assert flash.inputs["K"] == expand.outputs["K"]

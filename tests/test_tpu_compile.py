@@ -344,14 +344,19 @@ def test_plain_and_differentiated_attention_stay_two_launches(
 # A PR that MEANS to change one of these programs replaces its digests here
 # and says so in PERF.md. PR 46 meant to change the flash kernels' bodies (a
 # crossed block in two strips): the jaxprs' digests are its, the StableHLO
-# digests without the kernels' bodies are the parent's still.
+# digests without the kernels' bodies are the parent's still. PR 47 MEANT to
+# change olmoe's three (its Q and K go from projection to kernel through
+# `ops/qk_ops.py:qk_assemble`, a `custom_vjp` and two Pallas launches each
+# way, where a float32 norm and rotary stood; the inference program holds the
+# forward launch): olmoe's two step digests and its inference digest are PR
+# 47's. gpt2-small has no norm and no rotary on Q or K: its three stand.
 STEP_PROGRAMS = {
     "gpt2-small": (
         "67175aee1ee0d338d3262d309294bef3754189380ee3398ca6e60179ed6e9e39",
         "730695f9a2bfef705cb0288c6c24bdb03987e0b842152fd2df2cdf96bf441588"),
     "olmoe-1b-7b": (
-        "603f2438b2d6897ed9b14096109fa3f684caa64524fdcb6f1581e077e6dda91a",
-        "eed2844a80e3c5a1be6360799c6a496d78b55d20e54cc140d638049bb7fb3c2e"),
+        "62d4d5538466371e865e60712357427b92fcb0cc6e24b1ef6658f5240ffcac0a",
+        "1a621231e022e9207a3e9e0fc5276fa2f7cba9391aca5f43cc59aa485bf88889"),
 }
 # the same models' `clone(for_test=True)` (no autodiff op, no optimizer op):
 # sha256 of the StableHLO text, kernels' bodies left out
@@ -359,14 +364,16 @@ INFERENCE_PROGRAMS = {
     "gpt2-small":
         "b6d9e75d434f2cb52b064a04313fe4d1f325ac2825cd95307a4349a7f2a90fc0",
     "olmoe-1b-7b":
-        "a155e756d34b15cb476bd9ef1b66039d1624c1b9a33dcfbcafdeda8442d2b19b",
+        "3c22c84744aba79bc786baee9683cfd403be5314505e7b7fca3886bcae2e8288",
 }
 # Pallas launches in the traced training step, by kernel: each forward launch
 # once (the parent held it twice: 36 and 15 launches)
 STEP_LAUNCHES = {
     "gpt2-small": {"flash_attention_fwd": 12, "flash_attention_bwd": 12},
     "olmoe-1b-7b": {"flash_attention_fwd": 1, "flash_attention_bwd": 1,
-                    "grouped_matmul": 9},
+                    "grouped_matmul": 9,
+                    # Q's and K's norm and rotary, one launch each way (PR 47)
+                    "qk_assemble_fwd": 2, "qk_assemble_bwd": 2},
 }
 
 
@@ -408,9 +415,10 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
 
     import paddle_tpu as pt
     from paddle_tpu.core import executor as ex
-    from paddle_tpu.ops import flash_ops, moe_ops, ssm_ops
+    from paddle_tpu.ops import flash_ops, moe_ops, qk_ops, ssm_ops
 
     monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(qk_ops, "kernels_eligible", qk_ops._shapes_ok)
     monkeypatch.setattr(
         flash_ops, "flash_eligible", lambda q, k=None, window=0: (
             flash_ops._shapes_flash_ok(q, q if k is None else k, window)
@@ -489,6 +497,60 @@ def test_step_program_holds_each_forward_kernel_launch_once(
                               one_chip, monkeypatch)
     assert dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr)) \
         == STEP_LAUNCHES[name]
+
+
+# `pt_qk_assemble_dispatch_total{op,emit}` of one traced training step of each
+# benchmark configuration (batch 1 x T 1024): the norms and rotaries an
+# attention layer marked in front of its kernel, one increment an op traced.
+# olmoe: one layer, Q and K each a whole-width norm in front of a rotary.
+# trinity: five layers, the four window layers as olmoe's (per head), the
+# global layer's two norms the last ops in front of its kernel. glm: five
+# latent layers, Q's partial rotary and the one-head `k_rope`'s. ouro: eight
+# layers' Q and K rotaries in `Repeat`'s body, which is traced once. gpt2-small
+# and the hybrid have neither op on Q or K.
+QK_ASSEMBLE_COUNTS = {
+    "gpt2-small": {},
+    "nemotron-3-nano-30b-a3b": {},
+    "olmoe-1b-7b": {("rms_norm", "float32"): 2,
+                    ("rotary_embedding", "kernel"): 2},
+    "trinity-mini": {("rms_norm", "float32"): 8, ("rms_norm", "kernel"): 2,
+                     ("rotary_embedding", "kernel"): 8},
+    "glm-4.7-flash": {("rotary_embedding", "kernel"): 10},
+    "ouro-2.6b": {("rotary_embedding", "kernel"): 16},
+}
+
+
+@pytest.mark.parametrize("name", sorted(QK_ASSEMBLE_COUNTS))
+def test_qk_assemble_counts_what_a_layer_marked(one_chip, compiled_mode,
+                                                monkeypatch, name):
+    from paddle_tpu.obs import metrics
+
+    def counts():
+        reg = metrics.registry()
+        return {(op, emit): reg.counter_value(
+            "pt_qk_assemble_dispatch_total", labels={"op": op, "emit": emit})
+            for op in ("rms_norm", "rotary_embedding")
+            for emit in ("kernel", "float32")}
+
+    raw, args = _step_program(lambda: _benchmark_model(name, 1, 1024), 1, 1024,
+                              one_chip, monkeypatch)
+    before = counts()
+    jaxpr = jax.make_jaxpr(raw)(*args)
+    traced = {k: int(n - before[k]) for k, n in counts().items()
+              if n != before[k]}
+    assert traced == QK_ASSEMBLE_COUNTS[name]
+    # one launch forward and one backward for each op that emits to a kernel
+    # on whole lane tiles turned whole (glm's partial turn: XLA's), and none
+    # for a norm the rotary behind it absorbed
+    launches = _launches(jaxpr.jaxpr)
+    last = sum(n for (_, emit), n in traced.items() if emit == "kernel")
+    want = 0 if name == "glm-4.7-flash" else last
+    if name == "ouro-2.6b":     # the body forward, recomputed, and backward
+        assert (launches["qk_assemble_fwd"], launches["qk_assemble_bwd"]) \
+            == (2 * want, want)
+    else:
+        assert (launches.get("qk_assemble_fwd", 0),
+                launches.get("qk_assemble_bwd", 0)) == (want, want)
 
 
 def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
@@ -656,8 +718,12 @@ def test_looped_lm_step_program_fits_one_chip(one_chip, compiled_mode,
                               1, 4096, one_chip, monkeypatch)
     jaxpr = jax.make_jaxpr(raw)(*args)
     launches = dict(_launches(jaxpr.jaxpr))
+    # a layer's Q and K rotaries ride with its attention kernels: a launch
+    # each forward, recomputed and backward (PR 47)
     assert launches == {"flash_attention_fwd": 2 * layers_,
-                        "flash_attention_bwd": layers_}
+                        "flash_attention_bwd": layers_,
+                        "qk_assemble_fwd": 4 * layers_,
+                        "qk_assemble_bwd": 2 * layers_}
     assert str(jaxpr).count(f"length={turns}") >= 2      # the two scans
     compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
     text = compiled.as_text()
@@ -665,7 +731,8 @@ def test_looped_lm_step_program_fits_one_chip(one_chip, compiled_mode,
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
     assert len([c for c in calls if "flash_attention_fwd" in c]) == 2 * layers_
     assert len([c for c in calls if "flash_attention_bwd" in c]) == layers_
-    assert len(calls) < turns * layers_
+    assert len([c for c in calls if "flash_attention" in c]) \
+        < turns * layers_
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 7.3e9          # 12 B a parameter
     print("looped step: arguments %.3f GiB, temporaries %.3f GiB" % (
@@ -673,6 +740,42 @@ def test_looped_lm_step_program_fits_one_chip(one_chip, compiled_mode,
         memory.temp_size_in_bytes / 2**30))
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.0 * 2**30)
+
+
+_AFMOE_STEP = {}
+
+
+def _afmoe_step(one_chip, monkeypatch):
+    """Trinity's step at the size `configs/afmoe.py` trains, traced, lowered
+    and compiled for the described v5e ONCE for the tests below (one worker
+    runs this file): its launches, texts and memory, and the scopes of the
+    norms and rotaries between each layer's `q_proj` and its kernel."""
+    if _AFMOE_STEP:
+        return _AFMOE_STEP
+    import paddle_tpu as pt
+    from paddle_tpu.core.executor import _op_scope
+
+    config = _load_module(os.path.join(ROOT, "configs", "afmoe.py"))
+    raw, args = _step_program(config.get_model, 1, 8192, one_chip,
+                              monkeypatch)
+    ops = pt.default_main_program().global_block().ops
+    made_by = {n: op for op in ops for n in op.output_names()}
+    scopes = []
+    for kernel in (op for op in ops if op.type == "flash_attention"):
+        op = made_by[kernel.inputs["Q"][0]]
+        while op.type in ("rotary_embedding", "rms_norm"):
+            scopes.append(_op_scope(op))
+            op = made_by[op.inputs["X"][0]]
+        assert op.type == "mul"
+    lowered = jax.jit(raw, donate_argnums=(0,)).lower(*args)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    _AFMOE_STEP.update(
+        launches=dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr)),
+        lowered=lowered.as_text(), compiled=compiled.as_text(),
+        arguments=memory.argument_size_in_bytes,
+        temporaries=memory.temp_size_in_bytes, q_scopes=scopes)
+    return _AFMOE_STEP
 
 
 def test_afmoe_step_program_fits_one_chip(one_chip, compiled_mode,
@@ -686,34 +789,50 @@ def test_afmoe_step_program_fits_one_chip(one_chip, compiled_mode,
     attention launches, the four window layers' kernels ANOTHER kernel body
     than the global layer's (the lower diagonal is in it), the grouped-matmul
     kernels in it and no `ragged-dot`."""
-    config = _load_module(os.path.join(ROOT, "configs", "afmoe.py"))
-    raw, args = _step_program(config.get_model, 1, 8192, one_chip,
-                              monkeypatch)
-    launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
-    assert launches["flash_attention_fwd"] == 5
-    assert launches["flash_attention_bwd"] == 5
-    lowered = jax.jit(raw, donate_argnums=(0,)).lower(*args)
+    step = _afmoe_step(one_chip, monkeypatch)
+    assert step["launches"]["flash_attention_fwd"] == 5
+    assert step["launches"]["flash_attention_bwd"] == 5
     # by kernel name, the distinct serialized bodies in the lowered text (a
     # jitted launch is lowered once however many layers call it): the global
     # layer's and the window layers'
     bodies = {}
     for body, name in re.findall(
             r'backend_config = "((?:[^"\\]|\\.)*)"[^\n]*?'
-            r'kernel_name = "(flash_attention_\w+)"', lowered.as_text()):
+            r'kernel_name = "(flash_attention_\w+)"', step["lowered"]):
         bodies.setdefault(name, set()).add(body)
     assert {n: len(b) for n, b in bodies.items()} == {
         "flash_attention_fwd": 2, "flash_attention_bwd": 2}
-    compiled = lowered.compile()
-    text = compiled.as_text()
+    text = step["compiled"]
     assert "flash_attention_bwd" in text and "ragged-dot" not in text
     assert "gmm" in text
-    memory = compiled.memory_analysis()
-    assert memory.argument_size_in_bytes > 8.4e9          # 12 B a parameter
+    assert step["arguments"] > 8.4e9          # 12 B a parameter
     print("afmoe step: arguments %.3f GiB, temporaries %.3f GiB" % (
-        memory.argument_size_in_bytes / 2**30,
-        memory.temp_size_in_bytes / 2**30))
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            < 15.0 * 2**30)
+        step["arguments"] / 2**30, step["temporaries"] / 2**30))
+    assert step["arguments"] + step["temporaries"] < 15.0 * 2**30
+
+
+def test_afmoe_step_writes_no_float32_array_of_qs_size_in_front_of_a_kernel(
+        one_chip, compiled_mode, monkeypatch):
+    """Between a layer's `q_proj` `mul` and its `flash_attention` (the scopes
+    of the norm and the rotary the layer marked, forward and transposed) the
+    compiled step writes nothing of Q's size, 8192 x 4096, in float32: the
+    parent wrote four such arrays a layer forward (the dot's own output, the
+    norm's, the rotary's halves) and as many backward. What is written there
+    is the two kernels' bf16: ten launches of a Q or K each way."""
+    step = _afmoe_step(one_chip, monkeypatch)
+    scopes = step["q_scopes"]
+    assert sorted(s.split(".")[0] for s in scopes) == \
+        ["rms_norm"] * 5 + ["rotary_embedding"] * 4
+    assert (step["launches"]["qk_assemble_fwd"],
+            step["launches"]["qk_assemble_bwd"]) == (10, 10)
+    under = [a for a in _written_arrays(step["compiled"])
+             if a[2] == 8192 * 4096 and not a[3]
+             and any(s + ")" in a[4] or s + "/" in a[4] for s in scopes)]
+    # the reading has teeth: each Q's kernel writes under its last op's scope,
+    # forward and backward, in bf16
+    assert sum(a[:2] == ("custom-call", "bf16") for a in under) == 10
+    assert not [a for a in under if a[1] == "f32"], under
+    assert not [a for a in under if a[0] == "copy"], under
 
 
 def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
@@ -771,8 +890,8 @@ def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
 
 
 def _written_arrays(hlo_text):
-    """(opcode, dtype, elements, in_fusion_body) of every array that an
-    instruction of the optimized HLO produces. What an instruction of a
+    """(opcode, dtype, elements, in_fusion_body, op_name) of every array that
+    an instruction of the optimized HLO produces. What an instruction of a
     fusion's body produces stays in registers; the rest is written out."""
     bodies = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo_text))
     out, body = [], False
@@ -790,10 +909,12 @@ def _written_arrays(hlo_text):
         if opcode.group(1) in ("parameter", "tuple", "get-tuple-element",
                                "bitcast"):
             continue
+        op_name = re.search(r'op_name="([^"]*)"', inst.group(1))
         for dtype, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
                                       inst.group(1)[:opcode.start()]):
             elements = math.prod(int(d) for d in dims.split(",") if d)
-            out.append((opcode.group(1), dtype, elements, body))
+            out.append((opcode.group(1), dtype, elements, body,
+                        op_name.group(1) if op_name else ""))
     return out
 
 
@@ -842,7 +963,7 @@ def test_gpt2_head_writes_no_float32_logits(one_chip, compiled_mode):
                 if a[2] == Bt * Tt * V]
 
     # the reading has teeth: the yardstick does write float32 logits
-    assert any(d == "f32" and not body for _, d, _, body in big(old))
+    assert any(d == "f32" and not body for _, d, _, body, _ in big(old))
     assert not [a for a in big(new) if a[1] == "f32" and not a[3]], big(new)
     assert not [a for a in big(new) if a[0] == "copy"], big(new)
     assert (new.memory_analysis().temp_size_in_bytes
