@@ -131,33 +131,19 @@ Commands:
               of serve processes on other machines)
   tune        --kernel K --shape k=v,k=v [--shape ...]
               [--dtype bf16|f32|int8]
-              [--dry-run] [--cache PATH] [--iters N] [--warmup N]
-              [--search guided|exhaustive] [--budget FRAC] [--mesh dp4]
+              [--dry-run] [--iters N] [--warmup N] [--mesh dp4]
               | --config M.py [--dry-run ...]
-              empirical kernel autotuner (paddle_tpu.tune): search legal
-              configs for a named kernel family over a shape grid (or
-              every tunable site of a model config), write the winners
-              to the persistent per-device table, print a before/after
-              report. --search guided (default) cost-model-ranks the
-              space and times only the top --budget fraction (0.4) with
-              successive-halving early stop; exhaustive is the v1 full
-              sweep. --mesh dp4 keys the --config sweep on PER-SHARD
-              shapes (what the kernels dispatch under a mesh). --dry-run
-              lists candidates without timing (works on any backend;
-              real timing requires TPU).
+              kernel sweep (paddle_tpu.tune): time every legal config of
+              a named kernel family at each shape (or at every tunable
+              site of a model config) against the rule's own choice and
+              print the ranking. Writes nothing: a kernel's tiles are
+              decided by its family's rule in tune/space.py, and what a
+              sweep finds is written there. --mesh dp4 takes the
+              --config sweep's shapes PER SHARD (what the kernels
+              dispatch under a mesh). --dry-run lists candidates without
+              timing (works on any backend; timing requires a TPU).
               Kernels: bahdanau (B,S,A,C), flash (Tq,Tk), lstm/gru
               (B,H), quant (M,K,N — int8).
-  tune export --out FILE [--cache PATH]
-  tune import FILE [FILE...] [--cache PATH]
-  tune merge  --out FILE IN1 [IN2...]
-              fleet-shared tuning database plumbing: export snapshots
-              the local table, import merges colleagues' tables into it,
-              merge aggregates N tables into a new file — conflicts
-              resolve measured-beats-interpolated then newest-wins, and
-              schema-version mismatches are loud errors. Pre-tuned
-              tables shipped under paddle_tpu/tune/tables/ are
-              auto-consulted beneath the local table (README
-              "Autotuning").
   stats       --url http://host:port | --file exposition.txt [--raw 1]
               scrape (or read) a Prometheus /metrics exposition, parse
               it with the paddle_tpu.obs.promparse grammar, and print a
@@ -201,10 +187,7 @@ def _cmd_train(argv) -> int:
         name, eq, val = a.partition("=") if a.startswith("--") else ("", "", "")
         name = name[2:].replace("-", "_")  # same normalization as parse_flags
         if name in train_opts:
-            # both '--config x' and '--config=x' forms; must be consumed
-            # BEFORE parse_flags (save_dir is also a registry flag and
-            # would otherwise be swallowed there, silently disabling the
-            # checkpoint dir)
+            # both '--config x' and '--config=x' forms
             if eq:
                 cfg[name] = val
                 i += 1
@@ -815,104 +798,20 @@ def _fmt_cfg(cfg) -> str:
     return ",".join(f"{k}={v}" for k, v in sorted(cfg.items()))
 
 
-def _cmd_tune_export(argv) -> int:
-    """`tune export --out FILE [--cache PATH]`: snapshot the local
-    table into a shareable file (the fleet exchange format — same
-    schema the runtime reads, so export/import round-trips
-    bit-identically)."""
-    from .tune import cache as tune_cache
-
-    opts = _parse_kv(argv, {"out": str, "cache": str})
-    if "out" not in opts:
-        raise SystemExit("tune export requires --out FILE")
-    src = opts.get("cache") or tune_cache.default_path()
-    table = tune_cache.TunedTable(src)
-    table.save(opts["out"])
-    print(f"exported {len(table)} entries from {src} to {opts['out']} "
-          f"(fingerprint {table.fingerprint()})")
-    return 0
-
-
-def _split_positional(argv, known):
-    """(positional files, option dict) from an argv mixing both —
-    `--k v` / `--k=v` options consumed pairwise, the rest positional."""
-    files, opt_argv, i = [], [], 0
-    while i < len(argv):
-        a = argv[i]
-        if a.startswith("--"):
-            opt_argv.append(a)
-            if "=" not in a and i + 1 < len(argv):
-                opt_argv.append(argv[i + 1])
-                i += 1
-        else:
-            files.append(a)
-        i += 1
-    return files, _parse_kv(opt_argv, known)
-
-
-def _cmd_tune_import(argv) -> int:
-    """`tune import FILE [FILE...] [--cache PATH]`: merge tables from
-    fleet colleagues into the local table. Conflicts resolve
-    measured-beats-interpolated, then newest-wins (cache.merge_entry);
-    a schema-version mismatch is a loud error, not a silent skip."""
-    from .tune import cache as tune_cache
-    from .tune import overrides as tune_overrides
-
-    files, opts = _split_positional(argv, {"cache": str})
-    if not files:
-        raise SystemExit("tune import requires at least one table FILE")
-    dst_path = opts.get("cache") or tune_cache.default_path()
-    dst = tune_cache.TunedTable(dst_path)
-    for f in files:
-        try:
-            src = tune_cache.load_strict(f)
-        except tune_cache.TableFormatError as e:
-            raise SystemExit(str(e)) from None
-        st = dst.merge_from(src)
-        print(f"{f}: +{st['added']} added, {st['replaced']} replaced, "
-              f"{st['kept']} kept (local won)")
-    dst.save(dst_path)
-    tune_overrides.reload_table()  # a live import must be visible
-    print(f"local table {dst_path}: {len(dst)} entries "
-          f"(fingerprint {dst.fingerprint()})")
-    return 0
-
-
-def _cmd_tune_merge(argv) -> int:
-    """`tune merge --out FILE IN1 IN2 ...`: merge N tables into a new
-    file without touching the local table (the fleet-aggregation step:
-    every host exports, one job merges, the result ships as the next
-    base table)."""
-    from .tune import cache as tune_cache
-
-    files, opts = _split_positional(argv, {"out": str})
-    if "out" not in opts or len(files) < 1:
-        raise SystemExit("tune merge requires --out FILE and at least "
-                         "one input table")
-    out = tune_cache.TunedTable(opts["out"], autoload=False)
-    for f in files:
-        try:
-            src = tune_cache.load_strict(f)
-        except tune_cache.TableFormatError as e:
-            raise SystemExit(str(e)) from None
-        st = out.merge_from(src)
-        print(f"{f}: +{st['added']} added, {st['replaced']} replaced, "
-              f"{st['kept']} kept")
-    out.save(opts["out"])
-    print(f"merged {len(files)} tables -> {opts['out']} "
-          f"({len(out)} entries, fingerprint {out.fingerprint()})")
-    return 0
+def _fmt_shape(params) -> str:
+    return _fmt_cfg({k: v for k, v in params.items() if k != "dtype"})
 
 
 def _cmd_tune(argv) -> int:
-    """Empirical kernel autotuner front-end (paddle_tpu.tune)."""
-    from .tune import cache as tune_cache
+    """Kernel sweep front-end (paddle_tpu.tune): prints, writes nothing."""
     from .tune import harness, space
 
-    if argv and argv[0] in ("export", "import", "merge"):
-        return {"export": _cmd_tune_export,
-                "import": _cmd_tune_import,
-                "merge": _cmd_tune_merge}[argv[0]](argv[1:])
+    if argv and not argv[0].startswith("--"):
+        raise SystemExit(
+            f"unknown tune verb {argv[0]!r}: there is no tuned table to "
+            "export, import or merge. `tune --kernel <family> --shape "
+            "k=v,... [--dry-run]` or `tune --config <model.py>` sweeps "
+            "and prints a ranking")
 
     dry = False
     rest = []
@@ -921,15 +820,9 @@ def _cmd_tune(argv) -> int:
             dry = True
         else:
             rest.append(a)
-    known = {"kernel": str, "shape": list, "dtype": str, "cache": str,
-             "iters": str, "warmup": str, "config": str, "search": str,
-             "budget": str, "mesh": str}
+    known = {"kernel": str, "shape": list, "dtype": str, "iters": str,
+             "warmup": str, "config": str, "mesh": str}
     opts = _parse_kv(rest, known)
-    mode = opts.get("search", "guided")
-    if mode not in ("guided", "exhaustive"):
-        raise SystemExit(f"--search must be guided or exhaustive, got "
-                         f"{mode!r}")
-    budget = float(opts.get("budget", 0.4))
     dp = 1
     if "mesh" in opts:
         from .parallel.mesh import parse_mesh_spec
@@ -990,8 +883,8 @@ def _cmd_tune(argv) -> int:
             except (ValueError, KeyError) as e:
                 print(f"{c['family']}: {e}")
                 continue
-            sig = tune_cache.make_sig(info["params"])
-            print(f"kernel {info['kernel']}  {sig}  dtype={c['dtype']}")
+            print(f"kernel {info['kernel']}  {_fmt_shape(info['params'])}"
+                  f"  dtype={c['dtype']}")
             print(f"  analytic default: {_fmt_cfg(info['default'])}")
             print(f"  {len(info['candidates'])} legal candidates:")
             for cfg in info["candidates"]:
@@ -1004,44 +897,28 @@ def _cmd_tune(argv) -> int:
         harness.ensure_timeable()
     except harness.TuningUnavailable as e:
         raise SystemExit(str(e)) from None
-    path = opts.get("cache") or tune_cache.default_path()
-    table = tune_cache.TunedTable(path)  # merge into any existing table
     iters = int(opts.get("iters", 7))
     warmup = int(opts.get("warmup", 2))
     for c in cases:
         try:
             rep = harness.tune_case(c["family"], c["params"], c["dtype"],
-                                    table=table, iters=iters,
-                                    warmup=warmup, mode=mode,
-                                    budget_fraction=budget)
+                                    iters=iters, warmup=warmup)
         except (NotImplementedError, ValueError) as e:
             print(f"{c['family']}: skipped — {e}")
             continue
-        sig = tune_cache.make_sig(rep["params"])
-        print(f"kernel {rep['kernel']}  {sig}  dtype={c['dtype']}  "
-              f"device={rep['device_kind']}")
+        print(f"kernel {rep['kernel']}  {_fmt_shape(rep['params'])}  "
+              f"dtype={c['dtype']}  device={rep['device_kind']}")
         for r in rep["rows"]:
-            if not r.get("timed", True):
-                t = "   (pruned by cost model)"
-            elif not r["numerics_ok"]:
+            if not r["numerics_ok"]:
                 t = "   FAILED numerics"
             else:
                 t = f"{r['median_s'] * 1e3:10.3f} ms"
             marks = ("   (default)" if r["is_default"] else "") + \
                     ("   <- best" if r["config"] == rep["best"] else "")
             print(f"    {_fmt_cfg(r['config']):<28}{t}{marks}")
-        s = rep.get("search", {})
-        if s.get("mode") == "guided":
-            print(f"  guided search timed {s['timed']}/{s['candidates']} "
-                  f"candidates ({s['timed_fraction']:.0%})"
-                  + (" — stopped early (leader stable)"
-                     if s.get("stopped_early") else ""))
         if "speedup_vs_default" in rep:
             print(f"  best {_fmt_cfg(rep['best'])}: "
                   f"{rep['speedup_vs_default']:.3f}x vs analytic default")
-    table.save(path)
-    print(f"tuned table written to {path} "
-          f"({len(table)} entries, fingerprint {table.fingerprint()})")
     return 0
 
 
@@ -1171,20 +1048,6 @@ def _cmd_stats(argv) -> int:
             for sname, labels, v in f.samples:
                 lb = ",".join(f"{k}={x}" for k, x in sorted(labels.items()))
                 print(f"    {sname}{{{lb}}} {v:.6g}")
-    if "pt_tune_consults_total" in families:
-        # tuned-coverage one-liner (the autotuner's provenance counters):
-        # of the consults the table COULD have answered (forced/env are
-        # operator overrides, not coverage), how many did it?
-        src = {lb.get("source"): v for _, lb, v in
-               families["pt_tune_consults_total"].samples}
-        covered = src.get("table", 0) + src.get("interpolated", 0)
-        total = covered + src.get("analytic", 0)
-        if total:
-            print(f"tuned coverage: {covered / total:.0%} of "
-                  f"{int(total)} kernel consults "
-                  f"({int(src.get('table', 0))} exact, "
-                  f"{int(src.get('interpolated', 0))} interpolated, "
-                  f"{int(src.get('analytic', 0))} analytic)")
     print(f"{len(families)} families parsed OK")
     return 0
 

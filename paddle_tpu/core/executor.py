@@ -32,6 +32,7 @@ import numpy as np
 from . import provenance, registry
 from .. import profiler
 from ..flags import FLAGS
+from ..tune import overrides as tune_overrides
 from .lod import LoDArray
 from .place import Place, default_place
 from .program import Program, Variable, default_main_program, grad_var_name
@@ -70,13 +71,6 @@ def memory_optimize(program=None, policy: str = "dots") -> None:
             f"{sorted(_REMAT_POLICIES)}"
         )
     program.remat_policy = policy
-
-
-def _tune_fingerprint() -> str:
-    """Lazy import: tune loads after core during package init."""
-    from ..tune import overrides as tune_overrides
-
-    return tune_overrides.fingerprint()
 
 
 def _check_finite(values: Dict[str, Any]) -> None:
@@ -480,14 +474,9 @@ class Executor:
             FLAGS.use_fused_attention,
             FLAGS.fused_attention_interpret,
             FLAGS.use_fused_conv,
-            # every trace-affecting kernel-config source (forced
-            # overrides, legacy env knobs like PT_ATTN_BBLK, the loaded
-            # tuned table) collapses into one fingerprint: a tuning
-            # sweep flipping ANY knob on a live Executor re-traces
-            # instead of silently reusing the stale tile choice, and
-            # future knobs invalidate the cache without touching this
-            # file (tune/overrides.py)
-            _tune_fingerprint(),
+            # the kernel configs a sweep or a test has forced, () when
+            # none: a change re-traces instead of reusing the old tile
+            tune_overrides.forced_key(),
         )
 
     def _compile(self, program: Program, feed, fetch_names, persist_names):

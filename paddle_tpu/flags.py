@@ -1,7 +1,7 @@
 """Global flag registry (gflags parity).
 
 Reference: paddle/utils/Flags.cpp:18-110 defines ~40 gflags consumed across
-the runtime (use_gpu, trainer_count, beam_size, check_nan_inf behavior via
+the runtime (use_gpu, trainer_count, check_nan_inf behavior via
 FLAGS_check_nan_inf in fluid executor.cc:60-72, log_period, ...). Here:
 a typed registry with env-var overrides (`PT_FLAGS_<NAME>`) and an argv
 parser, read through the `FLAGS` namespace object.
@@ -164,11 +164,6 @@ define_flag("prefetch_to_device", 2,
 define_flag("show_param_stats_period", 0,
             "trainer: dump per-parameter value/gradient stats every N "
             "batches (reference: TrainerInternal.cpp:81-109); 0 = off")
-define_flag("beam_size", 7, "default beam width for beam-search decode")
-define_flag("save_dir", "./output",
-            "conventional checkpoint directory; checkpointing itself is "
-            "enabled per-run (CLI: train --save_dir; API: "
-            "Trainer(checkpoint_config=...))")
 define_flag("stats_period", 0,
             "trainer: emit a one-line runtime-stats log (step, "
             "dispatches, syncs, checkpoint commits, guard skips, trace "
@@ -208,18 +203,3 @@ define_flag("use_fused_attention", True,
 define_flag("fused_attention_interpret", False,
             "testing only: allow the fused attention decoder kernels in "
             "pallas interpret mode on non-TPU backends")
-define_flag("use_tuned_table", True,
-            "consult the persistent tuned-config table (paddle_tpu.tune, "
-            "`paddle_tpu tune`) for kernel tile/block choices before the "
-            "analytic defaults. Lookups are keyed by device_kind, so a "
-            "machine without tuned entries (or any non-TPU backend) "
-            "deterministically falls back to the analytic models; set 0 "
-            "to ignore tables entirely (A/B escape hatch)")
-define_flag("tune_interpolate", True,
-            "on a tuned-table miss, fall through to the nearest tuned "
-            "entry for the same kernel/dtype/device by log-space shape "
-            "distance (Autotuner v2 shape interpolation), re-validated "
-            "against the target shape's legality model before use; the "
-            "consult is recorded as source=interpolated in "
-            "pt_tune_consults_total. Set 0 to restrict lookups to exact "
-            "shape signatures (A/B escape hatch)")

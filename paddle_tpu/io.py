@@ -384,17 +384,6 @@ def save_inference_model(
                              "shape": [int(d) for d in v.shape]}
         except KeyError:
             pass
-    # tuned-kernel provenance travels with the artifact: which device
-    # the exporter's tuned table was measured for and its content hash,
-    # so serving.engine warmup can detect a stale/missing table on the
-    # serving host and warn instead of silently running untuned
-    from .tune import cache as _tune_cache
-    from .tune import overrides as _tune_overrides
-
-    tuning = {
-        "device_kind": _tune_cache.device_kind(),
-        "table_fingerprint": _tune_overrides.table().fingerprint(),
-    }
     # generation-state specs travel with the artifact: beam geometry +
     # decode-state dtypes/shapes, so the serving scheduler can allocate
     # its device-resident slot pool (and pre-compile the pool step at
@@ -429,7 +418,6 @@ def save_inference_model(
                 # every standby actually loaded the new version before
                 # the router flips (fleetctl/rollout.py)
                 "program_fingerprint": program_fingerprint(pruned),
-                "tuning": tuning,
                 **({"generation": generation} if generation else {}),
                 **({"sharding": sharding} if sharding else {}),
                 **({"quant": quant} if quant else {}),
@@ -548,10 +536,6 @@ def load_inference_model(dirname: str, scope: Optional[Scope] = None):
     # program fingerprint; ServingEngine recomputes it when missing so
     # /healthz "versions" is populated for every artifact age
     program._program_fingerprint = meta.get("program_fingerprint") or None
-    # tuned-kernel provenance (absent in pre-tuner artifacts): the
-    # exporter's device_kind + tuned-table fingerprint, checked by
-    # serving.ServingEngine.warmup against the serving host's table
-    program._tuning_meta = meta.get("tuning") or None
     # generation sidecar (absent for feed-forward models / pre-gen
     # artifacts): beam geometry + decode-state specs, consumed by
     # serving.scheduler.ContinuousScheduler warmup

@@ -370,26 +370,6 @@ def _trace_families():
     ]
 
 
-def _tune_families():
-    """Tuned-coverage of the live process: per-source consult counts
-    from the autotuner's one lookup point (tune/overrides.py). Every
-    source label renders from the first scrape (0 included), so
-    `paddle_tpu stats` on a fresh process already shows the full
-    forced/env/table/interpolated/analytic surface — the ratio of
-    table+interpolated to analytic IS the tuned-coverage number."""
-    import sys
-
-    overrides = sys.modules.get("paddle_tpu.tune.overrides")
-    if overrides is None:
-        return []
-    st = overrides.consult_stats()
-    return [
-        ("pt_tune_consults_total", "counter",
-         "tuned-config consults by provenance (tune/overrides.lookup)",
-         [({"source": s}, float(v)) for s, v in sorted(st.items())]),
-    ]
-
-
 def _quant_families():
     """The int8 serving fast path's footprint (paddle_tpu.quant): how
     many matmul sites run quantized, the weight bytes that stopped
@@ -505,7 +485,6 @@ def _statset_families():
 
 _REGISTRY.add_collector(_faults_families)
 _REGISTRY.add_collector(_trace_families)
-_REGISTRY.add_collector(_tune_families)
 _REGISTRY.add_collector(_quant_families)
 _REGISTRY.add_collector(_executor_families)
 _REGISTRY.add_collector(_provenance_families)

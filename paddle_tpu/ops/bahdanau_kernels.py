@@ -60,54 +60,17 @@ def reset_dispatch_stats():
 def _bblk(B: int, Sp: int, A: int, C: int, itemsize: int) -> int:
     """Batch tile shared by ALL the attention kernels (fwd, bwd-step,
     phase-2 use one eligibility so a config never runs fused forward and
-    then fails to tile the backward). Legality (divisibility + the
-    family-wide VMEM working-set model) lives in tune/space.py
-    `bahdanau_blk_legal` — ONE model shared with the autotuner's
-    candidate generator, so the tuner can never emit a tile this
-    dispatch would reject.
-
-    Consult order (tune/overrides.py): forced override (programmatic
-    force(), or the legacy PT_ATTN_BBLK env knob — still honored) ->
-    tuned table entry for this (shape, dtype, device) -> the analytic
-    default below. A FORCED tile that fails legality warns and disables
-    the fused path (the operator pinned it for a sweep; silently
-    substituting would invalidate the sweep); a stale TABLE entry that
-    fails legality is ignored and the analytic default applies.
-
-    Analytic default: 8 measured best on v5e at the NMT shapes (256k
-    tok/s vs 217k at 16/32, bs256 sweep — larger tiles triple the f32
-    temporaries and spill); 4 and 2 are fallback candidates for SMALL
-    batches only (a sub-8 tile is a legal Mosaic block shape only when
-    it spans the whole batch dim — the last-two-dims (8k, 128k)-or-full
-    rule; B=4 and B=2 verified lowering and matching on v5e hardware,
-    round 5)."""
-    from ..tune import overrides as tune_overrides
-    from ..tune.cache import ITEMSIZE_DTYPE
-    from ..tune.space import bahdanau_blk_legal
+    then fails to tile the backward), 0 where the fused decoder is off:
+    tune/space.py's rule for the family (`bahdanau_default`, legality
+    `bahdanau_blk_legal`), or what a sweep forced."""
+    from ..tune import space
 
     if B <= 0:  # mesh-local batch that the dp axis does not divide
         return 0
-    ov = tune_overrides.lookup(
-        "bahdanau_attention", {"B": B, "Sp": Sp, "A": A, "C": C},
-        ITEMSIZE_DTYPE.get(itemsize, f"itemsize{itemsize}"))
-    if ov is not None:
-        b = int(ov.config.get("bblk", 0))
-        if b and bahdanau_blk_legal(b, B, Sp, A, C, itemsize):
-            return b
-        if ov.source in ("forced", "env"):
-            import warnings
-
-            warnings.warn(
-                f"forced attention tile bblk={b} ({ov.source}) fails "
-                f"eligibility at B={B} Sp={Sp} A={A} C={C} "
-                f"(divisibility or VMEM); fused attention decoder "
-                f"DISABLED for this shape", stacklevel=2)
-            return 0
-        # stale table entry (tuned on other geometry/version): ignore
-    for b in (8, 4, 2):
-        if bahdanau_blk_legal(b, B, Sp, A, C, itemsize):
-            return b
-    return 0
+    cfg = space.pick("bahdanau_attention",
+                     {"B": B, "Sp": Sp, "A": A, "C": C},
+                     space.ITEMSIZE_DTYPE[itemsize])
+    return int(cfg["bblk"]) if cfg else 0
 
 
 def _interpret() -> bool:
@@ -121,7 +84,7 @@ def _backend_ok() -> bool:
 
 
 def _pad_s(s: int) -> int:
-    from ..tune.space import pad_s  # one padding rule, shared with tuner
+    from ..tune.space import pad_s  # one padding rule, shared with the sweep
 
     return pad_s(s)
 

@@ -146,46 +146,20 @@ class FlashBlocks(NamedTuple):
 
 
 def _v5e_block_sizes(Tq: int, Tk: int, dtype=None) -> FlashBlocks:
-    """Block choice for the TPU kernels. Consult order (tune/overrides):
-    forced/tuned {block_q, block_k} for this (Tq, Tk, dtype, device) —
-    validated against the shared legality predicate
-    (tune/space.flash_block_legal: blocks must DIVIDE the 128-aligned
-    sequence) — else the analytic default: 512-row q/k blocks up to
-    T=4096, 1024 from 8192, rounded down to the largest 128-multiple
-    divisor (e.g. T=1280 → 256)."""
+    """Block choice for the TPU kernels: tune/space.py's rule for the
+    flash family (`flash_default`), or what a sweep forced."""
     import numpy as np
 
-    from ..tune import overrides as tune_overrides
-    from ..tune.space import flash_block_legal
+    from ..tune import space
 
-    def blk(T):
-        if T % 128:
-            # _flash_kernel is gate-free (benchmarks call it directly);
-            # without this check b would decrement to 0 and `T % 0` raise
-            raise ValueError(
-                f"flash kernel requires a 128-aligned sequence, got T={T}"
-            )
-        b = min(T, 512 if T < 8192 else 1024)
-        while T % b:
-            b -= 128
-        return b
-
-    ov = tune_overrides.lookup(
+    cfg = space.pick(
         "flash_attention", {"Tq": Tq, "Tk": Tk},
         np.dtype(dtype).name if dtype is not None else "bfloat16")
-    if ov is not None:
-        oq = int(ov.config.get("block_q", 0))
-        ok = int(ov.config.get("block_k", 0))
-        if flash_block_legal(oq, ok, Tq, Tk):
-            return FlashBlocks(oq, ok)
-        if ov.source in ("forced", "env"):
-            import warnings
-
-            warnings.warn(
-                f"forced flash blocks q={oq} k={ok} do not divide "
-                f"Tq={Tq} Tk={Tk}; using the analytic default",
-                stacklevel=2)
-    return FlashBlocks(blk(Tq), blk(Tk))
+    if cfg is None:
+        # _flash_kernel is gate-free (benchmarks call it directly)
+        raise ValueError("flash kernel requires 128-aligned sequences, "
+                         f"got Tq={Tq} Tk={Tk}")
+    return FlashBlocks(int(cfg["block_q"]), int(cfg["block_k"]))
 
 
 # ------------------------------------------------------------------ kernels
@@ -613,7 +587,7 @@ def _params(*semantics):
 # jitted, as every kernel launch below: a model's layers share shapes, so the
 # kernel is traced and lowered once a program and not once a layer (36 traces
 # of gpt2-small's step cost its set-up 17 s: PERF.md section 6, PR 28). What
-# is read when the op is traced (the block sizes, a tuner's forced ones among
+# is read when the op is traced (the block sizes, a sweep's forced ones among
 # them) comes in as a static argument, so a cached trace never hides it.
 @functools.partial(jax.jit,
                    static_argnames=("heads", "causal", "blocks", "statistics",
@@ -765,7 +739,7 @@ _packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)
 
 def _flash_kernel(q, k, v, causal: bool, window: int = 0):
     """Direct fused-kernel call over [B, T, H, D], no dispatch gate
-    (benchmarks, the tuner and the eligible path all come through here):
+    (benchmarks, the sweep tool and the eligible path all come through here):
     merging H and D is a free reshape to the packed layout."""
     B, Tq, H, D = q.shape
     pack = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # noqa: E731

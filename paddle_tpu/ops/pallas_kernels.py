@@ -94,75 +94,34 @@ def _bwd_vmem_bytes(B: int, H: int, G: int, itemsize: int,
     return weight_block + io_blocks + carries + dw_acc
 
 
-def _tuned_fused(kind: str, B: int, H: int, itemsize: int):
-    """Tuned/forced fused-vs-scan decision from the override registry
-    (None = no entry -> the measured-window analytic default applies).
-    The fused-RNN kernels have no free tile parameter — their empirical
-    knob is the dispatch itself, so the tuner records {"fused": bool}
-    per (B, H, dtype, device)."""
-    from ..tune import overrides as tune_overrides
-    from ..tune.cache import ITEMSIZE_DTYPE
+def _tuned_fused(kind: str, B: int, H: int, itemsize: int) -> bool:
+    """The fused-vs-scan choice at (B, H, dtype): tune/space.py's rule
+    for the family (tile alignment, the VMEM model above and the
+    measured H window: `_rnn_default`), or what a sweep forced. The
+    fused-RNN kernels have no free tile parameter: their knob is the
+    dispatch itself."""
+    from ..tune import space
 
-    ov = tune_overrides.lookup(
-        f"fused_{kind}", {"B": B, "H": H},
-        ITEMSIZE_DTYPE.get(itemsize, f"itemsize{itemsize}"))
-    if ov is None or "fused" not in ov.config:
-        return None
-    return bool(ov.config["fused"])
+    return bool(space.pick(f"fused_{kind}", {"B": B, "H": H},
+                           space.ITEMSIZE_DTYPE[itemsize])["fused"])
 
 
 def lstm_supported(B: int, H: int, gate_act, cell_act, cand_act, peep,
                    itemsize: int = 2) -> bool:
-    # hard legality first (tile alignment, gate forms, VMEM model) —
-    # no override can force an illegal config through
-    if not (
-        peep is None
-        and gate_act == "sigmoid"
-        and cell_act == "tanh"
-        and cand_act == "tanh"
-        and B >= 8 and B % 8 == 0
-        and H % 128 == 0
-        and _bwd_vmem_bytes(B, H, 4, itemsize,
-                            _LSTM_FUSED_DW_MAX_H) <= _VMEM_BUDGET
-        and _backend_ok()
-    ):
-        return False
-    tuned = _tuned_fused("lstm", B, H, itemsize)
-    if tuned is not None:
-        return tuned
-    # measured window (rnn_kernel_microbench, round 3: old link, rounds
-    # <= 5, not re-measured on this chip; record in git history; with
-    # the outer-einsum dW past H=640): 1.02x at H=512, 1.45x at
-    # 768, 1.60x at 1024, 1.13x at 1280 — the reference's largest
-    # published config (benchmark/README.md:129-136) now eligible at
-    # bf16; H=256 still loses (0.86x, r2 data): the per-step matmul
-    # is too small to amortize the kernel's fixed work
-    return 384 <= H <= 1280
+    return (peep is None
+            and gate_act == "sigmoid"
+            and cell_act == "tanh"
+            and cand_act == "tanh"
+            and _backend_ok()
+            and _tuned_fused("lstm", B, H, itemsize))
 
 
 def gru_supported(B: int, H: int, gate_act, cand_act,
                   itemsize: int = 2) -> bool:
-    if not (
-        gate_act == "sigmoid"
-        and cand_act == "tanh"
-        and B >= 8 and B % 8 == 0
-        and H % 128 == 0
-        and _bwd_vmem_bytes(B, H, 3, itemsize,
-                            _GRU_FUSED_DW_MAX_H) <= _VMEM_BUDGET
-        and _backend_ok()
-    ):
-        return False
-    tuned = _tuned_fused("gru", B, H, itemsize)
-    if tuned is not None:
-        return tuned
-    # measured window (rnn_kernel_microbench, round 3: old link, rounds
-    # <= 5, not re-measured on this chip; record in git history; with
-    # the hand-written reverse-time backward kernel replacing the
-    # scan-replay VJP): 1.18x at H=128, 1.06x at 256, 1.72x at 512
-    # (the NMT config), 1.70x at 640, 1.24x at 768, 1.61x at 1024,
-    # 1.88x at 1280. H=384 alone dips to 0.86x (3H=1152 tiles badly
-    # against the 512-lane MXU pass) and stays on the scan
-    return 128 <= H <= 1280 and H != 384
+    return (gate_act == "sigmoid"
+            and cand_act == "tanh"
+            and _backend_ok()
+            and _tuned_fused("gru", B, H, itemsize))
 
 
 # ------------------------------------------------------------------ LSTM ---

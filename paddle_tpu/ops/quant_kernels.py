@@ -12,16 +12,15 @@ Two lowerings, one legality model:
 - `_quant_matmul_pallas`: the TPU Pallas kernel — (block_m, block_n)
   output tiles over a full-K panel, int8 io tiles, int32 accumulator,
   per-column f32 scale epilogue. Tile legality (int8's (32, 128)
-  minimum tile, divide-the-array, VMEM working set) lives in
-  tune/space.py `quant_matmul_*` — shared with the autotuner, so tuned
-  int8 is just another autotuner column next to tuned bf16;
+  minimum tile, divide-the-array, VMEM working set) and the default
+  tile live in tune/space.py `quant_matmul_*`;
 - `_quant_matmul_ref`: the jnp reference (CPU/correctness) — an exact
   int32 contraction via dot_general, bit-identical math to the tile
   kernel since integer adds are associative (no float reorder hazard).
 
-The dispatch consults tune/overrides.lookup exactly like the other
-fused kernels (one consult point, provenance counted), and is a HOT
-PATH under the zero-cost lint (tests/test_quant.py): no per-call scale
+The dispatch asks tune/space.pick for its tile like the other fused
+kernels, and is a HOT PATH under the zero-cost lint
+(tests/test_quant.py): no per-call scale
 recomputation, no host syncs — scales arrive as traced arrays/attrs
 computed once at convert time (quant/convert.py).
 """
@@ -81,22 +80,16 @@ def _quant_matmul_pallas(xq, wq, block_m: int, block_n: int):
 
 
 def quant_matmul(xq, wq):
-    """int8 [M, K] × int8 [K, N] → int32 [M, N], tuned-tile dispatch.
-
-    One overrides.lookup consult per TRACE (the jit cache makes it
-    per-shape, not per-call); an illegal/absent config falls back to
-    the analytic default, and a shape outside the family's eligibility
-    entirely falls back to the reference contraction (XLA handles it)."""
-    from ..tune import overrides, space
+    """int8 [M, K] × int8 [K, N] → int32 [M, N] at the tile
+    tune/space.py picks for the shape (one pick per TRACE: the jit
+    cache makes it per-shape, not per-call); a shape with no legal tile
+    falls back to the reference contraction (XLA handles it)."""
+    from ..tune import space
 
     M, K = xq.shape
     _, N = wq.shape
-    params = {"M": int(M), "K": int(K), "N": int(N)}
-    ov = overrides.lookup("quant_matmul", params, "int8")
-    cfg = ov.config if ov is not None else None
-    if cfg is None:
-        cfg = space.quant_matmul_default(
-            dict(params, dtype="int8"))
+    cfg = space.pick("quant_matmul",
+                     {"M": int(M), "K": int(K), "N": int(N)}, "int8")
     if cfg is None:
         return _quant_matmul_ref(xq, wq)
     return _quant_matmul_pallas(xq, wq, int(cfg["block_m"]),

@@ -3,8 +3,8 @@
 Reference lineage: the Go master fences a misbehaving trainer by
 re-dispatching its tasks elsewhere; a serving stack has no "elsewhere"
 per process, so the standard containment is the circuit breaker: a
-model whose engine keeps throwing (bad artifact, OOMing bucket, a
-poisoned tuned table) must fail FAST with 503 instead of letting every
+model whose engine keeps throwing (bad artifact, OOMing bucket)
+must fail FAST with 503 instead of letting every
 request ride the queue into a guaranteed error — queue time spent on a
 doomed call is latency stolen from healthy models on the same host.
 

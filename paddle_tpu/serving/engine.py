@@ -54,11 +54,6 @@ from .metrics import MetricSet
 
 __all__ = ["BucketPolicy", "ServingEngine"]
 
-# stale-table warning / coverage naming renders every family the engine
-# can dispatch, INCLUDING the quantized one — short dtype aliases for
-# the `paddle_tpu tune` command it prints
-_DTYPE_SHORT = {"bfloat16": "bf16", "float32": "f32", "int8": "int8"}
-
 
 def _pow2_buckets(max_batch_size: int) -> Tuple[int, ...]:
     out, b = [], 1
@@ -242,9 +237,6 @@ class ServingEngine:
                 except KeyError:
                     spec = {"dtype": "float32", "shape": []}
             self.feed_specs[n] = spec
-        # tuned-kernel provenance from meta.json (io.save_inference_model
-        # since the tuner PR): exporter device_kind + table fingerprint
-        self.tuning_meta = getattr(self.program, "_tuning_meta", None)
         # generation sidecar (io.save_inference_model since the
         # continuous-batching PR): beam geometry + decode-state specs so
         # the scheduler can allocate its slot pool without re-tracing
@@ -467,108 +459,6 @@ class ServingEngine:
         return self.scheduler().generate(feed, timeout_ms=timeout_ms)
 
     # ------------------------------------------------------------------
-    def tune_coverage(self) -> List[Dict[str, Any]]:
-        """Per-site tuned-coverage of everything THIS engine can
-        dispatch: the decode-step sites over the live bucket grid plus
-        any concrete-shape sites of the program, each classified the
-        way overrides.lookup would resolve it — "table" (exact local or
-        shipped-base entry), "interpolated" (+ the donor signature), or
-        "analytic" (untuned). Classification does not touch the
-        pt_tune_consults_total counters (overrides.classify)."""
-        from ..tune import cache as tune_cache
-        from ..tune import overrides as tune_overrides
-        from ..tune import space as tune_space
-
-        sites = list(self.decode_tune_cases())
-        try:
-            sites += tune_space.cases_from_program(self.program,
-                                                   dp=self._mesh_dp())
-        except (ValueError, KeyError):
-            pass
-        out, seen = [], set()
-        for c in sites:
-            try:
-                fam = tune_space.get_family(c["family"])
-                norm = fam.normalize(c["params"], c["dtype"])
-            except (KeyError, ValueError):
-                continue
-            key = (fam.name, tune_cache.make_sig(norm), c["dtype"])
-            if key in seen:
-                continue
-            seen.add(key)
-            source, origin = tune_overrides.classify(fam.name, norm,
-                                                     c["dtype"])
-            out.append({"family": fam.name, "sig": key[1],
-                        "dtype": c["dtype"], "source": source,
-                        **({"origin": origin} if origin else {})})
-        return out
-
-    def _coverage_detail(self) -> str:
-        """The actionable tail of the stale-table warning: WHICH
-        kernels/shapes will run untuned (analytic) vs interpolated, and
-        the exact `paddle_tpu tune` command that fixes it."""
-        cov = self.tune_coverage()
-        untuned = [c for c in cov if c["source"] == "analytic"]
-        interp = [c for c in cov if c["source"] == "interpolated"]
-        if not untuned and not interp:
-            return ""
-        lines = []
-        if untuned:
-            lines.append(
-                "untuned (analytic defaults): " + "; ".join(
-                    f"{c['family']}[{c['sig']} {c['dtype']}]"
-                    for c in untuned[:8])
-                + (f" (+{len(untuned) - 8} more)"
-                   if len(untuned) > 8 else ""))
-        if interp:
-            lines.append(
-                "interpolated from nearby shapes: " + "; ".join(
-                    f"{c['family']}[{c['sig']} <- {c.get('origin', '?')}]"
-                    for c in interp[:8])
-                + (f" (+{len(interp) - 8} more)"
-                   if len(interp) > 8 else ""))
-        lines.append(
-            "to tune them on this host: `paddle_tpu tune --config "
-            "<model.py>` for the training shapes, or per shape e.g. "
-            + "; ".join(
-                f"`paddle_tpu tune --kernel {c['family']} --shape "
-                f"{c['sig']} --dtype "
-                f"{_DTYPE_SHORT.get(c['dtype'], c['dtype'])}`"
-                for c in (untuned or interp)[:2]))
-        return "\n  " + "\n  ".join(lines)
-
-    def check_tuned_table(self) -> bool:
-        """Compare the model's recorded tuning provenance (exporter
-        device_kind + tuned-table fingerprint, meta.json) against this
-        process's table. A mismatch means the kernels the exporter
-        measured are NOT what this host will dispatch — warn loudly
-        (warmup calls this) instead of silently serving untuned/stale
-        configs, and NAME the affected kernels/shapes (untuned vs
-        interpolated) with the tune command that would fix them.
-        Returns True when provenance matches or the artifact predates
-        the tuner."""
-        if not self.tuning_meta:
-            return True  # pre-tuner artifact: nothing recorded
-        from ..tune import cache as tune_cache
-        from ..tune import overrides as tune_overrides
-
-        saved_kind = self.tuning_meta.get("device_kind")
-        saved_fp = self.tuning_meta.get("table_fingerprint")
-        cur_kind = tune_cache.device_kind()
-        cur_fp = tune_overrides.table().fingerprint()
-        if saved_kind == cur_kind and saved_fp == cur_fp:
-            return True
-        import warnings
-
-        warnings.warn(
-            f"model {self.model_name!r} was exported with tuned-kernel "
-            f"table {saved_fp} on device {saved_kind!r}; this process "
-            f"has table {cur_fp} on {cur_kind!r} — serving may run "
-            "untuned or stale kernel configs (re-run `paddle_tpu tune` "
-            "on this host and re-export, or ship the exporter's table "
-            "via PT_TUNE_CACHE)" + self._coverage_detail(), stacklevel=2)
-        return False
-
     def _zero_bucket_feed(self, nb: int, tb: Optional[int]):
         """Zero feed at one (batch bucket, seq bucket) geometry, or None
         when the model's feed shapes aren't fully concrete past the
@@ -586,26 +476,16 @@ class ServingEngine:
                 (nb, *dims), np.dtype(spec.get("dtype", "float32")))
         return feed
 
-    def warmup(self, tune_decode: Optional[bool] = None) -> int:
+    def warmup(self) -> int:
         """Pre-compile every bucket program derivable from the model's
         feed specs (zero feeds at each bucket geometry), so live
         traffic never pays a cold trace+compile — the CLI does this at
-        startup. Also cross-checks the model's tuned-table provenance
-        (check_tuned_table) so a stale table is warned about at startup,
-        not discovered in a latency regression.
-
-        For generation models the scheduler's slot machinery (pool
-        step + admit + per-bucket prefix programs) warms too, and
-        `tune_decode` controls the ROADMAP-4c slice: empirically tune
-        the decode-step kernels against the live bucket grid via
-        paddle_tpu.tune, populating the per-device table. Default None
-        = only on TPU (the harness refuses CPU timings); True warns and
-        skips when timing is unavailable rather than failing warmup.
+        startup. For generation models the scheduler's slot machinery
+        (pool step + admit + per-bucket prefix programs) warms too.
 
         Returns the number of bucket programs touched; models whose
         feed shapes aren't fully concrete past the batch axis are
         skipped (their buckets compile lazily)."""
-        self.check_tuned_table()
         pol = self.policy
         compiled = 0
         for nb in pol.batch_buckets:
@@ -617,163 +497,7 @@ class ServingEngine:
                 compiled += 1
         if self._gen_spec is not None:
             compiled += self.scheduler().warmup()
-            if tune_decode is None:
-                import jax
-
-                tune_decode = jax.default_backend() == "tpu"
-            if tune_decode:
-                self.tune_decode_kernels()
         return compiled
-
-    # -- decode-step kernel tuning (ROADMAP 4c slice) -------------------
-    def _mesh_dp(self) -> int:
-        """The serving mesh's data-parallel degree (1 off-mesh): the
-        fused kernels dispatch inside shard_map at the PER-SHARD batch
-        (ops/mesh_dispatch.local_batch), so every tuning consult this
-        engine derives must key on bucket/dp — a global-batch entry
-        would tune a shape that never dispatches (ADVICE.md's per-shard
-        eligibility lesson, applied to tuning)."""
-        if self.mesh is None or self.batch_axis is None:
-            return 1
-        return int(self.mesh.shape.get(self.batch_axis, 1))
-
-    def decode_tune_cases(self) -> List[Dict[str, Any]]:
-        """Tunable kernel sites of the decode step, expanded over the
-        live batch-bucket grid: the decode-step batch is
-        (bucket x beam_size) rows — divided by the mesh's dp degree
-        when this replica serves sharded — a shape the offline
-        `tune --config` sweep cannot know (it sees -1 batch dims).
-        Covers bahdanau attention-GRU sites (both the fused train-side
-        op and the beam-search monolith) and static-shape
-        flash_attention sites in any block."""
-        from ..tune.space import pad_s
-
-        spec = self._gen_spec
-        amp = "bfloat16" if getattr(self.program, "amp_dtype", None) \
-            else "float32"
-        out: List[Dict[str, Any]] = []
-        dp = self._mesh_dp()
-
-        def var_shape(block, name):
-            try:
-                return [int(d) for d in block.var(name).shape]
-            except (KeyError, TypeError, ValueError):
-                return None
-
-        K = spec.beam_size if spec is not None else 1
-        for block in self.program.blocks:
-            for op in block.ops:
-                if op.type in ("attention_gru_decoder",
-                               "attention_gru_beam_search"):
-                    enc = var_shape(block, op.inputs["EncState"][0])
-                    wa = var_shape(block, op.inputs["WaEnc"][0])
-                    src = int(op.attrs.get("src_max_len") or 0)
-                    if not enc or not wa or src <= 0:
-                        continue
-                    kk = int(op.attrs.get("beam_size", K)) \
-                        if op.type == "attention_gru_beam_search" else K
-                    for nb in self.policy.batch_buckets:
-                        if nb % dp:
-                            continue  # ragged shard: runtime scans
-                        out.append({
-                            "family": "bahdanau_attention",
-                            "params": {"B": (nb // dp) * kk,
-                                       "Sp": pad_s(src),
-                                       "A": wa[1], "C": enc[-1]},
-                            "dtype": amp, "op": op.type})
-                elif op.type == "flash_attention":
-                    s = var_shape(block, op.inputs["Q"][0])
-                    k = var_shape(block, op.inputs["K"][0])
-                    if not s or not k or len(s) < 3 or s[1] <= 0 \
-                            or k[1] <= 0:
-                        continue
-                    out.append({"family": "flash_attention",
-                                "params": {"Tq": s[1], "Tk": k[1]},
-                                "dtype": amp, "op": op.type})
-                elif op.type in ("quantized_mul", "quantized_matmul"):
-                    # int8 sites (quant/convert.py): the weight panel
-                    # [K, N] is static, the row count is the batch
-                    # bucket times any concrete inner leading dims — a
-                    # shape the offline sweep cannot know, so expand it
-                    # over the live bucket grid like the decode sites.
-                    # Without this the stale-table warning named only
-                    # the fp kernel shapes and `paddle_tpu stats`
-                    # undercounted tuned coverage on quantized models.
-                    w = var_shape(block, op.inputs["Y"][0])
-                    x = var_shape(block, op.inputs["X"][0])
-                    if not w or len(w) != 2 or min(w) <= 0 or not x:
-                        continue
-                    xd = int(op.attrs.get("x_num_col_dims", 1))
-                    inner = x[1:xd]
-                    if any(d <= 0 for d in inner):
-                        continue
-                    mult = 1
-                    for d in inner:
-                        mult *= d
-                    for nb in self.policy.batch_buckets:
-                        if nb % dp:
-                            continue  # ragged shard: runtime falls back
-                        out.append({
-                            "family": "quant_matmul",
-                            "params": {"M": (nb // dp) * mult,
-                                       "K": w[0], "N": w[1]},
-                            "dtype": "int8", "op": op.type})
-        # dedupe (several buckets/ops can land on one shape signature)
-        seen, uniq = set(), []
-        for c in out:
-            key = (c["family"], tuple(sorted(c["params"].items())),
-                   c["dtype"])
-            if key not in seen:
-                seen.add(key)
-                uniq.append(c)
-        return uniq
-
-    def tune_decode_kernels(self, require_tpu: bool = True,
-                            iters: int = 5, warmup: int = 2
-                            ) -> List[Dict[str, Any]]:
-        """Consult/populate the per-device tuned table for every
-        decode-step kernel shape the bucket grid can dispatch
-        (CLBlast's per-device database, applied at serving warmup so
-        production configs are tuned configs). Already-tuned shapes are
-        skipped (the table is the cache); off-TPU the harness refuses
-        and this warns + returns what it skipped instead of failing
-        startup."""
-        from ..tune import harness as tune_harness
-        from ..tune import overrides as tune_overrides
-        from ..tune import space as tune_space
-
-        table = tune_overrides.table()
-        reports: List[Dict[str, Any]] = []
-        for case in self.decode_tune_cases():
-            try:
-                fam = tune_space.get_family(case["family"])
-                norm = fam.normalize(case["params"], case["dtype"])
-            except (KeyError, ValueError) as e:
-                reports.append({**case, "status": f"ineligible: {e}"})
-                continue
-            if table.get(fam.name, norm, case["dtype"]) is not None:
-                reports.append({**case, "status": "cached"})
-                continue
-            try:
-                r = tune_harness.tune_case(
-                    case["family"], case["params"], case["dtype"],
-                    table=table, iters=iters, warmup=warmup,
-                    require_tpu=require_tpu)
-            except tune_harness.TuningUnavailable as e:
-                import warnings
-
-                warnings.warn(
-                    f"decode-step tuning skipped for model "
-                    f"{self.model_name!r}: {e}", stacklevel=2)
-                reports.append({**case, "status": "unavailable"})
-                break
-            except ValueError as e:
-                # shape outside the kernel's eligibility: analytic path
-                reports.append({**case, "status": f"ineligible: {e}"})
-                continue
-            reports.append({**case, "status": "tuned",
-                            "best": r.get("best")})
-        return reports
 
     def compiled_programs(self) -> int:
         """Number of XLA programs the underlying Executor holds."""

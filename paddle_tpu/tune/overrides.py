@@ -1,104 +1,39 @@
-"""Central per-kernel config override registry.
+"""Forced kernel configs: the only thing that overrules a family's rule.
 
-Every tunable kernel consults THIS module at trace time instead of
-reading env vars or tables itself. Lookup precedence (Autotuner v2):
+A kernel family's tile, block or fused-or-scan choice is a function of
+its shapes and dtype, written once in tune/space.py. `force()` /
+`forcing()` pin one family's config process-wide; `space.pick` then
+hands the kernel that config where it is legal at the shape (and warns
+and turns the family off where it is not: someone pinned exactly that
+tile, and another in its place would falsify their sweep). Tests and
+the sweep tool (tune/harness.py) are the callers. No table, file,
+environment variable or flag is read here.
 
-  1. forced override — programmatic `force()` (the harness pins each
-     candidate this way while timing it) or a legacy env knob
-     (PT_ATTN_BBLK keeps working, routed through here);
-  2. the EXACT persistent tuned table (tune/cache.py), keyed by
-     (kernel, shape signature, dtype, device_kind) — the user's local
-     table first, then the read-through BASE table shipped with the
-     package for this device kind (tune/tables/<device_kind>.json;
-     a local entry always shadows the shipped one);
-  3. shape INTERPOLATION — a lookup miss falls through to the nearest
-     tuned entry for the same kernel/dtype/device by log-space shape
-     distance (CLBlast's database lesson: a config measured at a
-     nearby shape transfers most of its win), but ONLY if that config
-     passes the target shape's own legality model
-     (space.config_legal) — an interpolated consult can never hand
-     the runtime a tile it would reject. Neighbors that fail the
-     re-check are skipped in distance order; none legal -> analytic;
-  4. None — the caller applies its analytic default.
-
-Every consult's PROVENANCE is recorded (`consult_stats()`:
-forced/env/table/interpolated/analytic) and exported as
-`pt_tune_consults_total{source=}` through obs.MetricsRegistry, so one
-/metrics scrape shows the tuned-coverage of a live process.
-
-The consumer contract (see ops/bahdanau_kernels._bblk): a FORCED config
-that fails the family's legality predicate warns and disables the fused
-path (the operator asked for exactly that tile; silently substituting
-another would invalidate their sweep), while a stale TABLE or
-INTERPOLATED entry that fails legality is ignored and the analytic
-default applies (a shipped table must never break a model).
-`Override.source` tells the cases apart.
-
-`fingerprint()` is the piece the Executor folds into its jit cache key:
-a content hash over everything that can change a lookup result — forced
-configs, legacy env knobs, the local AND base tables, and the
-FLAGS.use_tuned_table / FLAGS.tune_interpolate knobs — so ANY future
-kernel knob invalidates the jit cache without the executor learning
-about it.
+`forced_key()` is the piece the Executor folds into its jit cache key,
+so a change of a forced config re-traces instead of reusing a step
+program built with the old tile.
 """
 
 from __future__ import annotations
 
 import contextlib
-import hashlib
-import json
-import math
-import os
 import threading
 from typing import Any, Dict, NamedTuple, Optional, Tuple
-
-from ..flags import FLAGS
-from . import cache as _cache
-
-# legacy env knobs, mapped into override configs: kernel -> (env var,
-# config key, parser). A parsed value of 0/empty means "unset" (the
-# pre-tuner PT_ATTN_BBLK semantics).
-ENV_KNOBS = {
-    "bahdanau_attention": ("PT_ATTN_BBLK", "bblk", int),
-}
-
-# interpolation acceptance radius in log-space: sqrt(sum_k ln(p/q)^2)
-# over the shared shape params. 2x on every axis of a 2-param family is
-# ~0.98; the default admits roughly "within 4x on one axis or 2-3x on
-# two" — far enough to bridge bucket grids, near enough that the tile
-# economics plausibly transfer. Beyond it the analytic default is the
-# better guess.
-INTERP_MAX_DIST = 1.5
-
-CONSULT_SOURCES = ("forced", "env", "table", "interpolated", "analytic")
 
 
 class Override(NamedTuple):
     config: Dict[str, Any]
-    source: str  # "forced" | "env" | "table" | "interpolated"
-    # for interpolated lookups: the donor entry's shape signature (the
-    # provenance trail warmup reports name)
-    origin: Optional[str] = None
+    source: str  # "forced"
 
 
 _lock = threading.RLock()
 _forced: Dict[str, Dict[str, Any]] = {}
-_table: Optional[_cache.TunedTable] = None
-_table_path: Optional[str] = None  # None -> flag/env/default resolution
-_base: Optional[_cache.TunedTable] = None
-_base_loaded = False
-_consults: Dict[str, int] = {s: 0 for s in CONSULT_SOURCES}
-# interpolation results are pure functions of (tables, target key) —
-# memoized per table fingerprints so the trace-time cost of a miss is
-# one dict hit after the first consult of a shape
-_interp_cache: Dict[Tuple, Optional[Tuple[Dict[str, Any], str]]] = {}
 
 
-# ------------------------------------------------------------- forcing --
 def force(kernel: str, config: Optional[Dict[str, Any]]) -> None:
     """Pin (or with None, unpin) a kernel family's config
-    process-wide. Takes effect at the next trace — the Executor's cache
-    key includes fingerprint(), so the next run() re-traces."""
+    process-wide. Takes effect at the next trace: the Executor's cache
+    key includes forced_key(), so the next run() re-traces."""
     with _lock:
         if config is None:
             _forced.pop(kernel, None)
@@ -108,7 +43,7 @@ def force(kernel: str, config: Optional[Dict[str, Any]]) -> None:
 
 @contextlib.contextmanager
 def forcing(kernel: str, config: Optional[Dict[str, Any]]):
-    """Scoped force() — the harness traces each candidate under this."""
+    """Scoped force(): the harness traces each candidate under this."""
     with _lock:
         prev = _forced.get(kernel)
     force(kernel, config)
@@ -118,255 +53,20 @@ def forcing(kernel: str, config: Optional[Dict[str, Any]]):
         force(kernel, prev)
 
 
-def _env_override(kernel: str) -> Optional[Dict[str, Any]]:
-    knob = ENV_KNOBS.get(kernel)
-    if not knob:
-        return None
-    env_var, key, parse = knob
-    raw = os.environ.get(env_var)
-    if not raw:
-        return None
-    try:
-        val = parse(raw)
-    except (TypeError, ValueError):
-        return None
-    return {key: val} if val else None
-
-
 def forced_config(kernel: str) -> Optional[Override]:
-    """Forced layer only (programmatic beats env)."""
     with _lock:
         cfg = _forced.get(kernel)
-    if cfg is not None:
-        return Override(dict(cfg), "forced")
-    env = _env_override(kernel)
-    if env is not None:
-        return Override(env, "env")
-    return None
+    return None if cfg is None else Override(dict(cfg), "forced")
 
 
-# --------------------------------------------------------------- table --
-def table() -> _cache.TunedTable:
-    """The process's LOCAL tuned table, lazily loaded from
-    set_table_path() else PT_TUNE_CACHE else the per-user default. A
-    missing file is an empty table (every lookup misses -> base table /
-    interpolation / analytic defaults)."""
-    global _table
+def forced_key() -> Tuple:
+    """The forced configs as a hashable tuple, () when nothing is."""
     with _lock:
-        if _table is None:
-            _table = _cache.TunedTable(_table_path or _cache.default_path())
-        return _table
-
-
-def base_table() -> Optional[_cache.TunedTable]:
-    """The read-through base layer: the pre-tuned table the package
-    ships for this device kind (tune/tables/<device_kind>.json), or
-    None when there is none — every non-TPU dev box, which is exactly
-    why shipping tables can never change CPU-suite behavior. Loaded
-    once per process (reload_table() re-probes)."""
-    global _base, _base_loaded
-    with _lock:
-        if not _base_loaded:
-            path = _cache.base_table_path()
-            _base = _cache.TunedTable(path) if path else None
-            _base_loaded = True
-        return _base
-
-
-def set_table_path(path: Optional[str]) -> None:
-    """Point the registry at a table file (None reverts to the
-    default resolution); the current table is dropped and reloaded
-    lazily."""
-    global _table, _table_path
-    with _lock:
-        _table_path = path
-        _table = None
-        _interp_cache.clear()
-
-
-def reload_table() -> None:
-    """Drop the in-memory tables so the next lookup rereads the files —
-    call after an external tune run wrote new entries."""
-    global _table, _base, _base_loaded
-    with _lock:
-        _table = None
-        _base = None
-        _base_loaded = False
-        _interp_cache.clear()
-
-
-# ------------------------------------------------------- interpolation --
-def _log_distance(a: Dict[str, int], b: Dict[str, int]) -> float:
-    """Log-space euclidean shape distance (CLBlast §4's nearest-shape
-    criterion): symmetric in the ratio per axis, so (B=64 -> B=128) is
-    as far as (B=128 -> B=64), and axes compose euclideanly. Requires
-    the same param-name set — entries from an older schema of a family
-    never match. inf on any non-positive dim."""
-    if set(a) != set(b):
-        return float("inf")
-    d2 = 0.0
-    for k, va in a.items():
-        vb = b[k]
-        if va <= 0 or vb <= 0:
-            return float("inf")
-        d2 += math.log(va / vb) ** 2
-    return math.sqrt(d2)
-
-
-def _interpolate(kernel: str, params: Dict[str, Any], dtype: str
-                 ) -> Optional[Tuple[Dict[str, Any], str]]:
-    """Nearest tuned neighbor whose config is LEGAL at the target
-    shape, or None. Pool = local table entries + base-table entries
-    (local shadows base per exact signature); candidates are walked in
-    distance order and each must pass space.config_legal for the
-    TARGET params before it may win — the property test's contract."""
-    from . import space as _space
-
-    target = {k: int(v) for k, v in params.items() if k != "dtype"}
-    pool: Dict[str, Tuple[Dict[str, int], Dict[str, Any]]] = {}
-    base = base_table()
-    if base is not None:
-        for p, cfg, _meta in base.entries_for(kernel, dtype):
-            pool[_cache.make_sig(p)] = (p, cfg)
-    for p, cfg, _meta in table().entries_for(kernel, dtype):
-        pool[_cache.make_sig(p)] = (p, cfg)
-    target_sig = _cache.make_sig(target)
-    ranked = sorted(
-        ((_log_distance(target, p), sig, cfg)
-         for sig, (p, cfg) in pool.items() if sig != target_sig),
-        key=lambda x: (x[0], x[1]))
-    for dist, sig, cfg in ranked:
-        if dist > INTERP_MAX_DIST:
-            break
-        if _space.config_legal(kernel, target, dtype, cfg):
-            return dict(cfg), sig
-    return None
-
-
-def _interpolated_lookup(kernel: str, params: Dict[str, Any],
-                         dtype: str) -> Optional[Override]:
-    base = base_table()
-    key = (table().fingerprint(),
-           base.fingerprint() if base is not None else "",
-           kernel, _cache.make_sig(params), dtype, _cache.device_kind())
-    with _lock:
-        if key in _interp_cache:
-            hit = _interp_cache[key]
-            return Override(dict(hit[0]), "interpolated", hit[1]) \
-                if hit is not None else None
-    hit = _interpolate(kernel, params, dtype)
-    with _lock:
-        if len(_interp_cache) > 4096:
-            _interp_cache.clear()
-        _interp_cache[key] = hit
-    if hit is None:
-        return None
-    return Override(dict(hit[0]), "interpolated", hit[1])
-
-
-# -------------------------------------------------------------- lookup --
-def _record(source: str) -> None:
-    with _lock:
-        _consults[source] = _consults.get(source, 0) + 1
-
-
-def consult_stats() -> Dict[str, int]:
-    """Per-source consult counts since process start / reset() — the
-    pt_tune_consults_total{source=} families (obs/metrics.py
-    collector). Every source key is always present, 0 included, so the
-    first scrape already shows the full surface."""
-    with _lock:
-        return {s: _consults.get(s, 0) for s in CONSULT_SOURCES}
-
-
-def lookup(kernel: str, params: Dict[str, Any],
-           dtype: str) -> Optional[Override]:
-    """The one consult point kernels call at trace time. `params` is
-    the family's canonical shape dict (space.KernelSpace.param_names
-    order is irrelevant — the signature sorts); `dtype` the io dtype
-    name ('bfloat16'/'float32'). Precedence: forced -> env -> exact
-    table (local, then shipped base) -> interpolated -> None
-    (analytic)."""
-    f = forced_config(kernel)
-    if f is not None:
-        _record(f.source)
-        return f
-    if not FLAGS.use_tuned_table:
-        _record("analytic")
-        return None
-    cfg = table().get(kernel, params, dtype)
-    if cfg is not None:
-        _record("table")
-        return Override(cfg, "table")
-    base = base_table()
-    if base is not None:
-        cfg = base.get(kernel, params, dtype)
-        if cfg is not None:
-            _record("table")
-            return Override(cfg, "table")
-    if FLAGS.tune_interpolate:
-        ov = _interpolated_lookup(kernel, params, dtype)
-        if ov is not None:
-            _record("interpolated")
-            return ov
-    _record("analytic")
-    return None
-
-
-def classify(kernel: str, params: Dict[str, Any],
-             dtype: str) -> Tuple[str, Optional[str]]:
-    """What WOULD lookup() resolve this consult to — (source, origin) —
-    without recording it in the consult counters. The serving warmup
-    coverage report uses this to name untuned-vs-interpolated shapes
-    without inflating the very counters an operator would then read."""
-    f = forced_config(kernel)
-    if f is not None:
-        return f.source, None
-    if not FLAGS.use_tuned_table:
-        return "analytic", None
-    if table().get(kernel, params, dtype) is not None:
-        return "table", None
-    base = base_table()
-    if base is not None and base.get(kernel, params, dtype) is not None:
-        return "table", None
-    if FLAGS.tune_interpolate:
-        ov = _interpolated_lookup(kernel, params, dtype)
-        if ov is not None:
-            return "interpolated", ov.origin
-    return "analytic", None
-
-
-# --------------------------------------------------------- fingerprint --
-def fingerprint() -> str:
-    """Content hash over every override source. Folded into the
-    Executor jit cache key: any knob change — a forced config, a legacy
-    env sweep variable, a retuned/reloaded local or base table, the
-    use_tuned_table / tune_interpolate flags — re-traces instead of
-    silently reusing a stale kernel config."""
-    with _lock:
-        forced = {k: _forced[k] for k in sorted(_forced)}
-    env = {var: os.environ.get(var, "")
-           for (var, _, _) in ENV_KNOBS.values()}
-    use_table = bool(FLAGS.use_tuned_table)
-    interp = bool(FLAGS.tune_interpolate)
-    tbl = table().fingerprint() if use_table else ""
-    base = base_table() if use_table else None
-    base_fp = base.fingerprint() if base is not None else ""
-    blob = json.dumps([forced, env, use_table, interp, tbl, base_fp],
-                      sort_keys=True)
-    return hashlib.sha1(blob.encode()).hexdigest()[:16]
+        return tuple((k, tuple(sorted(_forced[k].items())))
+                     for k in sorted(_forced))
 
 
 def reset() -> None:
-    """Test isolation: clear forced configs, consult counters, and drop
-    the tables."""
-    global _table, _table_path, _base, _base_loaded
+    """Test isolation: clear the forced configs."""
     with _lock:
         _forced.clear()
-        _table = None
-        _table_path = None
-        _base = None
-        _base_loaded = False
-        _interp_cache.clear()
-        for s in CONSULT_SOURCES:
-            _consults[s] = 0

@@ -1,13 +1,13 @@
-"""Per-kernel candidate spaces: legality predicates + generators.
+"""Per-kernel-family rules: legality, candidates, the default, the pick.
 
-THE design rule of this module: the legality model is defined ONCE and
-shared by the tuner and the runtime. `ops/bahdanau_kernels._bblk`
-imports `bahdanau_blk_legal` from here; `ops/flash_ops` imports
-`flash_block_legal`.
-So a candidate this module emits is exactly a config the runtime will
-accept, and a config the runtime accepts is exactly one this module can
-enumerate — the tuner can never measure a config that later fails to
-lower, and the property test (tests/test_tune.py) pins the equivalence.
+A family's tile, block or fused-or-scan choice is a function of its
+shapes and dtype, and this module is the one place it is written. Per
+family: a legality predicate, the candidate generator the sweep tool
+walks (tune/harness.py), and the default rule. The kernels call
+`pick(family, params, dtype)`: the config forced through
+tune/overrides.py where one is and it is legal at the shape, else the
+default. Nothing else overrules a default: no table, file, environment
+variable or flag.
 
 Legality has two ingredients per family:
 - Mosaic tile rules: the last-two-dims (8k, 128k)-or-full block-shape
@@ -17,14 +17,16 @@ Legality has two ingredients per family:
   against the 15 MiB scoped budget in ops/pallas_kernels._VMEM_BUDGET,
   which reproduces every measured compile overflow — see its comment).
 
-Anything in `ops/` is imported lazily: this module loads during
-`paddle_tpu.core` import (via tune.overrides via the Executor), before
-the ops package exists.
+Anything in `ops/` is imported lazily: the kernels import this module,
+and it imports them back for their VMEM constants and case runners.
 """
 
 from __future__ import annotations
 
+import warnings
 from typing import Any, Callable, Dict, List, Optional
+
+from . import overrides
 
 Params = Dict[str, Any]
 Config = Dict[str, Any]
@@ -50,14 +52,16 @@ def _dtype_of(name: str):
             "int8": jnp.int8}[name]
 
 
-# io dtypes the spaces can key on: int8 joined with the quantized-matmul
-# family (the serving fast path) — tuned int8 is just another column of
-# the same per-device table.
+# io dtypes a sweep can name; a kernel that only knows its itemsize
+# names its dtype through ITEMSIZE_DTYPE
 DTYPES = ("bfloat16", "float32", "int8")
+ITEMSIZE_DTYPE = {1: "int8", 2: "bfloat16", 4: "float32", 8: "float64"}
 
 
 def _itemsize(dtype_name: str) -> int:
-    return {"bfloat16": 2, "float32": 4, "int8": 1}[dtype_name]
+    import numpy as np
+
+    return 2 if dtype_name == "bfloat16" else np.dtype(dtype_name).itemsize
 
 
 # ------------------------------------------------------------- bahdanau --
@@ -80,24 +84,26 @@ def bahdanau_blk_legal(b: int, B: int, Sp: int, A: int, C: int,
             + 5 * b * Sp * A * 4) <= _vmem_budget()
 
 
+def _bahdanau_legal(params: Params, config: Config) -> bool:
+    return bahdanau_blk_legal(
+        int(config.get("bblk", 0)), params["B"], params["Sp"], params["A"],
+        params["C"], _itemsize(params["dtype"]))
+
+
 def bahdanau_candidates(params: Params) -> List[Config]:
-    B, Sp, A, C = params["B"], params["Sp"], params["A"], params["C"]
-    item = _itemsize(params["dtype"])
-    out = []
-    for b in range(1, B + 1):
-        if B % b == 0 and bahdanau_blk_legal(b, B, Sp, A, C, item):
-            out.append({"bblk": b})
-    return out
+    return [{"bblk": b} for b in range(1, params["B"] + 1)
+            if _bahdanau_legal(params, {"bblk": b})]
 
 
 def bahdanau_default(params: Params) -> Optional[Config]:
-    """The runtime's analytic choice (bahdanau_kernels._bblk fallback
-    order): 8 measured best on v5e at the NMT shapes; 4 and 2 for small
-    batches only."""
-    B, Sp, A, C = params["B"], params["Sp"], params["A"], params["C"]
-    item = _itemsize(params["dtype"])
+    """8 measured best on v5e at the NMT shapes (256k tok/s vs 217k at
+    16/32, bs256 sweep: larger tiles triple the f32 temporaries and
+    spill); 4 and 2 are for SMALL batches only (a sub-8 tile is a legal
+    Mosaic block shape only when it spans the whole batch dim; B=4 and
+    B=2 verified lowering and matching on v5e hardware, round 5). None:
+    no legal tile, the fused decoder is off at this shape."""
     for b in (8, 4, 2):
-        if bahdanau_blk_legal(b, B, Sp, A, C, item):
+        if _bahdanau_legal(params, {"bblk": b}):
             return {"bblk": b}
     return None
 
@@ -151,11 +157,16 @@ FLASH_BLOCK_GRID = (128, 256, 384, 512, 640, 768, 1024, 1536, 2048)
 
 
 def flash_block_legal(bq: int, bk: int, Tq: int, Tk: int) -> bool:
-    """The TPU flash kernel requires blocks to DIVIDE the sequence and
-    be lane-aligned (128) — ops/flash_ops._v5e_block_sizes rounds its
-    target down through exactly this predicate."""
+    """The TPU flash kernels require blocks to DIVIDE the sequence and
+    be lane-aligned (128)."""
     return (bq > 0 and bk > 0 and bq % 128 == 0 and bk % 128 == 0
             and Tq % bq == 0 and Tk % bk == 0)
+
+
+def _flash_legal(params: Params, config: Config) -> bool:
+    return flash_block_legal(int(config.get("block_q", 0)),
+                             int(config.get("block_k", 0)),
+                             params["Tq"], params["Tk"])
 
 
 def flash_candidates(params: Params) -> List[Config]:
@@ -166,8 +177,9 @@ def flash_candidates(params: Params) -> List[Config]:
 
 
 def flash_default(params: Params) -> Optional[Config]:
-    """The v5e-tuned heuristic (flash_ops._v5e_block_sizes): 512-wide
-    blocks up to T=4096, 1024 from 8192, rounded down to a divisor."""
+    """512-row q/k blocks up to T=4096, 1024 from 8192, rounded down to
+    the largest 128-multiple divisor (e.g. T=1280 -> 256). None: a
+    sequence that is not 128-aligned has no legal block."""
     def blk(T):
         if T % 128:
             return 0
@@ -223,9 +235,8 @@ def _flash_case(params: Params, dtype: str) -> "Case":
 # ------------------------------------------------------------- RNN cells --
 def _rnn_hard_ok(kind: str, B: int, H: int, itemsize: int) -> bool:
     """Hard (non-empirical) fused-RNN legality: tile alignment + the
-    backward-kernel VMEM model from ops/pallas_kernels — everything in
-    lstm_supported/gru_supported EXCEPT the measured H-window, which is
-    exactly the judgment the tuner replaces."""
+    backward-kernel VMEM model from ops/pallas_kernels. The gate forms
+    and the backend are lstm_supported's / gru_supported's to check."""
     from ..ops import pallas_kernels as pk
 
     if not (B >= 8 and B % 8 == 0 and H % 128 == 0):
@@ -236,15 +247,20 @@ def _rnn_hard_ok(kind: str, B: int, H: int, itemsize: int) -> bool:
     return pk._bwd_vmem_bytes(B, H, g, itemsize, dw_max) <= pk._VMEM_BUDGET
 
 
-def _rnn_candidates(kind: str):
-    def gen(params: Params) -> List[Config]:
-        out = [{"fused": False}]
-        if _rnn_hard_ok(kind, params["B"], params["H"],
-                        _itemsize(params["dtype"])):
-            out.insert(0, {"fused": True})
-        return out
+def _rnn_legal(kind: str):
+    def legal(params: Params, config: Config) -> bool:
+        if "fused" not in config:
+            return False
+        return not config["fused"] or _rnn_hard_ok(
+            kind, params["B"], params["H"], _itemsize(params["dtype"]))
 
-    return gen
+    return legal
+
+
+def _rnn_candidates(kind: str):
+    legal = _rnn_legal(kind)
+    return lambda params: [c for c in ({"fused": True}, {"fused": False})
+                           if legal(params, c)]
 
 
 def _rnn_default(kind: str):
@@ -252,8 +268,19 @@ def _rnn_default(kind: str):
         B, H = params["B"], params["H"]
         if not _rnn_hard_ok(kind, B, H, _itemsize(params["dtype"])):
             return {"fused": False}
-        # the measured windows (rnn_kernel_microbench: old link, rounds
-        # <= 5, not re-measured on this chip; record in git history)
+        # The measured windows. LSTM's upper end was read on the v5e in
+        # PR 48 (forward + backward of one layer, bf16, T 100, ten
+        # alternating pairs, kernel over lax.scan: PERF.md section 6):
+        # 1.22x at B 128 H 1024, 1.17x at B 128 H 1152, 1.73x at B 32
+        # H 1280, 1.81x at B 32 H 1152; at B 128 H 1280 the VMEM model
+        # above decides, not the window. The rest is from before this
+        # chip (rnn_kernel_microbench, rounds <= 5, the old link; record
+        # in git history). LSTM: 1.02x at H 512, 1.45x at 768; H 256
+        # loses (0.86x): the per-step matmul is too small to amortize
+        # the kernel's fixed work. GRU: 1.18x at H 128, 1.06x at 256,
+        # 1.72x at 512 (the NMT config), 1.70x at 640, 1.24x at 768,
+        # 1.61x at 1024, 1.88x at 1280; H 384 alone dips to 0.86x
+        # (3H = 1152 tiles badly against the 512-lane MXU pass).
         if kind == "lstm":
             return {"fused": 384 <= H <= 1280}
         return {"fused": 128 <= H <= 1280 and H != 384}
@@ -285,32 +312,34 @@ def quant_matmul_legal(bm: int, bn: int, M: int, K: int, N: int) -> bool:
     return ws <= _vmem_budget()
 
 
+def _quant_legal(params: Params, config: Config) -> bool:
+    return quant_matmul_legal(int(config.get("block_m", 0)),
+                              int(config.get("block_n", 0)),
+                              params["M"], params["K"], params["N"])
+
+
 def quant_matmul_candidates(params: Params) -> List[Config]:
     M, K, N = params["M"], params["K"], params["N"]
     # M and N themselves join the grids so shapes below the minimum
     # tile (e.g. a batch-1 bucket) still have the whole-dim candidate
     ms = sorted({b for b in (*QUANT_BLOCK_M, M) if M % b == 0})
     ns = sorted({b for b in (*QUANT_BLOCK_N, N) if N % b == 0})
-    return [{"block_m": bm, "block_n": bn}
-            for bm in ms for bn in ns
-            if quant_matmul_legal(bm, bn, M, K, N)]
+    return [c for c in ({"block_m": bm, "block_n": bn}
+                        for bm in ms for bn in ns)
+            if _quant_legal(params, c)]
 
 
 def quant_matmul_default(params: Params) -> Optional[Config]:
-    """Analytic choice of the runtime fallback: the largest legal
-    output tile (fewest grid steps — the int8 panels are small enough
-    that dispatch overhead, not VMEM, dominates at serving shapes)."""
-    M, K, N = params["M"], params["K"], params["N"]
-    best = None
+    """The largest legal output tile (fewest grid steps: the int8
+    panels are small enough that dispatch overhead, not VMEM, dominates
+    at serving shapes). None: no legal tile, the reference contraction
+    runs."""
+    M, N = params["M"], params["N"]
     for bm in sorted({*QUANT_BLOCK_M, M}, reverse=True):
-        if M % bm:
-            continue
         for bn in sorted({*QUANT_BLOCK_N, N}, reverse=True):
-            if N % bn:
-                continue
-            if quant_matmul_legal(bm, bn, M, K, N):
+            if _quant_legal(params, {"block_m": bm, "block_n": bn}):
                 return {"block_m": bm, "block_n": bn}
-    return best
+    return None
 
 
 def _quant_case(params: Params, dtype: str) -> "Case":
@@ -359,21 +388,24 @@ class Case:
 
 
 class KernelSpace:
-    def __init__(self, name: str, param_names, candidates, default,
-                 make_case=None, doc: str = ""):
+    """One family: its shape params, legality of a config at a shape,
+    the candidates a sweep walks, the default rule, and `off`: what the
+    kernel gets when a forced config is illegal (None where the family
+    has an unfused path to fall to)."""
+
+    def __init__(self, name: str, param_names, legal, candidates, default,
+                 off=lambda params: None, make_case=None, doc: str = ""):
         self.name = name
         self.param_names = tuple(param_names)
-        self._candidates = candidates
-        self._default = default
+        self.legal = legal
+        self.candidates = candidates
+        self.default = default
+        self.off = off
         self._make_case = make_case
         self.doc = doc
 
-    def normalize(self, params: Params, dtype: str) -> Params:
-        """Validated, canonically-ordered params incl. dtype — the shape
-        signature the cache keys on."""
-        if dtype not in DTYPES:
-            raise ValueError(f"{self.name}: dtype must be one of "
-                             f"{DTYPES}, got {dtype!r}")
+    def shape(self, params: Params, dtype: str) -> Params:
+        """The family's params as ints, in canonical order, with dtype."""
         missing = [k for k in self.param_names if k not in params]
         if missing:
             raise ValueError(
@@ -383,11 +415,13 @@ class KernelSpace:
         norm["dtype"] = dtype
         return norm
 
-    def candidates(self, params: Params) -> List[Config]:
-        return self._candidates(params)
-
-    def default(self, params: Params) -> Optional[Config]:
-        return self._default(params)
+    def normalize(self, params: Params, dtype: str) -> Params:
+        """shape() for input from outside the program (the CLI): the
+        dtype must be one a sweep can build a case for."""
+        if dtype not in DTYPES:
+            raise ValueError(f"{self.name}: dtype must be one of "
+                             f"{DTYPES}, got {dtype!r}")
+        return self.shape(params, dtype)
 
     def make_case(self, params: Params, dtype: str) -> Case:
         if self._make_case is None:
@@ -399,24 +433,29 @@ class KernelSpace:
 
 FAMILIES: Dict[str, KernelSpace] = {
     "bahdanau_attention": KernelSpace(
-        "bahdanau_attention", ("B", "Sp", "A", "C"),
-        bahdanau_candidates, bahdanau_default, _bahdanau_case,
+        "bahdanau_attention", ("B", "Sp", "A", "C"), _bahdanau_legal,
+        bahdanau_candidates, bahdanau_default, make_case=_bahdanau_case,
         doc="batch tile (bblk) of the fused Bahdanau decoder kernels"),
     "flash_attention": KernelSpace(
-        "flash_attention", ("Tq", "Tk"),
-        flash_candidates, flash_default, _flash_case,
+        "flash_attention", ("Tq", "Tk"), _flash_legal,
+        flash_candidates, flash_default,
+        off=flash_default,  # the kernels have no unfused path to fall to
+        make_case=_flash_case,
         doc="q/k block sizes of the TPU flash-attention kernel"),
     "fused_lstm": KernelSpace(
-        "fused_lstm", ("B", "H"),
+        "fused_lstm", ("B", "H"), _rnn_legal("lstm"),
         _rnn_candidates("lstm"), _rnn_default("lstm"),
+        off=lambda params: {"fused": False},
         doc="fused-vs-scan dispatch of the whole-sequence LSTM kernel"),
     "fused_gru": KernelSpace(
-        "fused_gru", ("B", "H"),
+        "fused_gru", ("B", "H"), _rnn_legal("gru"),
         _rnn_candidates("gru"), _rnn_default("gru"),
+        off=lambda params: {"fused": False},
         doc="fused-vs-scan dispatch of the whole-sequence GRU kernel"),
     "quant_matmul": KernelSpace(
-        "quant_matmul", ("M", "K", "N"),
-        quant_matmul_candidates, quant_matmul_default, _quant_case,
+        "quant_matmul", ("M", "K", "N"), _quant_legal,
+        quant_matmul_candidates, quant_matmul_default,
+        make_case=_quant_case,
         doc="output tile (block_m, block_n) of the int8×int8→int32 "
             "quantized-matmul kernel"),
 }
@@ -436,25 +475,27 @@ def get_family(name: str) -> KernelSpace:
     return FAMILIES[key]
 
 
-def config_legal(family: str, params: Params, dtype: str,
-                 config: Config) -> bool:
-    """Is `config` a legal candidate for `params` — i.e. would the
-    candidate generator itself have emitted it? THE re-validation gate
-    for shape-interpolated lookups (tune/overrides.py): a config tuned
-    at a NEIGHBORING shape is only usable at the target shape if it is
-    inside the target's own candidate set, so an interpolated consult
-    can never hand the runtime a tile its legality model rejects.
-    Membership (not just predicate re-evaluation) is deliberate: the
-    generators encode extra structure — divisor grids, the fixed block
-    lists — that a bare predicate check would miss. Malformed
-    params/config degrade to False, never raise (interpolation feeds
-    arbitrary table contents through here)."""
-    try:
-        fam = get_family(family)
-        norm = fam.normalize(params, dtype)
-        return dict(config) in fam.candidates(norm)
-    except (KeyError, ValueError, TypeError):
-        return False
+def pick(family: str, params: Params, dtype: str) -> Optional[Config]:
+    """THE choice of a family's config at a shape, called by the kernels
+    at trace time: the forced config where one is and it is legal here;
+    a warning and the family's `off` where it is forced and illegal
+    (someone pinned exactly that tile for a sweep: another in its place
+    would falsify the sweep); else the default rule. None: the kernel
+    does not run at this shape."""
+    fam = FAMILIES[family]
+    shape = fam.shape(params, dtype)
+    forced = overrides.forced_config(family)
+    if forced is None:
+        return fam.default(shape)
+    if fam.legal(shape, forced.config):
+        return forced.config
+    off = fam.off(shape)
+    warnings.warn(
+        f"forced {family} config {forced.config} fails eligibility at "
+        f"{shape} (divisibility or VMEM); "
+        + ("the fused kernel is DISABLED for this shape" if off is None
+           else f"using {off}"), stacklevel=3)
+    return off
 
 
 # ------------------------------------------------- model program sweep --
@@ -468,9 +509,8 @@ def cases_from_program(program=None, dp: int = 1) -> List[Dict[str, Any]]:
     `dp` is the data-parallel degree the model will RUN under: the
     fused kernels dispatch inside shard_map at the PER-SHARD batch
     (ops/mesh_dispatch.local_batch — ADVICE.md's per-shard eligibility
-    lesson), so tuning must key on the per-shard shape too, or every
-    mesh run misses the table and a global-batch entry tunes a shape
-    that never dispatches. Batch-carrying params divide by dp;
+    lesson), so a sweep must time the per-shard shape too, or it times
+    a shape that never dispatches. Batch-carrying params divide by dp;
     non-divisible sites are skipped (the runtime falls back to the
     scan/XLA formulation there — nothing to tune)."""
     from ..core.program import default_main_program
@@ -517,8 +557,7 @@ def cases_from_program(program=None, dp: int = 1) -> List[Dict[str, Any]]:
             elif op.type in ("quantized_mul", "quantized_matmul"):
                 # int8 sites (quant/convert.py rewrite): the weight
                 # panel [K, N] is static; the row count comes from X
-                # when concrete (serving buckets expand the -1 case via
-                # engine.decode_tune_cases)
+                # when concrete
                 x = var_shape(block, op.inputs["X"][0])
                 w = var_shape(block, op.inputs["Y"][0])
                 if not x or not w or len(w) != 2 or min(w) <= 0:

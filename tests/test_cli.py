@@ -82,7 +82,7 @@ def test_cli_train_rejects_unknown_flag(tmp_path):
 
 def test_cli_train_eq_form_options(tmp_path):
     """--num_passes=N / --save_dir=D forms must work (and save_dir must
-    reach the checkpoint config, not be swallowed by the flag registry)."""
+    reach the checkpoint config)."""
     cfg = tmp_path / "model.py"
     cfg.write_text(CONFIG)
     ckpt = tmp_path / "ck"
@@ -96,10 +96,10 @@ def test_cli_train_eq_form_options(tmp_path):
 def test_cli_train_flag_missing_value_and_bad_value(tmp_path):
     cfg = tmp_path / "model.py"
     cfg.write_text(CONFIG)
-    r = _run(["train", "--config", str(cfg), "--beam_size"], str(tmp_path))
+    r = _run(["train", "--config", str(cfg), "--log_period"], str(tmp_path))
     assert r.returncode != 0
     assert "requires a value" in (r.stderr + r.stdout)
-    r = _run(["train", "--config", str(cfg), "--beam_size=abc"],
+    r = _run(["train", "--config", str(cfg), "--log_period=abc"],
              str(tmp_path))
     assert r.returncode != 0
     out = r.stderr + r.stdout
@@ -275,8 +275,8 @@ def test_cli_tune_dry_run(tmp_path):
 
 
 def test_cli_tune_refuses_to_time_on_cpu(tmp_path):
-    """Without --dry-run, timing on a CPU backend must refuse loudly
-    (the per-device table stays TPU-only) — determinism guard."""
+    """Without --dry-run, timing on a CPU backend must refuse loudly: a
+    timing off the TPU says nothing of the chip."""
     r = _run(["tune", "--kernel", "bahdanau",
               "--shape", "B=16,S=10,A=128,C=128"], str(tmp_path))
     assert r.returncode != 0
@@ -305,88 +305,23 @@ def get_model():
     assert "Tk=1024,Tq=1024" in r.stdout
 
 
-def _seed_table(path, entries):
-    """Write a tuned table via the real TunedTable (keys/format stay in
-    sync with the runtime by construction)."""
-    from paddle_tpu.tune.cache import TunedTable
+def test_cli_tune_table_verbs_are_gone(tmp_path, monkeypatch):
+    """There is no tuned table to export, import or merge, and a sweep
+    takes no --cache, --search or --budget: each exits non-zero, the
+    verbs naming what `tune` does now, and nothing is written."""
+    from paddle_tpu import cli
 
-    t = TunedTable(str(path), autoload=False)
-    for fam, params, dtype, cfg, meta in entries:
-        t.put(fam, params, dtype, cfg, device="tpu-v5-lite", meta=meta)
-    t.save()
-    return t.fingerprint()
-
-
-def test_cli_tune_export_import_merge_round_trip(tmp_path):
-    """The fleet workflow end to end: host A exports, host B imports
-    into its local table (precedence applied), a merge job aggregates —
-    and export -> import -> export is bit-identical."""
-    a = tmp_path / "hostA.json"
-    _seed_table(a, [
-        ("bahdanau_attention", {"B": 256, "Sp": 64, "A": 512, "C": 512},
-         "bfloat16", {"bblk": 8},
-         {"provenance": "measured", "updated_at": 100}),
-        ("flash_attention", {"Tq": 2048, "Tk": 2048}, "bfloat16",
-         {"block_q": 512, "block_k": 512},
-         {"provenance": "interpolated", "updated_at": 100}),
-    ])
-    exp = tmp_path / "export.json"
-    r = _run(["tune", "export", "--out", str(exp), "--cache", str(a)],
-             str(tmp_path))
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "exported 2 entries" in r.stdout
-
-    # host B: older interpolated bahdanau (loses), MEASURED flash (wins
-    # over A's interpolated despite being older)
-    b = tmp_path / "hostB.json"
-    _seed_table(b, [
-        ("bahdanau_attention", {"B": 256, "Sp": 64, "A": 512, "C": 512},
-         "bfloat16", {"bblk": 16},
-         {"provenance": "interpolated", "updated_at": 999}),
-        ("flash_attention", {"Tq": 2048, "Tk": 2048}, "bfloat16",
-         {"block_q": 1024, "block_k": 1024},
-         {"provenance": "measured", "updated_at": 50}),
-    ])
-    r = _run(["tune", "import", str(exp), "--cache", str(b)],
-             str(tmp_path))
-    assert r.returncode == 0, r.stderr[-2000:]
-    from paddle_tpu.tune.cache import TunedTable
-
-    merged = TunedTable(str(b))
-    assert merged.get("bahdanau_attention",
-                      {"B": 256, "Sp": 64, "A": 512, "C": 512},
-                      "bfloat16", device="tpu-v5-lite") == {"bblk": 8}
-    assert merged.get("flash_attention", {"Tq": 2048, "Tk": 2048},
-                      "bfloat16", device="tpu-v5-lite") == {
-        "block_q": 1024, "block_k": 1024}
-
-    # bit-identical round trip: import the export into an EMPTY local
-    # table and re-export
-    empty = tmp_path / "empty.json"
-    r = _run(["tune", "import", str(exp), "--cache", str(empty)],
-             str(tmp_path))
-    assert r.returncode == 0, r.stderr[-2000:]
-    exp2 = tmp_path / "export2.json"
-    r = _run(["tune", "export", "--out", str(exp2), "--cache",
-              str(empty)], str(tmp_path))
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert exp.read_bytes() == exp2.read_bytes()
-
-    # merge: N inputs -> one output, without touching any local table
-    out = tmp_path / "fleet.json"
-    r = _run(["tune", "merge", "--out", str(out), str(a), str(b)],
-             str(tmp_path))
-    assert r.returncode == 0, r.stderr[-2000:]
-    fleet = TunedTable(str(out))
-    assert len(fleet) == 2
-    assert fleet.get("flash_attention", {"Tq": 2048, "Tk": 2048},
-                     "bfloat16", device="tpu-v5-lite") == {
-        "block_q": 1024, "block_k": 1024}
-
-
-def test_cli_tune_import_rejects_schema_mismatch(tmp_path):
-    bad = tmp_path / "bad.json"
-    bad.write_text('{"version": 999, "entries": {}}')
-    r = _run(["tune", "import", str(bad)], str(tmp_path))
+    r = _run(["tune", "export", "--out", "t.json"], str(tmp_path))
     assert r.returncode != 0
-    assert "schema version" in (r.stderr + r.stdout)
+    out = r.stderr + r.stdout
+    assert "tune --kernel" in out and "Traceback" not in out, out
+    monkeypatch.chdir(tmp_path)
+    for verb in (["import", "t.json"], ["merge", "--out", "t.json", "a"]):
+        with pytest.raises(SystemExit, match="tune --kernel"):
+            cli._cmd_tune(verb)
+    for opt in (["--cache", "t.json"], ["--search", "guided"],
+                ["--budget", "0.4"]):
+        with pytest.raises(SystemExit, match=f"unknown option {opt[0]}"):
+            cli._cmd_tune(["--kernel", "flash", "--shape",
+                           "Tq=1024,Tk=1024", "--dry-run", *opt])
+    assert not list(tmp_path.iterdir())

@@ -29,10 +29,7 @@ def test_flag_define_parse_and_env(monkeypatch):
 
 
 def test_every_flag_is_read_by_the_package():
-    """A flag nothing reads is an option that selects nothing. Two are
-    known and named: `beam_size` and `save_dir` document the reference's
-    gflags of the same names; the layers and `train --save_dir` take the
-    value as an argument instead (ROADMAP Queue 3)."""
+    """A flag nothing reads is an option that selects nothing."""
     import os
     import re
 
@@ -53,7 +50,7 @@ def test_every_flag_is_read_by_the_package():
         if not name.startswith("test_")  # defined by the tests above
         and not re.search(rf"FLAGS\.{name}\b", source)
         and f'backend_ok("{name}")' not in source)
-    assert unread == ["beam_size", "save_dir"]
+    assert unread == []
 
 
 def test_parse_bool_flag_bare():
@@ -90,7 +87,7 @@ def test_init_atomic_on_bad_value():
     """A failing coercion mid-kwargs applies nothing (docstring claim)."""
     before = FLAGS.log_period
     with pytest.raises((TypeError, ValueError)):
-        pt.init(log_period=99, beam_size="xyz")  # int("xyz") fails
+        pt.init(log_period=99, stats_period="xyz")  # int("xyz") fails
     assert FLAGS.log_period == before
 
 

@@ -432,7 +432,7 @@ def test_generate_trips_shared_breaker(gen_model_dir):
         reg.stop()
 
 
-# ------------------------------------------------------- warmup + tuning ----
+# ---------------------------------------------------------------- warmup ----
 
 
 def test_warmup_precompiles_pool_from_meta(gen_model_dir):
@@ -442,7 +442,7 @@ def test_warmup_precompiles_pool_from_meta(gen_model_dir):
     eng = ServingEngine(gen_model_dir,
                         policy=BucketPolicy(max_batch_size=4),
                         model_name="warm_gen")
-    eng.warmup(tune_decode=False)
+    eng.warmup()
     sched = eng._scheduler
     assert sched is not None and sched._state is not None
     compiled = sched.compiles
@@ -454,62 +454,6 @@ def test_warmup_precompiles_pool_from_meta(gen_model_dir):
     assert sched.compiles == compiled  # zero cold compiles under traffic
     assert "generation" in eng.stats()
     sched.stop()
-
-
-def test_decode_tune_cases_and_cpu_refusal(gen_model_dir, monkeypatch):
-    """ROADMAP-4c satellite: warmup consults/populates the tuned table
-    for the decode-step kernel shapes. This model has no tunable
-    kernel sites (plain fc steps) so the case list is empty; with a
-    monkeypatched case list the plumbing must consult the table first
-    (cached), tune misses, and degrade to a warning off-TPU."""
-    from paddle_tpu.tune import harness as tune_harness
-
-    eng = ServingEngine(gen_model_dir, model_name="tune_gen")
-    assert eng.decode_tune_cases() == []
-    assert eng.tune_decode_kernels() == []  # no sites, no TPU needed
-
-    case = {"family": "bahdanau_attention",
-            "params": {"B": 8 * K, "Sp": 8, "A": 16, "C": 32},
-            "dtype": "float32", "op": "attention_gru_beam_search"}
-    monkeypatch.setattr(eng, "decode_tune_cases", lambda: [case])
-    calls = []
-
-    def fake_tune(family, params, dtype, table=None, iters=5, warmup=2,
-                  require_tpu=True):
-        calls.append((family, dict(params), dtype))
-        table.put(family, params, dtype, {"bblk": 8})
-        return {"best": {"bblk": 8}}
-
-    monkeypatch.setattr(tune_harness, "tune_case", fake_tune)
-    reports = eng.tune_decode_kernels(require_tpu=False)
-    assert [r["status"] for r in reports] == ["tuned"] and len(calls) == 1
-    # second pass: the table IS the cache — no re-timing
-    reports = eng.tune_decode_kernels(require_tpu=False)
-    assert [r["status"] for r in reports] == ["cached"] and len(calls) == 1
-
-    # off-TPU the harness refuses; warmup degrades to a warning
-    def refuse(*a, **kw):
-        raise tune_harness.TuningUnavailable("no TPU")
-
-    monkeypatch.setattr(tune_harness, "tune_case", refuse)
-    monkeypatch.setattr(
-        eng, "decode_tune_cases",
-        lambda: [dict(case, params=dict(case["params"], B=64))])
-    with pytest.warns(UserWarning, match="tuning skipped"):
-        reports = eng.tune_decode_kernels()
-    assert reports[-1]["status"] == "unavailable"
-
-
-def test_chain_decode_tune_cases_empty_but_warmup_clean(chain_model_dir):
-    """warmup(tune_decode=True) on CPU must not raise even when asked
-    to tune: no tunable sites here, and the tune path never blocks
-    serving startup."""
-    eng = ServingEngine(chain_model_dir,
-                        policy=BucketPolicy(max_batch_size=2),
-                        model_name="warm_chain")
-    n = eng.warmup(tune_decode=True)
-    assert n >= len(eng.policy.batch_buckets)
-    eng._scheduler.stop()
 
 
 # ----------------------------------------------------------------- http -----

@@ -639,27 +639,15 @@ def test_dead_trainer_gauges_disappear():
 
 def test_cli_stats_file(tmp_path, capsys):
     from paddle_tpu import cli
-    from paddle_tpu.tune import overrides as tune_overrides
 
     registry().counter_inc("pt_demo_total", help="demo",
                            labels={"kind": "a"})
-    # exercise the tuned-coverage summary: one analytic consult and one
-    # exact-table hit land in pt_tune_consults_total
-    tune_overrides.lookup("bahdanau_attention",
-                          {"B": 16, "Sp": 16, "A": 128, "C": 128},
-                          "float32")
-    tune_overrides.table().put(
-        "bahdanau_attention", {"B": 16, "Sp": 16, "A": 128, "C": 128},
-        "float32", {"bblk": 8})
-    tune_overrides.lookup("bahdanau_attention",
-                          {"B": 16, "Sp": 16, "A": 128, "C": 128},
-                          "float32")
     p = tmp_path / "m.prom"
     p.write_text(registry().render())
     assert cli.main(["stats", "--file", str(p)]) == 0
     out = capsys.readouterr().out
     assert "pt_demo_total" in out and "families parsed OK" in out
-    assert "tuned coverage: 50% of 2 kernel consults" in out
+    assert "pt_tune" not in out and "tuned coverage" not in out
 
 
 def test_cli_stats_rejects_malformed_file(tmp_path):

@@ -267,7 +267,7 @@ def test_tampered_scales_fail_loudly(mlp_dir, tmp_path):
 
 def test_quant_tune_space_legality_property():
     """Every candidate the int8 family emits passes its own legality
-    model AND config_legal membership (the interpolation gate); the
+    predicate, which also refuses a tile that does not divide; the
     default is always a member; tiles respect int8's (32,128) minimum
     unless they span the whole dim."""
     fam = tune_space.FAMILIES["quant_matmul"]
@@ -285,11 +285,8 @@ def test_quant_tune_space_legality_property():
             assert bm % 32 == 0 or bm == M, (params, cfg)
             assert bn % 128 == 0 or bn == N, (params, cfg)
             assert tune_space.quant_matmul_legal(bm, bn, M, K, N)
-            assert tune_space.config_legal(
-                "quant_matmul", {"M": M, "K": K, "N": N}, "int8", cfg)
-        assert not tune_space.config_legal(
-            "quant_matmul", {"M": M, "K": K, "N": N}, "int8",
-            {"block_m": M + 1, "block_n": N})
+            assert fam.legal(params, cfg)
+        assert not fam.legal(params, {"block_m": M + 1, "block_n": N})
 
 
 def test_quant_case_exact_all_candidates():
@@ -336,7 +333,6 @@ def test_engine_buckets_and_zero_compile_warmup(mlp_dir, tmp_path):
     oracle = ServingEngine(q_dir, model_name="tq_oracle")
     n = eng.warmup()
     assert n == len(eng.policy.batch_buckets) == eng.compiled_programs()
-    assert eng.check_tuned_table()
     before = eng.exe.cache_stats["misses"]
     rng = np.random.RandomState(3)
     for k in rng.randint(1, 9, size=12):
@@ -350,27 +346,6 @@ def test_engine_buckets_and_zero_compile_warmup(mlp_dir, tmp_path):
     # the engine advertises the artifact's quant footprint
     s = eng.stats()
     assert s["quant"]["mode"] == "int8" and s["quant"]["sites"] == 3
-
-
-def test_engine_tune_cases_cover_quant_family(mlp_dir, tmp_path):
-    """Satellite 6: decode_tune_cases / tune_coverage name the int8
-    family per bucket, so check_tuned_table coverage counts quantized
-    matmuls like any other kernel."""
-    program, feeds, fetches, scope, _, _ = _load_convert(mlp_dir)
-    q_dir = str(tmp_path / "int8")
-    pt.io.save_inference_model(q_dir, feeds, fetches,
-                               main_program=program, scope=scope)
-    eng = ServingEngine(q_dir, policy=BucketPolicy(batch_buckets=(2, 4)),
-                        quantize="int8")
-    cases = [c for c in eng.decode_tune_cases()
-             if c["family"] == "quant_matmul"]
-    # 3 sites x 2 buckets
-    assert len(cases) == 6
-    assert {c["params"]["M"] for c in cases} == {2, 4}
-    assert all(c["dtype"] == "int8" for c in cases)
-    cov = eng.tune_coverage()
-    assert any(c["family"] == "quant_matmul" and c["dtype"] == "int8"
-               for c in cov)
 
 
 def test_engine_quantize_knob_validation(mlp_dir, tmp_path):
