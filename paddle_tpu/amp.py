@@ -64,6 +64,9 @@ LOW_PRECISION_OPS = frozenset({
     # the state-space mixer's two projections and the scan's matmuls; its
     # decays and carried state are float32 inside the op (ops/ssm_ops.py)
     "mamba2_mixer",
+    # the gated short-convolution operator's two projections; the gates and
+    # the taps between them are float32 inside the op (ops/short_conv_ops.py)
+    "short_conv_operator",
 })
 
 # The subset of low-precision sites the int8 converter may rewrite: dense

@@ -28,7 +28,7 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
             scoring: str = "softmax", router_bias: bool = False,
             gate_scale: float = 1.0, expert_act: str = "swiglu",
             held_experts=None, shared_expert_dim: int = 0,
-            chunk_shares=None):
+            chunk_shares=None, gate_norm_eps: float = 0.0):
     """input [B, T, d] -> (out [B, T, d], router logits [B*T, E] float32,
     tokens per expert [E] int32). Each token goes to its `experts_per_token`
     highest-scoring experts of `num_experts`; every (token, slot) pair is
@@ -38,8 +38,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
     renormalised unless `norm_topk_prob`. "sigmoid": s = sigmoid(logits), the
     choice is the top k of s + b (`router_bias`: b [E] is `<name>.router_bias`,
     zeros, NOT trained: a buffer a load balancer would steer), the gates s of
-    the chosen, over their sum with `norm_topk_prob`. `gate_scale` multiplies
-    the gates.
+    the chosen, over their sum with `norm_topk_prob` (over their sum +
+    `gate_norm_eps` where a published layer adds one: LFM2's 1e-6).
+    `gate_scale` multiplies the gates.
     expert_act "swiglu": silu(x Wg) * (x Wu) -> Wd, parameters `<name>.gate`,
     `.up` [E, d, f], `.down` [E, f, d]. "relu2": relu(x Wu)^2 -> Wd, no gate
     stack. All bias-free; `<name>.router` [d, E].
@@ -120,6 +121,8 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
         attrs["scoring"] = scoring
     if gate_scale != 1.0:
         attrs["gate_scale"] = float(gate_scale)
+    if gate_norm_eps:
+        attrs["gate_norm_eps"] = float(gate_norm_eps)
     if part:
         attrs["held_lo"], attrs["held_hi"] = lo, hi
         held = helper.create_tmp_variable(np.int32, (hi - lo,))

@@ -1,6 +1,8 @@
-"""State-space layer DSL: the Mamba-2 mixer (ops/ssm_ops.py). Beyond the
-2017 reference's layer set; the sequence mixer of the hybrid Mamba-2 /
-attention decoders (Nemotron-H and its kin).
+"""Layer DSL of the sequence mixers that are neither attention nor an RNN:
+the Mamba-2 mixer (ops/ssm_ops.py), the sequence mixer of the hybrid Mamba-2
+/ attention decoders (Nemotron-H and its kin), and the gated short-convolution
+operator (ops/short_conv_ops.py) of the convolution / attention hybrids
+(LFM2 and its kin). Beyond the 2017 reference's layer set.
 """
 
 from __future__ import annotations
@@ -13,7 +15,7 @@ from ..initializer import (ConstantInitializer, Initializer,
 from ..param_attr import ParamAttr
 from .helper import LayerHelper
 
-__all__ = ["mamba2_mixer"]
+__all__ = ["mamba2_mixer", "short_conv_operator"]
 
 
 class _Mamba2Init(Initializer):
@@ -84,4 +86,47 @@ def mamba2_mixer(input, num_heads: int, head_dim: int, n_groups: int,
         attrs={"num_heads": H, "head_dim": P, "n_groups": G,
                "state_size": N, "epsilon": float(epsilon),
                "chunk": int(chunk)})
+    return out
+
+
+def short_conv_operator(input, hidden=None, kernel: int = 3, param_attr=None,
+                        name=None):
+    """input [B, T, d] -> [B, T, d]: a gated short convolution (LFM2's `conv`
+    layers), bias-free, `hidden` wide (None: d):
+
+        [B | C | X] = input W_in          W_in [d, 3 hidden], in that order
+        c_t = sum_{k < kernel} w[k] (B * X)_{t - (kernel - 1) + k}
+        out = (C * c) W_out               W_out [hidden, d]
+
+    a causal depthwise convolution (zeros before the sequence's start, no
+    activation) between two gates. Parameters: `<name>.in_w`, `.conv_w`
+    [kernel, hidden], `.out_w`. The taps start at U(+-1/sqrt(kernel)) (a
+    depthwise `Conv1d`'s default), the projections at the DSL's Glorot.
+    param_attr may be a mapping {"in_w" | "conv_w" | "out_w": attr}
+    (`ParamAttr.derive`): a caller's initialiser wins. Under amp the two
+    projections run in the amp dtype and what lies between them reads and
+    writes it with float32 arithmetic inside (one pass forward, one
+    backward)."""
+    helper = LayerHelper("short_conv_operator", name=name)
+    d = int(input.shape[-1])
+    width, K = d if hidden is None else int(hidden), int(kernel)
+    if K < 1:
+        raise ValueError(f"kernel {kernel}: at least one tap")
+
+    def param(suffix, shape, init):
+        return helper.create_parameter(
+            ParamAttr.derive(param_attr, helper.name, suffix), shape,
+            default_initializer=init)
+
+    bound = 1.0 / np.sqrt(K)
+    inputs = {
+        "X": [input],
+        "InW": [param("in_w", (d, 3 * width), XavierInitializer())],
+        "ConvW": [param("conv_w", (K, width),
+                        UniformInitializer(-bound, bound))],
+        "OutW": [param("out_w", (width, d), XavierInitializer())],
+    }
+    out = helper.create_tmp_variable(input.dtype, input.shape)
+    helper.append_op(type="short_conv_operator", inputs=inputs,
+                     outputs={"Out": [out]})
     return out

@@ -16,4 +16,5 @@ from .olmoe import olmoe_lm  # noqa: F401
 from .nemotron_h import nemotron_h_lm  # noqa: F401
 from .glm_moe import glm_moe_lm  # noqa: F401
 from .afmoe import afmoe_lm  # noqa: F401
+from .lfm2_moe import lfm2_moe_lm  # noqa: F401
 from .looped import looped_lm  # noqa: F401

@@ -26,4 +26,5 @@ from . import recurrent_ops  # noqa: F401
 from . import rnn_ops  # noqa: F401
 from . import sequence_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
+from . import short_conv_ops  # noqa: F401
 from ..core.registry import registered_ops  # noqa: F401

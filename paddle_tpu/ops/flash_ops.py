@@ -198,9 +198,11 @@ _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _TN = (((0,), (0,)), ((), ()))   # a.T @ b
 _LANES = 128
 # one fused backward pass keeps dQ for a whole sequence in VMEM (float32
-# accumulator and the output block: 10 bytes a row and lane); beyond this
-# many query rows dQ gets a pass of its own
-_FUSED_BWD_MAX_TQ = 8192
+# accumulator and the output block: 10 bytes a row and lane of a lane block);
+# beyond this many (query row, lane) elements dQ gets a pass of its own. 20
+# MiB: 8192 rows of a 256-lane block (latent attention's heads), 16 384 rows of
+# a 128-lane one (heads of 64 and 128)
+_FUSED_BWD_MAX_ELEMENTS = 8192 * 256
 
 
 def _head_lanes(width: int, D: int):
@@ -731,7 +733,8 @@ def _packed_attention_bwd(heads, causal, window, saved, do):
     return _packed_backward(
         q, k, v, o, lse, do, heads=heads, causal=causal, window=window,
         blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
-        fused=q.shape[1] <= _FUSED_BWD_MAX_TQ)
+        fused=q.shape[1] * max(_LANES, q.shape[2] // heads)
+        <= _FUSED_BWD_MAX_ELEMENTS)
 
 
 _packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)

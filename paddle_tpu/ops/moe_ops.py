@@ -270,15 +270,16 @@ def _summed_chunks(chunk, count, shape):
 
 
 def route(x, router_w, top_k: int, norm_topk_prob: bool,
-          scoring: str = "softmax", bias=None, gate_scale: float = 1.0):
+          scoring: str = "softmax", bias=None, gate_scale: float = 1.0,
+          gate_norm_eps: float = 0.0):
     """The router, in float32 whatever the activations' dtype: logits
     [T, E] (matmul at the highest precision: the TPU's default would round
     both inputs to bf16), then the scores and the top-k. `scoring`
     "softmax": the k largest probabilities are the gates. "sigmoid"
     (DeepSeek-V3's): s = sigmoid(logits), the CHOICE is the top k of s +
     `bias` ([E], no gradient), the gates are s of the chosen. Gates are
-    divided by their sum with `norm_topk_prob` and multiplied by
-    `gate_scale`. Returns (logits, gates [T, k], experts [T, k] int32)."""
+    divided by their sum (+ `gate_norm_eps`) with `norm_topk_prob` and
+    multiplied by `gate_scale`. Returns (logits, gates [T, k], experts [T, k] int32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax":
@@ -292,7 +293,8 @@ def route(x, router_w, top_k: int, norm_topk_prob: bool,
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
     if norm_topk_prob:
-        gates = gates / gates.sum(-1, keepdims=True)
+        total = gates.sum(-1, keepdims=True)
+        gates = gates / (total + gate_norm_eps if gate_norm_eps else total)
     if gate_scale != 1.0:
         gates = gates * gate_scale
     return logits, gates, experts.astype(jnp.int32)
@@ -306,7 +308,7 @@ def relu2(x):
 def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
             norm_topk_prob: bool = False, *, scoring: str = "softmax",
             router_bias=None, gate_scale: float = 1.0, held=None,
-            shared=None, chunk_shares=None):
+            shared=None, chunk_shares=None, gate_norm_eps: float = 0.0):
     """x [T, d] -> (out [T, d], router logits [T, E] f32, tokens per expert
     [E] int32, pairs per held expert [held] int32 or None, the row path [2]
     int32 or None). y = sum_j gate_j * expert_{e_j}(x) over the token's
@@ -347,7 +349,8 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     rows = T * top_k
     with jax.named_scope("route"):
         logits, gates, experts = route(x, router_w, top_k, norm_topk_prob,
-                                       scoring, router_bias, gate_scale)
+                                       scoring, router_bias, gate_scale,
+                                       gate_norm_eps)
     with jax.named_scope("dispatch"):
         flat = experts.reshape(-1)                       # [T*k]
         # an absent expert's pairs sort behind every held one's
@@ -479,8 +482,8 @@ def moe_ffn_kernel(ctx):
     SwiGLU experts, SharedGateW [d, f_s] (the shared expert is of the routed
     experts' kind: `expert_act`). Attrs: top_k,
     norm_topk_prob, and where they differ from a softmax router over experts
-    that are all here: scoring, gate_scale, held_lo / held_hi, and
-    chunk_shares (absent: two even shares of the rows a chunk). Out shaped
+    that are all here: scoring, gate_scale, gate_norm_eps (added to the sum
+    the chosen gates are divided by), held_lo / held_hi, and chunk_shares (absent: two even shares of the rows a chunk). Out shaped
     like X, in the compute dtype; RouterLogits [tokens, E] float32 (under
     amp too: the router never drops precision, the expert matmuls do);
     TokensPerExpert [E] int32, summing to tokens x top_k; HeldPairs [held]
@@ -504,7 +507,8 @@ def moe_ffn_kernel(ctx):
         scoring=ctx.attr("scoring", "softmax"),
         router_bias=ctx.input("RouterBias"),
         gate_scale=float(ctx.attr("gate_scale", 1.0)), held=held,
-        shared=shared, chunk_shares=ctx.attr("chunk_shares"))
+        shared=shared, chunk_shares=ctx.attr("chunk_shares"),
+        gate_norm_eps=float(ctx.attr("gate_norm_eps", 0.0)))
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("RouterLogits", logits)
     ctx.set_output("TokensPerExpert", counts)

@@ -258,7 +258,7 @@ def test_attn_device_ms_is_in_the_manifest_for_every_cell():
     wrapped = {m["name"] for m in manifest["per_layer"]
                if m["name"].endswith(".attn_device_ms")}
     assert wrapped == {"nemotron.attn_device_ms", "glm.attn_device_ms",
-                       "trinity.attn_device_ms",
+                       "trinity.attn_device_ms", "lfm2.attn_device_ms",
                        # PR 44: a reader of its own (the rows are inside a
                        # loop's body, where the scope is the loop's)
                        "ouro.attn_device_ms"}
@@ -1799,13 +1799,12 @@ def test_the_manifest_lists_the_ouro_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell = OURO + ".train-log10"
-    assert manifest["workloads"][-1]["name"] == cell     # appended, last
-    entry = manifest["workloads"][-1]
+    assert manifest["workloads"][6]["name"] == cell      # appended in PR 44
+    entry = manifest["workloads"][6]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         OURO, "train-log10", 1)
-    assert len(manifest["workloads"]) == 7 and len(manifest["configs"]) == 6
-    assert sum(w["chips"] for w in manifest["workloads"]) == 7   # no 4-chip
-    config = manifest["configs"][-1]
+    assert sum(w["chips"] for w in manifest["workloads"][:7]) == 7   # no 4-chip
+    config = manifest["configs"][5]
     assert config["name"] == OURO and config["source"] == _ouro_config()["source"]
     assert config["reduced"] == _ouro_config()["reduced"] == [
         "num_hidden_layers", "layer_types"]
@@ -1819,7 +1818,8 @@ def test_the_manifest_lists_the_ouro_cell_and_its_metrics():
     names = [m["name"] for m in manifest["per_layer"]]
     end = names.index(mine[-1]) + 1                      # appended in PR 44
     assert names[end - len(mine):end] == mine
-    assert names[end:] == ["attn.masked_pair_share"]     # PR 46's, every cell
+    # PR 46's, every cell; PR 49 appended its own cell's behind it
+    assert names[end] == "attn.masked_pair_share"
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     assert {by_name[n]["layer"] for n in mine[:3]} == {"Looped stack"}
     assert by_name["repeat.saved_gib"]["moves"] == "peak_hbm_gib" \
@@ -1957,7 +1957,8 @@ def test_masked_pair_share_reads_the_ops_pairs_gauge(registry, share):
 def test_the_manifest_lists_the_masked_pair_share_for_every_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    entry = manifest["per_layer"][-1]
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "attn.masked_pair_share")
     assert entry == {"name": "attn.masked_pair_share", "unit": "ratio",
                      "better": "lower", "source": "program_counter",
                      "layer": "Kernels", "moves": "items_s"}
@@ -1992,3 +1993,433 @@ def test_the_traced_op_publishes_the_pairs_the_reader_reads():
     share = reader.compute({"registry": train.registry_delta(after, after)})
     kept, computed = (after[PAIRS % (k, "xla")][1] for k in ("kept", "computed"))
     assert share == pytest.approx(1 - kept / computed)
+
+
+# PR 49: lfm2-24b-a2b (gated short-convolution operators and one attention
+# layer in five, a sigmoid top-4-of-64 router with a choice bias)
+LFM2 = "lfm2-24b-a2b"
+LFM2_CELL = {"batch": 1, "seqlen": 16384}
+C_, A_ = "conv", "full_attention"
+
+
+def _lfm2_config():
+    with open(os.path.join(BENCH, "configs", LFM2, "config.json")) as f:
+        return json.load(f)
+
+
+def test_lfm2_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _lfm2_config()
+    config_dir = os.path.join(BENCH, "configs", LFM2)
+    got = flops.train_flops_per_item(cfg, LFM2_CELL, config_dir)
+    assert got == 3 * 439357440.0 == 1318072320.0
+    own = _load("configs", LFM2, "flops.py")
+    # an operator is its two projections; the attention layer its four and
+    # the kernels over the (T + 1) / 2 keys a query sees
+    assert own.operator_flops_per_token(cfg, 16384, C_) == 33554432
+    assert own.operator_flops_per_token(cfg, 16384, A_) == 20971520 + 67112960
+    with pytest.raises(ValueError, match="unknown kind"):
+        own.operator_flops_per_token(cfg, 16384, "sliding_attention")
+    # the shares ISSUE 49 states: conv 30 %, dense MLP 33 %, attention 20 %,
+    # head 8 %, held experts 9 %
+    whole = 439357440.0
+    assert round(100 * 4 * 33554432 / whole) == 31
+    assert round(100 * 6 * 2048 * 11776 / whole) == 33
+    assert round(100 * (20971520 + 67112960) / whole) == 20
+    assert round(100 * 2 * 2048 * 8192 / whole) == 8
+    assert round(100 * 4 * (262144 + 9437184) / whole) == 9
+    # one more routed conv layer adds an operator, a router and half a pair
+    more = own.forward_flops_per_token(
+        dict(cfg, layer_types=cfg["layer_types"] + [C_]), 16384)
+    assert more - whole == 33554432 + 262144 + 9437184
+    wider = own.forward_flops_per_token(dict(cfg, held_experts=[0, 16]), 16384)
+    assert wider - whole == 4 * 9437184
+    # at T 8192 (the cell's fallback) only the kernels' part moves
+    short = own.forward_flops_per_token(cfg, 8192)
+    assert whole - short == 4 * 32 * 64 * 8192 / 2
+
+
+def test_lfm2_config_keeps_the_published_sizes():
+    cfg = _lfm2_config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        published = next(e for e in map(json.loads, f)
+                         if e["name"] == "LFM2-24B-A2B")
+    assert cfg["source"] == published["source_url"]
+    differs = [k for k, v in published["config"].items()
+               if cfg.get(k, "absent") != v]
+    assert sorted(differs) == sorted(cfg["reduced"]) == sorted(
+        ["num_hidden_layers", "layer_types", "num_dense_layers",
+         "num_experts", "vocab_size"])
+    assert cfg["published"] == {k: published["config"][k] for k in differs}
+    assert cfg["rope_parameters"] == published["config"]["rope_parameters"]
+    # the cut: published layers 1-5 (one dense layer, four routed ones, one
+    # whole period 3 conv : 1 attention among the routed), 8 of 64 experts
+    # behind a router that stays 64 wide, an eighth of the vocabulary
+    assert cfg["layer_types"] == published["config"]["layer_types"][1:6] \
+        == [C_, A_, C_, C_, C_]
+    assert cfg["num_hidden_layers"] == 5 and cfg["num_dense_layers"] == 1
+    assert cfg["layer_types"][1:].count(C_) == 3 * cfg["layer_types"][1:].count(A_)
+    assert cfg["vocab_size"] * 8 == 65536
+    assert cfg["router_experts"] == 64 and cfg["held_experts"] == [0, 8]
+    # no width is cut
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["conv_L_cache"],
+            cfg["intermediate_size"], cfg["moe_intermediate_size"],
+            cfg["num_experts_per_tok"], cfg["routed_scaling_factor"]) == (
+        2048, 32, 8, 3, 11776, 1536, 4, 1)
+    assert "8-chip" in cfg["deployment"] and "40" in cfg["distortion"]
+    for key in ("assumed", "departures", "deployment", "distortion",
+                "gradient_limits"):
+        assert cfg[key], key
+    for key in ("basis", "head_size", "stream", "conv_operator",
+                "attention_operator", "dense_ffn", "router", "aux_cost",
+                "optimizer", "compute_dtype", "initialisers"):
+        assert cfg["assumed"][key], key
+    assert any("UNTIED" in d for d in cfg["departures"])
+    # the parameters this chip holds: ISSUE 49's count
+    d = 2048
+    conv = d * 3 * d + 3 * d + d * d
+    attn = 2 * d * d + 2 * d * 512 + 2 * 64
+    experts = 8 * 3 * d * 1536
+    dense = conv + 3 * d * 11776 + 2 * d
+    attn_routed = attn + d * 64 + experts + 2 * d
+    conv_routed = conv + d * 64 + experts + 2 * d
+    assert (conv, attn, dense, attn_routed, conv_routed) == (
+        16783360, 10485888, 89139200, 86118528, 92416000)
+    assert dense + attn_routed + 3 * conv_routed + 2 * 8192 * d + d == 486062208
+
+
+def test_lfm2_kernels_count_on_hand_made_cells():
+    flash = _load("kernels", "lfm2_flash_attention.py")
+    plain = _load("kernels", "flash_attention.py")
+    cfg = {"layer_types": [C_, A_, C_, A_], "num_attention_heads": 4,
+           "num_key_value_heads": 2, "hidden_size": 32,
+           "num_hidden_layers": 4}
+    cell = {"batch": 2, "seqlen": 5}
+    assert flash.attention_layers(cfg) == 2
+    flops, bytes_ = flash.flops_and_bytes(cfg, cell)
+    # two attention layers of four; heads of 32 / 4 = 8; 15 causal pairs
+    assert flops == 2 * 2 * 4 * 6 * 2 * 15 * 8
+    assert bytes_ == 2 * 6 * (2 * 5 * 4 * 8 + 2 * 5 * 2 * 8) * 2
+    assert plain.flops_and_bytes(cfg, cell)[0] == 2 * flops   # every layer
+    got = flash.flops_and_bytes(_lfm2_config(), LFM2_CELL)
+    pairs = 16384 * 16385 // 2
+    assert got[0] == 32 * 12 * 64 * pairs                      # 3.30 TFLOP
+    assert got[1] == 6 * 16384 * (2048 + 512) * 2
+    assert got[0] / 197e12 > got[1] / 819e9                    # compute-bound
+    gmm = _load("kernels", "lfm2_grouped_matmul.py")
+    cfg = _lfm2_config()
+    d, f = 2048, 1536
+    flops, bytes_ = gmm.flops_and_bytes(cfg, LFM2_CELL)
+    rows = 4 * 16384 * 4 * 8 / 64           # even routing: 8 192 a layer
+    assert gmm.routed_layers(cfg) == 4 and rows == 32768
+    assert flops == 18 * rows * d * f
+    assert bytes_ == 2 * (9 * 4 * 8 * d * f + rows * (5 * d + 7 * f))
+    assert gmm.flops_and_bytes(cfg, LFM2_CELL, rows=100.0) == (
+        18 * 100.0 * d * f, 2 * (9 * 4 * 8 * d * f + 100.0 * (5 * d + 7 * f)))
+
+
+LFM2_MOE = "moe_ffn.lfm2.h1.moe.tmp_40"
+LFM2_CONV = ("short_conv_operator.lfm2.h0.conv.tmp_3",
+             "short_conv_operator.lfm2.h2.conv.tmp_50")
+LFM2_ATTN = "flash_attention.lfm2.h1.attn.tmp_30"
+
+
+def _lfm2_run_record():
+    """Two steps of two operators, the attention layer and one routed
+    layer."""
+    first, second = LFM2_CONV
+    ops = [
+        _row(first, 6_000_000, None, "jvp(", "in_proj"),
+        _row(first, 1_000_000, "tpu_custom_call", "jvp(", "mix"),
+        _row(first, 2_000_000, None, "jvp(", "out_proj"),
+        _row(first, 12_000_000, None, "transpose(jvp(", "in_proj"),
+        _row(first, 2_000_000, "tpu_custom_call", "transpose(jvp(", "mix"),
+        _row(first, 400_000, None, "transpose(jvp(", "mix"),   # dw's sum
+        _row(first, 4_000_000, None, "transpose(jvp(", "out_proj"),
+        _row(second, 1_000_000, "tpu_custom_call", "jvp(", "mix"),
+        _row(second, 2_000_000, "tpu_custom_call", "transpose(jvp(", "mix"),
+        _row(second, 30_000_000, None, "", container=True),    # a loop: out
+        _row(LFM2_ATTN, 10_000_000, "tpu_custom_call", "jvp("),
+        _row(LFM2_ATTN, 30_000_000, "tpu_custom_call", "transpose(jvp("),
+        _row(LFM2_ATTN, 1_000_000, None, "transpose(jvp("),    # dK, dV sums
+        _row(LFM2_MOE, 4_000_000, "tpu_custom_call", "", "experts"),
+        _row(LFM2_MOE, 2_000_000, None, "", "dispatch"),
+        _row(LFM2_MOE, 3_000_000, None, "", "combine"),
+        _row("mul.fc_9.tmp_9", 5_000_000),        # the head: no part of it
+        _row("rms_norm.l0n1", 700_000),           # a stream norm: none
+    ]
+    held = 'pt_moe_held_pairs_total{expert="%d",layer="lfm2.h1.moe"}'
+    every = 'pt_moe_expert_tokens_total{expert="%d",layer="lfm2.h1.moe"}'
+    path = 'pt_moe_row_path_total{layer="lfm2.h1.moe",path="%d"}'
+    registry = {held % 0: 8000.0, held % 1: 8384.0,
+                every % 0: 8000.0, every % 1: 8384.0, every % 60: 114688.0,
+                path % 0: 2.0, "pt_executor_donated_bytes": 5.83e9,
+                'pt_short_conv_dispatch_total{path="pallas"}': 0.0,
+                "pt_short_conv_bytes": 2 * 738226176.0}
+    program_ops = [
+        {"type": "short_conv_operator", "scope": first,
+         "inputs": {"X": ["h0"]}, "outputs": {"Out": ["lfm2.h0.conv.tmp_3"]}},
+        {"type": "flash_attention", "scope": LFM2_ATTN,
+         "inputs": {"Q": ["q"], "K": ["k"], "V": ["v"]},
+         "outputs": {"Out": ["lfm2.h1.attn.tmp_30"]}},
+        {"type": "moe_ffn", "scope": LFM2_MOE, "inputs": {"X": ["h2"]},
+         "outputs": {"Out": ["lfm2.h1.moe.tmp_40"]}},
+        {"type": "short_conv_operator", "scope": second,
+         "inputs": {"X": ["h3"]}, "outputs": {"Out": ["lfm2.h2.conv.tmp_50"]}},
+        {"type": "mul", "scope": "mul.fc_9.tmp_9",
+         "inputs": {"X": ["hf"], "Y": ["w"]}, "outputs": {"Out": ["l"]}}]
+    return {"steps": 2, "trace": {"ops": ops}, "registry": registry,
+            "device": {"kind": "TPU v5 lite"}, "config": _lfm2_config(),
+            "cell": dict(LFM2_CELL), "program_ops": program_ops}
+
+
+def test_conv_readers_find_the_operators_rows_by_op_type_and_inner_scope():
+    run = _lfm2_run_record()
+    device = _load("layer_metrics", "conv.device_ms.py")
+    # every leaf row under an operator's scope, forward and backward; not
+    # the loop, not the attention layer's, the routed layer's or the head's
+    assert device.compute(run) == pytest.approx(30.4 / 2)
+    info = device.info(run)
+    assert info["operators"] == 2
+    assert info["by_inner_scope_ms"] == pytest.approx(
+        {"in_proj": 9.0, "mix": 3.2, "out_proj": 3.0})
+    assert info["by_pass_ms"] == pytest.approx(
+        {"jvp": 5.0, "transpose": 10.2})
+    mix = _load("layer_metrics", "conv.mix_ms.py")
+    assert mix.compute(run) == pytest.approx(6.4 / 2)
+    info = mix.info(run)
+    assert info["run_by"] == "kernels" and info["kernels_ms"] == pytest.approx(3.0)
+    assert info["by_pass_ms"] == pytest.approx({"jvp": 1.0, "transpose": 2.2})
+    assert info["bytes_per_step"] == 2 * 738226176.0
+    assert list(info["dispatch"]) == [
+        'pt_short_conv_dispatch_total{path="pallas"}']
+    # the kernels' share of their roofline: two operators' operands and
+    # results (11 T d bf16 elements each and the float32 taps) at 819 GB/s
+    # over the 3.2 ms a step under `mix`; memory-bound
+    roof = _load("layer_metrics", "kernel.short_conv_roofline.py")
+    need = _load("kernels", "gated_short_conv.py")
+    flops, bytes_ = need.flops_and_bytes(run["config"], run["cell"])
+    assert need.operators(run["config"]) == 4
+    assert bytes_ == 4 * (11 * 16384 * 2048 * 2 + 12 * 3 * 2048) == 2953084928
+    assert flops == 4 * 16384 * 2048 * 24
+    assert roof.compute(run) == pytest.approx(
+        100 * bytes_ / 819e9 / 3.2e-3, rel=1e-3)
+    assert roof.info(run)["bound"] == "memory"
+    assert roof.info(run)["program_counted_bytes"] == 2 * 738226176.0
+    # XLA's plain form: the same rows with no kernel among them, and no
+    # boundary to count bytes at: the share is left out
+    for r in run["trace"]["ops"]:
+        if r["scope"] in LFM2_CONV:
+            r["target"] = None
+    assert mix.info(run)["run_by"] == "xla"
+    assert roof.compute(run) is None
+    # a Program without the op (every other configuration, the parent of the
+    # PR that added it), or no trace: nothing, not raised
+    for empty in (dict(run, trace=None), dict(run, program_ops=None),
+                  dict(run, program_ops=_glm_run_record()["program_ops"]),
+                  dict(run, program_ops=_nemo_run_record()["program_ops"])):
+        assert device.compute(empty) is None and mix.compute(empty) is None
+        assert roof.compute(empty) is None
+
+
+def test_lfm2_rooflines_on_a_hand_made_run_record():
+    run = _lfm2_run_record()
+    flash = _load("layer_metrics", "lfm2.flash_roofline.py")
+    # one layer's 3.30 TFLOP at 197 TFLOP/s over the kernels' 20 ms a step
+    need = 32 * 12 * 64 * (16384 * 16385 // 2) / 197e12
+    assert flash.compute(run) == pytest.approx(100 * need / 20e-3, rel=1e-3)
+    info = flash.info(run)
+    assert info["kernels_per_step"] == 2.0 and info["bound"] == "compute"
+    assert flash.compute(dict(run, trace=None)) is None
+    gmm = _load("layer_metrics", "lfm2.gmm_roofline.py")
+    d, f = 2048, 1536                       # 2 steps, 16 384 held pairs
+    want_bytes = 2 * (9 * 4 * 8 * d * f + 8192.0 * (5 * d + 7 * f))
+    assert gmm.info(run)["flops_per_step"] == 18 * 8192.0 * d * f
+    assert gmm.info(run)["bytes_per_step"] == want_bytes
+    assert gmm.info(run)["held_pairs_per_step"] == 8192.0
+    assert gmm.compute(run) == pytest.approx(
+        100 * want_bytes / 819e9 / 2e-3, rel=1e-3)    # the kernels' 2 ms
+    assert gmm.info(run)["bound"] == "memory"
+    no_held = {k: v for k, v in run["registry"].items()
+               if "held_pairs" not in k}
+    assert gmm.compute(dict(run, registry=no_held)) is None
+    assert gmm.compute(dict(run, trace=None)) is None
+
+
+LFM2_WRAPPERS = {"lfm2.head_device_ms": "head.device_ms",
+                 "lfm2.feed_produce_ms_per_step": "feed.produce_ms_per_step",
+                 "lfm2.opt_device_ms": "opt.device_ms",
+                 "lfm2.donated_gib": "step.donated_gib",
+                 "lfm2.attn_device_ms": "attn.device_ms",
+                 "lfm2.held_pair_share": "moe.held_pair_share",
+                 "lfm2.bounded_step_share": "moe.bounded_step_share",
+                 "lfm2.moe_device_ms": "nemotron.moe_device_ms",
+                 "lfm2.moe_dispatch_ms": "nemotron.moe_dispatch_ms",
+                 "lfm2.load_max_over_mean": "nemotron.load_max_over_mean"}
+
+
+@pytest.mark.parametrize("name", sorted(LFM2_WRAPPERS))
+def test_an_lfm2_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run = _lfm2_run_record()                # 2 steps x 16 384 tokens x 4 pairs
+    run["program_ops"] += [
+        {"type": "adam", "scope": "adam.w", "inputs": {"Param": ["w"]},
+         "outputs": {"ParamOut": ["w"]}},
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.s",
+         "inputs": {"Logits": ["l"], "Label": ["y"]},
+         "outputs": {"Softmax": ["s"], "Loss": ["c"]}}]
+    run["trace"]["ops"].append(_row("adam.w", 2_000_000))
+    run["timers_s"] = {"prefetch.read": 0.004, "prefetch.batch": 0.002}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", LFM2_WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == LFM2_WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    if hasattr(wrapper, "info"):
+        assert wrapper.info(run) == wrapped.info(run)
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+    expected = {"lfm2.held_pair_share": 16384 / 131072,          # 0.125
+                "lfm2.bounded_step_share": 1.0,
+                "lfm2.load_max_over_mean": 114688 / 2048,
+                "lfm2.moe_device_ms": 4.5,
+                "lfm2.moe_dispatch_ms": 2.5,
+                "lfm2.attn_device_ms": 20.5}
+    if name in expected:
+        assert got == pytest.approx(expected[name])
+
+
+def test_the_manifest_lists_the_lfm2_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = LFM2 + ".train-log10"
+    assert manifest["workloads"][-1]["name"] == cell     # appended, last
+    entry = manifest["workloads"][-1]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        LFM2, "train-log10", 1)
+    assert len(manifest["workloads"]) == 8 and len(manifest["configs"]) == 7
+    assert sum(w["chips"] for w in manifest["workloads"]) == 8   # no 4-chip
+    config = manifest["configs"][-1]
+    assert config["name"] == LFM2 and config["source"] == _lfm2_config()["source"]
+    assert config["reduced"] == _lfm2_config()["reduced"] == [
+        "num_hidden_layers", "layer_types", "num_dense_layers", "num_experts",
+        "vocab_size"]
+    mine = [m["name"] for m in manifest["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine[:3] == ["conv.device_ms", "conv.mix_ms",
+                        "kernel.short_conv_roofline"]
+    assert sorted(mine[3:]) == sorted(
+        list(LFM2_WRAPPERS) + ["lfm2.flash_roofline", "lfm2.gmm_roofline"])
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(mine):] == mine                    # appended, last
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert {by_name[n]["layer"] for n in mine
+            if n.startswith(("conv.",))} == {"Short-conv operators"}
+    assert by_name["kernel.short_conv_roofline"]["layer"] == "Kernels"
+    assert by_name["lfm2.donated_gib"]["moves"] == "peak_hbm_gib"
+    assert {by_name[n]["moves"] for n in mine} == {"items_s", "peak_hbm_gib"}
+    assert {by_name[n]["unit"] for n in mine if "roofline" in n} == {"%"}
+    for name in mine:
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    # no reader that was there lists the new cell: their entries are untouched
+    assert not [m["name"] for m in manifest["per_layer"][:-len(mine)]
+                if cell in m.get("workloads", ())]
+    # no step tail: the cell reports items_s, peak_hbm_gib and setup_s
+    tail = next(m for m in manifest["end_to_end"] if m["name"] == "step_ms_p90")
+    assert cell not in tail["workloads"]
+    assert manifest["run_seconds"] == 36
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        traffic = json.load(f)
+    assert (traffic["batch"], traffic["seqlen"], traffic["sync_every"],
+            traffic["warmup_steps"], traffic["trace_seconds"]) == (
+        1, 16384, 10, 20, 4)
+    for why in (entry["why"], config["why"]):
+        assert len(why) <= 200 and "\n" not in why
+
+
+def test_the_benchmarks_lfm2_reference_is_the_trees_bit_for_bit():
+    """`chipbench/configs/lfm2-24b-a2b/reference.py` is a copy of
+    `tests/lfm2_moe_reference.py`, text for text, and gives the same cost,
+    gradients and routers to the bit on the CPU, its own choice or a handed
+    one: the two cannot drift apart unseen."""
+    import lfm2_moe_reference as tree
+
+    copy = _load("configs", LFM2, "reference.py")
+    assert open(copy.__file__).read() == open(tree.__file__).read()
+    cfg = dict(_lfm2_config(), **_lfm2_config()["rehearsal"])
+    d, V = cfg["hidden_size"], cfg["vocab_size"]
+    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    D, K = d // H, cfg["conv_L_cache"]
+    E, held, f = cfg["router_experts"], 4, cfg["moe_intermediate_size"]
+    operator = {C_: [(d, 3 * d), (K, d), (d, d)],
+                A_: [(d, H * D), (d, KV * D), (d, KV * D), (D,), (D,),
+                     (H * D, d)]}
+    ffn = {"dense": [(d, cfg["intermediate_size"])] * 2
+           + [(cfg["intermediate_size"], d)],
+           "routed": [(d, E), (held, d, f), (held, d, f), (held, f, d), (E,)]}
+    rng = np.random.RandomState(0)
+    shapes = [(V, d)] + [s for op, kind in zip(cfg["layer_types"],
+                                               tree._kinds(cfg))
+                         for s in [(d,)] + operator[op] + [(d,)] + ffn[kind]] \
+        + [(d,), (d, V)]
+    params = [(rng.randn(*s) * 0.2 + (len(s) == 1 and s != (E,))).astype(
+        np.float32) for s in shapes]
+    params = [np.zeros_like(p) if p.shape == (E,) else p for p in params]
+    toks = rng.randint(0, V, (2, 41))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    own = [m.loss_grads_and_routers(cfg, params, feed) for m in (tree, copy)]
+    choice = copy.chosen(cfg, params, [z for _, _, z in own[1][2]])
+    handed = [m.loss_grads_and_routers(cfg, params, feed, choice)
+              for m in (tree, copy)]
+    for (c1, g1, r1), (c2, g2, r2) in (own, handed):
+        assert float(c1) == float(c2) and np.isfinite(float(c1))
+        assert len(g1) == len(g2) == len(params) and len(r1) == len(r2) == 2
+        for a, b in zip(g1, g2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(r1, r2):
+            np.testing.assert_array_equal(np.asarray(a[2]), np.asarray(b[2]))
+    # the reference's own choice, handed back to it, is its own result
+    assert float(own[0][0]) == float(handed[0][0])
+    assert [int(m.sum()) for m in choice] == [2 * 40 * 2] * 2
+
+
+def test_the_lfm2_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference (handed the program's choice) at the rehearsal's tolerances,
+    the routed counters and the operators' byte gauge reach the run record,
+    every new metric file returns a number or nothing on the rehearsal's
+    run, and every metric's name carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         LFM2 + ".train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486149"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.lfm2.held_pair_share",
+            "REHEARSAL_ON_CPU.lfm2.load_max_over_mean",
+            "REHEARSAL_ON_CPU.lfm2.bounded_step_share",
+            "REHEARSAL_ON_CPU.lfm2.donated_gib",
+            "REHEARSAL_ON_CPU.lfm2.feed_produce_ms_per_step",
+            "REHEARSAL_ON_CPU.attn.masked_pair_share",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    # XLA:CPU has no device plane: the trace's readers return nothing
+    assert not {"REHEARSAL_ON_CPU.conv.device_ms",
+                "REHEARSAL_ON_CPU.conv.mix_ms"} & names
+    share = result["metrics"][
+        "REHEARSAL_ON_CPU.lfm2.held_pair_share"]["value"]
+    assert 0.1 < share < 0.5                 # 4 of 16 experts held: 0.25
+    assert "choice_counts_off_program" in result["compared"]
+    info = next(json.loads(line.split("info ", 1)[1])
+                for line in out.stdout.splitlines() if "] info {" in line)
+    assert info["cell"] == LFM2 + ".train-log10" and info["rehearsal"]

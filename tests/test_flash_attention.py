@@ -200,8 +200,10 @@ def test_packed_kernel_matches_oracle(B, Tq, Tk, H, D, causal, dtype):
 
 
 def test_packed_backward_split_matches_fused(monkeypatch):
-    """Beyond _FUSED_BWD_MAX_TQ query rows dQ gets a pass of its own: the
-    same gradients as the one fused pass."""
+    """Beyond _FUSED_BWD_MAX_ELEMENTS (query rows x a lane block's lanes) dQ
+    gets a pass of its own: the same gradients as the one fused pass. The
+    rule keeps every shape that ran fused before it (8192 rows of a 256-lane
+    block) and takes 16 384 rows of a 128-lane one."""
     from jax.experimental.pallas import tpu as pltpu
 
     from paddle_tpu.ops import flash_ops
@@ -209,13 +211,15 @@ def test_packed_backward_split_matches_fused(monkeypatch):
     q, k, v, w = _packed_case(1, 256, 384, 2, 64, jnp.float32, seed=4)
     grad = jax.grad(lambda q, k, v: jnp.sum(
         flash_ops._packed_attention(q, k, v, 2, False) * w), (0, 1, 2))
+    limit = flash_ops._FUSED_BWD_MAX_ELEMENTS
     with pltpu.force_tpu_interpret_mode():
         fused = grad(q, k, v)
-        monkeypatch.setattr(flash_ops, "_FUSED_BWD_MAX_TQ", 0)
+        monkeypatch.setattr(flash_ops, "_FUSED_BWD_MAX_ELEMENTS", 0)
         split = grad(q, k, v)
     for a, b in zip(fused, split):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+    assert 8192 * 256 <= limit and 16384 * 128 <= limit < 16384 * 256
 
 
 def test_bthd_entry_and_packed_entry_agree():

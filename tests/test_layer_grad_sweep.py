@@ -923,6 +923,14 @@ def build_mamba2_mixer():
 
 
 @case
+def build_short_conv_operator():
+    # seven tokens under three taps: the zeros before the sequence's start,
+    # every tap, both gates and the backward's own rule
+    h, feed = _pre_btd(7, 8)
+    return _scalar(L.short_conv_operator(h, kernel=3)), feed
+
+
+@case
 def build_moe_aux_loss():
     (out, logits, counts), feed = _moe()
     return L.elementwise_add(_scalar(out),

@@ -174,6 +174,25 @@ def _flash_gqa(case):
     return fwd_bwd, specs
 
 
+def _short_conv(case):
+    from paddle_tpu.ops import short_conv_ops
+
+    # an LFM2 operator's mix at the cell's shape: [1, 16384, 3 x 2048] bf16,
+    # 3 taps: blocks of 256 whole rows with 16 halo rows before (and, in the
+    # backward, behind), 512-lane tiles, chunks of 16 rows rolled along the
+    # sublanes
+    (B, T, d, K), with_bwd = case
+    specs = [((B, T, 3 * d), BF16), ((K, d), F32)]
+
+    def fwd(bcx, w):
+        return short_conv_ops._kernel_fwd(bcx, w)
+
+    def fwd_bwd(bcx, w):
+        return short_conv_ops._kernel_bwd(bcx, w, fwd(bcx, w))
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
 def _ssd_scan(case):
     from paddle_tpu.ops import ssm_ops
 
@@ -280,6 +299,13 @@ CASES = [
     ("gmm_fwd_bwd_olmoe_gate_up", _gmm, (2048, 1024)),
     ("gmm_fwd_bwd_olmoe_down", _gmm, (1024, 2048)),
     ("flash_fwd_bwd_nemotron_gqa_t8192", _flash_gqa, (1, 8192, 32, 2, 128)),
+    # LFM2's attention layer at the cell's T 16 384: 32 heads of 64 (K and V
+    # repeated to them), blocks of 1024, the fused backward with dQ's
+    # [16384, 128] float32 accumulator in VMEM
+    ("flash_fwd_bwd_lfm2_d64_t16384", _flash, ((1, 16384, 2048), 32, True)),
+    ("short_conv_fwd_lfm2_t16384", _short_conv, ((1, 16384, 2048, 3), False)),
+    ("short_conv_fwd_bwd_lfm2_t16384", _short_conv,
+     ((1, 16384, 2048, 3), True)),
     ("gmm_fwd_bwd_nemotron_share_up", _gmm_share, (2688, 1920)),
     ("gmm_fwd_bwd_nemotron_share_down", _gmm_share, (1920, 2688)),
     ("ssd_scan_fwd_nemotron_t8192", _ssd_scan, (False, False)),
@@ -415,9 +441,12 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
 
     import paddle_tpu as pt
     from paddle_tpu.core import executor as ex
-    from paddle_tpu.ops import flash_ops, moe_ops, qk_ops, ssm_ops
+    from paddle_tpu.ops import (flash_ops, moe_ops, qk_ops, short_conv_ops,
+                                ssm_ops)
 
     monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(short_conv_ops, "kernels_eligible",
+                        short_conv_ops._shapes_ok)
     monkeypatch.setattr(qk_ops, "kernels_eligible", qk_ops._shapes_ok)
     monkeypatch.setattr(
         flash_ops, "flash_eligible", lambda q, k=None, window=0: (
@@ -740,6 +769,41 @@ def test_looped_lm_step_program_fits_one_chip(one_chip, compiled_mode,
         memory.temp_size_in_bytes / 2**30))
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.0 * 2**30)
+
+
+def test_lfm2_step_program_fits_one_chip(one_chip, compiled_mode,
+                                         monkeypatch):
+    """The lfm2-24b-a2b cell's whole step (batch 1 x T 16 384, 486 M
+    parameters: published layers 1-5 of LFM2-24B-A2B, conv | attention, conv,
+    conv, conv, the first dense, 8 of 64 experts held) compiles for the
+    described v5e with arguments + temporaries under 14.5 GiB by the
+    compiler's own books (which settles T 16 384 against 8192 before any chip
+    time), ONE forward and one fused-backward attention launch at 32 heads of
+    64 over 16 k keys, the grouped-matmul kernels in it and no `ragged-dot`,
+    and no float32 array of the operators' [T, 6144] projection."""
+    raw, args = _step_program(
+        lambda: _benchmark_model("lfm2-24b-a2b", 1, 16384), 1, 16384,
+        one_chip, monkeypatch)
+    launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
+    # 16 384 query rows of a 128-lane block: dQ for the whole sequence still
+    # fits the fused backward's VMEM (`_FUSED_BWD_MAX_ELEMENTS`)
+    assert {n: c for n, c in launches.items() if n.startswith("flash")} == {
+        "flash_attention_fwd": 1, "flash_attention_bwd": 1}
+    # every operator's mix is one launch a direction
+    assert launches["gated_short_conv_fwd"] == 4
+    assert launches["gated_short_conv_bwd"] == 4
+    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_bwd" in text and "ragged-dot" not in text
+    assert "gmm" in text
+    assert "f32[1,16384,6144]" not in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 5.8e9          # 12 B a parameter
+    print("lfm2 step: arguments %.3f GiB, temporaries %.3f GiB" % (
+        memory.argument_size_in_bytes / 2**30,
+        memory.temp_size_in_bytes / 2**30))
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 14.5 * 2**30)
 
 
 _AFMOE_STEP = {}

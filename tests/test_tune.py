@@ -217,6 +217,8 @@ CELL_BLOCKS = {
     "glm-4.7-flash": (8192, 1024),
     "trinity-mini": (8192, 1024),
     "ouro-2.6b": (4096, 512),
+    # PR 49: the first cell at 16 k keys; the rule's 1024 x 1024 from 8192 up
+    "lfm2-24b-a2b": (16384, 1024),
 }
 
 
@@ -224,7 +226,7 @@ CELL_BLOCKS = {
 def test_cell_attention_blocks(name):
     """Every attention site of a benchmark cell's step program gets the
     blocks the parent gave it (there the table's row, here the rule):
-    512 x 512 at T 1024 and 4096, 1024 x 1024 at 8192."""
+    512 x 512 at T 1024 and 4096, 1024 x 1024 at 8192 and at 16 384."""
     from paddle_tpu.ops.flash_ops import FlashBlocks, _v5e_block_sizes
 
     assert sorted(CELL_BLOCKS) == sorted(
