@@ -57,10 +57,33 @@ dtype; the norm float32 with one rounding at its output), so on the chip the
 kernels' y is the einsums' to the bit and the gated kernels' output XLA's
 norm of it to the bit (one mixer at the hybrid's sizes, PR 43).
 
+The conv in front of the scan, with its bias and its `silu`
+(`causal_conv_silu`, counted in `pt_ssm_conv_dispatch_total{path}`), has
+the same two homes: `pallas`, one kernel a direction over the bf16 [B, T, C]
+array as the in-projection wrote it (`causal_conv_silu_fwd`,
+`causal_conv_silu_bwd`: a grid step is a block of rows of one 512-lane
+tile, float32 inside, one rounding out; no float32 array of that shape
+reaches HBM where XLA's form wrote a padded copy, four shifted windows and
+the sum `silu`'s derivative read back: 24.0 ms of the hybrid's 246 ms step,
+PERF.md section 6, PR 50), taken on the TPU backend, outside a mesh, for
+bf16 rows in whole blocks of 1024 and lanes in whole tiles of 512
+(`_shapes_conv_ok`); `xla`, `silu(causal_depthwise_conv)` with that
+function's own one-pass backward, every other case, exactly: the CPU, a
+mesh, float32, odd shapes. The kernels' values are the XLA form's float32
+arithmetic in the same order, rounded once.
+
 What the backward keeps and recomputes: `mamba2_mixer` puts conv, scan and
 gated norm under one `jax.checkpoint`, so across the step it keeps z, xBC,
-dt and the small per-head vectors and nothing of the scan. Inside the
-checkpoint's backward the differentiated forward kernel runs once more and
+dt and the small per-head vectors and nothing of the conv or the scan.
+Inside the checkpoint's backward the conv's forward kernel runs once more
+(the scan's backward kernel reads the activated xBC; keeping it instead
+would hold 0.39 GiB more over four mixers), and the conv's backward kernel
+reads xBC and the cotangent once, forms the pre-activation again in
+registers, and writes dx once and the taps' and the bias's gradients as
+float32 partial sums a grid step; the cotangent it reads is the scan
+backward's dx, dB and dC as three operands, a lane tile the one it lies in,
+so their concatenation is never written. The differentiated forward scan
+kernel runs once more too and
 also writes the state each chunk STARTS from ([T / Q, H P, N] float32, 134
 MB a mixer at T 8192, one transient array that lives until the backward
 kernel has read it); the backward kernel recomputes a chunk's [Q, Q] blocks
@@ -87,6 +110,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import amp
 from ..core.registry import register_op
+from .short_conv_ops import (_CARRY, _CHUNK, _HALO, _back, _chunk_rows, _f32,
+                             _forth)
 
 CHUNK = 128
 _LANES = 128
@@ -753,6 +778,262 @@ def _conv_bwd(saved, g):
 causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
+# ---- the conv, its bias and its silu as kernels --------------------------
+# One pass a direction over [B, T, C] as the in-projection wrote it. Nothing
+# crosses lanes here, so a grid step is one (batch, block of `_CONV_ROWS`
+# rows, tile of `_CONV_LANES` lanes), with the `_HALO` rows on the side the
+# taps reach to (ops/short_conv_ops.py's scheme and its row-window helpers):
+# the rows BEFORE the block for x (forward, and again backward), the rows
+# AFTER it for x and the cotangent (backward). Inside, rows in chunks of
+# `_CHUNK`. The forward walks them up and carries the last `_CARRY` rows of
+# x; the backward walks them down and carries the first `_CARRY` rows of
+# dpre = dy silu'(pre) of the chunk behind, so the pre-activation is formed
+# once a row, in registers. dw and db leave a grid step as partial sums over
+# its rows, eight sublanes a tap (the bias the K-th); XLA adds them up.
+_CONV_ROWS = 1024
+_CONV_LANES = 512
+_CONV_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel"),
+    vmem_limit_bytes=48 * 1024 * 1024)
+_ALL = slice(None)
+
+
+def _shapes_conv_ok(x, w) -> bool:
+    """Backend-independent: bf16 rows the blocks divide, lanes the tiles
+    divide, taps the carried rows reach."""
+    T, C = x.shape[1:]
+    return (x.dtype == jnp.bfloat16 and T % _CONV_ROWS == 0
+            and C % _CONV_LANES == 0 and 1 <= w.shape[0] <= _CARRY + 1)
+
+
+def conv_kernels_eligible(x, w) -> bool:
+    """The kernels take the conv where they take the scan: the TPU backend,
+    outside a mesh, at shapes `_shapes_conv_ok` admits."""
+    from . import mesh_dispatch
+
+    return (_on_tpu() and mesh_dispatch.current() is None
+            and _shapes_conv_ok(x, w))
+
+
+def _pre_windows(ext, w_ref, b_ref, K: int):
+    """ext = [carried rows | chunk] of x, float32 -> (the chunk's
+    pre-activation b + sum_k w[k] x_{t - (K - 1) + k}, summed in
+    `causal_depthwise_conv`'s order, and x as each tap reads it)."""
+    back = [_back(ext, K - 1 - k) for k in range(K)]
+    pre = b_ref[...]
+    for k in range(K):
+        pre = pre + back[k] * w_ref[k:k + 1, :]
+    return pre, back
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, b_ref, o_ref, *, K: int):
+    carried = jnp.where(pl.program_id(1) == 0, 0.0, _f32(
+        before_ref, pl.ds(_HALO - _CARRY, _CARRY), _ALL))
+
+    def chunk(r, carried):
+        rows = _chunk_rows(r)
+        x = _f32(x_ref, rows, _ALL)
+        pre, _ = _pre_windows(jnp.concatenate([carried, x], axis=0), w_ref,
+                              b_ref, K)
+        o_ref[0, rows, :] = (pre * jax.nn.sigmoid(pre)).astype(o_ref.dtype)
+        return x[_CHUNK - _CARRY:]
+
+    jax.lax.fori_loop(0, x_ref.shape[1] // _CHUNK, chunk, carried)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, w_ref, b_ref, *rest,
+                     K: int, starts: tuple):
+    """`starts`: the lane tile each of the cotangent's parts starts at; the
+    parts' blocks and their rows behind the block follow the five operands,
+    then dx's block and the partial sums'."""
+    n = len(starts)
+    dy_refs, dy_after_refs, (dx_ref, sums_ref) = (
+        rest[:n], rest[n:2 * n], rest[2 * n:])
+    tile = pl.program_id(2)
+    chunks = x_ref.shape[1] // _CHUNK
+
+    def cotangent(refs, rows):          # of the part this lane tile lies in
+        g = _f32(refs[0], rows, _ALL)
+        for ref, start in zip(refs[1:], starts[1:]):
+            g = jnp.where(tile >= start, _f32(ref, rows, _ALL), g)
+        return g
+
+    def dpre_of(ext, g):
+        pre, back = _pre_windows(ext, w_ref, b_ref, K)
+        s = jax.nn.sigmoid(pre)
+        return g * (s * (1.0 + pre * (1.0 - s))), back
+
+    # dpre of the rows behind the block, zeros behind the sequence
+    ahead = pl.ds(0, _HALO)
+    behind, _ = dpre_of(
+        jnp.concatenate([_f32(x_ref, _chunk_rows(chunks - 1), _ALL)[
+            _CHUNK - _CARRY:], _f32(after_ref, ahead, _ALL)], axis=0),
+        cotangent(dy_after_refs, ahead))
+    behind = jnp.where(pl.program_id(1) == pl.num_programs(1) - 1, 0.0,
+                       behind[:_CARRY])
+    front = jnp.where(pl.program_id(1) == 0, 0.0, _f32(
+        before_ref, pl.ds(_HALO - _CARRY, _CARRY), _ALL))
+
+    def chunk(step, carry):
+        behind, sums = carry
+        r = chunks - 1 - step
+        rows = _chunk_rows(r)
+        # the rows in front of the chunk: the chunk before it, or the halo
+        inside = _f32(x_ref, _chunk_rows(jnp.maximum(r - 1, 0)), _ALL)[
+            _CHUNK - _CARRY:]
+        ext = jnp.concatenate([jnp.where(r == 0, front, inside),
+                               _f32(x_ref, rows, _ALL)], axis=0)
+        dpre, back = dpre_of(ext, cotangent(dy_refs, rows))
+        ext = jnp.concatenate([dpre, behind], axis=0)
+        dx = _forth(ext, K - 1) * w_ref[0:1, :]
+        for k in range(1, K):
+            dx = dx + _forth(ext, K - 1 - k) * w_ref[k:k + 1, :]
+        dx_ref[0, rows, :] = dx.astype(dx_ref.dtype)
+        sums = tuple(acc + sum(jnp.split(product, _CHUNK // 8, axis=0))
+                     for acc, product in zip(
+                         sums, (*[dpre * seen for seen in back], dpre)))
+        return dpre[:_CARRY], sums
+
+    zeros = tuple(jnp.zeros((8, x_ref.shape[2]), jnp.float32)
+                  for _ in range(K + 1))
+    _, sums = jax.lax.fori_loop(0, chunks, chunk, (behind, zeros))
+    for k in range(K + 1):
+        sums_ref[0, k] = sums[k]
+
+
+def _conv_specs(T: int):
+    """BlockSpec makers over a [B, T, width] array for a grid (batch, row
+    block, lane tile): a block, the halo in front of it, the halo behind
+    it; `lane` maps the grid's lane tile to the array's."""
+    per = _CONV_ROWS // _HALO
+
+    def block(lane=lambda j: j):
+        return pl.BlockSpec((1, _CONV_ROWS, _CONV_LANES),
+                            lambda b, i, j: (b, i, lane(j)))
+
+    def before(lane=lambda j: j):
+        return pl.BlockSpec((1, _HALO, _CONV_LANES), lambda b, i, j: (
+            b, jnp.maximum(i * per - 1, 0), lane(j)))
+
+    def after(lane=lambda j: j):
+        return pl.BlockSpec((1, _HALO, _CONV_LANES), lambda b, i, j: (
+            b, jnp.minimum((i + 1) * per, T // _HALO - 1), lane(j)))
+
+    return block, before, after
+
+
+def _taps_specs(K: int):
+    return [pl.BlockSpec((K, _CONV_LANES), lambda b, i, j: (0, j)),
+            pl.BlockSpec((1, _CONV_LANES), lambda b, i, j: (0, j))]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _conv_silu_forward(x, w, b, interpret=False):
+    Bsz, T, C = x.shape
+    K = w.shape[0]
+    block, before, _ = _conv_specs(T)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, K=K),
+        grid=(Bsz, T // _CONV_ROWS, C // _CONV_LANES),
+        in_specs=[block(), before(), *_taps_specs(K)],
+        out_specs=block(),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_CONV_PARAMS, interpret=interpret,
+        name="causal_conv_silu_fwd",
+    )(x, x, w.astype(jnp.float32), b.astype(jnp.float32)[None, :])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _conv_silu_backward(x, w, b, dys, interpret=False):
+    """(dx in x's dtype, dw [K, C] and db [C] float32) given the cotangent
+    of the activated array as the tuple of its parts along the lanes (one
+    part: the whole)."""
+    Bsz, T, C = x.shape
+    K = w.shape[0]
+    blocks = T // _CONV_ROWS
+    block, before, after = _conv_specs(T)
+    tiles = [dy.shape[2] // _CONV_LANES for dy in dys]
+    starts = tuple(sum(tiles[:n]) for n in range(len(tiles)))
+
+    def part(spec, start, n):
+        # a tile outside the part holds the block of the part's nearest
+        # tile, which the pipeline fetches once a row block either way
+        return spec(lambda j: jnp.clip(j - start, 0, n - 1))
+
+    parts = list(zip(starts, tiles))
+    dx, sums = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, K=K, starts=starts),
+        grid=(Bsz, blocks, C // _CONV_LANES),
+        in_specs=[block(), before(), after(), *_taps_specs(K),
+                  *[part(block, *p) for p in parts],
+                  *[part(after, *p) for p in parts]],
+        out_specs=[block(),
+                   pl.BlockSpec((1, K + 1, 8, _CONV_LANES),
+                                lambda b, i, j: (b * blocks + i, 0, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((Bsz * blocks, K + 1, 8, C),
+                                        jnp.float32)],
+        compiler_params=_CONV_PARAMS, interpret=interpret,
+        name="causal_conv_silu_bwd",
+    )(x, x, x, w.astype(jnp.float32), b.astype(jnp.float32)[None, :],
+      *dys, *dys)
+    sums = jnp.sum(sums, axis=(0, 2))
+    return dx, sums[:K].astype(w.dtype), sums[K].astype(b.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu_kernels(x, w, b, parts: tuple):
+    """The kernels, no dispatch gate. `parts`: the widths along the lanes at
+    which the backward takes the cotangent apart, (C,) for one operand."""
+    return _conv_silu_forward(x, w, b)
+
+
+def _conv_silu_kernels_fwd(x, w, b, parts):
+    return _conv_silu_forward(x, w, b), (x, w, b)
+
+
+def _conv_silu_kernels_bwd(parts, saved, dy):
+    # where the cotangent is a concatenation at these widths (the scan's
+    # backward kernel writes dx, dB and dC as three arrays), XLA folds each
+    # slice into the array it is a copy of and the concatenation goes
+    edges = [sum(parts[:n]) for n in range(len(parts) + 1)]
+    return _conv_silu_backward(*saved, tuple(
+        dy[..., lo:hi] for lo, hi in zip(edges, edges[1:])))
+
+
+_conv_silu_kernels.defvjp(_conv_silu_kernels_fwd, _conv_silu_kernels_bwd)
+
+
+def _count_conv_dispatch(path: str) -> None:
+    from ..obs import metrics
+
+    metrics.registry().counter_inc(
+        "pt_ssm_conv_dispatch_total",
+        help="state-space mixers' short convolutions traced, by the "
+             "formulation that runs them",
+        labels={"path": path})
+
+
+def causal_conv_silu(x, w, b, parts: tuple = ()):
+    """silu(`causal_depthwise_conv`(x, w, b)) in x's dtype: x [B, T, C], w
+    [K, C], b [C]. Float32 inside, one rounding out. The path is chosen
+    here, when the op is traced, and counted in
+    `pt_ssm_conv_dispatch_total{path}`: `pallas`, one kernel a direction
+    (`conv_kernels_eligible`), whose backward keeps x alone and forms the
+    pre-activation again in registers; `xla`, today's form exactly, every
+    other case. `parts`: widths along C at which the cotangent arrives as a
+    concatenation, which the backward kernel then reads as operands of
+    their own where each is whole lane tiles."""
+    if conv_kernels_eligible(x, w):
+        _count_conv_dispatch("pallas")
+        if sum(parts) != x.shape[2] or any(
+                width % _CONV_LANES for width in parts):
+            parts = (x.shape[2],)
+        return _conv_silu_kernels(x, w, b, tuple(parts))
+    _count_conv_dispatch("xla")
+    return jax.nn.silu(causal_depthwise_conv(x, w, b)).astype(x.dtype)
+
+
 def gated_group_rms_norm(y, z, w, groups: int, eps: float):
     """rms(y * silu(z)) * w with the mean square taken inside each of
     `groups` equal runs of the last axis; float32 inside and out."""
@@ -769,15 +1050,21 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
     dt]; conv_w [K, d_in + 2 G N]; dt_bias, A_log, D [H]; norm_w [d_in];
     out_w [d_in, d]. The two projections run in their weights' dtype (the
     amp dtype where the caller cast them), everything between as the module
-    docstring says."""
+    docstring says: on the TPU, outside a mesh, at the shapes their blocks
+    divide, the conv with its bias and silu is one Pallas kernel a
+    direction under the `conv` scope and the scan with the gated norm one a
+    direction under `scan` (each runs its forward twice, the second time
+    inside the checkpoint's backward); everywhere else XLA's forms of the
+    same values (`causal_depthwise_conv`, `_ssd_einsums`,
+    `gated_group_rms_norm`), chosen when the op is traced."""
     H, P, G, N = num_heads, head_dim, n_groups, state_size
     d_in = H * P
     cd = in_w.dtype
 
     def between(z, xBC, dt, conv_w, conv_b, dt_bias, A_log, D, norm_w):
         with jax.named_scope("conv"):
-            xBC = jax.nn.silu(
-                causal_depthwise_conv(xBC, conv_w, conv_b)).astype(cd)
+            xBC = causal_conv_silu(xBC, conv_w, conv_b,
+                                   (d_in, G * N, G * N))
         with jax.named_scope("scan"):
             dt = jax.nn.softplus(dt + dt_bias)
             A = -jnp.exp(A_log.astype(jnp.float32))
@@ -790,10 +1077,10 @@ def mamba2_mixer(h, in_w, conv_w, conv_b, dt_bias, A_log, D, norm_w, out_w,
         xBC = zxd[..., d_in:2 * d_in + 2 * G * N].astype(cd)
         dt = zxd[..., -H:]                                   # float32
     # one checkpoint from the projection's output to the other's input: the
-    # backward keeps z, xBC and dt and computes the conv, the scan (its
-    # [Q, Q] blocks and chunk states, in the kernels or as XLA's arrays) and
-    # the float32 y (an array only where XLA's norm reads it) again instead
-    # of holding them
+    # backward keeps z, xBC and dt and computes the conv (the activated xBC,
+    # in its kernel or as XLA's float32 arrays), the scan (its [Q, Q] blocks
+    # and chunk states, in the kernels or as XLA's arrays) and the float32 y
+    # (an array only where XLA's norm reads it) again instead of holding them
     y = jax.checkpoint(between)(z, xBC, dt, conv_w, conv_b, dt_bias, A_log,
                                 D, norm_w)
     with jax.named_scope("out_proj"):

@@ -512,6 +512,41 @@ def test_ssm_readers_on_a_hand_made_run_record():
         assert reader.compute(dict(run, trace=None)) is None
 
 
+@pytest.mark.parametrize("kernels", [True, False], ids=["kernels", "xla"])
+def test_ssm_conv_ms_reads_the_mixers_conv_scope(kernels):
+    """`ssm.conv_ms`: `ssm.device_ms`'s rows under the mixer's inner `conv`
+    scope, by pass, with who ran them and the path the program counted; on a
+    program without that counter (the parent of PR 50) the time all the
+    same, and nothing where there is no mixer or no trace."""
+    run = _nemo_run_record()
+    target = "tpu_custom_call" if kernels else None
+    run["trace"]["ops"] += [
+        _row(MIXER, 1_000_000, target, "jvp(", "conv"),
+        _row(MIXER, 1_200_000, target, "transpose(jvp(", "conv"),
+        _row(MIXER, 2_000_000, target, "transpose(jvp(", "conv"),
+        _row(MIXER, 200_000, None, "transpose(jvp(", "conv"),    # dw's sums
+        _row(MIXER, 50_000_000, None, "", "conv", container=True),
+        _row(NEMO_MOE, 7_000_000, None, "", "conv")]     # another op's scope
+    counted = 'pt_ssm_conv_dispatch_total{path="pallas"}'
+    if kernels:
+        run["registry"][counted] = 4.0
+    conv = _load("layer_metrics", "ssm.conv_ms.py")
+    assert conv.compute(run) == pytest.approx(4.4 / 2)
+    info = conv.info(run)
+    assert info["by_pass_ms"] == pytest.approx({"jvp": 0.5, "transpose": 1.7})
+    assert info["kernels_ms"] == pytest.approx(2.1 if kernels else 0.0)
+    assert info["run_by"] == ("kernels" if kernels else "xla")
+    assert info["dispatch"] == ({counted: 4.0} if kernels else {})
+    # the other readers of the layer see the rows as the layer's, not the scan's
+    assert _load("layer_metrics", "ssm.device_ms.py").info(run)[
+        "by_inner_scope_ms"]["conv"] == pytest.approx(2.2)
+    assert _load("layer_metrics", "ssm.scan_ms.py").compute(run) == \
+        pytest.approx(14.0)
+    assert conv.compute(dict(run, program_ops=run["program_ops"][1:])) is None
+    assert conv.compute(dict(run, trace=None)) is None
+    assert conv.compute(dict(run, registry=None)) == pytest.approx(2.2)
+
+
 def test_nemotron_flash_roofline_and_moe_wrapper_on_a_hand_made_run_record():
     run = _nemo_run_record()
     flash = _load("layer_metrics", "nemotron.flash_roofline.py")
@@ -633,7 +668,12 @@ def test_the_manifest_lists_the_nemotron_cell_and_its_metrics():
                     "nemotron.head_device_ms", "nemotron.opt_device_ms",
                     "nemotron.donated_gib", "nemotron.moe_dispatch_ms",
                     "nemotron.gmm_roofline", "nemotron.load_max_over_mean",
-                    "nemotron.feed_produce_ms_per_step"]
+                    "nemotron.feed_produce_ms_per_step",
+                    "ssm.conv_ms"]                       # PR 50, the list's last
+    assert manifest["per_layer"][-1] == {
+        "name": "ssm.conv_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "State-space mixers",
+        "moves": "items_s", "workloads": [cell]}
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
@@ -2312,7 +2352,10 @@ def test_the_manifest_lists_the_lfm2_cell_and_its_metrics():
     assert sorted(mine[3:]) == sorted(
         list(LFM2_WRAPPERS) + ["lfm2.flash_roofline", "lfm2.gmm_roofline"])
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(mine):] == mine                    # appended, last
+    end = names.index(mine[-1]) + 1                      # appended in PR 49
+    assert names[end - len(mine):end] == mine            # contiguous, in order
+    # PR 50 appended one reader of the hybrid's cell behind them
+    assert names[end:] == ["ssm.conv_ms"]
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     assert {by_name[n]["layer"] for n in mine
             if n.startswith(("conv.",))} == {"Short-conv operators"}
@@ -2323,7 +2366,8 @@ def test_the_manifest_lists_the_lfm2_cell_and_its_metrics():
     for name in mine:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
     # no reader that was there lists the new cell: their entries are untouched
-    assert not [m["name"] for m in manifest["per_layer"][:-len(mine)]
+    assert not [m["name"] for m in manifest["per_layer"][:end - len(mine)]
+                + manifest["per_layer"][end:]
                 if cell in m.get("workloads", ())]
     # no step tail: the cell reports items_s, peak_hbm_gib and setup_s
     tail = next(m for m in manifest["end_to_end"] if m["name"] == "step_ms_p90")

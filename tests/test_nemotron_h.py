@@ -291,6 +291,145 @@ def test_conv_backward_is_the_transposed_taps(dtype):
                                    rtol=1e-5, atol=1e-5)
 
 
+def _silu_of_the_conv(x, w, b):
+    """Today's form, the kernels' oracle and the op's exact fallback."""
+    return jax.nn.silu(ssm_ops.causal_depthwise_conv(x, w, b)).astype(x.dtype)
+
+
+@pytest.mark.parametrize("B_,T_,C,K,parts", [
+    (1, 1024, 512, 4, ()),
+    (2, 2048, 512, 2, ()),
+    (1, 3072, 1024, 4, (512, 512)),
+    (1, 1024, 6144, 4, (4096, 1024, 1024))],
+    ids=["one_block_one_tile", "K2_two_sequences_two_blocks",
+         "three_blocks_two_parts", "nemotrons_twelve_tiles_x_B_C"])
+def test_conv_kernels_interpreted_against_the_plain_form(B_, T_, C, K, parts):
+    """The two Pallas kernels of the conv, its bias and its silu, interpreted,
+    give `silu(causal_depthwise_conv)`'s values and its gradients to a
+    rounding of bf16 (a block's halo rows in front and behind, the carried
+    rows between chunks, the zeros before the start and behind the end of
+    EACH sequence of the batch), dw and db to float32's; the backward reads
+    the cotangent whole or as its parts along the lanes, a lane tile the part
+    it lies in."""
+    r = _rng(T_ + C + K)
+    x = jnp.asarray(r.randn(B_, T_, C), jnp.bfloat16)
+    w = jnp.asarray(r.randn(K, C) * 0.5, jnp.float32)
+    b = jnp.asarray(r.randn(C) * 0.3, jnp.float32)
+    dy = jnp.asarray(r.randn(B_, T_, C), jnp.bfloat16)
+    assert ssm_ops._shapes_conv_ok(x, w)
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    want, vjp = jax.vjp(_silu_of_the_conv, x, w, b)
+    y = ssm_ops._conv_silu_forward(x, w, b, interpret=True)
+    assert y.dtype == want.dtype and y.shape == want.shape
+    np.testing.assert_allclose(f32(y), f32(want), rtol=2 ** -7, atol=1e-6)
+    edges = np.cumsum((0,) + (parts or (C,)))
+    dx, dw, db = ssm_ops._conv_silu_backward(
+        x, w, b, tuple(dy[..., lo:hi] for lo, hi in zip(edges, edges[1:])),
+        interpret=True)
+    dx0, dw0, db0 = vjp(dy)
+    assert (dx.dtype, dw.dtype, db.dtype) == (jnp.bfloat16, jnp.float32,
+                                              jnp.float32)
+    assert (dx.shape, dw.shape, db.shape) == (x.shape, w.shape, b.shape)
+    np.testing.assert_allclose(f32(dx), f32(dx0), rtol=2 ** -7, atol=1e-5)
+    # sums of B T products of order one: float32's rounding of their size
+    np.testing.assert_allclose(dw, dw0, rtol=1e-4, atol=2e-2)
+    np.testing.assert_allclose(db, db0, rtol=1e-4, atol=2e-2)
+
+
+def test_conv_kernels_take_only_shapes_their_blocks_divide():
+    ok = lambda T_, C, K, dt=jnp.bfloat16: ssm_ops._shapes_conv_ok(  # noqa: E731
+        jax.ShapeDtypeStruct((1, T_, C), dt),
+        jax.ShapeDtypeStruct((K, C), jnp.float32))
+    assert ok(8192, 6144, 4) and ok(1024, 512, 9) and ok(2048, 1024, 1)
+    assert not ok(8192, 6144, 4, jnp.float32)       # float32 rows: XLA's form
+    assert not ok(8192 + 256, 6144, 4) and not ok(40, 512, 4)
+    assert not ok(1024, 768, 4) and not ok(1024, 512, 10)
+    # the CPU backend never dispatches to them
+    assert not ssm_ops.conv_kernels_eligible(
+        jnp.zeros((1, 1024, 512), jnp.bfloat16), jnp.zeros((4, 512)))
+
+
+@pytest.mark.parametrize("where,shape,dtype", [
+    ("cpu", (1, 1024, 512), jnp.bfloat16),
+    ("tpu_mesh", (1, 1024, 512), jnp.bfloat16),
+    ("tpu", (1, 1024, 512), jnp.float32),
+    ("tpu", (2, 40, 512), jnp.bfloat16),
+    ("tpu", (1, 1024, 96), jnp.bfloat16)],
+    ids=["cpu", "under_a_mesh", "float32", "rows_no_block_divides",
+         "lanes_no_tile_divides"])
+def test_conv_silu_elsewhere_is_todays_form_to_the_bit(monkeypatch, where,
+                                                       shape, dtype):
+    """Where the kernels do not take it (the CPU; the TPU backend, steered,
+    under a mesh, in float32, at shapes the blocks do not divide) the op is
+    `silu(causal_depthwise_conv)` cast to x's dtype: the same program (no
+    `pallas_call` is traced), so the same values and gradients to the bit."""
+    import contextlib
+
+    from jax.sharding import Mesh
+
+    from paddle_tpu.ops import mesh_dispatch
+
+    if where != "cpu":
+        monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
+    r = _rng(shape[1])
+    x = jnp.asarray(r.randn(*shape), dtype)
+    w = jnp.asarray(r.randn(4, shape[2]), jnp.float32)
+    b = jnp.asarray(r.randn(shape[2]), jnp.float32)
+    cot = jnp.asarray(r.randn(*shape), jnp.float32)
+    both = lambda fn: jax.value_and_grad(  # noqa: E731
+        lambda *a: (fn(*a).astype(jnp.float32) * cot).sum(),
+        argnums=(0, 1, 2))
+    mesh = mesh_dispatch.active_mesh(
+        Mesh(np.array(jax.devices()[:1]), ("dp",)), "dp") \
+        if where == "tpu_mesh" else contextlib.nullcontext()
+    with mesh:
+        op = lambda *a: ssm_ops.causal_conv_silu(  # noqa: E731
+            *a, parts=(shape[2],))
+        assert "pallas_call" not in str(jax.make_jaxpr(both(op))(x, w, b))
+        got = both(op)(x, w, b)
+    want = both(_silu_of_the_conv)(x, w, b)
+    for a, e in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == e.dtype and a.shape == e.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(e, np.float32))
+
+
+@pytest.mark.parametrize("parts,operands", [
+    ((512, 512), 2), ((768, 256), 1), ((), 1)],
+    ids=["two_parts", "parts_no_tile_divides", "no_parts"])
+def test_conv_silu_takes_its_kernels_on_the_tpu(monkeypatch, parts, operands):
+    """... and where the backend is the TPU (steered; only traced), for bf16
+    rows the blocks divide, both directions are one `pallas_call` each, the
+    backward's cotangent in the parts the caller named where each is whole
+    lane tiles, else whole."""
+    monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct((1, 2048, 1024), jnp.bfloat16)
+    w, b = (jax.ShapeDtypeStruct(s, jnp.float32) for s in ((4, 1024), (1024,)))
+    grad = jax.grad(lambda *a: ssm_ops.causal_conv_silu(
+        *a, parts=parts).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    jaxpr = jax.make_jaxpr(grad)(x, w, b)
+    text = str(jaxpr)
+    assert text.count("pallas_call") == 2
+    assert "causal_conv_silu_fwd" in text and "causal_conv_silu_bwd" in text
+
+    def launches(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from launches(sub)
+
+    bwd = next(e for e in launches(jaxpr.jaxpr)
+               if "bwd" in str(e.params["name"]))
+    # x, its two halos, the taps, the bias; then the parts and their halos
+    assert len(bwd.invars) == 5 + 2 * operands
+    out = jax.eval_shape(grad, x, w, b)
+    assert [(o.shape, o.dtype) for o in out] == [
+        (x.shape, jnp.bfloat16), (w.shape, jnp.float32),
+        (b.shape, jnp.float32)]
+
+
 def test_mamba2_init_draws_the_family_ranges():
     pt.reset()
     prog, startup = pt.Program(), pt.Program()

@@ -193,6 +193,28 @@ def _short_conv(case):
     return (fwd_bwd if with_bwd else fwd), specs
 
 
+def _conv_silu(with_bwd):
+    from paddle_tpu.ops import ssm_ops
+
+    # a Nemotron mixer's conv, bias and silu at the cell's shape: the packed
+    # [1, 8192, 6144] bf16 the in-projection writes, 4 taps: blocks of 1024
+    # rows x 512 lanes (the lane tile a grid axis) with 16 halo rows before
+    # and, in the backward, behind; the backward reads the cotangent as the
+    # scan's backward kernel hands it, dx | dB | dC as three operands
+    specs = [((1, 8192, 6144), BF16), ((4, 6144), F32), ((6144,), F32)]
+    parts = (4096, 1024, 1024)
+    assert ssm_ops._shapes_conv_ok(jax.ShapeDtypeStruct(*specs[0]),
+                                   jax.ShapeDtypeStruct(*specs[1]))
+
+    def fwd(x, w, b):
+        return ssm_ops._conv_silu_kernels(x, w, b, parts)
+
+    def fwd_bwd(*args):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(), (0, 1, 2))(*args)
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
 def _ssd_scan(case):
     from paddle_tpu.ops import ssm_ops
 
@@ -312,6 +334,8 @@ CASES = [
     ("ssd_scan_fwd_bwd_nemotron_t8192", _ssd_scan, (True, False)),
     ("ssd_scan_gated_fwd_nemotron_t8192", _ssd_scan, (False, True)),
     ("ssd_scan_gated_fwd_bwd_nemotron_t8192", _ssd_scan, (True, True)),
+    ("causal_conv_silu_fwd_nemotron_t8192", _conv_silu, False),
+    ("causal_conv_silu_fwd_bwd_nemotron_t8192", _conv_silu, True),
     # ResNet-50 head at a full serving bucket, and the small probe shape
     ("quant_matmul_64x2048x1000", _quant, (64, 2048, 1000)),
     ("quant_matmul_8x512x512", _quant, (8, 512, 512)),
@@ -591,10 +615,19 @@ def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
     it, and no `ragged-dot` is (XLA's own grouped kernel, which takes the
     job where the width padding fails to hand it to megablox, carries no
     scope for a trace's readers)."""
+    from paddle_tpu.obs import metrics
+
     config = _load_module(os.path.join(ROOT, "configs", "nemotron_h.py"))
     raw, args = _step_program(config.get_model, 1, 8192, one_chip,
                               monkeypatch)
-    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    counted = lambda: [metrics.registry().counter_value(  # noqa: E731
+        "pt_ssm_conv_dispatch_total", labels={"path": p})
+        for p in ("pallas", "xla")]
+    before = counted()
+    lowered = jax.jit(raw, donate_argnums=(0,)).lower(*args)
+    # the step's one trace counts each of the four mixers' convs once
+    assert [b - a for a, b in zip(before, counted())] == [4, 0]
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "flash_attention_bwd" in text and "ragged-dot" not in text
     # the four mixers' scans are the kernels: forward, the checkpoint's
@@ -624,14 +657,28 @@ def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
     assert len(re.findall(
         r"= \(bf16\[1,8192,4096\]\S*, f32\[1,64,4096,128\]\S*\) "
         r"custom-call\(.*ssd_scan_fwd", text)) == 8
-    # ... and the conv's backward is one pass over its float32 cotangent,
-    # not four float32 [1, 8192, 6144] products written first
-    assert not re.findall(
-        r"= \((?:f32\[1,8192,6144\]\S*, ){3}f32\[1,8192,6144\]\S*\) fusion",
-        text)
+    # ... and the conv, its bias and its silu are one kernel a direction
+    # (PR 50): forward, the checkpoint's second forward and backward, every
+    # one under the op's inner `conv` scope, where `ssm.conv_ms` finds it.
+    # The backward reads the scan backward's dx, dB and dC as they are (the
+    # slices of their concatenation fold into the three arrays), and no
+    # float32 array of the packed [1, 8192, 6144] is left anywhere in the
+    # step: the pad, the shifted windows, the sum kept for silu's derivative
+    # and the transposed taps' padded cotangent all went
+    convs = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call".*'
+        r'op_name="([^"]*causal_conv_silu_(?:fwd|bwd)[^"]*)"', text)
+    assert len(convs) == 12, convs
+    assert all("mamba2_mixer." in name and "/conv/" in name
+               for name in convs), convs
+    assert sum("transpose(jvp(" in name for name in convs) == 8
+    assert "f32[1,8192,6144]" not in text
+    assert not re.findall(r"= bf16\[1,8192,6144\]\S* (?:concatenate|fusion)\(",
+                          text)
     assert dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr)) == {
         "flash_attention_fwd": 1, "flash_attention_bwd": 1,
-        "grouped_matmul": 32, "ssd_scan_fwd": 8, "ssd_scan_bwd": 4}
+        "grouped_matmul": 32, "ssd_scan_fwd": 8, "ssd_scan_bwd": 4,
+        "causal_conv_silu_fwd": 8, "causal_conv_silu_bwd": 4}
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 7.9e9          # 12 B a parameter
     # the parent's books read 7.454 + 4.687 GiB here and the chip 12.215
@@ -645,22 +692,28 @@ def test_nemotron_step_program_fits_one_chip(one_chip, compiled_mode,
             < 12.4 * 2**30)
 
 
-@pytest.mark.parametrize("where,chunk,what,path", [
-    ("tpu", 128, "mixer", "pallas_chunked_gated"),
-    ("tpu", 128, "scan", "pallas_chunked"),
-    ("cpu", 128, "mixer", "xla_chunked"),
-    ("tpu_mesh", 128, "mixer", "xla_chunked"),
-    ("tpu", 16, "mixer", "xla_chunked")])
+@pytest.mark.parametrize("where,chunk,what,path,conv", [
+    ("tpu", 128, "mixer", "pallas_chunked_gated", "xla"),
+    ("tpu", 128, "scan", "pallas_chunked", None),
+    ("cpu", 128, "mixer", "xla_chunked", "xla"),
+    ("tpu_mesh", 128, "mixer", "xla_chunked", "xla"),
+    ("tpu", 16, "mixer", "xla_chunked", "xla"),
+    ("tpu", 128, "mixer_amp", "pallas_chunked_gated", "pallas"),
+    ("cpu", 128, "mixer_amp", "xla_chunked", "xla"),
+    ("tpu_mesh", 128, "mixer_amp", "xla_chunked", "xla")])
 def test_scan_dispatch_counts_the_path_it_chose(monkeypatch, where, chunk,
-                                                what, path):
+                                                what, path, conv):
     """`pt_ssm_scan_dispatch_total{path}`: for an eligible shape where the
     backend is the TPU (steered: nothing is lowered here, the op is only
     traced) the mixer takes the kernels with the gated norm as their
     epilogue and `ssd_chunked_scan` alone the kernels without it; the
     einsums (and XLA's norm) on the CPU, under an active mesh (a bare
     `pallas_call` cannot be partitioned) and at a chunk the kernels do not
-    take. One increment an op traced, under one label; no flag and no
-    attribute chooses."""
+    take. `pt_ssm_conv_dispatch_total{path}` beside it: a mixer's conv, bias
+    and silu take their kernels (`pallas`) on the TPU for a bf16 projection
+    (`mixer_amp`) of whole row blocks, and XLA's form (`xla`) in float32, at
+    256 tokens, on the CPU and under a mesh. One increment an op traced,
+    under one label; no flag and no attribute chooses."""
     import contextlib
 
     import numpy as np
@@ -672,34 +725,49 @@ def test_scan_dispatch_counts_the_path_it_chose(monkeypatch, where, chunk,
     if where != "cpu":
         monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
     H, P, G, N, d = 4, 64, 2, 128, 64
+    T_ = 256
+    if what == "mixer_amp":     # one row block of one 512-lane tile
+        G, T_ = 1, 1024
     width = 2 * H * P + 2 * G * N + H
-    if what == "mixer":
-        shapes = [(1, 256, d), (d, width), (4, H * P + 2 * G * N),
+    if what != "scan":
+        shapes = [(1, T_, d), (d, width), (4, H * P + 2 * G * N),
                   (H * P + 2 * G * N,), (H,), (H,), (H,), (H * P,),
                   (H * P, d)]
+        # under amp the stream and the two projection matrices are bf16
+        low = (0, 1, 8) if what == "mixer_amp" else ()
+        dtypes = [BF16 if at in low else F32 for at in range(len(shapes))]
         traced = lambda *a: ssm_ops.mamba2_mixer(  # noqa: E731
             *a, num_heads=H, head_dim=P, n_groups=G, state_size=N, eps=1e-5,
             chunk=chunk)
-        want = (1, 256, d)
+        want = (1, T_, d)
     else:
         shapes = [(1, 256, H, P), (1, 256, H), (H,), (1, 256, G, N),
                   (1, 256, G, N), (H,)]
+        dtypes = [F32] * len(shapes)
         traced = lambda *a: ssm_ops.ssd_chunked_scan(  # noqa: E731
             *a, chunk=chunk)
         want = (1, 256, H, P)
-    count = lambda p: metrics.registry().counter_value(  # noqa: E731
-        "pt_ssm_scan_dispatch_total", labels={"path": p})
-    labels = ("pallas_chunked_gated", "pallas_chunked", "xla_chunked")
-    before = {p: count(p) for p in labels}
+    families = {
+        "pt_ssm_scan_dispatch_total": (
+            "pallas_chunked_gated", "pallas_chunked", "xla_chunked"),
+        "pt_ssm_conv_dispatch_total": ("pallas", "xla")}
+    count = lambda: {  # noqa: E731
+        (family, p): metrics.registry().counter_value(
+            family, labels={"path": p})
+        for family, paths in families.items() for p in paths}
+    before = count()
     mesh = mesh_dispatch.active_mesh(
         Mesh(np.array(jax.devices()[:1]), ("dp",)), "dp") \
         if where == "tpu_mesh" else contextlib.nullcontext()
     with mesh:
-        out = jax.eval_shape(traced, *[jax.ShapeDtypeStruct(s, F32)
-                                       for s in shapes])
+        out = jax.eval_shape(traced, *[jax.ShapeDtypeStruct(s, dt)
+                                       for s, dt in zip(shapes, dtypes)])
     assert out.shape == want
-    assert {p: count(p) - before[p] for p in labels} == {
-        p: int(p == path) for p in labels}
+    after = count()
+    assert {k: after[k] - before[k] for k in after} == {
+        (family, p): int(p == chosen)
+        for family, chosen in zip(families, (path, conv))
+        for p in families[family]}
 
 
 def test_glm_moe_step_program_fits_one_chip(one_chip, compiled_mode,
