@@ -21,6 +21,7 @@ __all__ = ["moe_ffn", "moe_aux_loss"]
 EXPERT_TOKENS_COUNTER = "pt_moe_expert_tokens_total"
 HELD_PAIRS_COUNTER = "pt_moe_held_pairs_total"
 ROW_PATH_COUNTER = "pt_moe_row_path_total"
+CHUNK_ROWS_COUNTER = "pt_moe_chunk_rows_total"
 
 
 def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
@@ -71,7 +72,9 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
     also publishes `pt_moe_held_pairs_total{layer,expert}`, the pairs it
     computed, by held expert (0 = `lo`), and, where its rows have a bound,
     `pt_moe_row_path_total{layer,path}`: the steps whose live pairs fitted
-    one chunk of the bound (path 0) and those that needed more (path 1)."""
+    one chunk of the bound (path 0) and those that needed more (path 1),
+    and `pt_moe_chunk_rows_total{layer,kind}`: the chunks' live rows, the
+    only ones their sums read (kind 0), and the chunks' rows (kind 1)."""
     helper = LayerHelper("moe_ffn", name=name)
     d = int(input.shape[-1])
     E, f = int(num_experts), int(expert_dim)
@@ -132,6 +135,8 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
         if bounds_rows((lo, hi), E, chunk_shares):
             row_path = helper.create_tmp_variable(np.int32, (2,))
             outputs["RowPath"] = [row_path]
+            chunk_rows = helper.create_tmp_variable(np.int32, (2,))
+            outputs["ChunkRows"] = [chunk_rows]
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
                      attrs=attrs)
     helper.main_program.add_step_statistic(
@@ -150,6 +155,11 @@ def moe_ffn(input, num_experts: int, experts_per_token: int, expert_dim: int,
                 index_label="path",
                 help="steps a chip's share of a routed layer ran in one "
                      "chunk of its bounded rows (path 0) or in more (path 1)")
+            helper.main_program.add_step_statistic(
+                chunk_rows, CHUNK_ROWS_COUNTER, labels={"layer": helper.name},
+                index_label="kind",
+                help="live rows (kind 0) among the rows of the chunks (kind "
+                     "1) a chip's share of a routed layer ran in")
     return out, logits, counts
 
 

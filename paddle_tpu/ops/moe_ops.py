@@ -205,28 +205,83 @@ _undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
 
 # -- a share's rows in chunks: gather in, sum out ---------------------------
 # R rows (`row_bound`) of the sort by expert, the live ones first; `tok` [R]
-# is each row's token. The gather's transpose is a scatter-add of R rows into
-# [T, d]. Read on a v5e at the hybrid's shapes (R 12 288, d 2688, T 8192,
-# float32 out; PERF.md section 6, PR 38): 2.56 ms, 2.70 with the rows sorted
-# by token first and `indices_are_sorted`, against 5.02 ms for a form made of
-# gathers alone (sort the rows by token, a segmented sum over runs of at most
-# k in shifted adds, each token's first row) and 5.40 ms for the whole-rows
-# gather of [T x k, d] through the inverse permutation.
-def _add_rows(acc, rows, tok):
-    """acc [T, d] float32 + rows [R, d], each row added to its token's."""
-    return acc.at[tok].add(rows.astype(jnp.float32))
+# is each row's token. The gather's transpose and the combine are sums of
+# rows into [T, d] float32. A token's rows are its k slots, so each sum is k
+# row GATHERS through the sort's inverse and one fused add: `slot_row` [T, k]
+# is where in the chunk a (token, slot) pair lies, `slot_live` [T, k] whether
+# it lies there among the live rows. A scatter-add of the R rows is the
+# slowest way the chip has of making such a sum: XLA's sorts the R indices,
+# copies the rows to float32, gathers the copy into sorted order and adds row
+# after row, dead rows (zeros) like live ones. Read on a v5e (PERF.md section
+# 6, PR 51; ms a call at lfm2's / trinity's / glm's / the hybrid's shapes: T
+# 16 384 / 8 192 / 8 192 / 8 192, k 4 / 8 / 4 / 6, R 24 576 / 24 576 / 8 192
+# / 6 144, d 2048 but the hybrid's 2688, bf16 rows): the combine 1.21 / 1.01
+# / 0.52 / 1.08 where `acc.at[tok].add(rows)` took 3.49 / 2.79 / 1.32 / 1.37,
+# the gather's backward 0.91 / 0.86 / 0.37 / 0.87 for 3.28 / 2.53 / 1.11 /
+# 1.05. The same sum as ONE gather of [T, k, d] and a sum over k reads 3.44 /
+# 1.36 / 1.49 / 3.50 (a k of 4 or 6 pads the bf16 tile's sublanes), the
+# scatter-add over the live rows alone, could it be had, 2.01 / 1.72 / 1.24 /
+# 1.23, and in a loop over tiles of the live rows 2.5 / 1.6-3.5 / 0.9-2.1 /
+# 0.9-1.7 by the tile (XLA lowers a tile's scatter by its size). PR 38 read
+# the hybrid's scatter-add at 2.56 ms (R 12 288) and the whole-rows gather of
+# [T x k, d] through the inverse, summed as [T, k, d], at 5.40.
+def _sum_of_slots(rows, slot_row, slot_live, weights=None):
+    """[T, d] float32: each token's sum over its live slots of rows
+    [R, d][slot_row] (x weights [T, k]). The k gathered [T, d] arrays stand
+    together in front of the add, so where the pairs are four chunks or more
+    (k T >= 4 R) the tokens go in two halves, one after the other: by the
+    chip, `peak_hbm_gib` against the scatter-add's (PERF.md section 6, PR
+    51): the hybrid (k T = 8 R) + 0.11 GB whole and - 0.00 in halves, glm (4
+    R) + 0.08 and + 0.02; lfm2 and trinity (2.7 R) - 0.21 and - 0.11 whole,
+    and trinity + 0.11 and 1.3 ms a step slower in halves."""
+    T, k = slot_row.shape
+    step = T // 2 if T * k >= 4 * rows.shape[0] and T % 2 == 0 else T
+    out = []
+    for part in (slice(lo, lo + step) for lo in range(0, T, step)):
+        total = 0.0
+        for s in range(k):
+            row = jnp.where(slot_live[part, s, None],
+                            rows[slot_row[part, s]],
+                            jnp.zeros((), rows.dtype)).astype(jnp.float32)
+            total = total + (row if weights is None
+                             else row * weights[part, s, None])
+        out.append(total)
+    return jnp.concatenate(out)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rows_of(x, tok, T):
+@jax.custom_vjp
+def _add_rows(acc, rows, gates, pairs, slot_row, slot_live):
+    """acc [T, d] float32 + rows [R, d], each weighted by its pair's gate
+    (gates [T, k]; `pairs` [R]: the rows' (token, slot) pairs) and added to
+    its token's."""
+    return acc + _sum_of_slots(rows, slot_row, slot_live, gates)
+
+
+def _add_rows_bwd(res, g):
+    rows, gates, pairs = res
+    of_rows = g[pairs // gates.shape[1]]                       # [R, d]
+    row_gates = gates.reshape(-1)[pairs]
+    d_gates = jnp.zeros((gates.size,), gates.dtype).at[pairs].add(
+        (of_rows * rows.astype(jnp.float32)).sum(-1).astype(gates.dtype))
+    return (g, (of_rows * row_gates[:, None]).astype(rows.dtype),
+            d_gates.reshape(gates.shape), None, None, None)
+
+
+_add_rows.defvjp(
+    lambda acc, rows, gates, pairs, slot_row, slot_live: (
+        _add_rows(acc, rows, gates, pairs, slot_row, slot_live),
+        (rows, gates, pairs)), _add_rows_bwd)
+
+
+@jax.custom_vjp
+def _rows_of(x, tok, slot_row, slot_live):
     """x [T, d] -> [R, d]; backward summed in float32 whatever x's dtype."""
     return x[tok]
 
 
 _rows_of.defvjp(
-    lambda x, tok, T: (x[tok], tok),
-    lambda T, tok, g: (_add_rows(jnp.zeros((T, g.shape[1]), jnp.float32), g,
-                                 tok).astype(g.dtype), None))
+    lambda x, tok, slot_row, slot_live: (x[tok], (slot_row, slot_live)),
+    lambda res, g: (_sum_of_slots(g, *res).astype(g.dtype), None, None, None))
 
 
 def _summed_chunks(chunk, count, shape):
@@ -311,9 +366,10 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
             shared=None, chunk_shares=None, gate_norm_eps: float = 0.0):
     """x [T, d] -> (out [T, d], router logits [T, E] f32, tokens per expert
     [E] int32, pairs per held expert [held] int32 or None, the row path [2]
-    int32 or None). y = sum_j gate_j * expert_{e_j}(x) over the token's
-    top_k experts e_j of all E the router scores; expert e is (silu(x Wg[e])
-    * (x Wu[e])) Wd[e], or, with `gate_w` None, relu(x Wu[e])^2 Wd[e].
+    int32 or None, the chunks' rows [2] int32 or None). y = sum_j gate_j *
+    expert_{e_j}(x) over the token's top_k experts e_j of all E the router
+    scores; expert e is (silu(x Wg[e]) * (x Wu[e])) Wd[e], or, with `gate_w`
+    None, relu(x Wu[e])^2 Wd[e].
 
     `held` (lo, hi): the stacks hold experts lo..hi-1 only. The (token,
     slot) pairs that chose one of them are sorted to the front of the T x k
@@ -333,7 +389,9 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
     count taken on the device (`_summed_chunks`): every held pair is
     computed either way, and only the order of a token's sum differs from
     the whole rows'. The row path says which it was: [1, 0] one chunk,
-    [0, 1] more; None where the rows have no bound.
+    [0, 1] more; None where the rows have no bound. The chunks' rows say
+    how full they were: [the live rows among them, the only ones a chunk's
+    sums read (`_sum_of_slots`), the step's chunks x R].
 
     `shared`: a shared expert, every token, of the routed experts' kind:
     (up [d, f_s], down [f_s, d]) beside relu^2 experts: + relu(x up)^2 down;
@@ -417,7 +475,7 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
         group's part of them; a row behind the live ones is a pair of an
         absent expert (or none: the sort padded to whole chunks), zero going
         in and coming out as in `whole`."""
-        order, group_sizes = ints
+        order, inverse, group_sizes = ints
         with jax.named_scope("dispatch"):
             first = j * R
             ends = jnp.cumsum(group_sizes)
@@ -425,34 +483,39 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
                            first, first + R)
             live = (first + jnp.arange(R) < ends[-1])[:, None]
             pairs = jax.lax.dynamic_slice(order, (first,), (R,))
-            tok = pairs // top_k
-            xs = _rows_of(x.astype(cd), tok, T)                  # [R, d]
+            # each token's k pairs as rows of this chunk
+            slot_row = inverse.reshape(T, top_k) - first
+            slot_live = (slot_row >= 0) & (
+                slot_row < jnp.minimum(R, ends[-1] - first))
+            slot_row = jnp.clip(slot_row, 0, R - 1)
+            xs = _rows_of(x.astype(cd), pairs // top_k, slot_row,
+                          slot_live)                             # [R, d]
         with jax.named_scope("experts"):
             ys = hidden_rows(xs, down, ups, cut[1] - cut[0], live)
         with jax.named_scope("combine"):
-            row_gates = gates.reshape(-1)[pairs]
-            return _add_rows(acc, ys.astype(jnp.float32)
-                             * row_gates[:, None], tok)
+            return _add_rows(acc, ys, gates, pairs, slot_row, slot_live)
 
     ups = (up_w,) if gate_w is None else (gate_w, up_w)
-    ints, path = (order, inverse, group_sizes), None
+    ints, path, chunk_rows = (order, inverse, group_sizes), None, None
     if R < rows:
         # as many chunks of R rows as the held pairs fill: one, in a step
         # whose routing keeps to the bound
         def chunks(ints):
-            return jnp.maximum(1, -(-ints[1].sum() // R))
+            return jnp.maximum(1, -(-ints[2].sum() // R))
 
-        ints = (jnp.pad(order, (0, -rows % R)), group_sizes)
+        ints = (jnp.pad(order, (0, -rows % R)), inverse, group_sizes)
         out = _summed_chunks(chunk, chunks, (T, d))(
             ints, x, gates, down_w, *ups)
         n = chunks(ints)
         path = jnp.stack([n == 1, n > 1]).astype(jnp.int32)
+        chunk_rows = jnp.stack([group_sizes.sum(), n * R])
     elif part:
         # a share keeps nothing of its T x k rows for the backward (held,
         # they would be 1 GB a layer at 8 192 tokens): it gathers them again
         out = jax.checkpoint(whole)(ints, x, gates, down_w, *ups)
         if bound:                          # its bound reaches all the rows
             path = jnp.array([0, 1], jnp.int32)
+            chunk_rows = jnp.array([rows, rows], jnp.int32)
     else:
         out = whole(ints, x, gates, down_w, *ups)
     if shared is not None:
@@ -471,7 +534,7 @@ def moe_ffn(x, router_w, gate_w, up_w, down_w, top_k: int,
             out = out + jnp.dot(hs, down_s,
                                 preferred_element_type=jnp.float32)
     return (out.astype(cd), logits, counts,
-            group_sizes if part else None, path)
+            group_sizes if part else None, path, chunk_rows)
 
 
 @register_op("moe_ffn")
@@ -487,7 +550,8 @@ def moe_ffn_kernel(ctx):
     like X, in the compute dtype; RouterLogits [tokens, E] float32 (under
     amp too: the router never drops precision, the expert matmuls do);
     TokensPerExpert [E] int32, summing to tokens x top_k; HeldPairs [held]
-    int32 where the op holds a share."""
+    int32 where the op holds a share; where its rows have a bound, RowPath
+    [2] and ChunkRows [2] int32 (`moe_ffn`'s row path and chunks' rows)."""
     x = ctx.input("X")
     gate_w, up_w, down_w = amp.cast_inputs(
         ctx, ctx.input("GateW"), ctx.input("UpW"), ctx.input("DownW"))
@@ -500,7 +564,7 @@ def moe_ffn_kernel(ctx):
     held = None
     if ctx.attr("held_hi") is not None:
         held = (int(ctx.attr("held_lo")), int(ctx.attr("held_hi")))
-    out, logits, counts, held_pairs, row_path = moe_ffn(
+    out, logits, counts, held_pairs, row_path, chunk_rows = moe_ffn(
         x.reshape(-1, x.shape[-1]), ctx.input("RouterW"), gate_w, up_w,
         down_w, int(ctx.attr("top_k")),
         bool(ctx.attr("norm_topk_prob", False)),
@@ -516,6 +580,8 @@ def moe_ffn_kernel(ctx):
         ctx.set_output("HeldPairs", held_pairs)
     if row_path is not None:
         ctx.set_output("RowPath", row_path)
+    if chunk_rows is not None:
+        ctx.set_output("ChunkRows", chunk_rows)
 
 
 @register_op("moe_aux_loss")

@@ -300,7 +300,8 @@ def test_the_eight_shares_add_up():
         whole = _whole(p, _layer_config(16, 0, 16))
         total, pairs = 0.0, 0
         for lo in range(0, 16, 2):
-            out, _, counts, held, _ = _share(p, lo, lo + 2, shared=(lo == 0))
+            out, _, counts, held, *_ = _share(p, lo, lo + 2,
+                                              shared=(lo == 0))
             total, pairs = total + out, pairs + int(held.sum())
     np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-5)
     assert pairs == 64 * 3 == int(counts.sum())
@@ -579,6 +580,54 @@ def test_configs_afmoe_trains_at_tiny_sizes(amp):
                              labels={"path": "xla"}) >= 3
 
 
+def test_a_bounded_share_publishes_how_full_its_chunks_were():
+    """`pt_moe_chunk_rows_total{layer,kind}`, step by step (a sync a step):
+    kind 0 is the chunks' live rows (the step's held pairs: the only rows a
+    chunk's sums read), kind 1 the step's chunks x R, live <= bound; a layer
+    whose rows have no bound (half of its experts held) publishes neither."""
+    from paddle_tpu.obs import metrics
+    from paddle_tpu.trainer import EndIteration, Trainer
+
+    pt.reset()
+    metrics.registry().reset_metrics()
+    m = _load_config().get_model(
+        layer_types=(W_, G_, W_), dense_layers=1, dim=48, heads=4, kv_heads=2,
+        head_dim=8, window=32, dense_dim=80, experts=16, held_experts=(0, 2),
+        experts_per_token=3, expert_dim=24, shared_expert_dim=24, seqlen=160,
+        vocab=64, batch=2, steps=6, seed=3, amp=None)
+    # 2 x 160 tokens x 3 = 960 rows, 2 of 16 held, three even shares a chunk
+    R = moe_ops.row_bound(960, (0, 2), 16, 8, 3)
+    assert R == 360
+    reg = metrics.registry()
+    layers = ("afmoe.h1.moe", "afmoe.h2.moe")
+
+    def read(counter, label, n):
+        return [[reg.counter_value(counter, labels={"layer": layer, label: i})
+                 for i in range(n)] for layer in layers]
+
+    seen = []
+
+    def handler(e):
+        if isinstance(e, EndIteration):
+            seen.append((read("pt_moe_held_pairs_total", "expert", 2),
+                         read("pt_moe_chunk_rows_total", "kind", 2)))
+
+    Trainer(cost=m["cost"]).train(m["reader"], num_passes=1,
+                                  event_handler=handler, log_interval=1)
+    assert len(seen) == 6
+    steps = np.diff(np.asarray([(np.zeros((2, 2)),) * 2] + seen), axis=0)
+    for held, (live, bound) in zip(steps[:, 0].sum(-1).ravel(),
+                                   steps[:, 1].reshape(-1, 2)):
+        assert 0 < held == live <= bound == max(1, -(-held // R)) * R
+    prog = pt.Program()
+    with pt.program_guard(prog, pt.Program()):
+        x = pt.layers.data("x", shape=[8, 16], dtype=np.float32)
+        pt.layers.moe_ffn(x, 4, 2, 8, name="a_half", held_experts=(0, 2))
+        pt.layers.moe_ffn(x, 16, 2, 8, name="an_eighth", held_experts=(0, 2))
+    assert [s["labels"]["layer"] for s in prog.step_statistics
+            if s["counter"] == "pt_moe_chunk_rows_total"] == ["an_eighth"]
+
+
 def test_a_layer_may_ask_for_chunks_of_three_even_shares():
     """`moe_ffn(chunk_shares=)`: absent, the op and its bound are what they
     were (two even shares of the T x k rows); Trinity's layers ask for
@@ -615,7 +664,8 @@ def test_a_layer_may_ask_for_chunks_of_three_even_shares():
             chunk_shares=shares)
 
     with jax.default_matmul_precision("highest"):
-        (out2, *_, held2, path2), (out3, *_, held3, path3) = share(None), share(3)
+        (out2, *_, held2, path2, _), (out3, *_, held3, path3, _) = (
+            share(None), share(3))
     assert int(held2.sum()) == int(held3.sum()) == 50
     np.testing.assert_array_equal(path2, [0, 1])        # 50 > 48: two chunks
     np.testing.assert_array_equal(path3, [1, 0])        # 50 <= 72: one

@@ -279,7 +279,7 @@ def test_swiglu_share_with_its_shared_expert_against_the_reference(lo, hi):
     cfg = _layer_config(16, lo, hi)
     w = jnp.asarray(_rng(4).randn(64, 16), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        out, _, counts, held, path = _share(p, lo, hi)
+        out, _, counts, held, path, _ = _share(p, lo, hi)
         # an eighth of the experts: 48 of the 192 rows, and the pairs fit
         assert path is None if (lo, hi) == (0, 16) else list(path) == [1, 0]
         np.testing.assert_allclose(out, _whole(p, cfg, lo, hi), rtol=1e-4,
@@ -324,7 +324,7 @@ def test_a_swiglu_share_on_a_bound_of_its_rows_against_the_reference(
     cfg = _layer_config(16, lo, hi)
     w = jnp.asarray(_rng(4).randn(64, 16), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        out, _, counts, held, path = _share(p, lo, hi, shared=shared)
+        out, _, counts, held, path, _ = _share(p, lo, hi, shared=shared)
         np.testing.assert_allclose(out, _whole(p, cfg, lo, hi), rtol=1e-4,
                                    atol=1e-5)
         g = jax.grad(
@@ -341,6 +341,84 @@ def test_a_swiglu_share_on_a_bound_of_its_rows_against_the_reference(
     assert float(jnp.abs(g["wr"][:, lo:hi]).max()) > 0     # the gates' path
 
 
+@pytest.mark.parametrize("n", [0, 1, 15, 16, 17, 47, 48])
+def test_a_chunks_gather_and_sum_against_the_plain_gather_and_add(n):
+    """`_rows_of` and `_add_rows` over a chunk of R 48 rows of which `n` are
+    live (none, one, ..., all but one, all), against `x[tok]` and
+    `.at[tok].add` of the gate-weighted rows, forward and VJP, float32: the
+    sums go through each token's k slots (the sort's inverse), not through
+    the rows' tokens. The pairs behind the live rows are RANDOM and drawn
+    twice: with the rows behind the live ones masked as a chunk masks them
+    (`alive`), neither draw shows."""
+    T, k, d, R = 40, 3, 16, 48
+    r = _rng(n)
+    x = jnp.asarray(r.randn(T, d), jnp.float32)
+    acc = jnp.asarray(r.randn(T, d), jnp.float32)
+    rows = jnp.asarray(r.randn(R, d), jnp.float32)
+    gates = jnp.asarray(r.rand(T, k), jnp.float32)
+    g_rows = jnp.asarray(r.randn(R, d), jnp.float32)
+    g_acc = jnp.asarray(r.randn(T, d), jnp.float32)
+    alive = lambda a: jnp.where(  # noqa: E731
+        (jnp.arange(R) < n)[:, None], a, 0.0)
+    results = []
+    for draw in range(2):
+        # a sort of the T x k pairs whose first n rows are the same pairs in
+        # both draws; this chunk is its first R rows
+        order = np.concatenate([_rng(7).permutation(T * k)[:n], np.setdiff1d(
+            _rng(10 + draw).permutation(T * k), _rng(7).permutation(T * k)[:n],
+            assume_unique=True)])
+        pairs = jnp.asarray(order[:R], jnp.int32)
+        tok = pairs // k
+        slot_row = np.argsort(order).reshape(T, k)
+        slot_live = jnp.asarray(slot_row < n)
+        slot_row = jnp.asarray(np.clip(slot_row, 0, R - 1), jnp.int32)
+        got, vjp = jax.vjp(
+            lambda x: moe_ops._rows_of(x, tok, slot_row, slot_live), x)
+        want, vjp_plain = jax.vjp(lambda x: x[tok], x)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_allclose(vjp(alive(g_rows))[0],
+                                   vjp_plain(alive(g_rows))[0],
+                                   rtol=1e-5, atol=1e-6)
+        summed, vjp = jax.vjp(
+            lambda acc, rows, gates: moe_ops._add_rows(
+                acc, alive(rows), gates, pairs, slot_row, slot_live),
+            acc, rows, gates)
+        plain, vjp_plain = jax.vjp(
+            lambda acc, rows, gates: acc.at[tok].add(
+                alive(rows) * gates.reshape(-1)[pairs][:, None]),
+            acc, rows, gates)
+        np.testing.assert_allclose(summed, plain, rtol=1e-5, atol=1e-6)
+        for a, b in zip(vjp(g_acc), vjp_plain(g_acc)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+        results.append((alive(got), summed) + vjp(g_acc))
+    for a, b in zip(*results):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("tokens,chunks", [(13, 1), (24, 1), (25, 2)],
+                         ids=["inside_a_chunk", "on_its_edge", "past_R"])
+def test_a_share_sums_its_chunks_through_the_slots_against_the_reference(
+        tokens, chunks):
+    """2 of 16 held, R = 48 rows: `tokens` steered to both held experts are
+    26 live pairs (the live rows end inside the chunk), 48 (on its edge) and
+    50 (a second chunk of two live rows). Values, every input's gradient,
+    and the chunks' rows: [the live ones, chunks x R]."""
+    lo, hi = 8, 10
+    p = _steered(_layer_inputs(), lo, hi, tokens)
+    cfg = _layer_config(16, lo, hi)
+    w = jnp.asarray(_rng(4).randn(64, 16), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out, _, _, held, _, rows = _share(p, lo, hi)
+        np.testing.assert_allclose(out, _whole(p, cfg, lo, hi), rtol=1e-4,
+                                   atol=1e-5)
+        g = jax.grad(lambda p: (_share(p, lo, hi)[0] * w).sum())(p)
+        r = jax.grad(lambda p: (_whole(p, cfg, lo, hi) * w).sum())(p)
+    assert int(held.sum()) == 2 * tokens
+    np.testing.assert_array_equal(rows, [2 * tokens, chunks * 48])
+    for name in ("x", "wr", "gate", "up", "down", "gate_s", "up_s", "down_s"):
+        assert _rel(g[name], r[name]) < 1e-4, (name, _rel(g[name], r[name]))
+
+
 def test_the_eight_shares_add_up():
     """E 16 as 8 shares of 2, each in one chunk of 48 of the 192 rows: every
     share's routed part, plus the SwiGLU shared expert counted once, is the
@@ -350,7 +428,7 @@ def test_the_eight_shares_add_up():
         whole = _whole(p, _layer_config(16, 0, 16))
         total, pairs = 0.0, 0
         for lo in range(0, 16, 2):
-            out, _, counts, held, path = _share(p, lo, lo + 2,
+            out, _, counts, held, path, _ = _share(p, lo, lo + 2,
                                                 shared=(lo == 0))
             total, pairs = total + out, pairs + int(held.sum())
             np.testing.assert_array_equal(path, [1, 0])

@@ -211,7 +211,7 @@ def test_the_eight_shares_add_up():
         whole = _whole(p, _layer_config(64, 0, 64))
         total, pairs = 0.0, 0
         for lo in range(0, 64, 8):
-            out, _, counts, held, _ = _share(p, lo, lo + 8)
+            out, _, counts, held, _, _ = _share(p, lo, lo + 8)
             total, pairs = total + out, pairs + int(held.sum())
     np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-5)
     assert pairs == 64 * 4 == int(counts.sum())

@@ -606,7 +606,7 @@ def test_held_experts_against_the_reference(lo, hi):
                             p["down_s"])[0]
 
     with jax.default_matmul_precision("highest"):
-        out, logits, counts, held, path = _share(p, lo, hi)
+        out, logits, counts, held, path, _ = _share(p, lo, hi)
         # a quarter of the experts: chunks of 96 of the 192 rows
         assert (path is None) == ((lo, hi) == (0, 16))
         np.testing.assert_allclose(out, want(p), rtol=1e-4, atol=1e-5)
@@ -668,7 +668,7 @@ def test_a_share_on_a_bound_of_its_rows_against_the_reference(
                             p["down_s"])[0]
 
     with jax.default_matmul_precision("highest"):
-        out, _, counts, pairs, path = got(p)
+        out, _, counts, pairs, path, _ = got(p)
         np.testing.assert_allclose(out, want(p), rtol=1e-4, atol=1e-5)
         g = jax.grad(lambda p: (got(p)[0] * w).sum())(p)
         r = jax.grad(lambda p: (want(p) * w).sum())(p)
@@ -693,7 +693,7 @@ def test_the_shares_add_up():
                              p["down_s"])[0]
         total, pairs = 0.0, 0
         for lo in range(0, 32, 4):
-            out, _, counts, held, path = _share(p, lo, lo + 4,
+            out, _, counts, held, path, _ = _share(p, lo, lo + 4,
                                                 shared=(lo == 0))
             total, pairs = total + out, pairs + int(held.sum())
             np.testing.assert_array_equal(path, [1, 0])
@@ -800,11 +800,13 @@ def test_olmoe_arguments_append_the_op_they_did():
     assert shapes["nemo.up"] == (2, 16, 8) and shapes["nemo.router"] == (16, 8)
     assert not prog.global_block().var("nemo.router_bias").trainable
     # 2 of 8 held: under half of the experts, so its rows run in chunks
-    assert sorted(new.outputs) == ["HeldPairs", "Out", "RouterLogits",
-                                   "RowPath", "TokensPerExpert"]
+    assert sorted(new.outputs) == ["ChunkRows", "HeldPairs", "Out",
+                                   "RouterLogits", "RowPath",
+                                   "TokensPerExpert"]
     assert [s["counter"] for s in prog.step_statistics] == [
         "pt_moe_expert_tokens_total", "pt_moe_expert_tokens_total",
-        "pt_moe_held_pairs_total", "pt_moe_row_path_total"]
+        "pt_moe_held_pairs_total", "pt_moe_row_path_total",
+        "pt_moe_chunk_rows_total"]
 
 
 # ------------------------------ the whole model against the plain reference ---

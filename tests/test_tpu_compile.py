@@ -399,7 +399,11 @@ def test_plain_and_differentiated_attention_stay_two_launches(
 # `ops/qk_ops.py:qk_assemble`, a `custom_vjp` and two Pallas launches each
 # way, where a float32 norm and rotary stood; the inference program holds the
 # forward launch): olmoe's two step digests and its inference digest are PR
-# 47's. gpt2-small has no norm and no rotary on Q or K: its three stand.
+# 47's. gpt2-small has no norm and no rotary on Q or K: its three stand. PR 51
+# meant to change the share cells' step programs (a chunk's sums are gathers
+# through the sort's inverse: `ops/moe_ops.py:_sum_of_slots`); no digest of
+# theirs is kept here (their tests below hold their memory and their counts),
+# and `moe_ffn(held=None)` is olmoe's path: these four digests stand.
 STEP_PROGRAMS = {
     "gpt2-small": (
         "67175aee1ee0d338d3262d309294bef3754189380ee3398ca6e60179ed6e9e39",
@@ -975,8 +979,11 @@ def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
     over chunks, and as often as the whole-rows share launched them (2
     forward; 2 again, 2 `gmm` and 2 `tgmm` backward), so the program holds
     no second copy of the kernels; no float array of 49 152 rows exists
-    anywhere; and the step compiles for the chip with the loops and the
-    kernels in it and no conditional."""
+    anywhere; no d-wide rows are scattered, forward or backward (PR 51): the
+    rows move by gathers alone, of a chunk's 6144 rows or of one of a
+    token's slots (half of the 8192 tokens at a time: 49 152 pairs are
+    eight chunks), each inside a chunk's loop; and the step compiles
+    for the chip with the loops and the kernels in it and no conditional."""
     from paddle_tpu.ops import moe_ops
 
     monkeypatch.setattr(moe_ops, "gmm_eligible", moe_ops._shapes_gmm_ok)
@@ -985,7 +992,7 @@ def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
 
     def step(x, wr, up, down):
         def cost(x, wr, up, down):
-            out, _, _, pairs, path = moe_ops.moe_ffn(
+            out, _, _, pairs, path, _ = moe_ops.moe_ffn(
                 x, wr, None, up, down, k, True, scoring="sigmoid",
                 gate_scale=2.5, held=(0, held))
             return out.astype(F32).sum(), (pairs, path)
@@ -996,10 +1003,15 @@ def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
         ((T, d), F32), ((d, E), F32), ((held, d, f), BF16),
         ((held, f, d), BF16))]
-    kernels, whole_rows = [], []
+    kernels, whole_rows, moved = [], [], []
 
     def walk(jaxpr, loops):
         for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("gather", "scatter-add", "scatter"):
+                moved.extend(
+                    (eqn.primitive.name, loops, v.aval.shape[0])
+                    for v in (*eqn.invars[2:], *eqn.outvars)
+                    if v.aval.ndim == 2 and v.aval.shape[1] == d)
             if eqn.primitive.name == "pallas_call":
                 kernels.append((loops, {
                     v.aval.shape[0] for v in eqn.invars
@@ -1015,10 +1027,13 @@ def test_a_share_under_a_half_runs_its_kernels_on_a_chunk_of_its_rows(
     walk(jax.make_jaxpr(step)(*args).jaxpr, 0)
     assert kernels == [(1, {6144})] * 8
     assert not whole_rows, whole_rows
+    assert {m[0] for m in moved} == {"gather"}, moved
+    assert {m[1:] for m in moved} == {(1, 6144), (1, T // 2)}, moved
     text = jax.jit(step).lower(*args).compile().as_text()
     assert len(re.findall(r" while\(", text)) >= 2     # forward, backward
     assert " conditional(" not in text
     assert "tpu_custom_call" in text and "ragged-dot" not in text
+    assert not re.findall(r"= f32\[\d+,%d\]\S* scatter\(" % d, text)
 
 
 def _written_arrays(hlo_text):
