@@ -32,6 +32,7 @@ import numpy as np
 from . import provenance, registry
 from .. import profiler
 from ..flags import FLAGS
+from ..obs import metrics
 from ..tune import overrides as tune_overrides
 from .lod import LoDArray
 from .place import Place, default_place
@@ -98,8 +99,16 @@ class Scope:
 
     def __init__(self):
         self.vars: Dict[str, Any] = {}
+        # what an Executor's step plan is valid against: `layout` moves
+        # when `set` adds a name (replacing a value leaves it), `writes`
+        # with every `set`
+        self.layout = 0
+        self.writes = 0
 
     def set(self, name: str, value) -> None:
+        if name not in self.vars:
+            self.layout += 1
+        self.writes += 1
         self.vars[name] = value
 
     def get(self, name: str):
@@ -166,6 +175,20 @@ def _feed_signature(feed: Dict[str, Any]):
             )
         )
     return tuple(sig)
+
+
+def _device_feed(feed) -> Dict[str, Any]:
+    """A copy of `feed` with its numpy values as jax arrays. Committed jax
+    arrays (the DevicePrefetcher path puts every batch on device ahead of
+    time) pass through untouched: re-wrapping them in jnp.asarray would
+    re-hash and re-place each one every batch."""
+    return {k: jnp.asarray(v) if isinstance(v, np.ndarray) else v
+            for k, v in (feed or {}).items()}
+
+
+def _fetch_names(fetch_list) -> tuple:
+    return tuple(v.name if isinstance(v, Variable) else v
+                 for v in (fetch_list or ()))
 
 
 def _op_scope(op) -> str:
@@ -375,6 +398,34 @@ def _tree_bytes(tree) -> tuple:
         int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize for a in leaves)
 
 
+class _StepPlan:
+    """What `Executor.run` / `run_window` derive from the program, the
+    names the scope holds, the feed's signature and the fetch list, and
+    not from a step's values: the persistable names in the order they are
+    passed (sorted, as the state dicts flatten), split into the donated
+    and the kept, and the compiled function. Valid while the scope's
+    layout is `stamp`; every other input is in the plan's key. It holds
+    names, never an array: the scope's reference to a donated buffer stays
+    the last one.
+
+    `clean_at` is the scope's `writes` when this plan's last call had
+    committed, None before one: while the scope still reads that, every
+    name holds what this executor put there (a step's own outputs, each
+    its own array; the kept ones placed), so the proof that no buffer is
+    donated twice and the placing of the kept need not be made again."""
+
+    __slots__ = ("stamp", "program", "donated", "kept", "fn", "clean_at")
+
+    def __init__(self, stamp, program, donated, kept):
+        self.stamp, self.program = stamp, program
+        self.donated, self.kept = donated, kept
+        self.fn = self.clean_at = None
+
+
+_PLANS_COUNTER = "pt_executor_plans_total"
+_PLANS_HELP = ("Executor.run / run_window calls by what became of the step "
+               "plan: built (the first call, or the scope's names or the "
+               "program moved) or reused")
 _DONATION_KEYS = ("donated_buffers", "donated_bytes", "kept_buffers",
                   "kept_bytes", "mismatches")
 # every live Executor, for the registry's pt_executor_* gauges
@@ -406,7 +457,11 @@ class Executor:
     """Reference API: fluid executor.py:71 `Executor(place).run(program,
 
     feed, fetch_list)`. Compilation is cached per (program version, feed
-    shapes, fetch list).
+    shapes, fetch list), and so is a call's bookkeeping: which persistables
+    the scope holds, in which order they are passed, which are donated
+    (`_StepPlan`). A call whose plan is valid gathers the arrays, draws
+    the seed, calls and writes back; the plan is rebuilt when a name is
+    added to the scope or the program's version moves.
 
     The buffers of the persistables a program rebinds
     (`rebound_persistables`) are donated to the step, so a training step
@@ -433,7 +488,15 @@ class Executor:
         self._cache: Dict[Any, Any] = {}
         # jit-cache accounting (the serving layer surfaces these in
         # /metrics): a miss = one whole-program trace + XLA compile
-        self.cache_stats: Dict[str, int] = {"hits": 0, "misses": 0}
+        # `plans_built` / `plans_reused`: calls that listed the scope's
+        # persistables anew / that found their step plan valid
+        self.cache_stats: Dict[str, int] = {
+            "hits": 0, "misses": 0, "plans_built": 0, "plans_reused": 0}
+        # scope -> {plan key: _StepPlan}; a plan dies with its scope
+        self._plans = weakref.WeakKeyDictionary()
+        for outcome in ("built", "reused"):
+            metrics.registry().declare_counter(
+                _PLANS_COUNTER, help=_PLANS_HELP, labels={"outcome": outcome})
         # one record per compiled step program, written when it traces
         self._donation: List[Dict[str, int]] = []
         # one `provenance.table`, its published rows, per step program compiled
@@ -508,6 +571,70 @@ class Executor:
         shard_maps eligible pallas calls over the dp axis."""
         return contextlib.nullcontext()
 
+    # -- the step plan --------------------------------------------------
+    def _plan_key(self, program: Program, mode: tuple, feed,
+                  fetch_names: tuple) -> tuple:
+        """What a step plan and its compiled function depend on besides
+        the names the scope holds. `mode` tells a window from a step."""
+        return self._cache_key_prefix() + self._program_trace_key(program) \
+            + mode + (_feed_signature(feed), fetch_names)
+
+    def _planned_state(self, program: Program, scope: Scope, key: tuple):
+        """(plan, donated, kept): the call's `_StepPlan`, built if `scope`
+        has none under `key` or has gained or lost a name since, and the
+        persistables gathered by it. Only a scope somebody else wrote since
+        this plan's last call is proven again to hand no buffer over twice
+        (`_own_buffers`) and walked for host values (`_place_kept`)."""
+        plans = self._plans.get(scope)
+        if plans is None:
+            plans = self._plans[scope] = {}
+        plan = plans.get(key)
+        # the length: a caller may empty `scope.vars` behind `set`'s back
+        stamp = (scope.layout, len(scope.vars))
+        if plan is None or plan.stamp != stamp:
+            rebound = rebound_persistables(program)
+            names = sorted(v.name for v in program.persistables()
+                           if scope.has(v.name))
+            # keeps the program alive: the key holds its id(), which
+            # could be recycled if the program were garbage collected
+            plan = plans[key] = _StepPlan(
+                stamp, program,
+                tuple(n for n in names if n in rebound),
+                tuple(n for n in names if n not in rebound))
+            outcome = "built"
+        else:
+            outcome = "reused"
+        self.cache_stats["plans_" + outcome] += 1
+        metrics.registry().counter_inc(_PLANS_COUNTER,
+                                       labels={"outcome": outcome})
+        held = scope.vars
+        donated = {n: held[n] for n in plan.donated}
+        kept = {n: held[n] for n in plan.kept}
+        if plan.clean_at != scope.writes:
+            donated = _own_buffers(donated, kept)
+            kept = self._place_kept(program, scope, kept)
+        plan.clean_at = None  # until this call has committed
+        return plan, donated, kept
+
+    def _planned_fn(self, plan: _StepPlan, key: tuple, build, args):
+        """The plan's compiled function, looked up (or built by
+        `build(persist_names)`) the first time; `args` are the call's
+        arguments, for the record of a freshly compiled program."""
+        if plan.fn is None:
+            names = sorted(plan.donated + plan.kept)
+            key += (tuple(names),)
+            cached = self._cache.get(key)
+            if cached is None:
+                self.cache_stats["misses"] += 1
+                plan.fn = build(names)
+                self._cache[key] = (plan.program, plan.fn)
+                if FLAGS.enable_timers:
+                    self._read_provenance(plan.fn, *args)
+                return plan.fn
+            plan.fn = cached[1]
+        self.cache_stats["hits"] += 1
+        return plan.fn
+
     # ------------------------------------------------------------------
     def run(
         self,
@@ -526,55 +653,19 @@ class Executor:
             as_numpy = return_numpy
         with profiler.timer("executor.prepare"):
             program = program or default_main_program()
-            feed = dict(feed or {})
             scope = scope or global_scope()
-            fetch_names = [
-                v.name if isinstance(v, Variable) else v
-                for v in (fetch_list or [])
-            ]
-
-            # normalize feed values to jax-compatible arrays. Committed
-            # jax arrays (the DevicePrefetcher path puts every batch on
-            # device ahead of time) pass through untouched — re-wrapping
-            # them in jnp.asarray would re-hash/re-place each one every
-            # batch
-            for k, v in feed.items():
-                if isinstance(v, jax.Array):
-                    continue
-                if isinstance(v, np.ndarray):
-                    feed[k] = jnp.asarray(v)
-
-            persist_names = sorted(
-                v.name
-                for v in program.persistables()
-                if scope.has(v.name)
-            )
-            key = self._cache_key_prefix() + \
-                self._program_trace_key(program) + (
-                    _feed_signature(feed),
-                    tuple(fetch_names),
-                    tuple(persist_names),
-                )
-            state = {n: scope.get(n) for n in persist_names}
-            donated, kept = self._split_state(program, state)
-            kept = self._place_kept(program, scope, kept)
-            seed = jnp.asarray(self._draw_seed(program), dtype=jnp.uint32)
+            feed = _device_feed(feed)
+            fetch_names = _fetch_names(fetch_list)
+            key = self._plan_key(program, (), feed, fetch_names)
+            plan, donated, kept = self._planned_state(program, scope, key)
+            # a host scalar: the call places it with its other arguments
+            seed = np.uint32(self._draw_seed(program))
             donated, feed, seed = self._place_inputs(
                 program, donated, feed, seed)
-
-            cached = self._cache.get(key)
-            if cached is None:
-                self.cache_stats["misses"] += 1
-                fn = self._compile(program, feed, fetch_names, persist_names)
-                # keep a strong ref to the program: the key uses
-                # id(program), which may be recycled if the program were
-                # garbage collected
-                self._cache[key] = (program, fn)
-                if FLAGS.enable_timers:
-                    self._read_provenance(fn, donated, kept, feed, seed)
-            else:
-                self.cache_stats["hits"] += 1
-                fn = cached[1]
+            fn = self._planned_fn(
+                plan, key,
+                lambda names: self._compile(program, feed, fetch_names, names),
+                (donated, kept, feed, seed))
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
             fetches, new_state, extras = fn(donated, kept, feed, seed)
@@ -592,10 +683,11 @@ class Executor:
                 )
             for n, v in new_state.items():
                 scope.set(n, v)
+            plan.clean_at = scope.writes
             # the scope held the other reference to every replaced
             # (donated: deleted by the call) array: drop the last one
             # here, so that it is timed in this span and not in the return
-            del state, donated
+            del donated
             if as_numpy:
                 fetches = [
                     np.asarray(f) if not isinstance(f, LoDArray) else f
@@ -778,39 +870,21 @@ class Executor:
         them is the caller's sync decision)."""
         with profiler.timer("executor.prepare"):
             program = program or default_main_program()
-            feed = dict(feed or {})
             scope = scope or global_scope()
-            fetch_names = [
-                v.name if isinstance(v, Variable) else v
-                for v in (fetch_list or [])
-            ]
+            feed = _device_feed(feed)
+            fetch_names = _fetch_names(fetch_list)
             if acc_state is not None and not fetch_names:
                 raise ValueError(
                     "run_window with acc_state needs fetch_list[0] = cost")
-            for k, v in feed.items():
-                if isinstance(v, jax.Array):
-                    continue
-                if isinstance(v, np.ndarray):
-                    feed[k] = jnp.asarray(v)
             leaves = jax.tree_util.tree_leaves(feed)
             if not leaves:
                 raise ValueError("run_window needs at least one feed slot")
-            k_steps = int(leaves[0].shape[0])
-            persist_names = sorted(
-                v.name for v in program.persistables() if scope.has(v.name)
-            )
-            key = self._cache_key_prefix() + \
-                self._program_trace_key(program) + (
-                    "scan_window",
-                    bool(skip_nonfinite),
-                    acc_state is not None,
-                    _feed_signature(feed),  # window size K: the leading dim
-                    tuple(fetch_names),
-                    tuple(persist_names),
-                )
-            state = {n: scope.get(n) for n in persist_names}
-            donated, kept = self._split_state(program, state)
-            kept = self._place_kept(program, scope, kept)
+            k_steps = int(leaves[0].shape[0])  # the window: the leading dim
+            with_acc = acc_state is not None
+            key = self._plan_key(
+                program, ("scan_window", bool(skip_nonfinite), with_acc),
+                feed, fetch_names)
+            plan, donated, kept = self._planned_state(program, scope, key)
             # the window donates the accumulator with the state, and a
             # fresh pass's holds one zero under several leaves
             acc_state = _own_buffers(acc_state, (donated, kept))
@@ -822,23 +896,14 @@ class Executor:
             # already-resident array is a cheap no-copy.
             donated, kept, acc_state = jax.device_put(
                 (donated, kept, acc_state), self.place.device)
-            seeds = jnp.asarray(
+            seeds = np.asarray(
                 [self._draw_seed(program) for _ in range(k_steps)],
-                dtype=jnp.uint32)
-
-            cached = self._cache.get(key)
-            if cached is None:
-                self.cache_stats["misses"] += 1
-                fn = self._build_window(
-                    program, fetch_names,
-                    skip_nonfinite, acc_state is not None)
-                self._cache[key] = (program, fn)
-                if FLAGS.enable_timers:
-                    self._read_provenance(fn, donated, kept, feed, seeds,
-                                          acc_state)
-            else:
-                self.cache_stats["hits"] += 1
-                fn = cached[1]
+                dtype=np.uint32)
+            fn = self._planned_fn(
+                plan, key,
+                lambda names: self._build_window(
+                    program, fetch_names, skip_nonfinite, with_acc),
+                (donated, kept, feed, seeds, acc_state))
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
             ys, new_state, acc_out, rebound_kept, created = fn(
@@ -855,5 +920,6 @@ class Executor:
                 # stacked K copies of a step-created persistable: keep the
                 # last step's value (what the step loop's scope would hold)
                 scope.set(n, jax.tree_util.tree_map(lambda a: a[-1], v))
-            del state, donated  # as in run(): they die in the span
+            plan.clean_at = scope.writes
+            del donated  # as in run(): it dies in the span
         return ys, acc_out

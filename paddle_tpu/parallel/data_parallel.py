@@ -230,10 +230,10 @@ class ParallelExecutor(Executor):
 
         prog = program or default_main_program()
         scope_ = scope or global_scope()
-        creates_new = any(
-            not scope_.has(v.name) for v in prog.persistables()
-        )
-        if creates_new and not feed and not fetch_list:
+        # only a call with no feed and no fetch can be init-style: a step
+        # does not list the program's persistables to find out
+        if not feed and not fetch_list and any(
+                not scope_.has(v.name) for v in prog.persistables()):
             return self.run_startup(prog, scope=scope_)
         return super().run(prog, feed=feed, fetch_list=fetch_list,
                            scope=scope_, return_numpy=return_numpy,

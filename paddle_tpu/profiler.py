@@ -29,7 +29,7 @@ The step path's spans (thread; where; what the block covers):
 | `prefetchWait` | trainer | `DevicePrefetcher.__iter__` | the step loop waiting in `q.get()` for a batch |
 | `prepareBatchData` | trainer | `Trainer._step_pass` | in-loop `DataFeeder` (executors that place their own input) |
 | `forwardBackward` | trainer | `_step_pass`, `_scan_pass` | the whole of `Executor.run` / `run_window` |
-| `executor.prepare` | trainer | `Executor.run` / `run_window` | entry to just before the jitted call: feed normalisation, persistables scan, cache key and lookup, state gather, seed, `_place_inputs` |
+| `executor.prepare` | trainer | `Executor.run` / `run_window` | entry to just before the jitted call: feed normalisation, the step plan's key and lookup, state gather in plan order, seed, `_place_inputs`; only a call that builds its plan (the first, or after a name was added to the scope or the program edited) lists, sorts and splits the persistables and looks up or compiles the step function, and only a scope somebody else wrote since the last call is proven again to donate no buffer twice and walked for host values |
 | `executor.call` | trainer | around `fn(donated, kept, feed, seed)` | the jitted call as Python sees it, and anything that blocks inside it (the first call of a shape traces and compiles here) |
 | `executor.commit` | trainer | after the call to return | `check_nan_inf`, `scope.set` of every new state buffer and the release of the buffers they replace, `as_numpy` |
 | `accumUpdate` | trainer | around `acc.update(...)` | the step's second dispatch (`accum_fold`) |

@@ -221,7 +221,8 @@ def test_only_a_traced_run_lowers_a_step_program_again(monkeypatch, timers):
     first = exe.run(feed=feed, fetch_list=[cost])
     again = exe.run(feed=feed, fetch_list=[cost])   # a cache hit
     assert np.isfinite(first[0]) and again[0] < first[0]
-    assert exe.cache_stats == {"hits": 1, "misses": 2}
+    assert exe.cache_stats == {"hits": 1, "misses": 2,
+                               "plans_built": 2, "plans_reused": 1}
     # once a compiled program (startup, step), with the call's own arguments
     assert lowered == ([4, 4] if timers else [])
     assert len(exe._provenance) == (2 if timers else 0)

@@ -170,6 +170,30 @@ def test_obs_trace_spans_are_annotations_too(monkeypatch):
         ["inner", "outer", "timed"]
 
 
+@pytest.mark.parametrize("window", [False, True], ids=["run", "run_window"])
+def test_executor_spans_once_a_call_in_order_plan_built_or_reused(window):
+    """The per-layer readers sum `executor.prepare` / `.call` / `.commit`
+    by name: each opens and closes once a call, in that order, whether the
+    call built its step plan or found it."""
+    trainer, prog, reader = _tiny_trainer()
+    exe, feed = trainer.exe, next(reader())
+    if window:
+        feed = {k: np.stack([v, v]) for k, v in feed.items()}
+
+    def call():
+        run = exe.run_window if window else exe.run
+        run(prog, feed=feed, fetch_list=[trainer.cost], scope=trainer.scope)
+
+    for outcome in ("plans_built", "plans_reused", "plans_reused"):
+        before = exe.cache_stats[outcome]
+        with obs_trace.tracing() as tr:
+            call()
+        assert exe.cache_stats[outcome] == before + 1
+        closed = [e[1] for b in tr._bufs for e in b.events]
+        assert [n for n in closed if n.startswith("executor.")] == \
+            ["executor.prepare", "executor.call", "executor.commit"], closed
+
+
 # -- (c) off: zero cost -----------------------------------------------------
 def test_off_path_is_one_shared_noop(monkeypatch):
     assert not FLAGS.enable_timers and not obs_trace.armed()
