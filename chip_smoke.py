@@ -90,31 +90,16 @@ def _emit(phase: str, dev: dict, rehearse: bool, **fields) -> dict:
     return rec
 
 
-class _CompileClock:
+def _compile_fields() -> dict:
     """Seconds JAX spent in backend compiles (or, on a persistent-cache
-    hit, in reading the executable back) plus the hit/miss counts."""
+    hit, in reading the executable back) plus the hit/miss counts, in this
+    process so far: the program's own record (`core/build.py`'s listener,
+    the one the repo has beside the yardstick's)."""
+    from paddle_tpu.core import build
 
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.seconds = 0.0
-        self.hits = self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _on_event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def fields(self) -> dict:
-        return {"compile_s": round(self.seconds, 2),
-                "cache_hits": self.hits, "cache_misses": self.misses}
+    t = build.compile_totals()
+    return {"compile_s": round(t["compile_s"], 2),
+            "cache_hits": t["cache_hits"], "cache_misses": t["cache_misses"]}
 
 
 def _config_for(name: str, work: str, sizes: dict) -> str:
@@ -265,7 +250,6 @@ def _train_phase(args, work, phase, config, make_feed, min_kernels):
     """Train `configs/<config>.py` through the CLI, then check the loss
     and that the step it ran holds the compiled kernels."""
     dev = _device(args.rehearse_cpu)
-    clock = _CompileClock()
     import paddle_tpu as pt
 
     over = REHEARSAL_SIZES[config] if args.rehearse_cpu else {}
@@ -278,11 +262,11 @@ def _train_phase(args, work, phase, config, make_feed, min_kernels):
     tok_s = n * sz["batch"] * sz["seqlen"] / (stamps[-1] - stamps[1])
     log(f"{phase}: {out['steps']} steps, {tok_s:.0f} tok/s "
         f"(per-step host read included)")
-    train = clock.fields()
+    train = _compile_fields()
     text = _step_text(pt.Executor(), make_feed(sz))
     out.update(_kernel_fields(
         text, 0 if args.rehearse_cpu else min_kernels(sz),
-        clock.hits - train["cache_hits"]))
+        _compile_fields()["cache_hits"] - train["cache_hits"]))
     _emit(phase, dev, args.rehearse_cpu, tok_per_s=round(tok_s, 1),
           **out, **train)
 
@@ -308,7 +292,6 @@ def phase_serve_prepare(args, work):
     """Save the artifact and the oracle answers, then EXIT: this process
     holds the chip, and the server child needs it next."""
     dev = _device(args.rehearse_cpu)
-    clock = _CompileClock()
     import numpy as np
 
     import paddle_tpu as pt
@@ -341,7 +324,7 @@ def phase_serve_prepare(args, work):
         oracle[f"x{b}"], oracle[f"y{b}"] = x, np.asarray(y, np.float32)
     np.savez(os.path.join(work, "oracle.npz"), **oracle)
     _emit("serve_prepare", dev, args.rehearse_cpu, model_dir=model_dir,
-          batches=[1, sz["max_batch"]], **clock.fields())
+          batches=[1, sz["max_batch"]], **_compile_fields())
 
 
 def _first_step_grads(config: str, make_executor) -> dict:
@@ -374,7 +357,6 @@ def phase_four_chips(args, work):
     gradients side by side."""
     dev = _device(args.rehearse_cpu)
     assert dev["count"] >= 4, f"--four-chips needs 4 devices: {dev}"
-    clock = _CompileClock()
     import jax
     import numpy as np
 
@@ -389,7 +371,7 @@ def phase_four_chips(args, work):
     cfg = _config_for("lstm_benchmark", work, over)
 
     dp4, _ = _cli_train(cfg, ["--mesh", "dp4"])
-    out, hits0 = {}, clock.hits
+    out, hits0 = {}, _compile_fields()["cache_hits"]
     # what the dp4 run left behind and what it compiled
     exe = ParallelExecutor(mesh_from_spec("dp4"))
     scope = pt.global_scope()
@@ -405,7 +387,7 @@ def phase_four_chips(args, work):
         [(s.device, s.data.shape) for s in shards]
     text = _step_text(exe, feed)
     out.update(_kernel_fields(text, 0 if args.rehearse_cpu else 4,
-                              clock.hits - hits0))
+                              _compile_fields()["cache_hits"] - hits0))
     if not args.rehearse_cpu:
         # inside the shard_map the kernel sees the per-chip batch
         local = f"[{sz['seqlen']},{sz['batch'] // 4},{4 * sz['hidden']}]"
@@ -451,7 +433,7 @@ def phase_four_chips(args, work):
           grad_tensors=len(worst), grad_worst_tensor=name,
           grad_max_rel_diff=round(worst[name], 5),
           grad_tol=FOUR_CHIP_GRAD_TOL, devices_holding_shards=4,
-          **out, **clock.fields())
+          **out, **_compile_fields())
 
 
 PHASES = {

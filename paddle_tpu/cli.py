@@ -224,9 +224,12 @@ def _cmd_train(argv) -> int:
     from .obs import trace as obs_trace
 
     if cfg.get("trace_out"):
-        # arm before the model builds so warmup/compile spans are in the
-        # capture too; exported in the finally below (and idempotently
-        # by the atexit hook if the env flag armed it first)
+        # arm before the model builds so that the capture holds the
+        # builds too: each `executor.call` that compiles has an
+        # `executor.build` span in it, with its phases (`build.trace`,
+        # `.lower`, `.compile`) and why it was built (core/build.py);
+        # exported in finish() below (and idempotently by the atexit
+        # hook if the env flag armed it first)
         obs_trace.arm(out=cfg["trace_out"])
     model = _load_config(cfg["config"])
     if FLAGS.stats_period:

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import build as builds
 from . import provenance, registry
 from .. import profiler
 from ..flags import FLAGS
@@ -502,6 +503,11 @@ class Executor:
         # one `provenance.table`, its published rows, per step program compiled
         # with timers on
         self._provenance: List[dict] = []
+        # one `build.Build` per step program built, in order, and what the
+        # last build of each Program depended on (keyed by its id(): the
+        # cache keeps the Program alive)
+        self.builds: List[builds.Build] = []
+        self._built: Dict[int, tuple] = {}
         _executors.add(self)
 
     @property
@@ -548,18 +554,20 @@ class Executor:
         return jax.jit(self._raw_step(program, fetch_names),
                        donate_argnums=(0,))
 
-    def _read_provenance(self, fn, *args) -> None:
+    def _read_provenance(self, fn, *args) -> str:
         """A traced run's record of a freshly compiled step program: which
         program op each instruction of its optimized HLO belongs to
-        (`core/provenance.py`), for the readers of a device trace. One more
-        lowering and one more read of the compile cache, before the first
-        call, while the arguments are alive; `fn` itself is called as ever."""
-        with self._device_context(), self._trace_context():
-            text = fn.lower(*args).compile().as_text()
+        (`core/provenance.py`), for the readers of a device trace. The
+        lowering and the compile (or cache read) happen here, ahead of the
+        first call and while the arguments are alive; `fn` itself is called
+        as ever and finds jit's caches full. Returns the table's label of
+        the program."""
+        text = fn.lower(*args).compile().as_text()
         table = provenance.table(text)
         # kept for the process's life: only what a trace cannot name itself
         self._provenance.append(
             dict(table, rows=provenance.published(table["rows"])))
+        return table["program"]
 
     def _device_context(self):
         return jax.default_device(self.place.device)
@@ -616,24 +624,51 @@ class Executor:
         plan.clean_at = None  # until this call has committed
         return plan, donated, kept
 
-    def _planned_fn(self, plan: _StepPlan, key: tuple, build, args):
+    def _planned_fn(self, plan: _StepPlan, key: tuple, build,
+                    kind: str = "step"):
         """The plan's compiled function, looked up (or built by
-        `build(persist_names)`) the first time; `args` are the call's
-        arguments, for the record of a freshly compiled program."""
+        `build(persist_names)`) the first time. A function just built comes
+        back inside `_first_call`, which is what the caller then calls."""
         if plan.fn is None:
             names = sorted(plan.donated + plan.kept)
-            key += (tuple(names),)
-            cached = self._cache.get(key)
+            full = key + (tuple(names),)
+            cached = self._cache.get(full)
             if cached is None:
                 self.cache_stats["misses"] += 1
                 plan.fn = build(names)
-                self._cache[key] = (plan.program, plan.fn)
-                if FLAGS.enable_timers:
-                    self._read_provenance(plan.fn, *args)
-                return plan.fn
+                self._cache[full] = (plan.program, plan.fn)
+                return self._first_call(plan, key, full[-1], kind)
             plan.fn = cached[1]
         self.cache_stats["hits"] += 1
         return plan.fn
+
+    def _first_call(self, plan: _StepPlan, key: tuple, names: tuple,
+                    kind: str):
+        """`plan.fn`, for its first call only, as a build (`core/build.py`):
+        the call traces, lowers and compiles inside an `executor.build` span
+        that says which program this is and why it was built, and a traced
+        run reads the program's provenance there, ahead of the call. `key`
+        ends in the feed's signature and the fetch names; a Program run with
+        neither is a startup program."""
+        depends = (names, key[-2], key[-1], plan.program.version, key[:-2])
+        if kind == "step" and not (key[-2] or key[-1]):
+            kind = "startup"
+        record = builds.Build(
+            self.builds, kind,
+            builds.cause(self._built.get(id(plan.program)), depends),
+            getattr(plan.fn, "__name__", ""))
+        self._built[id(plan.program)] = depends
+        fn = plan.fn
+
+        def first_call(*args):
+            with record:
+                if FLAGS.enable_timers:
+                    with record.provenance():
+                        record.args["module"] = self._read_provenance(
+                            fn, *args)
+                return fn(*args)
+
+        return first_call
 
     # ------------------------------------------------------------------
     def run(
@@ -664,8 +699,7 @@ class Executor:
                 program, donated, feed, seed)
             fn = self._planned_fn(
                 plan, key,
-                lambda names: self._compile(program, feed, fetch_names, names),
-                (donated, kept, feed, seed))
+                lambda names: self._compile(program, feed, fetch_names, names))
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
             fetches, new_state, extras = fn(donated, kept, feed, seed)
@@ -903,7 +937,7 @@ class Executor:
                 plan, key,
                 lambda names: self._build_window(
                     program, fetch_names, skip_nonfinite, with_acc),
-                (donated, kept, feed, seeds, acc_state))
+                "window")
         with self._device_context(), self._trace_context(), \
                 profiler.timer("executor.call"):
             ys, new_state, acc_out, rebound_kept, created = fn(

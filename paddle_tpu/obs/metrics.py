@@ -405,13 +405,18 @@ def _executor_families():
     """What the live Executors' step programs donate (core/executor.py
     `donation_stats`, summed): the buffers and bytes of the persistables
     a program rebinds, consumed by each step, against those it only
-    reads. The families exist, at 0, from the first Executor on."""
+    reads. The families exist, at 0, from the first Executor on. Behind
+    them what building the step programs took and why each was built
+    (core/build.py), from the process's first build on."""
     import sys
 
     executor = sys.modules.get("paddle_tpu.core.executor")
-    st = executor.donation_totals() if executor is not None else None
-    if not st:
+    if executor is None:
         return []
+    built = sys.modules["paddle_tpu.core.build"].families()
+    st = executor.donation_totals()
+    if not st:
+        return built
     return [
         ("pt_executor_donated_buffers", "gauge",
          "persistable buffers the compiled step programs donate (rebound "
@@ -429,7 +434,7 @@ def _executor_families():
          "persistables a step rebound though no op names them as written "
          "(left undonated: a kernel lacks register_op(writes=...))",
          [(None, st["mismatches"])]),
-    ]
+    ] + built
 
 
 def _provenance_families():

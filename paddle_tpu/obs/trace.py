@@ -354,6 +354,23 @@ def _end() -> None:
     b.push(("X", name, cat, t0, t1 - t0, merged or None))
 
 
+def _ended(name: str, cat: str, dur: float,
+           args: Optional[Dict[str, Any]] = None,
+           start: Optional[float] = None) -> None:
+    """A span that ends now (`profiler.record`): it lasted `dur` seconds,
+    or began at `start` on this module's clock."""
+    tr = _trace
+    if tr is None:
+        return
+    b = tr.buf()
+    merged = dict(b.ctx)
+    if args:
+        merged.update(args)
+    t1 = time.perf_counter()
+    t0 = t1 - dur if start is None else start
+    b.push(("X", name, cat, t0, t1 - t0, merged or None))
+
+
 def span(name: str, cat: str = "host", **args):
     """Context manager recording one span. Disarmed: returns the no-op
     singleton. (Building `args` still costs a dict at the call site —

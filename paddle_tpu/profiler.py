@@ -8,7 +8,8 @@ Reference surface:
 - Per-parameter value/grad stats (TrainerInternal.cpp:81-109).
 
 ONE primitive, three sinks. `timer(name)` (= `StatSet.timer`) is the
-single way the step path records a span:
+single way the step path records a span (`record(name, seconds)` is its
+entry point for a span that is known only once it has ended):
 
 - Off (`FLAGS.enable_timers` false and `obs.trace` disarmed): two
   boolean tests, then the one shared no-op context object. No clock
@@ -37,6 +38,19 @@ The step path's spans (thread; where; what the block covers):
 | `lazyRead` | the reader's | `_LazyScalar.materialize`, first read | a handler reading an event's lazy cost: the third fence |
 | `prefetch.read` | `pt-prefetch` | around the reader's `next()` | the user's reader |
 | `prefetch.batch` | `pt-prefetch` | `DataFeeder.feed` + `device_put` | converting and placing one batch |
+
+Off that path, a handful of times a process and always on (`always=True`:
+in the StatSet and the table `--dump_stats` prints whatever the flags), a
+step program's build (`core/build.py`; each span's args say which program,
+of what kind, built why, and what the compile cache said):
+
+| Span | Thread | Where | Covers |
+|---|---|---|---|
+| `executor.build` | the caller's | `Executor._first_call`, inside the `executor.call` of a call that made its function; for a build `jax.jit` makes on its own (cause `jit_arguments`), handed over by `core/build.py`'s listener | the new function's first call: the phases below and the rest (argument handling, the executable's load, the first dispatch, whatever blocks) |
+| `build.trace` | the caller's | `core/build.py`'s listener, when JAX says the phase ended (`record`: a span that ends then; no annotation) | the Python walk of the Program's ops into a jaxpr |
+| `build.lower` | the caller's | the same | jaxpr to MLIR, Mosaic's lowering of the Pallas kernels in it |
+| `build.compile` | the caller's | the same | XLA's backend compile, or the read of the persistent cache |
+| `build.provenance` | the caller's | `_first_call`, with `FLAGS.enable_timers` only | `Executor._read_provenance` ahead of the call, less the three phases it fires itself: the optimized HLO as text and its parse |
 
 Dispatch is async, so what a span measures depends on whether its block
 reads a result back. `forwardBackward` is the host's side of a step:
@@ -105,16 +119,19 @@ class Stat:
 class _Timer:
     """One live `StatSet.timer` block (the on path only)."""
 
-    __slots__ = ("_stat", "_name", "_traced", "_ann", "_t0")
+    __slots__ = ("_stat", "_name", "_traced", "_args", "_ann", "_t0")
 
-    def __init__(self, stat: Optional[Stat], name: str, traced: bool):
+    def __init__(self, stat: Optional[Stat], name: str, traced: bool,
+                 args: Optional[Dict[str, Any]] = None):
         self._stat = stat
         self._name = name
         self._traced = traced
+        self._args = args
 
     def __enter__(self):
         if self._traced:
-            _trace._begin(self._name, "timer")  # ring + annotation
+            # ring + annotation; `args` is read when the block ends
+            _trace._begin(self._name, "timer", self._args)
         else:
             self._ann = _trace._annotate(self._name)
         self._t0 = time.perf_counter()
@@ -156,17 +173,32 @@ class StatSet:
                     s = self.stats[name] = Stat(name, self.keep_samples)
         return s
 
-    def timer(self, name: str, always: bool = False):
+    def timer(self, name: str, always: bool = False,
+              args: Optional[Dict[str, Any]] = None):
         """The span primitive (REGISTER_TIMER parity; module docstring).
         Off — timers off (and not `always`, the WITH_TIMER compile gate)
         and `obs.trace` disarmed — it returns the shared no-op context
         object; on, a block that feeds the Stat (timers), the calling
-        thread's trace ring (armed) and the profiler's own timeline."""
+        thread's trace ring (armed; `args` are the span's, beside the
+        thread's trace context) and the profiler's own timeline."""
         traced = _trace._armed
         timed = always or FLAGS.enable_timers
         if not (timed or traced):
             return _trace._NULL
-        return _Timer(self.get(name) if timed else None, name, traced)
+        return _Timer(self.get(name) if timed else None, name, traced, args)
+
+    def record(self, name: str, seconds: float,
+               args: Optional[Dict[str, Any]] = None,
+               start: Optional[float] = None) -> None:
+        """A span nobody could stand around: it is known by its duration
+        alone, when it ends, which is now (a phase of a build, reported by
+        JAX as it finishes: `core/build.py`). `timer`'s `always` form less
+        the annotation, which cannot be entered in the past. `start`, a
+        `time.perf_counter()` reading, places the ring's span where its
+        duration, taken on another clock, would cross a neighbour's."""
+        self.get(name).add(seconds)
+        if _trace._armed:
+            _trace._ended(name, "timer", seconds, args, start)
 
     def print_all_status(self) -> str:
         """Formatted table (reference: StatSet::printAllStatus); adds a
@@ -214,8 +246,15 @@ def global_stat_set() -> StatSet:
     return _global_stats
 
 
-def timer(name: str, always: bool = False):
-    return _global_stats.timer(name, always)
+def timer(name: str, always: bool = False,
+          args: Optional[Dict[str, Any]] = None):
+    return _global_stats.timer(name, always, args)
+
+
+def record(name: str, seconds: float,
+           args: Optional[Dict[str, Any]] = None,
+           start: Optional[float] = None) -> None:
+    _global_stats.record(name, seconds, args, start)
 
 
 @contextlib.contextmanager

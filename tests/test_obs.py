@@ -681,6 +681,11 @@ _HOT_PATHS = [
     # (per-admission)
     ("paddle_tpu.serving.scheduler", "_spec_round"),
     ("paddle_tpu.serving.prefix_cache", "get"),
+    # every step's way to its function (ISSUE 55: a build's spans are
+    # opened by `_first_call`, on the miss alone)
+    ("paddle_tpu.core.executor", "_planned_fn"),
+    ("paddle_tpu.core.executor", "run"),
+    ("paddle_tpu.core.executor", "run_window"),
 ]
 
 
@@ -733,3 +738,31 @@ def test_disarmed_tracing_zero_alloc_lint():
                     f"{mod_name}.{fn_name}:{line} calls trace hook "
                     f"{f_.attr}() outside an `if ..._armed` guard — "
                     "that work runs on the DISARMED step path")
+
+
+def test_planned_fn_hit_branch_runs_no_build_code_lint():
+    """`_planned_fn` on a call that finds its function: one test of
+    `plan.fn`, the hit count, the return. Everything of a build is under
+    the `if plan.fn is None:` and the `if cached is None:` inside it, and
+    neither `run` nor `run_window` names the build module or a clock."""
+    import inspect
+    import textwrap
+
+    from paddle_tpu.core.executor import Executor
+
+    tree = ast.parse(textwrap.dedent(inspect.getsource(Executor._planned_fn)))
+    fn = tree.body[0]
+    body = [n for n in fn.body if not (isinstance(n, ast.Expr) and
+                                       isinstance(n.value, ast.Constant))]
+    miss, count, ret = body
+    assert isinstance(miss, ast.If) and \
+        ast.unparse(miss.test) == "plan.fn is None"
+    assert ast.unparse(count) == "self.cache_stats['hits'] += 1"
+    assert ast.unparse(ret) == "return plan.fn"
+    outside = "\n".join(ast.unparse(n) for n in (count, ret))
+    assert "_first_call" not in outside and "builds" not in outside
+    assert "_first_call" in ast.unparse(miss)
+    for method in (Executor.run, Executor.run_window):
+        src = inspect.getsource(method)
+        assert "builds." not in src and "perf_counter" not in src \
+            and "_first_call" not in src
