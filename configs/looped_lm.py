@@ -1,8 +1,9 @@
 """`paddle_tpu train --config` module: the looped decoder LM
 (`paddle_tpu.models.looped_lm`; `transformers` model_type `ouro`): ONE stack
 of layers run `turns` times with one set of weights through `layers.Repeat`
-(a scan over a Program sub-block, rematerialised a turn at a time), the
-closing norm, the head and a float32 exit gate read after every turn, and
+(a scan over a Program sub-block, rematerialised a turn at a time, all but
+the last), the closing norm, the head and a float32 exit gate read after
+every turn, and
 the cost the expected cross-entropy over the exits less `exit_beta` x the
 exit distribution's entropy. The layer is `ByteDance/Ouro-2.6B`'s: four
 RMSNorms with the residual adding a normed branch, 16 heads of 128 with

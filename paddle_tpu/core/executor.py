@@ -65,7 +65,8 @@ def memory_optimize(program=None, policy: str = "dots") -> None:
     policies only choose what of the first run is kept as well. Nothing is
     rematerialised a region at a time. A model whose memory is K passes over
     one stack of layers gets that from `layers.Repeat(remat=True)`: the loop
-    saves each turn's carry and recomputes one turn at a time."""
+    saves each turn's carry and recomputes one turn at a time, all but the
+    last."""
     program = program or default_main_program()
     if policy not in _REMAT_POLICIES:
         raise ValueError(

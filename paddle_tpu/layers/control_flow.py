@@ -16,9 +16,9 @@ value.
 Which loop: `While` runs until a condition falls and is forward-only
 (inference, decoding, data logic); a TRAINABLE loop of a fixed count over a
 whole stream (one stack of layers run K times with one set of weights) is
-`Repeat`, a bounded `lax.scan` that rematerialises a turn at a time; a
-recurrence over a SEQUENCE axis (per-step slices, masks, LoD) is
-`recurrent_group`.
+`Repeat`, a bounded `lax.scan` that rematerialises a turn at a time, all
+but the last; a recurrence over a SEQUENCE axis (per-step slices, masks,
+LoD) is `recurrent_group`.
 """
 
 from __future__ import annotations
@@ -145,13 +145,19 @@ class Repeat:
     block, once (`LayerHelper.create_parameter`), and every turn reads the
     same ones: under `append_backward` their gradient is the sum over the
     turns, with nothing for the optimizer to know. The op lowers to a
-    `jax.lax.scan` of length `times` whose body is under `jax.checkpoint`
-    (`remat=True`, the default): what the loop keeps for the backward pass
-    is each turn's carries and the stacked outputs, and the backward pass
-    recomputes one turn at a time, so K turns hold one turn's activations.
-    `remat=False` keeps every turn's (the same values; K times the memory).
-    The compiled step holds the block's kernels once forward, once
-    recomputed and once backward whatever `times` is.
+    `jax.lax.scan` of length `times`. With `remat=True`, the default, what
+    the loop keeps for the backward pass is the carries of turns 1..K-1 and
+    the stacked outputs: the backward pass runs each of those turns again,
+    one at a time, before it transposes it, so K turns hold one turn's
+    activations. The LAST turn is not run again: it is differentiated where
+    it stands, and its residuals, which the backward pass would have rebuilt
+    first of all, live from the forward pass to the backward pass across the
+    ops between them. A step runs the block 2 K - 1 times forward and K times
+    backward. `remat=False` keeps every turn's activations (the same values;
+    K times the memory) and runs the block K times each way; `times=1` is the
+    block alone either way. The compiled step holds the block's kernels, with
+    `remat`, three times forward (the loop, the last turn, a turn run again)
+    and twice backward (the last turn, the loop) whatever `times` is.
 
     A carry keeps its shape and dtype: `update(h, h2)` with another shape or
     dtype raises here, at build time, as do `update` / `turn_output` after

@@ -26,12 +26,14 @@ expected cost over the exits:
           dense layer to the letter), so the stream is float32 under amp
           with no cast
 
-The turns are a `layers.Repeat`: one Program sub-block, compiled once as the
-body of a scan and rematerialised a turn at a time, so K turns hold one
-turn's activations and the weights' gradients are summed over the turns by
-the differentiation itself. The head, its cross-entropy and the float32 gate
-are INSIDE the block: one turn's [T, vocab] logits are alive at a time and
-are recomputed with their turn, which is why the labels go in here.
+The turns are a `layers.Repeat`: one Program sub-block, traced once as the
+body of a scan and rematerialised a turn at a time, all but the last (which
+is differentiated where it stands: the backward pass starts from it), so K
+turns hold one turn's activations and the weights' gradients are summed over
+the turns by the loop's own differentiation rule. The head, its
+cross-entropy and the float32 gate are INSIDE the block: one turn's [T,
+vocab] logits are alive at a time and are recomputed with their turn (the
+last turn's are not), which is why the labels go in here.
 
 looped_lm: tokens [B, T] int32, labels [B, T, 1] int32 -> (cost [], per-turn
 token costs [K, B, T, 1], exit probabilities [K, B, T]).

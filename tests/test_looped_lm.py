@@ -224,6 +224,8 @@ def test_the_gradient_reaches_the_gate_only_through_p():
         _, costs, gates = loop()
         cost = pt.layers.mean(pt.layers.exit_expected_cost(costs, gates, beta)[0])
         pairs = pt.append_backward(cost)
+        # a drawn gate weight leaves a gradient under 1e-4 once in ~20 draws
+        pt.default_startup_program().random_seed = 3
         exe = pt.Executor()
         exe.run(pt.default_startup_program())
         pt.global_scope().set("gate.b", np.full((1,), 0.2, np.float32))

@@ -1,10 +1,13 @@
 """`layers.Repeat`: one Program sub-block run K times with one set of
-weights, as a scan whose body is rematerialised a turn at a time. Held to the
-same network written K times by shared `ParamAttr` names (values, every
-gradient, one Adam step), with `remat` on and off.
+weights, as a scan whose turns are rematerialised one at a time, all but the
+last, which is differentiated where it stands. Held to the same network
+written K times by shared `ParamAttr` names (values, every gradient, one Adam
+step), with `remat` on and off, and to `scan(checkpoint(turn))`, the lowering
+that ran all K turns again, to the bit.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -223,17 +226,193 @@ def test_clone_for_test_runs_the_loop():
     assert a.shape == (B, D) and np.array_equal(a, b)   # dropout is off
 
 
-def test_the_loop_is_counted_and_says_what_it_keeps():
+@pytest.mark.parametrize("kind,rerun,carries", [("remat", 2, 2),
+                                                 ("no_remat", 0, 3)])
+def test_the_loop_is_counted_and_says_what_it_keeps(kind, rerun, carries):
     from paddle_tpu.obs import metrics
 
-    _values_and_grads("remat", 3)
+    _values_and_grads(kind, 3)
     reg = metrics.registry()
-    assert reg.counter_value("pt_repeat_dispatch_total",
-                             labels={"remat": "true"}) >= 1
+    assert reg.counter_value(
+        "pt_repeat_dispatch_total",
+        labels={"remat": str(kind == "remat").lower()}) >= 1
     text = reg.render()
     assert "pt_repeat_turns 3" in text
-    # three turns' carries [B, D] and the stacked rows [3, B], float32
-    assert f"pt_repeat_saved_bytes {3 * B * D * 4 + 3 * B * 4}" in text
+    assert f"pt_repeat_rerun_turns {rerun}" in text
+    # the carries [B, D] of the turns the loop runs again (with remat: all
+    # but the last) and the stacked rows [3, B], float32
+    assert f"pt_repeat_saved_bytes {carries * B * D * 4 + 3 * B * 4}" in text
+
+
+def _runs(jaxpr, primitive):
+    """How often a traced program executes `primitive`: a scan's body as
+    often as the scan is long, a called sub-program as often as it is
+    called."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == primitive
+        inner = sum(_runs(sub, primitive)
+                    for sub in jax.core.jaxprs_in_params(eqn.params))
+        total += inner * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else inner
+    return total
+
+
+def _step_jaxpr(times, remat=True):
+    def build():
+        _, h, rows = _looped(times, remat=remat)
+        cost = _cost(h, _split_rows(rows, times))
+        pt.append_backward(cost)
+        return cost
+
+    prog, startup, cost = _programs(build)
+    exe = pt.Executor()
+    exe.run(startup)
+    state = {p.name: pt.global_scope().get(p.name) for p in prog.parameters()}
+    raw = exe._raw_step(prog, [cost.name])
+    return jax.make_jaxpr(raw)({}, state, {"x": _x()}, np.uint32(1))
+
+
+@pytest.mark.parametrize("times", [1, 2, 4])
+def test_a_step_runs_the_turn_2k_minus_1_times(times):
+    """The body's one `tanh` (its derivative reads the forward's result, so
+    every `tanh` in the step is a forward run of a turn): K forward, K - 1
+    again in the backward pass, where `scan(checkpoint(turn))` ran all K
+    again; K without `remat`."""
+    assert _runs(_step_jaxpr(times).jaxpr, "tanh") == 2 * times - 1
+    assert _runs(_step_jaxpr(times, remat=False).jaxpr, "tanh") == times
+
+
+def test_the_turn_is_traced_linearised_and_transposed_once():
+    """The forward loop, the last turn and the backward loop call ONE jitted
+    turn: the step holds three programs of its name (the turn, its forward
+    that keeps residuals, its transpose), the latter two called twice each
+    and the same object both times, so each is lowered once."""
+    called = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "jit" and eqn.params["name"] == "step":
+                inner = eqn.params["jaxpr"]
+                called.setdefault(id(inner), [inner, 0])[1] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(_step_jaxpr(4).jaxpr)
+    assert sorted(n for _, n in called.values()) == [1, 2, 2]
+
+
+def _turn_in_jax(params, labels, key, rate):
+    """A turn as `repeat_kernel` makes one, closing over differentiated
+    floats, an integer array and a key; carries a stream and a counter."""
+    def turn(vals, it):
+        h, seen = vals
+        u = jnp.tanh(h @ params["w"] + params["b"])
+        keep = jax.random.bernoulli(jax.random.fold_in(key, it), 1.0 - rate,
+                                    u.shape)
+        u = jnp.where(keep, u / (1.0 - rate), 0.0)
+        u = u + 0.01 * labels.astype(jnp.float32)[:, None]
+        h2 = h + u
+        return (h2, seen + 1), ((h2 * h2).sum(-1), jnp.argmax(h2, -1))
+
+    return turn
+
+
+@pytest.mark.parametrize("times", [2, 4])
+def test_gradients_are_the_bits_of_the_loop_that_ran_every_turn_again(times):
+    """Parameter gradients, the gradient of the carry that enters the loop
+    and (through a cost that weighs every turn's output) the turn outputs'
+    are, in float32, the bits of `scan(checkpoint(turn))`; with dropout in the
+    turn, an integer operand closed over, an integer carry and an integer
+    turn output beside the float ones."""
+    from paddle_tpu.ops import control_flow_ops as cf
+
+    rs = np.random.RandomState(1)
+    params = {"w": rs.randn(D, D).astype(np.float32) * 0.5,
+              "b": rs.randn(D).astype(np.float32)}
+    labels = np.arange(B, dtype=np.int32)
+    weights = jnp.arange(1.0, times + 1.0)[:, None]
+
+    def cost(loop):
+        def of(params, h0, labels, key):
+            turn = _turn_in_jax(params, labels, key, 0.25)
+            (h, seen), (rows, top) = loop(turn, (h0, jnp.int32(0)))
+            return h.sum() + (rows * weights).sum(), (seen, top)
+        return jax.jit(jax.value_and_grad(of, argnums=(0, 1), has_aux=True))
+
+    def oracle(turn, vals):
+        return jax.lax.scan(jax.checkpoint(turn), vals,
+                            jnp.arange(times, dtype=jnp.int32))
+
+    def mine(turn, vals):
+        step, ints, floats = cf._explicit(turn, vals, jnp.int32(0))
+        assert len(ints) == 2 and len(floats) == 2     # labels, key | w, b
+        return cf._all_turns_but_the_last_again(step, times)(
+            ints, floats, vals)
+
+    args = (params, _x(), labels, jax.random.PRNGKey(5))
+    (want, (seen, top)), (d_params, d_h0) = cost(oracle)(*args)
+    (got, (seen2, top2)), (d_params2, d_h02) = cost(mine)(*args)
+    assert int(seen) == int(seen2) == times
+    assert np.array_equal(top, top2) and np.array_equal(want, got)
+    assert np.abs(d_h0).max() > 1e-3
+    assert np.array_equal(d_h0, d_h02)
+    for name in params:
+        assert np.abs(d_params[name]).max() > 1e-3
+        assert np.array_equal(d_params[name], d_params2[name]), name
+
+
+def test_a_program_with_dropout_and_labels_in_the_body_matches_the_oracle(
+        monkeypatch):
+    """The same Program through both lowerings (the oracle put in the
+    kernel's place), to float32's last digits: dropout in the body (the same
+    masks: a turn's key is folded from its number either way), an int32 feed
+    read inside it, and a layer in front of the loop whose gradient is the
+    carry's."""
+    from paddle_tpu.ops import control_flow_ops as cf
+
+    times = 3
+
+    def build():
+        x = pt.layers.data("x", shape=[D], dtype=np.float32)
+        ids = pt.layers.data("ids", shape=[1], dtype=np.int32)
+        h0 = pt.layers.fc(x, size=D, param_attr=ParamAttr(name="front.w"),
+                          bias_attr=False)
+        loop = pt.layers.Repeat(times=times)
+        with loop.block():
+            h2, row = _body(h0, dropout=0.3)
+            h2 = pt.layers.elementwise_add(
+                h2, pt.layers.scale(pt.layers.cast(ids, np.float32), 0.01))
+            loop.update(h0, h2)
+            loop.turn_output(row)
+        h, rows = loop()
+        cost = _cost(h, _split_rows(rows, times))
+        return cost, pt.append_backward(cost)
+
+    def grads():
+        prog, startup, (cost, pairs) = _programs(build)
+        exe = pt.Executor()
+        exe.run(startup)
+        feed = {"x": _x(), "ids": np.arange(B, dtype=np.int32).reshape(B, 1)}
+        got = exe.run(prog, feed=feed,
+                      fetch_list=[cost] + [g for _, g in pairs])
+        return got[0], {p.name: g for (p, _), g in zip(pairs, got[1:])}
+
+    cost, mine = grads()
+    monkeypatch.setattr(
+        cf, "_all_turns_but_the_last_again",
+        lambda step, times: lambda ints, floats, vals: jax.lax.scan(
+            jax.checkpoint(lambda v, it: step(ints, floats, v, it)), vals,
+            jnp.arange(times, dtype=jnp.int32)))
+    want_cost, want = grads()
+    # the same sums in the same order; XLA fuses a turn that stands outside
+    # the loop with its neighbours, so the last bit is the compiler's
+    np.testing.assert_allclose(cost, want_cost, rtol=1e-6)
+    assert sorted(mine) == sorted(want) == ["b", "front.w", "n.w", "w"]
+    for name in want:
+        assert np.abs(want[name]).max() > 1e-3
+        np.testing.assert_allclose(mine[name], want[name], rtol=2e-6,
+                                   atol=1e-6, err_msg=name)
 
 
 def test_the_body_is_traced_once_whatever_k_is():

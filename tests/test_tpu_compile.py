@@ -602,9 +602,11 @@ def test_qk_assemble_counts_what_a_layer_marked(one_chip, compiled_mode,
     launches = _launches(jaxpr.jaxpr)
     last = sum(n for (_, emit), n in traced.items() if emit == "kernel")
     want = 0 if name == "glm-4.7-flash" else last
-    if name == "ouro-2.6b":     # the body forward, recomputed, and backward
+    if name == "ouro-2.6b":
+        # the body forward in the loop, for the last turn and run again;
+        # backward for the last turn and in the loop (PR 56)
         assert (launches["qk_assemble_fwd"], launches["qk_assemble_bwd"]) \
-            == (2 * want, want)
+            == (3 * want, 2 * want)
     else:
         assert (launches.get("qk_assemble_fwd", 0),
                 launches.get("qk_assemble_bwd", 0)) == (want, want)
@@ -808,37 +810,49 @@ def test_looped_lm_step_program_fits_one_chip(one_chip, compiled_mode,
     """The ouro-2.6b cell's whole step (batch 1 x T 4096, 8 layers of Ouro-2.6B
     run four times through `layers.Repeat`, 612 M parameters) compiles for the
     described v5e under 15.0 GiB by `memory_analysis()` (arguments 6.845 +
-    temporaries 7.906, which overstate: the compiler's `program HBM usage` line
-    says 6.03 G beside the arguments, 12.90 GiB, and the chip reads that to
-    0.01), holds the loop as `while`s (a forward and a backward one), and calls the flash kernels as
-    often as 2 L forward and L backward applications need: the stack once in
-    the forward loop's body, once recomputed and once backward in the backward
-    loop's; not K L times."""
+    temporaries 7.827, which overstate: the compiler's `program HBM usage` line
+    says 6.06 G beside the arguments, and the chip reads that to 0.01), and
+    holds the loop as `while`s (a forward and a backward one, of K - 1 turns
+    each) with the LAST turn beside them, differentiated where it stands (PR
+    56: the backward loop ran it again from its carry, as it runs the others).
+    So the jaxpr calls the flash kernels 3 L times forward and 2 L backward:
+    the stack once in the forward loop's body, once for the last turn with
+    its residuals kept and once more in the backward loop's body, which runs a
+    turn again; the transposed stack for the last turn and once in the
+    backward loop's body. Not K L times either way. The temporaries stay
+    within 0.1 GiB of the 7.877 that the lowering which ran all K turns again
+    printed here: the backward loop's gradient sums START from the last
+    turn's, so there is one set of them, and the last turn's residuals are
+    dead before the loop begins."""
     layers_, turns = 8, 4
     raw, args = _step_program(lambda: _benchmark_model("ouro-2.6b", 1, 4096),
                               1, 4096, one_chip, monkeypatch)
     jaxpr = jax.make_jaxpr(raw)(*args)
     launches = dict(_launches(jaxpr.jaxpr))
-    # a layer's Q and K rotaries ride with its attention kernels: a launch
-    # each forward, recomputed and backward (PR 47)
-    assert launches == {"flash_attention_fwd": 2 * layers_,
-                        "flash_attention_bwd": layers_,
-                        "qk_assemble_fwd": 4 * layers_,
-                        "qk_assemble_bwd": 2 * layers_}
-    assert str(jaxpr).count(f"length={turns}") >= 2      # the two scans
+    # a layer's Q and K rotaries ride with its attention kernels (PR 47)
+    assert launches == {"flash_attention_fwd": 3 * layers_,
+                        "flash_attention_bwd": 2 * layers_,
+                        "qk_assemble_fwd": 6 * layers_,
+                        "qk_assemble_bwd": 4 * layers_}
+    assert str(jaxpr).count(f"length={turns - 1}") >= 2      # the two scans
     compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
     text = compiled.as_text()
     assert len(re.findall(r"\bwhile\(", text)) == 2
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
-    assert len([c for c in calls if "flash_attention_fwd" in c]) == 2 * layers_
-    assert len([c for c in calls if "flash_attention_bwd" in c]) == layers_
-    assert len([c for c in calls if "flash_attention" in c]) \
-        < turns * layers_
+    forward = [c for c in calls if "flash_attention_fwd" in c]
+    backward = [c for c in calls if "flash_attention_bwd" in c]
+    assert (len(forward), len(backward)) == (3 * layers_, 2 * layers_)
+    assert max(len(forward), len(backward)) < turns * layers_
+    # the forward that the backward loop runs again says so in its path,
+    # where `repeat.recompute_ms` looks for it: one stack's kernels
+    assert len([c for c in forward if "/rematted_computation/" in c
+                and "transpose(jvp(repeat." in c]) == layers_
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 7.3e9          # 12 B a parameter
     print("looped step: arguments %.3f GiB, temporaries %.3f GiB" % (
         memory.argument_size_in_bytes / 2**30,
         memory.temp_size_in_bytes / 2**30))
+    assert memory.temp_size_in_bytes < (7.877 + 0.1) * 2**30
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.0 * 2**30)
 
