@@ -67,6 +67,9 @@ LOW_PRECISION_OPS = frozenset({
     # the gated short-convolution operator's two projections; the gates and
     # the taps between them are float32 inside the op (ops/short_conv_ops.py)
     "short_conv_operator",
+    # Mamba-1's mixer: its four projections; the taps, dt, the scan's decays
+    # and state are float32 inside the op (ops/ssm_ops.py)
+    "mamba1_mixer",
 })
 
 # The subset of low-precision sites the int8 converter may rewrite: dense

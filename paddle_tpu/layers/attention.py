@@ -20,7 +20,8 @@ from ..param_attr import ParamAttr
 from .helper import LayerHelper
 
 __all__ = ["attention_gru_decoder", "attention_gru_beam_search",
-           "multi_head_attention", "latent_attention"]
+           "multi_head_attention", "latent_attention",
+           "differential_attention"]
 
 
 def _in_front_of_kernel(helper, emit, var):
@@ -259,6 +260,132 @@ def latent_attention(
         attrs={"num_heads": num_heads, "causal": True},
     )
     return proj(out, "wo", E)
+
+
+def differential_attention(
+    query,
+    num_heads: int,
+    num_kv_heads: int,
+    depth: int,
+    window: Optional[int] = None,
+    shared_kv=None,
+    return_kv: bool = False,
+    rms_eps: float = 1e-5,
+    lam_std: float = 0.1,
+    param_attr=None,
+    name=None,
+):
+    """Causal DIFFERENTIAL attention (Ye et al. 2024) over a dense [B, T, E]
+    input, heads of D = E / num_heads: the heads form PAIRS (2p, 2p + 1), a
+    query pair p reads K/V pair p // (num_heads / num_kv_heads), and a pair
+    is two softmaxes over one value twice a head wide:
+
+        [q | k | v] = u W_qkv + b     W_qkv [E, (num_heads + 2 num_kv_heads) D]
+        A_1 = softmax(q_1 k_1^T / sqrt(D)),  A_2 = softmax(q_2 k_2^T / sqrt(D))
+        o   = (A_1 - lam A_2) [v_1 | v_2]                        (2 D lanes)
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+        lam_init = 0.8 - 0.6 exp(-0.3 depth)
+        o  <- rms_{2D}(o, w) (1 - lam_init);      out = o W_o + b_o
+
+    under the causal mask, and under `window` W also j > i - W. `depth`: the
+    layer's index in its model (it sets lam_init). lq1, lk1, lq2, lk2 [D]
+    are learned, N(0, `lam_std`) at the start (0.1 as published), shared by
+    the layer's pairs; w [2 D] starts at one.
+    shared_kv=(k, v): the layer projects q alone (W_q [E, E] with its bias)
+    and reads these keys and values, another layer's [B, T, num_kv_heads D]
+    (cross attention inside one sequence, so Tq == Tk and the mask stays
+    causal; anything else raises). return_kv: returns (out, (k, v)), this
+    layer's keys and values after their bias, for such readers.
+    Four `flash_attention` ops a layer ((q_1, k_1, v_1), (q_1, k_1, v_2),
+    (q_2, k_2, v_1), (q_2, k_2, v_2): the kernels take one width for Q, K and
+    V), so eligibility, the window bound, the dispatch counters and the mesh
+    rule are every attention layer's; `diff_combine` behind them. The ops'
+    scopes carry `<name>.qkv`, `<name>.kernels`, `<name>.combine`,
+    `<name>.out_proj`. param_attr may be a mapping {"wqkv" | "wq" | "wo":
+    attr}. Parameters, in order: wqkv (or wq) and its bias, lq1, lk1, lq2,
+    lk2, the norm's scale, wo and its bias."""
+    import math
+
+    from ..initializer import ConstantInitializer, NormalInitializer
+    from .nn import fc, split
+
+    helper = LayerHelper("differential_attention", name=name)
+    E = int(query.shape[-1])
+    if E % num_heads or num_heads % 2 or num_kv_heads % 2:
+        raise ValueError(f"{num_heads} query and {num_kv_heads} K/V heads "
+                         f"over {E} lanes do not form pairs")
+    if num_heads % num_kv_heads:
+        raise ValueError(f"{num_heads} query heads do not share "
+                         f"{num_kv_heads} K/V heads evenly")
+    if window is not None and window <= 0:
+        raise ValueError(f"window {window}: a positive number of keys")
+    D = E // num_heads
+    E_kv = num_kv_heads * D
+    n = helper.name
+
+    def _derive(s):
+        return ParamAttr.derive(param_attr, n, s)
+
+    def pairs(x):
+        """(the first heads, the second heads) of a packed projection."""
+        h = LayerHelper("split_head_pairs", name=f"{n}.qkv")
+        half = tuple(x.shape[:-1]) + (int(x.shape[-1]) // 2,)
+        outs = [h.create_tmp_variable(x.dtype, half) for _ in range(2)]
+        h.append_op(type="split_head_pairs", inputs={"X": [x]},
+                    outputs={"First": [outs[0]], "Second": [outs[1]]},
+                    attrs={"head_dim": D})
+        return outs
+
+    if shared_kv is None:
+        qkv = fc(query, size=E + 2 * E_kv, num_flatten_dims=2,
+                 param_attr=_derive("wqkv"), bias_attr=_derive("wqkv_b"),
+                 name=f"{n}.qkv")
+        q, k, v = split(qkv, [E, E_kv, E_kv], dim=2)
+    else:
+        k, v = shared_kv
+        if (tuple(k.shape) != tuple(v.shape) or int(k.shape[-1]) != E_kv
+                or tuple(k.shape[:-1]) != tuple(query.shape[:-1])):
+            raise ValueError(
+                f"shared_kv {tuple(k.shape)}, {tuple(v.shape)}: another "
+                f"layer's keys and values over the SAME sequence, "
+                f"{tuple(query.shape[:-1]) + (E_kv,)}")
+        q = fc(query, size=E, num_flatten_dims=2, param_attr=_derive("wq"),
+               bias_attr=_derive("wq_b"), name=f"{n}.qkv")
+    (q1, q2), (k1, k2), (v1, v2) = pairs(q), pairs(k), pairs(v)
+    attrs = {"num_heads": num_heads // 2, "causal": True}
+    if window:
+        attrs["window"] = int(window)
+    kernels = LayerHelper("flash_attention", name=f"{n}.kernels")
+
+    def attend(q_, k_, v_):
+        out = kernels.create_tmp_variable(query.dtype, tuple(q_.shape))
+        kernels.append_op(type="flash_attention",
+                          inputs={"Q": [q_], "K": [k_], "V": [v_]},
+                          outputs={"Out": [out]}, attrs=dict(attrs))
+        return out
+
+    launched = [attend(q1, k1, v1), attend(q1, k1, v2),
+                attend(q2, k2, v1), attend(q2, k2, v2)]
+    lam_init = 0.8 - 0.6 * math.exp(-0.3 * depth)
+    vectors = [helper.create_parameter(
+        _derive(s), (D,), default_initializer=NormalInitializer(0.0, lam_std))
+        for s in ("lq1", "lk1", "lq2", "lk2")]
+    norm_w = helper.create_parameter(
+        _derive("subln").name, (2 * D,),
+        default_initializer=ConstantInitializer(1.0))
+    combine = LayerHelper("diff_combine", name=f"{n}.combine")
+    out = combine.create_tmp_variable(query.dtype, tuple(query.shape))
+    slots = ("A11", "A12", "A21", "A22", "LamQ1", "LamK1", "LamQ2", "LamK2")
+    combine.append_op(
+        type="diff_combine",
+        inputs={**{s: [x] for s, x in zip(slots, launched + vectors)},
+                "NormW": [norm_w]},
+        outputs={"Out": [out]},
+        attrs={"head_dim": D, "lam_init": lam_init, "epsilon": rms_eps,
+               "launches": len(launched)})
+    out = fc(out, size=E, num_flatten_dims=2, param_attr=_derive("wo"),
+             bias_attr=_derive("wo_b"), name=f"{n}.out_proj")
+    return (out, (k, v)) if return_kv else out
 
 
 def _decoder_params(helper, ctx_dim, emb_dim, hidden, att_size):

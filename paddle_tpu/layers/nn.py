@@ -31,6 +31,7 @@ __all__ = [
     "layer_norm",
     "rms_norm",
     "rotary_embedding",
+    "silu_gate",
     "dropout",
     "cross_entropy",
     "softmax_with_cross_entropy",
@@ -692,6 +693,26 @@ def elementwise_div(x, y, axis=-1):
     return _binary("elementwise_div", x, y, {"axis": axis})
 
 
+def silu_gate(x, gate=None, name=None):
+    """x * silu(gate), shaped like x; with no `gate`, x is [gate | value]
+    side by side along its last axis (a fused up-projection's output) and
+    the result is value * silu(gate), half as wide. Float32 inside, x's
+    dtype out; the backward keeps the operands alone
+    (ops/ssm_ops.py:silu_gate)."""
+    helper = LayerHelper("silu_gate", name=name)
+    inputs = {"X": [x]}
+    shape = tuple(x.shape)
+    if gate is None:
+        if shape[-1] % 2:
+            raise ValueError(f"a fused [gate | value] of {shape[-1]} lanes")
+        shape = shape[:-1] + (shape[-1] // 2,)
+    else:
+        inputs["Gate"] = [gate]
+    out = helper.create_tmp_variable(x.dtype, shape)
+    helper.append_op(type="silu_gate", inputs=inputs, outputs={"Out": [out]})
+    return out
+
+
 def scale(x, scale=1.0, bias=0.0):
     return _unary("scale", x, {"scale": scale, "bias": bias})
 
@@ -760,7 +781,17 @@ def transpose(x, perm):
 
 
 def matmul(x, y, transpose_x=False, transpose_y=False):
-    return _binary("matmul", x, y, {"transpose_X": transpose_x, "transpose_Y": transpose_y})
+    helper = LayerHelper("matmul")
+    shape = tuple(x.shape)
+    if len(x.shape) >= 2 and len(y.shape) >= 2:
+        shape = tuple(x.shape[:-2]) + (
+            x.shape[-1] if transpose_x else x.shape[-2],
+            y.shape[-2] if transpose_y else y.shape[-1])
+    out = helper.create_tmp_variable(x.dtype, shape, x.lod_level)
+    helper.append_op(
+        type="matmul", inputs={"X": [x], "Y": [y]}, outputs={"Out": [out]},
+        attrs={"transpose_X": transpose_x, "transpose_Y": transpose_y})
+    return out
 
 
 def clip(x, min, max):  # noqa: A002 — fluid layers.clip signature

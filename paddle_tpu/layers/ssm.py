@@ -2,7 +2,9 @@
 the Mamba-2 mixer (ops/ssm_ops.py), the sequence mixer of the hybrid Mamba-2
 / attention decoders (Nemotron-H and its kin), and the gated short-convolution
 operator (ops/short_conv_ops.py) of the convolution / attention hybrids
-(LFM2 and its kin). Beyond the 2017 reference's layer set.
+(LFM2 and its kin), and Mamba-1's mixer with the gated memory unit that reads
+its scan's output in a later layer (the SambaY decoders: Phi-4-mini-flash and
+its kin). Beyond the 2017 reference's layer set.
 """
 
 from __future__ import annotations
@@ -15,7 +17,8 @@ from ..initializer import (ConstantInitializer, Initializer,
 from ..param_attr import ParamAttr
 from .helper import LayerHelper
 
-__all__ = ["mamba2_mixer", "short_conv_operator"]
+__all__ = ["mamba2_mixer", "short_conv_operator", "mamba1_mixer",
+           "gated_memory_unit"]
 
 
 class _Mamba2Init(Initializer):
@@ -130,3 +133,99 @@ def short_conv_operator(input, hidden=None, kernel: int = 3, param_attr=None,
     helper.append_op(type="short_conv_operator", inputs=inputs,
                      outputs={"Out": [out]})
     return out
+
+
+class _Mamba1ALog(Initializer):
+    """A_log[c, n] = log(n + 1) (the `mamba1_init` startup op)."""
+
+    def __call__(self, var, startup=None):
+        b = (startup or default_startup_program()).global_block()
+        b.create_var(var.name, var.shape, var.dtype, persistable=True)
+        b.append_op("mamba1_init", outputs={"Out": [var.name]},
+                    attrs={"shape": list(var.shape)})
+
+
+def mamba1_mixer(input, state_size: int = 16, conv_kernel: int = 4,
+                 expand: int = 2, dt_rank=None, emit_memory: bool = False,
+                 param_attr=None, name=None):
+    """input [B, T, d] -> [B, T, d]: Mamba-1's mixer (Gu & Dao 2023). With
+    d_in = `expand` d, N = `state_size`, K = `conv_kernel`, R = `dt_rank`
+    (None: ceil(d / 16)):
+
+        [x | z] = u W_in                  W_in [d, 2 d_in], no bias
+        x = silu(causal depthwise conv_K(x) + b_conv)
+        [r | B | C] = x W_x               W_x [d_in, R + 2 N], no bias
+        dt = softplus(r W_dt + b_dt)      W_dt [R, d_in];  A = -exp(A_log)
+        S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+        y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]             S_0 = 0
+        out = (y * silu(z)) W_out         W_out [d_in, d], no bias
+
+    a decay for every channel and state (`ops/ssm_ops.py:selective_scan`).
+    With `emit_memory` returns (out, y): the scan's output WITH the D x term,
+    in front of the gate, for the `gated_memory_unit`s of later layers.
+    Parameters: `<name>.in_w`, `.conv_w` [K, d_in], `.conv_b`, `.x_w`,
+    `.dt_w`, `.dt_b`, `.A_log` [d_in, N], `.D`, `.out_w`. A_log[c, n] starts
+    at log(n + 1), dt_b at the inverse softplus of a log-uniform draw in
+    [0.001, 0.1], W_dt at U(+-R^-0.5), D at one, the taps at U(+-1/sqrt(K))
+    with a zero bias (the family's initialisers); the other projections keep
+    the DSL's Glorot. param_attr may be a mapping {"in_w" | ... | "out_w":
+    attr} (`ParamAttr.derive`): a caller's initialiser wins. The inner
+    scopes of the op's rows: `in_proj`, `conv`, `dt_bc`, `scan`, `gate`,
+    `out_proj`."""
+    helper = LayerHelper("mamba1_mixer", name=name)
+    d = int(input.shape[-1])
+    N, K = int(state_size), int(conv_kernel)
+    d_in = int(expand) * d
+    R = -(-d // 16) if dt_rank is None else int(dt_rank)
+
+    def param(suffix, shape, init):
+        return helper.create_parameter(
+            ParamAttr.derive(param_attr, helper.name, suffix), shape,
+            default_initializer=init)
+
+    taps, dt_bound = 1.0 / np.sqrt(K), R ** -0.5
+    inputs = {
+        "X": [input],
+        "InW": [param("in_w", (d, 2 * d_in), XavierInitializer())],
+        "ConvW": [param("conv_w", (K, d_in), UniformInitializer(-taps, taps))],
+        "ConvB": [param("conv_b", (d_in,), ConstantInitializer(0.0))],
+        "XW": [param("x_w", (d_in, R + 2 * N), XavierInitializer())],
+        "DtW": [param("dt_w", (R, d_in),
+                      UniformInitializer(-dt_bound, dt_bound))],
+        "DtB": [param("dt_b", (d_in,), _Mamba2Init("dt_bias"))],
+        "ALog": [param("A_log", (d_in, N), _Mamba1ALog())],
+        "D": [param("D", (d_in,), ConstantInitializer(1.0))],
+        "OutW": [param("out_w", (d_in, d), XavierInitializer())],
+    }
+    out = helper.create_tmp_variable(input.dtype, input.shape)
+    memory = helper.create_tmp_variable(
+        input.dtype, tuple(input.shape[:-1]) + (d_in,))
+    helper.append_op(type="mamba1_mixer", inputs=inputs,
+                     outputs={"Out": [out], "Memory": [memory]})
+    return (out, memory) if emit_memory else out
+
+
+def gated_memory_unit(input, memory, param_attr=None, name=None):
+    """input [B, T, d], memory [B, T, m] -> [B, T, d]: a gated memory unit
+    (Ren et al. 2025, SambaY): an earlier layer's memory, here a Mamba-1
+    mixer's scan output, gated by this layer's input,
+
+        out = (memory * silu(u W_g)) W_o     W_g [d, m], W_o [m, d], no bias
+
+    no convolution and no scan of its own. An `fc`, a `silu_gate`, an `fc`,
+    whose ops' scopes carry `<name>.gate_proj`, `<name>.gate`,
+    `<name>.out_proj`. param_attr may be a mapping {"gate_w" | "out_w":
+    attr}."""
+    from .nn import fc, silu_gate
+
+    helper = LayerHelper("gated_memory_unit", name=name)
+    d, m = int(input.shape[-1]), int(memory.shape[-1])
+
+    def proj(inp, scope, weight, size):
+        return fc(inp, size=size, num_flatten_dims=2, bias_attr=False,
+                  param_attr=ParamAttr.derive(param_attr, helper.name, weight),
+                  name=f"{helper.name}.{scope}")
+
+    gated = silu_gate(memory, proj(input, "gate_proj", "gate_w", m),
+                      name=f"{helper.name}.gate")
+    return proj(gated, "out_proj", "out_w", d)

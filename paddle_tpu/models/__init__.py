@@ -18,3 +18,4 @@ from .glm_moe import glm_moe_lm  # noqa: F401
 from .afmoe import afmoe_lm  # noqa: F401
 from .lfm2_moe import lfm2_moe_lm  # noqa: F401
 from .looped import looped_lm  # noqa: F401
+from .phi4flash import phi4flash_layer_kinds, phi4flash_lm  # noqa: F401

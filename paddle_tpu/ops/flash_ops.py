@@ -934,3 +934,87 @@ def latent_kv_expand_kernel(ctx):
                             int(ctx.attr("nope_dim")))
     ctx.set_output("K", k)
     ctx.set_output("V", v)
+
+
+# ---- differential attention (Ye et al. 2024) around the kernels -----------
+# A pair of heads (2p, 2p + 1) is two softmaxes over one value twice a head
+# wide: o_p = (A_1 - lam A_2) [v_1 | v_2]. The kernels take one width for Q,
+# K and V, so `layers.differential_attention` launches them four times a
+# layer, on the even and odd heads apart (`split_head_pairs`), and
+# `diff_combine` puts the four outputs together.
+def split_head_pairs(x, head_dim: int):
+    """x [B, T, H x D] -> (the even heads, the odd heads), each [B, T, H / 2
+    x D] in x's order: the first and the second head of every pair."""
+    B, T, E = x.shape
+    pairs = x.reshape(B, T, E // (2 * head_dim), 2, head_dim)
+    return (pairs[:, :, :, 0].reshape(B, T, E // 2),
+            pairs[:, :, :, 1].reshape(B, T, E // 2))
+
+
+@register_op("split_head_pairs")
+def split_head_pairs_kernel(ctx):
+    """X [B, T, H x D] -> First, Second (attr head_dim): the heads 0, 2, 4..
+    and 1, 3, 5.. of a packed projection."""
+    first, second = split_head_pairs(ctx.input("X"), int(ctx.attr("head_dim")))
+    ctx.set_output("First", first)
+    ctx.set_output("Second", second)
+
+
+def diff_lambda(lq1, lk1, lq2, lk2, lam_init: float):
+    """exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init, float32."""
+    f = lambda a, b: jnp.exp(jnp.sum(  # noqa: E731
+        a.astype(jnp.float32) * b.astype(jnp.float32)))
+    return f(lq1, lk1) - f(lq2, lk2) + lam_init
+
+
+def diff_combine(a11, a12, a21, a22, lq1, lk1, lq2, lk2, norm_w, *,
+                 head_dim: int, lam_init: float, eps: float):
+    """The pair arithmetic behind the kernels. a11 = A_1 v_1, a12 = A_1 v_2,
+    a21 = A_2 v_1, a22 = A_2 v_2, each [B, T, P x D] (a pair's head p of D
+    lanes); the four vectors [D] give lam (`diff_lambda`); norm_w [2 D].
+
+        o_p = [a11_p | a12_p] - lam [a21_p | a22_p]               (2 D lanes)
+        out_p = o_p rsqrt(mean(o_p^2) + eps) norm_w (1 - lam_init)
+
+    -> [B, T, P x 2 D] in a11's dtype, float32 inside. One checkpoint: the
+    backward keeps the four operands and forms the float32 arrays again."""
+    D = int(head_dim)
+
+    @jax.checkpoint
+    def combine(a11, a12, a21, a22, lq1, lk1, lq2, lk2, norm_w):
+        B, T, E = a11.shape
+        heads = lambda a: a.astype(jnp.float32).reshape(B, T, E // D, D)  # noqa: E731
+        lam = diff_lambda(lq1, lk1, lq2, lk2, lam_init)
+        o = jnp.concatenate([heads(a11) - lam * heads(a21),
+                             heads(a12) - lam * heads(a22)], axis=-1)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        o = o * (norm_w.astype(jnp.float32) * (1.0 - lam_init))
+        return o.reshape(B, T, 2 * E).astype(a11.dtype)
+
+    return combine(a11, a12, a21, a22, lq1, lk1, lq2, lk2, norm_w)
+
+
+_launches: dict = {}      # a combine op's name in its Program -> its launches
+
+
+@register_op("diff_combine")
+def diff_combine_kernel(ctx):
+    """Program-IR face of `diff_combine`: A11, A12, A21, A22, LamQ1, LamK1,
+    LamQ2, LamK2, NormW -> Out (attrs head_dim, lam_init, epsilon, and
+    `launches`: the `flash_attention` ops the layer appended for this
+    combine, summed over the combines traced into the gauge
+    `pt_diff_attention_launches_total`: forward launches a step)."""
+    from ..obs import metrics
+
+    _launches[ctx.op.outputs["Out"][0]] = int(ctx.attr("launches", 4))
+    metrics.registry().gauge(
+        "pt_diff_attention_launches_total", lambda: sum(_launches.values()),
+        help="flash_attention ops a step behind the differential-attention "
+             "layers' pair arithmetic, summed over the combines traced so "
+             "far (a combine traced again counted once)")
+    ctx.set_output("Out", diff_combine(
+        *(ctx.input(s) for s in ("A11", "A12", "A21", "A22", "LamQ1", "LamK1",
+                                 "LamQ2", "LamK2", "NormW")),
+        head_dim=int(ctx.attr("head_dim")),
+        lam_init=float(ctx.attr("lam_init")),
+        eps=float(ctx.attr("epsilon", 1e-5))))

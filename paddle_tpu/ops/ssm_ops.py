@@ -1124,3 +1124,512 @@ def mamba2_init_kernel(ctx):
     else:
         raise ValueError(f"mamba2_init: unknown kind {kind!r}")
     ctx.set_output("Out", out)
+
+
+# ---- Mamba-1: the selective scan ------------------------------------------
+# Gu & Dao 2023. A decay of its own for every channel c and state n, so the
+# recurrence does not turn into [Q, Q] matmuls as Mamba-2's does:
+#
+#     S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+#     y_t[c]    = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]                S_0 = 0
+#
+# `selective_scan` is a `jax.custom_vjp` over chunks of `SEL_CHUNK` tokens.
+# Its residuals are its operands and the state each chunk STARTS from ([B,
+# T / Q, N, C] float32: 10.5 MB a mixer at T 8192, C 5120, N 16); nothing of
+# [T, C, N] shape outlives a chunk, forward or backward. Two homes, chosen
+# when the op is traced and counted in `pt_selective_scan_dispatch_total
+# {path}` (no flag, no attribute):
+#
+# - `pallas`: the kernels below, on the TPU backend, outside a mesh, at the
+#   shapes `_shapes_selective_ok` admits. The state is [N, C] with the
+#   states in sublanes and the channels in lanes, resident in VMEM across
+#   the sequential chunk axis; a token's step is elementwise work on the
+#   vector unit and one sum over the N sublanes. The backward walks the
+#   chunks from the last down: it forms a chunk's states again from its
+#   saved start into a VMEM scratch, then carries the state's cotangent
+#   through the chunk's tokens in reverse.
+# - `xla_chunked`: a `lax.scan` over the chunks, each chunk an associative
+#   scan over its tokens on [Q, N, C] arrays, its backward `jax.vjp` of the
+#   chunk run from the saved start (so a chunk is computed again, as under
+#   `jax.checkpoint`): every other case, exactly: the CPU, a mesh, odd shapes.
+#
+# Float32 arithmetic, state and decays on both; x, B, C and y in the dtype
+# they arrive in (the amp dtype), dt float32 (after its softplus).
+SEL_CHUNK = 128
+# what the state is carried from chunk to chunk in: float32 by design (a
+# bf16 carry is the wrong program `tests/phi4flash_controls.py` swaps in)
+_CARRY_DTYPE = jnp.float32
+
+
+def _sel_chunk(S0, x, dt, A_t, Bm, Cm):
+    """One chunk by an associative scan. S0 [B, N, C] float32; x, dt [B, Q,
+    C]; Bm, Cm [B, Q, N]; A_t [N, C] -> (y [B, Q, C] float32 without the D
+    term, the state at the chunk's end)."""
+    x, dt, Bm, Cm = (a.astype(jnp.float32) for a in (x, dt, Bm, Cm))
+    decay = jnp.exp(dt[:, :, None, :] * A_t)                   # [B, Q, N, C]
+    fresh = (dt * x)[:, :, None, :] * Bm[..., None]
+
+    def combine(first, then):
+        return then[0] * first[0], then[0] * first[1] + then[1]
+
+    to_here, own = jax.lax.associative_scan(combine, (decay, fresh), axis=1)
+    S = to_here * S0[:, None] + own
+    return jnp.sum(S * Cm[..., None], axis=2), S[:, -1]
+
+
+def _sel_chunks(a, Q: int):
+    """[B, T, ...] -> [T / Q, B, Q, ...]: the chunks in front, for a scan."""
+    B, T = a.shape[:2]
+    return jnp.moveaxis(a.reshape(B, T // Q, Q, *a.shape[2:]), 1, 0)
+
+
+def _sel_unchunk(a):
+    a = jnp.moveaxis(a, 0, 1)
+    return a.reshape(a.shape[0], -1, *a.shape[3:])
+
+
+def _sel_pad(arrays, Q: int):
+    """A ragged tail padded with zeros: dt 0 is no decay and no input, so the
+    state passes through and the tail's outputs are dropped."""
+    pad = -arrays[0].shape[1] % Q
+    if not pad:
+        return arrays
+    return tuple(jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in arrays)
+
+
+def _sel_xla_forward(x, dt, A, Bm, Cm, D):
+    """(y in x's dtype, the chunk starts [B, chunks, N, C] float32)."""
+    Bsz, T, C = x.shape
+    A_t = A.astype(jnp.float32).T
+    xs = tuple(_sel_chunks(a, SEL_CHUNK)
+               for a in _sel_pad((x, dt, Bm, Cm), SEL_CHUNK))
+
+    def step(S, chunk):
+        y, S_end = _sel_chunk(S, *chunk[:2], A_t, *chunk[2:])
+        return S_end.astype(_CARRY_DTYPE).astype(jnp.float32), (y, S)
+
+    _, (y, starts) = jax.lax.scan(
+        step, jnp.zeros((Bsz, A.shape[1], C), jnp.float32), xs)
+    y = _sel_unchunk(y)[:, :T] + D.astype(jnp.float32) * x.astype(jnp.float32)
+    return y.astype(x.dtype), jnp.moveaxis(starts, 0, 1)
+
+
+def _sel_xla_backward(x, dt, A, Bm, Cm, D, starts, dy):
+    """The operands' cotangents, in their dtypes: the chunks in reverse, each
+    `jax.vjp` of `_sel_chunk` from its saved start, the state's cotangent
+    carried down and A's summed on the way."""
+    T = x.shape[1]
+    A_t = A.astype(jnp.float32).T
+    dy32 = dy.astype(jnp.float32)
+    x_c, dt_c, B_c, C_c, dy_c = (
+        _sel_chunks(a, SEL_CHUNK)
+        for a in _sel_pad((x, dt, Bm, Cm, dy32), SEL_CHUNK))
+
+    def step(carry, chunk):
+        dS, dA_t = carry
+        S0, xc, dtc, Bc, Cc, dyc = chunk
+        _, pull = jax.vjp(_sel_chunk, S0, xc, dtc, A_t, Bc, Cc)
+        dS0, dxc, ddtc, dA_c, dBc, dCc = pull((dyc, dS))
+        return (dS0, dA_t + dA_c), (dxc, ddtc, dBc, dCc)
+
+    zero = jnp.zeros_like(starts[:, 0])
+    (_, dA_t), grads = jax.lax.scan(
+        step, (zero, jnp.zeros_like(A_t)),
+        (jnp.moveaxis(starts, 1, 0), x_c, dt_c, B_c, C_c, dy_c), reverse=True)
+    dx, ddt, dB, dC = (_sel_unchunk(g)[:, :T] for g in grads)
+    x32 = x.astype(jnp.float32)
+    dx = dx.astype(jnp.float32) + D.astype(jnp.float32) * dy32
+    return (dx.astype(x.dtype), ddt.astype(dt.dtype), dA_t.T.astype(A.dtype),
+            dB.astype(Bm.dtype), dC.astype(Cm.dtype),
+            jnp.sum(dy32 * x32, axis=(0, 1)).astype(D.dtype))
+
+
+# The kernels. A grid step is one (batch, chunk, tile of `lanes` channels),
+# the channel tiles innermost: a chunk's B and C blocks stay where they are
+# while its tiles go by (they are fetched once a chunk), and the backward's
+# dB and dC blocks are summed over the tiles in place. The state of every
+# tile lives in one scratch [tiles, N, lanes] across the sequential chunk
+# axis. B and C arrive with each of a token's N numbers in all 128 lanes ([B,
+# T, N, 128] float32, XLA's broadcast): a token's [N, lanes] operand is that
+# vreg column laid side by side, and nothing crosses lanes inside a step.
+# dB and dC leave as the same shape, a token's N sums still spread over 128
+# lanes' partial sums, and XLA adds the lanes up.
+def _sel_lanes(C: int) -> int:
+    return next(w for w in (512, 256, 128) if C % w == 0)
+
+
+def _shapes_selective_ok(x, A) -> bool:
+    """Backend-independent: whole chunks, channels in whole lane tiles, the
+    states whole sublane tiles."""
+    T, C = x.shape[1:]
+    return (x.dtype in (jnp.bfloat16, jnp.float32) and T % SEL_CHUNK == 0
+            and C % _LANES == 0 and A.shape[1] % 8 == 0)
+
+
+def selective_kernels_eligible(x, A) -> bool:
+    from . import mesh_dispatch
+
+    return (_on_tpu() and mesh_dispatch.current() is None
+            and _shapes_selective_ok(x, A))
+
+
+def _across_tiles(v, lanes: int):
+    """[N, 128] -> [N, lanes]: the same vregs side by side."""
+    return jnp.concatenate([v] * (lanes // _LANES), axis=1)
+
+
+def _fold_tiles(v):
+    """[N, lanes] -> [N, 128]: the lane tiles added up."""
+    return sum(v[:, i:i + _LANES] for i in range(0, v.shape[1], _LANES))
+
+
+def _row(ref, t, N: int):
+    """Row t of a [Q, lanes] float32 ref in all N sublanes."""
+    return jnp.broadcast_to(ref[pl.ds(t, 1), :], (N, ref.shape[1]))
+
+
+def _sel_fwd_kernel(x_ref, dt_ref, bl_ref, cl_ref, at_ref, d_ref, y_ref,
+                    start_ref, s_scr, u_scr, y_scr):
+    tile, (N, lanes) = pl.program_id(2), at_ref.shape
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_scr[tile] = jnp.zeros((N, lanes), s_scr.dtype)
+
+    start = s_scr[tile].astype(jnp.float32)
+    start_ref[0, 0] = start
+    x = x_ref[0].astype(jnp.float32)
+    u_scr[...] = dt_ref[0] * x
+    A_t = at_ref[...]
+
+    def token(t, S):
+        S = (jnp.exp(_row(dt_ref.at[0], t, N) * A_t) * S
+             + _row(u_scr, t, N) * _across_tiles(bl_ref[0, t], lanes))
+        y_scr[pl.ds(t, 1), :] = jnp.sum(
+            S * _across_tiles(cl_ref[0, t], lanes), axis=0, keepdims=True)
+        return S
+
+    s_scr[tile] = jax.lax.fori_loop(0, x.shape[0], token, start).astype(
+        s_scr.dtype)
+    y_ref[0] = (y_scr[...] + d_ref[...] * x).astype(y_ref.dtype)
+
+
+def _sel_bwd_kernel(x_ref, dt_ref, bl_ref, cl_ref, at_ref, d_ref, start_ref,
+                    dy_ref, dx_ref, ddt_ref, dbl_ref, dcl_ref, da_ref,
+                    h_scr, all_scr, u_scr, dy_scr, du_scr, dd_scr):
+    """The chunks arrive last first. `all_scr` [Q, N, lanes]: the state in
+    front of each of the chunk's tokens, formed again from the saved start;
+    `h_scr` [tiles, N, lanes]: the cotangent of the state the chunk ends in."""
+    tile, (N, lanes) = pl.program_id(2), at_ref.shape
+    Q = x_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_scr[tile] = jnp.zeros((N, lanes), jnp.float32)
+
+    @pl.when(tile == 0)
+    def _():
+        dbl_ref[...] = jnp.zeros(dbl_ref.shape, jnp.float32)
+        dcl_ref[...] = jnp.zeros(dcl_ref.shape, jnp.float32)
+
+    x = x_ref[0].astype(jnp.float32)
+    dt = dt_ref[0]
+    u_scr[...] = dt * x
+    dy_scr[...] = dy_ref[0].astype(jnp.float32)
+    A_t = at_ref[...]
+
+    def decay_input_and_b(t):
+        Bl = _across_tiles(bl_ref[0, t], lanes)
+        return (jnp.exp(_row(dt_ref.at[0], t, N) * A_t),
+                _row(u_scr, t, N) * Bl, Bl)
+
+    def again(t, S):
+        all_scr[t] = S
+        a, own, _ = decay_input_and_b(t)
+        return a * S + own
+
+    jax.lax.fori_loop(0, Q, again, start_ref[0, 0])
+
+    def token(i, carry):
+        H, dA_t = carry
+        t = Q - 1 - i
+        before = all_scr[t]
+        a, own, Bl = decay_input_and_b(t)
+        dy_b = _row(dy_scr, t, N)
+        G = dy_b * _across_tiles(cl_ref[0, t], lanes) + H
+        dcl_ref[0, t] += _fold_tiles(dy_b * (a * before + own))
+        dbl_ref[0, t] += _fold_tiles(G * _row(u_scr, t, N))
+        du_scr[pl.ds(t, 1), :] = jnp.sum(G * Bl, axis=0, keepdims=True)
+        H = a * G
+        dlog = H * before                    # the cotangent of dt_t A
+        dd_scr[pl.ds(t, 1), :] = jnp.sum(dlog * A_t, axis=0, keepdims=True)
+        return H, dA_t + dlog * _row(dt_ref.at[0], t, N)
+
+    H, dA_t = jax.lax.fori_loop(
+        0, Q, token, (h_scr[tile], jnp.zeros((N, lanes), jnp.float32)))
+    h_scr[tile] = H
+    da_ref[0, 0] = dA_t
+    du = du_scr[...]
+    ddt_ref[0] = dd_scr[...] + du * x
+    dx_ref[0] = (du * dt + d_ref[...] * dy_scr[...]).astype(dx_ref.dtype)
+
+
+_SEL_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _sel_specs(N: int, lanes: int, chunk_of):
+    """BlockSpec makers for a grid (batch, chunk step, channel tile):
+    [B, T, C] arrays, the lane-spread [B, T, N, 128] ones, the per-chunk
+    [B, chunks, N, C] ones, and the two [., C] operands."""
+    Q = SEL_CHUNK
+    return (pl.BlockSpec((1, Q, lanes), lambda b, s, j: (b, chunk_of(s), j)),
+            pl.BlockSpec((1, Q, N, _LANES),
+                         lambda b, s, j: (b, chunk_of(s), 0, 0)),
+            pl.BlockSpec((1, 1, N, lanes),
+                         lambda b, s, j: (b, chunk_of(s), 0, j)),
+            lambda rows: pl.BlockSpec((rows, lanes), lambda b, s, j: (0, j)))
+
+
+def _spread(m):
+    """[B, T, N] -> [B, T, N, 128] float32: each number in all 128 lanes."""
+    return jnp.broadcast_to(m.astype(jnp.float32)[..., None],
+                            (*m.shape, _LANES))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sel_kernel_forward(x, dt, A, Bm, Cm, D, interpret=False):
+    Bsz, T, C = x.shape
+    N, lanes = A.shape[1], _sel_lanes(C)
+    tokens, spread, chunked, small = _sel_specs(N, lanes, lambda s: s)
+    return pl.pallas_call(
+        _sel_fwd_kernel,
+        grid=(Bsz, T // SEL_CHUNK, C // lanes),
+        in_specs=[tokens, tokens, spread, spread, small(N), small(1)],
+        out_specs=[tokens, chunked],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((Bsz, T // SEL_CHUNK, N, C),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((C // lanes, N, lanes), _CARRY_DTYPE),
+                        pltpu.VMEM((SEL_CHUNK, lanes), jnp.float32),
+                        pltpu.VMEM((SEL_CHUNK, lanes), jnp.float32)],
+        compiler_params=_SEL_PARAMS, interpret=interpret,
+        name="selective_scan_fwd",
+    )(x, dt, _spread(Bm), _spread(Cm), A.astype(jnp.float32).T,
+      D.astype(jnp.float32)[None, :])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sel_kernel_backward(x, dt, A, Bm, Cm, D, starts, dy, interpret=False):
+    Bsz, T, C = x.shape
+    N, lanes, chunks = A.shape[1], _sel_lanes(C), T // SEL_CHUNK
+    tokens, spread, chunked, small = _sel_specs(
+        N, lanes, lambda s: chunks - 1 - s)
+    rows = pltpu.VMEM((SEL_CHUNK, lanes), jnp.float32)
+    dx, ddt, dBl, dCl, dA_t = pl.pallas_call(
+        _sel_bwd_kernel,
+        grid=(Bsz, chunks, C // lanes),
+        in_specs=[tokens, tokens, spread, spread, small(N), small(1), chunked,
+                  tokens],
+        out_specs=[tokens, tokens, spread, spread, chunked],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(x.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((Bsz, T, N, _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((Bsz, T, N, _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct(starts.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((C // lanes, N, lanes), jnp.float32),
+                        pltpu.VMEM((SEL_CHUNK, N, lanes), jnp.float32),
+                        rows, rows, rows, rows],
+        compiler_params=_SEL_PARAMS, interpret=interpret,
+        name="selective_scan_bwd",
+    )(x, dt, _spread(Bm), _spread(Cm), A.astype(jnp.float32).T,
+      D.astype(jnp.float32)[None, :], starts, dy)
+    dD = jnp.sum(dy.astype(jnp.float32) * x.astype(jnp.float32), axis=(0, 1))
+    return (dx, ddt.astype(dt.dtype),
+            jnp.sum(dA_t, axis=(0, 1)).T.astype(A.dtype),
+            jnp.sum(dBl, axis=-1).astype(Bm.dtype),
+            jnp.sum(dCl, axis=-1).astype(Cm.dtype), dD.astype(D.dtype))
+
+
+@jax.custom_vjp
+def _selective_scan(x, dt, A, Bm, Cm, D):
+    return _selective_scan_fwd(x, dt, A, Bm, Cm, D)[0]
+
+
+def _selective_scan_fwd(x, dt, A, Bm, Cm, D):
+    forward = (_sel_kernel_forward if selective_kernels_eligible(x, A)
+               else _sel_xla_forward)
+    y, starts = forward(x, dt, A, Bm, Cm, D)
+    return y, (x, dt, A, Bm, Cm, D, starts)
+
+
+def _selective_scan_bwd(saved, dy):
+    backward = (_sel_kernel_backward if selective_kernels_eligible(*saved[:3:2])
+                else _sel_xla_backward)
+    return backward(*saved, dy.astype(saved[0].dtype))
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
+
+_sel_bytes: dict = {}      # an op's name in its Program -> (bytes, saved)
+
+
+def selective_scan_bytes(batch: int, T: int, C: int, N: int, itemsize: int):
+    """(the bytes of `selective_scan`'s operands and results over one forward
+    and one backward, the bytes of the chunk starts kept between them), from
+    its static shapes: forward reads x, B, C (`itemsize` an element) and dt
+    (float32) and writes y; backward reads those and dy and writes their
+    five gradients; A [C, N] and D [C] float32 are read twice and their
+    gradients written once. What an implementation reads again or keeps in
+    between (the starts, forward and backward) counts nothing here."""
+    tokens = batch * T
+    forward = tokens * ((2 * C + 2 * N) * itemsize + 4 * C)
+    backward = tokens * ((4 * C + 4 * N) * itemsize + 8 * C)
+    return (forward + backward + 3 * 4 * (C * N + C),
+            batch * -(-T // SEL_CHUNK) * C * N * 4)
+
+
+def _count_selective(path: str, x, A, name: str) -> None:
+    """One scan traced: its path, and its bytes a step (gauges: the sum over
+    the ops traced so far, an op traced again counted once)."""
+    from ..obs import metrics
+
+    reg = metrics.registry()
+    reg.counter_inc(
+        "pt_selective_scan_dispatch_total",
+        help="Mamba-1 selective scans traced, by the formulation that runs "
+             "them",
+        labels={"path": path})
+    B, T, C = x.shape
+    _sel_bytes[name] = selective_scan_bytes(B, T, C, A.shape[1],
+                                            x.dtype.itemsize)
+    reg.gauge(
+        "pt_selective_scan_bytes",
+        lambda: sum(b for b, _ in _sel_bytes.values()),
+        help="bytes of the selective scans' operands and results over one "
+             "forward and one backward, from the static shapes of the ops "
+             "traced so far")
+    reg.gauge(
+        "pt_selective_scan_saved_state_bytes",
+        lambda: sum(s for _, s in _sel_bytes.values()),
+        help="bytes of the chunk-start states the selective scans keep "
+             "between forward and backward ([B, T / Q, N, C] float32 an op)")
+
+
+def selective_scan(x, dt, A, Bm, Cm, D, name: str = ""):
+    """Mamba-1's scan: x [B, T, C], dt [B, T, C] float32 and positive (after
+    its softplus), A [C, N] negative, Bm / Cm [B, T, N], D [C] -> y [B, T, C]
+    in x's dtype (the recurrence above this section). Differentiable in all
+    six; the path is chosen here, when the op is traced, and counted in
+    `pt_selective_scan_dispatch_total{path}` with the op's bytes beside it.
+    `name`: what the registry's byte counts know this op by."""
+    _count_selective(
+        "pallas" if selective_kernels_eligible(x, A) else "xla_chunked", x, A,
+        name)
+    return _selective_scan(x, dt, A, Bm, Cm, D)
+
+
+@jax.custom_vjp
+def silu_gate(value, gate):
+    """value * silu(gate) in value's dtype, float32 inside; the backward
+    keeps the two operands and nothing of their shape beside them."""
+    g = gate.astype(jnp.float32)
+    return (value.astype(jnp.float32) * (g * jax.nn.sigmoid(g))).astype(
+        value.dtype)
+
+
+def _silu_gate_fwd(value, gate):
+    return silu_gate(value, gate), (value, gate)
+
+
+def _silu_gate_bwd(saved, dy):
+    value, gate = saved
+    v, g, dy = (a.astype(jnp.float32) for a in (value, gate, dy))
+    s = jax.nn.sigmoid(g)
+    return ((dy * g * s).astype(value.dtype),
+            (dy * v * (s * (1.0 + g * (1.0 - s)))).astype(gate.dtype))
+
+
+silu_gate.defvjp(_silu_gate_fwd, _silu_gate_bwd)
+
+
+@register_op("silu_gate")
+def silu_gate_kernel(ctx):
+    """X * silu(Gate), shaped like X (`layers.silu_gate`). Without a Gate, X
+    is [gate | value] side by side along its last axis (a fused
+    up-projection's output) and Out is half as wide."""
+    x = ctx.input("X")
+    if ctx.has_input("Gate"):
+        value, gate = x, ctx.input("Gate")
+    else:
+        half = x.shape[-1] // 2
+        gate, value = x[..., :half], x[..., half:]
+    ctx.set_output("Out", silu_gate(value, gate))
+
+
+def mamba1_mixer(h, in_w, conv_w, conv_b, x_w, dt_w, dt_b, A_log, D, out_w,
+                 *, name: str = ""):
+    """h [B, T, d] -> (out [B, T, d], the scan's output y [B, T, d_in] in front
+    of the gate), both in the projections' dtype (the amp dtype where the
+    caller cast them). in_w [d, 2 d_in] gives [x | z]; conv_w [K, d_in],
+    conv_b [d_in]; x_w [d_in, R + 2 N] gives [r | B | C]; dt_w [R, d_in],
+    dt_b [d_in]; A_log [d_in, N]; D [d_in]; out_w [d_in, d]. One checkpoint
+    from the in-projection's output to the out-projection's input, as
+    `mamba2_mixer` has it: the backward keeps [x | z] and forms the conv, the
+    two small projections, dt and the scan's forward again."""
+    cd = in_w.dtype
+    d_in, N = A_log.shape
+    R = dt_w.shape[0]
+
+    def between(xz, conv_w, conv_b, x_w, dt_w, dt_b, A_log, D):
+        with jax.named_scope("conv"):
+            x = causal_conv_silu(xz[..., :d_in], conv_w, conv_b)
+        with jax.named_scope("dt_bc"):
+            rbc = jnp.dot(x, x_w, preferred_element_type=jnp.float32)
+            dt = jax.nn.softplus(
+                jnp.dot(rbc[..., :R].astype(cd), dt_w,
+                        preferred_element_type=jnp.float32) + dt_b)
+            Bm, Cm = (rbc[..., R:R + N].astype(cd),
+                      rbc[..., R + N:].astype(cd))
+        with jax.named_scope("scan"):
+            y = selective_scan(x, dt, -jnp.exp(A_log.astype(jnp.float32)),
+                               Bm, Cm, D, name=name)
+        with jax.named_scope("gate"):
+            return silu_gate(y, xz[..., d_in:]), y
+
+    with jax.named_scope("in_proj"):
+        xz = jnp.dot(h.astype(cd), in_w,
+                     preferred_element_type=jnp.float32).astype(cd)
+    gated, y = jax.checkpoint(between)(xz, conv_w, conv_b, x_w, dt_w, dt_b,
+                                       A_log, D)
+    with jax.named_scope("out_proj"):
+        return jnp.dot(gated, out_w,
+                       preferred_element_type=jnp.float32).astype(cd), y
+
+
+@register_op("mamba1_mixer")
+def mamba1_mixer_kernel(ctx):
+    """Program-IR face: X [B, T, d]; InW, ConvW, ConvB, XW, DtW, DtB, ALog,
+    D, OutW as `mamba1_mixer` takes them. Out shaped like X and Memory [B, T,
+    d_in] (the scan's output in front of the gate, for the layers that read
+    it), in the compute dtype: under amp the four projection matrices are
+    cast down, the taps, dt's bias, A_log and D stay float32."""
+    in_w, x_w, dt_w, out_w = amp.cast_inputs(
+        ctx, ctx.input("InW"), ctx.input("XW"), ctx.input("DtW"),
+        ctx.input("OutW"))
+    out, memory = mamba1_mixer(
+        ctx.input("X"), in_w, ctx.input("ConvW"), ctx.input("ConvB"), x_w,
+        dt_w, ctx.input("DtB"), ctx.input("ALog"), ctx.input("D"), out_w,
+        name=ctx.op.outputs["Out"][0])
+    ctx.set_output("Out", out)
+    ctx.set_output("Memory", memory)
+
+
+@register_op("mamba1_init")
+def mamba1_init_kernel(ctx):
+    """Startup op: A_log [d_in, N] with A_log[c, n] = log(n + 1) (Mamba-1's
+    S4D-real start: A = -(1 .. N) in every channel)."""
+    d_in, N = ctx.attr("shape")
+    ctx.set_output("Out", jnp.broadcast_to(
+        jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), (d_in, N)))

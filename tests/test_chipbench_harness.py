@@ -670,7 +670,7 @@ def test_the_manifest_lists_the_nemotron_cell_and_its_metrics():
                     "nemotron.gmm_roofline", "nemotron.load_max_over_mean",
                     "nemotron.feed_produce_ms_per_step",
                     "ssm.conv_ms"]                       # PR 50, the list's last
-    assert manifest["per_layer"][-1] == {
+    assert manifest["per_layer"][97] == {      # the last until PR 57's eleven
         "name": "ssm.conv_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "State-space mixers",
         "moves": "items_s", "workloads": [cell]}
@@ -2335,13 +2335,12 @@ def test_the_manifest_lists_the_lfm2_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell = LFM2 + ".train-log10"
-    assert manifest["workloads"][-1]["name"] == cell     # appended, last
-    entry = manifest["workloads"][-1]
+    assert manifest["workloads"][7]["name"] == cell      # appended in PR 49
+    entry = manifest["workloads"][7]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         LFM2, "train-log10", 1)
-    assert len(manifest["workloads"]) == 8 and len(manifest["configs"]) == 7
-    assert sum(w["chips"] for w in manifest["workloads"]) == 8   # no 4-chip
-    config = manifest["configs"][-1]
+    assert sum(w["chips"] for w in manifest["workloads"][:8]) == 8   # no 4-chip
+    config = manifest["configs"][6]
     assert config["name"] == LFM2 and config["source"] == _lfm2_config()["source"]
     assert config["reduced"] == _lfm2_config()["reduced"] == [
         "num_hidden_layers", "layer_types", "num_dense_layers", "num_experts",
@@ -2356,7 +2355,7 @@ def test_the_manifest_lists_the_lfm2_cell_and_its_metrics():
     end = names.index(mine[-1]) + 1                      # appended in PR 49
     assert names[end - len(mine):end] == mine            # contiguous, in order
     # PR 50 appended one reader of the hybrid's cell behind them
-    assert names[end:] == ["ssm.conv_ms"]
+    assert names[end:end + 1] == ["ssm.conv_ms"]
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     assert {by_name[n]["layer"] for n in mine
             if n.startswith(("conv.",))} == {"Short-conv operators"}
@@ -2468,3 +2467,440 @@ def test_the_lfm2_cell_rehearses_on_the_cpu(tmp_path):
     info = next(json.loads(line.split("info ", 1)[1])
                 for line in out.stdout.splitlines() if "] info {" in line)
     assert info["cell"] == LFM2 + ".train-log10" and info["rehearsal"]
+
+
+# PR 57: phi-4-mini-flash-reasoning (Mamba-1 mixers, differential attention
+# under a window, whole and on another layer's keys and values, a gated memory
+# unit, a tied head)
+PHI4 = "phi-4-mini-flash-reasoning"
+PHI4_CELL = {"batch": 1, "seqlen": 8192}
+
+
+def _phi4_config():
+    with open(os.path.join(BENCH, "configs", PHI4, "config.json")) as f:
+        return json.load(f)
+
+
+def test_phi4_flops_per_token():
+    flops = _load("flops.py")
+    cfg = _phi4_config()
+    config_dir = os.path.join(BENCH, "configs", PHI4)
+    got = flops.train_flops_per_item(cfg, PHI4_CELL, config_dir)
+    assert got == 3 * 1527004640.0 == 4581013920.0
+    own = _load("configs", PHI4, "flops.py")
+    mixing = {k: own.mixing_flops_per_token(cfg, 8192, k)
+              for k in ("mamba", "gmu", "window", "full", "cross")}
+    assert mixing == {"mamba": 82247680, "gmu": 52428800,
+                      "window": 39321600 + 7619040,
+                      "full": 39321600 + 62922240,
+                      "cross": 26214400 + 62922240}
+    with pytest.raises(ValueError, match="unknown layer kind"):
+        own.mixing_flops_per_token(cfg, 8192, "conv")
+    # the shares ISSUE 57 states: the MLPs 62 %, the head 8 %; whole-length
+    # attention's kernels 62.9 MFLOP a token, the windowed layer's 7.6
+    whole = 1527004640.0
+    assert round(100 * 6 * 6 * 2560 * 10240 / whole) == 62
+    assert round(100 * 2 * 2560 * 25008 / whole) == 8
+    assert own.keys_seen(8192) == 4096.5
+    assert own.keys_seen(8192, 512) == (512 * 513 / 2 + 7680 * 512) / 8192
+    # at T 4096 (the cell's fallback) only the two whole-length layers move
+    short = own.forward_flops_per_token(cfg, 4096)
+    assert whole - short == pytest.approx(
+        2 * 6 * 40 * 64 * 2048 + 6 * 40 * 64 * (
+            own.keys_seen(8192, 512) - own.keys_seen(4096, 512)))
+    assert round(3 * short / 1e9, 2) == 4.39
+    # the whole model: 9 mixers, 8 windowed, 1 whole, 7 units, 7 cross
+    full = dict(cfg, num_hidden_layers=32, layer_ids=None, vocab_size=200064)
+    assert own.forward_flops_per_token(full, 8192) == (
+        9 * 82247680 + 8 * 46940640 + 102243840 + 7 * 52428800
+        + 7 * 89136640 + 32 * 157286400 + 2 * 2560 * 200064)
+
+
+def test_phi4_config_keeps_the_published_sizes():
+    cfg = _phi4_config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        published = next(e for e in map(json.loads, f)
+                         if e["name"] == "Phi-4-mini-flash-reasoning")
+    assert cfg["source"] == published["source_url"]
+    differs = [k for k, v in published["config"].items()
+               if cfg.get(k, "absent") != v]
+    assert sorted(differs) == sorted(cfg["reduced"]) == [
+        "num_hidden_layers", "vocab_size"]
+    assert cfg["published"] == {k: published["config"][k] for k in differs}
+    # the cut: one period of the self-decoder, the boundary pair, one period
+    # of the cross-decoder; an eighth of the vocabulary
+    assert cfg["layer_ids"] == [0, 1, 16, 17, 18, 19]
+    assert cfg["num_hidden_layers"] == len(cfg["layer_ids"]) == 6
+    assert cfg["vocab_size"] * 8 == 200064
+    ref = _load("configs", PHI4, "reference.py")
+    assert [k for _, k in ref.held_layers(cfg)] == [
+        "mamba", "window", "mamba", "full", "gmu", "cross"]
+    # no width is cut
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["intermediate_size"],
+            cfg["sliding_window"], cfg["mb_per_layer"], cfg["mamba_d_state"],
+            cfg["mamba_d_conv"], cfg["mamba_expand"], cfg["mamba_dt_rank"]) \
+        == (2560, 40, 20, 10240, 512, 2, 16, 4, 2, 160)
+    assert cfg["tie_word_embeddings"] and not cfg["mlp_bias"]
+    assert "8-chip" in cfg["deployment"] and "32" in cfg["distortion"]
+    for key in ("assumed", "departures", "deployment", "distortion",
+                "gradient_limits", "held_layers"):
+        assert cfg[key], key
+    for key in ("basis", "mixer", "layer_map", "gated_memory_unit",
+                "attention", "stream", "optimizer", "compute_dtype",
+                "initialisers"):
+        assert cfg["assumed"][key], key
+    # the parameters this chip holds: ISSUE 57's count
+    d, f, c = 2560, 10240, 5120
+    mlp = d * 2 * f + f * d
+    mixer = d * 2 * c + 4 * c + c + c * 192 + 160 * c + c + c * 16 + c + c * d
+    attn = d * 5120 + 5120 + 4 * 64 + 128 + d * d + d
+    cross = d * d + d + 4 * 64 + 128 + d * d + d
+    gmu = 2 * d * c
+    assert (mixer, mlp, attn, gmu, cross) == (
+        41241600, 78643200, 19668864, 26214400, 13112704)
+    layer = lambda mixing: mixing + mlp + 4 * d  # noqa: E731
+    assert (layer(mixer), layer(attn), layer(gmu), layer(cross)) == (
+        119895040, 98322304, 104867840, 91766144)
+    assert (2 * layer(mixer) + 2 * layer(attn) + layer(gmu) + layer(cross)
+            + 2 * d + 25008 * d) == 697094272
+
+
+def test_phi4_kernels_count_on_hand_made_cells():
+    cfg = _phi4_config()
+    scan = _load("kernels", "selective_scan.py")
+    assert scan.held_kinds(cfg).count("mamba") == 2
+    ops, bytes_ = scan.flops_and_bytes(cfg, PHI4_CELL)
+    C, N, T = 5120, 16, 8192
+    assert ops == 2 * T * 27 * C * N
+    # forward: x, B, C, y bf16 and dt float32; backward: those, dy and the
+    # five gradients; A and D twice and their gradients
+    assert bytes_ == 2 * (T * ((2 * C + 2 * N) * 2 + 4 * C)
+                          + T * ((4 * C + 4 * N) * 2 + 8 * C)
+                          + 12 * (C * N + C))
+    # what the program's own gauge counts for the same two ops
+    import sys as _sys
+    _sys.path.insert(0, ROOT)
+    from paddle_tpu.ops import ssm_ops
+    assert 2 * ssm_ops.selective_scan_bytes(1, T, C, N, 2)[0] == bytes_
+    assert ssm_ops.selective_scan_bytes(1, T, C, N, 2)[1] == 64 * C * N * 4
+    flash = _load("kernels", "phi4_flash_attention.py")
+    plain = _load("kernels", "flash_attention.py")
+    assert flash.kept_pairs(8192) == 8192 * 8193 // 2
+    assert flash.kept_pairs(8192, 512) == 512 * 513 // 2 + 7680 * 512
+    assert flash.kept_pairs(256, 512) == 256 * 257 // 2
+    got = flash.flops_and_bytes(cfg, PHI4_CELL)
+    # a whole-length layer: four launches of what the plain count gives one
+    # layer of 20 heads over 10 of 64
+    one = plain.flops_and_bytes(
+        {"num_attention_heads": 20, "num_key_value_heads": 10,
+         "num_hidden_layers": 1, "hidden_size": 1280}, PHI4_CELL)
+    windowed = 4 * 20 * 12 * flash.kept_pairs(8192, 512) * 64
+    assert got == (2 * 4 * one[0] + windowed, 3 * 4 * one[1])
+
+
+PHI4_MIXER = ("mamba1_mixer.phi4.h0.mamba.tmp_3",
+              "mamba1_mixer.phi4.h16.mamba.tmp_40")
+
+
+def _phi4_run_record():
+    """Two steps of two mixers, one attention layer, one memory unit."""
+    first, second = PHI4_MIXER
+    flash = "flash_attention.phi4.h1.attn.kernels.tmp_%d"
+    ops = [
+        _row(first, 6_000_000, None, "jvp(", "in_proj"),
+        _row(first, 1_000_000, "tpu_custom_call", "jvp(", "conv"),
+        _row(first, 500_000, None, "jvp(", "dt_bc"),
+        _row(first, 3_000_000, "tpu_custom_call", "jvp(", "scan"),
+        _row(first, 200_000, None, "jvp(", "gate"),
+        _row(first, 2_000_000, None, "jvp(", "out_proj"),
+        _row(first, 3_000_000, "tpu_custom_call", "transpose(jvp(", "scan"),
+        _row(first, 7_000_000, "tpu_custom_call", "transpose(jvp(", "scan"),
+        _row(first, 300_000, None, "transpose(jvp(", "scan"),   # dA's sum
+        _row(second, 3_000_000, "tpu_custom_call", "jvp(", "scan"),
+        _row(second, 10_000_000, "tpu_custom_call", "transpose(jvp(", "scan"),
+        _row(second, 30_000_000, None, "", container=True),     # a loop: out
+        _row("mul.phi4.h1.attn.qkv.tmp_10", 2_000_000, None, "jvp("),
+        _row("split_head_pairs.phi4.h1.attn.qkv.tmp_13", 100_000, None, "jvp("),
+        _row(flash % 20, 5_000_000, "tpu_custom_call", "jvp("),
+        _row(flash % 21, 5_000_000, "tpu_custom_call", "jvp("),
+        _row(flash % 21, 15_000_000, "tpu_custom_call", "transpose(jvp("),
+        _row(flash % 21, 400_000, None, "transpose(jvp("),      # dK, dV sums
+        _row("diff_combine.phi4.h1.attn.combine.tmp_24", 600_000, None, "jvp("),
+        _row("diff_combine.phi4.h1.attn.combine.tmp_24", 1_400_000, None,
+             "transpose(jvp("),
+        _row("mul.phi4.h1.attn.out_proj.tmp_25", 1_000_000, None, "jvp("),
+        _row("mul.phi4.h18.gmu.gate_proj.tmp_60", 1_000_000, None, "jvp("),
+        _row("silu_gate.phi4.h18.gmu.gate.tmp_61", 200_000, None, "jvp("),
+        _row("mul.phi4.h18.gmu.out_proj.tmp_62", 1_000_000, None,
+             "transpose(jvp("),
+        _row("silu_gate.phi4.h1.mlp.gate.tmp_30", 900_000, None, "jvp("),
+        _row("matmul.matmul_0.tmp_0", 5_000_000),     # the head: no part of it
+    ]
+
+    def op(kind, out, **inputs):
+        return {"type": kind, "scope": f"{kind}.{out}",
+                "inputs": {k: [v] for k, v in inputs.items()},
+                "outputs": {"Out": [out]}}
+
+    attn = "phi4.h1.attn."
+    program_ops = [
+        {"type": "mamba1_mixer", "scope": first, "inputs": {"X": ["h0"]},
+         "outputs": {"Out": ["phi4.h0.mamba.tmp_3"],
+                     "Memory": ["phi4.h0.mamba.tmp_4"]}},
+        op("mul", attn + "qkv.tmp_10", X="u", Y="w"),
+        {"type": "split_head_pairs",
+         "scope": "split_head_pairs." + attn + "qkv.tmp_13",
+         "inputs": {"X": ["q"]},
+         "outputs": {"First": [attn + "qkv.tmp_13"],
+                     "Second": [attn + "qkv.tmp_14"]}},
+        op("flash_attention", attn + "kernels.tmp_20", Q="q1", K="k1", V="v1"),
+        op("flash_attention", attn + "kernels.tmp_21", Q="q1", K="k1", V="v2"),
+        op("diff_combine", attn + "combine.tmp_24", A11="a", A12="b"),
+        op("mul", attn + "out_proj.tmp_25", X="o", Y="wo"),
+        op("silu_gate", "phi4.h1.mlp.gate.tmp_30", X="up"),
+        {"type": "mamba1_mixer", "scope": second, "inputs": {"X": ["h2"]},
+         "outputs": {"Out": ["phi4.h16.mamba.tmp_40"],
+                     "Memory": ["phi4.h16.mamba.tmp_41"]}},
+        op("mul", "phi4.h18.gmu.gate_proj.tmp_60", X="u", Y="wg"),
+        op("silu_gate", "phi4.h18.gmu.gate.tmp_61",
+           X="phi4.h16.mamba.tmp_41", Gate="phi4.h18.gmu.gate_proj.tmp_60"),
+        op("mul", "phi4.h18.gmu.out_proj.tmp_62", X="g", Y="wo"),
+        op("matmul", "matmul_0.tmp_0", X="hf", Y="phi4.tok_emb")]
+    registry = {'pt_selective_scan_dispatch_total{path="pallas"}': 0.0,
+                "pt_selective_scan_bytes": 2 * 737e6,
+                "pt_selective_scan_saved_state_bytes": 2 * 20971520.0,
+                "pt_diff_attention_launches_total": 12.0,
+                "pt_executor_donated_bytes": 8.37e9}
+    return {"steps": 2, "trace": {"ops": ops}, "registry": registry,
+            "device": {"kind": "TPU v5 lite"}, "config": _phi4_config(),
+            "cell": dict(PHI4_CELL), "program_ops": program_ops}
+
+
+def test_phi4_readers_find_their_rows_by_op_type_name_and_inner_scope():
+    run = _phi4_run_record()
+    mixers = _load("layer_metrics", "ssm1.device_ms.py")
+    # every leaf row under a mixer's scope, forward and backward; not the
+    # loop, not the attention layer's, the unit's or the head's
+    assert mixers.compute(run) == pytest.approx(36.0 / 2)
+    info = mixers.info(run)
+    assert info["mixers"] == 2
+    assert info["by_inner_scope_ms"] == pytest.approx(
+        {"in_proj": 3.0, "conv": 0.5, "dt_bc": 0.25, "scan": 13.15,
+         "gate": 0.1, "out_proj": 1.0})
+    assert info["by_pass_ms"] == pytest.approx(
+        {"jvp": 7.85, "transpose": 10.15})
+    scan = _load("layer_metrics", "ssm1.scan_ms.py")
+    assert scan.compute(run) == pytest.approx(26.3 / 2)
+    info = scan.info(run)
+    assert info["run_by"] == "kernels"
+    assert info["kernels_ms"] == pytest.approx(13.0)
+    assert info["bytes_per_step"] == 2 * 737e6
+    assert info["saved_state_bytes"] == 2 * 20971520.0
+    assert list(info["dispatch"]) == [
+        'pt_selective_scan_dispatch_total{path="pallas"}']
+    # the scans' share of their roofline: the operands and results of two
+    # ops at 819 GB/s over the 13.15 ms a step under `scan`; memory's bound
+    roof = _load("layer_metrics", "kernel.selective_scan_roofline.py")
+    need = _load("kernels", "selective_scan.py")
+    ops, bytes_ = need.flops_and_bytes(run["config"], run["cell"])
+    assert roof.compute(run) == pytest.approx(
+        100 * bytes_ / 819e9 / 13.15e-3, rel=1e-3)
+    assert roof.compute(run) < 100
+    assert roof.info(run)["bound"] == "memory"
+    assert roof.info(run)["run_by"] == "kernels"
+    # XLA's plain form: the same rows by their scope, whoever runs them
+    for r in run["trace"]["ops"]:
+        if r["scope"] in PHI4_MIXER:
+            r["target"] = None
+    assert scan.info(run)["run_by"] == "xla"
+    assert roof.compute(run) is not None
+    run = _phi4_run_record()
+    unit = _load("layer_metrics", "gmu.device_ms.py")
+    assert unit.units(run["program_ops"]) == ["phi4.h18.gmu."]
+    assert unit.compute(run) == pytest.approx(2.2 / 2)     # not the MLP's gate
+    assert unit.info(run)["by_scope_ms"] == pytest.approx(
+        {"gate_proj": 0.5, "gate": 0.1, "out_proj": 0.5})
+    attn = _load("layer_metrics", "diffattn.device_ms.py")
+    assert attn.layers(run["program_ops"]) == ["phi4.h1.attn."]
+    assert attn.compute(run) == pytest.approx(30.5 / 2)
+    info = attn.info(run)
+    assert info["by_scope_ms"] == pytest.approx(
+        {"qkv": 1.05, "kernels": 12.7, "combine": 1.0, "out_proj": 0.5})
+    assert info["kernels_ms"] == pytest.approx(12.5)
+    assert info["launches_per_step"] == 12.0
+    assert info["by_layer_ms"] == pytest.approx({"phi4.h1.attn.": 15.25})
+    combine = _load("layer_metrics", "diffattn.combine_ms.py")
+    assert combine.compute(run) == pytest.approx(1.0)
+    assert combine.info(run)["by_pass_ms"] == pytest.approx(
+        {"jvp": 0.3, "transpose": 0.7})
+    flash = _load("layer_metrics", "phi4.flash_roofline.py")
+    need = _load("kernels", "phi4_flash_attention.py")
+    flops, bytes_ = need.flops_and_bytes(run["config"], run["cell"])
+    assert flash.compute(run) == pytest.approx(
+        100 * flops / 197e12 / 12.5e-3, rel=1e-3)
+    assert flash.info(run)["bound"] == "compute"
+    # a Program without the ops (every other configuration, the parent of the
+    # PR that added them), or no trace: nothing, not raised
+    for empty in (dict(run, trace=None), dict(run, program_ops=None),
+                  dict(run, program_ops=_glm_run_record()["program_ops"]),
+                  dict(run, program_ops=_lfm2_run_record()["program_ops"])):
+        for reader in (mixers, scan, roof, unit, attn, combine):
+            assert reader.compute(empty) is None
+    assert flash.compute(dict(run, trace=None)) is None
+
+
+PHI4_WRAPPERS = {"phi4.head_device_ms": "head.device_ms",
+                 "phi4.feed_produce_ms_per_step": "feed.produce_ms_per_step",
+                 "phi4.opt_device_ms": "opt.device_ms",
+                 "phi4.donated_gib": "step.donated_gib"}
+
+
+@pytest.mark.parametrize("name", sorted(PHI4_WRAPPERS))
+def test_a_phi4_wrapper_returns_what_the_reader_it_wraps_returns(name):
+    run = _phi4_run_record()
+    run["program_ops"] += [
+        {"type": "adam", "scope": "adam.phi4.tok_emb",
+         "inputs": {"Param": ["phi4.tok_emb"]},
+         "outputs": {"ParamOut": ["phi4.tok_emb"]}},
+        {"type": "softmax_with_cross_entropy",
+         "scope": "softmax_with_cross_entropy.s",
+         "inputs": {"Logits": ["matmul_0.tmp_0"], "Label": ["y"]},
+         "outputs": {"Softmax": ["s"], "Loss": ["c"]}}]
+    run["trace"]["ops"].append(_row("adam.phi4.tok_emb", 2_000_000))
+    run["timers_s"] = {"prefetch.read": 0.004, "prefetch.batch": 0.002}
+    wrapper = _load("layer_metrics", name + ".py")
+    wrapped = _load("layer_metrics", PHI4_WRAPPERS[name] + ".py")
+    assert wrapper.WRAPS == PHI4_WRAPPERS[name]
+    got, want = wrapper.compute(run), wrapped.compute(run)
+    assert got is not None and got == want
+    empty = dict(run, trace=None, registry={}, timers_s={})
+    assert wrapper.compute(empty) is None and wrapped.compute(empty) is None
+    # the tied head: the walk back from the cost ends in the `matmul` and
+    # takes the table's Adam update with it
+    expected = {"phi4.head_device_ms": 3.5, "phi4.opt_device_ms": 1.0}
+    if name in expected:
+        assert got == pytest.approx(expected[name])
+
+
+def test_the_manifest_lists_the_phi4_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = PHI4 + ".train-log10"
+    assert manifest["workloads"][8]["name"] == cell      # appended in PR 57
+    entry = manifest["workloads"][8]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        PHI4, "train-log10", 1)
+    assert sum(w["chips"] for w in manifest["workloads"][:9]) == 9   # no 4-chip
+    config = manifest["configs"][7]
+    assert config["name"] == PHI4 and config["source"] == _phi4_config()["source"]
+    assert config["reduced"] == _phi4_config()["reduced"] == [
+        "num_hidden_layers", "vocab_size"]
+    mine = [m["name"] for m in manifest["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine == ["ssm1.device_ms", "ssm1.scan_ms",
+                    "kernel.selective_scan_roofline", "gmu.device_ms",
+                    "diffattn.device_ms", "diffattn.combine_ms",
+                    "phi4.flash_roofline", "phi4.head_device_ms",
+                    "phi4.opt_device_ms", "phi4.donated_gib",
+                    "phi4.feed_produce_ms_per_step"]
+    names = [m["name"] for m in manifest["per_layer"]]
+    end = names.index(mine[-1]) + 1
+    assert names[end - len(mine):end] == mine and names.index(mine[0]) == 98
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert {by_name[n]["layer"] for n in mine[:2] + mine[3:4]} == {
+        "Mamba-1 mixers"}
+    assert {by_name[n]["layer"] for n in mine[4:6]} == {
+        "Differential attention"}
+    assert by_name["phi4.donated_gib"]["moves"] == "peak_hbm_gib"
+    assert {by_name[n]["moves"] for n in mine} == {"items_s", "peak_hbm_gib"}
+    assert {by_name[n]["unit"] for n in mine if "roofline" in n} == {"%"}
+    for name in mine:
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+    # no reader that was there lists the new cell: their entries are untouched
+    assert not [m["name"] for m in manifest["per_layer"][:98]
+                if cell in m.get("workloads", ())]
+    tail = next(m for m in manifest["end_to_end"] if m["name"] == "step_ms_p90")
+    assert cell not in tail["workloads"]
+    assert manifest["run_seconds"] == 36
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        traffic = json.load(f)
+    assert (traffic["batch"], traffic["seqlen"], traffic["sync_every"],
+            traffic["warmup_steps"], traffic["trace_seconds"],
+            traffic["mesh"]) == (1, 8192, 10, 20, 4, None)
+    for why in (entry["why"], config["why"]):
+        assert len(why) <= 200 and "\n" not in why
+
+
+def test_the_benchmarks_phi4_reference_is_the_trees_bit_for_bit():
+    """`chipbench/configs/phi-4-mini-flash-reasoning/reference.py` is a copy
+    of `tests/phi4flash_reference.py`, text for text, and gives the same cost
+    and gradients to the bit on the CPU: the two cannot drift apart unseen."""
+    import phi4flash_reference as tree
+
+    copy = _load("configs", PHI4, "reference.py")
+    assert open(copy.__file__).read() == open(tree.__file__).read()
+    cfg = dict(_phi4_config(), **_phi4_config()["rehearsal"])
+    d, V, f = cfg["hidden_size"], cfg["vocab_size"], cfg["intermediate_size"]
+    c, N = cfg["mamba_expand"] * d, cfg["mamba_d_state"]
+    R, K = cfg["mamba_dt_rank"], cfg["mamba_d_conv"]
+    D = d // cfg["num_attention_heads"]
+    kv = cfg["num_key_value_heads"] * D
+    tail = [(D,)] * 4 + [(2 * D,), (d, d), (d,)]
+    mixing = {"mamba": [(d, 2 * c), (K, c), (c,), (c, R + 2 * N), (R, c),
+                        (c,), (c, N), (c,), (c, d)],
+              "gmu": [(d, c), (c, d)],
+              "window": [(d, d + 2 * kv), (d + 2 * kv,)] + tail,
+              "cross": [(d, d), (d,)] + tail}
+    mixing["full"] = mixing["window"]
+    shapes = [(V, d)] + [s for _, kind in tree.held_layers(cfg)
+                         for s in [(d,), (d,)] + mixing[kind]
+                         + [(d,), (d,), (d, 2 * f), (f, d)]] + [(d,), (d,)]
+    rng = np.random.RandomState(0)
+    params = [(rng.randn(*s) * 0.2).astype(np.float32) for s in shapes]
+    toks = rng.randint(0, V, (2, 41))
+    feed = {"toks": toks[:, :-1].astype(np.int32),
+            "labels": toks[:, 1:, None].astype(np.int32)}
+    assert copy.prepare(feed) is feed
+    (c1, g1), (c2, g2) = (m.loss_and_grads(cfg, params, feed)
+                          for m in (tree, copy))
+    assert float(c1) == float(c2) and np.isfinite(float(c1))
+    assert len(g1) == len(g2) == len(params) == 86
+    for a, b in zip(g1, g2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert float(np.abs(np.asarray(a)).max()) > 0
+
+
+def test_the_phi4_cell_rehearses_on_the_cpu(tmp_path):
+    """`run.py --rehearse-cpu` of the new cell: the harness finds the
+    configuration's files by name, the first step agrees with the plain
+    reference at the rehearsal's tolerances (the tied table's gradient among
+    them), the scans' and the pair arithmetic's gauges reach the run record,
+    every new metric file returns a number or nothing on the rehearsal's run,
+    and every metric's name carries the rehearsal's prefix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         PHI4 + ".train-log10", "--rehearse-cpu", "--trace", "1",
+         "--seed", "2147486157"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] and result["rehearsal"] and not result["failed"]
+    names = set(result["metrics"])
+    assert all(n.startswith("REHEARSAL_ON_CPU.") for n in names)
+    assert {"REHEARSAL_ON_CPU.phi4.donated_gib",
+            "REHEARSAL_ON_CPU.phi4.feed_produce_ms_per_step",
+            "REHEARSAL_ON_CPU.attn.masked_pair_share",
+            "REHEARSAL_ON_CPU.loop.dispatch_per_step"} <= names
+    # XLA:CPU has no device plane: the trace's readers return nothing
+    assert not {"REHEARSAL_ON_CPU.ssm1.device_ms",
+                "REHEARSAL_ON_CPU.diffattn.device_ms",
+                "REHEARSAL_ON_CPU.kernel.selective_scan_roofline"} & names
+    assert "choice_counts_off_program" not in result["compared"]   # dense
+    info = next(json.loads(line.split("info ", 1)[1])
+                for line in out.stdout.splitlines() if "] info {" in line)
+    assert info["cell"] == PHI4 + ".train-log10" and info["rehearsal"]
+    assert info["gradient_error_worst"][1] < 0.2

@@ -931,6 +931,42 @@ def build_short_conv_operator():
 
 
 @case
+def build_mamba1_mixer():
+    # seven tokens: the taps' zeros before the start, dt through its low
+    # rank, a decay a channel and state, the gate; both outputs read
+    h, feed = _pre_btd(7, 8)
+    out, memory = L.mamba1_mixer(h, state_size=3, dt_rank=2,
+                                 emit_memory=True)
+    return L.elementwise_add(_scalar(out), _scalar(memory)), feed
+
+
+@case
+def build_gated_memory_unit():
+    h, feed = _pre_btd(5, 8)
+    memory = L.fc(h, size=12, num_flatten_dims=2)
+    return _scalar(L.gated_memory_unit(h, memory)), feed
+
+
+@case
+def build_silu_gate():
+    # a fused [gate | value] and a gate of its own
+    h, feed = _pre_btd(5, 8)
+    return _scalar(L.silu_gate(L.silu_gate(h),
+                               L.fc(h, size=4, num_flatten_dims=2))), feed
+
+
+@case
+def build_differential_attention():
+    # four query heads over two K/V heads of 2 lanes: two query pairs on one
+    # K/V pair, a window of three keys; then a cross layer on its k and v
+    h, feed = _pre_btd(6, 8)
+    out, kv = L.differential_attention(h, 4, 2, depth=1, window=3,
+                                       return_kv=True)
+    cross = L.differential_attention(out, 4, 2, depth=3, shared_kv=kv)
+    return _scalar(cross), feed
+
+
+@case
 def build_moe_aux_loss():
     (out, logits, counts), feed = _moe()
     return L.elementwise_add(_scalar(out),

@@ -247,6 +247,32 @@ def _ssd_scan(case):
     return (fwd_bwd if with_bwd else fwd), specs
 
 
+def _selective_scan(with_bwd):
+    from paddle_tpu.ops import ssm_ops
+
+    # a Phi-4-mini-flash mixer's selective scan at the cell's shape: T 8192
+    # in 64 chunks of 128, 5120 channels in 10 tiles of 512 lanes, 16 states
+    # in the sublanes: the forward kernel (every tile's [16, 512] float32
+    # state in one VMEM scratch across the chunk axis, B and C spread over
+    # 128 lanes) and, with `with_bwd`, the backward kernel (a chunk's 128
+    # states formed again into a 4 MB scratch, dB and dC summed over the
+    # tiles in place)
+    specs = [((1, 8192, 5120), BF16), ((1, 8192, 5120), F32),
+             ((5120, 16), F32), ((1, 8192, 16), BF16), ((1, 8192, 16), BF16),
+             ((5120,), F32)]
+    assert ssm_ops._shapes_selective_ok(jax.ShapeDtypeStruct(*specs[0]),
+                                        jax.ShapeDtypeStruct(*specs[2]))
+
+    def fwd(*args):
+        return ssm_ops._sel_kernel_forward(*args)[0]
+
+    def fwd_bwd(*args):
+        y, starts = ssm_ops._sel_kernel_forward(*args)
+        return ssm_ops._sel_kernel_backward(*args, starts, y)
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
 def _gmm_share(kn):
     from paddle_tpu.ops import moe_ops
 
@@ -336,6 +362,8 @@ CASES = [
     ("ssd_scan_gated_fwd_bwd_nemotron_t8192", _ssd_scan, (True, True)),
     ("causal_conv_silu_fwd_nemotron_t8192", _conv_silu, False),
     ("causal_conv_silu_fwd_bwd_nemotron_t8192", _conv_silu, True),
+    ("selective_scan_fwd_phi4_t8192", _selective_scan, False),
+    ("selective_scan_fwd_bwd_phi4_t8192", _selective_scan, True),
     # ResNet-50 head at a full serving bucket, and the small probe shape
     ("quant_matmul_64x2048x1000", _quant, (64, 2048, 1000)),
     ("quant_matmul_8x512x512", _quant, (8, 512, 512)),
@@ -886,6 +914,39 @@ def test_lfm2_step_program_fits_one_chip(one_chip, compiled_mode,
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 5.8e9          # 12 B a parameter
     print("lfm2 step: arguments %.3f GiB, temporaries %.3f GiB" % (
+        memory.argument_size_in_bytes / 2**30,
+        memory.temp_size_in_bytes / 2**30))
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 14.5 * 2**30)
+
+
+def test_phi4_step_program_fits_one_chip(one_chip, compiled_mode,
+                                         monkeypatch):
+    """The phi-4-mini-flash-reasoning cell's whole step (batch 1 x T 8192,
+    697 M parameters: published layers 0, 1, 16, 17, 18, 19 of
+    Phi-4-mini-flash-reasoning) compiles for the described v5e with arguments
+    + temporaries under 14.5 GiB by the compiler's own books (which settles
+    T 8192 against 4096 before any chip time: ISSUE 57's rule), twelve
+    forward and twelve backward attention launches at 20 heads of 64 (four a
+    layer), each mixer's scan forward twice (once again inside its
+    checkpoint) and backward once, and no array of [T, d_in, N] shape."""
+    raw, args = _step_program(
+        lambda: _benchmark_model("phi-4-mini-flash-reasoning", 1, 8192), 1,
+        8192, one_chip, monkeypatch)
+    launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
+    assert launches == {
+        "flash_attention_fwd": 12, "flash_attention_bwd": 12,
+        "selective_scan_fwd": 4, "selective_scan_bwd": 2,
+        "causal_conv_silu_fwd": 4, "causal_conv_silu_bwd": 2}
+    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "selective_scan_bwd" in text and "flash_attention_bwd" in text
+    for shape in ("[1,8192,5120,16]", "[1,8192,16,5120]", "[8192,5120,16]",
+                  "[8192,16,5120]"):
+        assert shape not in text, shape
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 8.3e9          # 12 B a parameter
+    print("phi4 step: arguments %.3f GiB, temporaries %.3f GiB" % (
         memory.argument_size_in_bytes / 2**30,
         memory.temp_size_in_bytes / 2**30))
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes

@@ -219,6 +219,8 @@ CELL_BLOCKS = {
     "ouro-2.6b": (4096, 512),
     # PR 49: the first cell at 16 k keys; the rule's 1024 x 1024 from 8192 up
     "lfm2-24b-a2b": (16384, 1024),
+    # PR 57: twelve launches a step at 20 heads of 64, one under a window
+    "phi-4-mini-flash-reasoning": (8192, 1024),
 }
 
 
