@@ -296,10 +296,15 @@ def differential_attention(
     (cross attention inside one sequence, so Tq == Tk and the mask stays
     causal; anything else raises). return_kv: returns (out, (k, v)), this
     layer's keys and values after their bias, for such readers.
-    Four `flash_attention` ops a layer ((q_1, k_1, v_1), (q_1, k_1, v_2),
-    (q_2, k_2, v_1), (q_2, k_2, v_2): the kernels take one width for Q, K and
-    V), so eligibility, the window bound, the dispatch counters and the mesh
-    rule are every attention layer's; `diff_combine` behind them. The ops'
+    ONE `flash_attention` op a layer, in its pair form (attr `head_pairs`):
+    heads 2p and 2p + 1 are neighbours in the packed projections, so at heads
+    of 64 lane block p of q is `[q_1 | q_2]`, of k `[k_1 | k_2]` and of v the
+    pair's value `[v_1 | v_2]`, whole, and the kernels keep each softmax's P V
+    over the block's 128 lanes as that head's output (two outputs, each
+    softmax computed once; K/V pair g read by its group's query pairs through
+    the index map, nothing repeated or split in HBM). Eligibility, the window
+    bound, the dispatch counters and the mesh rule are every attention
+    layer's; `diff_combine` behind the launch takes the two outputs. The ops'
     scopes carry `<name>.qkv`, `<name>.kernels`, `<name>.combine`,
     `<name>.out_proj`. param_attr may be a mapping {"wqkv" | "wq" | "wo":
     attr}. Parameters, in order: wqkv (or wq) and its bias, lq1, lk1, lq2,
@@ -326,16 +331,6 @@ def differential_attention(
     def _derive(s):
         return ParamAttr.derive(param_attr, n, s)
 
-    def pairs(x):
-        """(the first heads, the second heads) of a packed projection."""
-        h = LayerHelper("split_head_pairs", name=f"{n}.qkv")
-        half = tuple(x.shape[:-1]) + (int(x.shape[-1]) // 2,)
-        outs = [h.create_tmp_variable(x.dtype, half) for _ in range(2)]
-        h.append_op(type="split_head_pairs", inputs={"X": [x]},
-                    outputs={"First": [outs[0]], "Second": [outs[1]]},
-                    attrs={"head_dim": D})
-        return outs
-
     if shared_kv is None:
         qkv = fc(query, size=E + 2 * E_kv, num_flatten_dims=2,
                  param_attr=_derive("wqkv"), bias_attr=_derive("wqkv_b"),
@@ -351,21 +346,19 @@ def differential_attention(
                 f"{tuple(query.shape[:-1]) + (E_kv,)}")
         q = fc(query, size=E, num_flatten_dims=2, param_attr=_derive("wq"),
                bias_attr=_derive("wq_b"), name=f"{n}.qkv")
-    (q1, q2), (k1, k2), (v1, v2) = pairs(q), pairs(k), pairs(v)
-    attrs = {"num_heads": num_heads // 2, "causal": True}
+    # ONE launch: a pair's heads are neighbours in the packed projections,
+    # so lane block p of q is [q_1 | q_2], of k [k_1 | k_2] and of v the pair's
+    # value, whole; the op's two outputs are A_1 [v_1 | v_2], A_2 [v_1 | v_2]
+    attrs = {"num_heads": num_heads, "causal": True, "head_pairs": True}
     if window:
         attrs["window"] = int(window)
     kernels = LayerHelper("flash_attention", name=f"{n}.kernels")
-
-    def attend(q_, k_, v_):
-        out = kernels.create_tmp_variable(query.dtype, tuple(q_.shape))
-        kernels.append_op(type="flash_attention",
-                          inputs={"Q": [q_], "K": [k_], "V": [v_]},
-                          outputs={"Out": [out]}, attrs=dict(attrs))
-        return out
-
-    launched = [attend(q1, k1, v1), attend(q1, k1, v2),
-                attend(q2, k2, v1), attend(q2, k2, v2)]
+    launched = [kernels.create_tmp_variable(query.dtype, tuple(q.shape))
+                for _ in range(2)]
+    kernels.append_op(type="flash_attention",
+                      inputs={"Q": [q], "K": [k], "V": [v]},
+                      outputs={"Out": [launched[0]], "Out2": [launched[1]]},
+                      attrs=attrs)
     lam_init = 0.8 - 0.6 * math.exp(-0.3 * depth)
     vectors = [helper.create_parameter(
         _derive(s), (D,), default_initializer=NormalInitializer(0.0, lam_std))
@@ -375,14 +368,14 @@ def differential_attention(
         default_initializer=ConstantInitializer(1.0))
     combine = LayerHelper("diff_combine", name=f"{n}.combine")
     out = combine.create_tmp_variable(query.dtype, tuple(query.shape))
-    slots = ("A11", "A12", "A21", "A22", "LamQ1", "LamK1", "LamQ2", "LamK2")
+    slots = ("First", "Second", "LamQ1", "LamK1", "LamQ2", "LamK2")
     combine.append_op(
         type="diff_combine",
         inputs={**{s: [x] for s, x in zip(slots, launched + vectors)},
                 "NormW": [norm_w]},
         outputs={"Out": [out]},
         attrs={"head_dim": D, "lam_init": lam_init, "epsilon": rms_eps,
-               "launches": len(launched)})
+               "launches": 1})
     out = fc(out, size=E, num_flatten_dims=2, param_attr=_derive("wo"),
              bias_attr=_derive("wo_b"), name=f"{n}.out_proj")
     return (out, (k, v)) if return_kv else out

@@ -78,6 +78,29 @@ def scaled_dot_product_attention(q, k, v, causal: bool = False,
 _reference = scaled_dot_product_attention
 
 
+def _pair_view(x):
+    """[B, T, H, D] -> [B, T, H / 2, 2 D]: heads 2p and 2p + 1 side by side
+    as one head of twice the width (a free reshape)."""
+    B, T, H, D = x.shape
+    return x.reshape(B, T, H // 2, 2 * D)
+
+
+def paired_attention(q, k, v, causal: bool = False, window: int = 0):
+    """Attention whose heads come in PAIRS over one value twice a head wide
+    (differential attention's, Ye et al. 2024), plain jnp: the XLA
+    formulation and the oracle of the kernels' pair form. q [B, T, H, D], k
+    and v [B, T, KV, D]; query pair p = heads (2p, 2p + 1) reads K/V pair
+    p // (H / KV). -> (A_1 [v_1 | v_2], A_2 [v_1 | v_2]), each [B, T, H / 2,
+    2 D], A_j = softmax(q_j k_j^T / sqrt(D)) under the mask: two dense
+    softmaxes a pair."""
+    D = q.shape[-1]
+    qp, kp, vp = _pair_view(q), _pair_view(k), _pair_view(v)
+    return tuple(
+        scaled_dot_product_attention(qp[..., j * D:(j + 1) * D],
+                                     kp[..., j * D:(j + 1) * D], vp, causal,
+                                     window) for j in (0, 1))
+
+
 def _shapes_flash_ok(q, k, window: int = 0) -> bool:
     """Backend-independent shape rules (separately testable): 128-aligned
     q AND kv sequence lengths (the kernels' blocks divide them), a head dim
@@ -87,7 +110,9 @@ def _shapes_flash_ok(q, k, window: int = 0) -> bool:
     count at D 64 leaves half a block: XLA keeps it). Fewer K/V heads than Q
     heads: the index maps share one K/V lane block among a group of query
     heads, so a lane block has to be one head (D 128 or 256; at D 64
-    `flash_attention` repeats K and V first). A `window` is a whole number
+    `flash_attention` repeats K and V first, or, in its pair form, hands the
+    rules each PAIR of heads as one head of 128 lanes: `_pair_view`). A
+    `window` is a whole number
     of 128-row tiles, so the lower diagonal enters a block at a tile's edge
     as the upper one does (any block size divides into them)."""
     Tq, H, D = q.shape[1:]
@@ -172,6 +197,19 @@ def _v5e_block_sizes(Tq: int, Tk: int, dtype=None) -> FlashBlocks:
 # other head's lanes zeroed is that head's contraction (at the price a
 # 64-wide contraction has on a 128-wide MXU anyway), and P V over all W value
 # lanes followed by a lane select is that head's output.
+#
+# The PAIR form (`pair`, heads of 64; differential attention's): the two heads
+# of a lane block are a pair that shares ONE value of 128 lanes, the block of
+# V as it stands (`[v_1 | v_2]`: heads 2p and 2p + 1 are neighbours in the
+# packed projection). The scores are the same; what differs is behind the
+# softmax: head j's P V over all W lanes IS head j's output, kept whole (an
+# accumulator and an output a head, no lane select), and the backward
+# contracts dO_j, the block's width, with the unmasked V and sums the two
+# heads' P^T dO into dV. A 64-wide value costs the 128-wide MXU what a
+# 128-wide one costs, so one launch in this form does what four launches over
+# the even and the odd heads did (PERF.md section 6, PR 58: 31.5 -> 16.4 ms
+# forward + backward a layer at T 8192). A K/V pair serves its group's query
+# pairs through the index map, as a K/V head of 128 lanes serves its group.
 #
 # Masks: `causal` (column <= row) and, with it, `window` W (column > row - W):
 # two diagonals W apart. A (q block, k block) pair wholly outside the band
@@ -347,18 +385,22 @@ def _exact_in_bf16(scale: float) -> bool:
     return math.frexp(scale)[0] == 0.5
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal,
-                window):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window,
+                pair=False):
     # grid (B, q blocks, lane blocks, k blocks): the statistics block of a
     # (batch, q block) stays in VMEM while the lane blocks take their turns.
     # The running max and sum of a head are kept across 128 lanes, every lane
-    # the row's value: what the VPU broadcasts for nothing
-    *lse_ref, q_sc, m_sc, l_sc, acc_sc = rest   # no statistics: no lse_ref
+    # the row's value: what the VPU broadcasts for nothing.
+    # `pair`: the block's value is whole (`[v_1 | v_2]`), so head j's P V over
+    # all W lanes is head j's output: an accumulator and an output a head
+    # (acc_sc [heads, bq, W]) where two plain heads share one by their lanes
     bq, W = q_ref.shape[1:]
     bk = k_ref.shape[1]
     qi, hb, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     masks = _head_lanes(W, D)
     heads = range(len(masks))
+    n = W // D if pair else 1       # outputs; no statistics: no lse_ref
+    o_refs, (*lse_ref, q_sc, m_sc, l_sc, acc_sc) = rest[:n], rest[n:]
     head0 = hb * len(masks)
     fold = _exact_in_bf16(scale)
 
@@ -395,8 +437,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal,
             alphas.append(_across(alpha, W))
             pvs.append(jnp.dot(p.astype(v.dtype), v,
                                preferred_element_type=jnp.float32))
-        acc_sc[rows, :] = (_merge(alphas, masks) * acc_sc[rows, :]
-                           + _merge(pvs, masks))
+            if pair:
+                acc_sc[j, rows, :] = alphas[j] * acc_sc[j, rows, :] + pvs[j]
+        if not pair:
+            acc_sc[rows, :] = (_merge(alphas, masks) * acc_sc[rows, :]
+                               + _merge(pvs, masks))
 
     # two heads a lane block: a crossed block's long strip runs down the rows
     # (0.574 -> 0.562 ms forward, 0.893 -> 0.884 backward at gpt2-small's
@@ -406,8 +451,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal,
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
-        l = _merge([_across(l_sc[j], W) for j in heads], masks)
-        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        if pair:
+            for j, o_ref in enumerate(o_refs):
+                o_ref[0] = (acc_sc[j] / _across(l_sc[j], W)).astype(
+                    o_ref.dtype)
+        else:
+            o_ref, = o_refs
+            l = _merge([_across(l_sc[j], W) for j in heads], masks)
+            o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
         for ref in lse_ref:
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
             stats = jnp.where(head0 % _LANES == 0, 0.0, ref[0])
@@ -417,25 +468,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, D, scale, causal,
             ref[0] = stats
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
-                D, scale, causal, window, want):
+def _bwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window, want,
+                pair=False):
     """`want` "dkv": grid (B, lane blocks, k blocks, q blocks), dK and dV
     summed over the q blocks; "dq": grid (.., q blocks, k blocks), dQ summed
     over the k blocks; "all": the dkv grid, and dQ summed for the whole
     sequence in a VMEM accumulator beside it, so the scores are recomputed
-    once."""
+    once. `pair`: an O and a dO a head, each the block's width, and V read
+    whole: dP_j = dO_j V^T and sum(O_j dO_j) over all W lanes, dV the sum of
+    the heads' P_j^T dO_j (no V scratch, no lane select); dK and dQ are head
+    j's in head j's lanes either way."""
     bq, W = q_ref.shape[1:]
     bk = k_ref.shape[1]
     hb = pl.program_id(1)
+    n = W // D if pair else 1
+    o_refs, do_refs, lse_ref, rest = (rest[:n], rest[n:2 * n], rest[2 * n],
+                                      rest[2 * n + 1:])
     if want == "dq":
         dq_ref, dq_sc = rest
         qi, ki = pl.program_id(2), pl.program_id(3)
     else:
         ki, qi = pl.program_id(2), pl.program_id(3)
+        dk_ref, dv_ref, *rest = rest
         if want == "all":
-            dk_ref, dv_ref, dq_ref, k_sc, v_sc, dk_sc, dv_sc, dq_sc = rest
-        else:
-            dk_ref, dv_ref, k_sc, v_sc, dk_sc, dv_sc = rest
+            dq_ref, *rest, dq_sc = rest
+        k_sc, *v_sc, dk_sc, dv_sc = rest
+        v_sc = v_sc[0] if v_sc else None      # a pair reads V whole
     nq = pl.num_programs(2 if want == "dq" else 3)
     nk = pl.num_programs(3 if want == "dq" else 2)
     masks = _head_lanes(W, D)
@@ -446,12 +504,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
     rows = pl.ds(pl.multiple_of(qi * bq, bq), bq) if want == "all" \
         else slice(None)
 
-    def alone(j, cols=slice(None)):
-        """Head j's K (scaled where that is exact) and V, alone in their
-        lanes: the contractions with Q and dO then see that head only."""
+    def k_alone(j, cols=slice(None)):
+        """Head j's K alone in its lanes (scaled where that is exact): the
+        contraction with Q then sees that head only."""
         kj = _only(k_ref[0, cols, :], masks[j])
-        return ((_scaled(kj, scale) if fold else kj),
-                _only(v_ref[0, cols, :], masks[j]))
+        return _scaled(kj, scale) if fold else kj
+
+    def v_alone(j, cols=slice(None)):
+        """Head j's V alone in its lanes, for the contraction with dO (a
+        pair's dO_j meets V whole)."""
+        return _only(v_ref[0, cols, :], masks[j])
 
     if want != "dq":
         @pl.when(qi == 0)
@@ -459,7 +521,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
             dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
             dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
             for j in heads:      # once a k block: the q blocks are inside
-                k_sc[j], v_sc[j] = alone(j)
+                if pair:
+                    k_sc[j] = k_alone(j)
+                else:
+                    k_sc[j], v_sc[j] = k_alone(j), v_alone(j)
     if want != "dkv":
         @pl.when(ki == 0)
         def _():
@@ -467,19 +532,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
     def step(r0, rn, c0, cn, diag):
         local = _span(r0, rn, bq)
-        q, do = q_ref[0, local, :], do_ref[0, local, :]
+        q, dos = q_ref[0, local, :], [ref[0, local, :] for ref in do_refs]
         stats = lse_ref[0, local, :]
-        o_do = o_ref[0, local, :].astype(jnp.float32) * do.astype(jnp.float32)
+        o_dos = [ref[0, local, :].astype(jnp.float32) * do.astype(jnp.float32)
+                 for ref, do in zip(o_refs, dos)]
         # the rows of dQ's accumulator these add to
         into = (pl.ds(pl.multiple_of(qi * bq + r0, rn), rn) if want == "all"
                 else local)
         cols = _span(c0, cn, bk)
         keep = (_causal_keep(rn, cn, qi * bq + r0, ki * bk + c0, window)
                 if diag else None)
+        v_whole = v_ref[0, cols, :] if pair else None
         dks, dvs, dqs = [], [], []
         for j in heads:
-            kj, vj = (alone(j, cols) if want == "dq"
-                      else (k_sc[j, cols, :], v_sc[j, cols, :]))
+            do = dos[j if pair else 0]
+            kj = k_alone(j, cols) if want == "dq" else k_sc[j, cols, :]
+            if pair:
+                vj = v_whole
+            elif want == "dq":
+                vj = v_alone(j, cols)
+            else:
+                vj = v_sc[j, cols, :]
             s = jax.lax.dot_general(q, kj, _NT,
                                     preferred_element_type=jnp.float32)
             if not fold:
@@ -489,7 +562,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
             p = jnp.exp(s - _stat_lane(stats, head0 + j))
             dp = jax.lax.dot_general(do, vj, _NT,
                                      preferred_element_type=jnp.float32)
-            delta = jnp.sum(_only(o_do, masks[j]), axis=1, keepdims=True)
+            delta = jnp.sum(o_dos[j] if pair else _only(o_dos[0], masks[j]),
+                            axis=1, keepdims=True)
             ds = p * (dp - delta)
             if not fold:
                 ds = ds * scale
@@ -505,7 +579,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
                                    preferred_element_type=jnp.float32))
         if want != "dq":
             dk_sc[cols, :] += _merge(dks, masks)
-            dv_sc[cols, :] += _merge(dvs, masks)
+            dv_sc[cols, :] += sum(dvs[1:], dvs[0]) if pair \
+                else _merge(dvs, masks)
         if want != "dkv":
             dq_sc[into, :] += _merge(dqs, masks)
 
@@ -538,8 +613,9 @@ def _geometry(q, k, heads):
 def _kv_lane(q, k):
     """Index-map helper: the K/V lane block a query lane block reads. K and
     V packed narrower than Q hold fewer heads (grouped-query attention, one
-    head a lane block: `_shapes_flash_ok`): a group of E_q / E_kv
-    consecutive query heads shares each."""
+    head a lane block: `_shapes_flash_ok`; or one PAIR of heads of 64 a lane
+    block, in the pair form): a group of E_q / E_kv consecutive query heads
+    (or pairs) shares each."""
     group = q.shape[2] // k.shape[2]
     return (lambda hb: hb) if group == 1 else (lambda hb: hb // group)
 
@@ -593,18 +669,23 @@ def _params(*semantics):
 # them) comes in as a static argument, so a cached trace never hides it.
 @functools.partial(jax.jit,
                    static_argnames=("heads", "causal", "blocks", "statistics",
-                                    "window"))
+                                    "window", "pair"))
 def _packed_forward(q, k, v, *, heads: int, causal: bool,
-                    blocks: FlashBlocks, statistics: bool, window: int = 0):
-    """[out [B, Tq, E]] and, with `statistics`, the log-sum-exp the backward
-    reads: [B, Tq, 128 x ceil(heads / 128)] float32, head h in lane h."""
+                    blocks: FlashBlocks, statistics: bool, window: int = 0,
+                    pair: bool = False):
+    """[out [B, Tq, E]] (`pair`: the first heads' and the second heads'
+    outputs, each [B, Tq, E]) and, with `statistics`, the log-sum-exp the
+    backward reads: [B, Tq, 128 x ceil(heads / 128)] float32, head h in lane
+    h."""
     B, Tq, Tk, E, D, W = _geometry(q, k, heads)
     bq, bk = blocks
     hpb = W // D
+    outs = hpb if pair else 1
     kmap = _k_range(causal, window, bq, bk)
     klane = _kv_lane(q, k)
-    out_specs = [pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb))]
-    out_shape = [jax.ShapeDtypeStruct((B, Tq, E), q.dtype)]
+    out_specs = [pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb))
+                 ] * outs
+    out_shape = [jax.ShapeDtypeStruct((B, Tq, E), q.dtype)] * outs
     if statistics:
         out_specs.append(pl.BlockSpec(
             (1, bq, _LANES),
@@ -613,7 +694,8 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
             (B, Tq, _LANES * pl.cdiv(heads, _LANES)), jnp.float32))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, D=D, scale=1.0 / math.sqrt(D),
-                          causal=causal, window=window),
+                          causal=causal, window=window,
+                          pair=pair),
         grid=(B, Tq // bq, E // W, Tk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb)),
@@ -626,7 +708,8 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
         scratch_shapes=[pltpu.VMEM((hpb, bq, W), q.dtype),
                         pltpu.VMEM((hpb, bq, _LANES), jnp.float32),
                         pltpu.VMEM((hpb, bq, _LANES), jnp.float32),
-                        pltpu.VMEM((bq, W), jnp.float32)],
+                        pltpu.VMEM((hpb, bq, W) if pair else (bq, W),
+                                   jnp.float32)],
         compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         name="flash_attention_fwd",
@@ -635,21 +718,26 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
 
 @functools.partial(jax.jit,
                    static_argnames=("heads", "causal", "blocks", "fused",
-                                    "window"))
+                                    "window", "pair"))
 def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
-                     blocks: FlashBlocks, fused: bool, window: int = 0):
+                     blocks: FlashBlocks, fused: bool, window: int = 0,
+                     pair: bool = False):
     """(dq, dk, dv). `fused`: one pass with dQ's accumulator for the whole
     sequence in VMEM; else dK / dV and dQ in a pass each. Where a group of
-    query heads shares a K/V head, the kernels write each query head's dK
-    and dV (float32, [B, Tk, E_q]) and one reduction after them sums the
-    group."""
+    query lane blocks shares a K/V lane block (a head of 128 lanes or more,
+    or a `pair`), the kernels write each query block's dK and dV (float32,
+    [B, Tk, E_q]) and one reduction after them sums the group. `pair`: `o`
+    and `do` are the two outputs' (first, second)."""
     B, Tq, Tk, E, D, W = _geometry(q, k, heads)
     bq, bk = blocks
     hpb = W // D
     group = E // k.shape[2]
     klane = _kv_lane(q, k)
     kernel = functools.partial(_bwd_kernel, D=D, scale=1.0 / math.sqrt(D),
-                               causal=causal, window=window)
+                               causal=causal, window=window,
+                               pair=pair)
+    o, do = (tuple(o), tuple(do)) if pair else ((o,), (do,))
+    operands = (q, k, v) + o + do + (lse,)
 
     def specs(qrow, krow):
         """In specs of (q, k, v, o, do, lse) given the index maps' q and k
@@ -660,7 +748,8 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
         ks = pl.BlockSpec((1, bk, W), at(krow, lane=klane))
         stat = pl.BlockSpec((1, bq, _LANES),
                             at(qrow, lane=lambda hb: hb * hpb // _LANES))
-        return [qs, ks, ks, qs, qs, stat], qs, pl.BlockSpec((1, bk, W), at(krow))
+        return ([qs, ks, ks] + [qs] * (len(o) + len(do)) + [stat], qs,
+                pl.BlockSpec((1, bk, W), at(krow)))
 
     # dK and dV (and, fused, dQ): k blocks outside, q blocks inside
     qmap = _q_range(causal, window, bq, bk, Tq)
@@ -670,10 +759,10 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
               jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if group > 1:
         shapes = [jax.ShapeDtypeStruct((B, Tk, E), jnp.float32)] * 2
-    scratch = [pltpu.VMEM((hpb, bk, W), k.dtype),
-               pltpu.VMEM((hpb, bk, W), v.dtype),
-               pltpu.VMEM((bk, W), jnp.float32),
-               pltpu.VMEM((bk, W), jnp.float32)]
+    # each head's K alone in its lanes, and V (a pair reads V whole); dK, dV
+    scratch = ([pltpu.VMEM((hpb, bk, W), k.dtype)]
+               + ([] if pair else [pltpu.VMEM((hpb, bk, W), v.dtype)])
+               + [pltpu.VMEM((bk, W), jnp.float32)] * 2)
     if fused:
         outs.append(pl.BlockSpec((1, Tq, W), lambda b, hb, ki, qi: (b, 0, hb)))
         shapes.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
@@ -686,10 +775,10 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
         compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         name="flash_attention_bwd" if fused else "flash_attention_bwd_dkv",
-    )(q, k, v, o, do, lse)
+    )(*operands)
     dk, dv = got[:2]
     if group > 1:
-        dk, dv = (a.reshape(B, Tk, E // (group * D), group, D).sum(3)
+        dk, dv = (a.reshape(B, Tk, E // (group * W), group, W).sum(3)
                   .reshape(k.shape).astype(k.dtype) for a in (dk, dv))
     if fused:
         return got[2], dk, dv
@@ -704,35 +793,42 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary"),
         name="flash_attention_bwd_dq",
-    )(q, k, v, o, do, lse)
+    )(*operands)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _packed_attention(q, k, v, heads: int, causal: bool, window: int = 0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _packed_attention(q, k, v, heads: int, causal: bool, window: int = 0,
+                      pair: bool = False):
     """The fused kernels over packed Q [B, T, E] and K, V [B, T, E_kv] (E_kv
     < E: fewer K/V heads, shared by groups of query heads): no dispatch gate.
     Not differentiated, the forward writes no statistics: this is what an
     inference program launches. A training step launches `_packed_attention_fwd`
     instead, once (`Executor` traces the forward ops once, under
-    differentiation), so no program holds the two side by side any more."""
-    return _packed_forward(
+    differentiation), so no program holds the two side by side any more.
+    `pair` (heads of 64): heads 2p and 2p + 1 are a PAIR that shares the value
+    of lane block p, `[v_1 | v_2]`, whole; two outputs, (the first heads', the
+    second heads'), each [B, T, E]: lane block p of output j is A_j [v_1 |
+    v_2]. K/V pair g serves the query pairs of its group."""
+    out = _packed_forward(
         q, k, v, heads=heads, causal=causal, statistics=False, window=window,
-        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))[0]
+        pair=pair, blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
+    return tuple(out) if pair else out[0]
 
 
-def _packed_attention_fwd(q, k, v, heads, causal, window=0):
-    o, lse = _packed_forward(
+def _packed_attention_fwd(q, k, v, heads, causal, window=0, pair=False):
+    *o, lse = _packed_forward(
         q, k, v, heads=heads, causal=causal, statistics=True, window=window,
-        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
+        pair=pair, blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
+    o = tuple(o) if pair else o[0]
     return o, (q, k, v, o, lse)
 
 
-def _packed_attention_bwd(heads, causal, window, saved, do):
+def _packed_attention_bwd(heads, causal, window, pair, saved, do):
     q, k, v, o, lse = saved
     return _packed_backward(
         q, k, v, o, lse, do, heads=heads, causal=causal, window=window,
-        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
+        pair=pair, blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
         fused=q.shape[1] * max(_LANES, q.shape[2] // heads)
         <= _FUSED_BWD_MAX_ELEMENTS)
 
@@ -740,14 +836,18 @@ def _packed_attention_bwd(heads, causal, window, saved, do):
 _packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)
 
 
-def _flash_kernel(q, k, v, causal: bool, window: int = 0):
+def _flash_kernel(q, k, v, causal: bool, window: int = 0, pair: bool = False):
     """Direct fused-kernel call over [B, T, H, D], no dispatch gate
     (benchmarks, the sweep tool and the eligible path all come through here):
-    merging H and D is a free reshape to the packed layout."""
+    merging H and D is a free reshape to the packed layout. `pair`: two
+    outputs, each [B, T, H / 2, 2 D] (`_packed_attention`)."""
     B, Tq, H, D = q.shape
     pack = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # noqa: E731
-    return _packed_attention(pack(q), pack(k), pack(v), H, causal,
-                             window).reshape(B, Tq, H, D)
+    out = _packed_attention(pack(q), pack(k), pack(v), H, causal, window,
+                            pair)
+    if pair:
+        return tuple(o.reshape(B, Tq, H // 2, 2 * D) for o in out)
+    return out.reshape(B, Tq, H, D)
 
 
 _DISPATCH_COUNTER = "pt_flash_attention_dispatch_total"
@@ -812,7 +912,8 @@ def _count_dispatch(path: str, q, k, causal, window) -> None:
     reg.add_collector(_pairs_family)
 
 
-def flash_attention(q, k, v, causal: bool = False, window: int = 0):
+def flash_attention(q, k, v, causal: bool = False, window: int = 0,
+                    pair: bool = False):
     """[B, T, H, D] attention; K and V may be [B, T, KV, D] with KV dividing
     H (query head j reads K/V head j // (H / KV): at D 128 and up the
     kernels share the K/V block, at D 64 K and V are repeated to H heads
@@ -828,7 +929,14 @@ def flash_attention(q, k, v, causal: bool = False, window: int = 0):
     traced and counted in `pt_flash_attention_dispatch_total{path}` (`xla`,
     `packed`, or `packed_window` for the kernels with a window bound), with
     the score pairs that path computes and the mask keeps beside it
-    (`pt_flash_attention_pairs{path,pairs}`)."""
+    (`pt_flash_attention_pairs{path,pairs}`).
+    `pair`: the PAIR form (`paired_attention`): heads 2p and 2p + 1 share the
+    value `[v_2p | v_2p+1]`, query pair p reads K/V pair p // (H / KV), and
+    two arrays come back, (A_first v, A_second v), each [B, T, H / 2, 2 D].
+    One launch, each softmax computed once: at D 64 a pair is one lane block
+    of Q, of K and of V as the projection packs them, so the gate sees a pair
+    as one head of 128 lanes (K/V blocks shared through the index map, none
+    repeated); any other D goes to the XLA formulation."""
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got {q.shape}")
     window = int(window or 0)
@@ -840,10 +948,19 @@ def flash_attention(q, k, v, causal: bool = False, window: int = 0):
     if q.shape[2] % k.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads do not share "
                          f"{k.shape[2]} K/V heads evenly")
+    if pair and (q.shape[2] % 2 or k.shape[2] % 2):
+        raise ValueError(f"{q.shape[2]} query and {k.shape[2]} K/V heads do "
+                         f"not form pairs")
     from . import mesh_dispatch
 
-    if q.shape[3] < _LANES:     # two heads a lane block: no K/V block to share
-        k, v = _repeat_kv(q, k, v)
+    if pair:
+        seen = tuple(jax.eval_shape(_pair_view, x) for x in (q, k))
+        kernels_take = 2 * q.shape[3] == _LANES
+        reference = paired_attention
+    else:
+        if q.shape[3] < _LANES:  # two heads a block: no K/V block to share
+            k, v = _repeat_kv(q, k, v)
+        seen, kernels_take, reference = (q, k), True, _reference
 
     am = mesh_dispatch.current()
     # mesh policy (ops/mesh_dispatch.py): a bare pallas_call cannot be
@@ -853,17 +970,19 @@ def flash_attention(q, k, v, causal: bool = False, window: int = 0):
     # wrap is a future multi-chip lever. A batch dp does not divide falls
     # back to the XLA formulation, which GSPMD partitions natively.
     sharded = am is not None and am.dp > 1
-    if not flash_eligible(q, k, window) or (sharded and q.shape[0] % am.dp):
+    if not (kernels_take and flash_eligible(*seen, window)) or (
+            sharded and q.shape[0] % am.dp):
         _count_dispatch("xla", q, k, causal, window)
-        return _reference(q, k, v, causal, window)
+        return reference(q, k, v, causal, window)
     _count_dispatch("packed_window" if window else "packed", q, k, causal,
                     window)
+    call = functools.partial(_flash_kernel, causal=causal, window=window,
+                             pair=pair)
     if sharded:
         call = mesh_dispatch.shard_batch(
-            functools.partial(_flash_kernel, causal=causal, window=window),
-            (0, 0, 0), ((0, 4),))
-        return call(q, k, v)
-    return _flash_kernel(q, k, v, causal, window)
+            call, (0, 0, 0), ((0, 4),) * (2 if pair else 1),
+            jax.tree.structure((0, 0)) if pair else None)
+    return call(q, k, v)
 
 
 @register_op("flash_attention")
@@ -875,7 +994,13 @@ def flash_attention_kernel(ctx):
     query heads. Attr `window` (absent or 0: none, the op as it always was):
     with `causal`, position i reads the keys i - window < j <= i. Used by
     layers.multi_head_attention (models/transformer.py; models/afmoe.py:
-    window and global layers in one model) and layers.latent_attention."""
+    window and global layers in one model) and layers.latent_attention.
+    Attr `head_pairs` (absent: plain heads), which
+    layers.differential_attention sets as it sets `window` (nothing in Q's, K's
+    or V's shape tells a pair from two heads): heads 2p and 2p + 1 are a pair
+    over one value of 2 D lanes, ONE launch a layer, and the op has two
+    outputs, Out = A_first [v_1 | v_2] and Out2 = A_second [v_1 | v_2], each
+    [B, T, E] (`flash_attention(pair=True)`)."""
     from .. import amp
 
     # under amp Q and K may arrive float32 (from rms_norm / rotary, which
@@ -889,9 +1014,11 @@ def flash_attention_kernel(ctx):
         raise ValueError(f"hidden dim {E} not divisible by heads {heads}")
     D = E // heads
     split = lambda x: x.reshape(B, x.shape[1], -1, D)  # noqa: E731
-    o = flash_attention(split(q), split(k), split(v), causal=causal,
-                        window=ctx.attr("window", 0))
-    ctx.set_output("Out", o.reshape(B, T, E))
+    pair = bool(ctx.attr("head_pairs", False))
+    out = flash_attention(split(q), split(k), split(v), causal=causal,
+                          window=ctx.attr("window", 0), pair=pair)
+    for slot, o in zip(("Out", "Out2"), out if pair else (out,)):
+        ctx.set_output(slot, o.reshape(B, T, E))
 
 
 _LATENT_COUNTER = "pt_latent_attention_dispatch_total"
@@ -938,28 +1065,12 @@ def latent_kv_expand_kernel(ctx):
 
 # ---- differential attention (Ye et al. 2024) around the kernels -----------
 # A pair of heads (2p, 2p + 1) is two softmaxes over one value twice a head
-# wide: o_p = (A_1 - lam A_2) [v_1 | v_2]. The kernels take one width for Q,
-# K and V, so `layers.differential_attention` launches them four times a
-# layer, on the even and odd heads apart (`split_head_pairs`), and
-# `diff_combine` puts the four outputs together.
-def split_head_pairs(x, head_dim: int):
-    """x [B, T, H x D] -> (the even heads, the odd heads), each [B, T, H / 2
-    x D] in x's order: the first and the second head of every pair."""
-    B, T, E = x.shape
-    pairs = x.reshape(B, T, E // (2 * head_dim), 2, head_dim)
-    return (pairs[:, :, :, 0].reshape(B, T, E // 2),
-            pairs[:, :, :, 1].reshape(B, T, E // 2))
-
-
-@register_op("split_head_pairs")
-def split_head_pairs_kernel(ctx):
-    """X [B, T, H x D] -> First, Second (attr head_dim): the heads 0, 2, 4..
-    and 1, 3, 5.. of a packed projection."""
-    first, second = split_head_pairs(ctx.input("X"), int(ctx.attr("head_dim")))
-    ctx.set_output("First", first)
-    ctx.set_output("Second", second)
-
-
+# wide: o_p = (A_1 - lam A_2) [v_1 | v_2]. At heads of 64 that pair IS a lane
+# block of the packed projections (`[q_1 | q_2]`, `[k_1 | k_2]`, `[v_1 |
+# v_2]`), so `layers.differential_attention` launches the kernels ONCE a
+# layer in their pair form (`flash_attention(pair=True)`): each softmax
+# computed once, its P V over the block's 128 value lanes kept whole, and
+# `diff_combine` takes the two outputs.
 def diff_lambda(lq1, lk1, lq2, lk2, lam_init: float):
     """exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init, float32."""
     f = lambda a, b: jnp.exp(jnp.sum(  # noqa: E731
@@ -967,31 +1078,32 @@ def diff_lambda(lq1, lk1, lq2, lk2, lam_init: float):
     return f(lq1, lk1) - f(lq2, lk2) + lam_init
 
 
-def diff_combine(a11, a12, a21, a22, lq1, lk1, lq2, lk2, norm_w, *,
+def diff_combine(first, second, lq1, lk1, lq2, lk2, norm_w, *,
                  head_dim: int, lam_init: float, eps: float):
-    """The pair arithmetic behind the kernels. a11 = A_1 v_1, a12 = A_1 v_2,
-    a21 = A_2 v_1, a22 = A_2 v_2, each [B, T, P x D] (a pair's head p of D
-    lanes); the four vectors [D] give lam (`diff_lambda`); norm_w [2 D].
+    """The pair arithmetic behind the kernels. first = A_1 [v_1 | v_2], second
+    = A_2 [v_1 | v_2], each [B, T, P x 2 D] (pair p in its 2 D lanes: the two
+    outputs of the kernels' pair form); the four vectors [D] give lam
+    (`diff_lambda`); norm_w [2 D].
 
-        o_p = [a11_p | a12_p] - lam [a21_p | a22_p]               (2 D lanes)
+        o_p = first_p - lam second_p                              (2 D lanes)
         out_p = o_p rsqrt(mean(o_p^2) + eps) norm_w (1 - lam_init)
 
-    -> [B, T, P x 2 D] in a11's dtype, float32 inside. One checkpoint: the
-    backward keeps the four operands and forms the float32 arrays again."""
+    -> [B, T, P x 2 D] in first's dtype, float32 inside. One checkpoint: the
+    backward keeps the two operands and forms the float32 arrays again."""
     D = int(head_dim)
 
     @jax.checkpoint
-    def combine(a11, a12, a21, a22, lq1, lk1, lq2, lk2, norm_w):
-        B, T, E = a11.shape
-        heads = lambda a: a.astype(jnp.float32).reshape(B, T, E // D, D)  # noqa: E731
+    def combine(first, second, lq1, lk1, lq2, lk2, norm_w):
+        B, T, E = first.shape
+        pairs = lambda a: a.astype(jnp.float32).reshape(  # noqa: E731
+            B, T, E // (2 * D), 2 * D)
         lam = diff_lambda(lq1, lk1, lq2, lk2, lam_init)
-        o = jnp.concatenate([heads(a11) - lam * heads(a21),
-                             heads(a12) - lam * heads(a22)], axis=-1)
+        o = pairs(first) - lam * pairs(second)
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
         o = o * (norm_w.astype(jnp.float32) * (1.0 - lam_init))
-        return o.reshape(B, T, 2 * E).astype(a11.dtype)
+        return o.reshape(B, T, E).astype(first.dtype)
 
-    return combine(a11, a12, a21, a22, lq1, lk1, lq2, lk2, norm_w)
+    return combine(first, second, lq1, lk1, lq2, lk2, norm_w)
 
 
 _launches: dict = {}      # a combine op's name in its Program -> its launches
@@ -999,22 +1111,22 @@ _launches: dict = {}      # a combine op's name in its Program -> its launches
 
 @register_op("diff_combine")
 def diff_combine_kernel(ctx):
-    """Program-IR face of `diff_combine`: A11, A12, A21, A22, LamQ1, LamK1,
-    LamQ2, LamK2, NormW -> Out (attrs head_dim, lam_init, epsilon, and
-    `launches`: the `flash_attention` ops the layer appended for this
-    combine, summed over the combines traced into the gauge
+    """Program-IR face of `diff_combine`: First, Second, LamQ1, LamK1, LamQ2,
+    LamK2, NormW -> Out (attrs head_dim, lam_init, epsilon, and `launches`:
+    the `flash_attention` ops the layer appended for this combine, summed
+    over the combines traced into the gauge
     `pt_diff_attention_launches_total`: forward launches a step)."""
     from ..obs import metrics
 
-    _launches[ctx.op.outputs["Out"][0]] = int(ctx.attr("launches", 4))
+    _launches[ctx.op.outputs["Out"][0]] = int(ctx.attr("launches", 1))
     metrics.registry().gauge(
         "pt_diff_attention_launches_total", lambda: sum(_launches.values()),
         help="flash_attention ops a step behind the differential-attention "
              "layers' pair arithmetic, summed over the combines traced so "
              "far (a combine traced again counted once)")
     ctx.set_output("Out", diff_combine(
-        *(ctx.input(s) for s in ("A11", "A12", "A21", "A22", "LamQ1", "LamK1",
-                                 "LamQ2", "LamK2", "NormW")),
+        *(ctx.input(s) for s in ("First", "Second", "LamQ1", "LamK1", "LamQ2",
+                                 "LamK2", "NormW")),
         head_dim=int(ctx.attr("head_dim")),
         lam_init=float(ctx.attr("lam_init")),
         eps=float(ctx.attr("epsilon", 1e-5))))

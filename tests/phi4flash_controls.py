@@ -44,8 +44,8 @@ def applied(name: str):
         module, attr = flash_ops, "flash_attention"
         right = flash_ops.flash_attention
 
-        def wrong(q, k, v, causal=False, window=0):
-            return right(q, k, v, causal=causal, window=0)
+        def wrong(q, k, v, causal=False, window=0, **kw):
+            return right(q, k, v, causal=causal, window=0, **kw)
     elif name == "bf16_state":
         module, attr = ssm_ops, "_CARRY_DTYPE"
         right, wrong = ssm_ops._CARRY_DTYPE, jnp.bfloat16

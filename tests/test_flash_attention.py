@@ -741,3 +741,187 @@ def test_op_counts_its_dispatch_in_the_built_program():
     after = _dispatch_counts()
     assert after["xla"] - before["xla"] >= 1    # the CPU: XLA's formulation
     assert after["packed"] == before["packed"]
+
+
+# ---------------------------------------------------------------------------
+# The PAIR form: heads 2p and 2p + 1 are one lane block over one value of 128
+# lanes (differential attention's), two outputs a launch. Interpreted, against
+# two dense softmaxes a pair written out here.
+
+def _pair_oracle(q, k, v, H, KV, window):
+    """(A_1 [v_1 | v_2], A_2 [v_1 | v_2]), each [B, T, H x 64], float32."""
+    B, T, _ = q.shape
+    D, group = 64, H // KV
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    qh = f32(q).reshape(B, T, H // 2, 2, D)
+    kh = jnp.repeat(f32(k).reshape(B, T, KV // 2, 2, D), group, axis=2)
+    vv = jnp.repeat(f32(v).reshape(B, T, KV // 2, 2 * D), group, axis=2)
+    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    keep = (ahead >= 0) & ((ahead < window) if window else True)
+    outs = []
+    for j in (0, 1):
+        s = jnp.einsum("bqpd,bkpd->bpqk", qh[:, :, :, j], kh[:, :, :, j]) / 8.0
+        a = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+        outs.append(jnp.einsum("bpqk,bkpe->bqpe", a, vv).reshape(B, T, H * D))
+    return tuple(outs)
+
+
+def _pair_case(T, H, KV, dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    mk = lambda n, t=dtype: jnp.asarray(  # noqa: E731
+        rng.randn(1, T, n * 64) * (0.5 if t == dtype else 1.0), t)
+    return (mk(H), mk(KV), mk(KV)), (mk(H, jnp.float32), mk(H, jnp.float32))
+
+
+def _pair_loss(att, weights):
+    def loss(q, k, v):
+        return sum(jnp.sum(o.astype(jnp.float32) * w)
+                   for o, w in zip(att(q, k, v), weights))
+    return loss
+
+
+PAIR_CASES = [
+    # id, T, heads, K/V heads, window, dtype. T 1024: four (q block, k block)
+    # pairs of 512, two crossed by the diagonal (in strips), one skipped;
+    # under the window 512 the lower diagonal has a block of its own too
+    ("f32_whole_group2", 1024, 4, 2, 0, jnp.float32),
+    ("f32_window512_group1", 1024, 4, 4, 512, jnp.float32),
+    ("bf16_whole_group2", 1024, 8, 4, 0, jnp.bfloat16),
+    ("bf16_window512_group2", 1024, 4, 2, 512, jnp.bfloat16),
+    ("bf16_whole_group1", 512, 2, 2, 0, jnp.bfloat16),
+    # a window narrower than a block: a crossed block whole under the mask
+    ("f32_window128_group2", 512, 4, 2, 128, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("T,H,KV,window,dtype", [c[1:] for c in PAIR_CASES],
+                         ids=[c[0] for c in PAIR_CASES])
+def test_pair_kernels_match_two_dense_softmaxes(T, H, KV, window, dtype):
+    """Both outputs, and dQ, dK and dV through both outputs (the five
+    operands' gradients: each half of dQ and of dK is one head's, dV is the
+    pair's), with K/V pairs read by one and by two query pairs."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops.flash_ops import _packed_attention
+
+    (q, k, v), weights = _pair_case(T, H, KV, dtype)
+    att = lambda *a: _packed_attention(*a, H, True, window, True)  # noqa: E731
+    with pltpu.force_tpu_interpret_mode():
+        outs = att(q, k, v)
+        grads = jax.grad(_pair_loss(att, weights), (0, 1, 2))(q, k, v)
+    oracle = lambda *a: _pair_oracle(*a, H, KV, window)  # noqa: E731
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = oracle(*f32)
+    want_grads = jax.grad(_pair_loss(oracle, weights), (0, 1, 2))(*f32)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert len(outs) == 2
+    for o, w in zip(outs, want):
+        assert o.shape == q.shape and o.dtype == dtype
+        np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(w),
+                                   rtol=tol, atol=tol)
+    for g, x, w in zip(grads, (q, k, v), want_grads):
+        assert g.shape == x.shape and g.dtype == dtype
+        scale = float(jnp.max(jnp.abs(w)))
+        for half in (slice(0, 64), slice(64, 128)):     # a head's lanes
+            lanes = lambda a: np.asarray(a, np.float32).reshape(  # noqa: E731
+                1, T, -1, 128)[..., half] / scale
+            np.testing.assert_allclose(lanes(g), lanes(w), rtol=0, atol=tol)
+
+
+def test_pair_backward_split_matches_fused(monkeypatch):
+    """dQ in a pass of its own (beyond `_FUSED_BWD_MAX_ELEMENTS`) reads the two
+    O and the two dO as the fused pass does."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops import flash_ops
+
+    (q, k, v), weights = _pair_case(512, 4, 2, jnp.float32, seed=3)
+    grad = jax.grad(_pair_loss(lambda *a: flash_ops._packed_attention(
+        *a, 4, True, 256, True), weights), (0, 1, 2))
+    with pltpu.force_tpu_interpret_mode():
+        fused = grad(q, k, v)
+        monkeypatch.setattr(flash_ops, "_FUSED_BWD_MAX_ELEMENTS", 0)
+        split = grad(q, k, v)
+    for a, b in zip(fused, split):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_a_trace_without_pairs_is_the_trace_it_was():
+    """`pair` is a static argument of the launches: left out, or False, the
+    kernels' jaxprs are the same text; True, another."""
+    from paddle_tpu.ops import flash_ops
+
+    q = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
+    blocks = flash_ops.FlashBlocks(512, 512)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda q, k, v: flash_ops._packed_forward(
+            q, k, v, heads=4, causal=True, blocks=blocks, statistics=True,
+            **kw))(q, q, q))
+
+    assert text() == text(pair=False)
+    assert text() != text(pair=True)
+
+
+@pytest.mark.parametrize("H,KV,D,path", [
+    (4, 2, 64, "packed"),      # a pair is a lane block: the kernels
+    (4, 4, 64, "packed"),
+    (4, 2, 128, "xla"),        # a pair of 256 lanes: XLA's two softmaxes
+    (8, 4, 32, "xla"),         # two pairs a lane block
+], ids=["d64_group2", "d64_group1", "d128", "d32"])
+def test_pair_dispatch_takes_heads_of_64_and_leaves_the_rest_to_xla(
+        monkeypatch, H, KV, D, path):
+    """The shape rule of the pair form (D 64 only, pairs x 128 lanes, the
+    sequence rules as ever), and what it refuses computed by the XLA pair
+    form: the same numbers either way."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops import flash_ops
+
+    monkeypatch.setattr(flash_ops.jax, "default_backend", lambda: "tpu")
+    rng = np.random.RandomState(5)
+    mk = lambda n: jnp.asarray(rng.randn(1, 1024, n, D) * 0.5,  # noqa: E731
+                               jnp.float32)
+    q, k, v = mk(H), mk(KV), mk(KV)
+    was = _dispatch_counts()
+    with pltpu.force_tpu_interpret_mode():
+        outs = flash_attention(q, k, v, causal=True, pair=True)
+    now = _dispatch_counts()
+    assert now[path] - was[path] == 1
+    assert sum(now.values()) - sum(was.values()) == 1
+    want = flash_ops.paired_attention(q, k, v, True)
+    for o, w in zip(outs, want):
+        assert o.shape == (1, 1024, H // 2, 2 * D)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(w), rtol=2e-5,
+                                   atol=2e-5)
+    if D == 64:     # the XLA pair form against the oracle written out above
+        pack = lambda x: x.reshape(1, 1024, -1)  # noqa: E731
+        for o, w in zip(want, _pair_oracle(pack(q), pack(k), pack(v), H, KV,
+                                           0)):
+            np.testing.assert_allclose(np.asarray(pack(o)), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5)
+
+
+def test_pair_form_wants_whole_pairs():
+    q = jnp.zeros((1, 256, 6, 64))
+    with pytest.raises(ValueError, match="pairs"):
+        flash_attention(q, jnp.zeros((1, 256, 3, 64)),
+                        jnp.zeros((1, 256, 3, 64)), causal=True, pair=True)
+    with pytest.raises(ValueError, match="pairs"):
+        flash_attention(q[:, :, :3], q[:, :, :3], q[:, :, :3], causal=True,
+                        pair=True)
+    # unaligned T, an odd number of PAIRS (three lane blocks): the rules see
+    # a pair as one head of 128 lanes
+    from paddle_tpu.ops.flash_ops import _pair_view, _shapes_flash_ok
+
+    ok = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 20, 64), jnp.bfloat16)
+    view = lambda x: jax.eval_shape(_pair_view, x)  # noqa: E731
+    assert view(ok).shape == (1, 8192, 20, 128)
+    assert _shapes_flash_ok(view(ok), view(kv), 512)
+    assert not _shapes_flash_ok(ok, kv)     # plain heads of 64 share no block
+    assert _shapes_flash_ok(view(q), view(q))
+    assert not _shapes_flash_ok(
+        view(jax.ShapeDtypeStruct((1, 1000, 4, 64), jnp.float32)),
+        view(jax.ShapeDtypeStruct((1, 1000, 4, 64), jnp.float32)))

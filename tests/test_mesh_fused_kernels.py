@@ -260,18 +260,21 @@ def test_fused_lstm_dp1_mesh(fused_interpret):
     assert np.isfinite(losses).all() and losses[1] < losses[0], losses
 
 
-def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch):
+@pytest.mark.parametrize("pair", [False, True], ids=["heads", "pairs"])
+def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch, pair):
     """The flash dispatcher wraps its kernel in shard_map under a dp
     mesh (kernel monkeypatched to the jnp reference — the real Mosaic
     kernel is TPU-only): per-shard local shapes, output parity vs
-    unsharded, and gradients flow."""
+    unsharded, and gradients flow. `pair`: the pair form's two outputs
+    leave the wrap sharded as its one does."""
     from paddle_tpu.ops import flash_ops
 
     calls = []
+    reference = flash_ops.paired_attention if pair else flash_ops._reference
 
-    def fake_kernel(q, k, v, causal, window=0):
+    def fake_kernel(q, k, v, causal, window=0, pair=False):
         calls.append(tuple(q.shape))
-        return flash_ops._reference(q, k, v, causal, window)
+        return reference(q, k, v, causal, window)
 
     monkeypatch.setattr(flash_ops, "_flash_kernel", fake_kernel)
     monkeypatch.setattr(flash_ops, "flash_eligible",
@@ -281,16 +284,20 @@ def test_flash_attention_shard_maps_under_dp_mesh(monkeypatch):
     # divide a 128-aligned sequence (what `flash_eligible` admits)
     mk = lambda: jnp.asarray(rng.randn(16, 128, 4, 64) * 0.3, jnp.float32)
     q, k, v = mk(), mk(), mk()
-    ref = flash_ops._reference(q, k, v, True)
-    g_ref = jax.grad(lambda q: jnp.sum(
-        flash_ops._reference(q, k, v, True) ** 2))(q)
+    total = lambda out: sum(  # noqa: E731
+        jnp.sum(o ** 2) for o in jax.tree.leaves(out))
+    ref = reference(q, k, v, True)
+    g_ref = jax.grad(lambda q: total(reference(q, k, v, True)))(q)
     mesh = pp.make_mesh((8,), ("dp",))
+    kw = {"pair": True} if pair else {}
     with mesh_dispatch.active_mesh(mesh, "dp"):
-        out = flash_ops.flash_attention(q, k, v, causal=True)
-        g = jax.grad(lambda q: jnp.sum(
-            flash_ops.flash_attention(q, k, v, causal=True) ** 2))(q)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+        out = flash_ops.flash_attention(q, k, v, causal=True, **kw)
+        g = jax.grad(lambda q: total(
+            flash_ops.flash_attention(q, k, v, causal=True, **kw)))(q)
+    assert jax.tree.structure(out) == jax.tree.structure(ref)
+    for o, r in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                   rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                rtol=1e-4, atol=1e-4)
     assert calls and calls[0][0] == 16 // 8, calls  # per-shard batch

@@ -10,6 +10,7 @@ CPU: attention takes the jnp formulation and the scan its plain form;
 `tests/test_tpu_compile.py` compiles the step for a described v5e.
 """
 
+import contextlib
 import math
 import os
 import re
@@ -257,22 +258,92 @@ def test_differential_attention_refuses_what_it_cannot_mask():
         pt.layers.differential_attention(x, 8, 4, depth=1, window=0)
 
 
-def test_split_head_pairs_and_the_launch_gauge():
+def test_the_launch_gauge_reads_one_launch_a_layer():
+    """A differential-attention layer is ONE `flash_attention` op with two
+    outputs: the gauge reads 1 a layer (3 at SMALL, where the four launches a
+    layer read 12), and the pairs gauge counts each of the 8 heads' softmaxes
+    once (the four launches counted 4 heads four times)."""
     from paddle_tpu.obs import metrics
 
-    x = jnp.arange(2 * 3 * 32, dtype=jnp.float32).reshape(2, 3, 32)
-    first, second = flash_ops.split_head_pairs(x, 4)
-    heads = x.reshape(2, 3, 8, 4)
-    np.testing.assert_array_equal(first.reshape(2, 3, 4, 4), heads[:, :, 0::2])
-    np.testing.assert_array_equal(second.reshape(2, 3, 4, 4),
-                                  heads[:, :, 1::2])
     flash_ops._launches.clear()
-    _build()
+    flash_ops._pairs.clear()
+    main, *_ = _build()
+    ops = main.global_block().ops
+    launches = [op for op in ops if op.type == "flash_attention"]
+    assert len(launches) == 3 == sum(op.type == "diff_combine" for op in ops)
+    for op in launches:
+        assert op.attrs["head_pairs"] and op.attrs["num_heads"] == 8
+        assert sorted(op.outputs) == ["Out", "Out2"]
+        # what `diffattn.device_ms` and the flash rooflines find the launch by
+        assert re.fullmatch(r"phi4\.h\d\.attn\.kernels\.tmp_\d+",
+                            next(n for names in op.outputs.values()
+                                 for n in names))
+    assert not any(op.type == "split_head_pairs" for op in ops)
     exe = pt.Executor()
     exe.run(pt.default_startup_program())
     exe.run(feed=_feed(), fetch_list=[])
-    assert "pt_diff_attention_launches_total 12\n" in \
-        metrics.registry().render()
+    text = metrics.registry().render()
+    assert "pt_diff_attention_launches_total 3\n" in text
+    computed = 3 * B * SMALL["num_attention_heads"] * T * T
+    assert flash_ops._pairs["xla", "computed"] == computed
+    assert ('pt_flash_attention_pairs{pairs="computed",path="xla"} '
+            + str(computed)) in text
+
+
+def _steer_to_the_kernels(monkeypatch):
+    """The dispatcher as the chip would decide (the shape rules alone), the
+    kernels interpreted."""
+    monkeypatch.setattr(
+        flash_ops, "flash_eligible", lambda q, k=None, window=0:
+        flash_ops._shapes_flash_ok(q, q if k is None else k, window))
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["whole", "window128"])
+def test_the_layer_through_the_interpreted_pair_kernels(monkeypatch, window):
+    """The layer's one launch on the kernels' path (heads of 64, T 256: a block
+    the diagonal crosses; two query pairs a K/V pair), forward and every
+    parameter's gradient against the same Program on the XLA pair form."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.obs import metrics
+
+    H, KV, E, T_ = 4, 2, 256, 256
+    r = _rng(17)
+    feed = {"x": r.randn(1, T_, E).astype(np.float32)}
+
+    def run(steer):
+        pt.reset()
+        main, startup = pt.default_main_program(), pt.default_startup_program()
+        main.random_seed = startup.random_seed = 3
+        x = pt.layers.data("x", shape=[T_, E], dtype=np.float32)
+        out = pt.layers.differential_attention(x, H, KV, depth=2,
+                                               window=window, name="da")
+        loss = pt.layers.mean(pt.layers.elementwise_mul(out, out))
+        pairs = append_backward(loss)
+        exe = pt.Executor()
+        exe.run(startup)
+        before = metrics.registry().render()
+        if steer:
+            _steer_to_the_kernels(monkeypatch)
+        with jax.default_matmul_precision("highest"), (
+                pltpu.force_tpu_interpret_mode() if steer
+                else contextlib.nullcontext()):
+            got = exe.run(main, feed=feed,
+                          fetch_list=[out] + [g for _, g in pairs])
+        return [p.name for p, _ in pairs], got, before
+
+    names, want, _ = run(False)
+    _, got, before = run(True)
+    path = "packed_window" if window else "packed"
+    counted = re.search(
+        r'pt_flash_attention_dispatch_total\{path="%s"\} (\d+)' % path,
+        metrics.registry().render())
+    was = re.search(
+        r'pt_flash_attention_dispatch_total\{path="%s"\} (\d+)' % path,
+        before)
+    assert int(counted.group(1)) == (int(was.group(1)) if was else 0) + 1
+    assert _rel(got[0], want[0]) < 1e-5
+    for name, g, w in zip(names, got[1:], want[1:]):
+        assert _rel(g, w) < 2e-4, (name, _rel(g, w))
 
 
 # -------------------------------------------------------- the two layers ---

@@ -174,6 +174,29 @@ def _flash_gqa(case):
     return fwd_bwd, specs
 
 
+def _flash_pair(case):
+    from paddle_tpu.ops import flash_ops
+
+    # a Phi-4-mini-flash differential-attention layer's ONE launch at the
+    # cell's shape: 40 query heads of 64 in 20 pairs over 10 K/V pairs, Q
+    # packed [1, 8192, 2560] and K, V [1, 8192, 1280]. A pair is a lane block
+    # (`[q_1 | q_2]`, `[k_1 | k_2]`, the value `[v_1 | v_2]` whole), two query
+    # pairs read a K/V pair through the index map, two outputs of the block's
+    # width, and the fused backward holds dQ's [8192, 128] float32 beside two
+    # O and two dO blocks
+    window, with_bwd = case
+    specs = [((1, 8192, 40 * 64), BF16)] + [((1, 8192, 20 * 64), BF16)] * 2
+
+    def fwd(q, k, v):
+        return flash_ops._packed_attention(q, k, v, 40, True, window, True)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: sum(o.astype(F32).sum() for o in fwd(*a)),
+                        (0, 1, 2))(q, k, v)
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
 def _short_conv(case):
     from paddle_tpu.ops import short_conv_ops
 
@@ -351,6 +374,10 @@ CASES = [
     # repeated to them), blocks of 1024, the fused backward with dQ's
     # [16384, 128] float32 accumulator in VMEM
     ("flash_fwd_bwd_lfm2_d64_t16384", _flash, ((1, 16384, 2048), 32, True)),
+    ("flash_pair_fwd_phi4_t8192", _flash_pair, (0, False)),
+    ("flash_pair_fwd_bwd_phi4_t8192", _flash_pair, (0, True)),
+    ("flash_pair_fwd_phi4_window512_t8192", _flash_pair, (512, False)),
+    ("flash_pair_fwd_bwd_phi4_window512_t8192", _flash_pair, (512, True)),
     ("short_conv_fwd_lfm2_t16384", _short_conv, ((1, 16384, 2048, 3), False)),
     ("short_conv_fwd_bwd_lfm2_t16384", _short_conv,
      ((1, 16384, 2048, 3), True)),
@@ -926,16 +953,18 @@ def test_phi4_step_program_fits_one_chip(one_chip, compiled_mode,
     697 M parameters: published layers 0, 1, 16, 17, 18, 19 of
     Phi-4-mini-flash-reasoning) compiles for the described v5e with arguments
     + temporaries under 14.5 GiB by the compiler's own books (which settles
-    T 8192 against 4096 before any chip time: ISSUE 57's rule), twelve
-    forward and twelve backward attention launches at 20 heads of 64 (four a
-    layer), each mixer's scan forward twice (once again inside its
-    checkpoint) and backward once, and no array of [T, d_in, N] shape."""
+    T 8192 against 4096 before any chip time: ISSUE 57's rule), three
+    forward and three backward attention launches at 40 heads of 64 in pairs
+    (ONE a layer since PR 58, where four at 20 heads ran), each mixer's scan
+    forward twice (once again inside its checkpoint) and backward once, no
+    array of [T, d_in, N] shape, and neither K nor V repeated to the query
+    heads in front of a kernel. The HBM reading is printed (`-s`)."""
     raw, args = _step_program(
         lambda: _benchmark_model("phi-4-mini-flash-reasoning", 1, 8192), 1,
         8192, one_chip, monkeypatch)
     launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
     assert launches == {
-        "flash_attention_fwd": 12, "flash_attention_bwd": 12,
+        "flash_attention_fwd": 3, "flash_attention_bwd": 3,
         "selective_scan_fwd": 4, "selective_scan_bwd": 2,
         "causal_conv_silu_fwd": 4, "causal_conv_silu_bwd": 2}
     compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
