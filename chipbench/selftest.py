@@ -20,8 +20,10 @@
    made-up snapshot, the wire-format reader on a hand-made `XSpace`;
 5. what a train cell owes (`drivers/train.py:correct`, `compared`, `info`)
    on a hand-made run record: no memory book is compared with anything, a
-   startup that did not repeat fails by name, and `run.py` books the peaks
-   from the driver's reading.
+   startup that did not repeat fails by name, `run.py` books the peaks from
+   the driver's reading, the names a plain program is compared by and the two
+   a program that keeps k of a row's candidates adds, and "the loss fell" on
+   the smallest of the last three cost reads.
 
 `tests/test_harness.py` holds what needs JAX or pytest.
 """
@@ -369,6 +371,27 @@ def a_train_cell_owes_no_memory_to_the_yardstick():
     assert info["peak_final"] == run["memory_peaks"]
     assert "peak_after_reference" not in info
     compared = train.compared(run)
+    # a plain program's names and no other: a routed one adds the second
+    # reading's two and the choice's four, one that keeps k of a row's
+    # candidates the second reading's and `kept_sets_off_rule`,
+    # `kept_turned_not_near_tie`
+    assert list(compared) == [
+        "startup_tensors_differing", "cost_reads_not_finite",
+        "last_cost_over_first", "first_cost_off_reference",
+        "programs_built_in_window", "cache_misses_in_window",
+        "gradient_error_nearest_limit"], list(compared)
+    kept = {"sets_off_rule": 0.0, "turned_not_near_tie": 2.0}
+    assert list(train.compared(dict(run, kept=[kept])))[7:] == [
+        "kept_sets_off_rule", "kept_turned_not_near_tie"]
+    assert any("kept_turned_not_near_tie 2.0" in b
+               for b in train.correct(dict(run, kept=[kept])))
+    # the loss fell: the smallest of the last three reads against the first
+    assert compared["last_cost_over_first"] == [7.5 / 10.8, 1.0]
+    spiked = dict(run, costs=[9.0, 7.5, 8.0, 14.83])
+    assert train.correct(spiked) == []
+    assert train.compared(spiked)["last_cost_over_first"] == [7.5 / 10.8, 1.0]
+    bad = train.correct(dict(run, costs=[9.0, 11.0, 12.0, 14.83]))
+    assert len(bad) == 1 and "the loss did not fall" in bad[0], bad
     assert compared["gradient_error_nearest_limit"] == [0.049, 0.05]
     assert abs(compared["first_cost_off_reference"][0] - 1e-4 / 10.8001) < 1e-12
     bad = train.correct(dict(run, startup_differs=["w"]))
