@@ -1,0 +1,16 @@
+"""keye.head_device_ms: `head.device_ms` on the keye-vl-2.0-30b-a3b cell, under a
+name of its own: device time a step in the head: the rows found by walking back
+from the cost op to the final norm (the head's GEMM over 18 992 columns, the
+cross-entropy, their backward). That reader's manifest entry lists the cells
+that were there, and a `model_config` PR may not edit an entry that is there
+(PERF.md section 7 item 3): this file only loads `head.device_ms.py` by path
+and returns what it returns. A later `benchmark` PR that drops the `workloads`
+lists retires this file."""
+
+from chipbench.readers import load_reader
+
+WRAPS = "head.device_ms"
+
+
+def compute(run):
+    return load_reader(WRAPS).compute(run)

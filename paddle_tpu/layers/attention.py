@@ -21,7 +21,7 @@ from .helper import LayerHelper
 
 __all__ = ["attention_gru_decoder", "attention_gru_beam_search",
            "multi_head_attention", "latent_attention",
-           "differential_attention"]
+           "differential_attention", "sparse_attention"]
 
 
 def _in_front_of_kernel(helper, emit, var):
@@ -57,6 +57,8 @@ def multi_head_attention(
     head_dim: Optional[int] = None,
     window: Optional[int] = None,
     out_gate: bool = False,
+    positions=None,
+    rope_sections=None,
 ):
     """Transformer multi-head attention over dense [B, T, E] inputs
     (self-attention when key/value are None). Beyond the 2017 reference's
@@ -90,6 +92,9 @@ def multi_head_attention(
     (`query`); the kernels' output is multiplied by sigmoid(query W_g)
     before the output projection (`afmoe`'s gated attention): an `fc` with
     a sigmoid and an `elementwise_mul`.
+    positions, rope_sections: the rotary's positions as fed data, int32 [B,
+    A, T], and the frequency pairs an axis turns (`rotary_embedding`'s
+    `positions` and `sections`; only with `rotary_theta`).
     All of these off by default, and then the ops appended are exactly
     those of a layer without them.
     param_attr may be a mapping {"wq" | "wk" | "wv" | "wg" | "wo": attr}
@@ -146,11 +151,15 @@ def multi_head_attention(
         k = _in_front_of_kernel(helper, emit, rms_norm(
             k, epsilon=rms_eps, name=f"{helper.name}.k_norm",
             param_attr=_derive(param_attr, "k_norm").name, group=group))
+    if positions is not None and not rotary_theta:
+        raise ValueError("positions are the rotary's: pass rotary_theta")
     if rotary_theta:
+        fed = {} if positions is None else {
+            "positions": positions, "sections": rope_sections}
         q = _in_front_of_kernel(helper, "kernel", rotary_embedding(
-            q, num_heads, rotary_theta))
+            q, num_heads, rotary_theta, **fed))
         k = _in_front_of_kernel(helper, "kernel", rotary_embedding(
-            k, kv_heads, rotary_theta))
+            k, kv_heads, rotary_theta, **fed))
     out = helper.create_tmp_variable(query.dtype,
                                      tuple(query.shape[:-1]) + (E_q,))
     attrs = {"num_heads": num_heads, "causal": causal}
@@ -379,6 +388,119 @@ def differential_attention(
     out = fc(out, size=E, num_flatten_dims=2, param_attr=_derive("wo"),
              bias_attr=_derive("wo_b"), name=f"{n}.out_proj")
     return (out, (k, v)) if return_kv else out
+
+
+def sparse_attention(
+    query,
+    positions=None,
+    num_heads: int = 32,
+    num_kv_heads: int = 4,
+    head_dim: int = 128,
+    index_heads: int = 16,
+    index_head_dim: int = 64,
+    topk: int = 2048,
+    rope_sections=None,
+    rotary_theta: float = 1e7,
+    rms_eps: float = 1e-6,
+    param_attr=None,
+    name=None,
+):
+    """Causal LEARNED SPARSE self-attention (the DeepSeek-Sparse-Attention
+    form) over a dense [B, T, E] input: grouped-query attention with a
+    per-head QK-norm and a rotary, in which a row attends only the `topk`
+    keys an INDEXER scores highest for it. No biases.
+
+        q = x W_q [T, H, D];  k = x W_k, v = x W_v [T, KV, D]
+        q <- rms_D(q, g_q), k <- rms_D(k, g_k): per head, one scale [D] each
+        q, k <- rotary(theta) at the token's position: `positions` int32
+            [B, A, T] as fed data with `rope_sections` (a three-axis rotary:
+            `rotary_embedding`), or 0..T-1 without
+        q^I = x W^I_q [T, Hi, Di];  k^I = x W^I_k [T, Di];  w = x W^I_w [T, Hi]
+        I(t, s) = sum_j w[t, j] relu(q^I[t, j] . k^I[s])         s <= t
+        S_t = the min(topk, t + 1) keys s <= t of largest I(t, s), the lower
+              index among equals
+        o_j[t] = softmax over s in S_t of (q_j[t] . k_{j // g}[s] / sqrt(D))
+                 applied to v_{j // g}[s];   out = concat_j(o_j) W_o
+
+    The indexer reads the layer's input x as it comes (no norm and no
+    position signal of its own). The sets are discrete and I enters the
+    output nowhere else, so the indexer's three matrices get NO gradient
+    under a cost of the output: they are `trainable=False` (no optimizer
+    state), and a job that trains through this layer continues training with
+    the indexer frozen (the alignment cost by which the published recipe
+    trains it is not built: ROADMAP Queue 2 A).
+    Projections are `fc` ops; the norm and rotary are the launch every
+    attention layer has (`qk_assemble`); then `sparse_keep` (the scores in
+    tiles, the selection, written as one bit a (row, key); its second output
+    `Chosen` int32 [B x T, topk], a row's kept keys by index and -1 behind
+    them, costs a program that does not fetch it nothing) and
+    `sparse_attention` (the packed flash kernels under that keep operand on
+    the chip, the plain form elsewhere: ops/sparse_attention_ops.py). The
+    ops' scopes carry `<name>.qkv`, `<name>.q_norm`, `.k_norm`, `.q_rope`,
+    `.k_rope`, `<name>.indexer` (its three projections; the scores are the
+    inner scope `indexer` of `sparse_keep`), `<name>.select`, `<name>.kernels`,
+    `<name>.out_proj`. param_attr may be a mapping {"wq" | "wk" | "wv" |
+    "wo" | "index_wq" | "index_wk" | "index_ww": attr}. Parameters, in order:
+    wq, wk, wv, the two norms' scales, index_wq, index_wk, index_ww, wo."""
+    from ..ops.sparse_attention_ops import keep_lanes
+    from .nn import fc, rms_norm, rotary_embedding
+
+    helper = LayerHelper("sparse_attention", name=name)
+    n = helper.name
+    E = int(query.shape[-1])
+    H, KV, D = int(num_heads), int(num_kv_heads), int(head_dim)
+    Hi, Di, topk = int(index_heads), int(index_head_dim), int(topk)
+    if H % KV:
+        raise ValueError(f"{H} query heads do not share {KV} K/V heads "
+                         f"evenly")
+    if topk <= 0:
+        raise ValueError(f"topk {topk}: a positive number of keys")
+    B, T = int(query.shape[0]), int(query.shape[1])
+
+    def _derive(s, trainable=True):
+        attr = ParamAttr.derive(param_attr, n, s)
+        if not trainable:
+            attr.trainable = False
+        return attr
+
+    def proj(s, size, scope, trainable=True):
+        return fc(query, size=size, num_flatten_dims=2, bias_attr=False,
+                  param_attr=_derive(s, trainable), name=f"{n}.{scope}")
+
+    q, k, v = proj("wq", H * D, "qkv"), proj("wk", KV * D, "qkv"), \
+        proj("wv", KV * D, "qkv")
+    fed = {} if positions is None else {"positions": positions,
+                                        "sections": rope_sections}
+    turned = []
+    for x, heads, s in ((q, H, "q_norm"), (k, KV, "k_norm")):
+        x = _in_front_of_kernel(helper, "float32", rms_norm(
+            x, epsilon=rms_eps, name=f"{n}.{s}",
+            param_attr=_derive(s).name, group=D))
+        turned.append(_in_front_of_kernel(helper, "kernel", rotary_embedding(
+            x, heads, rotary_theta, name=f"{n}.{s[0]}_rope", **fed)))
+    q, k = turned
+    # the sets are discrete: the indexer gets no gradient, and is frozen
+    index = [proj(s, size, "indexer", trainable=False) for s, size in (
+        ("index_wq", Hi * Di), ("index_wk", Di), ("index_ww", Hi))]
+    select = LayerHelper("sparse_keep", name=f"{n}.select")
+    keep = select.create_tmp_variable(np.int32, (B, T, keep_lanes(T)))
+    chosen = select.create_tmp_variable(
+        np.int32, (B * T if B >= 0 else -1, topk))
+    select.append_op(
+        type="sparse_keep",
+        inputs=dict(zip(("IndexQ", "IndexK", "IndexW"),
+                        ([x] for x in index))),
+        outputs={"Keep": [keep], "Chosen": [chosen]},
+        attrs={"index_heads": Hi, "topk": topk})
+    kernels = LayerHelper("sparse_attention", name=f"{n}.kernels")
+    out = kernels.create_tmp_variable(query.dtype, (B, T, H * D))
+    kernels.append_op(
+        type="sparse_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "Keep": [keep]},
+        outputs={"Out": [out]},
+        attrs={"num_heads": H, "topk": topk})
+    return fc(out, size=E, num_flatten_dims=2, bias_attr=False,
+              param_attr=_derive("wo"), name=f"{n}.out_proj")
 
 
 def _decoder_params(helper, ctx_dim, emb_dim, hidden, att_size):

@@ -545,22 +545,48 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None, group=None):
 
 
 def rotary_embedding(x, num_heads: int, theta: float = 10000.0, name=None,
-                     rotary_dim: Optional[int] = None):
+                     rotary_dim: Optional[int] = None, positions=None,
+                     sections=None):
     """Rotary position embedding (Su et al. 2021, rotate-half convention)
     on a packed multi-head projection [B, T, E]: position t rotates each
     head's (i, i + D/2) lane pair by t * theta^(-2i/D). No parameters.
     rotary_dim R: only the last R lanes of each head turn (pairs (i, i +
     R/2) of those R, frequencies theta^(-2i/R)) and the D - R in front pass
     through: latent attention's `[nope | rope]` head. None: the whole head,
-    and the op appended is the one it always was."""
+    and the op appended is the one it always was.
+    positions: an int32 Variable [B, A, T], the positions as FED DATA, A
+    axes a token (a data layer is batch-major), with `sections`: A counts of
+    frequency pairs that sum to half the turned lanes; pair i turns by the
+    position on the axis whose section holds it (a three-axis rotary's
+    `mrope_section` [16, 24, 24] over temporal, height and width: the
+    Qwen2-VL form, contiguous sections). `sections` None with A = 1: a plain
+    fed position. A token whose axes are equal turns as position t = that
+    value does without the input, bit for bit. None: positions 0..T-1, and
+    the op appended is the one it always was."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_tmp_variable(x.dtype, x.shape)
     attrs = {"num_heads": num_heads, "theta": float(theta)}
     if rotary_dim is not None:
         attrs["rotary_dim"] = int(rotary_dim)
+    inputs = {"X": [x]}
+    if positions is not None:
+        turned = int(x.shape[-1]) // num_heads if rotary_dim is None \
+            else int(rotary_dim)
+        sections = [turned // 2] if sections is None else [
+            int(n) for n in sections]
+        if len(positions.shape) != 3 or int(positions.shape[1]) \
+                != len(sections) or sum(sections) != turned // 2:
+            raise ValueError(
+                f"positions {tuple(positions.shape)} with sections "
+                f"{sections}: [B, {len(sections)}, T] and sections that sum "
+                f"to {turned // 2} pairs")
+        inputs["Positions"] = [positions]
+        attrs["sections"] = sections
+    elif sections is not None:
+        raise ValueError("sections without positions")
     helper.append_op(
         type="rotary_embedding",
-        inputs={"X": [x]},
+        inputs=inputs,
         outputs={"Out": [out]},
         attrs=attrs,
     )

@@ -19,3 +19,4 @@ from .afmoe import afmoe_lm  # noqa: F401
 from .lfm2_moe import lfm2_moe_lm  # noqa: F401
 from .looped import looped_lm  # noqa: F401
 from .phi4flash import phi4flash_layer_kinds, phi4flash_lm  # noqa: F401
+from .keye_vl import keye_lm  # noqa: F401

@@ -19,6 +19,7 @@ from . import generation_ops  # noqa: F401
 from . import math_ops  # noqa: F401
 from . import nn_ops  # noqa: F401
 from . import flash_ops  # noqa: F401
+from . import sparse_attention_ops  # noqa: F401
 from . import fused_conv_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import quant_kernels  # noqa: F401

@@ -385,8 +385,40 @@ def _exact_in_bf16(scale: float) -> bool:
     return math.frexp(scale)[0] == 0.5
 
 
+# The KEEP operand (`keep`, a learned sparse attention's: ops/
+# sparse_attention_ops.py): beside the two diagonals, which are functions of a
+# (row, key)'s indices alone, a kept set is DATA, one bit a (row, key): int32
+# [B, Tq, 128 x ceil(Tk / 4096)], lane block w of a row holding its keys
+# 4096 w .. 4096 w + 4095, key 128 u + c of them in bit u of lane c, so a
+# [rows, 128] block of words opens into thirty-two [rows, 128] key tiles by a
+# shift and a compare, with no relayout. A (row, key) pair is inside the mask
+# where it is inside the band AND its bit is set; a block the diagonals leave
+# bare runs under its bits. Without the operand every trace is what it was.
+KEEP_TILES = 32         # key tiles of 128 lanes a lane block of words holds
+
+
+def _kept_here(words, tile0, cn):
+    """[rows, cn] bool: the bits of the cn / 128 key tiles from `tile0` on
+    (a traced scalar; the tiles lie inside one lane block of words), from
+    `words` [rows, 128] int32."""
+    return jnp.concatenate(
+        [(words >> ((tile0 + u) % KEEP_TILES)) & 1 for u in range(cn // _LANES)],
+        axis=1) != 0
+
+
+def _operand(keep):
+    """The keep operand as the launchers' trailing operands: none, or it."""
+    return () if keep is None else (keep,)
+
+
+def _keep_lane(bk):
+    """Index-map helper: the lane block of the keep operand's words that
+    holds k block `ki`'s bits."""
+    return lambda ki: ki * (bk // _LANES) // KEEP_TILES
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window,
-                pair=False):
+                pair=False, kept=False):
     # grid (B, q blocks, lane blocks, k blocks): the statistics block of a
     # (batch, q block) stays in VMEM while the lane blocks take their turns.
     # The running max and sum of a head are kept across 128 lanes, every lane
@@ -400,6 +432,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window,
     masks = _head_lanes(W, D)
     heads = range(len(masks))
     n = W // D if pair else 1       # outputs; no statistics: no lse_ref
+    if kept:
+        keep_ref, *rest = rest
     o_refs, (*lse_ref, q_sc, m_sc, l_sc, acc_sc) = rest[:n], rest[n:]
     head0 = hb * len(masks)
     fold = _exact_in_bf16(scale)
@@ -419,6 +453,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window,
         k, v = k_ref[0, cols, :], v_ref[0, cols, :]
         keep = (_causal_keep(rn, cn, qi * bq + r0, ki * bk + c0, window)
                 if diag else None)
+        if kept:
+            bits = _kept_here(keep_ref[0, rows, :], (ki * bk + c0) // _LANES,
+                              cn)
+            keep = bits if keep is None else jnp.logical_and(keep, bits)
         alphas, pvs = [], []
         for j in heads:
             s = jax.lax.dot_general(q_sc[j, rows, :], k, _NT,
@@ -469,7 +507,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window, want,
-                pair=False):
+                pair=False, kept=False):
     """`want` "dkv": grid (B, lane blocks, k blocks, q blocks), dK and dV
     summed over the q blocks; "dq": grid (.., q blocks, k blocks), dQ summed
     over the k blocks; "all": the dkv grid, and dQ summed for the whole
@@ -484,6 +522,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window, want,
     n = W // D if pair else 1
     o_refs, do_refs, lse_ref, rest = (rest[:n], rest[n:2 * n], rest[2 * n],
                                       rest[2 * n + 1:])
+    if kept:
+        keep_ref, *rest = rest
     if want == "dq":
         dq_ref, dq_sc = rest
         qi, ki = pl.program_id(2), pl.program_id(3)
@@ -542,6 +582,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *rest, D, scale, causal, window, want,
         cols = _span(c0, cn, bk)
         keep = (_causal_keep(rn, cn, qi * bq + r0, ki * bk + c0, window)
                 if diag else None)
+        if kept:
+            bits = _kept_here(keep_ref[0, local, :], (ki * bk + c0) // _LANES,
+                              cn)
+            keep = bits if keep is None else jnp.logical_and(keep, bits)
         v_whole = v_ref[0, cols, :] if pair else None
         dks, dvs, dqs = [], [], []
         for j in heads:
@@ -670,19 +714,21 @@ def _params(*semantics):
 @functools.partial(jax.jit,
                    static_argnames=("heads", "causal", "blocks", "statistics",
                                     "window", "pair"))
-def _packed_forward(q, k, v, *, heads: int, causal: bool,
+def _packed_forward(q, k, v, keep=None, *, heads: int, causal: bool,
                     blocks: FlashBlocks, statistics: bool, window: int = 0,
                     pair: bool = False):
     """[out [B, Tq, E]] (`pair`: the first heads' and the second heads'
     outputs, each [B, Tq, E]) and, with `statistics`, the log-sum-exp the
     backward reads: [B, Tq, 128 x ceil(heads / 128)] float32, head h in lane
-    h."""
+    h. `keep`: the keep operand (above `_kept_here`), or None."""
     B, Tq, Tk, E, D, W = _geometry(q, k, heads)
     bq, bk = blocks
     hpb = W // D
     outs = hpb if pair else 1
     kmap = _k_range(causal, window, bq, bk)
     klane = _kv_lane(q, k)
+    kept = _operand(keep)
+    wlane = _keep_lane(bk)
     out_specs = [pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb))
                  ] * outs
     out_shape = [jax.ShapeDtypeStruct((B, Tq, E), q.dtype)] * outs
@@ -695,7 +741,7 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
     return pl.pallas_call(
         functools.partial(_fwd_kernel, D=D, scale=1.0 / math.sqrt(D),
                           causal=causal, window=window,
-                          pair=pair),
+                          pair=pair, kept=bool(kept)),
         grid=(B, Tq // bq, E // W, Tk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, W), lambda b, qi, hb, ki: (b, qi, hb)),
@@ -703,7 +749,9 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
                          lambda b, qi, hb, ki: (b, kmap(qi, ki), klane(hb))),
             pl.BlockSpec((1, bk, W),
                          lambda b, qi, hb, ki: (b, kmap(qi, ki), klane(hb))),
-        ],
+        ] + [pl.BlockSpec(
+            (1, bq, _LANES),
+            lambda b, qi, hb, ki: (b, qi, wlane(kmap(qi, ki))))] * len(kept),
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hpb, bq, W), q.dtype),
                         pltpu.VMEM((hpb, bq, _LANES), jnp.float32),
@@ -713,15 +761,15 @@ def _packed_forward(q, k, v, *, heads: int, causal: bool,
         compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         name="flash_attention_fwd",
-    )(q, k, v)
+    )(q, k, v, *kept)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("heads", "causal", "blocks", "fused",
                                     "window", "pair"))
-def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
-                     blocks: FlashBlocks, fused: bool, window: int = 0,
-                     pair: bool = False):
+def _packed_backward(q, k, v, o, lse, do, keep=None, *, heads: int,
+                     causal: bool, blocks: FlashBlocks, fused: bool,
+                     window: int = 0, pair: bool = False):
     """(dq, dk, dv). `fused`: one pass with dQ's accumulator for the whole
     sequence in VMEM; else dK / dV and dQ in a pass each. Where a group of
     query lane blocks shares a K/V lane block (a head of 128 lanes or more,
@@ -733,11 +781,13 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
     hpb = W // D
     group = E // k.shape[2]
     klane = _kv_lane(q, k)
+    kept = _operand(keep)
+    wlane = _keep_lane(bk)
     kernel = functools.partial(_bwd_kernel, D=D, scale=1.0 / math.sqrt(D),
                                causal=causal, window=window,
-                               pair=pair)
+                               pair=pair, kept=bool(kept))
     o, do = (tuple(o), tuple(do)) if pair else ((o,), (do,))
-    operands = (q, k, v) + o + do + (lse,)
+    operands = (q, k, v) + o + do + (lse,) + kept
 
     def specs(qrow, krow):
         """In specs of (q, k, v, o, do, lse) given the index maps' q and k
@@ -748,8 +798,11 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
         ks = pl.BlockSpec((1, bk, W), at(krow, lane=klane))
         stat = pl.BlockSpec((1, bq, _LANES),
                             at(qrow, lane=lambda hb: hb * hpb // _LANES))
-        return ([qs, ks, ks] + [qs] * (len(o) + len(do)) + [stat], qs,
-                pl.BlockSpec((1, bk, W), at(krow)))
+        words = pl.BlockSpec(
+            (1, bq, _LANES),
+            lambda b, hb, i, j: (b, qrow(i, j), wlane(krow(i, j))))
+        return ([qs, ks, ks] + [qs] * (len(o) + len(do)) + [stat]
+                + [words] * len(kept), qs, pl.BlockSpec((1, bk, W), at(krow)))
 
     # dK and dV (and, fused, dQ): k blocks outside, q blocks inside
     qmap = _q_range(causal, window, bq, bk, Tq)
@@ -799,7 +852,7 @@ def _packed_backward(q, k, v, o, lse, do, *, heads: int, causal: bool,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _packed_attention(q, k, v, heads: int, causal: bool, window: int = 0,
-                      pair: bool = False):
+                      pair: bool = False, keep=None):
     """The fused kernels over packed Q [B, T, E] and K, V [B, T, E_kv] (E_kv
     < E: fewer K/V heads, shared by groups of query heads): no dispatch gate.
     Not differentiated, the forward writes no statistics: this is what an
@@ -809,28 +862,34 @@ def _packed_attention(q, k, v, heads: int, causal: bool, window: int = 0,
     `pair` (heads of 64): heads 2p and 2p + 1 are a PAIR that shares the value
     of lane block p, `[v_1 | v_2]`, whole; two outputs, (the first heads', the
     second heads'), each [B, T, E]: lane block p of output j is A_j [v_1 |
-    v_2]. K/V pair g serves the query pairs of its group."""
+    v_2]. K/V pair g serves the query pairs of its group. `keep`: the keep
+    operand (above `_kept_here`), int32 and so without a gradient; the
+    backward respects the same bits."""
     out = _packed_forward(
-        q, k, v, heads=heads, causal=causal, statistics=False, window=window,
-        pair=pair, blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
+        q, k, v, keep, heads=heads, causal=causal, statistics=False,
+        window=window, pair=pair,
+        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
     return tuple(out) if pair else out[0]
 
 
-def _packed_attention_fwd(q, k, v, heads, causal, window=0, pair=False):
+def _packed_attention_fwd(q, k, v, heads, causal, window=0, pair=False,
+                          keep=None):
     *o, lse = _packed_forward(
-        q, k, v, heads=heads, causal=causal, statistics=True, window=window,
-        pair=pair, blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
+        q, k, v, keep, heads=heads, causal=causal, statistics=True,
+        window=window, pair=pair,
+        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype))
     o = tuple(o) if pair else o[0]
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, o, lse, keep)
 
 
 def _packed_attention_bwd(heads, causal, window, pair, saved, do):
-    q, k, v, o, lse = saved
+    q, k, v, o, lse, keep = saved
     return _packed_backward(
-        q, k, v, o, lse, do, heads=heads, causal=causal, window=window,
-        pair=pair, blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
+        q, k, v, o, lse, do, keep, heads=heads, causal=causal,
+        window=window, pair=pair,
+        blocks=_v5e_block_sizes(q.shape[1], k.shape[1], q.dtype),
         fused=q.shape[1] * max(_LANES, q.shape[2] // heads)
-        <= _FUSED_BWD_MAX_ELEMENTS)
+        <= _FUSED_BWD_MAX_ELEMENTS) + (None,)
 
 
 _packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)

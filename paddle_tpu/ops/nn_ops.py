@@ -282,7 +282,10 @@ def rms_norm_kernel(ctx):
     ctx.set_output("Y", y.reshape(x.shape))
 
 
-def rotary(x, theta: float, rotary_dim=None, out_dtype=None):
+_FED_COUNTER = "pt_rotary_fed_positions_total"
+
+
+def rotary(x, theta: float, rotary_dim=None, out_dtype=None, tables=None):
     """Rotary position embedding on [B, T, H, D], rotate-half convention
     (the `transformers` one: the head dim's two HALVES pair up, not its
     even/odd lanes): inv_freq_i = theta^(-2i/D), position t; out = x * cos
@@ -293,10 +296,12 @@ def rotary(x, theta: float, rotary_dim=None, out_dtype=None):
     head). The backward is a rule of its own (`qk_assemble`; reverse mode
     only): the turn by the negated angle of the cotangent, one pass, no
     residual, returned in x's dtype, the passed-through lanes' cotangent
-    passed through."""
+    passed through. `tables`: (cos, sin) of fed positions
+    (`qk_ops.fed_tables`) in place of 0..T-1's."""
     R = x.shape[3] if rotary_dim is None else int(rotary_dim)
     return qk_assemble(x, None, 0.0, False, float(theta), R,
-                       jnp.dtype(x.dtype if out_dtype is None else out_dtype))
+                       jnp.dtype(x.dtype if out_dtype is None else out_dtype),
+                       True, tables)
 
 
 def _norm_behind(ctx):
@@ -323,7 +328,15 @@ def rotary_embedding_kernel(ctx):
     the kernel's input dtype. Such a rotary behind a norm the layer marked
     too takes the norm's INPUT and does both (`qk_assemble`: the same
     values): the norm's float32 output stays bound for whoever else reads
-    it, and is never computed where nobody does."""
+    it, and is never computed where nobody does.
+    Input `Positions` (optional), int32 [B, A, T] with attr `sections` (A
+    counts of frequency pairs, summing to half the turned lanes): positions
+    as FED DATA, pair i's from the axis whose section holds it (a three-axis
+    rotary: temporal, height, width; A = 1 is a plain fed position). The cos
+    and sin tables are made from it here, in float32, under the inner scope
+    `tables`, and are operands of `qk_assemble`'s two lowerings; an op
+    traced with it counts in `pt_rotary_fed_positions_total`. Without the
+    input the op is the one it always was."""
     x = ctx.input("X")
     heads = ctx.attr("num_heads")
     rotary_dim = ctx.attr("rotary_dim")
@@ -337,6 +350,17 @@ def rotary_embedding_kernel(ctx):
                          f"a head of {E // heads}")
     D, theta = E // heads, float(ctx.attr("theta", 10000.0))
     emit, out_dtype = _qk_emit(ctx, jnp.dtype(x.dtype))
+    tables = None
+    if ctx.has_input("Positions"):
+        from ..obs import metrics
+        from .qk_ops import fed_tables
+
+        metrics.registry().counter_inc(
+            _FED_COUNTER, help="rotary ops traced whose positions are fed "
+            "data (a `Positions` input), by the axes a token has",
+            labels={"axes": str(len(ctx.attr("sections")))})
+        tables = fed_tables(ctx.input("Positions"), ctx.attr("sections"),
+                            theta, D if rotary_dim is None else rotary_dim)
     norm = _norm_behind(ctx) if emit == "kernel" and rotary_dim is None \
         else None
     if norm is not None and norm.attrs.get("group") in (None, D):
@@ -344,9 +368,10 @@ def rotary_embedding_kernel(ctx):
             ctx.env[norm.inputs["X"][0]].reshape(B, T, heads, D),
             ctx.env[norm.inputs["Scale"][0]].reshape(-1, D),
             norm.attrs.get("epsilon", 1e-5), norm.attrs.get("group") is None,
-            theta, D, out_dtype)
+            theta, D, out_dtype, True, tables)
     else:
-        out = rotary(x.reshape(B, T, heads, D), theta, rotary_dim, out_dtype)
+        out = rotary(x.reshape(B, T, heads, D), theta, rotary_dim, out_dtype,
+                     tables)
     ctx.set_output("Out", out.reshape(B, T, E))
 
 

@@ -46,12 +46,40 @@ def _tables(T, theta, R):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def _turn(x32, theta, R, backward=False):
+def fed_tables(positions, sections, theta, R):
+    """cos and sin [B, T, R / 2] float32 of FED positions: `positions` int32
+    [B, A, T], A axes a token, and `sections` (A counts that sum to R / 2):
+    frequency pair i takes its position from the axis whose section holds i
+    (contiguous sections, the Qwen2-VL form of a three-axis rotary:
+    temporal, height, width), angle = position * theta^(-2i / R). One axis
+    and one section is a plain fed position; equal axes give `_tables`' bits
+    at position t = the fed one."""
+    sections = tuple(int(n) for n in sections)
+    if positions.ndim != 3 or positions.shape[1] != len(sections) \
+            or sum(sections) != R // 2:
+        raise ValueError(
+            f"positions {positions.shape} with sections {sections}: [B, "
+            f"{len(sections)}, T] and sections that sum to {R // 2} pairs")
+    with jax.named_scope("tables"):
+        inv_freq = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
+        axis_of = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections),
+                             total_repeat_length=R // 2)
+        # [B, T, R / 2]: pair i's position, from its axis
+        at = jnp.take(positions, axis_of, axis=1).transpose(0, 2, 1)
+        ang = at.astype(jnp.float32) * inv_freq
+        return jnp.cos(ang), jnp.sin(ang)
+
+
+def _turn(x32, theta, R, backward=False, tables=None):
     """x32 [B, T, H, D] float32 with the last R lanes of each head turned by
     the position's angles (rotate-half pairs), `backward` by their negation:
-    the map is linear and that is its transpose. Float32."""
+    the map is linear and that is its transpose. Float32. `tables`: (cos, sin)
+    [B, T, R / 2] of fed positions (`fed_tables`) in place of 0..T-1's."""
     B, T, H, D = x32.shape
-    cos, sin = (t[None, :, None, :] for t in _tables(T, theta, R))
+    if tables is None:
+        cos, sin = (t[None, :, None, :] for t in _tables(T, theta, R))
+    else:
+        cos, sin = (t[:, :, None, :] for t in tables)
     if backward:
         sin = -sin
     if R < D:
@@ -73,19 +101,19 @@ def _rsqrt_mean_square(x32, eps, whole):
     return jax.lax.rsqrt(jnp.mean(rows * rows, axis=-1, keepdims=True) + eps)
 
 
-def _assemble(x, scale, eps, whole, theta, R, out_dtype):
+def _assemble(x, scale, eps, whole, theta, R, out_dtype, tables=None):
     x32 = x.astype(jnp.float32)
     if scale is not None:
         x32 = x32 * _rsqrt_mean_square(x32, eps, whole) * scale
     if theta is not None:
-        x32 = _turn(x32, theta, R)
+        x32 = _turn(x32, theta, R, tables=tables)
     return x32.astype(out_dtype)
 
 
-def _assemble_bwd(x, scale, g, eps, whole, theta, R):
+def _assemble_bwd(x, scale, g, eps, whole, theta, R, tables=None):
     g = g.astype(jnp.float32)
     if theta is not None:
-        g = _turn(g, theta, R, backward=True)
+        g = _turn(g, theta, R, backward=True, tables=tables)
     if scale is None:
         return g, None
     x32 = x.astype(jnp.float32)
@@ -240,10 +268,11 @@ def _bwd_kernel(*refs, D, eps, whole, norm, turn):
 
 
 def _call(backward, x_like, scale, theta, R, outs, extra, eps, whole,
-          interpret):
+          interpret, tables=None):
     """One pass over the packed rows of `x_like` [B, T, H, D] (and of the
     arrays in `extra`, its shape): blocks of whole rows of a few heads, the
-    tables' blocks by the rows' positions."""
+    tables' blocks by the rows' positions (`tables`, of fed positions: a row
+    of the batch has its own, [B x T, D], and a block reads its own rows')."""
     B, T, H, D = x_like.shape
     rows, heads = _block(T, H, D, whole)
     per_seq = T // rows
@@ -254,12 +283,15 @@ def _call(backward, x_like, scale, theta, R, outs, extra, eps, whole,
         args.append(scale)
         specs.append(pl.BlockSpec(scale.shape, lambda i, j: (0, 0)))
     if theta is not None:
-        cos, sin = _tables(T, theta, R)
+        cos, sin = _tables(T, theta, R) if tables is None else (
+            t.reshape(B * T, R // 2) for t in tables)
         if backward:
             sin = -sin
         args += [jnp.concatenate([cos, cos], axis=-1),
                  jnp.concatenate([-sin, sin], axis=-1)]
-        specs += [pl.BlockSpec((rows, D), lambda i, j: (i % per_seq, 0))] * 2
+        specs += [pl.BlockSpec((rows, D), (lambda i, j: (i % per_seq, 0))
+                               if tables is None else (lambda i, j: (i, 0)))
+                  ] * 2
     out_specs = [block]
     if len(outs) > 1:
         out_specs.append(pl.BlockSpec(
@@ -284,34 +316,38 @@ _STATIC = ("eps", "whole", "theta", "R", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("out_dtype",))
-def _kernel_fwd(x, scale, eps, whole, theta, R, out_dtype, interpret=False):
+def _kernel_fwd(x, scale, eps, whole, theta, R, out_dtype, interpret=False,
+                tables=None):
     B, T, H, D = x.shape
     out, = _call(False, x, scale, theta, R,
                  [jax.ShapeDtypeStruct((B * T, H * D), out_dtype)], (), eps,
-                 whole, interpret)
+                 whole, interpret, tables)
     return out.reshape(x.shape)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _kernel_bwd(x, scale, g, eps, whole, theta, R, interpret=False):
+def _kernel_bwd(x, scale, g, eps, whole, theta, R, interpret=False,
+                tables=None):
     B, T, H, D = g.shape
     outs = [jax.ShapeDtypeStruct((B * T, H * D), x.dtype)]
     if scale is None:
-        dx, = _call(True, g, None, theta, R, outs, (), eps, whole, interpret)
+        dx, = _call(True, g, None, theta, R, outs, (), eps, whole, interpret,
+                    tables)
         return dx.reshape(g.shape), None
     # eight sublanes of partial sums a grid step; XLA adds them up
     rows, heads = _block(T, H, D, whole)
     outs.append(jax.ShapeDtypeStruct(
         (B * T // rows * (H // heads), 8, scale.size), jnp.float32))
     dx, dscale = _call(True, g, scale, theta, R, outs, (x,), eps, whole,
-                       interpret)
+                       interpret, tables)
     return dx.reshape(g.shape), jnp.sum(dscale, axis=(0, 1)).reshape(
         scale.shape)
 
 
 # ---- the function --------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
-def qk_assemble(x, scale, eps, whole, theta, R, out_dtype, kernels=True):
+def qk_assemble(x, scale, eps, whole, theta, R, out_dtype, kernels=True,
+                tables=None):
     """On the [B, T, H, D] view x of a Q or K projection: the RMS norm with
     `scale` [H | 1, D] (None: no norm) over each head's D lanes, or with
     `whole` over all H x D of a token; then the rotary turn of base `theta`
@@ -322,25 +358,30 @@ def qk_assemble(x, scale, eps, whole, theta, R, out_dtype, kernels=True):
     forms the normed value again in registers and gives dx in x's dtype,
     dScale in float32. `kernels` False: the XLA formulation whatever the
     backend and the shape (a value that is dead code where the layer's rotary
-    takes the norm's input: a launch nobody reads is still traced)."""
+    takes the norm's input: a launch nobody reads is still traced).
+    `tables`: (cos, sin) [B, T, R / 2] float32 of FED positions
+    (`fed_tables`), operands of both lowerings in place of the tables of
+    0..T-1; they get no gradient. None: every trace is what it was."""
     return _qk_assemble_fwd(x, scale, eps, whole, theta, R, out_dtype,
-                            kernels)[0]
+                            kernels, tables)[0]
 
 
-def _qk_assemble_fwd(x, scale, eps, whole, theta, R, out_dtype, kernels):
+def _qk_assemble_fwd(x, scale, eps, whole, theta, R, out_dtype, kernels,
+                     tables=None):
     lower = _kernel_fwd if kernels and kernels_eligible(x, theta, R) \
         else _assemble
-    out = lower(x, scale, eps, whole, theta, R, out_dtype)
+    out = lower(x, scale, eps, whole, theta, R, out_dtype, tables=tables)
     # without a norm the rule needs x's dtype alone
-    return out, (x if scale is not None else jnp.zeros((), x.dtype), scale)
+    return out, (x if scale is not None else jnp.zeros((), x.dtype), scale,
+                 tables)
 
 
 def _qk_assemble_bwd(eps, whole, theta, R, out_dtype, kernels, saved, g):
-    x, scale = saved
+    x, scale, tables = saved
     lower = _kernel_bwd if kernels and kernels_eligible(g, theta, R) \
         else _assemble_bwd
-    dx, dscale = lower(x, scale, g, eps, whole, theta, R)
-    return dx.astype(x.dtype), dscale
+    dx, dscale = lower(x, scale, g, eps, whole, theta, R, tables=tables)
+    return dx.astype(x.dtype), dscale, None
 
 
 qk_assemble.defvjp(_qk_assemble_fwd, _qk_assemble_bwd)

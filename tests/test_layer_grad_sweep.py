@@ -877,6 +877,49 @@ def build_rotary_embedding_sub_range():
     return _scalar(L.rotary_embedding(h, num_heads=2, rotary_dim=2)), feed
 
 
+def _fed_positions(t=4):
+    """int32 [1, 3, t] positions as data: a two-token span of one row (its
+    height axis stays, its width axis advances) among text tokens."""
+    p = L.data("p", shape=[1, 3, t], dtype=np.int32, append_batch_size=False)
+    pos = np.stack([np.arange(t)] * 3)
+    pos[0, 1:3], pos[1, 1:3], pos[2, 1:3] = 1, 1, (1, 2)
+    pos[:, 3:] = 3 + np.arange(t - 3)
+    return p, {"p": pos[None].astype(np.int32)}
+
+
+@case
+def build_rotary_embedding_fed_positions():
+    # two heads of four lanes: pair 0 turns by the temporal axis, pair 1 by
+    # the width axis; the height axis has no pair
+    h, feed = _pre_btd()
+    p, fed = _fed_positions()
+    return _scalar(L.rotary_embedding(h, num_heads=2, positions=p,
+                                      sections=[1, 0, 1])), {**feed, **fed}
+
+
+@case
+def build_multi_head_attention_fed_positions():
+    h, feed = _pre_btd()
+    p, fed = _fed_positions()
+    h = L.multi_head_attention(h, num_heads=2, causal=True, bias_attr=False,
+                               qk_norm="head", rotary_theta=100.0,
+                               positions=p, rope_sections=[1, 1, 0])
+    return _scalar(h), {**feed, **fed}
+
+
+@case
+def build_sparse_attention():
+    # four query heads over two K/V heads of 4 lanes, an indexer of two heads
+    # of 3; a row keeps 3 of up to 6 keys (the indexer is frozen: its three
+    # matrices are not checked, and get no gradient)
+    h, feed = _pre_btd(6, 8)
+    p, fed = _fed_positions(6)
+    out = L.sparse_attention(h, p, num_heads=4, num_kv_heads=2, head_dim=4,
+                             index_heads=2, index_head_dim=3, topk=3,
+                             rope_sections=[1, 0, 1], rotary_theta=100.0)
+    return _scalar(out), {**feed, **fed}
+
+
 @case
 def build_latent_attention():
     # two heads of 3 + 2 lanes through a 6-wide query latent and a 4-wide

@@ -197,6 +197,49 @@ def _flash_pair(case):
     return (fwd_bwd if with_bwd else fwd), specs
 
 
+def _flash_keep(with_bwd):
+    from paddle_tpu.ops import flash_ops
+
+    # a Keye-VL sparse-attention layer's launch at the cell's shape: 32 query
+    # heads x 128 over 4 K/V heads, T 16 384, under the KEEP operand: int32
+    # [1, 16384, 512], a bit a (row, key), whose [block_q, 128] block of words
+    # opens into a k block's key tiles by a shift a tile; the fused backward
+    # holds dQ's [16384, 128] float32 beside it
+    T = 16384
+    specs = [((1, T, 4096), BF16)] + [((1, T, 512), BF16)] * 2 + [
+        ((1, T, 128 * (T // 4096)), jnp.int32)]
+
+    def fwd(q, k, v, keep):
+        return flash_ops._packed_attention(q, k, v, 32, True, 0, False, keep)
+
+    def fwd_bwd(q, k, v, keep):
+        return jax.grad(lambda q, k, v: fwd(q, k, v, keep).astype(F32).sum(),
+                        (0, 1, 2))(q, k, v)
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
+def _qk_fed(with_bwd):
+    from paddle_tpu.ops import qk_ops
+
+    # the per-head norm and the three-axis rotary of a Keye-VL layer's Q in
+    # one launch, the cos and sin tables of the FED positions as operands
+    T = 16384
+    specs = [((1, T, 32, 128), BF16), ((1, 128), F32), ((1, 3, T), jnp.int32)]
+
+    def fwd(x, scale, positions):
+        tables = qk_ops.fed_tables(positions, (16, 24, 24), 1e7, 128)
+        return qk_ops._kernel_fwd(x, scale, 1e-6, False, 1e7, 128, BF16,
+                                  tables=tables)
+
+    def fwd_bwd(x, scale, positions):
+        tables = qk_ops.fed_tables(positions, (16, 24, 24), 1e7, 128)
+        return qk_ops._kernel_bwd(x, scale, x, 1e-6, False, 1e7, 128,
+                                  tables=tables)
+
+    return (fwd_bwd if with_bwd else fwd), specs
+
+
 def _short_conv(case):
     from paddle_tpu.ops import short_conv_ops
 
@@ -378,6 +421,8 @@ CASES = [
     ("flash_pair_fwd_bwd_phi4_t8192", _flash_pair, (0, True)),
     ("flash_pair_fwd_phi4_window512_t8192", _flash_pair, (512, False)),
     ("flash_pair_fwd_bwd_phi4_window512_t8192", _flash_pair, (512, True)),
+    ("flash_keep_operand_fwd_bwd_keye_t16384", _flash_keep, True),
+    ("qk_assemble_fed_positions_bwd_keye_t16384", _qk_fed, True),
     ("short_conv_fwd_lfm2_t16384", _short_conv, ((1, 16384, 2048, 3), False)),
     ("short_conv_fwd_bwd_lfm2_t16384", _short_conv,
      ((1, 16384, 2048, 3), True)),
@@ -517,9 +562,11 @@ def _benchmark_model(name, batch, seqlen):
         config, {"batch": batch, "seqlen": seqlen}, 7)
 
 
-def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
+def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False,
+                  more_feed=None):
     """(raw step, its arguments as shapes on the described chip) of the
-    training step of the model `build()` makes, or of its `for_test` clone."""
+    training step of the model `build()` makes, or of its `for_test` clone.
+    `more_feed`: {name: (dims, dtype)} fed beside `toks` and `labels`."""
     import numpy as np
 
     import paddle_tpu as pt
@@ -555,6 +602,7 @@ def _step_program(build, batch, seqlen, one_chip, monkeypatch, for_test=False):
     kept = {n: v for n, v in state.items() if n not in rebound}
     feed = {"toks": shape((batch, seqlen), np.int32),
             "labels": shape((batch, seqlen, 1), np.int32)}
+    feed.update({n: shape(*v) for n, v in (more_feed or {}).items()})
     fetch = [model["cost"].name] + [s["var"] for s in prog.step_statistics]
     return (pt.Executor()._raw_step(prog, fetch),
             (donated, kept, feed, shape((), np.uint32)))
@@ -945,6 +993,53 @@ def test_lfm2_step_program_fits_one_chip(one_chip, compiled_mode,
         memory.temp_size_in_bytes / 2**30))
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 14.5 * 2**30)
+
+
+def test_keye_step_program_fits_one_chip(one_chip, compiled_mode,
+                                         monkeypatch):
+    """The keye-vl-2.0-30b-a3b cell's whole step (batch 1 x T 16 384, 465 M
+    parameters of which 456 M are trained: four layers of learned sparse
+    attention over 16 of 128 experts, an eighth of the vocabulary) compiles
+    for the described v5e with arguments + temporaries under 13 GiB by the
+    compiler's own books (which settles T 16 384 before any chip time), four
+    forward and four fused-backward attention launches under the keep operand
+    (and no other: the plain form is not in the program), eight norm-and-rotary
+    launches a direction with the fed tables, the selection by counting (no
+    sort in the program), the grouped-matmul kernels in it, and NO array of
+    [T, T] elements of any type: the largest the indexer writes is a tile's
+    [512, 16, 16384] scores."""
+    T = 16384
+    raw, args = _step_program(
+        lambda: _load_module(os.path.join(
+            ROOT, "chipbench", "configs", "keye-vl-2.0-30b-a3b", "model.py")
+        ).get_model(
+            __import__("json").load(open(os.path.join(
+                ROOT, "chipbench", "configs", "keye-vl-2.0-30b-a3b",
+                "config.json"))),
+            {"batch": 1, "seqlen": T, "image_spans": 4, "image_grid": 32}, 7),
+        1, T, one_chip, monkeypatch,
+        more_feed={"positions": ((1, 3, T), "int32")})
+    launches = dict(_launches(jax.make_jaxpr(raw)(*args).jaxpr))
+    assert {n: c for n, c in launches.items()
+            if n.startswith(("flash", "qk_"))} == {
+        "flash_attention_fwd": 4, "flash_attention_bwd": 4,
+        "qk_assemble_fwd": 8, "qk_assemble_bwd": 8}
+    compiled = jax.jit(raw, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "gmm" in text and "ragged-dot" not in text
+    assert not [line for line in text.splitlines()      # the router sorts
+                if " sort(" in line and "sparse_keep" in line]
+    assert not re.findall(r"\b[a-z]+\d*\[[\d,]*16384,16384[\d,]*\]", text)
+    biggest = max(n for _, dtype, n, body, _ in _written_arrays(text)
+                  if not body and dtype in ("f32", "bf16"))
+    assert biggest <= T * 18992                 # the head's logits
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 5.4e9          # 12 B a trained one
+    print("keye step: arguments %.3f GiB, temporaries %.3f GiB" % (
+        memory.argument_size_in_bytes / 2**30,
+        memory.temp_size_in_bytes / 2**30))
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 13.0 * 2**30)
 
 
 def test_phi4_step_program_fits_one_chip(one_chip, compiled_mode,

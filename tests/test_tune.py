@@ -221,6 +221,9 @@ CELL_BLOCKS = {
     "lfm2-24b-a2b": (16384, 1024),
     # PR 57: twelve launches a step at 20 heads of 64, one under a window
     "phi-4-mini-flash-reasoning": (8192, 1024),
+    # PR 60: four launches a step under a keep operand whose [block_q, 128]
+    # words hold a k block's 8 key tiles (32 is a multiple of them)
+    "keye-vl-2.0-30b-a3b": (16384, 1024),
 }
 
 
