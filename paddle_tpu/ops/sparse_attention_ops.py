@@ -18,7 +18,15 @@ Two ops (`layers.sparse_attention` appends them behind the projections):
   whole) and the selection, written as the KEEP operand of the attention
   kernels: one BIT a (row, key), int32 [B, T, 128 x ceil(T / 4096)]
   (`flash_ops._kept_here` has the layout): 32 MiB a layer at T 16 384, and
-  all the backward keeps of the choice. Its second output `Chosen` int32
+  all the backward keeps of the choice. A tile is scored and counted against
+  its CAUSAL PREFIX, not the sequence: the tiles go in groups of consecutive
+  tiles (`_groups`: at most `GROUPS` scored, from T, the tile's rows and
+  `topk` alone), a group's tiles see the keys before the group's end, and
+  the tiles whose rows all lie under `topk` are neither scored nor counted
+  (such a row keeps every key it may see). The bits are the whole-sequence
+  form's to the bit: a key behind the prefix was never valid. At T 16 384,
+  `topk` 2 048: 54.7 % of the [T, T] pairs (`pt_sparse_keep_scored_pairs`),
+  where half are causal. Its second output `Chosen` int32
   [B x T, topk] (a row's kept keys by index, ascending, -1 where it has
   fewer) is derived from the bits alone, so a program that does not fetch it
   does not compute it.
@@ -39,22 +47,26 @@ tile was what the step waited for: 361 ms a layer against 18.5).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.registry import register_op
 from . import flash_ops
 
 BLOCK = 512                     # rows a tile of scores holds
+GROUPS = 8                      # causal prefixes a sequence is scored against
 _LANES = flash_ops._LANES
 _TILES = flash_ops.KEEP_TILES
 _SCORE_BYTES_A_BLOCK = 256 * 2**20   # the plain attention's float32 scores
 
 
-def _rows(T: int, cap: int = BLOCK) -> int:
-    """The largest divisor of T that is at most `cap`."""
+def _rows(T: int, cap: int | None = None) -> int:
+    """The largest divisor of T that is at most `cap` (`BLOCK` if none)."""
+    cap = BLOCK if cap is None else cap
     return max(r for r in range(1, max(1, min(T, cap)) + 1) if T % r == 0)
 
 
@@ -143,27 +155,77 @@ def unpack_bits(words, T):
     return bits.reshape(R, -1)[:, :T] != 0
 
 
+def _groups(tiles: int, rows: int, topk: int):
+    """[(first tile, end tile)]: a sequence's `tiles` tiles of `rows` rows in
+    groups of consecutive tiles; a group's tiles see the keys before its end
+    and no others. The tiles that end at or before key `topk` are one group
+    (their rows keep every key they may see: nothing to score); the others
+    end where a split into `GROUPS` equal parts ends, so at most `GROUPS`
+    groups are scored."""
+    size = -(-tiles // GROUPS)
+    free = min(topk // rows, tiles)
+    ends = sorted(({free, tiles} - {0})
+                  | {e for e in range(size, tiles, size) if e > free})
+    return list(zip([0] + ends[:-1], ends))
+
+
+def scored_pairs(T: int, topk: int) -> int:
+    """The (row, key) pairs the indexer scores for one sequence of T: a
+    group's rows times its prefix, over the groups that are scored at all
+    (T x T for one group; T (T + 1) / 2 are causal)."""
+    rows = _rows(T)
+    return sum((end - first) * rows * end * rows
+               for first, end in _groups(T // rows, rows, topk)
+               if end * rows > topk)
+
+
 def keep_bits(q_i, k_i, w_i, topk: int):
     """The keep operand int32 [B, T, 128 x ceil(T / 4096)] of q_i [B, T, Hi,
     Di], k_i [B, T, Di], w_i [B, T, Hi]: a tile of at most `BLOCK` rows of
-    one sequence at a time."""
-    B, T = q_i.shape[:2]
+    one sequence at a time, scored and counted against its group's causal
+    prefix k_i[b, :P] (`_groups`), its words padded with zero lanes behind
+    key P. A group whose prefix is at most `topk` keys long is neither scored
+    nor counted: its bits are the causal mask's."""
+    T = q_i.shape[1]
     rows = _rows(T)
+    return _grouped_bits(q_i, k_i, w_i, topk=topk, rows=rows,
+                         groups=tuple(_groups(T // rows, rows, topk)),
+                         scores=index_scores, select=select_by_count)
+
+
+# jitted: a group's loop is traced and lowered once for all the layers that
+# call it with one shape; everything that shapes the trace is a static
+# argument, the two functions among them (read from the module where
+# `keep_bits` is called, so that one replaced there is another trace)
+@functools.partial(jax.jit, static_argnames=("topk", "rows", "groups",
+                                             "scores", "select"))
+def _grouped_bits(q_i, k_i, w_i, *, topk, rows, groups, scores, select):
+    B, T = q_i.shape[:2]
     per_seq = T // rows
+    lanes = keep_lanes(T)
+    out = []
+    for first, end in groups:
+        n, P = end - first, end * rows
+        k_p = k_i[:, :P]
 
-    def tile(i):
-        b, t0 = i // per_seq, (i % per_seq) * rows
+        def tile(i):
+            b, t0 = i // per_seq, (i % per_seq) * rows
 
-        def mine(a):
-            return jax.lax.dynamic_slice_in_dim(a[b], t0, rows)
+            def mine(a):
+                return jax.lax.dynamic_slice_in_dim(a[b], t0, rows)
 
-        z = index_scores(mine(q_i), k_i[b], mine(w_i))
-        with jax.named_scope("select"):
-            valid = jnp.arange(T)[None, :] <= (t0 + jnp.arange(rows))[:, None]
-            return pack_bits(select_by_count(z, valid, topk))
+            z = scores(mine(q_i), k_p[b], mine(w_i)) if P > topk else None
+            with jax.named_scope("select"):
+                valid = jnp.arange(P)[None, :] <= (
+                    t0 + jnp.arange(rows))[:, None]
+                return pack_bits(valid if z is None
+                                 else select(z, valid, topk))
 
-    bits = jax.lax.map(tile, jnp.arange(B * per_seq))
-    return bits.reshape(B, T, -1)
+        ids = np.add.outer(np.arange(B) * per_seq, np.arange(first, end))
+        bits = jax.lax.map(tile, jnp.asarray(ids.ravel(), jnp.int32))
+        bits = bits.reshape(B, n * rows, -1)
+        out.append(jnp.pad(bits, ((0, 0), (0, 0), (0, lanes - bits.shape[2]))))
+    return jnp.concatenate(out, axis=1)
 
 
 def chosen_from_bits(bits, T: int, topk: int):
@@ -237,6 +299,8 @@ _DISPATCH_HELP = ("sparse-attention ops traced, by the form their attention "
 # (an op's output name, T, topk, path) -> a step's (kept pairs, causal pairs,
 # keep operand's bytes, pairs the forward computes x heads, pairs kept x heads)
 _traced: dict = {}
+# (a `sparse_keep` op's output name, T, topk) -> the pairs its indexer scores
+_scored: dict = {}
 
 
 def kept_pairs(T: int, topk: int) -> int:
@@ -247,18 +311,33 @@ def kept_pairs(T: int, topk: int) -> int:
 
 
 def _families():
+    def of_attention(i):
+        return sum(v[i] for v in _traced.values())
+
     gauges = (
         ("pt_sparse_attention_kept_pairs", "(row, key) pairs the sparse-"
          "attention layers traced so far keep a step, a head (static "
-         "arithmetic: min(topk, t + 1) a row)"),
+         "arithmetic: min(topk, t + 1) a row)", of_attention(0)),
         ("pt_sparse_attention_causal_pairs", "(row, key) pairs under the "
-         "causal mask of the same layers a step, a head"),
+         "causal mask of the same layers a step, a head", of_attention(1)),
         ("pt_sparse_attention_saved_choice_bytes", "bytes of the keep "
          "operands (a bit a (row, key)) the same layers' backward keeps of "
-         "the choice a step"))
-    return [(name, "gauge", text,
-             [({}, float(sum(v[i] for v in _traced.values())))])
-            for i, (name, text) in enumerate(gauges)]
+         "the choice a step", of_attention(2)),
+        ("pt_sparse_keep_scored_pairs", "(row, key) pairs the indexer scores "
+         "a step in the sparse_keep ops traced so far (static arithmetic: a "
+         "group of tiles against its causal prefix; twice the causal pairs "
+         "where every tile sees the whole sequence)", sum(_scored.values())))
+    return [(name, "gauge", text, [({}, float(value))])
+            for name, text, value in gauges]
+
+
+def _count_scored(ctx, B, T, topk):
+    """One `sparse_keep` op traced: the pairs its indexer scores a step,
+    recorded once an (op, T, topk) in `_scored`."""
+    from ..obs import metrics
+
+    _scored[ctx.op.outputs["Keep"][0], T, topk] = B * scored_pairs(T, topk)
+    metrics.registry().add_collector(_families)
 
 
 def _count(ctx, path, q, topk, bits):
@@ -305,12 +384,16 @@ def sparse_keep_kernel(ctx):
     """IndexQ [B, T, Hi x Di], IndexK [B, T, Di], IndexW [B, T, Hi] (attrs
     `index_heads`, `topk`) -> Keep int32 [B, T, 128 x ceil(T / 4096)], the
     attention kernels' keep operand, and Chosen int32 [B x T, topk]. Inner
-    scopes `indexer` (the scores), `select`, `chosen`. No gradient."""
+    scopes `indexer` (a tile's scores against its group's causal prefix),
+    `select` (the counts over that prefix, the bits packed), `chosen`; one
+    loop a group of tiles (`keep_bits`), the scored pairs counted where the
+    op is traced (`pt_sparse_keep_scored_pairs`). No gradient."""
     q_i, k_i, w_i = (jax.lax.stop_gradient(ctx.input(s))
                      for s in ("IndexQ", "IndexK", "IndexW"))
     B, T, _ = q_i.shape
     heads, topk = int(ctx.attr("index_heads")), int(ctx.attr("topk"))
     bits = keep_bits(q_i.reshape(B, T, heads, -1), k_i, w_i, topk)
+    _count_scored(ctx, B, T, topk)
     ctx.set_output("Keep", bits)
     ctx.set_output("Chosen", chosen_from_bits(bits, T, topk))
 

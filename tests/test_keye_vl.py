@@ -276,6 +276,152 @@ def test_selection_by_count_is_the_top_k_on_a_tile_of_equal_scores():
                      == _select_top_k(z, valid, k)).all())
 
 
+def _keep_bits_whole(q_i, k_i, w_i, topk):
+    """`keep_bits` as PR 60 wrote it: every tile scored and counted against
+    the WHOLE sequence's keys. The witness of the grouped form."""
+    Bn, Tn = q_i.shape[:2]
+    rows = sp._rows(Tn)
+    per_seq = Tn // rows
+
+    def tile(i):
+        b, t0 = i // per_seq, (i % per_seq) * rows
+
+        def mine(a):
+            return jax.lax.dynamic_slice_in_dim(a[b], t0, rows)
+
+        z = sp.index_scores(mine(q_i), k_i[b], mine(w_i))
+        with jax.named_scope("select"):
+            valid = jnp.arange(Tn)[None, :] <= (t0 + jnp.arange(rows))[:, None]
+            return sp.pack_bits(sp.select_by_count(z, valid, topk))
+
+    bits = jax.lax.map(tile, jnp.arange(Bn * per_seq))
+    return bits.reshape(Bn, Tn, -1)
+
+
+def _exact_operands(Tn, dtype, seed=0, batch=2, Hi=16, Di=8):
+    """Indexer operands whose every score is exact in float32 in whatever
+    order a backend sums the products and the heads: small integers and
+    halves (exact in bf16 too). A handful of distinct keys, so that most of a
+    row's scores are ties, across every prefix's end."""
+    r = _rng(seed)
+    q_i = r.randint(-2, 3, (batch, Tn, Hi, Di))
+    k_i = r.randint(-2, 3, (batch, 5, Di))[:, r.randint(0, 5, Tn)]
+    w_i = r.randint(-2, 3, (batch, Tn, Hi)) / 2
+    return tuple(jnp.asarray(a, dtype) for a in (q_i, k_i, w_i))
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(inner)
+
+
+# (tiles of 32 rows, topk, dtype): 1, 3, 8 and 9 tiles; topk under a tile's
+# rows (no group is free), at the first group's end, between two groups' ends
+# (the free tiles are a group of their own), at T and beyond it
+GROUPED = [(1, 8, "float32"), (1, 32, "bfloat16"), (3, 16, "bfloat16"),
+           (3, 32, "float32"), (3, 40, "bfloat16"), (8, 8, "float32"),
+           (8, 100, "bfloat16"), (9, 20, "bfloat16"), (9, 64, "float32"),
+           (9, 1000, "float32")]
+
+
+@pytest.mark.parametrize("tiles,topk,dtype", GROUPED)
+def test_grouped_keep_bits_are_the_whole_sequence_forms(monkeypatch, tiles,
+                                                        topk, dtype):
+    """A tile scored and counted against its group's causal prefix keeps what
+    it kept against the whole sequence, to the bit, ties included. Under one
+    `jit` a case (eagerly every group's map compiles alone)."""
+    monkeypatch.setattr(sp, "BLOCK", 32)
+    Tn = 32 * tiles
+    operands = _exact_operands(Tn, dtype, seed=tiles + topk)
+    got, want = jax.jit(lambda *a: (
+        sp.keep_bits(*a, topk), _keep_bits_whole(*a, topk)))(*operands)
+    assert got.shape == (2, Tn, sp.keep_lanes(Tn)) and got.dtype == jnp.int32
+    assert bool((got == want).all())
+    kept = np.asarray(sp.unpack_bits(got.reshape(2 * Tn, -1), Tn)).sum(1)
+    assert (kept == np.minimum(topk, np.tile(np.arange(Tn), 2) + 1)).all()
+
+
+def test_groups_follow_the_shapes_and_a_free_group_is_not_scored(monkeypatch):
+    """The groups' ends by hand at the cell's shapes and at the edges; a
+    group whose prefix is at most topk keys traces neither the scores nor a
+    count (no `dot_general`, no `while` but the map's own), and a sequence of
+    one tile is one map over the whole sequence, as before."""
+    assert sp._groups(32, 512, 2048) == [(4 * i, 4 * i + 4) for i in range(8)]
+    assert sp._groups(32, 512, 1024) == [(0, 2), (2, 4)] + [
+        (4 * i, 4 * i + 4) for i in range(1, 8)]
+    assert sp._groups(32, 512, 100) == [(4 * i, 4 * i + 4) for i in range(8)]
+    assert sp._groups(9, 32, 96) == [(0, 3), (3, 4), (4, 6), (6, 8), (8, 9)]
+    assert sp._groups(1, 96, 16) == [(0, 1)] == sp._groups(1, 96, 500)
+    assert sp._groups(32, 512, 1 << 20) == [(0, 32)]
+
+    def count(Tn, topk):
+        names = list(_primitives(jax.make_jaxpr(
+            lambda *a: sp.keep_bits(*a, topk))(
+                *_exact_operands(Tn, "float32")).jaxpr))
+        return names.count("dot_general"), names.count("while") \
+            + names.count("scan")
+
+    monkeypatch.setattr(sp, "BLOCK", 32)
+    assert count(96, 8)[0] == 3 * count(32, 8)[0] > 0   # three, all scored
+    assert count(96, 1000) == (0, 1)    # T <= topk: the causal mask's bits
+    assert count(96, 32)[0] == 2 * count(32, 8)[0]    # the first is free
+    monkeypatch.setattr(sp, "BLOCK", 512)
+    assert count(96, 16) == count(32, 8)              # one tile: one map
+
+
+@pytest.fixture(scope="module")
+def two_tiles():
+    """(the indexer's operands of two tiles of 32 rows, their right bits at
+    topk 32: the first tile free, the second scored)."""
+    operands = _operands(64, Hi=4, ties=False)[3:]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sp, "BLOCK", 32)
+        return operands, jax.jit(lambda *a: sp.keep_bits(*a, 32))(*operands)
+
+
+@pytest.mark.parametrize("control", keye_vl_controls.CONTROLS[:4])
+def test_the_controls_still_turn_the_grouped_bits(monkeypatch, two_tiles,
+                                                  control):
+    """Each control that replaces a function of `sparse_attention_ops` is
+    reached through the module by the grouped form too: the bits change."""
+    monkeypatch.setattr(sp, "BLOCK", 32)
+    operands, right = two_tiles
+    with keye_vl_controls.applied(control):
+        wrong = jax.jit(lambda *a: sp.keep_bits(*a, 32))(*operands)
+    assert wrong.shape == right.shape and not bool((right == wrong).all())
+
+
+def test_scored_pairs_are_static_arithmetic_counted_once(monkeypatch):
+    """`pt_sparse_keep_scored_pairs` by hand at the cell's shapes and at a
+    small one, its ratio to the causal pairs, and an op traced twice counted
+    once."""
+    import types
+
+    # T 16 384 in tiles of 512, topk 2 048: eight groups of 2 048 rows, the
+    # first free, group g scored against 2 048 g keys
+    assert sp.scored_pairs(16384, 2048) == 2048 * 2048 * sum(range(2, 9))
+    assert sp.scored_pairs(16384, 2048) / (16384 * 16385 // 2) \
+        == pytest.approx(1.0937, abs=1e-4)
+    assert sp.scored_pairs(16384, 16384) == 0
+    assert sp.scored_pairs(96, 16) == 96 * 96       # one tile, as before
+    monkeypatch.setattr(sp, "BLOCK", 32)
+    # nine tiles of 32, topk 96: tiles 0-2 free, then ends at 4, 6, 8, 9
+    by_hand = 32 * (1 * 128 + 2 * 192 + 2 * 256 + 1 * 288)
+    assert sp.scored_pairs(288, 96) == by_hand
+    monkeypatch.setattr(sp, "_scored", {})
+    ctx = types.SimpleNamespace(op=types.SimpleNamespace(
+        outputs={"Keep": ["a.attn.keep"]}))
+    for Tn, topk in ((288, 96), (288, 96), (96, 8)):
+        sp._count_scored(ctx, 2, Tn, topk)
+    assert len(sp._scored) == 2
+    families = {f[0]: f[3][0][1] for f in sp._families()}
+    assert families["pt_sparse_keep_scored_pairs"] == 2 * (
+        by_hand + 32 * (32 + 64 + 96))
+
+
 def test_attention_kernels_under_the_keep_operand_match_the_plain_form():
     """The packed flash kernels, interpreted, 4-over-2 heads of 128 at T 1024
     (two 512-row blocks: a crossed block's strips and a bare block, each
@@ -474,6 +620,22 @@ def test_the_indexer_is_frozen_and_gets_no_gradient(float32_step):
     state = [v.name for v in main.persistables()]
     assert not [n for n in state if ".index_w" in n and "moment" in n]
     assert [n for n in state if "attn.wq" in n and "moment" in n]
+
+
+def test_the_scored_pairs_reach_the_registry(float32_step):
+    """A traced model's `sparse_keep` ops publish their gauge beside the
+    attention ops' three: at one tile a sequence every row scores every key,
+    2 T / (T + 1) of the causal pairs."""
+    from paddle_tpu.obs import metrics
+
+    series = {}
+    for line in metrics.registry().render().splitlines():
+        if line.startswith("pt_sparse_"):
+            name, value = line.rsplit(" ", 1)
+            series[name] = float(value)
+    scored = series["pt_sparse_keep_scored_pairs"]
+    assert scored >= 2 * 2 * T * T          # two layers, batch 2
+    assert 1.0 < scored / series["pt_sparse_attention_causal_pairs"] <= 2.0
 
 
 @pytest.mark.parametrize("control", keye_vl_controls.CONTROLS)
